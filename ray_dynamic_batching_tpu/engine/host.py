@@ -28,9 +28,9 @@ class ModelHost:
 
     def __init__(self, checkpoint_dir: Optional[str] = None, seed: int = 0,
                  model_kwargs: Optional[Dict[str, Dict[str, Any]]] = None):
-        from ray_dynamic_batching_tpu.utils.compile_cache import maybe_enable
+        from ray_dynamic_batching_tpu.utils import compile_cache
 
-        maybe_enable()  # repeat bucket compiles become disk hits
+        compile_cache.enable()  # repeat bucket compiles become disk hits
         self.checkpoint_dir = checkpoint_dir
         self.seed = seed
         self.model_kwargs = model_kwargs or {}

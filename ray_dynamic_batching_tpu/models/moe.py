@@ -73,7 +73,9 @@ class MoEBlock(nn.Module):
 
         # dispatch [B,T,E,C]: one-hot over capacity slots; overflow tokens
         # get an out-of-range index -> all-zero row (fall through residual)
-        cap_idx = jnp.where(within_cap, pos_in_expert, C)
+        # (positions are whole-number floats — running counts — and
+        # one_hot wants integer indices)
+        cap_idx = jnp.where(within_cap, pos_in_expert, C).astype(jnp.int32)
         cap_onehot = jax.nn.one_hot(cap_idx, C, dtype=jnp.float32)  # [B,T,k,E,C]
         dispatch = jnp.einsum(
             "btke,btkec->btec", choice_onehot, cap_onehot

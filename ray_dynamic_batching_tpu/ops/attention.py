@@ -1,23 +1,103 @@
-"""Attention ops with backend dispatch (XLA reference now, Pallas on TPU).
+"""Attention ops with backend dispatch (XLA reference, Pallas on TPU).
 
 The reference framework has no attention of its own (it serves fixed-shape
 vision models through torch); attention enters via the north-star LLM configs.
 This module is the single place models get attention from, so the engine can
-swap the XLA einsum reference for the fused Pallas kernel
-(:mod:`ray_dynamic_batching_tpu.ops.flash_attention`) on TPU without touching
+swap the XLA einsum reference for the fused Pallas kernels
+(:mod:`ray_dynamic_batching_tpu.ops.decode_attention`,
+:mod:`ray_dynamic_batching_tpu.ops.flash_attention`) on TPU without touching
 model code.
+
+Which path a call took is never a guess: every dispatch appends one
+:class:`AttentionPath` to a bounded trace-time record — the path, the
+program being traced (the compile ledger's frame), and the reason each
+kernel tried before it declined. ``chip_smoke.py`` prints it as the
+*paths* table.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
-from typing import Optional, Tuple
+import dataclasses
+from typing import Callable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-_BACKEND = "auto"  # "auto" | "xla" | "pallas"
+from ray_dynamic_batching_tpu.ops.pallas_common import resolve_interpret
+from ray_dynamic_batching_tpu.utils.compile_ledger import current_program
+
+# "auto": Pallas on a TPU backend, XLA elsewhere. "xla": the reference
+# everywhere. "pallas": the kernels everywhere (interpret mode off-TPU)
+# and STRICT — a call every kernel declines raises AttentionDeclined
+# with the reasons instead of quietly running the reference.
+_BACKEND = "auto"
+
+PATH_PAGED_KERNEL = "paged_kernel"
+PATH_SLAB_KERNEL = "slab_kernel"
+PATH_FLASH = "flash"
+PATH_XLA = "xla"
+
+
+class AttentionDeclined(ValueError):
+    """Every Pallas kernel declined a call made under the strict
+    ``"pallas"`` backend."""
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionPath:
+    """One dispatch, recorded while its program traced."""
+
+    program: str              # compile-ledger program ("" outside one)
+    path: str                 # PATH_*; gathered paged reads say so below
+    gathered: bool            # paged pool gathered to a slab view first
+    tp: int                   # shard_map width of the kernel call (1 = none)
+    interpret: bool           # kernel ran interpreted (never on a TPU)
+    q_shape: Tuple[int, ...]
+    kv_shape: Tuple[int, ...]
+    kv_dtype: str
+    declines: Tuple[str, ...]  # why each kernel tried first said no
+
+    def describe(self) -> str:
+        name = {
+            PATH_PAGED_KERNEL: "paged kernel",
+            PATH_SLAB_KERNEL: "slab kernel",
+            PATH_FLASH: "flash kernel",
+            PATH_XLA: "XLA einsum",
+        }[self.path]
+        if self.tp > 1:
+            name += f" (shard_map tp={self.tp})"
+        return ("gather-then-" if self.gathered else "") + name
+
+
+# Bounded: one entry per traced attention call (a 24-layer program
+# leaves 24), oldest dropped first. Trace-time only — a cached dispatch
+# never reaches this module.
+_PATHS: collections.deque = collections.deque(maxlen=4096)
+
+
+def attention_paths() -> List[AttentionPath]:
+    """The recorded dispatches, oldest first."""
+    return list(_PATHS)
+
+
+def clear_attention_paths() -> None:
+    _PATHS.clear()
+
+
+def _record(path: str, q, k, declines: List[str], *, gathered: bool = False,
+            tp: int = 1) -> None:
+    kernel = path in (PATH_PAGED_KERNEL, PATH_SLAB_KERNEL, PATH_FLASH)
+    _PATHS.append(AttentionPath(
+        program=current_program(), path=path, gathered=gathered, tp=tp,
+        interpret=kernel and resolve_interpret(None),
+        q_shape=tuple(q.shape), kv_shape=tuple(k.shape),
+        kv_dtype=str(k.dtype), declines=tuple(declines),
+    ))
+
+
 # (mesh, axis) when sequence parallelism is active. ContextVar, not a module
 # global: concurrent jit traces (e.g. a serve replica warming up while a
 # train step traces) must not observe each other's mesh.
@@ -57,20 +137,33 @@ def sequence_parallel(mesh, axis: str = "sp"):
 
 @contextlib.contextmanager
 def tensor_parallel(mesh, axis: str = "tp"):
-    """While active (including during jit tracing), the PAGED decode
-    read routes the Pallas kernel through its per-shard ``shard_map``
-    wrapper over the mesh's ``axis`` (``paged_decode_attention``'s
-    ``mesh`` parameter): q and the page pools split on the kv-head dim,
-    page table and lengths stay replicated — page indices are
-    shard-invariant. The non-kernel paths need no context: the gather
-    fallback is plain jnp, which GSPMD partitions from the pool's
-    NamedSharding. Enter it inside the jitted step function, exactly
-    like :func:`sequence_parallel`."""
+    """While active (including during jit tracing), every Pallas kernel
+    runs per head shard under ``shard_map`` over the mesh's ``axis`` —
+    GSPMD cannot partition a ``pallas_call`` (on a TPU the lowering
+    refuses: "Mosaic kernels cannot be automatically partitioned"). The
+    paged kernel has its own wrapper (``paged_decode_attention``'s
+    ``mesh`` parameter: q and the page pools split on the kv-head dim,
+    page table and lengths replicated — page indices are
+    shard-invariant); the slab-layout kernels go through
+    :func:`_dense_kernel`. The XLA paths need no context: plain jnp,
+    which GSPMD partitions from the operands' shardings. A TP engine
+    holds it around everything it traces
+    (``DecodeEngine._device_ctx``)."""
     token = _TP_CTX.set((mesh, axis))
     try:
         yield
     finally:
         _TP_CTX.reset(token)
+
+
+def _tp_slice() -> Tuple[Optional[object], str, int]:
+    """(mesh, axis, width) of the active :func:`tensor_parallel` slice;
+    width 1 (mesh None) when there is none."""
+    ctx = _TP_CTX.get()
+    if ctx is None:
+        return None, "", 1
+    mesh, axis = ctx
+    return mesh, axis, int(mesh.shape.get(axis, 1))
 
 
 def self_attention(
@@ -151,6 +244,88 @@ def dot_product_attention(
             q, k, v, page_table, kv_lengths, mask=mask, scale=scale,
             k_scale=k_scale, v_scale=v_scale,
         )
+    return _dense_attention(
+        q, k, v, causal=causal, mask=mask, scale=scale,
+        k_scale=k_scale, v_scale=v_scale, declines=[], gathered=False,
+    )
+
+
+class _ShardDeclined(Exception):
+    """A kernel wrapper declined inside a shard_map body (a traced
+    function cannot return None); :func:`_dense_kernel` turns it back
+    into the wrapper's None."""
+
+
+def _dense_kernel(
+    kernel: Callable[..., Optional[jax.Array]],
+    q: jax.Array, k: jax.Array, v: jax.Array,
+    mask: Optional[jax.Array],
+    k_scale: Optional[jax.Array], v_scale: Optional[jax.Array],
+    declines: List[str],
+) -> Tuple[Optional[jax.Array], int]:
+    """Call a slab-layout kernel wrapper ``kernel(q, k, v, mask, k_scale,
+    v_scale)`` — directly, or per head shard under an active
+    :func:`tensor_parallel` slice: q/k/v (and the int8 scale planes)
+    split on their head axis, the head-invariant mask replicated, so
+    each shard's call is the ordinary single-device kernel on its head
+    slice and decides its own eligibility from the shapes it will
+    actually stream. Returns (output or None, shard_map width)."""
+    from jax.sharding import PartitionSpec as P
+
+    mesh, axis, tp = _tp_slice()
+    if tp == 1:
+        return kernel(q, k, v, mask, k_scale, v_scale), 1
+    N, K = q.shape[2], k.shape[2]
+    if N % tp or K % tp:
+        declines.append(
+            f"tp={tp}: heads {N}/{K} do not divide over the slice, and "
+            "GSPMD cannot partition a Pallas call")
+        return None, tp
+    heads = P(None, None, axis, None)
+    operands = {"q": (q, heads), "k": (k, heads), "v": (v, heads)}
+    if mask is not None:
+        operands["mask"] = (mask, P())
+    if k_scale is not None:
+        operands["k_scale"] = (k_scale, P(None, None, axis))
+        operands["v_scale"] = (v_scale, P(None, None, axis))
+    names = list(operands)
+
+    def local(*args):
+        got = dict(zip(names, args))
+        out = kernel(got["q"], got["k"], got["v"], got.get("mask"),
+                     got.get("k_scale"), got.get("v_scale"))
+        if out is None:
+            raise _ShardDeclined
+        return out
+
+    try:
+        # check_vma=False: pallas_call declares no varying-axes rule,
+        # and every operand's layout over ``axis`` is stated above.
+        return jax.shard_map(
+            local, mesh=mesh,
+            in_specs=tuple(operands[n][1] for n in names),
+            out_specs=heads, check_vma=False,
+        )(*(operands[n][0] for n in names)), tp
+    except _ShardDeclined:
+        return None, tp
+
+
+def _dense_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    causal: bool,
+    mask: Optional[jax.Array],
+    scale: Optional[float],
+    k_scale: Optional[jax.Array],
+    v_scale: Optional[jax.Array],
+    declines: List[str],
+    gathered: bool,
+) -> jax.Array:
+    """Slab-layout attention: decode kernel, else flash kernel, else the
+    XLA reference — each decline's reason lands in ``declines`` (seeded
+    by the paged path when it gathered its way here)."""
     if _use_pallas():
         if not causal:
             # Small query windows — plain decode (Tq == 1), speculative
@@ -163,26 +338,51 @@ def dot_product_attention(
             # wider windows.
             from ray_dynamic_batching_tpu.ops import decode_attention
 
-            out = decode_attention.decode_attention(
-                q, k, v, mask=mask, scale=scale,
-                k_scale=k_scale, v_scale=v_scale,
+            out, tp = _dense_kernel(
+                lambda q, k, v, mask, ks, vs:
+                decode_attention.decode_attention(
+                    q, k, v, mask=mask, scale=scale,
+                    k_scale=ks, v_scale=vs, why=declines,
+                ),
+                q, k, v, mask, k_scale, v_scale, declines,
             )
             if out is not None:
+                _record(PATH_SLAB_KERNEL, q, k, declines,
+                        gathered=gathered, tp=tp)
                 return out
+        else:
+            declines.append("decode kernel: causal=True call (its "
+                            "windows ride an explicit mask)")
         if k_scale is not None:
             k, v = _dequantize(k, k_scale, q.dtype), _dequantize(
                 v, v_scale, q.dtype)
             k_scale = v_scale = None
         from ray_dynamic_batching_tpu.ops import flash_attention
 
-        out = flash_attention.flash_attention(
-            q, k, v, causal=causal, mask=mask, scale=scale
+        out, tp = _dense_kernel(
+            lambda q, k, v, mask, ks, vs: flash_attention.flash_attention(
+                q, k, v, causal=causal, mask=mask, scale=scale,
+                why=declines,
+            ),
+            q, k, v, mask, None, None, declines,
         )
         if out is not None:
+            _record(PATH_FLASH, q, k, declines, gathered=gathered, tp=tp)
             return out
+        if _BACKEND == "pallas":
+            raise AttentionDeclined(
+                f"attention backend 'pallas' is strict and every kernel "
+                f"declined q{tuple(q.shape)} kv{tuple(k.shape)}: "
+                + "; ".join(declines)
+            )
+    else:
+        declines.append(
+            f"pallas off: backend {_BACKEND!r} on "
+            f"{jax.default_backend()}")
     if k_scale is not None:
         k, v = _dequantize(k, k_scale, q.dtype), _dequantize(
             v, v_scale, q.dtype)
+    _record(PATH_XLA, q, k, declines, gathered=gathered)
     return _xla_attention(q, k, v, causal=causal, mask=mask, scale=scale)
 
 
@@ -208,20 +408,20 @@ def _paged_attention(
             "explicit mask on this path means a caller mixed the slab "
             "and paged conventions"
         )
+    declines: List[str] = []
     if _use_pallas():
         from ray_dynamic_batching_tpu.ops import decode_attention
 
-        tp_ctx = _TP_CTX.get()
+        tp_mesh, tp_axis, tp = _tp_slice()
         mesh_kwargs = {}
-        if tp_ctx is not None:
-            tp_mesh, tp_axis = tp_ctx
-            if tp_mesh.shape.get(tp_axis, 1) > 1:
-                mesh_kwargs = {"mesh": tp_mesh, "mesh_axis": tp_axis}
+        if tp > 1:
+            mesh_kwargs = {"mesh": tp_mesh, "mesh_axis": tp_axis}
         out = decode_attention.paged_decode_attention(
             q, k, v, page_table, kv_lengths, scale=scale,
-            k_scale=k_scale, v_scale=v_scale, **mesh_kwargs,
+            k_scale=k_scale, v_scale=v_scale, why=declines, **mesh_kwargs,
         )
         if out is not None:
+            _record(PATH_PAGED_KERNEL, q, k, declines, tp=tp)
             return out
     # Gather fallback: rebuild each slot's logical KV run [B, S, K, H]
     # (S = NP * ps) and re-enter the slab path. Sentinel/garbage pages
@@ -246,8 +446,9 @@ def _paged_attention(
     if k_scale is not None:
         ks_g, vs_g = logical(k_scale), logical(v_scale)
     win = paged_window_mask(kv_lengths, NP * ps, q.shape[1])
-    return dot_product_attention(
-        q, k_g, v_g, mask=win, scale=scale, k_scale=ks_g, v_scale=vs_g,
+    return _dense_attention(
+        q, k_g, v_g, causal=False, mask=win, scale=scale,
+        k_scale=ks_g, v_scale=vs_g, declines=declines, gathered=True,
     )
 
 

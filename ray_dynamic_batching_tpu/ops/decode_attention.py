@@ -37,8 +37,8 @@ be (8, 128)-tile-aligned or span the array):
 - The [B, Tq, S] mask's trailing dim is the S tile, so Sb must be a
   multiple of 128 or span S (``_pick_sb``).
 Large prefill tiles stay on the flash kernel
-(``ops/flash_attention.py``); this covers the decode half VERDICT r4 #8
-called out (the reference has no decode engine to compare against — its
+(``ops/flash_attention.py``); this covers the decode half (the
+reference has no decode engine to compare against — its
 serving path is fixed-shape vision forwards,
 ``293-project/src/scheduler.py:435-452``).
 
@@ -52,7 +52,7 @@ gate.
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional
+from typing import Any, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -60,12 +60,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_dynamic_batching_tpu.ops import tile_math
-from ray_dynamic_batching_tpu.ops.tile_math import VMEM_BLOCK_BUDGET_BYTES
+from ray_dynamic_batching_tpu.ops.pallas_common import (
+    declined,
+    resolve_interpret,
+)
+from ray_dynamic_batching_tpu.ops.tile_math import (
+    VMEM_BLOCK_BUDGET_BYTES,
+    VMEM_LIMIT_BYTES,
+)
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept
-# either so the kernel lowers on both sides of the rename.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                           getattr(pltpu, "TPUCompilerParams", None))
+# Grid (slot, head block, KV tile): the KV axis carries the
+# online-softmax scratch, so it is sequential; the scoped-VMEM limit is
+# the one the tile budget was sized against (ops/tile_math.py).
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=VMEM_LIMIT_BYTES,
+)
 
 NEG_INF = -1e30
 
@@ -100,11 +110,19 @@ def _decode_kernel(
     # per-(t, g)-row window. Sb divides S (``_pick_sb``), so there is no
     # ragged tail to mask.
     if mask_ref is not None:
-        mvals = mask_ref[0, :, :] != 0  # [Tq, Sb]
-        # [Tq, Sb] -> one row per (t, g): g shares t's window.
-        valid = jnp.broadcast_to(
-            mvals[:, None, :], (window, G, Sb)
-        ).reshape(R, Sb)
+        # [Tq, Sb] -> one row per (t, g): g shares t's window. Widen the
+        # streamed int8 to 32 bits BEFORE any comparison and pick each
+        # row's window with iota selects: Mosaic refuses to carry an i1
+        # vector born from an 8-bit tile into the f32 select below
+        # ("changeBitwidth when src bitwidth and dst bitwidth differs
+        # too much", TPU v5e, Tq*G rows with G > 1), and a [1, Sb] row
+        # broadcast over sublanes is a layout it always has.
+        m32 = mask_ref[0, :, :].astype(jnp.int32)  # [Tq, Sb]
+        t_of_row = jax.lax.broadcasted_iota(jnp.int32, (R, Sb), 0) // G
+        rows = jnp.zeros((R, Sb), jnp.int32)
+        for t in range(window):  # static unroll: window <= 8
+            rows = jnp.where(t_of_row == t, m32[t:t + 1, :], rows)
+        valid = rows != 0
     else:
         valid = None
     _scan_tile(
@@ -200,8 +218,8 @@ def _pick_heads_block(K: int) -> int:
 # runtime picker cannot drift. H=64 geometries (gpt2_medium,
 # llama_tiny, whisper heads) double under 128-lane padding; budgeting
 # the raw H undercounted the K/V block ~2x and picked tiles whose true
-# double-buffered footprint blew the ~16 MB/core this file assumes —
-# the exact bug class the shared model (and its lint rule) pins down.
+# double-buffered footprint overran the block budget — the exact bug
+# class the shared model (and its lint rule) pins down.
 
 
 def _pick_sb(S: int, kb: int, H: int, kv_itemsize: int,
@@ -306,9 +324,7 @@ def _decode_attention(
             pltpu.VMEM((kb, R), jnp.float32),
             pltpu.VMEM((kb, R, H), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*args)
 
@@ -395,9 +411,7 @@ def _paged_decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, R, H), q.dtype),
-        compiler_params=_COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(page_table, lengths, *args)
 
@@ -415,10 +429,12 @@ def paged_decode_attention(
     interpret: Optional[bool] = None,
     mesh: Optional[Any] = None,
     mesh_axis: str = "tp",
+    why: Optional[List[str]] = None,
 ) -> Optional[jax.Array]:
     """Fused page-table decode attention; returns None when the shapes
     aren't the paged decode pattern (caller falls back to the explicit
-    gather — same decline contract as :func:`decode_attention`).
+    gather — same decline contract as :func:`decode_attention`), with
+    the reason appended to ``why``.
 
     q [B, Tq, N, H] with Tq <= MAX_WINDOW_FOR_KERNEL; k/v [P, ps, K, H]
     page pools with K dividing N; page_table [B, NP] int32 (sentinel P =
@@ -447,29 +463,40 @@ def paged_decode_attention(
     partitions from the pool's NamedSharding.
     """
     if q.ndim != 4 or k.ndim != 4:
-        return None
+        return declined(why, "paged kernel: q/k are not rank 4")
     B, Tq, N, H = q.shape
     if not (1 <= Tq <= MAX_WINDOW_FOR_KERNEL):
-        return None  # wide windows are prefill-shaped: gather/flash path
+        # Wide windows are prefill-shaped: gather, then the flash kernel.
+        return declined(
+            why, f"paged kernel: window Tq={Tq} > "
+            f"{MAX_WINDOW_FOR_KERNEL} is prefill-shaped")
     P, ps, K, Hk = k.shape
     if Hk != H or v.shape != k.shape or K == 0 or N % K != 0:
-        return None
+        return declined(
+            why, f"paged kernel: q heads {N}x{H} do not group over "
+            f"pool heads {K}x{Hk}")
     if page_table.ndim != 2 or page_table.shape[0] != B:
-        return None
+        return declined(why, "paged kernel: page table is not [B, NP]")
     if kv_lengths.shape != (B,):
-        return None
+        return declined(why, "paged kernel: kv_lengths is not [B]")
     if (k_scale is None) != (v_scale is None):
-        return None
+        return declined(why, "paged kernel: one of k_scale/v_scale only")
     if k_scale is not None and (
             k_scale.shape != (P, ps, K) or v_scale.shape != (P, ps, K)):
-        return None
+        return declined(why, "paged kernel: scale planes are not "
+                             "[P, ps, K]")
     if not tile_math.lane_aligned_page(ps):
-        return None
+        return declined(
+            why, f"paged kernel: page size {ps} is not a 128-lane "
+            "multiple")
     tp = 1
     if mesh is not None:
         tp = int(mesh.shape.get(mesh_axis, 1))
         if tp > 1 and (K % tp != 0 or N % tp != 0):
-            return None  # heads replicate under this mesh: gather path
+            # Heads replicate under this mesh: gather path.
+            return declined(
+                why, f"paged kernel: heads {N}/{K} do not divide over "
+                f"tp={tp}")
     # Per-shard footprint: each shard owns K/tp kv heads, so the guard
     # budgets the block the kernel will ACTUALLY stream on one core.
     k_local = tile_math.shard_heads(K, tp)
@@ -482,9 +509,11 @@ def paged_decode_attention(
             # heads, so each head block still carries Tq*G window rows.
             window=Tq, G=G,
     ) > VMEM_BLOCK_BUDGET_BYTES:
-        return None  # page too fat for VMEM double-buffering: gather path
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        # Page too fat for VMEM double-buffering: gather path.
+        return declined(
+            why, f"paged kernel: page tile (ps={ps}, kb={kb}, H={H}) "
+            "exceeds the VMEM block budget")
+    interpret = resolve_interpret(interpret)
     scale = scale if scale is not None else H ** -0.5
     # Rows ordered (t, g) per kv head: [B, Tq, K, G, H] ->
     # [B, K, Tq*G, H] (Tq == 1 collapses to the historical layout).
@@ -526,7 +555,6 @@ def _paged_decode_attention_tp(
     shard's call is the ordinary single-device kernel on its head
     slice — numerics are per-head, so the sharded result is exactly the
     unsharded one re-laid-out."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     args = [q_r, k, v, page_table, kv_lengths]
@@ -550,11 +578,13 @@ def _paged_decode_attention_tp(
             scale=scale, window=window, interpret=interpret,
         )
 
-    return shard_map(
+    # check_vma=False: pallas_call declares no varying-axes rule, and
+    # every operand's layout over ``axis`` is stated in the specs above.
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=P(None, axis, None, None),
-        check_rep=False,
+        check_vma=False,
     )(*args)
 
 
@@ -569,10 +599,12 @@ def decode_attention(
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     interpret: Optional[bool] = None,
+    why: Optional[List[str]] = None,
 ) -> Optional[jax.Array]:
     """Fused small-window attention; returns None when the shapes aren't
     the decode pattern (caller falls back to flash/XLA, same contract as
-    ``flash_attention.flash_attention``).
+    ``flash_attention.flash_attention``), with the reason appended to
+    ``why``.
 
     q [B, Tq, N, H] with Tq <= MAX_WINDOW_FOR_KERNEL; k/v [B, S, K, H]
     with K dividing N; mask None or broadcastable to [B, 1, Tq, S]
@@ -585,22 +617,27 @@ def decode_attention(
     bandwidth win.
     """
     if q.ndim != 4 or k.ndim != 4:
-        return None
+        return declined(why, "decode kernel: q/k are not rank 4")
     B, Tq, N, H = q.shape
     _, S, K, _ = k.shape
     if not (1 <= Tq <= MAX_WINDOW_FOR_KERNEL):
-        return None
+        return declined(
+            why, f"decode kernel: window Tq={Tq} > "
+            f"{MAX_WINDOW_FOR_KERNEL} is prefill-shaped")
     if K == 0 or N % K != 0 or v.shape != k.shape:
-        return None
+        return declined(
+            why, f"decode kernel: q heads {N} do not group over kv "
+            f"heads {K}")
     if (k_scale is None) != (v_scale is None):
-        return None
+        return declined(why, "decode kernel: one of k_scale/v_scale only")
     if k_scale is not None and (
             k_scale.shape != (B, S, K) or v_scale.shape != (B, S, K)):
-        return None
+        return declined(why, "decode kernel: scale planes are not "
+                             "[B, S, K]")
     G = N // K
     if mask is not None:
         if mask.shape[-1] != S:
-            return None
+            return declined(why, "decode kernel: mask does not span S")
         try:
             mask = jnp.broadcast_to(
                 mask, (B, 1, Tq, S)
@@ -609,9 +646,10 @@ def decode_attention(
             # e.g. a per-head [B, N, Tq, S] mask: not this kernel's
             # pattern — decline so the caller falls back to XLA, which
             # handles arbitrary masks.
-            return None
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+            return declined(
+                why, f"decode kernel: mask {mask.shape} is not "
+                "head-invariant")
+    interpret = resolve_interpret(interpret)
     # KV tile: must divide S (a ragged tile's block would clamp and
     # re-read shifted rows), be mask-tile-legal, and fit VMEM
     # double-buffered. 0 = no legal tile (pathological S) -> XLA.
@@ -619,7 +657,9 @@ def decode_attention(
                   mask is not None, target=block_k,
                   with_scales=k_scale is not None)
     if sb == 0:
-        return None
+        return declined(
+            why, f"decode kernel: no KV tile of S={S} is a 128-multiple "
+            "divisor that fits the VMEM block budget")
     scale = scale if scale is not None else H ** -0.5
     # Rows ordered (t, g) per kv head: [B, Tq, K, G, H] -> [B, K, Tq*G, H].
     q_r = q.reshape(B, Tq, K, G, H).transpose(0, 2, 1, 3, 4).reshape(

@@ -11,7 +11,8 @@ bandwidth-bound KV scans where a custom kernel buys nothing.
 Design (FlashAttention-2 style, one pass over KV):
 - grid (B, N, ceil(Tq/block_q)); each program owns one query tile of one head.
 - K/V for the head are resident in VMEM (seq buckets cap Tk, so at 8k seq,
-  bf16, H=128 the pair costs 4 MB — comfortably under the ~16 MB budget).
+  bf16, H=128 the pair costs 4 MB — comfortably under the block budget of
+  ``ops/tile_math.py``, which the wrapper enforces).
 - inner ``fori_loop`` over KV tiles carries (m, l, acc) in registers/VMEM:
   m/l rescaling per tile, scores and accumulator in f32 (bf16 inputs go
   through the MXU with f32 accumulation via ``preferred_element_type``).
@@ -24,7 +25,7 @@ Design (FlashAttention-2 style, one pass over KV):
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +33,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_dynamic_batching_tpu.ops import tile_math
+from ray_dynamic_batching_tpu.ops.pallas_common import (
+    declined,
+    resolve_interpret,
+)
 
 NEG_INF = -1e30
 
@@ -239,6 +244,9 @@ def _flash_attention(
         cost_estimate=pl.CostEstimate(
             flops=flops, bytes_accessed=bytes_accessed, transcendentals=B * N * Tq * Tk
         ),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=tile_math.VMEM_LIMIT_BYTES,
+        ),
         interpret=interpret,
     )(*args)
     return out.transpose(0, 2, 1, 3)  # back to [B, Tq, N, H]
@@ -255,10 +263,12 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = 128,
     interpret: Optional[bool] = None,
+    why: Optional[List[str]] = None,
 ) -> Optional[jax.Array]:
     """Fused attention; returns None when the shape isn't worth a kernel
     (tiny decode queries, GQA head counts that don't divide) so the
-    dispatcher (:mod:`ray_dynamic_batching_tpu.ops.attention`) falls back to XLA.
+    dispatcher (:mod:`ray_dynamic_batching_tpu.ops.attention`) falls back
+    to XLA, with the reason appended to ``why``.
 
     Shapes: q [B, Tq, N, H], k/v [B, Tk, K, H], mask broadcastable to
     [B, 1, Tq, Tk] (True = attend).
@@ -266,9 +276,13 @@ def flash_attention(
     B, Tq, N, H = q.shape
     _, Tk, K, _ = k.shape
     if Tq < MIN_QUERY_FOR_PALLAS:
-        return None
+        return declined(
+            why, f"flash kernel: Tq={Tq} < {MIN_QUERY_FOR_PALLAS} query "
+            "rows is not worth a query-tiled launch")
     if K == 0 or N % K != 0:
-        return None
+        return declined(
+            why, f"flash kernel: q heads {N} do not group over kv "
+            f"heads {K}")
     scale = scale if scale is not None else H ** -0.5
     block_q = _pick_block(Tq, block_q)
     block_k = _pick_block(Tk, block_k)
@@ -278,12 +292,16 @@ def flash_attention(
     # it); f32 lowers fine at any alignment, so only narrow shapes
     # decline to XLA (pinned in tests/test_tpu_lowering.py).
     if q.dtype.itemsize < 4 and block_q % 8 != 0:
-        return None
+        return declined(
+            why, f"flash kernel: {q.dtype} query tile of {block_q} rows "
+            "is not sublane-aligned (Mosaic mixed-type broadcast bug)")
     # Degenerate tiling (prime-ish sequence lengths -> width-<8 tiles at
     # <=1/128 MXU utilization, e.g. ViT-G/14's 257) is not worth a
     # kernel: XLA's fused attention handles these shapes well.
     if block_q < 8 or block_k < 8:
-        return None
+        return declined(
+            why, f"flash kernel: Tq={Tq}/Tk={Tk} only tile at "
+            f"{block_q}x{block_k}, under one sublane group")
     # Per-grid-step VMEM guard sharing the runtime/static footprint model
     # (ops/tile_math.py): the resident K/V pair, the q/out tiles, and the
     # streamed int8 mask tile, all padded and double-buffered, must fit
@@ -299,9 +317,11 @@ def flash_attention(
     if mask is not None:
         blocks += tile_math.padded_block_bytes((1, block_q, Tk), 1)
     if tile_math.DOUBLE_BUFFER * blocks > tile_math.VMEM_BLOCK_BUDGET_BYTES:
-        return None
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        return declined(
+            why, f"flash kernel: resident K/V [Tk={Tk}, H={H}]"
+            + (" plus the mask tile" if mask is not None else "")
+            + " exceeds the VMEM block budget")
+    interpret = resolve_interpret(interpret)
 
     mask_i8 = None
     if mask is not None:

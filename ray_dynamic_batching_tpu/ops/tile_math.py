@@ -39,15 +39,25 @@ LANE = 128
 # tile's compute: two buffers per streamed block are resident at once.
 DOUBLE_BUFFER = 2
 
-# Per-grid-step VMEM ceiling for a kernel call's streamed blocks
-# (~16 MB VMEM/core): footprints count the FULLY padded tiles (sublane
-# AND 128-lane dims) double-buffered, so the budget honestly bounds the
-# in-VMEM bytes and can sit close to the core limit — q/out blocks and
-# f32 accumulator scratch riding alongside are small. 15 MB keeps
-# whisper's only legal decode tile (whole S=448, ~14.7 MB true) while
-# rejecting the H=64 whole-S tiles the old raw-H budget wrongly
-# accepted (~16.8 MB true).
+# Per-grid-step VMEM ceiling for a kernel call's streamed blocks:
+# footprints count the FULLY padded tiles (sublane AND 128-lane dims)
+# double-buffered, so the budget honestly bounds their in-VMEM bytes.
+# 15 MB keeps whisper's only legal decode tile (whole S=448, ~14.7 MB
+# true) while rejecting the H=64 whole-S tiles the old raw-H budget
+# wrongly accepted (~16.8 MB true).
 VMEM_BLOCK_BUDGET_BYTES = 15 * 1024 * 1024
+
+# The scoped-VMEM limit every kernel asks Mosaic for
+# (``CompilerParams.vmem_limit_bytes``). The block budget above counts
+# streamed blocks only; the q/out blocks, the f32 online-softmax scratch
+# and the compiler's own temporaries ride alongside, so the limit is
+# STATED, with room for them, instead of inherited from whatever the
+# compiler defaults to on a given chip — the budget and the limit are one
+# decision. A v5e core holds 128 MiB of VMEM (``pltpu.get_tpu_info()``).
+# Measured on a v5e, libtpu 0.0.34: the tile at the budget's edge (sb=896
+# of S=1792, 14.1 MiB streamed) compiles under this limit, and also under
+# the default one.
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 
 
 def sublane_pack(itemsize: int) -> int:

@@ -96,7 +96,12 @@ def single_device_mesh(device: Optional[jax.Device] = None) -> Mesh:
 
 def _feasible_spec(spec: P, shape: Tuple[int, ...], mesh: Mesh) -> P:
     """Drop mesh axes that don't divide the corresponding dim (e.g. GQA with
-    kv_heads < tp replicates the kv projections instead of erroring)."""
+    kv_heads < tp replicates the kv projections instead of erroring), and
+    trailing Nones: ``P(None, 'tp', None) != P(None, 'tp')`` to jit's
+    executable cache although they place data identically, and programs
+    hand arrays back under the trimmed spelling — a cache allocated under
+    the long one makes the first program warmed compile a second time on
+    the first live dispatch."""
     out = []
     for i, ax in enumerate(spec):
         if ax is None:
@@ -110,7 +115,16 @@ def _feasible_spec(spec: P, shape: Tuple[int, ...], mesh: Mesh) -> P:
             out.append(ax)
         else:
             out.append(None)
+    while out and out[-1] is None:
+        out.pop()
     return P(*out)
+
+
+def feasible_sharding(mesh: Mesh, spec: P,
+                      shape: Tuple[int, ...]) -> NamedSharding:
+    """``spec`` on ``mesh`` for an array of ``shape``, with the axes that
+    do not divide it replicated (:func:`_feasible_spec`)."""
+    return NamedSharding(mesh, _feasible_spec(spec, shape, mesh))
 
 
 def param_shardings(mesh: Mesh, model: ServableModel, params: Any) -> Any:
@@ -118,7 +132,7 @@ def param_shardings(mesh: Mesh, model: ServableModel, params: Any) -> Any:
     (infeasible axes degrade to replication rather than erroring)."""
     specs = param_path_specs(model, params)
     return jax.tree_util.tree_map(
-        lambda leaf, s: NamedSharding(mesh, _feasible_spec(s, leaf.shape, mesh)),
+        lambda leaf, s: feasible_sharding(mesh, s, leaf.shape),
         params,
         specs,
     )
@@ -143,9 +157,7 @@ def _sharded_alloc(mesh: Mesh, make_fn, spec) -> Any:
     def _shard(field_spec, field_shape):
         if field_shape is None:  # absent optional plane (e.g. scales)
             return None
-        return NamedSharding(
-            mesh, _feasible_spec(field_spec, field_shape.shape, mesh)
-        )
+        return feasible_sharding(mesh, field_spec, field_shape.shape)
 
     # Field-generic so every cache plane — including a quantized cache's
     # scale planes — gets a sharding; a hand-listed constructor here

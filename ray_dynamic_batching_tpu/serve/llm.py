@@ -30,6 +30,7 @@ Result: ``DecodeResult`` (tokens, finish_reason, ttft_ms, total_ms).
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
@@ -38,6 +39,7 @@ from ray_dynamic_batching_tpu.engine.decode import DecodeEngine
 from ray_dynamic_batching_tpu.engine.queue import RequestQueue
 from ray_dynamic_batching_tpu.engine.request import Request, RequestDropped
 from ray_dynamic_batching_tpu.serve.replica import Replica
+from ray_dynamic_batching_tpu.utils.compile_ledger import get_ledger
 from ray_dynamic_batching_tpu.utils.logging import get_logger
 
 logger = get_logger("serve.llm")
@@ -87,15 +89,24 @@ class LLMReplica(Replica):
         self.queue.close()
         self.engines: Dict[int, DecodeEngine] = {}
         self._queues: Dict[int, RequestQueue] = {}
-        for bucket in sorted(engine_builders):
-            q = RequestQueue(
-                f"{deployment}:{bucket}", max_len=max_ongoing_requests
-            )
-            self._queues[bucket] = q
-            self.engines[bucket] = engine_builders[bucket](q)
-        if warmup:
-            for engine in self.engines.values():
-                engine.warmup()
+        # A warmed replica's whole start-up — weight placement, cache
+        # allocation, then warmup — is one warmup phase of the compile
+        # ledger (depth-counted; engine.warmup() nests inside). The
+        # ledger is process-wide: without the bracket, the second replica
+        # of a process allocates its cache after the first one's warmup
+        # armed the steady-state mark, and every fill it compiles is
+        # charged as a serving-time violation.
+        with (get_ledger().warming() if warmup
+              else contextlib.nullcontext()):
+            for bucket in sorted(engine_builders):
+                q = RequestQueue(
+                    f"{deployment}:{bucket}", max_len=max_ongoing_requests
+                )
+                self._queues[bucket] = q
+                self.engines[bucket] = engine_builders[bucket](q)
+            if warmup:
+                for engine in self.engines.values():
+                    engine.warmup()
 
     @property
     def engine(self) -> DecodeEngine:

@@ -36,6 +36,7 @@ plus a canonical serving segment must stay inside the ratcheted budget
 
 from __future__ import annotations
 
+import contextlib
 import json
 import threading
 import time
@@ -99,6 +100,14 @@ def _frames() -> List[_Frame]:
     if stack is None:
         stack = _tls.frames = []
     return stack
+
+
+def current_program() -> str:
+    """Name of the instrumented program executing (tracing, on a first
+    call) on this thread; "" outside one. Trace-time records
+    (``ops/attention.py``) use it to say WHICH program took a path."""
+    stack = _frames()
+    return stack[-1].name if stack else ""
 
 
 def _shape_sig(args: Tuple[Any, ...], limit: int = 12) -> str:
@@ -169,6 +178,16 @@ class CompileLedger:
             if self._warmup_depth == 0:
                 self._armed = True
                 self._phase = PHASE_STEADY
+
+    @contextlib.contextmanager
+    def warming(self):
+        """``begin_warmup`` ... ``end_warmup`` around a block, the end
+        guaranteed (nests, like the pair it wraps)."""
+        self.begin_warmup()
+        try:
+            yield
+        finally:
+            self.end_warmup()
 
     def steady_state(self) -> None:
         """Force-arm the steady-state mark (gates/tests; engine warmup

@@ -84,8 +84,6 @@ class RDBConfig:
     # Number of schedule intervals over which compile cost is amortized when
     # judging merge feasibility.
     compile_amortization_intervals: int = 60
-    # Persistent compilation cache directory ("" disables).
-    compilation_cache_dir: str = ""
 
     # --- queues (ref 293-project/src/scheduler.py:190) ---
     max_queue_len: int = 4096

@@ -5,8 +5,8 @@ three-tier decode horizon: while slots are free, an arrival during an
 in-flight decode scan waits at most ``ttft_horizon`` substeps before the
 engine can admit it, instead of the full ``decode_horizon`` scan. These
 tests quantify that bound on CPU — substeps between arrival and admission
-under the ttft tier vs a full-horizon policy — so the TTFT win survives
-relay outages as a regression-protected property, not a one-off on-chip
+under the ttft tier vs a full-horizon policy — so the bound is a
+regression-protected count on the CPU lane, not a one-off on-chip
 measurement. The decomposition tests pin the queue/scan/prefill split the
 bench LLM row publishes (bench.py ``ttft_breakdown``).
 """

@@ -179,7 +179,7 @@ def main(argv=None) -> int:
                          "%(default)s)")
     ap.add_argument("--allow-empty", action="store_true",
                     help="a capture with zero request traces passes "
-                         "instead of failing (watchdog partial windows)")
+                         "instead of failing (a capture cut short)")
     args = ap.parse_args(argv)
 
     try:

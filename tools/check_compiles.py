@@ -179,8 +179,8 @@ def main(argv=None) -> int:
         errors.extend(check_budget(warmup_counts, _load_budget()))
 
     if args.json:
-        # The report IS the stdout (consumers json.loads it — the
-        # watchdog's compile_report hook); verdicts go to stderr.
+        # The report IS the stdout (consumers json.loads it);
+        # verdicts go to stderr.
         print(ledger.to_json(), end="")
     if errors:
         print("COMPILE GATE FAILED:", file=sys.stderr)

@@ -25,8 +25,7 @@ Three modes:
            queue books balanced through migrated_out/migrated_in.
   --bench  one migration timed against recompute-from-scratch: the
            parcel pause (freeze -> ship -> splice -> resume) vs. paying
-           a fresh prefill TTFT for the same cache. Emits JSON for
-           tools/tpu_watchdog.py's bench_llm_migrate arm.
+           a fresh prefill TTFT for the same cache. Emits JSON.
 
 Exit: 0 conformant, 1 violation, 2 usage.
 
