@@ -58,12 +58,23 @@ generator batches, ``serve/batching.py:209-276``).
 from __future__ import annotations
 
 import collections
+import heapq
+import itertools
 import math
 import threading
 import time
 import zlib
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (
+    Any,
+    Callable,
+    Dict,
+    List,
+    NamedTuple,
+    Optional,
+    Sequence,
+    Tuple,
+)
 
 import jax
 import jax.numpy as jnp
@@ -193,6 +204,109 @@ class _ChunkTrain:
     last: Any = None       # slab mode: last chunk's take-row logits
     insert_prefix: bool = False  # slab: publish chunk 0 on completion
     started_ms: float = 0.0
+
+
+class Turn(NamedTuple):
+    """One device dispatch of the engine, as the engine thread saw it: a
+    record of ``DecodeEngine.turns``. ``kind`` is ``"turn"`` (a decode or
+    speculative scan), ``"chunk"`` (a chunk group, or one slab chunk) or
+    ``"prefill"`` (a monolithic group prefill); the legacy long/session
+    fills show only as the turns they interleave. Stamps are ``now_ms()``
+    on the engine thread, in order: ``t_dispatch``, ``t_issued`` (the
+    jitted call returned), ``t_fetched`` (the result reached the host;
+    0.0 where nothing was fetched — a non-final chunk — so the device may
+    still be running it), ``t_done`` (harvest / registration done). The
+    host gap before a dispatch is its ``t_dispatch`` less the previous
+    record's ``t_fetched``: host time with an empty device. The load the
+    engine stood under — ``trains`` at the dispatch; ``queue_len``,
+    ``pages_allocated`` and ``positions_cached`` as the record is written,
+    at ``t_done`` — is what :func:`summarize_turns` shows beside each of
+    the longest gaps."""
+
+    kind: str
+    t_dispatch: float
+    t_issued: float
+    t_fetched: float
+    t_done: float
+    substeps: int           # decode substeps (0 for a chunk / prefill)
+    tokens: int             # prefill tokens (0 for a turn)
+    active: int             # decoding slots at dispatch
+    trains: int             # chunk trains pending at dispatch
+    queue_len: int          # requests queued at t_done
+    pages_allocated: int    # paged pool at t_done (0 on a slab engine)
+    positions_cached: int   # sum of the slots' cached lengths at t_done
+    after_idle: bool        # an idle wait lay between this and the last
+
+
+# Sized for the benchmark's 51 s window at several times the cells'
+# highest dispatch rate (~30/s at a 33 ms one-substep scan): 160/s.
+_TURN_RING = 8192
+_ENGINE_ORDINAL = itertools.count()   # numbers the engines of a process
+
+
+def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
+                    span_ms: Optional[float] = None,
+                    longest: int = 8) -> Dict[str, Any]:
+    """Turn records summed, for an operator asking a slow replica where
+    its time goes (the one definition of every quantity read from the
+    ring): dispatches and scans held (and ``dropped`` by the bounded ring),
+    decode substeps per scan, mean slot occupancy over the scans' substeps,
+    and the host gaps — a dispatch's ``t_dispatch`` less the previous
+    record's ``t_fetched``, where that one was fetched and no idle wait lay
+    between: host time with an empty device. ``host_gap_share`` is their
+    sum over ``span_ms`` (default: first dispatch to last ``t_done``),
+    ``host_gap_ms`` their median, p99, maximum and sum with its split:
+    ``harvest`` (fetch -> the earlier record's work done) and ``feed`` (from
+    there to the dispatch: fabric, admit, prefill scheduling, the turn's
+    preparation). ``longest_gaps`` lists the largest with that split and
+    the load the engine stood under: chunk trains pending at the dispatch
+    that ended the gap; queued requests, pages allocated and positions
+    cached as the earlier record was written, inside the gap."""
+    scans = [t for t in turns if t.kind == "turn"]
+    out: Dict[str, Any] = {"dispatches": len(turns), "scans": len(scans),
+                           "dropped": dropped}
+    if len(turns) < 2:
+        return out
+    substeps = sum(t.substeps for t in scans)
+    if substeps:
+        out["substeps_per_dispatch"] = substeps / len(scans)
+        out["mean_occupancy"] = sum(
+            t.active * t.substeps for t in scans
+        ) / (num_slots * substeps)
+    gaps = [
+        (cur.t_dispatch - prev.t_fetched, prev, cur)
+        for prev, cur in zip(turns, turns[1:])
+        if prev.t_fetched and not cur.after_idle
+    ]
+    if span_ms is None:
+        span_ms = turns[-1].t_done - turns[0].t_dispatch
+    if span_ms > 0:
+        out["host_gap_share"] = sum(g for g, _, _ in gaps) / span_ms
+    if gaps:
+        g = [x for x, _, _ in gaps]
+        harvest = [prev.t_done - prev.t_fetched for _, prev, _ in gaps]
+        feed = [cur.t_dispatch - prev.t_done for _, prev, cur in gaps]
+        out["host_gap_ms"] = {
+            "n": len(g), "p50": float(np.percentile(g, 50)),
+            "p99": float(np.percentile(g, 99)), "max": max(g),
+            "sum": sum(g), "harvest_sum": sum(harvest),
+            "feed_sum": sum(feed),
+            "harvest_p50": float(np.percentile(harvest, 50)),
+            "feed_p50": float(np.percentile(feed, 50)),
+        }
+    out["longest_gaps"] = [
+        {"gap_ms": round(g, 3),
+         "harvest_ms": round(prev.t_done - prev.t_fetched, 3),
+         "feed_ms": round(cur.t_dispatch - prev.t_done, 3),
+         "after": prev.kind, "before": cur.kind,
+         "at_ms": round(cur.t_dispatch, 3),
+         "trains": cur.trains, "queue_len": prev.queue_len,
+         "pages_allocated": prev.pages_allocated,
+         "positions_cached": prev.positions_cached}
+        for g, prev, cur in heapq.nlargest(
+            longest, gaps, key=lambda g: g[0])
+    ]
+    return out
 
 
 # Speculation observability (ISSUE 13 satellite): the ``paged`` tag
@@ -776,10 +890,24 @@ class DecodeEngine:
         )
         self._trains: List[_ChunkTrain] = []   # FIFO (arrival order)
         self._train_slots: set = set()
-        # Interleave cadence log (bounded): ("chunk", tokens) /
-        # ("turn", horizon) events, the stall-bound pin's observable.
-        self.interleave_log: collections.deque = collections.deque(
-            maxlen=4096
+        # The always-on turn ring: one ``Turn`` per device dispatch,
+        # written on the engine thread. The stall-bound pin, the flight
+        # recorder's ``decode.turn`` span, the scan wait of
+        # ``_ttft_parts``, ``snapshot()["turns"]`` and the benchmark's
+        # engine metrics all read it; ``reset_ttft_window`` clears it.
+        self.turns: collections.deque = collections.deque(
+            maxlen=_TURN_RING
+        )
+        self.turns_dropped = 0
+        self._last_scan: Optional[Turn] = None  # newest "turn" record
+        self._idled = False
+        # Every phase span names its engine, by the process's count of
+        # engines and the chip it is pinned to: all Python threads of a
+        # process share one line name in the profiler's trace, and a
+        # reader nests only one thread's spans.
+        # (No "#", "," or "=": a TraceAnnotation's own separators.)
+        self._phase_tag = f"{self.model.name}:{next(_ENGINE_ORDINAL)}" + (
+            "" if device is None else f"@{device.id}"
         )
         # TTFT decomposition: (queue_wait, scan_wait, prefill) per admission
         # over a rolling window — queue_wait is arrival->dequeue (slot
@@ -788,8 +916,6 @@ class DecodeEngine:
         # prefill is dequeue->first token. Consumed by ttft_breakdown();
         # the bench LLM row publishes it so an on-chip run shows where the
         # TTFT milliseconds live (BASELINE.json north star: p50 < 150 ms).
-        self._scan_start_ms = 0.0
-        self._scan_end_ms = 0.0
         self._ttft_parts: collections.deque = collections.deque(maxlen=1024)
         # Prompt-prefix KV reuse for chunked admissions (0 = off). Paged
         # engines reuse by page REFERENCE (longest shared page-prefix,
@@ -944,6 +1070,30 @@ class DecodeEngine:
         # SUCCESSFUL loop iterations, so a perpetually-failing _step (device
         # OOM, corrupt params) reads as a stall even though the thread lives.
         self.last_heartbeat = time.monotonic()
+
+    def _phase(self, name: str, **attrs: Any):
+        """One engine-loop phase on the profiler's clock
+        (:meth:`~utils.tracing.Tracer.phase`), named for this engine."""
+        return _tracer().phase(name, replica=self._phase_tag, **attrs)
+
+    def _log_dispatch(self, kind: str, t_dispatch: float, t_issued: float,
+                      t_fetched: float, substeps: int, tokens: int,
+                      active: int, trains: int) -> Turn:
+        """Append this dispatch's record to the turn ring (its work on the
+        host is done: ``t_done`` is now)."""
+        rec = Turn(
+            kind, t_dispatch, t_issued, t_fetched, now_ms(),
+            substeps, tokens, active, trains, len(self.queue),
+            self._allocator.allocated_pages if self.paged else 0,
+            int(self._len_host.sum()), self._idled,
+        )
+        if len(self.turns) == self.turns.maxlen:
+            self.turns_dropped += 1
+        self.turns.append(rec)
+        if kind == "turn":
+            self._last_scan = rec
+        self._idled = False
+        return rec
 
     def _device_ctx(self):
         """The scope everything this engine allocates, traces and
@@ -1807,9 +1957,13 @@ class DecodeEngine:
         The mono count cap only applies while slots are actively decoding
         (it exists to protect THEIR latency); an idle engine ramps by
         filling every free slot at once — there is nothing to stall."""
-        free = self._free_slots()
-        if not free:
-            return 0
+        with self._phase("rdb.engine.admit") as ph:
+            free = self._free_slots()
+            admitted = self._admit_into(free) if free else 0
+            ph.set_metadata(admitted=admitted, queue_len=len(self.queue))
+            return admitted
+
+    def _admit_into(self, free: List[int]) -> int:
         if self._active_mask.any() and not self.chunked_prefill:
             # Legacy monolithic rationing: the admission COUNT bounds the
             # stall. Chunked engines admit into trains instead — the
@@ -2065,6 +2219,12 @@ class DecodeEngine:
         can never deadlock the pool among themselves."""
         if not self._trains:
             return
+        with self._phase("rdb.engine.prefill",
+                         trains=len(self._trains)) as ph:
+            ph.set_metadata(tokens=self._spend_prefill_budget())
+
+    def _spend_prefill_budget(self) -> int:
+        """One round of :meth:`_pump_prefill`; returns the tokens spent."""
         model_tag = {"model": self.model.name}
         budget = self.prefill_token_budget
         parked: set = set()
@@ -2133,6 +2293,7 @@ class DecodeEngine:
                 and not self._active_mask.any()):
             self._relieve_train_starvation()
         PREFILL_PENDING.set(float(len(self._trains)), tags=model_tag)
+        return self.prefill_token_budget - budget
 
     def _drain_prefill(self) -> None:
         """Pump pending chunk trains to completion (tests and manual
@@ -2236,112 +2397,138 @@ class DecodeEngine:
         the group-admission convention)."""
         W = trains[0].C
         n = len(trains)
-        group = next(s for s in self._admit_group_sizes() if s >= n)
-        tokens = np.zeros((group, W), np.int32)
-        mask = np.zeros((group, W), np.int32)
-        tables = np.full((group, self._n_table_entries), self.num_pages,
-                         np.int32)
-        meta_i = np.zeros((6, group), np.int32)
-        meta_f = np.zeros((2, group), np.float32)
-        bias_ids = np.zeros((group, self.max_bias_entries), np.int32)
-        bias_vals = np.zeros((group, self.max_bias_entries), np.float32)
-        finals: List[Tuple[int, _ChunkTrain]] = []
-        for i, t in enumerate(trains):
-            piece = t.prompt[t.pos : t.pos + W]
-            take = int(piece.size)
-            final = t.pos + take >= t.total
-            tokens[i, :take] = piece
-            mask[i, :take] = 1
-            tables[i] = table_array(
-                t.opts["_pages"], self._n_table_entries, self.num_pages
-            )
-            # Non-final rows steer the lengths scatter to the sentinel
-            # slot: only the FINAL chunk publishes the verified length.
-            meta_i[0, i] = t.slot_idx if final else self.num_slots
-            meta_i[1, i] = t.pos
-            meta_i[2, i] = take - 1
-            meta_i[3, i] = t.opts["top_k"]
-            meta_i[4, i] = t.opts["seed"]
-            meta_i[5, i] = t.total
-            meta_f[0, i] = t.opts["temperature"]
-            meta_f[1, i] = t.opts.get("top_p", 1.0)
-            bias_ids[i], bias_vals[i] = self._bias_arrays(t.opts)
-            if final:
-                finals.append((i, t))
-        for i in range(n, group):
-            tokens[i] = tokens[0]
-            mask[i] = mask[0]
-            tables[i] = tables[0]
-            meta_i[:, i] = meta_i[:, 0]
-            meta_f[:, i] = meta_f[:, 0]
-            bias_ids[i] = bias_ids[0]
-            bias_vals[i] = bias_vals[0]
-        first, self._cache = self._chunk_paged_fn(
-            self.params,
-            jnp.asarray(np.stack([tokens, mask])),
-            self._cache,
-            jnp.asarray(tables),
-            jnp.asarray(meta_i),
-            jnp.asarray(meta_f),
-            jnp.asarray(bias_ids),
-            jnp.asarray(bias_vals),
-        )
-        for t in trains:
-            t.pos = min(t.pos + W, t.total)
-        PREFILL_CHUNKS.inc(n, tags={"model": self.model.name})
-        self.interleave_log.append(("chunk", W * n))
-        if not finals:
-            return
-        first_host = np.asarray(first)  # rdb-lint: disable=host-sync-in-hot-path (THE one fetch per chunk dispatch: the fused first-token ids — TTFT ends here, never at a logits round-trip)
-        t_done = now_ms()
-        for i, t in finals:
-            self._retire_train(t)
-            if self.paged_prefix is not None:
-                # Publish BEFORE registration: a stop-on-first-token
-                # finish frees the slot's pages, and the insert must pin
-                # them first (the legacy after_commit contract).
-                self.paged_prefix.insert(t.prompt, t.opts["_pages"])
-            if self._dcache is not None:
-                # The draft has no pages-direct path (its cache is a
-                # slab): replay the whole prompt through the draft's
-                # chunk program so speculation starts synced.
-                self._draft_long_fill(
-                    t.prompt, t.slot_idx, self.prompt_buckets[-1]
+        active = int(self._active_mask.sum())
+        pending = len(self._trains)
+        with self._phase("rdb.engine.prefill.prepare"):
+            group = next(s for s in self._admit_group_sizes() if s >= n)
+            tokens = np.zeros((group, W), np.int32)
+            mask = np.zeros((group, W), np.int32)
+            tables = np.full((group, self._n_table_entries),
+                             self.num_pages, np.int32)
+            meta_i = np.zeros((6, group), np.int32)
+            meta_f = np.zeros((2, group), np.float32)
+            bias_ids = np.zeros((group, self.max_bias_entries), np.int32)
+            bias_vals = np.zeros((group, self.max_bias_entries),
+                                 np.float32)
+            finals: List[Tuple[int, _ChunkTrain]] = []
+            for i, t in enumerate(trains):
+                piece = t.prompt[t.pos : t.pos + W]
+                take = int(piece.size)
+                final = t.pos + take >= t.total
+                tokens[i, :take] = piece
+                mask[i, :take] = 1
+                tables[i] = table_array(
+                    t.opts["_pages"], self._n_table_entries, self.num_pages
                 )
-            self._register(t.slot_idx, t.req, int(first_host[i]), t.opts,
-                           t_done)
+                # Non-final rows steer the lengths scatter to the
+                # sentinel slot: only the FINAL chunk publishes the
+                # verified length.
+                meta_i[0, i] = t.slot_idx if final else self.num_slots
+                meta_i[1, i] = t.pos
+                meta_i[2, i] = take - 1
+                meta_i[3, i] = t.opts["top_k"]
+                meta_i[4, i] = t.opts["seed"]
+                meta_i[5, i] = t.total
+                meta_f[0, i] = t.opts["temperature"]
+                meta_f[1, i] = t.opts.get("top_p", 1.0)
+                bias_ids[i], bias_vals[i] = self._bias_arrays(t.opts)
+                if final:
+                    finals.append((i, t))
+            for i in range(n, group):
+                tokens[i] = tokens[0]
+                mask[i] = mask[0]
+                tables[i] = tables[0]
+                meta_i[:, i] = meta_i[:, 0]
+                meta_f[:, i] = meta_f[:, 0]
+                bias_ids[i] = bias_ids[0]
+                bias_vals[i] = bias_vals[0]
+            tokmask = np.stack([tokens, mask])
+        t_dispatch = now_ms()
+        with self._phase("rdb.engine.prefill.dispatch"):
+            first, self._cache = self._chunk_paged_fn(
+                self.params,
+                jnp.asarray(tokmask),
+                self._cache,
+                jnp.asarray(tables),
+                jnp.asarray(meta_i),
+                jnp.asarray(meta_f),
+                jnp.asarray(bias_ids),
+                jnp.asarray(bias_vals),
+            )
+        t_issued = now_ms()
+        t_fetched = 0.0
+        if finals:
+            with self._phase("rdb.engine.prefill.fetch"):
+                first_host = np.asarray(first)  # rdb-lint: disable=host-sync-in-hot-path (THE one fetch per chunk dispatch: the fused first-token ids — TTFT ends here, never at a logits round-trip)
+            t_fetched = now_ms()
+        with self._phase("rdb.engine.prefill.finish"):
+            for t in trains:
+                t.pos = min(t.pos + W, t.total)
+            PREFILL_CHUNKS.inc(n, tags={"model": self.model.name})
+            for i, t in finals:
+                self._retire_train(t)
+                if self.paged_prefix is not None:
+                    # Publish BEFORE registration: a stop-on-first-token
+                    # finish frees the slot's pages, and the insert must
+                    # pin them first (the legacy after_commit contract).
+                    self.paged_prefix.insert(t.prompt, t.opts["_pages"])
+                if self._dcache is not None:
+                    # The draft has no pages-direct path (its cache is a
+                    # slab): replay the whole prompt through the draft's
+                    # chunk program so speculation starts synced.
+                    self._draft_long_fill(
+                        t.prompt, t.slot_idx, self.prompt_buckets[-1]
+                    )
+                self._register(t.slot_idx, t.req, int(first_host[i]),
+                               t.opts, t_fetched)
+        self._log_dispatch("chunk", t_dispatch, t_issued, t_fetched, 0,
+                           W * n, active, pending)
 
     def _advance_train_slab(self, train: _ChunkTrain) -> None:
         """One row-cache chunk for a slab train (the legacy chunk
         program under the token budget); the final chunk flows into the
         fused commit+sample dispatch via ``_commit_and_register``."""
         C = train.C
-        chunk_fn, commit_fn, _seed, extract_fn = self._long_prefill_fns(C)
-        piece = train.prompt[train.pos : train.pos + C]
-        take = int(piece.size)
-        tokens = np.zeros((1, C), np.int32)
-        mask = np.zeros((1, C), np.int32)
-        tokens[0, :take] = piece
-        mask[0, :take] = 1
-        train.last, train.row = chunk_fn(
-            self.params, jnp.asarray(tokens), jnp.asarray(mask),
-            train.row, jnp.int32(train.pos), jnp.int32(take - 1),
-        )
-        if train.insert_prefix and train.pos == 0 and take == C:
-            # Chunk 0 was full: its k/v depend only on the first C token
-            # ids — exactly reusable (the legacy after_first hook).
-            self.prefix_cache.insert(
-                train.prompt, *extract_fn(train.row, C)
+        active = int(self._active_mask.sum())
+        pending = len(self._trains)
+        with self._phase("rdb.engine.prefill.prepare"):
+            chunk_fn, commit_fn, _seed, extract_fn = \
+                self._long_prefill_fns(C)
+            piece = train.prompt[train.pos : train.pos + C]
+            take = int(piece.size)
+            tokens = np.zeros((1, C), np.int32)
+            mask = np.zeros((1, C), np.int32)
+            tokens[0, :take] = piece
+            mask[0, :take] = 1
+        t_dispatch = now_ms()
+        with self._phase("rdb.engine.prefill.dispatch"):
+            train.last, train.row = chunk_fn(
+                self.params, jnp.asarray(tokens), jnp.asarray(mask),
+                train.row, jnp.int32(train.pos), jnp.int32(take - 1),
             )
-        train.pos += take
-        PREFILL_CHUNKS.inc(tags={"model": self.model.name})
-        self.interleave_log.append(("chunk", C))
-        if train.pos >= train.total:
-            self._retire_train(train)
-            self._commit_and_register(
-                train.req, train.prompt, train.opts, train.slot_idx,
-                commit_fn, train.row, train.last, C,
-            )
+        t_issued = now_ms()
+        t_fetched = 0.0
+        with self._phase("rdb.engine.prefill.finish"):
+            if train.insert_prefix and train.pos == 0 and take == C:
+                # Chunk 0 was full: its k/v depend only on the first C
+                # token ids — exactly reusable (the legacy after_first
+                # hook).
+                self.prefix_cache.insert(
+                    train.prompt, *extract_fn(train.row, C)
+                )
+            train.pos += take
+            PREFILL_CHUNKS.inc(tags={"model": self.model.name})
+            if train.pos >= train.total:
+                # The fused commit fetches the first token: the slab
+                # train's one fetch, inside its finish.
+                self._retire_train(train)
+                self._commit_and_register(
+                    train.req, train.prompt, train.opts, train.slot_idx,
+                    commit_fn, train.row, train.last, C,
+                )
+                t_fetched = now_ms()
+        self._log_dispatch("chunk", t_dispatch, t_issued, t_fetched, 0, C,
+                           active, pending)
 
     def _retire_train(self, train: _ChunkTrain) -> None:
         if train in self._trains:
@@ -2627,28 +2814,36 @@ class DecodeEngine:
             for i in range(n, group):
                 pids[i] = pids[0]
             extra = (jnp.asarray(pids),)
-        first, self._cache = self._prefill_fn(bucket, group)(
-            self.params,
-            tokmask_d,
-            self._cache,
-            meta_i_d,
-            meta_f_d,
-            jnp.asarray(bias_ids),
-            jnp.asarray(bias_vals),
-            *extra,
-        )
-        if self._dcache is not None:
-            # The draft must see the same prompt: fill its cache rows too.
-            self._dcache = self._draft_prefill_fn(bucket, group)(
-                self.draft_params,
+        active = int(self._active_mask.sum())
+        t_dispatch = now_ms()
+        with self._phase("rdb.engine.prefill.dispatch"):
+            first, self._cache = self._prefill_fn(bucket, group)(
+                self.params,
                 tokmask_d,
-                self._dcache,
+                self._cache,
                 meta_i_d,
+                meta_f_d,
+                jnp.asarray(bias_ids),
+                jnp.asarray(bias_vals),
+                *extra,
             )
-        first_host = np.asarray(first)  # ONE fetch for the whole group
+            if self._dcache is not None:
+                # The draft must see the same prompt: fill its cache rows
+                # too.
+                self._dcache = self._draft_prefill_fn(bucket, group)(
+                    self.draft_params,
+                    tokmask_d,
+                    self._dcache,
+                    meta_i_d,
+                )
+        t_issued = now_ms()
+        with self._phase("rdb.engine.prefill.fetch"):
+            first_host = np.asarray(first)  # ONE fetch for the whole group
         t = now_ms()
         for i, (req, _prompt, opts) in enumerate(items):
             self._register(slot_ids[i], req, int(first_host[i]), opts, t)
+        self._log_dispatch("prefill", t_dispatch, t_issued, t, 0,
+                           bucket * n, active, len(self._trains))
 
     # --- chunked prefill (long prompts) ------------------------------------
     def _prefill_chunk_impl(self, params, tokens, attn_mask, row_cache,
@@ -3161,9 +3356,11 @@ class DecodeEngine:
         queue_wait = max(0.0, admit_ms - req.arrival_ms)
         # The share of queue_wait spent inside the decode scan that was in
         # flight when the request arrived: overlap of [arrival, dequeue]
-        # with the most recently completed scan window.
-        scan_wait = max(0.0, min(admit_ms, self._scan_end_ms)
-                        - max(req.arrival_ms, self._scan_start_ms))
+        # with the most recently completed scan window (the turn ring's).
+        scan = self._last_scan
+        scan_wait = 0.0 if scan is None else max(
+            0.0, min(admit_ms, scan.t_fetched)
+            - max(req.arrival_ms, scan.t_dispatch))
         prefill_ms = max(0.0, t - admit_ms)
         self._ttft_parts.append(
             (queue_wait, min(scan_wait, queue_wait), prefill_ms)
@@ -3408,8 +3605,11 @@ class DecodeEngine:
         return out
 
     def reset_ttft_window(self) -> None:
-        """Drop the rolling TTFT window (benchmark phase boundaries)."""
+        """Drop the rolling TTFT window and the turn ring (benchmark phase
+        boundaries)."""
         self._ttft_parts.clear()
+        self.turns.clear()
+        self.turns_dropped = 0
 
     def _sampling_arrays(self):
         """Device copies of the per-slot sampling state, PACKED by dtype:
@@ -3427,26 +3627,33 @@ class DecodeEngine:
             )
         return self._sampling_dev
 
-    def _record_turn_span(self, horizon: int, active_mask,
-                          spec: bool = False) -> None:
-        """One retroactive span per decode scan (dispatch -> host fetch),
-        linked to every sequence that was active in it: continuous
-        batching's fan-in, the decode analogue of the batch-execution
-        span. Bounded by num_slots links per turn."""
-        links = [
+    def _turn_links(self, active_mask) -> Optional[List]:
+        """Trace contexts of the sequences active in the scan just
+        fetched, for its ``decode.turn`` span; taken BEFORE the harvest
+        can finish any of them. None with the flight recorder off."""
+        if not _tracer().enabled or not active_mask.any():
+            return None
+        return [
             _link_to(slot.request.trace_ctx)
             for i, slot in enumerate(self._slots)
             if active_mask[i] and slot.request is not None
         ]
+
+    def _record_turn_span(self, rec: Turn, links: List, horizon: int,
+                          spec: bool = False) -> None:
+        """One retroactive span per decode scan (dispatch -> host fetch,
+        the turn ring's stamps), linked to every sequence that was active
+        in it: continuous batching's fan-in, the decode analogue of the
+        batch-execution span. Bounded by num_slots links per turn."""
         _tracer().record_span(
             "decode.turn",
-            start_ms=self._scan_start_ms,
-            end_ms=self._scan_end_ms,
+            start_ms=rec.t_dispatch,
+            end_ms=rec.t_fetched,
             links=links,
             model=self.model.name,
             lane=self.model.name,
             horizon=int(horizon),
-            active=int(active_mask.sum()),
+            active=rec.active,
             spec=spec,
         )
 
@@ -3579,158 +3786,191 @@ class DecodeEngine:
 
     def _spec_step(self) -> None:
         k = self.spec_tokens
-        paged_tag = "true" if self.paged else "false"
-        if self.paged:
-            if self._spec_scratch:
-                # A previous round died between reserve and splice (a
-                # device error the loop swallowed): its scratch would
-                # otherwise leak refcounts forever and shadow-occupy the
-                # pool. Roll it back before arranging a fresh window.
-                self._rollback_spec_scratch()
-            if not self._reserve_spec_scratch():
+        with self._phase("rdb.engine.turn") as ph:
+            with self._phase("rdb.engine.turn.prepare"):
+                reserved = True
+                if self.paged:
+                    if self._spec_scratch:
+                        # A previous round died between reserve and
+                        # splice (a device error the loop swallowed): its
+                        # scratch would otherwise leak refcounts forever
+                        # and shadow-occupy the pool. Roll it back before
+                        # arranging a fresh window.
+                        self._rollback_spec_scratch()
+                    reserved = self._reserve_spec_scratch()
+            if not reserved:
                 # Pool too tight for a verify window this round: one
                 # plain paged step instead (its own headroom ladder may
                 # capacity-evict, but the spec path never does) — under
                 # sustained pressure throughput degrades to the non-spec
                 # paged arm, not off a cliff.
-                return self._step(horizon=1)
-        try:
-            # From here to the packed fetch, scratch is armed but
-            # unresolved: ANY failure — table upload, sampling-state
-            # upload, the dispatch itself — must roll it back NOW, not
-            # at the next spec round (there may never be one: a sampled
-            # row can pin _use_spec() False for the engine's remaining
-            # lifetime, shadow-occupying the pool), then let the loop's
-            # error handling see the error.
-            if self.paged:
-                self._refresh_table()
-            (_samp_f, _samp_i, bias_ids_d, bias_vals_d) = \
-                self._sampling_arrays()
-            self._scan_start_ms = now_ms()
-            packed, self._cache, self._dcache = self._spec_fn(
-                self.params,
-                self._cache,
-                self._dcache,
-                jnp.asarray(np.stack([
-                    self._tokens[:, 0],
-                    self._active_mask.astype(np.int32),
-                ])),
-                bias_ids_d,
-                bias_vals_d,
-            )
-            ph = np.asarray(packed)  # ONE fetch per round  # rdb-lint: disable=host-sync-in-hot-path (THE one fetch per spec round: ph carries tokens+counts+lengths packed)
-        except BaseException:
-            if self.paged:
-                self._rollback_spec_scratch()
-            raise
-        self._scan_end_ms = now_ms()
-        self.interleave_log.append(("turn", k))
-        if _tracer().enabled:
-            self._record_turn_span(k, self._active_mask, spec=True)
-        out = ph[: k + 1]        # [k+1, B]
-        n_out = ph[k + 1]        # [B]
-        lengths = ph[k + 2]      # [B]
-        if self.paged:
-            # Accepted prefixes commit by page-table splice, rejected
-            # tails free — resolved from the post-round lengths BEFORE
-            # the harvest can finish (and free) any slot.
-            self._splice_spec_pages(lengths)
-        self.steps += 1
-        DECODE_STEPS.inc(tags={"model": self.model.name})
-        tags = {"model": self.model.name, "paged": paged_tag}
-        SPEC_ROUNDS.inc(tags=tags)
-        live = np.asarray([
-            not slot.free and self._active_mask[i] and n_out[i] > 0
-            for i, slot in enumerate(self._slots)
-        ])
-        active_n = int(self._active_mask.sum())
-        drafted = k * active_n
-        accepted = int((n_out[live] - 1).sum()) if live.any() else 0
-        # Conservation by construction, pinned in tier-1:
-        # accepted + rejected == drafted, per round.
-        if drafted:
-            SPEC_DRAFTED.inc(drafted, tags=tags)
-            SPEC_REJECTED.inc(drafted - accepted, tags=tags)
-            self._spec_acc_window.append((accepted, drafted))
-            rate = self.spec_acceptance()
-            if rate is not None:
-                SPEC_ACCEPTANCE.set(rate, tags=tags)
-        if accepted:  # one summed increment, not one .inc() per slot
-            SPEC_ACCEPTED.inc(accepted, tags=tags)
-        # Same harvest as the plain scan, with advanced = (j < n_out):
-        # a short row is draft rejection, not cache capacity.
-        self._harvest(
-            out,
-            np.arange(k + 1)[:, None] < n_out[None, :],
-            lengths,
-            k + 1,
-            blocked_finishes_capacity=False,
-        )
+                return self._plain_turn(ph, 1)
+            active = int(self._active_mask.sum())
+            ph.set_metadata(horizon=k, active=active, spec=1)
+            try:
+                # From here to the packed fetch, scratch is armed but
+                # unresolved: ANY failure — table upload, sampling-state
+                # upload, the dispatch itself — must roll it back NOW,
+                # not at the next spec round (there may never be one: a
+                # sampled row can pin _use_spec() False for the engine's
+                # remaining lifetime, shadow-occupying the pool), then
+                # let the loop's error handling see the error.
+                with self._phase("rdb.engine.turn.prepare"):
+                    if self.paged:
+                        self._refresh_table()
+                    (_samp_f, _samp_i, bias_ids_d, bias_vals_d) = \
+                        self._sampling_arrays()
+                    state = np.stack([
+                        self._tokens[:, 0],
+                        self._active_mask.astype(np.int32),
+                    ])
+                t_dispatch = now_ms()
+                with self._phase("rdb.engine.turn.dispatch"):
+                    packed, self._cache, self._dcache = self._spec_fn(
+                        self.params,
+                        self._cache,
+                        self._dcache,
+                        jnp.asarray(state),
+                        bias_ids_d,
+                        bias_vals_d,
+                    )
+                t_issued = now_ms()
+                with self._phase("rdb.engine.turn.fetch"):
+                    ph_host = np.asarray(packed)  # ONE fetch per round  # rdb-lint: disable=host-sync-in-hot-path (THE one fetch per spec round: ph_host carries tokens+counts+lengths packed)
+            except BaseException:
+                if self.paged:
+                    self._rollback_spec_scratch()
+                raise
+            t_fetched = now_ms()
+            with self._phase("rdb.engine.turn.harvest"):
+                links = self._turn_links(self._active_mask)
+                out = ph_host[: k + 1]        # [k+1, B]
+                n_out = ph_host[k + 1]        # [B]
+                lengths = ph_host[k + 2]      # [B]
+                if self.paged:
+                    # Accepted prefixes commit by page-table splice,
+                    # rejected tails free — resolved from the post-round
+                    # lengths BEFORE the harvest can finish (and free) any
+                    # slot.
+                    self._splice_spec_pages(lengths)
+                self.steps += 1
+                DECODE_STEPS.inc(tags={"model": self.model.name})
+                tags = {"model": self.model.name,
+                        "paged": "true" if self.paged else "false"}
+                SPEC_ROUNDS.inc(tags=tags)
+                live = np.asarray([
+                    not slot.free and self._active_mask[i] and n_out[i] > 0
+                    for i, slot in enumerate(self._slots)
+                ])
+                drafted = k * active
+                accepted = int((n_out[live] - 1).sum()) if live.any() else 0
+                # Conservation by construction, pinned in tier-1:
+                # accepted + rejected == drafted, per round.
+                if drafted:
+                    SPEC_DRAFTED.inc(drafted, tags=tags)
+                    SPEC_REJECTED.inc(drafted - accepted, tags=tags)
+                    self._spec_acc_window.append((accepted, drafted))
+                    rate = self.spec_acceptance()
+                    if rate is not None:
+                        SPEC_ACCEPTANCE.set(rate, tags=tags)
+                if accepted:  # one summed increment, not one .inc() per slot
+                    SPEC_ACCEPTED.inc(accepted, tags=tags)
+                # Same harvest as the plain scan, with advanced =
+                # (j < n_out): a short row is draft rejection, not cache
+                # capacity.
+                self._harvest(
+                    out,
+                    np.arange(k + 1)[:, None] < n_out[None, :],
+                    lengths,
+                    k + 1,
+                    blocked_finishes_capacity=False,
+                )
+            rec = self._log_dispatch("turn", t_dispatch, t_issued, t_fetched,
+                                     1, 0, active, len(self._trains))
+            if links is not None:
+                self._record_turn_span(rec, links, k, spec=True)
 
     def _step(self, horizon: Optional[int] = None) -> None:
         if horizon is None and self._use_spec():
             return self._spec_step()
-        h = horizon if horizon is not None else self._pick_horizon()
-        if self.paged:
-            # Pages for every position this scan can write, allocated
-            # host-side before the dispatch (static shapes can't grow
-            # mid-scan), then one tiny [B, NP] table upload when dirty.
-            self._ensure_page_headroom(h)
-            self._refresh_table()
-        # Per-slot index of the NEXT token to sample (prefill was index 0).
-        tok_idx = np.asarray(
-            [len(s.generated) if not s.free else 0 for s in self._slots],
-            dtype=np.int32,
-        )
-        prev_tokens = self._tokens.copy()  # draft catch-up window head
-        active_at_dispatch = self._active_mask.copy()
-        samp_f, samp_i, bias_ids_d, bias_vals_d = self._sampling_arrays()
-        self._scan_start_ms = now_ms()
-        packed, self._cache, self._counts = self._decode_fn(
-            self.params,
-            self._cache,
+        with self._phase("rdb.engine.turn") as ph:
+            self._plain_turn(ph, horizon)
+
+    def _plain_turn(self, ph: Any, horizon: Optional[int]) -> None:
+        """One plain decode scan inside the open ``rdb.engine.turn`` phase
+        ``ph``: prepare, dispatch, fetch, harvest, and the ring's record."""
+        with self._phase("rdb.engine.turn.prepare"):
+            h = horizon if horizon is not None else self._pick_horizon()
+            if self.paged:
+                # Pages for every position this scan can write, allocated
+                # host-side before the dispatch (static shapes can't grow
+                # mid-scan), then one tiny [B, NP] table upload when dirty.
+                self._ensure_page_headroom(h)
+                self._refresh_table()
+            # Per-slot index of the NEXT token to sample (prefill was
+            # index 0).
+            tok_idx = np.asarray(
+                [len(s.generated) if not s.free else 0 for s in self._slots],
+                dtype=np.int32,
+            )
+            prev_tokens = self._tokens.copy()  # draft catch-up window head
+            active_at_dispatch = self._active_mask.copy()
+            active = int(active_at_dispatch.sum())
+            samp_f, samp_i, bias_ids_d, bias_vals_d = self._sampling_arrays()
             # ONE per-dispatch upload: tokens / active / sample index.
-            jnp.asarray(np.stack([
+            state = np.stack([
                 self._tokens[:, 0],
                 active_at_dispatch.astype(np.int32),
                 tok_idx,
-            ])),
-            h,
-            samp_f,
-            samp_i,
-            bias_ids_d,
-            bias_vals_d,
-            self._counts,
-        )
-        packed_host = np.asarray(packed)          # ONE fetch per dispatch  # rdb-lint: disable=host-sync-in-hot-path (THE one fetch per dispatch: packed carries tokens+advanced+lengths)
-        self._scan_end_ms = now_ms()
-        if active_at_dispatch.any():
-            self.interleave_log.append(("turn", h))
-        if _tracer().enabled and active_at_dispatch.any():
-            self._record_turn_span(h, active_at_dispatch)
-        toks_host = packed_host[:h]               # [h, B]
-        advanced_host = packed_host[h : 2 * h].astype(bool)   # [h, B]
-        lengths_host = packed_host[2 * h]         # [B] (post-horizon)
-        self.steps += h
-        DECODE_STEPS.inc(h, tags={"model": self.model.name})
-        if self._dcache is not None:
-            # Keep the DRAFT cache tracking the sequence through plain
-            # decode intervals (sampled-row fallback, inter-chunk steps):
-            # without this, speculation resumes from a stale draft context
-            # and acceptance collapses. The tokens whose k/v landed at
-            # positions [len, len+h) are [pending, emitted[:-1]].
-            window = np.concatenate(
-                [prev_tokens, toks_host[: h - 1].T], axis=1
-            )  # [B, h]
-            counts = advanced_host.sum(axis=0).astype(np.int32)
-            self._dcache = self._draft_catchup_fn(
-                self.draft_params,
-                self._dcache,
-                jnp.asarray(window),
-                jnp.asarray(active_at_dispatch),
-                jnp.asarray(counts),
+            ])
+        ph.set_metadata(horizon=h, active=active, spec=0)
+        t_dispatch = now_ms()
+        with self._phase("rdb.engine.turn.dispatch"):
+            packed, self._cache, self._counts = self._decode_fn(
+                self.params,
+                self._cache,
+                jnp.asarray(state),
+                h,
+                samp_f,
+                samp_i,
+                bias_ids_d,
+                bias_vals_d,
+                self._counts,
             )
-        self._harvest(toks_host, advanced_host, lengths_host, h)
+        t_issued = now_ms()
+        with self._phase("rdb.engine.turn.fetch"):
+            packed_host = np.asarray(packed)          # ONE fetch per dispatch  # rdb-lint: disable=host-sync-in-hot-path (THE one fetch per dispatch: packed carries tokens+advanced+lengths)
+        t_fetched = now_ms()
+        with self._phase("rdb.engine.turn.harvest"):
+            links = self._turn_links(active_at_dispatch)
+            toks_host = packed_host[:h]               # [h, B]
+            advanced_host = packed_host[h : 2 * h].astype(bool)   # [h, B]
+            lengths_host = packed_host[2 * h]         # [B] (post-horizon)
+            self.steps += h
+            DECODE_STEPS.inc(h, tags={"model": self.model.name})
+            if self._dcache is not None:
+                # Keep the DRAFT cache tracking the sequence through plain
+                # decode intervals (sampled-row fallback, inter-chunk
+                # steps): without this, speculation resumes from a stale
+                # draft context and acceptance collapses. The tokens whose
+                # k/v landed at positions [len, len+h) are
+                # [pending, emitted[:-1]].
+                window = np.concatenate(
+                    [prev_tokens, toks_host[: h - 1].T], axis=1
+                )  # [B, h]
+                counts = advanced_host.sum(axis=0).astype(np.int32)
+                self._dcache = self._draft_catchup_fn(
+                    self.draft_params,
+                    self._dcache,
+                    jnp.asarray(window),
+                    jnp.asarray(active_at_dispatch),
+                    jnp.asarray(counts),
+                )
+            self._harvest(toks_host, advanced_host, lengths_host, h)
+        rec = self._log_dispatch("turn", t_dispatch, t_issued, t_fetched, h,
+                                 0, active, len(self._trains))
+        if links is not None:
+            self._record_turn_span(rec, links, h)
 
     def _harvest(self, toks_host, advanced_host, lengths_host, h: int,
                  blocked_finishes_capacity: bool = True) -> None:
@@ -3934,19 +4174,20 @@ class DecodeEngine:
         which must never nest under rank 100."""
         if not self.paged:
             return
-        with self._fabric_lock:
-            if not (self._parcel_in_q or self._migrate_out_q
-                    or self._push_out_q):
-                return
-            inbound, self._parcel_in_q = self._parcel_in_q, []
-            moves, self._migrate_out_q = self._migrate_out_q, []
-            pushes, self._push_out_q = self._push_out_q, []
-        for parcel in inbound:
-            self._import_parcel(parcel)
-        for rid, deliver in moves:
-            self._migrate_stream_out(rid, deliver)
-        for key, deliver in pushes:
-            self._push_prefix_out(key, deliver)
+        with self._phase("rdb.engine.fabric"):
+            with self._fabric_lock:
+                if not (self._parcel_in_q or self._migrate_out_q
+                        or self._push_out_q):
+                    return
+                inbound, self._parcel_in_q = self._parcel_in_q, []
+                moves, self._migrate_out_q = self._migrate_out_q, []
+                pushes, self._push_out_q = self._push_out_q, []
+            for parcel in inbound:
+                self._import_parcel(parcel)
+            for rid, deliver in moves:
+                self._migrate_stream_out(rid, deliver)
+            for key, deliver in pushes:
+                self._push_prefix_out(key, deliver)
 
     def _migrate_stream_out(
         self, request_id: str,
@@ -4179,6 +4420,20 @@ class DecodeEngine:
                     return
         raise TimeoutError(f"{self.model.name}: decode did not drain")
 
+    def _publish_gauges(self) -> None:
+        """The gauge writes after a turn."""
+        with self._phase("rdb.engine.publish"):
+            tags = {"model": self.model.name}
+            ACTIVE_SLOTS.set(float(self._active_mask.sum()), tags=tags)
+            if self.paged:
+                KV_PAGES_FREE.set(
+                    float(self._allocator.free_pages), tags=tags
+                )
+                KV_PAGE_OCCUPANCY.set(
+                    self._allocator.allocated_pages / self.num_pages,
+                    tags=tags,
+                )
+
     def _loop(self) -> None:
         with self._device_ctx():
             while self._run.is_set():
@@ -4188,22 +4443,11 @@ class DecodeEngine:
                     self._pump_prefill()
                     if self._active_mask.any():
                         self._step()
-                        ACTIVE_SLOTS.set(
-                            float(self._active_mask.sum()),
-                            tags={"model": self.model.name},
-                        )
-                        if self.paged:
-                            KV_PAGES_FREE.set(
-                                float(self._allocator.free_pages),
-                                tags={"model": self.model.name},
-                            )
-                            KV_PAGE_OCCUPANCY.set(
-                                self._allocator.allocated_pages
-                                / self.num_pages,
-                                tags={"model": self.model.name},
-                            )
+                        self._publish_gauges()
                     elif not self._trains:
-                        self.queue.wait_for_requests(self.idle_wait_s)
+                        with self._phase("rdb.engine.idle_wait"):
+                            self.queue.wait_for_requests(self.idle_wait_s)
+                        self._idled = True
                     self.last_heartbeat = time.monotonic()
                 except Exception:  # noqa: BLE001 — engine must not die silently
                     logger.exception(
@@ -4322,6 +4566,19 @@ class DecodeEngine:
             reserved = float(self.num_slots * self.max_len)
         return used / reserved if reserved > 0 else 1.0
 
+    def turn_summary(self, records: Optional[Sequence[Turn]] = None,
+                     span_ms: Optional[float] = None,
+                     longest: int = 8) -> Dict[str, Any]:
+        """:func:`summarize_turns` of the ring, or of ``records`` (a slice
+        of it) over ``span_ms``: what ``snapshot()["turns"]`` shows an
+        operator and what the benchmark's engine metrics are read from."""
+        # deque.copy() is one call under the GIL: the engine thread may
+        # append while an operator's thread reads.
+        return summarize_turns(
+            list(self.turns.copy()) if records is None else records,
+            self.num_slots, self.turns_dropped, span_ms, longest,
+        )
+
     def snapshot(self) -> Dict[str, Any]:
         """Operator-facing state dump (the engine analogue of
         ``LiveScheduler.snapshot()``): slot/KV occupancy plus — in paged
@@ -4338,6 +4595,7 @@ class DecodeEngine:
             "active_slots": self.active_slots,
             "kv_occupancy": self.kv_occupancy(),
             "ttft": self.ttft_breakdown(),
+            "turns": self.turn_summary(),
             "prefill": {
                 "mode": "chunked" if self.chunked_prefill else "mono",
                 "token_budget": self.prefill_token_budget,

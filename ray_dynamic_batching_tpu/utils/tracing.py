@@ -255,6 +255,24 @@ class Tracer:
         self._finish(s)
         return s
 
+    def phase(self, name: str, **attrs: Any) -> Any:
+        """A loop phase on the PROFILER's clock: a
+        ``jax.profiler.TraceAnnotation`` and nothing else — no ``Span``, no
+        lock, no exporter. For phases that run thousands of times a minute
+        and belong to no request (the engine's admit / prefill / turn /
+        idle wait). Outside a ``jax.profiler`` session it is a no-op (under
+        a microsecond); inside one the span lands, with ``attrs``, in the
+        same ``.xplane.pb`` as the device's operations, so a device idle
+        gap can be laid over what the host was doing. The profiler session
+        is the switch. Names are ``rdb.<layer>.<phase>``; attributes small
+        ints/strings. Attributes known only at the end go through the
+        returned object's ``set_metadata(**attrs)``."""
+        global _TraceAnnotation
+        if _TraceAnnotation is None:
+            # Lazy: the sim and the linters load this module without JAX.
+            from jax.profiler import TraceAnnotation as _TraceAnnotation
+        return _TraceAnnotation(name, **attrs)
+
     def finished_spans(self) -> List[Span]:
         with self._lock:
             return list(self._finished)
@@ -263,6 +281,8 @@ class Tracer:
         with self._lock:
             self._finished.clear()
 
+
+_TraceAnnotation: Any = None  # jax.profiler.TraceAnnotation, on first phase()
 
 _tracer = Tracer()
 

@@ -282,7 +282,7 @@ class TestTokenExactness:
 class TestStallBound:
     def test_budget_bounds_chunks_between_turns(self, lm):
         """Under a saturating long-prompt burst with one long-lived
-        active stream, the interleave cadence log shows at most
+        active stream, the engine's turn ring shows at most
         ``prefill_token_budget`` chunk tokens between consecutive decode
         turns — no serial prefill train, ever."""
         model, params = lm
@@ -305,7 +305,7 @@ class TestStallBound:
         engine._admit()
         engine._drain_prefill()
         assert engine.active_slots == 1
-        engine.interleave_log.clear()
+        engine.reset_ttft_window()
         burst = []
         for _ in range(4):
             r = Request(model=model.name, payload={
@@ -317,17 +317,17 @@ class TestStallBound:
         engine.run_until_idle(timeout_s=300)
         for r in burst + [live]:
             r.future.result(timeout=5)
-        log = list(engine.interleave_log)
-        assert any(kind == "chunk" for kind, _ in log)
+        log = list(engine.turns)
+        assert any(t.kind == "chunk" for t in log)
         # Between consecutive turns, chunk tokens never exceed the
-        # budget while a stream was active (the whole log here: the
+        # budget while a stream was active (the whole ring here: the
         # live stream outlasts the burst).
         since_turn = 0
-        for kind, amount in log:
-            if kind == "turn":
+        for t in log:
+            if t.kind == "turn":
                 since_turn = 0
             else:
-                since_turn += amount
+                since_turn += t.tokens
                 assert since_turn <= budget, log
 
     def test_budget_clamps_to_chunk_width(self, lm):

@@ -1,0 +1,345 @@
+"""Engine-loop phase spans on the profiler's clock, and the turn ring
+(ISSUE 24; tier-1). Counts, order and containment only — never a time.
+
+- ``tracer().phase`` is a ``jax.profiler.TraceAnnotation`` and nothing
+  else: no ``Span``, no exporter call, no switch but a profiler session.
+- Under ``jax.profiler.start_trace`` (CPU) a tiny paged engine's
+  ``rdb.engine.*`` spans arrive in ``ProfileData`` with their attributes,
+  children nest inside parents, and the phases tile the engine thread.
+- ``DecodeEngine.turns`` holds one ``Turn`` per device dispatch; the
+  flight recorder's ``decode.turn`` span and ``snapshot()["turns"]`` are
+  computed from it.
+"""
+
+import collections
+import glob
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_dynamic_batching_tpu.engine.decode import DecodeEngine, Turn
+from ray_dynamic_batching_tpu.engine.queue import RequestQueue
+from ray_dynamic_batching_tpu.engine.request import Request
+from ray_dynamic_batching_tpu.models import registry  # noqa: F401
+from ray_dynamic_batching_tpu.models.base import get_model
+from ray_dynamic_batching_tpu.utils.tracing import tracer
+
+TOP = {"rdb.engine.fabric", "rdb.engine.admit", "rdb.engine.prefill",
+       "rdb.engine.turn", "rdb.engine.publish", "rdb.engine.idle_wait"}
+CHILDREN = {
+    "rdb.engine.turn": {"rdb.engine.turn.prepare", "rdb.engine.turn.dispatch",
+                        "rdb.engine.turn.fetch", "rdb.engine.turn.harvest"},
+    "rdb.engine.prefill": {"rdb.engine.prefill.prepare",
+                           "rdb.engine.prefill.dispatch",
+                           "rdb.engine.prefill.fetch",
+                           "rdb.engine.prefill.finish"},
+}
+# The share of the engine thread's time, between its first and its last
+# phase, that no top-level phase may leave uncovered: what lies between two
+# phases is the loop's own `while`, one `any()` and the heartbeat stamp.
+UNCOVERED_SHARE = 0.10
+
+
+@pytest.fixture(scope="module")
+def lm():
+    model = get_model("llama_tiny", dtype=jnp.float32)
+    return model, model.init(jax.random.PRNGKey(0))
+
+
+def _engine(lm, **kw):
+    model, params = lm
+    queue = RequestQueue(model.name, max_len=256)
+    opts = dict(num_slots=4, max_len=96, prompt_buckets=[8, 16],
+                eos_token_id=None, default_max_new_tokens=8,
+                decode_horizon=4, paged=True, page_size=128)
+    opts.update(kw)
+    return DecodeEngine(model, params, queue, **opts), queue
+
+
+def _submit(queue, model_name, lens=(5, 12, 40, 9, 30, 14), new=6, seed=1):
+    rng = np.random.default_rng(seed)
+    reqs = []
+    for n in lens:
+        r = Request(model=model_name, payload={
+            "tokens": rng.integers(1, 500, n).tolist(),
+            "max_new_tokens": new}, slo_ms=60_000.0)
+        queue.add_request(r)
+        reqs.append(r)
+    return reqs
+
+
+# --- phase(): a profiler annotation and nothing else ------------------------
+@pytest.mark.parametrize("recorder_on", [False, True])
+def test_phase_touches_nothing_of_the_flight_recorder(recorder_on):
+    t = tracer()
+    t.reset()
+    exported = []
+    if recorder_on:
+        t.set_exporter(exported.append)
+    try:
+        with t.phase("rdb.test.outer", n=1) as ph:
+            with t.phase("rdb.test.inner"):
+                pass
+            ph.set_metadata(late=2)
+        assert t.enabled is recorder_on
+        assert t.finished_spans() == [] and exported == []
+        assert t.current_span() is None
+    finally:
+        t.reset()
+
+
+# --- the spans, as a profiler session records them ----------------------------
+@pytest.fixture(scope="module")
+def traced(lm, tmp_path_factory):
+    """One profiler session (Python tracer off) over a started engine that
+    serves a burst and then idles: the spans of its thread, by line."""
+    import jax.profiler as jp
+
+    engine, queue = _engine(lm)
+    engine.warmup()
+    trace_dir = str(tmp_path_factory.mktemp("engine_trace"))
+    opts = jp.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    engine.start()
+    try:
+        jp.start_trace(trace_dir, profiler_options=opts)
+        try:
+            for r in _submit(queue, engine.model.name):
+                r.future.result(timeout=120)
+            time.sleep(0.05)      # a few idle waits
+        finally:
+            jp.stop_trace()
+    finally:
+        engine.stop()
+    (path,) = glob.glob(trace_dir + "/**/*.xplane.pb", recursive=True)
+    lines = []
+    for plane in jp.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                    dict(e.stats))
+                   for e in line.events if e.name.startswith("rdb.engine.")]
+            if evs:
+                lines.append(sorted(evs, key=lambda e: (e[1], -e[2])))
+    return engine, lines
+
+
+def test_every_phase_is_recorded_on_the_engine_thread_with_its_attributes(
+        traced):
+    engine, lines = traced
+    (spans,) = lines          # one engine, one thread, one line
+    names = {s[0] for s in spans}
+    assert TOP <= names
+    assert CHILDREN["rdb.engine.turn"] | CHILDREN["rdb.engine.prefill"] <= names
+    # the engine's own tag: its model, numbered among the process's engines
+    assert engine._phase_tag.startswith(engine.model.name + ":")
+    assert all(s[3].get("replica") == engine._phase_tag for s in spans)
+    by = collections.defaultdict(list)
+    for s in spans:
+        by[s[0]].append(s[3])
+    assert all({"admitted", "queue_len"} <= set(a) for a in by["rdb.engine.admit"])
+    assert sum(a["admitted"] for a in by["rdb.engine.admit"]) == 6
+    assert all({"trains", "tokens"} <= set(a) for a in by["rdb.engine.prefill"])
+    assert all(a["tokens"] <= engine.prefill_token_budget and a["trains"] >= 1
+               for a in by["rdb.engine.prefill"])
+    turns = by["rdb.engine.turn"]
+    assert all({"horizon", "active", "spec"} <= set(a) for a in turns)
+    assert all(a["spec"] == 0 and 1 <= a["active"] <= engine.num_slots
+               and a["horizon"] in (1, engine.ttft_horizon, engine.decode_horizon)
+               for a in turns)
+    # the ring and the trace count the same scans (the trace may have
+    # started inside one)
+    scans = sum(1 for t in engine.turns if t.kind == "turn")
+    assert scans - 1 <= len(turns) <= scans
+
+
+def test_children_nest_inside_their_parents(traced):
+    _engine_, (spans,) = traced
+    parents = [s for s in spans if s[0] in CHILDREN]
+    for name, start, end, _ in spans:
+        if name in TOP:
+            continue
+        parent = name.rsplit(".", 1)[0]
+        assert name in CHILDREN[parent]
+        assert any(p[0] == parent and p[1] <= start and end <= p[2]
+                   for p in parents), name
+    for p in parents:      # and no two children of one parent overlap
+        kids = [s for s in spans if s[0] in CHILDREN[p[0]]
+                and p[1] <= s[1] and s[2] <= p[2]]
+        assert kids
+        for a, b in zip(kids, kids[1:]):
+            assert a[2] <= b[1]
+    complete = [p for p in parents if p[0] == "rdb.engine.turn"]
+    for p in complete:     # every scan: prepare, dispatch, fetch, harvest
+        kids = [s[0].rsplit(".", 1)[1] for s in spans
+                if s[0] in CHILDREN[p[0]] and p[1] <= s[1] and s[2] <= p[2]]
+        assert kids == ["prepare", "dispatch", "fetch", "harvest"]
+
+
+def test_the_phases_tile_the_engine_threads_time(traced):
+    _engine_, (spans,) = traced
+    tops = [s for s in spans if s[0] in TOP]
+    for a, b in zip(tops, tops[1:]):
+        assert a[2] <= b[1], (a, b)       # top-level phases never overlap
+    covered = sum(e - s for _, s, e, _ in tops)
+    extent = tops[-1][2] - tops[0][1]
+    assert (extent - covered) / extent < UNCOVERED_SHARE
+    # one loop iteration with work: fabric, admit, [prefill], turn, publish
+    i = next(i for i, s in enumerate(tops) if s[0] == "rdb.engine.turn")
+    assert tops[i + 1][0] == "rdb.engine.publish"
+    before = [s[0] for s in tops[max(i - 3, 0):i]]
+    assert before[-1] in ("rdb.engine.prefill", "rdb.engine.admit")
+    assert "rdb.engine.fabric" in before and "rdb.engine.admit" in before
+    # the idle wait follows an iteration that found nothing to do
+    j = max(i for i, s in enumerate(tops) if s[0] == "rdb.engine.idle_wait")
+    assert tops[j - 1][0] == "rdb.engine.admit"
+
+
+# --- the turn ring ---------------------------------------------------------------
+def test_ring_holds_one_record_per_dispatch_with_monotone_stamps(lm):
+    engine, queue = _engine(lm)
+    steps0 = engine.steps
+    reqs = _submit(queue, engine.model.name)
+    engine.run_until_idle(timeout_s=300)
+    for r in reqs:
+        r.future.result(timeout=5)
+    ring = list(engine.turns)
+    assert ring and all(isinstance(t, Turn) for t in ring)
+    assert engine.turns_dropped == 0
+    assert {t.kind for t in ring} == {"turn", "chunk"}
+    assert sum(t.substeps for t in ring) == engine.steps - steps0
+    # 40 and 30 tokens at a 16-token chunk: 3 + 2 chunks; the four short
+    # prompts one chunk each, grouped at most two to a program
+    chunks = [t for t in ring if t.kind == "chunk"]
+    assert sum(t.tokens for t in chunks) >= 3 * 16 + 2 * 16 + 8 + 16 + 16 + 16
+    for t in ring:
+        assert t.t_dispatch <= t.t_issued <= t.t_done
+        assert t.t_fetched == 0.0 or t.t_issued <= t.t_fetched <= t.t_done
+        assert 0 <= t.active <= engine.num_slots and t.queue_len >= 0
+        assert 0 <= t.pages_allocated <= engine.num_pages
+        assert not t.after_idle        # run_until_idle never waits
+        if t.kind == "turn":
+            assert t.t_fetched and t.substeps >= 1 and t.tokens == 0
+            assert t.active >= 1
+        else:
+            assert t.substeps == 0 and t.tokens > 0 and t.trains >= 1
+    for a, b in zip(ring, ring[1:]):
+        assert a.t_dispatch <= b.t_dispatch and a.t_done <= b.t_dispatch
+    # a chunk that finishes no prompt fetches nothing; one that does, does
+    assert any(t.t_fetched == 0.0 for t in chunks)
+    assert any(t.t_fetched for t in chunks)
+
+
+def test_reset_clears_the_ring_and_a_full_ring_counts_what_it_drops(lm):
+    engine, queue = _engine(lm)
+    reqs = _submit(queue, engine.model.name)
+    engine.run_until_idle(timeout_s=300)
+    n = len(engine.turns)
+    assert n > 4
+    engine.reset_ttft_window()
+    assert len(engine.turns) == 0 and engine.turns_dropped == 0
+    assert engine.turn_summary() == {"dispatches": 0, "scans": 0,
+                                     "dropped": 0}
+    # the same work again into a ring of four: same dispatches, n - 4 dropped
+    engine.turns = collections.deque(maxlen=4)
+    reqs = _submit(queue, engine.model.name)
+    engine.run_until_idle(timeout_s=300)
+    for r in reqs:
+        r.future.result(timeout=5)
+    assert len(engine.turns) == 4 and engine.turns_dropped == n - 4
+    assert engine.snapshot()["turns"]["dropped"] == n - 4
+
+
+def test_idle_wait_marks_the_next_record(lm):
+    engine, queue = _engine(lm)
+    engine.warmup()
+    engine.reset_ttft_window()
+    engine.start()
+    try:
+        time.sleep(0.03)                  # the loop idles
+        for r in _submit(queue, engine.model.name, lens=(5,)):
+            r.future.result(timeout=120)
+    finally:
+        engine.stop()
+    first, *rest = list(engine.turns)
+    assert first.after_idle and first.kind == "chunk"
+    assert not any(t.after_idle for t in rest)
+    # no gap is charged to the host across the wait
+    assert first.t_dispatch not in {
+        g["at_ms"] for g in engine.turn_summary(longest=10 ** 6)["longest_gaps"]}
+
+
+def test_flight_recorder_turn_spans_and_scan_wait_come_from_the_ring(lm):
+    t = tracer()
+    t.reset()
+    spans = []
+    t.set_exporter(spans.append)
+    try:
+        engine, queue = _engine(lm)
+        reqs = _submit(queue, engine.model.name)
+        engine.run_until_idle(timeout_s=300)
+        for r in reqs:
+            r.future.result(timeout=5)
+    finally:
+        t.reset()
+    ring = [x for x in engine.turns if x.kind == "turn"]
+    turn_spans = [s for s in spans if s.name == "decode.turn"]
+    assert [(s.start_ms, s.end_ms) for s in turn_spans] == [
+        (x.t_dispatch, x.t_fetched) for x in ring]
+    assert [(s.attributes["horizon"], s.attributes["active"])
+            for s in turn_spans] == [(x.substeps, x.active) for x in ring]
+    assert all(len(s.links) <= s.attributes["active"] for s in turn_spans)
+    # every admission's scan wait is an overlap with a scan of the ring
+    parts = list(engine._ttft_parts)
+    assert len(parts) == len(reqs)
+    longest = max(x.t_fetched - x.t_dispatch for x in ring)
+    assert all(0.0 <= scan <= min(wait, longest) for wait, scan, _ in parts)
+    prefill = [s for s in spans if s.name == "decode.prefill"]
+    assert sorted(s.attributes["scan_wait_ms"] for s in prefill) == sorted(
+        round(scan, 2) for _, scan, _ in parts)
+
+
+def test_snapshot_sums_the_ring(lm):
+    engine, queue = _engine(lm)
+    reqs = _submit(queue, engine.model.name)
+    engine.run_until_idle(timeout_s=300)
+    for r in reqs:
+        r.future.result(timeout=5)
+    ring = list(engine.turns)
+    s = engine.snapshot()["turns"]
+    scans = [t for t in ring if t.kind == "turn"]
+    assert s["dispatches"] == len(ring) and s["dropped"] == 0
+    assert s["substeps_per_dispatch"] == pytest.approx(
+        sum(t.substeps for t in scans) / len(scans))
+    assert 0.0 < s["mean_occupancy"] <= 1.0
+    assert 0.0 < s["host_gap_share"] < 1.0
+    gaps = s["longest_gaps"]
+    assert 1 <= len(gaps) <= 8
+    assert [g["gap_ms"] for g in gaps] == sorted(
+        (g["gap_ms"] for g in gaps), reverse=True)
+    for g in gaps:
+        assert g["gap_ms"] == pytest.approx(g["harvest_ms"] + g["feed_ms"],
+                                            abs=2e-3)
+        assert g["after"] in ("turn", "chunk") and g["before"] in ("turn", "chunk")
+        # the load the engine stood under, from the two records of the gap
+        prev, cur = next((a, b) for a, b in zip(ring, ring[1:])
+                         if round(b.t_dispatch, 3) == g["at_ms"])
+        assert g["trains"] == cur.trains and g["queue_len"] == prev.queue_len
+        assert g["pages_allocated"] == prev.pages_allocated > 0
+        assert g["positions_cached"] == prev.positions_cached > 0
+    hg = s["host_gap_ms"]
+    assert hg["n"] == sum(1 for a in ring[:-1] if a.t_fetched)
+    assert hg["sum"] == pytest.approx(hg["harvest_sum"] + hg["feed_sum"])
+    assert hg["p50"] <= hg["p99"] <= hg["max"] == pytest.approx(
+        gaps[0]["gap_ms"], abs=1e-3)
+    # a slice of the ring over a stated span: what the benchmark reads
+    part = engine.turn_summary(records=ring[:len(ring) // 2], span_ms=1e6)
+    assert part["dispatches"] == len(ring) // 2
+    assert part["host_gap_share"] == pytest.approx(
+        part["host_gap_ms"]["sum"] / 1e6)
+    # a gap is counted only after a dispatch whose result was fetched
+    fetched = sum(1 for a in ring[:-1] if a.t_fetched)
+    assert len(engine.turn_summary(longest=10 ** 6)["longest_gaps"]) == fetched

@@ -35,12 +35,14 @@ from tools.lint.core import (
 # on a def line rather than editing this table for one-offs.
 HOT_FUNCTIONS: Dict[str, Set[str]] = {
     "engine/decode.py": {
-        "_step", "_spec_step", "_harvest", "_interleave_step",
+        "_step", "_spec_step", "_plain_turn", "_harvest",
+        "_interleave_step",
         # ISSUE 15: the token-budget prefill scheduler runs between
         # every decode turn — its chunk dispatches are steady-state
         # serving latency exactly like the scan, with ONE designed
         # fetch (the fused first-token ids) per chunk program.
-        "_pump_prefill", "_dispatch_chunk_group", "_advance_train_slab",
+        "_pump_prefill", "_spend_prefill_budget", "_dispatch_chunk_group",
+        "_advance_train_slab",
         "_grant_train_pages",
     },
     "engine/worker.py": {"_run_placement"},

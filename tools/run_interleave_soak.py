@@ -25,7 +25,7 @@ Two modes:
   --live   (CI full lane) a real chunked vs monolithic paged
            DecodeEngine pair on CPU (llama_tiny): byte-identical tokens
            over a mixed short+long workload, the stall bound read from
-           the chunked engine's own interleave cadence log (never more
+           the chunked engine's own turn ring, `engine.turns` (never more
            than one budget's worth of chunk tokens between decode
            turns), zero client-visible errors, and page conservation
            after drain.
@@ -222,18 +222,18 @@ def run_live(n_long: int = 4) -> int:
         violations.append(
             f"page leak after drain: mono={leak_m} chunked={leak_c}"
         )
-    # Stall bound from the engine's own cadence log: never more than
+    # Stall bound from the engine's own turn ring: never more than
     # one budget of chunk tokens between decode turns.
     budget = engine.prefill_token_budget
     since_turn = 0
     worst = 0
     chunk_events = 0
-    for kind, amount in engine.interleave_log:
-        if kind == "turn":
+    for turn in engine.turns:
+        if turn.kind == "turn":
             since_turn = 0
         else:
             chunk_events += 1
-            since_turn += amount
+            since_turn += turn.tokens
             worst = max(worst, since_turn)
     if chunk_events == 0:
         violations.append("chunked arm dispatched no chunk programs — "
