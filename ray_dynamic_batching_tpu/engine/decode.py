@@ -104,6 +104,7 @@ from ray_dynamic_batching_tpu.engine.pagefabric import (
     export_stream_parcel,
 )
 from ray_dynamic_batching_tpu.engine.queue import RequestQueue
+from ray_dynamic_batching_tpu.models.decoder import fit_head_dim
 from ray_dynamic_batching_tpu.ops import jit_model
 from ray_dynamic_batching_tpu.ops.tile_math import (
     lane_aligned_page,
@@ -453,8 +454,12 @@ def copy_rows_into_paged(cache, rows, slots, write_pids):
     S = cache.page_table.shape[1] * cache.page_size
     ps = cache.page_size
     flat = write_pids.reshape(-1)
-    k = cache.k.at[:, flat].set(_row_as_pages(rows.k, S, ps), mode="drop")
-    v = cache.v.at[:, flat].set(_row_as_pages(rows.v, S, ps), mode="drop")
+    # Pool rows are lane-padded (models/decoder.py::pool_head_dim).
+    Hp = cache.k.shape[-1]
+    k = cache.k.at[:, flat].set(
+        fit_head_dim(_row_as_pages(rows.k, S, ps), Hp), mode="drop")
+    v = cache.v.at[:, flat].set(
+        fit_head_dim(_row_as_pages(rows.v, S, ps), Hp), mode="drop")
     ks, vs = cache.k_scale, cache.v_scale
     if ks is not None:
         ks = ks.at[:, flat].set(
@@ -800,6 +805,24 @@ class DecodeEngine:
                         num_slots, self.num_pages, self.page_size,
                         self._paged_capacity,
                     ))
+            # The head's true width, as the model's row caches have it
+            # (the pool's rows are lane-padded: pool_head_dim).
+            self._kv_head_dim = jax.eval_shape(
+                lambda: model.make_cache(1, self.page_size)).k.shape[-1]
+            # How the pool lies on the device, once, for snapshot():
+            # the order of its axes (row-major is what the paged kernel
+            # and the page write read; the pool's lane-padded rows make
+            # it the device's default) and its bytes there.
+            planes = [x for x in (self._cache.k, self._cache.v,
+                                  self._cache.k_scale, self._cache.v_scale)
+                      if x is not None]
+            layout = self._cache.k.format.layout
+            self._pool_stats = {
+                "layout": (None if layout is None
+                           else list(layout.major_to_minor)),
+                "resident_bytes": sum(
+                    x.on_device_size_in_bytes() for x in planes),
+            }
         elif mesh is not None and hasattr(model, "cache_pspec"):
             from ray_dynamic_batching_tpu.parallel.mesh import (
                 make_sharded_cache,
@@ -2628,8 +2651,12 @@ class DecodeEngine:
         are pinned (prefix-cache refs) and never rewritten after
         publication (CoW invariant), so this read races nothing."""
         idx = np.asarray(page_ids, np.int32)
-        out = {"k": np.asarray(self._cache.k[:, idx]),
-               "v": np.asarray(self._cache.v[:, idx])}
+        # A parcel carries the head, not the pool's lane padding (cut
+        # AFTER the gather: a gather of part of a row makes XLA re-lay
+        # the whole pool out for it).
+        H = self._kv_head_dim
+        out = {"k": np.asarray(self._cache.k[:, idx])[..., :H],
+               "v": np.asarray(self._cache.v[:, idx])[..., :H]}
         if self._cache.quantized:
             out["k_scale"] = np.asarray(self._cache.k_scale[:, idx])
             out["v_scale"] = np.asarray(self._cache.v_scale[:, idx])
@@ -2642,11 +2669,12 @@ class DecodeEngine:
         writer (this engine thread), like the page-table upload."""
         with self._device_ctx():
             idx = jnp.asarray(np.asarray(page_ids, np.int32))
+            Hp = self._cache.k.shape[-1]
             repl = {
-                "k": self._cache.k.at[:, idx].set(
-                    jnp.asarray(payload["k"], self._cache.k.dtype)),
-                "v": self._cache.v.at[:, idx].set(
-                    jnp.asarray(payload["v"], self._cache.v.dtype)),
+                "k": self._cache.k.at[:, idx].set(fit_head_dim(
+                    jnp.asarray(payload["k"], self._cache.k.dtype), Hp)),
+                "v": self._cache.v.at[:, idx].set(fit_head_dim(
+                    jnp.asarray(payload["v"], self._cache.v.dtype), Hp)),
             }
             if self._cache.quantized:
                 repl["k_scale"] = self._cache.k_scale.at[:, idx].set(
@@ -2919,11 +2947,13 @@ class DecodeEngine:
             g = arr[:, safe]  # [L, NP, ps, ...]
             return g.reshape((arr.shape[0], 1, S) + arr.shape[3:])
 
+        # Lane-padded pool rows are cut back to the row cache's head.
+        H = row_cache.k.shape[-1]
         k = jax.lax.dynamic_update_slice(
-            row_cache.k, logical(cache.k), (0, 0, 0, 0, 0)
+            row_cache.k, fit_head_dim(logical(cache.k), H), (0, 0, 0, 0, 0)
         )
         v = jax.lax.dynamic_update_slice(
-            row_cache.v, logical(cache.v), (0, 0, 0, 0, 0)
+            row_cache.v, fit_head_dim(logical(cache.v), H), (0, 0, 0, 0, 0)
         )
         scales = {}
         if cache.k_scale is not None:
@@ -4607,6 +4637,7 @@ class DecodeEngine:
             out["num_pages"] = self.num_pages
             out["free_pages"] = self._allocator.free_pages
             out["allocated_pages"] = self._allocator.allocated_pages
+            out["kv_pool"] = dict(self._pool_stats)
             out["page_journal"] = {
                 "events": self._page_journal.snapshot(),
                 "journal_total": self._page_journal.total,

@@ -105,7 +105,8 @@ class KVCache:
 
 @pytree_dataclass
 class PagedKVCache:
-    """Paged KV pool: k/v ``[L, P, page_size, K, H]`` fixed HBM pages,
+    """Paged KV pool: k/v ``[L, P, page_size, K, Hp]`` fixed HBM pages
+    (``Hp`` = :func:`pool_head_dim`: the head, lane-padded),
     gathered per slot through ``page_table`` ``[B, NP]`` int32 (entry j
     names the physical page backing logical positions
     ``[j*page_size, (j+1)*page_size)`` of that slot; unallocated entries
@@ -144,7 +145,7 @@ class PagedKVCache:
             )
         n_entries = max_len // page_size
         shape = (cfg.num_layers, num_pages, page_size,
-                 cfg.num_kv_heads, cfg.head_dim)
+                 cfg.num_kv_heads, pool_head_dim(cfg.head_dim))
         quantized = jnp.dtype(dtype) == jnp.dtype(jnp.int8)
         return PagedKVCache(
             k=jnp.zeros(shape, dtype=dtype),
@@ -173,6 +174,36 @@ class PagedKVCache:
     @property
     def quantized(self) -> bool:
         return self.k_scale is not None
+
+
+def pool_head_dim(head_dim: int) -> int:
+    """Width of a (token, head) row in the PAGED pool: the head size
+    rounded up to the 128 lanes. The paged kernel and XLA's in-place page
+    write both read rows lane-major, so a row narrower than the lanes is
+    lane-padded on the device whatever the array says; saying it in the
+    SHAPE makes that row-major layout the device's default for the pool.
+    With the true width in the shape (64), the default layout puts the
+    page's position axis minor-most instead, and every program that
+    touches the pool converts k and v on the way in and back on the way
+    out: four pool-sized copies a dispatch. A layout kept by
+    ``jax.experimental.layout`` would say the same thing without the
+    padding showing, but an executable loaded from the persistent compile
+    cache forgets it (PERF.md, PR 25). A head that fills the lanes (128,
+    256) is not padded."""
+    return -(-head_dim // 128) * 128
+
+
+def fit_head_dim(x: jax.Array, width: int) -> jax.Array:
+    """x [..., H] -> [..., width]: zero-pad the head axis up to the
+    pool's row width, or cut a pool row back to the head. Zeros are
+    inert on both sides of attention (q . 0 adds nothing to a score, p .
+    0 nothing to an output lane that is then cut)."""
+    H = x.shape[-1]
+    if width == H:
+        return x
+    if width < H:
+        return x[..., :width]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - H)])
 
 
 def quantize_kv_rows(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -270,6 +301,9 @@ class DecoderLayer(nn.Module):
             # stacked array every decode step forces XLA to materialize a
             # fresh multi-GB copy per token (measured 15 ms/substep for
             # GPT-2-medium at 32 slots vs ~2 ms with in-place updates).
+            # The paged READ keeps the same contract (below): the pools
+            # are passed whole and the layer is an index — no read makes
+            # an array the size of a layer of the pool.
             # A 4-tuple carries the int8 cache's per-row scales; every
             # write path scatters codes and scales with the SAME indices.
             quantized = len(cache_kv) == 4
@@ -304,6 +338,9 @@ class DecoderLayer(nn.Module):
                     )
                 P = k_full.shape[1]
                 ps = k_full.shape[2]
+                # Pool rows are lane-padded (pool_head_dim).
+                k_w = fit_head_dim(k_w, k_full.shape[-1])
+                v_w = fit_head_dim(v_w, v_full.shape[-1])
                 n_entries = page_table.shape[1]
                 idx = positions  # [B, T]
                 rows = jnp.arange(B)[:, None]
@@ -393,16 +430,21 @@ class DecoderLayer(nn.Module):
             else:
                 new_cache = (k_full, v_full)
             if page_table is not None:
-                # Paged read: k/v are the page pools; the dispatcher
-                # gathers through the table (fused in the Pallas paged
-                # kernel; an explicit gather + the shared decode mask on
-                # the fallback — one mask rule, token-exact either way).
+                # Paged read: the STACKED pools go to the dispatcher
+                # whole and this layer is an index into them — in the
+                # Pallas paged kernel's block map, or in the fallback's
+                # one (layer, page) gather + the shared decode mask: one
+                # mask rule, token-exact either way. Slicing
+                # ``k_full[layer_idx]`` here would make XLA materialise
+                # a layer of the pool per layer per substep in front of
+                # the kernel (a Mosaic operand is a buffer).
+                kv = (k_full, v_full)
                 scale_kwargs.update(page_table=page_table,
-                                    kv_lengths=kv_lengths)
+                                    kv_lengths=kv_lengths, layer=layer_idx)
+            else:
+                kv = (k_full[layer_idx], v_full[layer_idx])
             attn_out = attn_ops.dot_product_attention(
-                q, k_full[layer_idx], v_full[layer_idx], mask=mask,
-                **scale_kwargs,
-            )
+                q, *kv, mask=mask, **scale_kwargs)
         elif token_mask is not None:
             # Full-sequence self-attention: routes through ring attention
             # over the sp mesh axis under a sequence_parallel context.

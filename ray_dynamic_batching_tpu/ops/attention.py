@@ -53,6 +53,7 @@ class AttentionPath:
     program: str              # compile-ledger program ("" outside one)
     path: str                 # PATH_*; gathered paged reads say so below
     gathered: bool            # paged pool gathered to a slab view first
+    stacked: bool             # paged kernel read the [L, ...] stack whole
     tp: int                   # shard_map width of the kernel call (1 = none)
     interpret: bool           # kernel ran interpreted (never on a TPU)
     q_shape: Tuple[int, ...]
@@ -67,8 +68,10 @@ class AttentionPath:
             PATH_FLASH: "flash kernel",
             PATH_XLA: "XLA einsum",
         }[self.path]
-        if self.tp > 1:
-            name += f" (shard_map tp={self.tp})"
+        how = (["stacked pool"] if self.stacked else []) + (
+            [f"shard_map tp={self.tp}"] if self.tp > 1 else [])
+        if how:
+            name += f" ({', '.join(how)})"
         return ("gather-then-" if self.gathered else "") + name
 
 
@@ -88,10 +91,11 @@ def clear_attention_paths() -> None:
 
 
 def _record(path: str, q, k, declines: List[str], *, gathered: bool = False,
-            tp: int = 1) -> None:
+            stacked: bool = False, tp: int = 1) -> None:
     kernel = path in (PATH_PAGED_KERNEL, PATH_SLAB_KERNEL, PATH_FLASH)
     _PATHS.append(AttentionPath(
-        program=current_program(), path=path, gathered=gathered, tp=tp,
+        program=current_program(), path=path, gathered=gathered,
+        stacked=stacked, tp=tp,
         interpret=kernel and resolve_interpret(None),
         q_shape=tuple(q.shape), kv_shape=tuple(k.shape),
         kv_dtype=str(k.dtype), declines=tuple(declines),
@@ -218,6 +222,7 @@ def dot_product_attention(
     v_scale: Optional[jax.Array] = None,
     page_table: Optional[jax.Array] = None,
     kv_lengths: Optional[jax.Array] = None,
+    layer: int = 0,
 ) -> jax.Array:
     """Multi-head attention.
 
@@ -231,18 +236,22 @@ def dot_product_attention(
     path dequantizes first and proceeds as usual.
 
     ``page_table`` [B, NP] + ``kv_lengths`` [B] switch to the PAGED
-    decode read: k/v (and scales) are page POOLS ([P, ps, K, H] /
-    [P, ps, K]) shared by all slots, and each slot's logical KV run is
-    the table-ordered gather of its pages. The Pallas paged kernel
-    fuses that gather into the KV scan (no logical-view materialization
-    in HBM); everywhere else an explicit gather rebuilds the slab view
-    and re-enters this function — one mask/dequant rule, so paged and
-    slab reads are token-exact against each other.
+    decode read: k/v are the STACKED page pools [L, P, ps, K, H], passed
+    whole, and ``layer`` names the layer to read — an index, never a
+    slice, so no program makes an array the size of a layer of the pool
+    (a single layer's [P, ps, K, H] pool is taken as a one-layer stack);
+    the scales are that layer's [P, ps, K] planes. Each slot's logical
+    KV run is the table-ordered gather of its pages. The Pallas paged
+    kernel fuses that gather into the KV scan (no logical-view
+    materialization in HBM); everywhere else ONE explicit gather over
+    (layer, page) rebuilds the slab view and re-enters this function —
+    one mask/dequant rule, so paged and slab reads are token-exact
+    against each other.
     """
     if page_table is not None:
         return _paged_attention(
-            q, k, v, page_table, kv_lengths, mask=mask, scale=scale,
-            k_scale=k_scale, v_scale=v_scale,
+            q, k, v, page_table, kv_lengths, layer, mask=mask,
+            scale=scale, k_scale=k_scale, v_scale=v_scale,
         )
     return _dense_attention(
         q, k, v, causal=causal, mask=mask, scale=scale,
@@ -388,10 +397,11 @@ def _dense_attention(
 
 def _paged_attention(
     q: jax.Array,
-    k: jax.Array,              # [P, ps, K, H] page pool (one layer)
+    k: jax.Array,              # [L, P, ps, K, H] stacked page pool
     v: jax.Array,
     page_table: jax.Array,     # [B, NP] int32, sentinel P = unallocated
     kv_lengths: jax.Array,     # [B] valid logical prefix (attend <= len)
+    layer: int,                # which layer of the stack to read
     *,
     mask: Optional[jax.Array],
     scale: Optional[float],
@@ -408,6 +418,10 @@ def _paged_attention(
             "explicit mask on this path means a caller mixed the slab "
             "and paged conventions"
         )
+    stacked = k.ndim == 5
+    if not stacked:
+        # One layer's pool: a one-layer stack (a free reshape).
+        k, v, layer = k[None], v[None], 0
     declines: List[str] = []
     if _use_pallas():
         from ray_dynamic_batching_tpu.ops import decode_attention
@@ -417,11 +431,12 @@ def _paged_attention(
         if tp > 1:
             mesh_kwargs = {"mesh": tp_mesh, "mesh_axis": tp_axis}
         out = decode_attention.paged_decode_attention(
-            q, k, v, page_table, kv_lengths, scale=scale,
+            q, k, v, page_table, kv_lengths, layer=layer, scale=scale,
             k_scale=k_scale, v_scale=v_scale, why=declines, **mesh_kwargs,
         )
         if out is not None:
-            _record(PATH_PAGED_KERNEL, q, k, declines, tp=tp)
+            _record(PATH_PAGED_KERNEL, q, k, declines, stacked=stacked,
+                    tp=tp)
             return out
     # Gather fallback: rebuild each slot's logical KV run [B, S, K, H]
     # (S = NP * ps) and re-enter the slab path. Sentinel/garbage pages
@@ -430,21 +445,30 @@ def _paged_attention(
     # Tq > 1 is the speculative-verify window: the STAIRCASE mask (row t
     # attends <= lengths + t, paged_window_mask — the same rule the
     # kernel computes in-VMEM from the prefetched lengths).
+    # The layer rides the SAME gather as the page ids (pool[layer, safe]
+    # is one gather over two collapsed axes), so no layer of the pool is
+    # materialised on the way.
     from ray_dynamic_batching_tpu.models.decoder import paged_window_mask
 
-    P = k.shape[0]
+    P, ps = k.shape[1], k.shape[2]
     safe = jnp.minimum(page_table, P - 1)
     B, NP = page_table.shape
-    ps = k.shape[1]
 
-    def logical(pages):
-        g = pages[safe]  # [B, NP, ps, ...]
-        return g.reshape((B, NP * ps) + pages.shape[2:])
+    def logical(g):  # [B, NP, ps, ...] -> [B, NP * ps, ...]
+        return g.reshape((B, NP * ps) + g.shape[3:])
 
-    k_g, v_g = logical(k), logical(v)
+    # Pool rows are lane-padded (models/decoder.py::pool_head_dim): the
+    # slab view is cut back to the head AFTER the gather. Gathering the
+    # head's lanes only (pool[layer, safe, :, :, :H]) reads half the
+    # bytes on paper, but XLA then re-lays the whole pool out for that
+    # gather: four pool-sized copies in the chunk program
+    # (tools/pool_traffic.py).
+    H = q.shape[-1]
+    k_g = logical(k[layer, safe])[..., :H]
+    v_g = logical(v[layer, safe])[..., :H]
     ks_g = vs_g = None
     if k_scale is not None:
-        ks_g, vs_g = logical(k_scale), logical(v_scale)
+        ks_g, vs_g = logical(k_scale[safe]), logical(v_scale[safe])
     win = paged_window_mask(kv_lengths, NP * ps, q.shape[1])
     return _dense_attention(
         q, k_g, v_g, causal=False, mask=win, scale=scale,

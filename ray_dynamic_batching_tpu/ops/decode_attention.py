@@ -334,10 +334,11 @@ def _decode_attention(
 )
 def _paged_decode_attention(
     q: jax.Array,          # [B, K, Tq*G, H]  rows ordered (t, g)
-    k: jax.Array,          # [P, ps, K, H] page pool
+    k: jax.Array,          # [L, P, ps, K, H] the STACKED page pool, whole
     v: jax.Array,
     page_table: jax.Array,  # [B, NP] int32, sentinel P
     lengths: jax.Array,     # [B] int32 — row t attends pos <= lengths[b]+t
+    layer: jax.Array,       # [1] int32 — which layer of the stack to read
     k_scale: Optional[jax.Array],  # [P, K, ps] f32 (int8 pool), or None
     v_scale: Optional[jax.Array],
     *,
@@ -347,7 +348,7 @@ def _paged_decode_attention(
 ) -> jax.Array:
     B, K, R, H = q.shape
     G = R // window
-    P, ps = k.shape[0], k.shape[1]
+    P, ps = k.shape[1], k.shape[2]
     NP = page_table.shape[1]
     kb = _pick_heads_block(K)
     has_scales = k_scale is not None
@@ -357,25 +358,33 @@ def _paged_decode_attention(
     # (sentinel/garbage entries clamp to a real page; the length bound
     # masks everything they could contribute). Pages replace the slab
     # kernel's S-axis tiles one-for-one, so the online-softmax scratch
-    # carry works unchanged.
-    def kv_index(b, j, p, pt, ln):
-        return (jnp.minimum(pt[b, p], P - 1), 0, j, 0)
+    # carry works unchanged. The LAYER is the block index of a squeezed
+    # leading axis, prefetched like the table: the operand is the pool
+    # itself, so XLA has no layer slice to materialise in front of the
+    # custom call, and the refs the body sees keep their [1, ps, kb, H]
+    # shape.
+    def kv_index(b, j, p, pt, ln, ly):
+        return (ly[0], jnp.minimum(pt[b, p], P - 1), 0, j, 0)
+
+    def q_index(b, j, p, pt, ln, ly):
+        return (b, j, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, kb, R, H), lambda b, j, p, pt, ln: (b, j, 0, 0)),
-        pl.BlockSpec((1, ps, kb, H), kv_index),
-        pl.BlockSpec((1, ps, kb, H), kv_index),
+        pl.BlockSpec((1, kb, R, H), q_index),
+        pl.BlockSpec((None, 1, ps, kb, H), kv_index),
+        pl.BlockSpec((None, 1, ps, kb, H), kv_index),
     ]
     args = [q, k, v]
     if has_scales:
         scale_spec = pl.BlockSpec(
             (1, kb, ps),
-            lambda b, j, p, pt, ln: (jnp.minimum(pt[b, p], P - 1), j, 0),
+            lambda b, j, p, pt, ln, ly: (
+                jnp.minimum(pt[b, p], P - 1), j, 0),
         )
         in_specs += [scale_spec, scale_spec]
         args += [k_scale, v_scale]
 
-    def kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest):
+    def kernel(pt_ref, len_ref, ly_ref, q_ref, k_ref, v_ref, *rest):
         ks_ref = rest[0] if has_scales else None
         vs_ref = rest[1] if has_scales else None
         o_ref, m_ref, l_ref, acc_ref = rest[2 if has_scales else 0:][:4]
@@ -395,12 +404,10 @@ def _paged_decode_attention(
         )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, K // kb, NP),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, kb, R, H), lambda b, j, p, pt, ln: (b, j, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, kb, R, H), q_index),
         scratch_shapes=[
             pltpu.VMEM((kb, R), jnp.float32),
             pltpu.VMEM((kb, R), jnp.float32),
@@ -413,7 +420,7 @@ def _paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct((B, K, R, H), q.dtype),
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(page_table, lengths, *args)
+    )(page_table, lengths, layer, *args)
 
 
 def paged_decode_attention(
@@ -423,6 +430,7 @@ def paged_decode_attention(
     page_table: jax.Array,
     kv_lengths: jax.Array,
     *,
+    layer: int = 0,
     scale: Optional[float] = None,
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
@@ -436,13 +444,20 @@ def paged_decode_attention(
     gather — same decline contract as :func:`decode_attention`), with
     the reason appended to ``why``.
 
-    q [B, Tq, N, H] with Tq <= MAX_WINDOW_FOR_KERNEL; k/v [P, ps, K, H]
-    page pools with K dividing N; page_table [B, NP] int32 (sentinel P =
-    unallocated); kv_lengths [B]. Window row t attends logical positions
-    <= kv_lengths[b] + t — the STAIRCASE rule of the speculative-verify
-    window (``models/decoder.py::paged_window_mask`` owns it), whose
-    Tq == 1 case is exactly the plain-decode ``decode_mask`` bound.
-    ``k_scale``/``v_scale`` [P, ps, K] enable the int8-pool path.
+    q [B, Tq, N, H] with Tq <= MAX_WINDOW_FOR_KERNEL; k/v the STACKED
+    page pools [L, P, ps, K, H], passed WHOLE, with ``layer`` naming the
+    layer to read (an index into the kernel's block map — never slice
+    the pool for this call: a Mosaic operand is a buffer, so XLA would
+    materialise the slice, a layer-sized copy per layer per substep). A
+    single layer's [P, ps, K, H] pool is served as a one-layer stack
+    (``k[None]``, layer 0 — a free reshape). K divides N; page_table
+    [B, NP] int32 (sentinel P = unallocated); kv_lengths [B]. Window row
+    t attends logical positions <= kv_lengths[b] + t — the STAIRCASE
+    rule of the speculative-verify window
+    (``models/decoder.py::paged_window_mask`` owns it), whose Tq == 1
+    case is exactly the plain-decode ``decode_mask`` bound.
+    ``k_scale``/``v_scale`` [P, ps, K] (this layer's planes, H times
+    smaller than the codes) enable the int8-pool path.
 
     Eligibility is the lane-alignment + VMEM-budget contract of
     ``ops/tile_math.py``: the page IS the KV tile, so its streamed
@@ -462,16 +477,24 @@ def paged_decode_attention(
     divide — replicated heads fall back to the gather path, which GSPMD
     partitions from the pool's NamedSharding.
     """
-    if q.ndim != 4 or k.ndim != 4:
-        return declined(why, "paged kernel: q/k are not rank 4")
+    if k.ndim == 4 and v.ndim == 4:
+        k, v = k[None], v[None]  # one layer's pool: a one-layer stack
+    if q.ndim != 4 or k.ndim != 5:
+        return declined(why, "paged kernel: q is not rank 4 or the pool "
+                             "is not [L, P, ps, K, H]")
     B, Tq, N, H = q.shape
     if not (1 <= Tq <= MAX_WINDOW_FOR_KERNEL):
         # Wide windows are prefill-shaped: gather, then the flash kernel.
         return declined(
             why, f"paged kernel: window Tq={Tq} > "
             f"{MAX_WINDOW_FOR_KERNEL} is prefill-shaped")
-    P, ps, K, Hk = k.shape
-    if Hk != H or v.shape != k.shape or K == 0 or N % K != 0:
+    L, P, ps, K, Hk = k.shape
+    if not 0 <= layer < L:
+        raise ValueError(f"layer {layer} is not in a stack of {L}")
+    # Pool rows may be wider than the head: lane-padded with zeros
+    # (models/decoder.py::pool_head_dim). q is padded to match below and
+    # the output cut back; zeros add nothing to a score or an output.
+    if Hk < H or v.shape != k.shape or K == 0 or N % K != 0:
         return declined(
             why, f"paged kernel: q heads {N}x{H} do not group over "
             f"pool heads {K}x{Hk}")
@@ -503,7 +526,7 @@ def paged_decode_attention(
     kb = _pick_heads_block(k_local)
     G = N // K
     if tile_math.paged_tile_bytes(
-            ps, kb, H, k.dtype.itemsize,
+            ps, kb, Hk, k.dtype.itemsize,
             with_scales=k_scale is not None,
             # G is shard-invariant: a shard keeps N/tp query per K/tp kv
             # heads, so each head block still carries Tq*G window rows.
@@ -511,15 +534,18 @@ def paged_decode_attention(
     ) > VMEM_BLOCK_BUDGET_BYTES:
         # Page too fat for VMEM double-buffering: gather path.
         return declined(
-            why, f"paged kernel: page tile (ps={ps}, kb={kb}, H={H}) "
+            why, f"paged kernel: page tile (ps={ps}, kb={kb}, H={Hk}) "
             "exceeds the VMEM block budget")
     interpret = resolve_interpret(interpret)
     scale = scale if scale is not None else H ** -0.5
     # Rows ordered (t, g) per kv head: [B, Tq, K, G, H] ->
-    # [B, K, Tq*G, H] (Tq == 1 collapses to the historical layout).
+    # [B, K, Tq*G, H] (Tq == 1 collapses to the historical layout),
+    # zero-padded to the pool's row width.
     q_r = q.reshape(B, Tq, K, G, H).transpose(0, 2, 1, 3, 4).reshape(
         B, K, Tq * G, H
     )
+    if Hk > H:
+        q_r = jnp.pad(q_r, ((0, 0), (0, 0), (0, 0), (0, Hk - H)))
     ks = vs = None
     if k_scale is not None:
         # [P, ps, K] -> [P, K, ps]: the page becomes the (lane) trailing
@@ -528,53 +554,51 @@ def paged_decode_attention(
         # path is the same trap this transpose avoids).
         ks = k_scale.transpose(0, 2, 1)
         vs = v_scale.transpose(0, 2, 1)
+    operands = (q_r, k, v, page_table.astype(jnp.int32),
+                kv_lengths.astype(jnp.int32),
+                jnp.full((1,), layer, jnp.int32), ks, vs)
+    static = dict(scale=float(scale), window=int(Tq),
+                  interpret=bool(interpret))
     if tp > 1:
-        out = _paged_decode_attention_tp(
-            mesh, mesh_axis, q_r, k, v, page_table.astype(jnp.int32),
-            kv_lengths.astype(jnp.int32), ks, vs,
-            scale=float(scale), window=int(Tq), interpret=bool(interpret),
-        )
+        out = _paged_decode_attention_tp(mesh, mesh_axis, *operands,
+                                         **static)
     else:
-        out = _paged_decode_attention(
-            q_r, k, v, page_table.astype(jnp.int32),
-            kv_lengths.astype(jnp.int32), ks, vs,
-            scale=float(scale), window=int(Tq), interpret=bool(interpret),
-        )
-    return out.reshape(B, K, Tq, G, H).transpose(0, 2, 1, 3, 4).reshape(
-        B, Tq, N, H
-    )
+        out = _paged_decode_attention(*operands, **static)
+    return out[..., :H].reshape(B, K, Tq, G, H).transpose(
+        0, 2, 1, 3, 4).reshape(B, Tq, N, H)
 
 
 def _paged_decode_attention_tp(
-    mesh, axis: str, q_r, k, v, page_table, kv_lengths, ks, vs,
+    mesh, axis: str, q_r, k, v, page_table, kv_lengths, layer, ks, vs,
     *, scale: float, window: int, interpret: bool,
 ):
     """The TP wrapper: ``shard_map`` the paged kernel over the mesh's
     ``axis`` with q/pools split on the kv-head dim and the page
-    table/lengths replicated (page indices are shard-invariant). Each
-    shard's call is the ordinary single-device kernel on its head
+    table/lengths/layer replicated (page indices are shard-invariant).
+    Each shard's call is the ordinary single-device kernel on its head
     slice — numerics are per-head, so the sharded result is exactly the
     unsharded one re-laid-out."""
     from jax.sharding import PartitionSpec as P
 
-    args = [q_r, k, v, page_table, kv_lengths]
+    args = [q_r, k, v, page_table, kv_lengths, layer]
     in_specs = [
         P(None, axis, None, None),   # q rows split by kv head
-        P(None, None, axis, None),   # k pool: heads split, pages whole
-        P(None, None, axis, None),
+        P(None, None, None, axis, None),  # k stack: heads split
+        P(None, None, None, axis, None),
         P(None, None),               # page table: replica-global
         P(None),                     # lengths: replica-global
+        P(None),                     # layer index: replica-global
     ]
     has_scales = ks is not None
     if has_scales:
         args += [ks, vs]
         in_specs += [P(None, axis, None), P(None, axis, None)]
 
-    def local(q_l, k_l, v_l, pt, ln, *rest):
+    def local(q_l, k_l, v_l, pt, ln, ly, *rest):
         ks_l = rest[0] if has_scales else None
         vs_l = rest[1] if has_scales else None
         return _paged_decode_attention(
-            q_l, k_l, v_l, pt, ln, ks_l, vs_l,
+            q_l, k_l, v_l, pt, ln, ly, ks_l, vs_l,
             scale=scale, window=window, interpret=interpret,
         )
 

@@ -89,7 +89,7 @@ def test_serve_paged_phase(pallas_forced):
     assert len(out["tokens"]) == 8
     assert out["warmup_compile_episodes"] > 0
     by_program = {(r["program"], r["path"]) for r in out["paths"]}
-    assert ("decode_step", "paged kernel") in by_program
+    assert ("decode_step", "paged kernel (stacked pool)") in by_program
     assert ("chunk_prefill", "gather-then-flash kernel") in by_program
     # The designed decline is visible, with its reason.
     chunk = next(r for r in out["paths"] if r["program"] == "chunk_prefill")
@@ -155,7 +155,7 @@ def test_four_chip_phase(pallas_forced, eight_devices):
     )
     # llama_tiny has two KV heads: they split two ways.
     assert any(r["program"] == "decode_step"
-               and r["path"] == "paged kernel (shard_map tp=2)"
+               and r["path"] == "paged kernel (stacked pool, shard_map tp=2)"
                for r in out["tp"]["paths"])
     assert any(r["program"] == "chunk_prefill"
                and r["path"] == "gather-then-flash kernel (shard_map tp=2)"

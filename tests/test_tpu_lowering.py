@@ -172,6 +172,46 @@ class TestDecodeKernelLowersForTPU:
         _lower_decode(4, 1, 8, 64, 257, 4)
 
 
+class TestPagedKernelLowersForTPU:
+    """The paged kernel's 5-D K/V block — ``(None, 1, ps, kb, Hp)`` on the
+    STACKED pool, the layer a prefetched block index — at the benchmark's
+    two configurations (``benchmark/configs/``): gpt2-medium (24 layers,
+    128 pages x 128, MHA 16x64 in lane-padded 128-wide pool rows, 16
+    slots x 8 table entries) and Mistral 7B cut to 16 layers (160 pages x
+    128, GQA 32/8 x 128, 8 slots x 32 entries)."""
+
+    GEOMETRIES = {
+        "gpt2-medium": dict(L=24, P=128, B=16, NP=8, N=16, K=16, H=64),
+        "mistral-7b": dict(L=16, P=160, B=8, NP=32, N=32, K=8, H=128),
+    }
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8])
+    @pytest.mark.parametrize("window", [1, 5])
+    @pytest.mark.parametrize("config", sorted(GEOMETRIES))
+    def test_stacked_pool_block(self, config, window, dtype):
+        g = self.GEOMETRIES[config]
+        ps = 128
+        q = jnp.zeros((g["B"], window, g["N"], g["H"]), jnp.bfloat16)
+        from ray_dynamic_batching_tpu.models.decoder import pool_head_dim
+
+        pool = jnp.zeros(
+            (g["L"], g["P"], ps, g["K"], pool_head_dim(g["H"])), dtype)
+        table = jnp.zeros((g["B"], g["NP"]), jnp.int32)
+        lengths = jnp.zeros((g["B"],), jnp.int32)
+        scale = (jnp.zeros((g["P"], ps, g["K"]), jnp.float32)
+                 if dtype == jnp.int8 else None)
+
+        def f(q, pool, table, lengths, scale):
+            out = da.paged_decode_attention(
+                q, pool, pool, table, lengths, layer=g["L"] - 1,
+                k_scale=scale, v_scale=scale, interpret=False)
+            assert out is not None, "paged kernel declined"
+            return out
+
+        export.export(jax.jit(f), platforms=["tpu"])(
+            q, pool, table, lengths, scale)
+
+
 class TestRegisteredDecodersLowerForTPU:
     """Geometries discovered from the MODEL REGISTRY — not hand-picked
     shapes — so a new decoder family is covered the moment it registers.
