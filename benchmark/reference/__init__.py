@@ -5,8 +5,9 @@ file names its reference (``"reference": "gpt2"``) and the harness finds
 ``benchmark/reference/<name>.py`` by that name.
 
 A reference module exports ``logits(weights, tokens, sizes) -> [T, V]``:
-``weights`` is the neutral layout ``benchmark.weights.neutral_view`` gives
-(2-D matrices, one dict per layer), ``sizes`` the configuration file.
+``weights`` is the neutral layout the configuration's view gives
+(``benchmark/views/<view>.py``: one dict per layer), ``sizes`` the
+configuration file.
 Layers run one at a time so that 7B widths fit beside the served model.
 """
 

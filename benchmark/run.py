@@ -154,14 +154,13 @@ class Deployed:
         import jax
         import jax.numpy as jnp
 
+        from benchmark import views
         from benchmark.weights import make_params
         from ray_dynamic_batching_tpu.models.base import (
             ModelSLO,
             get_model,
             register_model,
         )
-        from ray_dynamic_batching_tpu.models.causal_lm import CausalLM
-        from ray_dynamic_batching_tpu.models.decoder import DecoderConfig
         from ray_dynamic_batching_tpu.parallel.placement import (
             PlacementManager,
         )
@@ -176,20 +175,17 @@ class Deployed:
         prog = config["program"]
         self.config = config
         self.model_name = prog["register_as"]
-        dcfg = DecoderConfig(**prog["decoder_config"])
+        self.view = views.get(config.get("view", "dense"))
         dtype = jnp.dtype(prog["dtype"])
-
-        def factory(**kw: Any) -> CausalLM:
-            return CausalLM(dcfg, name=self.model_name, **kw)
-
         register_model(self.model_name, slo=ModelSLO(
-            latency_slo_ms=float(prog["ttft_slo_ms"])))(factory)
+            latency_slo_ms=float(prog["ttft_slo_ms"])))(
+                model_factory(prog, self.model_name))
         self.model = get_model(self.model_name, dtype=dtype)
-        self.num_layers = dcfg.num_layers
-        self.vocab_size = dcfg.vocab_size
+        self.vocab_size = int(prog["decoder_config"]["vocab_size"])
 
         t = time.monotonic()
-        self.params = make_params(self.model, seed, dtype)
+        self.params = make_params(
+            self.model, seed, dtype, getattr(self.view, "seeding", None))
         jax.block_until_ready(self.params)
         split["weights_s"] = time.monotonic() - t
 
@@ -243,6 +239,26 @@ class Deployed:
             self.controller.shutdown()
 
 
+def model_factory(prog: Dict[str, Any], name: str):
+    """What builds the configuration's model, under ``name``, from
+    ``decoder_config`` and the ``dtype`` the registry passes on:
+    ``program.factory``, a ``module:callable`` of the program's package, or
+    the decoder family."""
+    sizes = prog["decoder_config"]
+    if "factory" in prog:
+        module, _, fn = prog["factory"].partition(":")
+        if not module.startswith("ray_dynamic_batching_tpu."):
+            raise ValueError(f"factory {prog['factory']!r} is not the "
+                             "program's")
+        make = getattr(importlib.import_module(module), fn)
+        return lambda **kw: make(**sizes, name=name, **kw)
+    from ray_dynamic_batching_tpu.models.causal_lm import CausalLM
+    from ray_dynamic_batching_tpu.models.decoder import DecoderConfig
+
+    dcfg = DecoderConfig(**sizes)
+    return lambda **kw: CausalLM(dcfg, name=name, **kw)
+
+
 def attention_path_lines() -> List[str]:
     from ray_dynamic_batching_tpu.ops.attention import attention_paths
 
@@ -263,13 +279,12 @@ def reference_check(dep: Deployed, seed: int) -> Dict[str, Any]:
     import numpy as np
 
     from benchmark import reference
-    from benchmark.weights import neutral_view
     from ray_dynamic_batching_tpu.utils.compile_ledger import get_ledger
 
     check = dep.config["reference_check"]
     rng = np.random.default_rng(int(seed) ^ 0x5EED)
     ref = reference.get(dep.config["reference"])
-    weights = neutral_view(dep.params, dep.num_layers)  # no copies
+    weights = dep.view.view(dep.params, dep.config)  # no copies
     worst, ok, served_all = 0.0, True, []
     # The reference's own programs compile here, after the replicas armed
     # the compile ledger's steady mark: bracket them as set-up.
@@ -480,7 +495,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         ref = reference_check(dep, seed)
         split["reference_check_s"] = time.monotonic() - t
         say(f"reference: ok={ref['ok']} worst margin to the reference's "
-            f"top-1 {ref['worst_gap']:.4f} (tolerance {ref['tol']})")
+            f"top-1 {ref['worst_gap']:.4f} (tolerance {ref['tol']}); "
+            f"served {ref['served']}")
 
         t = time.monotonic()
         requests = loadgen.build_requests(

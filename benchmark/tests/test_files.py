@@ -72,6 +72,11 @@ def test_every_named_file_exists_and_loads():
         cfg = json.loads((ROOT / c["file"]).read_text())
         assert cfg["source"] == c["source"] and cfg["reduced"] == c["reduced"]
         assert (ROOT / "benchmark" / "reference" / f"{cfg['reference']}.py").exists()
+        view = importlib.import_module(
+            f"benchmark.views.{cfg.get('view', 'dense')}")
+        assert callable(view.view)
+        seeding = getattr(view, "seeding", None)
+        assert seeding is None or callable(seeding)
     for w in BENCH["workloads"]:
         used.add(w["config"])
         assert (ROOT / "benchmark" / "traffic" / f"{w['traffic']}.json").exists()
@@ -134,3 +139,8 @@ def test_no_file_of_the_benchmark_is_unused():
             == {w["traffic"] for w in BENCH["workloads"]})
     assert ({f"benchmark/configs/{p.name}" for p in (bench / "configs").glob("*.json")}
             == {c["file"] for c in BENCH["configs"]})
+    named = [json.loads((ROOT / c["file"]).read_text()) for c in BENCH["configs"]]
+    assert ({p.stem for p in (bench / "views").glob("*.py")} - {"__init__"}
+            == {cfg.get("view", "dense") for cfg in named})
+    assert ({p.stem for p in (bench / "reference").glob("*.py")} - {"__init__"}
+            == {cfg["reference"] for cfg in named})

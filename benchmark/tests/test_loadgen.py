@@ -35,7 +35,8 @@ def test_every_seed_offers_the_same_work_in_its_own_order(mix):
         mid = (spec["lo"] * spec["hi"]) ** 0.5 if spec["dist"] == "loguniform" \
             else (spec["lo"] + spec["hi"]) / 2.0
         below = sum(1 for v in vals if v < mid)     # half on either side
-        assert abs(below - len(vals) / 2.0) <= 2
+        # (flooring to whole tokens shifts a few tenths of a percent down)
+        assert abs(below - len(vals) / 2.0) <= max(2, 0.01 * len(vals))
     if t["loop"] == "open":
         assert len(a) == round(t["arrivals"]["rate_rps"] * 48.0)
         assert prompts(a) != prompts(b)     # the order is the seed's

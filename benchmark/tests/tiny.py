@@ -32,7 +32,9 @@ def tiny_cell(workload: str, rate_rps: float = 30.0) -> Cell:
                                  hi=150 if long_prompts else 60)
     traffic["output_len"].update(lo=4, hi=12)
     if traffic["loop"] == "open":
-        traffic["arrivals"]["rate_rps"] = rate_rps
+        # a replica: every engine of several has scans to show in 3 s
+        traffic["arrivals"]["rate_rps"] = rate_rps * int(
+            cfg["deployment"]["num_replicas"])
         traffic["limits"] = {"ttft_ms": 60_000.0, "tpot_ms": 60_000.0}
     else:
         traffic.update(clients=4, set_size=8)
