@@ -104,6 +104,7 @@ from ray_dynamic_batching_tpu.engine.pagefabric import (
     export_stream_parcel,
 )
 from ray_dynamic_batching_tpu.engine.queue import RequestQueue
+from ray_dynamic_batching_tpu.models.causal_lm import merge_routing_counters
 from ray_dynamic_batching_tpu.models.decoder import fit_head_dim
 from ray_dynamic_batching_tpu.ops import jit_model
 from ray_dynamic_batching_tpu.ops.tile_math import (
@@ -222,7 +223,16 @@ class Turn(NamedTuple):
     engine stood under — ``trains`` at the dispatch; ``queue_len``,
     ``pages_allocated`` and ``positions_cached`` as the record is written,
     at ``t_done`` — is what :func:`summarize_turns` shows beside each of
-    the longest gaps."""
+    the longest gaps.
+
+    An expert model's decode scans and paged chunk groups also carry their
+    routing, counted on the device over REAL tokens only (active slots, a
+    chunk's unpadded tokens) and fetched with the tokens: ``moe_rows``
+    (token-expert pairs routed, all layers and substeps), ``moe_experts_hit``
+    (experts with at least one real row, summed over layers and substeps)
+    and ``moe_max_rows`` (the most rows one expert took in one layer of one
+    substep). All 0 for a dense model, for the other programs, and for a
+    chunk group that finished no prompt (nothing of it was fetched)."""
 
     kind: str
     t_dispatch: float
@@ -237,6 +247,9 @@ class Turn(NamedTuple):
     pages_allocated: int    # paged pool at t_done (0 on a slab engine)
     positions_cached: int   # sum of the slots' cached lengths at t_done
     after_idle: bool        # an idle wait lay between this and the last
+    moe_rows: int = 0
+    moe_experts_hit: int = 0
+    moe_max_rows: int = 0
 
 
 # Sized for the benchmark's 51 s window at several times the cells'
@@ -262,10 +275,19 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
     preparation). ``longest_gaps`` lists the largest with that split and
     the load the engine stood under: chunk trains pending at the dispatch
     that ended the gap; queued requests, pages allocated and positions
-    cached as the earlier record was written, inside the gap."""
+    cached as the earlier record was written, inside the gap. An expert
+    model's records add ``moe_rows_per_expert`` (rows routed over experts
+    hit: how many rows share one read of an expert's weights) and
+    ``moe_imbalance`` (the most rows one expert took in a layer of a
+    substep, over that mean)."""
     scans = [t for t in turns if t.kind == "turn"]
     out: Dict[str, Any] = {"dispatches": len(turns), "scans": len(scans),
                            "dropped": dropped}
+    hit = sum(t.moe_experts_hit for t in turns)
+    if hit:
+        out["moe_rows_per_expert"] = sum(t.moe_rows for t in turns) / hit
+        out["moe_imbalance"] = (max(t.moe_max_rows for t in turns)
+                                / out["moe_rows_per_expert"])
     if len(turns) < 2:
         return out
     substeps = sum(t.substeps for t in scans)
@@ -978,6 +1000,12 @@ class DecodeEngine:
             else:
                 self.session_cache = SessionCache(session_cache_size)
         self._prefill_fns: Dict[int, Callable] = {}
+        # An expert model's decode and paged chunk programs also return
+        # their routing counters (``Turn``'s ``moe_*``); a dense model's
+        # programs are built without the argument and do not change.
+        self._moe_kw: Dict[str, bool] = (
+            {"moe_counters": True}
+            if getattr(model, "has_experts", False) else {})
         # Donations: cache (arg 1) and counts (arg 8 — params=0,
         # cache=1, step_state=2, horizon=3, samp_f=4, samp_i=5,
         # bias_ids=6, bias_vals=7, counts=8).
@@ -1101,14 +1129,16 @@ class DecodeEngine:
 
     def _log_dispatch(self, kind: str, t_dispatch: float, t_issued: float,
                       t_fetched: float, substeps: int, tokens: int,
-                      active: int, trains: int) -> Turn:
+                      active: int, trains: int,
+                      moe: Sequence[int] = (0, 0, 0)) -> Turn:
         """Append this dispatch's record to the turn ring (its work on the
-        host is done: ``t_done`` is now)."""
+        host is done: ``t_done`` is now). ``moe``: the dispatch's routing
+        counters as fetched (``Turn``'s last three fields)."""
         rec = Turn(
             kind, t_dispatch, t_issued, t_fetched, now_ms(),
             substeps, tokens, active, trains, len(self.queue),
             self._allocator.allocated_pages if self.paged else 0,
-            int(self._len_host.sum()), self._idled,
+            int(self._len_host.sum()), self._idled, *(int(c) for c in moe),
         )
         if len(self.turns) == self.turns.maxlen:
             self.turns_dropped += 1
@@ -1358,8 +1388,10 @@ class DecodeEngine:
         )
         temps, topp = meta_f[0], meta_f[1]
         params = self._mp(params)
-        taken, pools = self.model.prefill_chunk_paged(
-            params, tokens, attn_mask, cache, tables, starts, take_idx
+        # An expert model's routing counters ride the ids fetch: [g + 3].
+        taken, pools, *moe = self.model.prefill_chunk_paged(
+            params, tokens, attn_mask, cache, tables, starts, take_idx,
+            **self._moe_kw,
         )
         lengths = cache.lengths.at[slots].set(new_len, mode="drop")
         cache = cache.replace(
@@ -1370,6 +1402,8 @@ class DecodeEngine:
             taken, temps, topk, seeds, jnp.zeros_like(slots), bias_ids,
             bias_vals, topp,
         )
+        if moe:
+            first = jnp.concatenate([first, moe[0]])
         return first, cache
 
     def _decode_impl(self, params, cache, step_state, horizon: int,
@@ -1393,6 +1427,8 @@ class DecodeEngine:
         Everything the host needs comes back PACKED in one int32 array
         [2h+1, B] (h token rows, h advanced rows, 1 lengths row) so the
         device→host boundary is crossed once per dispatch, not three times.
+        An expert model adds three rows, each one routing counter of the
+        whole scan broadcast over B (``Turn``'s ``moe_*``): [2h+4, B].
         """
         tokens = step_state[0][:, None]
         active = step_state[1].astype(bool)
@@ -1423,8 +1459,8 @@ class DecodeEngine:
             # fuse each convert+scale into its consuming matmul.
             step_fn = (self.model.decode_step_paged if self.paged
                        else self.model.decode_step)
-            logits, cache = step_fn(
-                self._mp(params), tokens, cache, advanced
+            logits, cache, *moe = step_fn(
+                self._mp(params), tokens, cache, advanced, **self._moe_kw
             )
             # Repetition control: subtract presence (any prior emission)
             # and frequency (per emission) penalties over the slot's
@@ -1439,16 +1475,19 @@ class DecodeEngine:
                                       topp)
             nxt = jnp.where(advanced, nxt, tokens[:, 0])
             counts = counts.at[rows, nxt].add(advanced.astype(jnp.int32))
-            return (cache, nxt[:, None], counts), (nxt, advanced)
+            return (cache, nxt[:, None], counts), (nxt, advanced, *moe)
 
-        (cache, _, counts), (toks, adv) = jax.lax.scan(
+        (cache, _, counts), (toks, adv, *moe) = jax.lax.scan(
             substep, (cache, tokens, counts),
             jnp.arange(horizon, dtype=jnp.int32),
         )
-        packed = jnp.concatenate(
-            [toks, adv.astype(jnp.int32), cache.lengths[None, :]], axis=0
-        )
-        return packed, cache, self._pin_counts(counts)
+        packed = [toks, adv.astype(jnp.int32), cache.lengths[None, :]]
+        if moe:
+            packed.append(jnp.broadcast_to(
+                merge_routing_counters(moe[0])[:, None],
+                (3, tokens.shape[0])))
+        return (jnp.concatenate(packed, axis=0), cache,
+                self._pin_counts(counts))
 
     def _spec_impl(self, params, cache, dcache, step_state,
                    bias_ids, bias_vals):
@@ -1826,6 +1865,8 @@ class DecodeEngine:
             "chunk" if self.chunked_prefill and self.paged else "prefill",
             self.ttft_horizon, self.decode_horizon,
         )
+        for line in self._expert_paths():
+            logger.info("%s: experts: %s", self.model.name, line)
 
     # --- admission ---------------------------------------------------------
     def _free_slots(self) -> List[int]:
@@ -2458,8 +2499,10 @@ class DecodeEngine:
                 if final:
                     finals.append((i, t))
             for i in range(n, group):
+                # A filler row repeats row 0's writes; its mask stays 0
+                # (the program reads the mask only to count an expert
+                # model's REAL routed tokens).
                 tokens[i] = tokens[0]
-                mask[i] = mask[0]
                 tables[i] = tables[0]
                 meta_i[:, i] = meta_i[:, 0]
                 meta_f[:, i] = meta_f[:, 0]
@@ -2480,10 +2523,13 @@ class DecodeEngine:
             )
         t_issued = now_ms()
         t_fetched = 0.0
+        moe = (0, 0, 0)
         if finals:
             with self._phase("rdb.engine.prefill.fetch"):
                 first_host = np.asarray(first)  # rdb-lint: disable=host-sync-in-hot-path (THE one fetch per chunk dispatch: the fused first-token ids — TTFT ends here, never at a logits round-trip)
             t_fetched = now_ms()
+            if self._moe_kw:
+                moe = first_host[group:]
         with self._phase("rdb.engine.prefill.finish"):
             for t in trains:
                 t.pos = min(t.pos + W, t.total)
@@ -2505,7 +2551,7 @@ class DecodeEngine:
                 self._register(t.slot_idx, t.req, int(first_host[i]),
                                t.opts, t_fetched)
         self._log_dispatch("chunk", t_dispatch, t_issued, t_fetched, 0,
-                           W * n, active, pending)
+                           W * n, active, pending, moe)
 
     def _advance_train_slab(self, train: _ChunkTrain) -> None:
         """One row-cache chunk for a slab train (the legacy chunk
@@ -3997,8 +4043,10 @@ class DecodeEngine:
                     jnp.asarray(counts),
                 )
             self._harvest(toks_host, advanced_host, lengths_host, h)
-        rec = self._log_dispatch("turn", t_dispatch, t_issued, t_fetched, h,
-                                 0, active, len(self._trains))
+        rec = self._log_dispatch(
+            "turn", t_dispatch, t_issued, t_fetched, h, 0, active,
+            len(self._trains),
+            packed_host[2 * h + 1:, 0] if self._moe_kw else (0, 0, 0))
         if links is not None:
             self._record_turn_span(rec, links, h)
 
@@ -4596,6 +4644,17 @@ class DecodeEngine:
             reserved = float(self.num_slots * self.max_len)
         return used / reserved if reserved > 0 else 1.0
 
+    def _expert_paths(self) -> List[str]:
+        """Which path each program's expert layers took (``ops/moe.py``'s
+        trace-time record of the process: engines of one process trace
+        the same programs by the same rule); empty for a dense model."""
+        if not self._moe_kw:
+            return []
+        from ray_dynamic_batching_tpu.ops.moe import moe_paths
+
+        return sorted({f"{p.program}: {p.rows} rows -> {p.describe()}"
+                       for p in moe_paths() if p.program})
+
     def turn_summary(self, records: Optional[Sequence[Turn]] = None,
                      span_ms: Optional[float] = None,
                      longest: int = 8) -> Dict[str, Any]:
@@ -4648,6 +4707,13 @@ class DecodeEngine:
                 "migrated_in": self.migrated_in,
                 "pushes_out": self.pushes_out,
                 "pushes_in": self.pushes_in,
+            }
+        if self._moe_kw:
+            turns = out["turns"]
+            out["moe"] = {
+                "rows_per_expert": turns.get("moe_rows_per_expert"),
+                "imbalance": turns.get("moe_imbalance"),
+                "paths": self._expert_paths(),
             }
         if self.draft_model is not None:
             out["spec"] = {

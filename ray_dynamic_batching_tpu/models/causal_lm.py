@@ -30,6 +30,34 @@ from ray_dynamic_batching_tpu.models.decoder import (
 )
 
 
+def routing_counters(routing: Any, valid: jax.Array,
+                     num_experts: int) -> jax.Array:
+    """``[rows, experts_hit, max_rows]`` int32 of one forward's expert
+    routing, over REAL tokens only: ``routing`` is the ``moe_routing``
+    collection (each expert layer's chosen experts ``[B, T, k]``) and
+    ``valid`` ``[B, T]`` says which tokens are real (pad tokens of a bucket
+    and inactive decode slots are computed and not counted). ``rows`` is the
+    token-expert pairs routed, ``experts_hit`` the experts with at least one
+    real row, both summed over layers; ``max_rows`` the most one expert took
+    in one layer."""
+    experts = jnp.arange(num_experts, dtype=jnp.int32)
+    rows = hit = most = jnp.zeros((), jnp.int32)
+    for idx in jax.tree_util.tree_leaves(routing):
+        took = ((idx[..., None] == experts)
+                & valid.astype(bool)[..., None, None]).sum(axis=(0, 1, 2))
+        rows += took.sum()
+        hit += (took > 0).sum()
+        most = jnp.maximum(most, took.max())
+    return jnp.stack([rows, hit, most]).astype(jnp.int32)
+
+
+def merge_routing_counters(counters: jax.Array) -> jax.Array:
+    """Several forwards' counters ``[n, 3]`` as one dispatch's ``[3]``:
+    rows and experts hit add, the most rows is the largest."""
+    return jnp.stack([counters[:, 0].sum(), counters[:, 1].sum(),
+                      counters[:, 2].max()])
+
+
 class CausalLM(ServableModel):
     family = "causal_lm"
 
@@ -48,6 +76,21 @@ class CausalLM(ServableModel):
         # scales, quantized at write (models/decoder.py::quantize_kv_rows).
         self.kv_dtype = kv_dtype
         self.module = DecoderModule(cfg, dtype=dtype)
+
+    @property
+    def has_experts(self) -> bool:
+        return self.cfg.num_experts > 0
+
+    def _forward(self, params, *args, moe_valid=None, **kwargs):
+        """``module.apply``; with ``moe_valid`` [B, T] (an expert model's
+        caller asking for them) also this forward's
+        :func:`routing_counters`, as a third result."""
+        if moe_valid is None:
+            return self.module.apply(params, *args, **kwargs)
+        (logits, cache), state = self.module.apply(
+            params, *args, mutable=["moe_routing"], **kwargs)
+        return logits, cache, routing_counters(
+            state["moe_routing"], moe_valid, self.cfg.num_experts)
 
     # --- ServableModel interface (apply == prefill logits for profiling) ---
     def init(self, rng: jax.Array):
@@ -208,7 +251,8 @@ class CausalLM(ServableModel):
         tables: jax.Array,     # [B, NP] per-row page-table rows
         starts: jax.Array,     # [B] global position of tokens[:, 0] per row
         take_idx: jax.Array,   # [B] per-row logits row to return
-    ) -> Tuple[jax.Array, PagedKVCache]:
+        moe_counters: bool = False,
+    ) -> Tuple[jax.Array, ...]:
         """Pages-DIRECT chunked prefill: one chunk of B independent (and
         independently-positioned) prompt fills, written straight through
         per-row page-table rows — no private row cache, no commit copy.
@@ -225,7 +269,9 @@ class CausalLM(ServableModel):
         like the slab chunk path; nothing ever attends them.
         ``lengths``/``page_table`` pass through untouched — the caller
         owns both (the engine scatters verified lengths itself at the
-        final chunk). Returns (logits at ``take_idx`` [B, V], cache)."""
+        final chunk). Returns (logits at ``take_idx`` [B, V], cache) and,
+        with ``moe_counters`` (an expert model), the chunk's
+        :func:`routing_counters` over ``attn_mask``'s real tokens."""
         B, W = tokens.shape
         S = tables.shape[1] * cache.page_size
         positions = starts[:, None] + jnp.broadcast_to(
@@ -235,14 +281,15 @@ class CausalLM(ServableModel):
         # can run past logical capacity) steer to S: their scatter drops
         # at the sentinel and their outputs are never taken.
         positions = jnp.where(positions < S, positions, S)
-        logits, new_cache = self.module.apply(
+        logits, new_cache, *counters = self._forward(
             params, tokens, positions, None, cache, scatter_writes=True,
             page_table=tables, kv_lengths=starts,
+            moe_valid=attn_mask if moe_counters else None,
         )
         taken = jnp.take_along_axis(
             logits, take_idx[:, None, None], axis=1
         )[:, 0]
-        return taken, new_cache
+        return (taken, new_cache, *counters)
 
     def verify_step_paged(
         self,
@@ -284,8 +331,11 @@ class CausalLM(ServableModel):
         tokens: jax.Array,   # [B, 1] current token per slot
         cache: KVCache,
         active: jax.Array,   # [B] bool — which slots advance
-    ) -> Tuple[jax.Array, KVCache]:
-        """One decode step for all slots; returns logits [B, V] + new cache.
+        moe_counters: bool = False,
+    ) -> Tuple[jax.Array, ...]:
+        """One decode step for all slots; returns logits [B, V] + new cache
+        (and, with ``moe_counters``, the step's :func:`routing_counters`
+        over the rows that advance).
 
         Rows whose cache is full are force-deactivated: their out-of-bounds
         scatter is explicitly dropped (decoder writes with mode="drop"), their
@@ -297,9 +347,13 @@ class CausalLM(ServableModel):
         active = jnp.logical_and(active, in_bounds)
         positions = cache.lengths[:, None]
         mask = decode_mask(cache.lengths, cache.capacity)
-        logits, new_cache = self.module.apply(params, tokens, positions, mask, cache)
+        logits, new_cache, *counters = self._forward(
+            params, tokens, positions, mask, cache,
+            moe_valid=active[:, None] if moe_counters else None,
+        )
         new_lengths = cache.lengths + active.astype(jnp.int32)
-        return logits[:, 0], new_cache.replace(lengths=new_lengths)
+        return (logits[:, 0], new_cache.replace(lengths=new_lengths),
+                *counters)
 
     def make_paged_cache(
         self, batch_size: int, num_pages: int, page_size: int,
@@ -319,11 +373,13 @@ class CausalLM(ServableModel):
         tokens: jax.Array,   # [B, 1] current token per slot
         cache: PagedKVCache,
         active: jax.Array,   # [B] bool — which slots advance
-    ) -> Tuple[jax.Array, PagedKVCache]:
+        moe_counters: bool = False,
+    ) -> Tuple[jax.Array, ...]:
         """One decode step against the paged pool — the exact
         :meth:`decode_step` contract (force-deactivation at logical
         capacity, lengths advance only for active rows, garbage logits
-        on inactive rows) with writes and reads routed through the page
+        on inactive rows, ``moe_counters``) with writes and reads routed
+        through the page
         table. Token-exact vs the slab step by construction: the write
         rule maps the same logical position to a physical (page,
         offset), and attention sees the same positions <= lengths window
@@ -331,12 +387,14 @@ class CausalLM(ServableModel):
         in_bounds = cache.lengths < cache.capacity
         active = jnp.logical_and(active, in_bounds)
         positions = cache.lengths[:, None]
-        logits, new_cache = self.module.apply(
+        logits, new_cache, *counters = self._forward(
             params, tokens, positions, None, cache,
             page_table=cache.page_table, kv_lengths=cache.lengths,
+            moe_valid=active[:, None] if moe_counters else None,
         )
         new_lengths = cache.lengths + active.astype(jnp.int32)
-        return logits[:, 0], new_cache.replace(lengths=new_lengths)
+        return (logits[:, 0], new_cache.replace(lengths=new_lengths),
+                *counters)
 
     # --- planning ---------------------------------------------------------
     def flops_per_sample(self, seq_len: Optional[int] = None) -> float:
@@ -346,6 +404,7 @@ class CausalLM(ServableModel):
             c.d_model * c.head_dim * (c.num_heads + 2 * c.num_kv_heads)
             + c.num_heads * c.head_dim * c.d_model
             + (3 if c.gated_mlp else 2) * c.d_model * c.mlp_dim
+            * (c.moe_top_k if c.num_experts else 1)
         )
         attn = 4 * T * c.d_model  # score+value flops per token, avg T/2 ctx * 2
         return c.num_layers * (per_tok + attn) * T + 2 * c.d_model * c.vocab_size * T
@@ -487,6 +546,28 @@ TINY_MOE = DecoderConfig(
     moe_top_k=2,
 )
 
+# allenai/OLMoE-1B-7B-0125-Instruct as published: 64 experts of width
+# 1,024, 8 a token with the gates as the softmax gave them, no shared
+# expert; MHA 16 x 128 with RMSNorm on the q and k projections.
+OLMOE_1B_7B = DecoderConfig(
+    vocab_size=50304,
+    d_model=2048,
+    num_layers=16,
+    num_heads=16,
+    num_kv_heads=16,
+    mlp_dim=1024,
+    max_seq_len=4096,
+    pos="rope",
+    norm="rms",
+    gated_mlp=True,
+    use_bias=False,
+    rope_theta=10000.0,
+    num_experts=64,
+    moe_top_k=8,
+    moe_renormalize=False,
+    qk_norm=True,
+)
+
 
 @register_model("gpt2_medium", slo=ModelSLO(latency_slo_ms=500.0))
 def _gpt2_medium(**kwargs) -> CausalLM:
@@ -521,3 +602,8 @@ def _llama_tiny_int8kv(**kwargs) -> CausalLM:
 @register_model("moe_tiny")
 def _moe_tiny(**kwargs) -> CausalLM:
     return CausalLM(TINY_MOE, name="moe_tiny", **kwargs)
+
+
+@register_model("olmoe_1b_7b", slo=ModelSLO(latency_slo_ms=1000.0))
+def _olmoe_1b_7b(**kwargs) -> CausalLM:
+    return CausalLM(OLMOE_1B_7B, name="olmoe_1b_7b", **kwargs)

@@ -48,7 +48,17 @@ class DecoderConfig:
     rope_theta: float = 10000.0
     num_experts: int = 0      # > 0 switches the MLP to a MoE block (ep axis)
     moe_top_k: int = 2
+    # Nothing reads this: routing is dropless (models/moe.py) and has no
+    # capacity. The field stays only because a benchmark rehearsal's
+    # configuration file, which a model PR may not edit, passes it
+    # (ROADMAP.md, Design debt).
     moe_capacity_factor: float = 1.25
+    # The top-k gates rescaled to sum to one (the published
+    # ``norm_topk_prob``); False uses them as the softmax gave them.
+    moe_renormalize: bool = True
+    # RMSNorm on the q and k PROJECTIONS (one scale over all heads' width),
+    # before the split into heads and before RoPE (OLMoE).
+    qk_norm: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -290,6 +300,11 @@ class DecoderLayer(nn.Module):
         q = dense((cfg.num_heads, cfg.head_dim), "q")(y)
         k = dense((cfg.num_kv_heads, cfg.head_dim), "k")(y)
         v = dense((cfg.num_kv_heads, cfg.head_dim), "v")(y)
+        if cfg.qk_norm:
+            q = RMSNorm(name="q_norm")(
+                q.reshape(*q.shape[:2], -1)).reshape(q.shape)
+            k = RMSNorm(name="k_norm")(
+                k.reshape(*k.shape[:2], -1)).reshape(k.shape)
         if cfg.pos == "rope":
             q = apply_rope(q, positions, cfg.rope_theta)
             k = apply_rope(k, positions, cfg.rope_theta)
@@ -466,7 +481,7 @@ class DecoderLayer(nn.Module):
                 mlp_dim=cfg.mlp_dim,
                 num_experts=cfg.num_experts,
                 top_k=cfg.moe_top_k,
-                capacity_factor=cfg.moe_capacity_factor,
+                renormalize=cfg.moe_renormalize,
                 gated=cfg.gated_mlp,
                 dtype=self.dtype,
                 name="moe",

@@ -1,38 +1,48 @@
-"""Mixture-of-experts FFN block with expert parallelism over the ``ep`` axis.
+"""Mixture-of-experts FFN block: dropless top-k routing across the whole
+batch, computed as a grouped matmul over rows sorted by expert.
 
 Expert parallelism is absent from the reference (SURVEY.md §2.4 lists EP as
-a from-scratch TPU design item). TPU-first design: GShard-style capacity-based
-dispatch expressed as dense one-hot einsums — every shape static, so the
-whole block jits once — with expert weights carrying a leading expert dim
-sharded over the ``ep`` mesh axis. Under GSPMD the dispatched-token tensor is
-sharding-constrained to ``ep``, which makes XLA insert the all_to_all pair
-(dispatch/combine) over ICI rather than gathering all tokens everywhere.
+a from-scratch TPU design item). One routing, every shape static:
 
-Top-k routing (renormalized), per-row capacity C = ceil(k*T/E * capacity
-factor); overflow tokens fall through the residual connection (standard
-GShard behavior — bounded memory beats tail-token coverage on TPU). The
-load-balance auxiliary loss is sown under ``intermediates/moe_aux_loss``.
+1. *route* — flatten to ``[B*T, D]``; router logits and the softmax over all
+   ``E`` experts in float32; the ``k`` largest gates of each token, used as
+   they are or renormalised to sum to one (``renormalize``, the published
+   ``norm_topk_prob``); the ``B*T*k`` (token, choice) pairs sorted by expert
+   and the rows gathered in that order, with ``E`` group sizes counted.
+2. *experts* — :func:`ops.moe.expert_mlp` on the sorted rows: a grouped
+   Pallas kernel on the TPU, ``jax.lax.ragged_dot`` elsewhere, the same rows
+   and group sizes on both.
+3. *combine* — rows back to token order (the inverse permutation), times
+   their gates, summed over the ``k`` choices in float32.
+
+Every token reaches all ``k`` of its experts: there is no capacity, nothing
+is dropped, and a pad token or an inactive decode slot cannot take a real
+token's place (it is computed and ignored). The cost follows the rows routed
+(``B*T*k``), not ``E`` times the rows.
+
+Expert weights carry a leading expert dim sharded over the ``ep`` mesh axis
+(``models/causal_lm.py`` sharding rules). Under a mesh the block takes the
+XLA form and GSPMD partitions it from the operands' shardings (tokens
+replicated over ``ep``, so the answer is the single-device one); a
+token-sharded deployment's all_to_all pair would sit where the rows are
+gathered into expert order and where they are put back — the two
+``named_scope``s below.
+
+The load-balance auxiliary loss is sown under
+``intermediates/moe_aux_loss``; the chosen experts ``[B, T, k]`` under
+``moe_routing/top_idx`` (collected only where a caller asks:
+``CausalLM``'s routing counters).
 """
 
 from __future__ import annotations
 
-import math
 from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
-
-def _constrain(x: jax.Array, spec: P) -> jax.Array:
-    """Sharding constraint that degrades to a no-op when no mesh is in
-    context (single-device eager tests) or a dim isn't divisible by its
-    mesh axis (e.g. batch-of-1 init under a dp>1 mesh)."""
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except (RuntimeError, ValueError):
-        return x
+from ray_dynamic_batching_tpu.ops import moe as moe_ops
 
 
 class MoEBlock(nn.Module):
@@ -40,7 +50,7 @@ class MoEBlock(nn.Module):
     mlp_dim: int
     num_experts: int
     top_k: int = 2
-    capacity_factor: float = 1.25
+    renormalize: bool = True  # top-k gates rescaled to sum to one
     gated: bool = True  # SwiGLU experts (matches the dense MLP family)
     dtype: Any = jnp.bfloat16
 
@@ -48,65 +58,57 @@ class MoEBlock(nn.Module):
     def __call__(self, x: jax.Array) -> jax.Array:  # [B, T, D]
         B, T, D = x.shape
         E, F, k = self.num_experts, self.mlp_dim, self.top_k
-        C = max(1, math.ceil(k * T / E * self.capacity_factor))
+        N = B * T
 
-        router = nn.Dense(
-            E, use_bias=False, dtype=jnp.float32, param_dtype=jnp.float32,
-            name="router",
-        )
-        gates = jax.nn.softmax(router(x.astype(jnp.float32)), axis=-1)  # [B,T,E]
-
-        # top-k gate selection, renormalized over the chosen experts
-        top_gates, top_idx = jax.lax.top_k(gates, k)          # [B,T,k]
-        top_gates = top_gates / jnp.maximum(
-            top_gates.sum(axis=-1, keepdims=True), 1e-9
-        )
-
-        # position of each (token, choice) within its expert's capacity
-        # buffer: running count of prior assignments to the same expert,
-        # choice-major priority (all first choices beat all second choices)
-        choice_onehot = jax.nn.one_hot(top_idx, E, dtype=jnp.float32)  # [B,T,k,E]
-        flat = choice_onehot.transpose(0, 2, 1, 3).reshape(B, k * T, E)
-        pos_flat = jnp.cumsum(flat, axis=1) - flat             # [B,kT,E]
-        pos_in_expert = pos_flat.reshape(B, k, T, E).transpose(0, 2, 1, 3)
-        within_cap = pos_in_expert < C                          # [B,T,k,E]
-
-        # dispatch [B,T,E,C]: one-hot over capacity slots; overflow tokens
-        # get an out-of-range index -> all-zero row (fall through residual)
-        # (positions are whole-number floats — running counts — and
-        # one_hot wants integer indices)
-        cap_idx = jnp.where(within_cap, pos_in_expert, C).astype(jnp.int32)
-        cap_onehot = jax.nn.one_hot(cap_idx, C, dtype=jnp.float32)  # [B,T,k,E,C]
-        dispatch = jnp.einsum(
-            "btke,btkec->btec", choice_onehot, cap_onehot
-        )
-        gate_per_expert = jnp.einsum("btke,btk->bte", choice_onehot, top_gates)
-        combine = dispatch * gate_per_expert[..., None]
+        with jax.named_scope("moe_route"):
+            router = nn.Dense(
+                E, use_bias=False, dtype=jnp.float32,
+                param_dtype=jnp.float32,
+                # [N, D] x [D, E] is small; a near-tie between the k-th and
+                # the next gate should not be decided by a bf16 MXU pass
+                precision=jax.lax.Precision.HIGHEST, name="router",
+            )
+            flat = x.reshape(N, D)
+            gates = jax.nn.softmax(router(flat.astype(jnp.float32)), axis=-1)
+            top_gates, top_idx = jax.lax.top_k(gates, k)          # [N, k]
+            if self.renormalize:
+                top_gates = top_gates / jnp.maximum(
+                    top_gates.sum(axis=-1, keepdims=True), 1e-9
+                )
+            # pair p = (token p // k, choice p % k); stable, so an expert's
+            # rows stay in token order. (A counting sort, one running count
+            # and a scatter in place of both argsorts, read the same substep
+            # on the chip: 26.43 against 26.30-26.33 ms, PERF.md, PR 27.)
+            pair_expert = top_idx.reshape(N * k)
+            order = jnp.argsort(pair_expert, stable=True)
+            group_sizes = (
+                pair_expert[:, None] == jnp.arange(E, dtype=pair_expert.dtype)
+            ).sum(axis=0).astype(jnp.int32)
+            xs = flat[order // k].astype(self.dtype)               # [N*k, D]
 
         # expert weights: leading expert dim sharded over ep, F over tp
         init = nn.initializers.lecun_normal()
         wi = self.param("wi", init, (E, D, F), jnp.float32)
         wo = self.param("wo", init, (E, F, D), jnp.float32)
-        if self.gated:
-            wg = self.param("wg", init, (E, D, F), jnp.float32)
+        wg = (self.param("wg", init, (E, D, F), jnp.float32)
+              if self.gated else None)
+        ys = moe_ops.expert_mlp(
+            xs, group_sizes, wi.astype(self.dtype), wo.astype(self.dtype),
+            None if wg is None else wg.astype(self.dtype),
+        )
 
-        xe = jnp.einsum("btec,btd->becd", dispatch, x.astype(jnp.float32))
-        # all_to_all: tokens move to their expert's devices
-        xe = _constrain(xe, P("dp", "ep", None, None))
-        xe = xe.astype(self.dtype)
-        h = jnp.einsum("becd,edf->becf", xe, wi.astype(self.dtype))
-        if self.gated:
-            g = jnp.einsum("becd,edf->becf", xe, wg.astype(self.dtype))
-            h = nn.silu(g) * h
-        else:
-            h = nn.gelu(h)
-        ye = jnp.einsum("becf,efd->becd", h, wo.astype(self.dtype))
-        ye = _constrain(ye, P("dp", "ep", None, None))
-        y = jnp.einsum("btec,becd->btd", combine, ye.astype(jnp.float32))
+        with jax.named_scope("moe_combine"):
+            back = jnp.argsort(order)        # where each pair's row went
+            y = (ys[back].reshape(N, k, D).astype(jnp.float32)
+                 * top_gates[..., None]).sum(axis=1)
 
         # load-balance aux loss (Shazeer/GShard): E * sum_e f_e * p_e
-        density = choice_onehot[:, :, 0].mean(axis=1)   # top-1 assignment frac
-        mean_gate = gates.mean(axis=1)                   # [B,E]
+        density = jax.nn.one_hot(
+            top_idx[:, 0].reshape(B, T), E, dtype=jnp.float32
+        ).mean(axis=1)                                   # top-1 assignment frac
+        mean_gate = gates.reshape(B, T, E).mean(axis=1)  # [B,E]
         aux = (density * mean_gate).sum(axis=-1).mean() * E
         self.sow("intermediates", "moe_aux_loss", aux)
-        return y.astype(x.dtype)
+        if not self.is_initializing():   # never part of an init's tree
+            self.sow("moe_routing", "top_idx", top_idx.reshape(B, T, k))
+        return y.reshape(B, T, D).astype(x.dtype)

@@ -170,6 +170,11 @@ def _tp_slice() -> Tuple[Optional[object], str, int]:
     return mesh, axis, int(mesh.shape.get(axis, 1))
 
 
+def tensor_parallel_width() -> int:
+    """Width of the active :func:`tensor_parallel` slice (1: none)."""
+    return _tp_slice()[2]
+
+
 def self_attention(
     q: jax.Array,
     k: jax.Array,
