@@ -232,7 +232,14 @@ class Turn(NamedTuple):
     (experts with at least one real row, summed over layers and substeps)
     and ``moe_max_rows`` (the most rows one expert took in one layer of one
     substep). All 0 for a dense model, for the other programs, and for a
-    chunk group that finished no prompt (nothing of it was fetched)."""
+    chunk group that finished no prompt (nothing of it was fetched).
+
+    A paged engine's scans carry ``kv_pages_live``: the sum over ALL slots,
+    from their cached lengths at the dispatch, of the page-table entries
+    that hold a position the scan's first substep may attend (an idle slot
+    counts its page 0, as the paged kernel does): the part of the table the
+    kernel's scan has to compute. 0 for a slab engine and for the other
+    programs."""
 
     kind: str
     t_dispatch: float
@@ -250,6 +257,7 @@ class Turn(NamedTuple):
     moe_rows: int = 0
     moe_experts_hit: int = 0
     moe_max_rows: int = 0
+    kv_pages_live: int = 0
 
 
 # Sized for the benchmark's 51 s window at several times the cells'
@@ -260,7 +268,8 @@ _ENGINE_ORDINAL = itertools.count()   # numbers the engines of a process
 
 def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
                     span_ms: Optional[float] = None,
-                    longest: int = 8) -> Dict[str, Any]:
+                    longest: int = 8,
+                    table_entries: int = 0) -> Dict[str, Any]:
     """Turn records summed, for an operator asking a slow replica where
     its time goes (the one definition of every quantity read from the
     ring): dispatches and scans held (and ``dropped`` by the bounded ring),
@@ -279,10 +288,20 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
     model's records add ``moe_rows_per_expert`` (rows routed over experts
     hit: how many rows share one read of an expert's weights) and
     ``moe_imbalance`` (the most rows one expert took in a layer of a
-    substep, over that mean)."""
+    substep, over that mean). A paged engine's (``table_entries`` a slot)
+    add ``kv_pages_live`` and ``kv_pages_scanned``, each scan weighed by its
+    substeps (live page-table entries; slots x ``table_entries``), and their
+    ratio ``kv_live_page_share``: how much of the grid the paged kernel
+    walks holds KV a slot attends."""
     scans = [t for t in turns if t.kind == "turn"]
     out: Dict[str, Any] = {"dispatches": len(turns), "scans": len(scans),
                            "dropped": dropped}
+    live = sum(t.kv_pages_live * t.substeps for t in scans)
+    if live and table_entries:
+        out["kv_pages_live"] = live
+        out["kv_pages_scanned"] = num_slots * table_entries * sum(
+            t.substeps for t in scans)
+        out["kv_live_page_share"] = live / out["kv_pages_scanned"]
     hit = sum(t.moe_experts_hit for t in turns)
     if hit:
         out["moe_rows_per_expert"] = sum(t.moe_rows for t in turns) / hit
@@ -1130,15 +1149,19 @@ class DecodeEngine:
     def _log_dispatch(self, kind: str, t_dispatch: float, t_issued: float,
                       t_fetched: float, substeps: int, tokens: int,
                       active: int, trains: int,
-                      moe: Sequence[int] = (0, 0, 0)) -> Turn:
+                      moe: Sequence[int] = (0, 0, 0),
+                      kv_pages_live: int = 0) -> Turn:
         """Append this dispatch's record to the turn ring (its work on the
         host is done: ``t_done`` is now). ``moe``: the dispatch's routing
-        counters as fetched (``Turn``'s last three fields)."""
+        counters as fetched (``Turn``'s ``moe_*`` fields);
+        ``kv_pages_live``: :meth:`_kv_pages_live` as the scan was
+        dispatched."""
         rec = Turn(
             kind, t_dispatch, t_issued, t_fetched, now_ms(),
             substeps, tokens, active, trains, len(self.queue),
             self._allocator.allocated_pages if self.paged else 0,
             int(self._len_host.sum()), self._idled, *(int(c) for c in moe),
+            kv_pages_live,
         )
         if len(self.turns) == self.turns.maxlen:
             self.turns_dropped += 1
@@ -1147,6 +1170,16 @@ class DecodeEngine:
             self._last_scan = rec
         self._idled = False
         return rec
+
+    def _kv_pages_live(self, window: int = 1) -> int:
+        """Page-table entries, summed over all slots, that hold a position
+        the scan about to be dispatched may attend in its first substep:
+        the last row of a ``window`` attends positions <= length +
+        ``window`` - 1 (the paged kernel's own bound). 0 on a slab engine."""
+        if not self.paged:
+            return 0
+        last = (self._len_host + (window - 1)) // self.page_size
+        return int(np.minimum(last + 1, self._n_table_entries).sum())
 
     def _device_ctx(self):
         """The scope everything this engine allocates, traces and
@@ -3882,6 +3915,7 @@ class DecodeEngine:
                 # paged arm, not off a cliff.
                 return self._plain_turn(ph, 1)
             active = int(self._active_mask.sum())
+            kv_pages_live = self._kv_pages_live(k + 1)
             ph.set_metadata(horizon=k, active=active, spec=1)
             try:
                 # From here to the packed fetch, scratch is armed but
@@ -3962,7 +3996,8 @@ class DecodeEngine:
                     blocked_finishes_capacity=False,
                 )
             rec = self._log_dispatch("turn", t_dispatch, t_issued, t_fetched,
-                                     1, 0, active, len(self._trains))
+                                     1, 0, active, len(self._trains),
+                                     kv_pages_live=kv_pages_live)
             if links is not None:
                 self._record_turn_span(rec, links, k, spec=True)
 
@@ -3992,6 +4027,7 @@ class DecodeEngine:
             prev_tokens = self._tokens.copy()  # draft catch-up window head
             active_at_dispatch = self._active_mask.copy()
             active = int(active_at_dispatch.sum())
+            kv_pages_live = self._kv_pages_live()
             samp_f, samp_i, bias_ids_d, bias_vals_d = self._sampling_arrays()
             # ONE per-dispatch upload: tokens / active / sample index.
             state = np.stack([
@@ -4046,7 +4082,8 @@ class DecodeEngine:
         rec = self._log_dispatch(
             "turn", t_dispatch, t_issued, t_fetched, h, 0, active,
             len(self._trains),
-            packed_host[2 * h + 1:, 0] if self._moe_kw else (0, 0, 0))
+            packed_host[2 * h + 1:, 0] if self._moe_kw else (0, 0, 0),
+            kv_pages_live=kv_pages_live)
         if links is not None:
             self._record_turn_span(rec, links, h)
 
@@ -4666,6 +4703,7 @@ class DecodeEngine:
         return summarize_turns(
             list(self.turns.copy()) if records is None else records,
             self.num_slots, self.turns_dropped, span_ms, longest,
+            self._n_table_entries if self.paged else 0,
         )
 
     def snapshot(self) -> Dict[str, Any]:
@@ -4696,7 +4734,11 @@ class DecodeEngine:
             out["num_pages"] = self.num_pages
             out["free_pages"] = self._allocator.free_pages
             out["allocated_pages"] = self._allocator.allocated_pages
-            out["kv_pool"] = dict(self._pool_stats)
+            out["kv_pool"] = dict(
+                self._pool_stats,
+                pages_live=out["turns"].get("kv_pages_live", 0),
+                pages_scanned=out["turns"].get("kv_pages_scanned", 0),
+            )
             out["page_journal"] = {
                 "events": self._page_journal.snapshot(),
                 "journal_total": self._page_journal.total,

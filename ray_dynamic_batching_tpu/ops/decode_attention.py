@@ -135,24 +135,50 @@ def _scan_tile(
     q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref,
     *, valid, scale: float, num_s: int,
 ):
-    """One KV tile of the online-softmax scan — the body shared by the
-    slab kernel (S-axis tiles, mask-derived ``valid``) and the paged
-    kernel (page-table tiles, length-derived ``valid``): init scratch at
-    tile 0, accumulate this tile per head, finalize into the output on
-    the last tile. The math being ONE function is what keeps the paged
-    and slab kernels numerically identical."""
-    kb = q_ref.shape[1]
-    R = q_ref.shape[2]
-    H = q_ref.shape[3]
-    compute_dtype = q_ref.dtype  # int8 codes cast exactly (<= +-127)
-    s_idx = pl.program_id(2)
+    """One KV tile of the online-softmax scan: init scratch at tile 0
+    (:func:`_scan_begin`), accumulate this tile per head
+    (:func:`_accumulate_tile`), finalize into the output on the last
+    tile (:func:`_scan_end`). The slab kernel (S-axis tiles,
+    mask-derived ``valid``) runs it whole on every tile; the paged
+    kernel (page-table tiles, length-derived ``valid``) calls the same
+    three pieces and guards the middle one by the slot's length. The
+    math being ONE function is what keeps the paged and slab kernels
+    numerically identical."""
+    _scan_begin(m_ref, l_ref, acc_ref)
+    _accumulate_tile(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref,
+                     acc_ref, valid=valid, scale=scale)
+    _scan_end(o_ref, m_ref, l_ref, acc_ref, num_s=num_s)
 
-    @pl.when(s_idx == 0)
+
+def _scan_begin(m_ref, l_ref, acc_ref):
+    @pl.when(pl.program_id(2) == 0)
     def _init():
-        m_ref[...] = jnp.full((kb, R), NEG_INF, jnp.float32)
-        l_ref[...] = jnp.zeros((kb, R), jnp.float32)
-        acc_ref[...] = jnp.zeros((kb, R, H), jnp.float32)
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
+
+def _scan_end(o_ref, m_ref, l_ref, acc_ref, *, num_s: int):
+    @pl.when(pl.program_id(2) == num_s - 1)
+    def _finalize():
+        for h in range(acc_ref.shape[0]):
+            l = l_ref[h, :]
+            # A fully-masked row (inactive spec rows are steered out of
+            # bounds; their outputs are never consumed) -> zeros, not NaN.
+            l = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, h, :, :] = (
+                acc_ref[h, :, :] / l[:, None]
+            ).astype(o_ref.dtype)
+
+
+def _accumulate_tile(
+    q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref, acc_ref,
+    *, valid, scale: float,
+):
+    """Fold this grid step's KV tile into the online-softmax scratch,
+    one KV head of the block at a time."""
+    kb = q_ref.shape[1]
+    compute_dtype = q_ref.dtype  # int8 codes cast exactly (<= +-127)
     for h in range(kb):         # static unroll: this program's KV heads
         q = q_ref[0, h, :, :]        # [R, H]
         k_tile = k_ref[0, :, h, :]   # [Sb, H]
@@ -190,17 +216,6 @@ def _scan_tile(
                 preferred_element_type=jnp.float32,
             )
         )  # [R, H]
-
-    @pl.when(s_idx == num_s - 1)
-    def _finalize():
-        for h in range(kb):
-            l = l_ref[h, :]
-            # A fully-masked row (inactive spec rows are steered out of
-            # bounds; their outputs are never consumed) -> zeros, not NaN.
-            l = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0, h, :, :] = (
-                acc_ref[h, :, :] / l[:, None]
-            ).astype(o_ref.dtype)
 
 
 def _pick_heads_block(K: int) -> int:
@@ -354,17 +369,29 @@ def _paged_decode_attention(
     has_scales = k_scale is not None
 
     # The page axis IS the KV tiling: grid step (b, j, p) streams slot
-    # b's p-th page — whichever physical page the PREFETCHED table names
-    # (sentinel/garbage entries clamp to a real page; the length bound
-    # masks everything they could contribute). Pages replace the slab
-    # kernel's S-axis tiles one-for-one, so the online-softmax scratch
-    # carry works unchanged. The LAYER is the block index of a squeezed
-    # leading axis, prefetched like the table: the operand is the pool
-    # itself, so XLA has no layer slice to materialise in front of the
-    # custom call, and the refs the body sees keep their [1, ps, kb, H]
-    # shape.
+    # b's p-th page — whichever physical page the PREFETCHED table names.
+    # Pages replace the slab kernel's S-axis tiles one-for-one, so the
+    # online-softmax scratch carry works unchanged. The LAYER is the
+    # block index of a squeezed leading axis, prefetched like the table:
+    # the operand is the pool itself, so XLA has no layer slice to
+    # materialise in front of the custom call, and the refs the body
+    # sees keep their [1, ps, kb, H] shape.
+    #
+    # The scan STOPS at the slot's length: the last window row attends
+    # pos <= lengths[b] + window - 1, so a page that starts past that is
+    # dead — its step repeats the last live page's block index (Pallas
+    # issues no copy for a repeated block, whatever the table holds
+    # there: the sentinel, or a page allocated ahead of the length) and
+    # skips the arithmetic (``_live_page`` below). Position 0 is always
+    # within the bound, so page 0 is always live. A sentinel/garbage
+    # entry of a live step (an idle slot's page 0) still clamps to a
+    # real page; the length bound masks everything it could contribute.
+    def page_index(b, p, pt, ln):
+        last_live = (ln[b] + (window - 1)) // ps
+        return jnp.minimum(pt[b, jnp.minimum(p, last_live)], P - 1)
+
     def kv_index(b, j, p, pt, ln, ly):
-        return (ly[0], jnp.minimum(pt[b, p], P - 1), 0, j, 0)
+        return (ly[0], page_index(b, p, pt, ln), 0, j, 0)
 
     def q_index(b, j, p, pt, ln, ly):
         return (b, j, 0, 0)
@@ -378,8 +405,7 @@ def _paged_decode_attention(
     if has_scales:
         scale_spec = pl.BlockSpec(
             (1, kb, ps),
-            lambda b, j, p, pt, ln, ly: (
-                jnp.minimum(pt[b, p], P - 1), j, 0),
+            lambda b, j, p, pt, ln, ly: (page_index(b, p, pt, ln), j, 0),
         )
         in_specs += [scale_spec, scale_spec]
         args += [k_scale, v_scale]
@@ -390,18 +416,25 @@ def _paged_decode_attention(
         o_ref, m_ref, l_ref, acc_ref = rest[2 if has_scales else 0:][:4]
         b = pl.program_id(0)
         p = pl.program_id(2)
-        # In-kernel STAIRCASE validity from the prefetched lengths: page
-        # p covers logical positions [p*ps, (p+1)*ps); window row t (row
-        # r = t*G + g) attends pos <= lengths[b] + t — the spec-verify
-        # window rule, whose Tq == 1 degenerate case is exactly the slab
-        # decode_mask bound. No mask array is streamed at all.
-        pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, (R, ps), 1)
-        t_of_row = jax.lax.broadcasted_iota(jnp.int32, (R, ps), 0) // G
-        valid = pos <= len_ref[b] + t_of_row
-        _scan_tile(
-            q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref,
-            acc_ref, valid=valid, scale=scale, num_s=NP,
-        )
+        _scan_begin(m_ref, l_ref, acc_ref)
+
+        @pl.when(p * ps <= len_ref[b] + (window - 1))
+        def _live_page():
+            # In-kernel STAIRCASE validity from the prefetched lengths:
+            # page p covers logical positions [p*ps, (p+1)*ps); window
+            # row t (row r = t*G + g) attends pos <= lengths[b] + t —
+            # the spec-verify window rule, whose Tq == 1 degenerate case
+            # is exactly the slab decode_mask bound. No mask array is
+            # streamed at all.
+            pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, (R, ps), 1)
+            t_of_row = jax.lax.broadcasted_iota(
+                jnp.int32, (R, ps), 0) // G
+            _accumulate_tile(
+                q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref, acc_ref,
+                valid=pos <= len_ref[b] + t_of_row, scale=scale,
+            )
+
+        _scan_end(o_ref, m_ref, l_ref, acc_ref, num_s=NP)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -455,7 +488,10 @@ def paged_decode_attention(
     t attends logical positions <= kv_lengths[b] + t — the STAIRCASE
     rule of the speculative-verify window
     (``models/decoder.py::paged_window_mask`` owns it), whose Tq == 1
-    case is exactly the plain-decode ``decode_mask`` bound.
+    case is exactly the plain-decode ``decode_mask`` bound. The scan
+    stops there: a table entry past ``(kv_lengths[b] + Tq - 1) // ps``
+    costs no copy and no arithmetic whatever it holds, so a slot's scan
+    costs what its live KV costs, not what the table is wide.
     ``k_scale``/``v_scale`` [P, ps, K] (this layer's planes, H times
     smaller than the codes) enable the int8-pool path.
 
@@ -470,8 +506,8 @@ def paged_decode_attention(
     per shard under ``shard_map`` over ``mesh_axis``: q and the pools
     split on the kv-head axis (the slab TP layout — pages are
     shard-invariant, so the page table and lengths replicate), each
-    shard scans its own head slice with the shared ``_scan_tile`` body,
-    and the VMEM guard budgets the PER-SHARD block
+    shard scans its own head slice with the same ``_accumulate_tile``
+    body, and the VMEM guard budgets the PER-SHARD block
     (``tile_math.shard_heads`` — a head-sharded kernel's bytes divide
     by the TP degree). Declines (None) when the head axis does not
     divide — replicated heads fall back to the gather path, which GSPMD

@@ -343,3 +343,70 @@ def test_snapshot_sums_the_ring(lm):
     # a gap is counted only after a dispatch whose result was fetched
     fetched = sum(1 for a in ring[:-1] if a.t_fetched)
     assert len(engine.turn_summary(longest=10 ** 6)["longest_gaps"]) == fetched
+
+
+# --- the ring's count of live page-table entries (ISSUE 28) ----------------------
+def _scan(substeps, live=None):
+    rec = Turn("turn", 0.0, 1.0, 2.0, 3.0, substeps, 0, 2, 0, 0, 8, 100,
+               False)
+    return rec if live is None else rec._replace(kv_pages_live=live)
+
+
+CHUNK = Turn("chunk", 4.0, 5.0, 0.0, 6.0, 0, 512, 2, 1, 0, 8, 100, False)
+
+
+@pytest.mark.parametrize("records, entries, share", [
+    # 4 slots x 8 entries: (6 x 2 + 10 x 8) live of 32 x (2 + 8) walked
+    ([_scan(2, 6), CHUNK, _scan(8, 10)], 8, (6 * 2 + 10 * 8) / (32 * 10)),
+    ([_scan(1, 32), _scan(1, 32)], 8, 1.0),            # every entry live
+    ([_scan(8, 4), _scan(8, 4)], 8, 1 / 8),            # idle: page 0 a slot
+    ([_scan(2), _scan(8)], 8, None),       # records without the field: 0
+    ([_scan(2, 6), _scan(8, 10)], 0, None),            # a slab engine
+    ([CHUNK, CHUNK], 8, None),                         # no scan
+])
+def test_summarize_turns_gives_the_live_page_share_by_hand(
+        records, entries, share):
+    from ray_dynamic_batching_tpu.engine.decode import summarize_turns
+
+    s = summarize_turns(records, 4, table_entries=entries)
+    if share is None:
+        assert not {"kv_pages_live", "kv_pages_scanned",
+                    "kv_live_page_share"} & set(s)
+        return
+    assert s["kv_live_page_share"] == pytest.approx(share)
+    assert s["kv_pages_live"] == sum(
+        t.kv_pages_live * t.substeps for t in records)
+    assert s["kv_pages_scanned"] == 4 * entries * sum(
+        t.substeps for t in records if t.kind == "turn")
+
+
+def test_a_paged_scan_counts_the_entries_its_first_substep_may_attend(lm):
+    """Each slot counts the pages up to its cached length's (an idle slot
+    its first): with one prompt past a page's end, a scan with that slot
+    decoding counts one entry more than there are slots."""
+    engine, queue = _engine(lm, max_len=300)       # 3 entries a slot
+    reqs = _submit(queue, engine.model.name, lens=(5, 140, 9))
+    engine.run_until_idle(timeout_s=300)
+    for r in reqs:
+        r.future.result(timeout=5)
+    scans = [t for t in engine.turns if t.kind == "turn"]
+    assert scans and all(t.kv_pages_live in (4, 5) for t in scans)
+    assert any(t.kv_pages_live == 5 for t in scans)
+    assert all(t.kv_pages_live == 0 for t in engine.turns
+               if t.kind != "turn")
+    snap = engine.snapshot()
+    live = sum(t.kv_pages_live * t.substeps for t in scans)
+    walked = 4 * 3 * sum(t.substeps for t in scans)
+    assert snap["turns"]["kv_live_page_share"] == pytest.approx(live / walked)
+    assert snap["kv_pool"]["pages_live"] == live
+    assert snap["kv_pool"]["pages_scanned"] == walked
+
+
+def test_a_slab_engine_counts_no_pages(lm):
+    engine, queue = _engine(lm, paged=False)
+    reqs = _submit(queue, engine.model.name, lens=(5, 12))
+    engine.run_until_idle(timeout_s=300)
+    for r in reqs:
+        r.future.result(timeout=5)
+    assert engine.turns and all(t.kv_pages_live == 0 for t in engine.turns)
+    assert "kv_live_page_share" not in engine.snapshot()["turns"]

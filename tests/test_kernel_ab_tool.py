@@ -1,0 +1,57 @@
+"""``tools/run_kernel_ab.py --paged``: the cases it times and the arithmetic
+that turns three call times into a live and a dead grid step (ISSUE 28).
+The times themselves come from the chip only."""
+
+import importlib.util
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+_spec = importlib.util.spec_from_file_location(
+    "run_kernel_ab",
+    Path(__file__).resolve().parents[1] / "tools" / "run_kernel_ab.py")
+ab = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(ab)
+
+
+@pytest.mark.parametrize("tag, L, P, B, NP, N, K, H, int8",
+                         ab.PAGED_GEOMETRIES)
+@pytest.mark.parametrize("share", ab.LIVE_SHARES)
+def test_a_case_has_the_stated_share_of_every_table_live(
+        tag, L, P, B, NP, N, K, H, int8, share):
+    table, lengths, live = ab.paged_case(0, B, NP, P, share)
+    assert live == max(1, round(share * NP))
+    assert table.shape == (B, NP) and lengths.shape == (B,)
+    # the kernel's own bound: pages up to the one the length lies in
+    assert (lengths // ab.PAGE + 1 == live).all()
+    assert (table[:, :live] < P).all() and (table[:, live:] == P).all()
+    # the same seed gives the same case
+    again = ab.paged_case(0, B, NP, P, share)
+    assert (again[0] == table).all() and (again[1] == lengths).all()
+
+
+def test_the_geometries_are_the_benchmarks_configurations():
+    import json
+
+    root = Path(__file__).resolve().parents[1]
+    for tag, L, P, B, NP, N, K, H, _ in ab.PAGED_GEOMETRIES[:3]:
+        cfg = json.loads((root / "benchmark" / "configs"
+                          / f"{tag}.json").read_text())
+        dec, llm = cfg["program"]["decoder_config"], cfg["deployment"]["llm"]
+        assert (L, N, K, H) == (dec["num_layers"], dec["num_heads"],
+                                dec["num_kv_heads"],
+                                dec["d_model"] // dec["num_heads"])
+        assert (B, P) == (llm["num_slots"], llm["kv_pool_pages"])
+        assert NP == -(-llm["max_len"] // llm["page_size"])
+        assert llm["page_size"] == ab.PAGE
+
+
+def test_step_costs_solve_the_two_ends():
+    rows = [{"call_us": 32 * 1.75 + 224 * 0.3, "live_steps": 32,
+             "dead_steps": 224},
+            {"call_us": 128 * 1.75 + 128 * 0.3, "live_steps": 128,
+             "dead_steps": 128},
+            {"call_us": 256 * 1.75, "live_steps": 256, "dead_steps": 0}]
+    live, dead = ab.step_costs_us(rows)
+    assert live == pytest.approx(1.75) and dead == pytest.approx(0.3)

@@ -23,7 +23,11 @@ from ray_dynamic_batching_tpu.engine.queue import RequestQueue
 from ray_dynamic_batching_tpu.engine.request import Request
 from ray_dynamic_batching_tpu.models import registry  # noqa: F401
 from ray_dynamic_batching_tpu.models.base import get_model
-from ray_dynamic_batching_tpu.models.decoder import decode_mask, dequantize_kv
+from ray_dynamic_batching_tpu.models.decoder import (
+    decode_mask,
+    dequantize_kv,
+    paged_window_mask,
+)
 from ray_dynamic_batching_tpu.ops import decode_attention as da
 from ray_dynamic_batching_tpu.ops.attention import (
     _xla_attention,
@@ -187,6 +191,134 @@ class TestPagedKernel:
         assert da.paged_decode_attention(
             q9, k, v, pt, lens, interpret=True
         ) is None
+
+
+
+PS, NP_LIVE = 128, 3      # the liveness cases: 3 table entries of 128
+
+
+def liveness_case(dtype, lengths, window, dead, seed=0):
+    """A pool, a table and lengths for the paged kernel's length guard.
+    Slot b holds real pages up to the last one a row of the ``window``
+    attends (``(lengths[b] + window - 1) // PS``); its entries past that
+    are ``dead``: ``"sentinel"`` (unallocated) or ``"nan"`` (allocated
+    pages full of NaN — NaN scale planes for an int8 pool —, which a scan
+    must neither read into its result nor multiply by zero). A length of
+    -1 is an idle slot: length 0 on the device, every entry the sentinel.
+    Returns the kernel's arguments and ``clean``, the same table with
+    every dead entry pointed at a page of finite rows, for references
+    that walk the whole table."""
+    rng = np.random.default_rng(seed)
+    B, N, K, H = len(lengths), 4, 2, 32
+    P = B * NP_LIVE + 3           # + the NaN page, a zero page, page P - 1
+    nan_page, zero_page = P - 3, P - 2
+    q = jnp.asarray(rng.standard_normal((B, window, N, H)), jnp.bfloat16)
+    shape = (P, PS, K, H)
+    ks = vs = None
+    if dtype == jnp.int8:
+        k = rng.integers(-127, 127, shape).astype(np.int8)
+        v = rng.integers(-127, 127, shape).astype(np.int8)
+        ks = rng.uniform(0.01, 0.1, (P, PS, K)).astype(np.float32)
+        vs = rng.uniform(0.01, 0.1, (P, PS, K)).astype(np.float32)
+        ks[nan_page] = vs[nan_page] = np.nan
+        ks, vs = jnp.asarray(ks), jnp.asarray(vs)
+    else:
+        k = rng.standard_normal(shape).astype(np.float32)
+        v = rng.standard_normal(shape).astype(np.float32)
+        k[nan_page] = v[nan_page] = np.nan
+        k[zero_page] = v[zero_page] = 0.0
+    table = np.full((B, NP_LIVE), P, np.int32)
+    clean = np.full((B, NP_LIVE), zero_page, np.int32)
+    for b, n in enumerate(lengths):
+        if n < 0:
+            continue
+        live = min((n + window - 1) // PS + 1, NP_LIVE)
+        table[b, :live] = clean[b, :live] = b * NP_LIVE + np.arange(live)
+        if dead == "nan":
+            table[b, live:] = nan_page
+    lens = jnp.asarray(np.maximum(lengths, 0), jnp.int32)
+    return (q, jnp.asarray(k, dtype), jnp.asarray(v, dtype), ks, vs,
+            jnp.asarray(table), lens, jnp.asarray(clean))
+
+
+def walk_whole_table(q, k, v, ks, vs, clean, lens, *, kernel):
+    """The gather path over ``clean``: each slot's logical rows rebuilt
+    from its table, then attended under the staircase mask — through the
+    slab kernel at one page a tile (``kernel=True``: the paged kernel's
+    own arithmetic, run on EVERY tile), or through XLA's softmax."""
+    B, NP = clean.shape
+    g = lambda pool: pool[clean].reshape((B, NP * PS) + pool.shape[2:])
+    win = paged_window_mask(lens, NP * PS, q.shape[1])
+    if kernel:
+        return da.decode_attention(
+            q, g(k), g(v), mask=win, block_k=PS, interpret=True,
+            k_scale=None if ks is None else g(ks),
+            v_scale=None if vs is None else g(vs))
+    kg, vg = g(k), g(v)
+    if ks is not None:
+        kg = dequantize_kv(kg, g(ks), q.dtype)
+        vg = dequantize_kv(vg, g(vs), q.dtype)
+    return _xla_attention(q, kg, vg, causal=False, mask=win, scale=None)
+
+
+LIVENESS_LENGTHS = {
+    "len0": [0, 0],
+    "page_less_1": [PS - 1, PS - 1],
+    "page": [PS, PS],
+    "page_plus_1": [PS + 1, PS + 1],
+    "capacity_less_1": [NP_LIVE * PS - 1, NP_LIVE * PS - 1],
+    # with an idle slot (-1) and, at window 5, rows that cross a page
+    # edge (PS - 3 + 4) beside rows that stop just short of one (PS - 5)
+    "mixed": [PS + 1, -1, NP_LIVE * PS - 1, PS - 3, PS - 5, 2 * PS - 1],
+}
+
+
+class TestPagedKernelStopsAtTheLength:
+    """A grid step whose page lies wholly past the slot's window does
+    nothing (no copy, no arithmetic): the outputs of every consumed row
+    equal, bit for bit, the same arithmetic run over the whole table
+    (the slab kernel over the gathered rows, a page a tile), and XLA's
+    softmax within bf16's tolerance; an idle slot's rows stay finite."""
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
+                             ids=["bf16", "int8"])
+    @pytest.mark.parametrize("window", [1, 5])
+    @pytest.mark.parametrize("dead", ["sentinel", "nan"])
+    @pytest.mark.parametrize("case", sorted(LIVENESS_LENGTHS))
+    def test_consumed_rows_equal_the_whole_table_walk(
+            self, case, dead, window, dtype):
+        lengths = LIVENESS_LENGTHS[case]
+        q, k, v, ks, vs, table, lens, clean = liveness_case(
+            dtype, lengths, window, dead)
+        out = da.paged_decode_attention(
+            q, k, v, table, lens, k_scale=ks, v_scale=vs, interpret=True)
+        assert out is not None
+        out = np.asarray(out.astype(jnp.float32))
+        assert np.isfinite(out).all()       # idle slots too
+        used = [b for b, n in enumerate(lengths) if n >= 0]
+        whole = walk_whole_table(q, k, v, ks, vs, clean, lens, kernel=True)
+        np.testing.assert_array_equal(
+            out[used], np.asarray(whole.astype(jnp.float32))[used])
+        ref = np.asarray(walk_whole_table(
+            q, k, v, ks, vs, clean, lens, kernel=False
+        ).astype(jnp.float32))[used]
+        # bf16's eight bits, on outputs as large as the int8 codes make them
+        np.testing.assert_allclose(
+            out[used], ref, atol=3e-2 * max(1.0, np.abs(ref).max()),
+            rtol=3e-2)
+
+    def test_entries_past_the_length_may_hold_anything(self):
+        """The index maps stop at the last live page: the block a dead
+        step names is the last live step's, whatever the table holds past
+        the length (here: entries that index far past the pool)."""
+        q, k, v, ks, vs, table, lens, clean = liveness_case(
+            jnp.bfloat16, [PS + 1, 5], 1, "sentinel")
+        wild = jnp.where(table == k.shape[0], 10_000, table)
+        out = da.paged_decode_attention(q, k, v, wild, lens, interpret=True)
+        whole = walk_whole_table(q, k, v, ks, vs, clean, lens, kernel=True)
+        np.testing.assert_array_equal(
+            np.asarray(out.astype(jnp.float32)),
+            np.asarray(whole.astype(jnp.float32)))
 
 
 class TestPoolBehavior:

@@ -97,7 +97,7 @@ class TestTPPagedTokenExactness:
 class TestTPPagedKernel:
     """The shard_map wrapper around the Pallas page-table kernel
     (interpret mode — the CPU-runnable half of the TPU lowering):
-    per-shard head slices through the same ``_scan_tile`` body must
+    per-shard head slices through the same ``_accumulate_tile`` body must
     reproduce the unsharded kernel bit-for-bit, f32 and int8."""
 
     def _mesh_out(self, dtype, eight_devices):
@@ -124,6 +124,40 @@ class TestTPPagedKernel:
 
     def test_tp2_kernel_matches_unsharded_int8(self, eight_devices):
         self._mesh_out(jnp.int8, eight_devices)
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
+                             ids=["bf16", "int8"])
+    def test_tp2_kernel_stops_at_unequal_lengths(self, dtype,
+                                                 eight_devices):
+        """The length guard under ``shard_map``: lengths and table
+        replicate, so every shard skips the same pages. Unequal lengths,
+        an idle slot, a verify window that crosses a page edge, NaN pages
+        past the lengths: the sharded kernel equals the unsharded one bit
+        for bit, and the whole-table walk in every consumed row."""
+        import numpy as np
+
+        from tests.test_paged_decode import (
+            LIVENESS_LENGTHS,
+            liveness_case,
+            walk_whole_table,
+        )
+        from ray_dynamic_batching_tpu.ops import decode_attention as da
+
+        lengths = LIVENESS_LENGTHS["mixed"]
+        q, k, v, ks, vs, table, lens, clean = liveness_case(
+            dtype, lengths, 5, "nan")
+        kw = dict(k_scale=ks, v_scale=vs, interpret=True)
+        base = da.paged_decode_attention(q, k, v, table, lens, **kw)
+        out = da.paged_decode_attention(q, k, v, table, lens,
+                                        mesh=tp2_mesh(), **kw)
+        assert out is not None and base is not None
+        out = np.asarray(out.astype(jnp.float32))
+        np.testing.assert_array_equal(
+            out, np.asarray(base.astype(jnp.float32)))
+        used = [b for b, n in enumerate(lengths) if n >= 0]
+        whole = walk_whole_table(q, k, v, ks, vs, clean, lens, kernel=True)
+        np.testing.assert_array_equal(
+            out[used], np.asarray(whole.astype(jnp.float32))[used])
 
     def test_kernel_declines_indivisible_heads(self, eight_devices):
         """K=4 heads under tp=8 cannot split: the kernel declines and

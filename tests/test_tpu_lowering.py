@@ -174,15 +174,18 @@ class TestDecodeKernelLowersForTPU:
 
 class TestPagedKernelLowersForTPU:
     """The paged kernel's 5-D K/V block — ``(None, 1, ps, kb, Hp)`` on the
-    STACKED pool, the layer a prefetched block index — at the benchmark's
-    two configurations (``benchmark/configs/``): gpt2-medium (24 layers,
-    128 pages x 128, MHA 16x64 in lane-padded 128-wide pool rows, 16
-    slots x 8 table entries) and Mistral 7B cut to 16 layers (160 pages x
-    128, GQA 32/8 x 128, 8 slots x 32 entries)."""
+    STACKED pool, the layer a prefetched block index, and the body's
+    branch on the slot's length — at the benchmark's three configurations
+    (``benchmark/configs/``): gpt2-medium (24 layers, 128 pages x 128,
+    MHA 16x64 in lane-padded 128-wide pool rows, 16 slots x 8 table
+    entries), Mistral 7B cut to 16 layers (160 pages x 128, GQA 32/8 x
+    128, 8 slots x 32 entries) and OLMoE cut to 12 layers (256 pages x
+    128, MHA 16x128, 32 slots x 8 entries)."""
 
     GEOMETRIES = {
         "gpt2-medium": dict(L=24, P=128, B=16, NP=8, N=16, K=16, H=64),
         "mistral-7b": dict(L=16, P=160, B=8, NP=32, N=32, K=8, H=128),
+        "olmoe-1b-7b": dict(L=12, P=256, B=32, NP=8, N=16, K=16, H=128),
     }
 
     @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8])

@@ -15,12 +15,22 @@ discipline (``profiles/profiler.py::timed_steps_ms`` — chained
 dispatches, one scalar fetched at the end), checks max-abs parity
 between the two backends on the same inputs, and writes one JSON record.
 
-Usage: python tools/run_kernel_ab.py [out_dir] [--iters N]
+``--paged`` measures the PAGED kernel alone instead
+(``ops/decode_attention.py::_paged_decode_attention``), at the stacked
+pools of the three benchmark configurations, with an eighth, a half and
+all of each slot's page table live: what a grid step costs whose page
+holds positions the slot attends (live) and one whose page lies past the
+slot's length (dead). One jitted program chains a call a layer, as a
+decode substep does, so the launch is paid once a program and not once a
+kernel. Read every kernel PR's step costs with it (``PERF.md``).
+
+Usage: python tools/run_kernel_ab.py [out_dir] [--iters N] [--paged]
                                      [--only tag1,tag2] [--out-name F]
-Writes <out_dir>/<F> (default kernel_ab.json in profiles/tpu_v5e) and
-prints one JSON summary line. ``--only`` restricts to named geometries
-(a couple of geometries are ~2 compiles each — a short chip call). Exit
-0 only when EVERY selected geometry succeeded on a non-CPU backend.
+Writes <out_dir>/<F> (default kernel_ab.json, paged_steps.json with
+``--paged``, in profiles/tpu_v5e) and prints one JSON summary line.
+``--only`` restricts to named geometries (a couple of geometries are ~2
+compiles each — a short chip call). Exit 0 only when EVERY selected
+geometry succeeded on a non-CPU backend.
 """
 
 from __future__ import annotations
@@ -46,6 +56,178 @@ GEOMETRIES = [
     ("bench_llm_row_int8kv", 64, 1, 16, 64, 256, 16, True),
     ("gqa_s2048_int8kv", 32, 1, 32, 128, 2048, 8, True),
 ]
+
+
+# Paged geometries: the benchmark's configurations (benchmark/configs/),
+# (tag, L layers, P pages, B slots, NP table entries, N, K, H, int8 pool).
+PAGED_GEOMETRIES = [
+    ("gpt2-medium", 24, 128, 16, 8, 16, 16, 64, False),
+    ("mistral-7b-v0.3-1chip", 16, 160, 8, 32, 32, 8, 128, False),
+    ("olmoe-1b-7b-1chip", 12, 256, 32, 8, 16, 16, 128, False),
+    ("gpt2-medium-int8kv", 24, 128, 16, 8, 16, 16, 64, True),
+]
+LIVE_SHARES = (0.125, 0.5, 1.0)
+PAGE = 128
+
+
+def paged_case(seed: int, B: int, NP: int, P: int, share: float):
+    """A page table and lengths with ``max(1, round(share * NP))`` live
+    pages in every slot: the offset inside the last live page drawn from
+    ``seed``, physical pages a permutation of the pool (cycled where the
+    slots' live pages outnumber it), entries past the length the
+    sentinel ``P``."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    live = max(1, round(share * NP))
+    lengths = (live - 1) * PAGE + rng.integers(0, PAGE - 1, B)
+    pages = np.resize(rng.permutation(P), B * live).reshape(B, live)
+    table = np.full((B, NP), P, np.int32)
+    table[:, :live] = pages
+    return table, lengths.astype(np.int32), live
+
+
+def step_costs_us(rows):
+    """Microseconds a live and a dead grid step from one geometry's rows
+    (a row: ``call_us`` of one kernel call, ``live_steps``, ``dead_steps``):
+    live = the fully live call over its grid, dead = what the emptiest
+    call took beyond its live steps over its dead ones. A call's fixed
+    cost is in both (it is ~1% of a 256-step grid)."""
+    full = max(rows, key=lambda r: r["live_steps"])
+    least = min(rows, key=lambda r: r["live_steps"])
+    live_us = full["call_us"] / (full["live_steps"] + full["dead_steps"])
+    return live_us, ((least["call_us"] - least["live_steps"] * live_us)
+                     / least["dead_steps"])
+
+
+def _time_paged(tag, L, P, B, NP, N, K, H, int8, iters):
+    """Rows (one a live share) of the paged kernel's time a call, with
+    its worst gap to the gather path on the same inputs."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_dynamic_batching_tpu.models.decoder import (
+        pool_head_dim,
+        quantize_kv_rows,
+    )
+    from ray_dynamic_batching_tpu.ops import attention as attn
+    from ray_dynamic_batching_tpu.ops.decode_attention import (
+        _pick_heads_block,
+    )
+
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    shape = (L, P, PAGE, K, pool_head_dim(H))
+    k = jax.random.normal(keys[0], shape, jnp.bfloat16)
+    v = jax.random.normal(keys[1], shape, jnp.bfloat16)
+    q = jax.random.normal(keys[2], (B, 1, N, H), jnp.bfloat16)
+    ks = vs = None
+    if int8:
+        k, ks = quantize_kv_rows(k)   # scales [L, P, ps, K]
+        v, vs = quantize_kv_rows(v)
+
+    def chain(layers):
+        """A program of one call a layer of ``layers``, each layer's
+        output the next one's query (one dependent chain, as the decode
+        program's layers are). A fresh callable a call: jit's trace
+        cache is keyed by the function, and the backend it is traced
+        under is not part of the key."""
+        def run(q, k, v, ks, vs, table, lengths):
+            for layer in layers:
+                q = attn.dot_product_attention(
+                    q, k, v, page_table=table, kv_lengths=lengths,
+                    layer=layer,
+                    k_scale=None if ks is None else ks[layer],
+                    v_scale=None if vs is None else vs[layer])
+            return q
+        return jax.jit(run)
+
+    blocks = K // _pick_heads_block(K)
+    cases = []
+    for share in LIVE_SHARES:
+        table, lengths, live = paged_case(0, B, NP, P, share)
+        cases.append((share, live, jnp.asarray(table), jnp.asarray(lengths)))
+    # Each traced once: the table and the lengths are arguments.
+    gather, kernel, program = chain([L - 1]), chain([L - 1]), chain(range(L))
+    attn.set_attention_backend("xla")
+    try:
+        refs = [gather(q, k, v, ks, vs, table, lengths)
+                for _, _, table, lengths in cases]
+    finally:
+        attn.set_attention_backend("auto")
+    rows = []
+    attn.set_attention_backend("pallas")
+    try:
+        for (share, live, table, lengths), ref in zip(cases, refs):
+            out = kernel(q, k, v, ks, vs, table, lengths)
+            program(q, k, v, ks, vs, table, lengths).block_until_ready()
+            samples = []
+            for _ in range(5):
+                t0 = time.perf_counter()
+                for _ in range(iters):
+                    res = program(q, k, v, ks, vs, table, lengths)
+                res.block_until_ready()
+                samples.append(
+                    (time.perf_counter() - t0) * 1e6 / (iters * L))
+            rows.append({
+                "geometry": tag, "live_share": share,
+                "call_us": statistics.median(samples),
+                "call_us_min_max": [min(samples), max(samples)],
+                "live_steps": B * blocks * live,
+                "dead_steps": B * blocks * (NP - live),
+                "max_abs_diff": float(jnp.max(jnp.abs(
+                    out.astype(jnp.float32) - ref.astype(jnp.float32)))),
+            })
+    finally:
+        attn.set_attention_backend("auto")
+    return rows
+
+
+def paged_main(out_dir: str, out_name: str, iters: int, only) -> int:
+    import jax
+
+    backend = jax.default_backend()
+    geometries = [g for g in PAGED_GEOMETRIES if not only or g[0] in only]
+    if not geometries:
+        raise SystemExit(f"--only matched nothing: {only}")
+    record = {"backend": backend,
+              "device_kind": jax.devices()[0].device_kind,
+              "captured": time.strftime("%Y%m%dT%H%M%S"), "iters": iters,
+              "geometries": []}
+    ok = True
+    for g in geometries:
+        try:
+            rows = _time_paged(*g, iters)
+        except Exception as exc:  # noqa: BLE001
+            ok = False
+            record["geometries"].append(
+                {"geometry": g[0], "error": repr(exc)[:500]})
+            print(f"{g[0]}: FAILED {exc!r}", file=sys.stderr, flush=True)
+            continue
+        live_us, dead_us = step_costs_us(rows)
+        record["geometries"].append({
+            "geometry": g[0], "rows": rows,
+            "live_step_us": live_us, "dead_step_us": dead_us})
+        for r in rows:
+            print(f"{g[0]}: live share {r['live_share']:.3f}: "
+                  f"{r['call_us']:.1f} us a call "
+                  f"({r['live_steps']} live + {r['dead_steps']} dead "
+                  f"steps), max |kernel - gather| "
+                  f"{r['max_abs_diff']:.2e}", flush=True)
+        print(f"{g[0]}: a live grid step {live_us:.3f} us, a dead one "
+              f"{dead_us:.3f} us", flush=True)
+        ok = ok and all(r["max_abs_diff"] < 0.1 for r in rows)
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, out_name), "w") as f:
+        json.dump(record, f, indent=1)
+        f.write("\n")
+    print(json.dumps({
+        "metric": "paged_decode_step_us", "backend": backend,
+        "live": {g["geometry"]: g.get("live_step_us")
+                 for g in record["geometries"]},
+        "dead": {g["geometry"]: g.get("dead_step_us")
+                 for g in record["geometries"]},
+    }), flush=True)
+    return 0 if ok and backend != "cpu" else 1
 
 
 def _time_attention(backend: str, q, k, v, mask, iters: int,
@@ -82,6 +264,12 @@ def main() -> int:
     iters = 20
     if "--iters" in sys.argv:
         iters = int(sys.argv[sys.argv.index("--iters") + 1])
+    if "--paged" in sys.argv:
+        only = (set(sys.argv[sys.argv.index("--only") + 1].split(","))
+                if "--only" in sys.argv else None)
+        out_name = (sys.argv[sys.argv.index("--out-name") + 1]
+                    if "--out-name" in sys.argv else "paged_steps.json")
+        return paged_main(out_dir, out_name, iters, only)
     geometries = GEOMETRIES
     if "--only" in sys.argv:
         # A couple of geometries (~2 compiles each) fit a short chip
