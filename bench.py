@@ -146,10 +146,8 @@ def bench_llm_serving(
     max_admissions_per_step: int = 8,
     deployment=None,
     quantize_kv: bool = False,
-    paged: bool = False,
     mesh: int = 1,
     spec: bool = False,
-    prefill: str = "default",
     long_frac: float = 0.0,
 ) -> dict:
     """North star: continuous-batching decode through the serving path.
@@ -160,28 +158,22 @@ def bench_llm_serving(
 
     ``mesh`` > 1 serves through a TP slice of that many chips (ROADMAP
     item 2's A/B axis): the replica gets a ``mesh``-chip device bundle,
-    so the engine runs GSPMD-sharded decode — over the sharded page
-    pool when ``paged`` — and ``tok_s_per_chip`` normalizes by the
+    so the engine runs GSPMD-sharded decode over the sharded page
+    pool, and ``tok_s_per_chip`` normalizes by the
     slice width (whole-slice tokens / chips), the planner's
     per-chip-throughput convention for mesh profile rows.
 
     ``spec`` attaches the ``gpt2_draft`` companion (ISSUE 13's A/B
-    axis; composes with ``paged`` — scratch-page drafts + splice
-    commits — but NOT with ``mesh`` > 1, which the engine rejects
+    axis: scratch-page drafts + splice
+    commits), but NOT with ``mesh`` > 1, which the engine rejects
     loudly). The row stamps the measured ``spec_acceptance`` so a
     capture can never be read without its acceptance context: at ~0
     (untrained draft) the row measures the bounded-degradation floor,
     at a real acceptance it measures the Leviathan multiplier.
 
-    ``prefill`` pins the admission path (ISSUE 15's A/B axis, composes
-    with ``paged``): "chunked" forces the token-budget chunk-train
-    scheduler, "mono" the legacy monolithic groups, "default" the
-    engine's own choice (chunked on paged, mono on slab).
     ``long_frac`` mixes that fraction of OVER-BUCKET prompts (~3x the
-    base prompt) into both phases — the long-prompt traffic whose
-    head-of-line stall the chunked arm exists to remove; the TTFT
-    percentiles of the two arms under the same mix ARE the ISSUE 15
-    measurement.
+    base prompt) into both phases — the long-prompt traffic that admits
+    as multi-chunk trains under the token budget.
     """
     import numpy as np
 
@@ -195,11 +187,6 @@ def bench_llm_serving(
     from ray_dynamic_batching_tpu.serve.router import Router
 
     rng = np.random.default_rng(0)
-    if prefill not in ("default", "mono", "chunked"):
-        raise ValueError(f"prefill must be default|mono|chunked, "
-                         f"got {prefill!r}")
-    chunked_prefill = {"default": None, "mono": False,
-                       "chunked": True}[prefill]
     t_build = time.perf_counter()
     if deployment is None:
         deployment = LLMDeployment(
@@ -211,9 +198,7 @@ def bench_llm_serving(
             decode_horizon=decode_horizon,
             max_admissions_per_step=max_admissions_per_step,
             quantize_kv=quantize_kv,
-            paged=paged,
             draft_model_name="gpt2_draft" if spec else None,
-            chunked_prefill=chunked_prefill,
         )
     devices = None
     slice_pg = slice_mgr = None
@@ -247,8 +232,7 @@ def bench_llm_serving(
          f"(slots={num_slots}, max_len={max_len})")
 
     # Long-prompt mix: over-bucket prompts (~3x base, capped so prompt
-    # + generation fits the cache) that admit as multi-chunk trains on
-    # the chunked arm and monolithic chunked fills on the mono arm.
+    # + generation fits the cache) that admit as multi-chunk trains.
     long_len = min(prompt_len * 3, max_len - max_new_tokens - 1)
 
     def payload():
@@ -267,8 +251,8 @@ def bench_llm_serving(
     elapsed = time.perf_counter() - t0
     total_tokens = sum(len(r.tokens) for r in results)
     # Per-CHIP normalization: a TP slice's whole-slice tok/s divided by
-    # its width — the same convention as mesh profile rows, so slab vs
-    # paged vs TP arms are directly comparable.
+    # its width — the same convention as mesh profile rows, so one-chip
+    # and TP rows are directly comparable.
     tok_s = total_tokens / elapsed / max(1, mesh)
     _log(f"saturation: {total_tokens} tokens / {elapsed:.1f}s = "
          f"{tok_s:.0f} tok/s/chip over {mesh} chip(s) "
@@ -306,10 +290,8 @@ def bench_llm_serving(
     _log(f"poisson @{offered_rps:.1f} rps ({len(ttfts)} reqs): "
          f"TTFT p50={p50:.0f} ms p99={p99:.0f} ms breakdown={breakdown}")
 
-    # Decode KV residency (the paged pool's occupancy win, measured at
-    # the end of the Poisson phase): useful cached tokens over reserved
-    # KV positions — slabs reserve everything up front, pages only what
-    # is live.
+    # Decode KV residency (measured at the end of the Poisson phase):
+    # useful cached tokens over the positions of the allocated pages.
     kv_occupancy = round(replica.engine.kv_occupancy(), 4)
     # Acceptance context for the spec arm (None off / before any round):
     # a spec capture without its acceptance rate is unreadable.
@@ -327,14 +309,11 @@ def bench_llm_serving(
         "num_slots": num_slots,
         "prompt_len": prompt_len,
         "max_new_tokens": max_new_tokens,
-        "paged": paged,
         "mesh": mesh,
         "spec": spec,
         "spec_acceptance": (None if acceptance is None
                             else round(acceptance, 4)),
         "kv_occupancy": kv_occupancy,
-        "prefill": ("chunked" if replica.engine.chunked_prefill
-                    else "mono"),
         "prefill_token_budget": replica.engine.prefill_token_budget,
         "long_frac": long_frac,
     }
@@ -501,34 +480,24 @@ def main() -> dict:
     llm_only = os.environ.get("RDB_BENCH_SCOPE") == "llm"
     # One config dict feeds BOTH llm rows: the int8-KV variant must
     # measure the same configuration as the bf16 row it is compared to.
-    # --paged on (RDB_BENCH_PAGED=1) runs the SAME configuration on the
-    # paged KV pool — the A/B axis against the slab record; the arm is
-    # stamped into every row ("paged") so captures can't be confused.
-    paged = os.environ.get("RDB_BENCH_PAGED") == "1"
     # --mesh N (RDB_BENCH_MESH) serves the llm rows through an N-chip TP
     # slice — ROADMAP item 2's A/B axis (1 = the classic single-chip
-    # record). Composes with --paged: the TP-paged arm is the
-    # mesh-native serving configuration the planner prices.
+    # record).
     mesh = int(os.environ.get("RDB_BENCH_MESH", "1") or 1)
     # --spec on (RDB_BENCH_SPEC=1) attaches the gpt2_draft companion —
-    # ISSUE 13's A/B axis; composes with --paged (scratch-page drafts +
-    # splice commits). The rows stamp the measured acceptance rate.
+    # ISSUE 13's A/B axis (scratch-page drafts + splice commits). The
+    # rows stamp the measured acceptance rate.
     spec = os.environ.get("RDB_BENCH_SPEC") == "1"
-    # --prefill {mono,chunked} (RDB_BENCH_PREFILL) pins the admission
-    # path — ISSUE 15's A/B axis; RDB_BENCH_LONG_FRAC mixes over-bucket
-    # prompts into both phases so the arms measure the head-of-line
-    # stall the token-budget scheduler removes.
-    prefill = os.environ.get("RDB_BENCH_PREFILL", "default") or "default"
+    # RDB_BENCH_LONG_FRAC mixes over-bucket prompts (multi-chunk trains)
+    # into both phases.
     long_frac = float(os.environ.get("RDB_BENCH_LONG_FRAC", "0") or 0)
     llm_kwargs = dict(
         num_slots=8 if fast else 64,
         saturation_requests=16 if fast else 192,
         poisson_duration_s=5.0 if fast else 15.0,
         decode_horizon=8 if fast else 32,
-        paged=paged,
         mesh=mesh,
         spec=spec,
-        prefill=prefill,
         long_frac=long_frac,
     )
     # A row that raises fails the run: no row's failure is caught, so a
@@ -579,10 +548,8 @@ def main() -> dict:
         # The device that produced these numbers, as JAX reports it.
         **stamp,
         "scope": "llm" if llm_only else "fast" if fast else "full",
-        "paged": paged,
         "mesh": mesh,
         "spec": spec,
-        "prefill": llm.get("prefill", prefill),
         "long_frac": long_frac,
         "ttft_p50_ms": llm["ttft_p50_ms"],
         "ttft_p99_ms": llm["ttft_p99_ms"],
@@ -599,45 +566,29 @@ if __name__ == "__main__":
 
     ap = argparse.ArgumentParser()
     ap.add_argument(
-        "--paged", choices=("on", "off"), default=None,
-        help="run the llm serving rows on the paged KV pool (the A/B "
-             "axis vs the slab record; also RDB_BENCH_PAGED=1)",
-    )
-    ap.add_argument(
         "--mesh", type=int, choices=(1, 2, 4), default=None,
         help="serve the llm rows through an N-chip TP slice (the mesh "
              "placement A/B axis, ROADMAP item 2; also "
-             "RDB_BENCH_MESH=N; composes with --paged)",
+             "RDB_BENCH_MESH=N)",
     )
     ap.add_argument(
         "--spec", choices=("on", "off"), default=None,
         help="attach the gpt2_draft speculative companion to the llm "
              "rows (ISSUE 13's A/B axis; also RDB_BENCH_SPEC=1; "
-             "composes with --paged, rows stamp the acceptance rate; "
-             "NOT with --mesh > 1 — the engine rejects paged+spec+mesh)",
-    )
-    ap.add_argument(
-        "--prefill", choices=("mono", "chunked"), default=None,
-        help="pin the llm rows' admission path (ISSUE 15's A/B axis; "
-             "also RDB_BENCH_PREFILL; composes with --paged — chunked "
-             "is the paged engine's default, mono the legacy "
-             "monolithic-group baseline)",
+             "rows stamp the acceptance rate; NOT with --mesh > 1 — "
+             "the engine rejects spec+mesh)",
     )
     ap.add_argument(
         "--long-frac", type=float, default=None,
         help="fraction of over-bucket (~3x) prompts mixed into the llm "
-             "phases (also RDB_BENCH_LONG_FRAC; the long-prompt traffic "
-             "whose TTFT stall the chunked arm removes)",
+             "phases (also RDB_BENCH_LONG_FRAC; they admit as "
+             "multi-chunk trains)",
     )
     cli = ap.parse_args()
-    if cli.paged is not None:
-        os.environ["RDB_BENCH_PAGED"] = "1" if cli.paged == "on" else "0"
     if cli.mesh is not None:
         os.environ["RDB_BENCH_MESH"] = str(cli.mesh)
     if cli.spec is not None:
         os.environ["RDB_BENCH_SPEC"] = "1" if cli.spec == "on" else "0"
-    if cli.prefill is not None:
-        os.environ["RDB_BENCH_PREFILL"] = cli.prefill
     if cli.long_frac is not None:
         os.environ["RDB_BENCH_LONG_FRAC"] = str(cli.long_frac)
     print(json.dumps(main()))

@@ -10,10 +10,9 @@ means. Phases, in order; the first that fails ends the run non-zero:
   device       jax.devices() is a TPU; HBM budget fits what it reports
   kernels      every Pallas attention kernel vs the XLA reference
   serve-paged  paged pool + chunked prefill, HTTP + handle requests
-  serve-slab   constructor defaults (slab cache, monolithic prefill)
   four-chips   four pinned replicas, then one TP=4 replica (>= 4 devices)
 
-Each serve phase asserts zero compiles after warmup and prints the
+The serve phase asserts zero compiles after warmup and prints the
 attention path every hot program compiled to (the *paths* table).
 
 The last line of stdout is one JSON object:
@@ -320,13 +319,10 @@ def phase_kernels(
     return rows
 
 
-# --- phases: serve-paged / serve-slab ---------------------------------------
-# Programs whose attention path the *paths* table must account for, per
-# arm: the decode scan, and the arm's admission program.
-HOT_PROGRAMS = {
-    "paged": ("decode_step", "chunk_prefill"),
-    "slab": ("decode_step", "prefill_group"),
-}
+# --- phase: serve-paged -----------------------------------------------------
+# Programs whose attention path the *paths* table must account for: the
+# decode scan, and the admission (chunk) program.
+HOT_PROGRAMS = ("decode_step", "chunk_prefill")
 
 
 def smoke_requests(vocab: int, n: int, lo: int, hi: int, max_new: int,
@@ -345,16 +341,17 @@ def smoke_requests(vocab: int, n: int, lo: int, hi: int, max_new: int,
     ]
 
 
-def serve_document(model: str, arm: str, *, num_slots: int, max_len: int,
+def serve_document(model: str, *, num_slots: int, max_len: int,
                    prompt_buckets: Sequence[int], max_new: int,
                    num_replicas: int = 1, chips_per_replica: int = 0,
                    llm_options: Optional[Dict[str, Any]] = None,
                    ) -> Dict[str, Any]:
     """The declarative config a user writes (serve/schema.py): one
     application, one built-in ``llm:`` deployment, published on a route.
-    The slab arm sets nothing beyond sizes — the constructor defaults;
-    ``llm_options`` are further ``llm:`` knobs (the four-chip phases
-    trim the number of programs each replica compiles)."""
+    Nothing is set beyond sizes — the constructor defaults are the paged
+    pool with chunked admission; ``llm_options`` are further ``llm:``
+    knobs (the four-chip phases trim the number of programs each replica
+    compiles)."""
     llm: Dict[str, Any] = {
         "model": model,
         "num_slots": num_slots,
@@ -362,19 +359,17 @@ def serve_document(model: str, arm: str, *, num_slots: int, max_len: int,
         "prompt_buckets": list(prompt_buckets),
         "default_max_new_tokens": max_new,
     }
-    if arm == "paged":
-        llm["paged"] = True  # chunked prefill is the paged default
     llm.update(llm_options or {})
     deployment: Dict[str, Any] = {
-        "name": f"{model}-{arm}", "llm": llm,
+        "name": f"{model}-smoke", "llm": llm,
         "num_replicas": num_replicas,
         "max_ongoing_requests": 4096,
     }
     if chips_per_replica:
         deployment["chips_per_replica"] = chips_per_replica
     return {"applications": [{
-        "name": f"smoke-{arm}",
-        "route_prefix": f"/smoke/{arm}",
+        "name": "smoke",
+        "route_prefix": "/smoke",
         "deployments": [deployment],
     }]}
 
@@ -407,9 +402,9 @@ def _http_generate(host: str, port: int, path: str, payload: Dict[str, Any],
     return finals[0]
 
 
-def report_paths(arm: str, allow_interpret: bool) -> List[Dict[str, Any]]:
-    """The *paths* table: for every program that traced since the arm
-    was deployed, which attention path each call site compiled to and
+def report_paths(allow_interpret: bool) -> List[Dict[str, Any]]:
+    """The *paths* table: for every program that traced since the
+    deployment came up, which attention path each call site compiled to and
     why any kernel declined — read from the dispatcher's trace-time
     record, not guessed from shapes. Fails when a hot program took the
     XLA reference, ran a kernel interpreted, or left no record."""
@@ -432,40 +427,40 @@ def report_paths(arm: str, allow_interpret: bool) -> List[Dict[str, Any]]:
         row["calls"] += 1
     rows = list(table.values())
     for row in rows:
-        _say(f"paths[{arm}]: {row['program'] or '<no program>':14s} "
+        _say(f"paths: {row['program'] or '<no program>':14s} "
              f"q{row['q']} kv{row['kv']} {row['kv_dtype']} x{row['calls']}"
              f" -> {row['path']}")
         for reason in row["declines"]:
-            _say(f"paths[{arm}]:     declined: {reason}")
-    hot = [r for r in records if r.program in HOT_PROGRAMS[arm]]
+            _say(f"paths:     declined: {reason}")
+    hot = [r for r in records if r.program in HOT_PROGRAMS]
     interpreted = sum(r.interpret for r in hot)
-    _say(f"paths[{arm}]: {interpreted} of {len(hot)} hot-program attention "
+    _say(f"paths: {interpreted} of {len(hot)} hot-program attention "
          "calls ran interpreted")
     on_xla = sorted({r.program for r in hot if r.path == PATH_XLA})
     if on_xla:
         raise PhaseFailed(
-            f"{arm}: {on_xla} compiled to the XLA reference at this "
+            f"{on_xla} compiled to the XLA reference at this "
             "geometry (reasons above)")
-    missing = set(HOT_PROGRAMS[arm]) - {r.program for r in hot}
+    missing = set(HOT_PROGRAMS) - {r.program for r in hot}
     if missing:
         raise PhaseFailed(
-            f"{arm}: no attention path recorded for {sorted(missing)}")
+            f"no attention path recorded for {sorted(missing)}")
     if interpreted and not allow_interpret:
         raise PhaseFailed(
-            f"{arm}: {interpreted} hot-program kernel calls ran in "
+            f"{interpreted} hot-program kernel calls ran in "
             "interpret mode")
     return rows
 
 
 def phase_serve(
-    arm: str, model: str, *, num_slots: int, max_len: int,
+    model: str, *, num_slots: int, max_len: int,
     prompt_buckets: Sequence[int], requests: List[Dict[str, Any]],
     http_requests: int = 6, timeout_s: float = 300.0,
     allow_interpret: bool = False, controller: Any = None,
     num_replicas: int = 1, chips_per_replica: int = 0,
     llm_options: Optional[Dict[str, Any]] = None, inspect: Any = None,
 ) -> Dict[str, Any]:
-    """Deploy one arm the way the README does, answer the request set
+    """Deploy the model the way the README does, answer the request set
     (the first ``http_requests`` over HTTP, the first of those
     streaming; the rest through the DeploymentHandle), and check it:
     every request succeeded with the tokens asked for and a TTFT, zero
@@ -484,14 +479,12 @@ def phase_serve(
         get_ledger,
     )
 
-    if arm not in HOT_PROGRAMS:
-        raise ValueError(f"arm must be one of {sorted(HOT_PROGRAMS)}")
     ledger = get_ledger()
     clear_attention_paths()
     warm_before = sum(ledger.counts(phase=PHASE_WARMUP).values())
     max_new = requests[0]["max_new_tokens"]
     doc = serve_document(
-        model, arm, num_slots=num_slots, max_len=max_len,
+        model, num_slots=num_slots, max_len=max_len,
         prompt_buckets=prompt_buckets, max_new=max_new,
         num_replicas=num_replicas, chips_per_replica=chips_per_replica,
         llm_options=llm_options,
@@ -505,7 +498,7 @@ def phase_serve(
     try:
         ready = time.perf_counter()
         out: Dict[str, Any] = {
-            "arm": arm,
+            "replicas": f"{num_replicas} x {max(1, chips_per_replica)}",
             "setup_s": round(ready - t_deploy, 1),
             "ready_since_process_start_s": round(ready - _T0, 1),
             "warmup_compile_episodes": sum(
@@ -514,8 +507,8 @@ def phase_serve(
         running = len(handle.router.replicas())
         if running != num_replicas:
             raise PhaseFailed(
-                f"{arm}: {running} of {num_replicas} replicas started")
-        _say(f"serve[{arm}]: ready in {out['setup_s']}s "
+                f"serve: {running} of {num_replicas} replicas started")
+        _say(f"serve: ready in {out['setup_s']}s "
              f"({out['ready_since_process_start_s']}s since process start),"
              f" {out['warmup_compile_episodes']} warmup compile episodes, "
              f"{running} replica(s)")
@@ -553,16 +546,16 @@ def phase_serve(
             if (len(toks) != req["max_new_tokens"]
                     or res["finish_reason"] != "length"):
                 raise PhaseFailed(
-                    f"{arm}: request {i} returned {len(toks)} tokens, "
+                    f"serve: request {i} returned {len(toks)} tokens, "
                     f"finish_reason {res['finish_reason']!r}; asked for "
                     f"{req['max_new_tokens']}")
             if not all(isinstance(t, int) and t >= 0 for t in toks):
-                raise PhaseFailed(f"{arm}: request {i} token ids {toks}")
+                raise PhaseFailed(f"serve: request {i} token ids {toks}")
             ttft = res["ttft_ms"]
             if not (isinstance(ttft, (int, float)) and 0 < ttft < 1e7):
-                raise PhaseFailed(f"{arm}: request {i} TTFT {ttft!r}")
+                raise PhaseFailed(f"serve: request {i} TTFT {ttft!r}")
         out["tokens"] = [res["tokens"] for res in results]
-        _say(f"serve[{arm}]: {len(results)} requests answered "
+        _say(f"serve: {len(results)} requests answered "
              f"({n_http} over HTTP, 1 streaming) in {out['serve_s']}s")
 
         # Zero compiles after warmup — NOT softened: this is what catches
@@ -570,8 +563,8 @@ def phase_serve(
         # (the engine loop would retry it for ever) and a leaf whose
         # placement drifted between warmup and serving.
         ledger.check_steady()
-        _say(f"serve[{arm}]: zero compiles after warmup")
-        out["paths"] = report_paths(arm, allow_interpret)
+        _say(f"serve: zero compiles after warmup")
+        out["paths"] = report_paths(allow_interpret)
         if inspect is not None:
             inspect(handle)
         return out
@@ -678,14 +671,14 @@ def phase_four_chips(
 
     try:
         pinned = phase_serve(
-            "paged", model, num_slots=num_slots, max_len=max_len,
+            model, num_slots=num_slots, max_len=max_len,
             prompt_buckets=prompt_buckets, requests=requests,
             timeout_s=timeout_s, allow_interpret=allow_interpret,
             controller=ctl, num_replicas=4, chips_per_replica=1,
             llm_options=llm_options, inspect=keep_one_chip,
         )
         served_tp = phase_serve(
-            "paged", model, num_slots=num_slots, max_len=max_len,
+            model, num_slots=num_slots, max_len=max_len,
             prompt_buckets=prompt_buckets, requests=requests,
             timeout_s=timeout_s, allow_interpret=allow_interpret,
             controller=ctl, num_replicas=1, chips_per_replica=tp,
@@ -737,12 +730,9 @@ MAX_LEN = 512           # four 128-position pages per slot
 MAX_NEW = 32
 N_REQUESTS = 36
 PROMPT_LO, PROMPT_HI = 16, 200
-# Paged/chunked: prompts over 128 admit as two-chunk trains. Slab/mono:
-# every prompt fits a bucket (an over-bucket prompt there rides the
-# long-chunk programs, which the mono arm compiles lazily by design).
-# Warmup compiles buckets x group sizes {1, 2} + three decode horizons.
+# Prompts over 128 admit as two-chunk trains. Warmup compiles buckets x
+# group sizes {1, 2} + three decode horizons.
 PAGED_BUCKETS = (64, 128)
-SLAB_BUCKETS = (128, 256)
 # Five replicas each compile their own programs (an executable is keyed
 # on its devices), about half a minute apiece cold at this depth: one
 # bucket, one group width and two decode horizons make it three per
@@ -750,7 +740,7 @@ SLAB_BUCKETS = (128, 256)
 FOUR_CHIP_BUCKETS = (128,)
 FOUR_CHIP_LLM = {"max_admissions_per_step": 1, "decode_horizon": 2}
 
-PHASES = ("device", "kernels", "serve-paged", "serve-slab", "four-chips")
+PHASES = ("device", "kernels", "serve-paged", "four-chips")
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
@@ -777,30 +767,26 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             causal_lm.GPT2_MEDIUM.vocab_size, N_REQUESTS, PROMPT_LO,
             PROMPT_HI, MAX_NEW,
         )
-        arms = []
+        served = []
         if "serve-paged" in want:
-            arms.append(phase_serve(
-                "paged", MODEL, num_slots=NUM_SLOTS, max_len=MAX_LEN,
+            served.append(phase_serve(
+                MODEL, num_slots=NUM_SLOTS, max_len=MAX_LEN,
                 prompt_buckets=PAGED_BUCKETS, requests=requests))
-        if "serve-slab" in want:
-            arms.append(phase_serve(
-                "slab", MODEL, num_slots=NUM_SLOTS, max_len=MAX_LEN,
-                prompt_buckets=SLAB_BUCKETS, requests=requests))
         if "four-chips" in want:
             if stamp["count"] >= 4:
                 four = phase_four_chips(
                     MODEL, num_slots=NUM_SLOTS, max_len=MAX_LEN,
                     prompt_buckets=FOUR_CHIP_BUCKETS, requests=requests,
                     llm_options=FOUR_CHIP_LLM)
-                arms += [four["pinned"], four["tp"]]
+                served += [four["pinned"], four["tp"]]
             else:
                 _say(f"four-chips: SKIPPED — {stamp['count']} device(s) "
                      "visible, the phase needs 4 (four pinned replicas, "
                      "then one TP=4 replica)")
-        for arm in arms:
-            _say(f"set-up: {arm['arm']:6s} {arm['setup_s']}s "
-                 f"(ready {arm['ready_since_process_start_s']}s after "
-                 f"process start), {arm['warmup_compile_episodes']} "
+        for out in served:
+            _say(f"set-up: {out['replicas']} chip(s) {out['setup_s']}s "
+                 f"(ready {out['ready_since_process_start_s']}s after "
+                 f"process start), {out['warmup_compile_episodes']} "
                  "warmup compile episodes")
     except Exception:  # noqa: BLE001 — any failed phase is a failed run
         import traceback

@@ -80,7 +80,9 @@ class HostedEngine:
         return float(f) if f else 1.0
 
     def has_work(self) -> bool:
-        if self.engine.active_slots > 0:
+        # ``busy`` counts chunk trains too: dequeued, holding a slot, no
+        # token yet — neither queued nor active.
+        if self.engine.busy:
             return True
         return not self.draining and len(self.engine.queue) > 0
 
@@ -282,17 +284,19 @@ class ColocatedLLMEngines:
 
     # --- execution ---------------------------------------------------------
     def _turn(self, hosted: HostedEngine) -> Tuple[bool, float]:
-        """One scheduling quantum for one engine: admit (unless draining),
-        then at most one compiled scan. Returns (compute ran, cost ms) —
-        cost EXCLUDES co-tenant scans that ran via between-chunk yields
-        inside this turn (they bill their own engines)."""
+        """One scheduling quantum for one engine — the engine loop's own
+        body: admit (unless draining), one prefill budget's worth of
+        chunk dispatches, then at most one compiled scan. Returns
+        (compute ran, cost ms) — cost EXCLUDES co-tenant scans that ran
+        via between-chunk yields inside this turn (they bill their own
+        engines)."""
         t0 = time.perf_counter()
         nested0 = self._nested_ms
         engine = hosted.engine
-        stepped = False
         with engine._device_ctx():
             if not hosted.draining:
                 engine._admit()
+            stepped = engine._pump_prefill() > 0
             if engine._active_mask.any():
                 engine._step()
                 stepped = True
@@ -339,7 +343,7 @@ class ColocatedLLMEngines:
 
     def _finalize_drains(self, hosted) -> None:
         for key, h in hosted:
-            if h.draining and h.engine.active_slots == 0:
+            if h.draining and not h.engine.busy:
                 with self._lock:
                     self._release(h)
                     # Pop by identity: a concurrent attach may have put a
@@ -418,7 +422,7 @@ class ColocatedLLMEngines:
             progressed = self.step_once()
             with self._lock:
                 idle = all(
-                    h.engine.active_slots == 0 and len(h.engine.queue) == 0
+                    not h.engine.busy and len(h.engine.queue) == 0
                     for h in self._hosted.values()
                 )
             if idle and not progressed:

@@ -10,16 +10,19 @@ steps (Orca-style continuous batching).
 TPU-first design — everything is static-shape so exactly TWO kinds of
 compiled programs serve the whole stream:
 
-- ``prefill[T]``: one per prompt-length bucket T. Runs the prompt on a fresh
-  single-row cache, scatters the full row into the big decode cache at a
-  *traced* slot index (``lax.dynamic_update_slice`` — no recompile per slot),
-  and returns the first sampled token.
+- ``chunk_prefill[g, W]``: one per (group width, chunk width). Runs the
+  next ``<=W`` prompt tokens of up to ``g`` admissions and scatters their
+  k/v straight through each admission's page-table row into the shared
+  page pool (``engine/paging.py``: one pool of lane-aligned pages behind
+  per-slot page tables, prefix and session reuse by page reference), and
+  returns the first sampled token of every row whose prompt ends in this
+  chunk.
 - ``decode_step``: one program for all ``num_slots`` slots, every step.
   Inactive slots are masked, their scatters dropped. Greedy sampling happens
   *in-program* (argmax over vocab) so only ``[B]`` token ids — not ``[B, V]``
   logits — cross the device→host boundary per step.
 
-The big cache is **donated** through both programs, so XLA updates it in
+The pool is **donated** through both programs, so XLA updates it in
 place in HBM — zero realloc, zero copy per token (SURVEY.md §7 hard part (e)).
 Admission between steps pulls from the shared :class:`RequestQueue`, keeping
 the Nexus staleness-discard and SLO accounting on the decode path too.
@@ -33,10 +36,9 @@ Two throughput levers on the hot loop:
   EOS mid-horizon produce discarded tokens for the remainder — bounded waste
   traded for sync amortization. With free slots and a non-empty queue the
   engine drops to single steps so admissions stay prompt.
-- **Token-budgeted chunked admission** (paged engines; slab opt-in via
-  ``chunked_prefill=True``): EVERY admission is a chunk train — the
-  prompt split into compiled ``<=C``-token chunk programs whose k/v
-  scatter straight through the slot's page table (pages granted per
+- **Token-budgeted chunked admission**: EVERY admission is a chunk train
+  — the prompt split into compiled ``<=C``-token chunk programs whose
+  k/v scatter straight through the slot's page table (pages granted per
   chunk from the shared allocator, CoW-borrowed prefix pages skipped) —
   and the engine's own step loop spends at most
   ``prefill_token_budget`` tokens advancing pending trains between
@@ -45,10 +47,7 @@ Two throughput levers on the hot loop:
   bound is ONE chunk program per decode turn, regardless of how much
   prefill is queued. The final chunk program samples the first token
   in-program, so TTFT ends at a ``[B]`` ids fetch — never a logits
-  round-trip. Engines running the legacy monolithic path instead ration
-  admissions by count (``max_admissions_per_step`` prefills between
-  decode steps), which merely bounds how MANY full-prompt programs
-  stall each round.
+  round-trip.
 
 Streaming: requests carrying a :class:`~.request.TokenStream` get every
 token pushed as it reaches the host, before the sequence finishes (ref
@@ -88,7 +87,6 @@ from ray_dynamic_batching_tpu.engine.request import (
 )
 from ray_dynamic_batching_tpu.engine.paging import (
     HostSpillTier,
-    OutOfPages,
     PageAllocator,
     PagedPrefixCache,
     PagedSessionCache,
@@ -173,9 +171,9 @@ class _Slot:
     stop: frozenset = frozenset()  # per-request stop token ids
     session_id: Optional[str] = None        # store row on finish
     prompt_tokens: Optional[np.ndarray] = None  # session history head
-    # Paged mode: physical page ids in logical order; the first
-    # ``shared_pages`` of them are borrowed (refcounted) from a
-    # prefix/session entry and are never written by this slot.
+    # Physical page ids in logical order; the first ``shared_pages`` of
+    # them are borrowed (refcounted) from a prefix/session entry and are
+    # never written by this slot.
     pages: List[int] = field(default_factory=list)
     shared_pages: int = 0
 
@@ -188,11 +186,11 @@ class _Slot:
 class _ChunkTrain:
     """One admission mid-chunked-prefill: the unit the token-budget
     scheduler advances between decode turns. The train HOLDS its slot
-    (``_free_slots`` excludes it) and — paged — the pages granted so
-    far (``opts['_pages']``: CoW-borrowed head + per-chunk grants);
+    (``_free_slots`` excludes it) and the pages granted so far
+    (``opts['_pages']``: CoW-borrowed head + per-chunk grants);
     ``pos`` is the next global position to prefill, ``base`` the first
     position this train computes (positions below it were seeded from
-    borrowed prefix/session pages, or a slab session row)."""
+    borrowed prefix/session pages)."""
 
     req: Request
     prompt: np.ndarray
@@ -202,18 +200,13 @@ class _ChunkTrain:
     pos: int = 0           # next global position to prefill
     base: int = 0          # first computed position (CoW/session skip)
     total: int = 0         # prompt length (prefill ends here)
-    row: Any = None        # slab mode: private row cache
-    last: Any = None       # slab mode: last chunk's take-row logits
-    insert_prefix: bool = False  # slab: publish chunk 0 on completion
     started_ms: float = 0.0
 
 
 class Turn(NamedTuple):
     """One device dispatch of the engine, as the engine thread saw it: a
     record of ``DecodeEngine.turns``. ``kind`` is ``"turn"`` (a decode or
-    speculative scan), ``"chunk"`` (a chunk group, or one slab chunk) or
-    ``"prefill"`` (a monolithic group prefill); the legacy long/session
-    fills show only as the turns they interleave. Stamps are ``now_ms()``
+    speculative scan) or ``"chunk"`` (a chunk group). Stamps are ``now_ms()``
     on the engine thread, in order: ``t_dispatch``, ``t_issued`` (the
     jitted call returned), ``t_fetched`` (the result reached the host;
     0.0 where nothing was fetched — a non-final chunk — so the device may
@@ -231,27 +224,26 @@ class Turn(NamedTuple):
     (token-expert pairs routed, all layers and substeps), ``moe_experts_hit``
     (experts with at least one real row, summed over layers and substeps)
     and ``moe_max_rows`` (the most rows one expert took in one layer of one
-    substep). All 0 for a dense model, for the other programs, and for a
-    chunk group that finished no prompt (nothing of it was fetched).
+    substep). All 0 for a dense model and for a chunk group that finished
+    no prompt (nothing of it was fetched).
 
-    A paged engine's scans carry ``kv_pages_live``: the sum over ALL slots,
+    A scan carries ``kv_pages_live``: the sum over ALL slots,
     from their cached lengths at the dispatch, of the page-table entries
     that hold a position the scan's first substep may attend (an idle slot
     counts its page 0, as the paged kernel does): the part of the table the
-    kernel's scan has to compute. 0 for a slab engine and for the other
-    programs."""
+    kernel's scan has to compute. 0 for a chunk group."""
 
     kind: str
     t_dispatch: float
     t_issued: float
     t_fetched: float
     t_done: float
-    substeps: int           # decode substeps (0 for a chunk / prefill)
+    substeps: int           # decode substeps (0 for a chunk)
     tokens: int             # prefill tokens (0 for a turn)
     active: int             # decoding slots at dispatch
     trains: int             # chunk trains pending at dispatch
     queue_len: int          # requests queued at t_done
-    pages_allocated: int    # paged pool at t_done (0 on a slab engine)
+    pages_allocated: int    # pages of the pool held at t_done
     positions_cached: int   # sum of the slots' cached lengths at t_done
     after_idle: bool        # an idle wait lay between this and the last
     moe_rows: int = 0
@@ -288,7 +280,7 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
     model's records add ``moe_rows_per_expert`` (rows routed over experts
     hit: how many rows share one read of an expert's weights) and
     ``moe_imbalance`` (the most rows one expert took in a layer of a
-    substep, over that mean). A paged engine's (``table_entries`` a slot)
+    substep, over that mean). With ``table_entries`` (a slot's) they
     add ``kv_pages_live`` and ``kv_pages_scanned``, each scan weighed by its
     substeps (live page-table entries; slots x ``table_entries``), and their
     ratio ``kv_live_page_share``: how much of the grid the paged kernel
@@ -351,10 +343,10 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
     return out
 
 
-# Speculation observability (ISSUE 13 satellite): the ``paged`` tag
-# ("true"/"false") splits the slab and paged spec arms so an A/B capture
-# can never conflate them; accepted + rejected == drafted is a per-round
-# conservation invariant pinned in tier-1 (tests/test_spec_paged.py).
+# Speculation observability (ISSUE 13 satellite): accepted + rejected ==
+# drafted is a per-round conservation invariant pinned in tier-1
+# (tests/test_spec_paged.py). The ``paged`` tag is always "true": kept so
+# the series operators' dashboards already select on do not change name.
 SPEC_ROUNDS = m.Counter(
     "rdb_decode_spec_rounds_total", "Speculative verify rounds",
     tag_keys=("model", "paged"),
@@ -378,8 +370,8 @@ SPEC_ACCEPTANCE = m.Gauge(
 )
 PREFIX_HITS = m.Counter(
     "rdb_decode_prefix_hits_total", "Prompt-prefix KV cache hits",
-    # granularity: "chunk" = slab whole-segment byte equality, "page" =
-    # paged longest-shared-page-prefix (ISSUE 7 satellite).
+    # granularity is always "page" (longest shared page-prefix); the tag
+    # stays so the series keeps its name.
     tag_keys=("model", "granularity"),
 )
 PREFIX_MISSES = m.Counter(
@@ -417,41 +409,10 @@ PREFILL_PENDING = m.Gauge(
 )
 
 
-def copy_rows_into(cache, rows, slots):
-    """Scatter a row-cache's per-request rows into the shared cache at
-    ``slots`` (static unroll — row count is a compile-time constant).
-    Shared by the target and draft prefill programs so the write rule
-    cannot diverge between them. Quantized caches carry their scale
-    planes through the same scatter — dropping them would reconstruct
-    garbage KV for every admitted prompt."""
-    nB = rows.lengths.shape[0]
-    k, v, lengths = cache.k, cache.v, cache.lengths
-    ks, vs = cache.k_scale, cache.v_scale
-    for i in range(nB):
-        k = jax.lax.dynamic_update_slice(
-            k, rows.k[:, i : i + 1], (0, slots[i], 0, 0, 0)
-        )
-        v = jax.lax.dynamic_update_slice(
-            v, rows.v[:, i : i + 1], (0, slots[i], 0, 0, 0)
-        )
-        if ks is not None:
-            ks = jax.lax.dynamic_update_slice(
-                ks, rows.k_scale[:, i : i + 1], (0, slots[i], 0, 0)
-            )
-            vs = jax.lax.dynamic_update_slice(
-                vs, rows.v_scale[:, i : i + 1], (0, slots[i], 0, 0)
-            )
-        lengths = jax.lax.dynamic_update_slice(
-            lengths, rows.lengths[i : i + 1], (slots[i],)
-        )
-    return cache.replace(k=k, v=v, lengths=lengths,
-                         k_scale=ks, v_scale=vs)
-
-
 def commit_row(cache, row, slot):
     """Copy a single finished row cache into the shared cache at ``slot``,
     slicing the (whole-chunk-rounded, possibly longer) row down to shared
-    capacity. Shared by the target and draft chunked-prefill commits."""
+    capacity: the commit of the draft model's prompt replay."""
     S = cache.capacity
     k = jax.lax.dynamic_update_slice(
         cache.k, row.k[:, :, :S], (0, slot, 0, 0, 0)
@@ -474,60 +435,15 @@ def commit_row(cache, row, slot):
                          k_scale=ks, v_scale=vs)
 
 
-def _row_as_pages(arr, S: int, ps: int):
-    """[L, nB, rowcap, ...] row-cache array -> [L, nB*NP, ps, ...] page
-    stack covering the first ``S`` positions (rowcap >= S by the paged
-    row-capacity rule; S is a whole number of pages)."""
-    L, nB = arr.shape[0], arr.shape[1]
-    sliced = arr[:, :, :S]
-    return sliced.reshape((L, nB * (S // ps), ps) + arr.shape[3:])
-
-
-def copy_rows_into_paged(cache, rows, slots, write_pids):
-    """Scatter per-request row caches into the PAGED pool: each row is
-    cut into page-size pieces and lands at the physical pages
-    ``write_pids`` names ([nB, NP] int32; sentinel entries — shared
-    CoW pages and unallocated tail — steer out of bounds and DROP, so
-    a borrowed prefix page is never rewritten). ``slots`` places the
-    per-slot lengths. The paged analogue of :func:`copy_rows_into`;
-    duplicate pad rows write identical data to identical pages, which
-    stays idempotent."""
-    S = cache.page_table.shape[1] * cache.page_size
-    ps = cache.page_size
-    flat = write_pids.reshape(-1)
-    # Pool rows are lane-padded (models/decoder.py::pool_head_dim).
-    Hp = cache.k.shape[-1]
-    k = cache.k.at[:, flat].set(
-        fit_head_dim(_row_as_pages(rows.k, S, ps), Hp), mode="drop")
-    v = cache.v.at[:, flat].set(
-        fit_head_dim(_row_as_pages(rows.v, S, ps), Hp), mode="drop")
-    ks, vs = cache.k_scale, cache.v_scale
-    if ks is not None:
-        ks = ks.at[:, flat].set(
-            _row_as_pages(rows.k_scale, S, ps), mode="drop"
-        )
-        vs = vs.at[:, flat].set(
-            _row_as_pages(rows.v_scale, S, ps), mode="drop"
-        )
-    lengths = cache.lengths.at[slots].set(rows.lengths)
-    return cache.replace(k=k, v=v, lengths=lengths,
-                         k_scale=ks, v_scale=vs)
-
-
-def run_chunked(chunk_fn, params, prompt, C, row, start_chunk=0,
-                between=None, after_first=None, base=0):
-    """Host loop driving a compiled chunk program over a (tail of a)
-    prompt: full-width chunks, right-padded tail, optional ``between``
-    callback after every non-final chunk (the decode-interleave hook) and
-    ``after_first`` on chunk 0 (the prefix-cache insert hook). ``base`` is
-    the global position of ``prompt[0]`` — nonzero when earlier positions
-    were seeded from cached KV (session continuation), and need not be
-    chunk-aligned (the chunk program takes a traced start). Returns
-    (last_logits, row)."""
+def run_chunked(chunk_fn, params, prompt, C, row, between=None):
+    """Host loop driving a compiled chunk program over a prompt on a row
+    cache: full-width chunks, right-padded tail, optional ``between``
+    callback after every non-final chunk (the decode-interleave hook).
+    Returns (last_logits, row)."""
     L = int(prompt.size)
     n_chunks = (L + C - 1) // C
     last = None
-    for ci in range(start_chunk, n_chunks):
+    for ci in range(n_chunks):
         piece = prompt[ci * C : (ci + 1) * C]
         tokens = np.zeros((1, C), dtype=np.int32)
         mask = np.zeros((1, C), dtype=np.int32)
@@ -538,76 +454,12 @@ def run_chunked(chunk_fn, params, prompt, C, row, start_chunk=0,
             jnp.asarray(tokens),
             jnp.asarray(mask),
             row,
-            jnp.int32(base + ci * C),
+            jnp.int32(ci * C),
             jnp.int32(piece.size - 1),
         )
-        if ci == 0 and after_first is not None:
-            after_first(row)
         if ci < n_chunks - 1 and between is not None:
             between()
     return last, row
-
-
-class _DeviceLRU:
-    """Bounded LRU whose values hold DEVICE arrays: dropping the last
-    reference on eviction frees the HBM on GC. Shared mechanics for the
-    prefix and session caches so the eviction/touch invariants cannot
-    diverge."""
-
-    def __init__(self, capacity: int):
-        from collections import OrderedDict
-
-        self.capacity = int(capacity)
-        self._entries = OrderedDict()
-
-    def _get(self, key):
-        entry = self._entries.get(key)
-        if entry is not None:
-            self._entries.move_to_end(key)
-        return entry
-
-    def _put(self, key, value) -> None:
-        self._entries[key] = value
-        self._entries.move_to_end(key)
-        while len(self._entries) > self.capacity:
-            self._entries.popitem(last=False)  # device buffers freed on GC
-
-    def clear(self) -> None:
-        self._entries.clear()
-
-    def __len__(self) -> int:
-        return len(self._entries)
-
-
-class PrefixCache(_DeviceLRU):
-    """Device-resident LRU of prompt-prefix KV segments.
-
-    Long prompts often share a fixed head (system prompt, few-shot
-    preamble). Each entry stores one chunk-width's worth of computed k/v
-    (``[L, 1, C, K, H]`` pair, device arrays) keyed by the EXACT first-C
-    token ids; a hit seeds the admission's row cache with a copy instead of
-    recomputing the chunk — pure HBM traffic versus a full forward pass.
-    Fixed segment width keeps every shape static (one compiled seed
-    program). vLLM-style paged prefix trees need dynamic block tables; this
-    is the static-shape TPU rendition, deliberately chunk-granular.
-    """
-
-    def __init__(self, capacity: int, width: int):
-        super().__init__(capacity)
-        self.width = int(width)
-
-    def _key(self, prompt: np.ndarray) -> bytes:
-        return np.ascontiguousarray(prompt[: self.width]).tobytes()
-
-    def lookup(self, prompt: np.ndarray) -> Optional[Tuple]:
-        """(k, v, k_scale, v_scale) — scales None for bf16 caches."""
-        return self._get(self._key(prompt))
-
-    def insert(self, prompt: np.ndarray, k: jax.Array, v: jax.Array,
-               k_scale=None, v_scale=None) -> None:
-        key = self._key(prompt)
-        if key not in self._entries:
-            self._put(key, (k, v, k_scale, v_scale))
 
 
 SESSION_HITS = m.Counter(
@@ -620,36 +472,17 @@ SESSION_MISSES = m.Counter(
 )
 
 
-class SessionCache(_DeviceLRU):
-    """Device-resident LRU of finished conversation turns, keyed by
-    session id.
-
-    Multi-turn chat resends the whole history each turn; KV depends only
-    on token ids, so the previous turn's cache row (prompt + generated
-    tokens) is exactly the prefix KV of the next turn's prompt. A hit
-    seeds the admission with the stored row and prefills ONLY the new
-    tail — turn-N TTFT stops scaling with conversation length. Entries
-    hold one full cache row ([L,1,S,K,H] k/v, device arrays) plus the
-    token history for the prefix check; sampling temperature is
-    irrelevant to reuse (KV is deterministic in the tokens)."""
-
-    def lookup(self, session_id: str, prompt: np.ndarray):
-        """Return (k, v, k_scale, v_scale, history_len) when the stored
-        turn is a strict prefix of ``prompt`` (leaving >= 1 tail token
-        to prefill); scales are None for bf16 caches."""
-        entry = self._get(session_id)
-        if entry is None:
-            return None
-        seg, history = entry
-        n = int(history.size)
-        if n >= prompt.size or not np.array_equal(history, prompt[:n]):
-            return None
-        return (*seg, n)
-
-    def store(self, session_id: str, seg: Tuple,
-              history: np.ndarray) -> None:
-        """``seg`` is _extract_row_impl's (k, v, k_scale, v_scale)."""
-        self._put(session_id, (seg, np.asarray(history, np.int32)))
+def require_paged(paged: bool) -> None:
+    """The one check of the legacy ``paged`` key (ROADMAP D15): the
+    benchmark's configurations still pass ``"paged": true`` to
+    ``LLMDeployment`` and ``DecodeEngine``, so the key stays in both
+    signatures with one legal value."""
+    if not paged:
+        raise ValueError(
+            "paged=False: the slab KV cache was removed — the paged pool "
+            "with chunked admission is the engine, not an option of it; "
+            "drop the key (or pass paged=True)"
+        )
 
 
 class DecodeEngine:
@@ -684,25 +517,25 @@ class DecodeEngine:
         device: Optional[jax.Device] = None,
         mesh: Optional[Any] = None,
         base_seed: int = 0,
-        paged: bool = False,
+        paged: bool = True,
         page_size: int = 128,
         kv_pool_pages: Optional[int] = None,
         host_spill_pages: int = 0,
-        chunked_prefill: Optional[bool] = None,
         prefill_token_budget: Optional[int] = None,
     ):
         from ray_dynamic_batching_tpu.utils import compile_cache
 
+        require_paged(paged)
         compile_cache.enable()  # prefill/decode compiles become disk hits
         self.model = model
         self.device = device
         self.mesh = mesh
-        if paged and draft_model is not None and mesh is not None:
+        if draft_model is not None and mesh is not None:
             # Loud, like the draft-model conflict ISSUE 13 lifted (and
             # the PR 10 TP-paged pattern): the spec verify window would
             # need the scratch-page scatter AND the staircase kernel
             # runnable per-shard under shard_map — neither is wired yet,
-            # and a silent slab/plain fallback would mislabel every A/B
+            # and a silent plain fallback would mislabel every A/B
             # capture stamped from the config. Checked BEFORE any
             # sharding work so a misconfigured replica fails in
             # microseconds, not after a multi-GB param reshard.
@@ -732,8 +565,8 @@ class DecodeEngine:
         if mesh is not None:
             # TP-sharded replica (BASELINE.json config 4): params sharded by
             # the model's Megatron-style rules, KV cache sharded over kv
-            # heads (cache_pspec), decode collectives ride ICI via GSPMD —
-            # the serving analogue of the reference's NCCL allreduce swap.
+            # heads (paged_cache_pspec), decode collectives ride ICI via
+            # GSPMD — the serving analogue of the reference's NCCL allreduce swap.
             from ray_dynamic_batching_tpu.parallel.mesh import shard_params
 
             params = shard_params(mesh, model, params)
@@ -758,121 +591,106 @@ class DecodeEngine:
 
         self._slots = [_Slot() for _ in range(num_slots)]
         # Host mirror of per-slot cache lengths (updated from each scan's
-        # packed result): drives paged page-headroom math and the
-        # kv_occupancy() residency metric in BOTH modes.
+        # packed result): drives the page-headroom math and the
+        # kv_occupancy() residency metric.
         self._len_host = np.zeros((num_slots,), dtype=np.int32)
         # --- paged KV pool (ISSUE 7 tentpole) ---------------------------
-        # Slab mode gives every slot a private max_len run; paged mode
-        # backs all slots with one pool of lane-aligned pages gathered
+        # All slots are backed by one pool of lane-aligned pages gathered
         # through per-slot page tables, so HBM occupancy follows cached
         # tokens (freed at EOS mid-cycle) and prefix/session reuse
         # shares pages copy-on-write instead of copying rows.
-        self.paged = bool(paged)
         self.page_size = int(page_size)
-        self._page_journal: Optional[PageEventJournal] = None
-        if self.paged:
-            if not lane_aligned_page(self.page_size):
-                raise ValueError(
-                    f"page_size {self.page_size} must be a 128-lane "
-                    "multiple (ops/tile_math.lane_aligned_page): the int8 "
-                    "scale tile streams the page as its lane dim"
-                )
-            # Logical per-slot capacity: whole pages covering max_len.
-            # The engine still enforces max_len (token-exactness vs the
-            # slab path); the partial last page is headroom that is
-            # never attended past max_len.
-            self._n_table_entries = pages_for(max_len, self.page_size)
-            self._paged_capacity = self._n_table_entries * self.page_size
-            full_backing = num_slots * self._n_table_entries
-            self.num_pages = int(kv_pool_pages or full_backing)
-            # The pool may be over-subscribed (num_pages < full backing:
-            # the occupancy win) but must hold at least one slot's worth
-            # or nothing can ever decode.
-            if self.num_pages < self._n_table_entries:
-                raise ValueError(
-                    f"kv_pool_pages {self.num_pages} cannot back even one "
-                    f"slot ({self._n_table_entries} pages at page_size "
-                    f"{self.page_size}, max_len {max_len})"
-                )
-            # Allocator event journal (bounded ring): alloc/free land
-            # from the allocator itself, CoW borrows / cache reclaims /
-            # capacity evictions from their decision sites below —
-            # rendered as Perfetto instant events + a page-occupancy
-            # counter track by utils/trace_export, surfaced by
-            # ``snapshot()``.
-            self._page_journal = PageEventJournal()
-            self._allocator = PageAllocator(self.num_pages,
-                                            journal=self._page_journal)
-            self._table_host = np.full(
-                (num_slots, self._n_table_entries), self.num_pages,
-                dtype=np.int32,
+        if not lane_aligned_page(self.page_size):
+            raise ValueError(
+                f"page_size {self.page_size} must be a 128-lane "
+                "multiple (ops/tile_math.lane_aligned_page): the int8 "
+                "scale tile streams the page as its lane dim"
             )
-            self._table_dirty = True
-            if mesh is not None and not hasattr(model, "paged_cache_pspec"):
-                # Loud, like the draft-model conflict: silently
-                # allocating the pool on ONE chip under a TP mesh would
-                # reshard it through ICI every step and mislabel every
-                # measurement stamped from the config (the PR-7 silent-
-                # fallback class).
-                raise ValueError(
-                    f"{getattr(model, 'name', type(model).__name__)}: "
-                    "paged=True on a TP mesh needs the model to define "
-                    "paged_cache_pspec (the pool's sharding layout) — "
-                    "see CausalLM.paged_cache_pspec"
-                )
-            if mesh is not None:
-                # TP serving slice over the paged pool (ROADMAP item 2):
-                # pages shard on the kv-head dim exactly like the slab
-                # TP cache (codes + scales planes included); the page
-                # table, lengths, and the host-side free-list allocator
-                # stay replica-global — page indices are shard-
-                # invariant. The decode kernel runs per-shard head
-                # slices under the mesh (ops/attention.tensor_parallel
-                # -> paged_decode_attention's shard_map wrapper); the
-                # CPU/XLA gather fallback partitions from the pool's
-                # NamedSharding under plain GSPMD, so both read paths
-                # stay token-exact vs the single-chip pool.
-                from ray_dynamic_batching_tpu.parallel.mesh import (
-                    make_sharded_paged_cache,
-                )
-
-                self._cache = make_sharded_paged_cache(
-                    mesh, model, num_slots, self.num_pages,
-                    self.page_size, self._paged_capacity,
-                )
-            else:
-                with self._device_ctx():
-                    self._cache = self._put(model.make_paged_cache(
-                        num_slots, self.num_pages, self.page_size,
-                        self._paged_capacity,
-                    ))
-            # The head's true width, as the model's row caches have it
-            # (the pool's rows are lane-padded: pool_head_dim).
-            self._kv_head_dim = jax.eval_shape(
-                lambda: model.make_cache(1, self.page_size)).k.shape[-1]
-            # How the pool lies on the device, once, for snapshot():
-            # the order of its axes (row-major is what the paged kernel
-            # and the page write read; the pool's lane-padded rows make
-            # it the device's default) and its bytes there.
-            planes = [x for x in (self._cache.k, self._cache.v,
-                                  self._cache.k_scale, self._cache.v_scale)
-                      if x is not None]
-            layout = self._cache.k.format.layout
-            self._pool_stats = {
-                "layout": (None if layout is None
-                           else list(layout.major_to_minor)),
-                "resident_bytes": sum(
-                    x.on_device_size_in_bytes() for x in planes),
-            }
-        elif mesh is not None and hasattr(model, "cache_pspec"):
+        # Logical per-slot capacity: whole pages covering max_len.
+        # The engine still enforces max_len; the partial last page is
+        # headroom that is never attended past max_len.
+        self._n_table_entries = pages_for(max_len, self.page_size)
+        self._paged_capacity = self._n_table_entries * self.page_size
+        full_backing = num_slots * self._n_table_entries
+        self.num_pages = int(kv_pool_pages or full_backing)
+        # The pool may be over-subscribed (num_pages < full backing:
+        # the occupancy win) but must hold at least one slot's worth
+        # or nothing can ever decode.
+        if self.num_pages < self._n_table_entries:
+            raise ValueError(
+                f"kv_pool_pages {self.num_pages} cannot back even one "
+                f"slot ({self._n_table_entries} pages at page_size "
+                f"{self.page_size}, max_len {max_len})"
+            )
+        # Allocator event journal (bounded ring): alloc/free land
+        # from the allocator itself, CoW borrows / cache reclaims /
+        # capacity evictions from their decision sites below —
+        # rendered as Perfetto instant events + a page-occupancy
+        # counter track by utils/trace_export, surfaced by
+        # ``snapshot()``.
+        self._page_journal = PageEventJournal()
+        self._allocator = PageAllocator(self.num_pages,
+                                        journal=self._page_journal)
+        self._table_host = np.full(
+            (num_slots, self._n_table_entries), self.num_pages,
+            dtype=np.int32,
+        )
+        self._table_dirty = True
+        if mesh is not None and not hasattr(model, "paged_cache_pspec"):
+            # Loud, like the draft-model conflict: silently
+            # allocating the pool on ONE chip under a TP mesh would
+            # reshard it through ICI every step and mislabel every
+            # measurement stamped from the config (the PR-7 silent-
+            # fallback class).
+            raise ValueError(
+                f"{getattr(model, 'name', type(model).__name__)}: "
+                "a TP mesh needs the model to define "
+                "paged_cache_pspec (the pool's sharding layout) — "
+                "see CausalLM.paged_cache_pspec"
+            )
+        if mesh is not None:
+            # TP serving slice over the paged pool (ROADMAP item 2):
+            # pages shard on the kv-head dim (codes + scales planes
+            # included); the page table, lengths, and the host-side
+            # free-list allocator stay replica-global — page indices
+            # are shard-invariant. The decode kernel runs per-shard head
+            # slices under the mesh (ops/attention.tensor_parallel
+            # -> paged_decode_attention's shard_map wrapper); the
+            # CPU/XLA gather fallback partitions from the pool's
+            # NamedSharding under plain GSPMD, so both read paths
+            # stay token-exact vs the single-chip pool.
             from ray_dynamic_batching_tpu.parallel.mesh import (
-                make_sharded_cache,
+                make_sharded_paged_cache,
             )
 
-            self._cache = make_sharded_cache(mesh, model, num_slots, max_len)
+            self._cache = make_sharded_paged_cache(
+                mesh, model, num_slots, self.num_pages,
+                self.page_size, self._paged_capacity,
+            )
         else:
             with self._device_ctx():
-                self._cache = self._put(model.make_cache(num_slots, max_len))
+                self._cache = self._put(model.make_paged_cache(
+                    num_slots, self.num_pages, self.page_size,
+                    self._paged_capacity,
+                ))
+        # The head's true width, as the model's row caches have it
+        # (the pool's rows are lane-padded: pool_head_dim).
+        self._kv_head_dim = jax.eval_shape(
+            lambda: model.make_cache(1, self.page_size)).k.shape[-1]
+        # How the pool lies on the device, once, for snapshot():
+        # the order of its axes (row-major is what the paged kernel
+        # and the page write read; the pool's lane-padded rows make
+        # it the device's default) and its bytes there.
+        planes = [x for x in (self._cache.k, self._cache.v,
+                              self._cache.k_scale, self._cache.v_scale)
+                  if x is not None]
+        layout = self._cache.k.format.layout
+        self._pool_stats = {
+            "layout": (None if layout is None
+                       else list(layout.major_to_minor)),
+            "resident_bytes": sum(
+                x.on_device_size_in_bytes() for x in planes),
+        }
         self._tokens = np.zeros((num_slots, 1), dtype=np.int32)
         self._active_mask = np.zeros((num_slots,), dtype=bool)
         # Per-slot sampling params (temperature 0 == greedy).
@@ -934,19 +752,14 @@ class DecodeEngine:
                                 self.decode_horizon)
         self.max_admissions_per_step = max(1, int(max_admissions_per_step))
         # --- token-budget chunked admission (ISSUE 15 tentpole) ---------
-        # Chunked prefill is the UNIVERSAL admission path on the paged
-        # engine (pages-direct chunk k/v, first-token fusion); slab
-        # engines opt in (row-cache chunks + fused commit) — the A/B arm
-        # the exactness matrix compares. ``prefill_token_budget`` is the
+        # Every admission is a chunk train (pages-direct chunk k/v,
+        # first-token fusion). ``prefill_token_budget`` is the
         # most prefill tokens one scheduler round may spend between
         # decode turns; clamped to >= one chunk width so a full-width
         # chunk can always dispatch (otherwise nothing would ever
         # admit). With the default budget of exactly one chunk, no
         # running stream ever waits more than ONE chunk program between
         # its turns — the stall bound tier-1 pins.
-        if chunked_prefill is None:
-            chunked_prefill = self.paged
-        self.chunked_prefill = bool(chunked_prefill)
         _chunk_w = self.prompt_buckets[-1] if self.prompt_buckets \
             else max_len
         self.prefill_token_budget = max(
@@ -981,44 +794,34 @@ class DecodeEngine:
         # the bench LLM row publishes it so an on-chip run shows where the
         # TTFT milliseconds live (BASELINE.json north star: p50 < 150 ms).
         self._ttft_parts: collections.deque = collections.deque(maxlen=1024)
-        # Prompt-prefix KV reuse for chunked admissions (0 = off). Paged
-        # engines reuse by page REFERENCE (longest shared page-prefix,
-        # copy-on-write at the partial boundary page); slab engines keep
-        # the chunk-granular device-copy caches.
-        self.prefix_cache: Optional[PrefixCache] = None
+        # Prompt-prefix KV reuse (0 = off) by page REFERENCE: longest
+        # shared page-prefix, copy-on-write at the partial boundary page.
         self.paged_prefix: Optional[PagedPrefixCache] = None
         if prefix_cache_size > 0 and self.prompt_buckets:
-            if self.paged:
-                self.paged_prefix = PagedPrefixCache(
-                    prefix_cache_size, self.page_size, self._allocator
-                )
-            else:
-                self.prefix_cache = PrefixCache(
-                    prefix_cache_size, self.prompt_buckets[-1]
-                )
+            self.paged_prefix = PagedPrefixCache(
+                prefix_cache_size, self.page_size, self._allocator
+            )
         # HBM -> host-RAM spill tier (0 = off): prefix-cache entries shed
         # under pool pressure spill their page CONTENTS to host RAM and
         # reload on the next matching prompt — hot system prompts survive
-        # pool churn instead of recomputing (ISSUE 11; paged-only, and
-        # pointless without a prefix cache to spill from).
+        # pool churn instead of recomputing (ISSUE 11; pointless without
+        # a prefix cache to spill from).
         self.host_spill: Optional[HostSpillTier] = None
         if host_spill_pages > 0 and self.paged_prefix is not None:
             self.host_spill = HostSpillTier(
                 host_spill_pages, self._read_pages, self._write_pages,
                 journal=self._page_journal,
             )
-        # Multi-turn session KV continuation (0 = off). Paged store pins
+        # Multi-turn session KV continuation (0 = off): the store pins
         # the finished slot's pages (O(1), no row copy).
-        self.session_cache: Optional[SessionCache] = None
         self.paged_sessions: Optional[PagedSessionCache] = None
         if session_cache_size > 0:
-            if self.paged:
-                self.paged_sessions = PagedSessionCache(
-                    session_cache_size, self.page_size, self._allocator
-                )
-            else:
-                self.session_cache = SessionCache(session_cache_size)
-        self._prefill_fns: Dict[int, Callable] = {}
+            self.paged_sessions = PagedSessionCache(
+                session_cache_size, self.page_size, self._allocator
+            )
+        # The draft model's long-fill programs (chunk, commit) by chunk
+        # width: compiled at the first admission (_draft_long_fill).
+        self._draft_fill_fns: Dict[int, Tuple[Callable, Callable]] = {}
         # An expert model's decode and paged chunk programs also return
         # their routing counters (``Turn``'s ``moe_*``); a dense model's
         # programs are built without the argument and do not change.
@@ -1074,7 +877,7 @@ class DecodeEngine:
             with self._device_ctx():
                 # Headroom past max_len: the draft drafts spec_tokens+1
                 # ahead of the verified length near the end of the cache.
-                # The draft cache stays a SLAB even on paged engines: the
+                # The draft cache is a slab of rows, not pages: the
                 # shared pool's pages are target-geometry tensors (K, H
                 # of the big model), so the small draft would need a
                 # second pool of its own shape for a footprint that is a
@@ -1159,7 +962,7 @@ class DecodeEngine:
         rec = Turn(
             kind, t_dispatch, t_issued, t_fetched, now_ms(),
             substeps, tokens, active, trains, len(self.queue),
-            self._allocator.allocated_pages if self.paged else 0,
+            self._allocator.allocated_pages,
             int(self._len_host.sum()), self._idled, *(int(c) for c in moe),
             kv_pages_live,
         )
@@ -1175,9 +978,7 @@ class DecodeEngine:
         """Page-table entries, summed over all slots, that hold a position
         the scan about to be dispatched may attend in its first substep:
         the last row of a ``window`` attends positions <= length +
-        ``window`` - 1 (the paged kernel's own bound). 0 on a slab engine."""
-        if not self.paged:
-            return 0
+        ``window`` - 1 (the paged kernel's own bound)."""
         last = (self._len_host + (window - 1)) // self.page_size
         return int(np.minimum(last + 1, self._n_table_entries).sum())
 
@@ -1334,65 +1135,6 @@ class DecodeEngine:
         )
         return jnp.where(temps > 0.0, sampled, greedy)
 
-    def _prefill_impl(self, params, tokmask, cache, meta_i, meta_f,
-                      bias_ids, bias_vals):
-        """``nB`` prompts → cache rows at ``slots`` + first sampled tokens.
-
-        Inputs arrive PACKED by dtype — ``tokmask`` [2, nB, T] stacks
-        tokens + attention mask, ``meta_i`` [4, nB] stacks
-        slots/top_k/seeds/tok_idx, ``meta_f`` [2, nB] stacks
-        temperature/top_p — so an admission group costs 5 host→device
-        transfers instead of 10; unpacking inside the program is free.
-        One compiled program per (prompt bucket, group size) serves every
-        slot combination (dynamic start indices, static shapes). Batching
-        admissions into one program means ONE dispatch and one ids
-        fetch per admission group instead of per request — each request
-        of a burst would otherwise wait out its predecessors' dispatches.
-        """
-        tokens, attn_mask = tokmask[0], tokmask[1]
-        slots, topk, seeds, tok_idx = (
-            meta_i[0], meta_i[1], meta_i[2], meta_i[3]
-        )
-        temps, topp = meta_f[0], meta_f[1]
-        params = self._mp(params)
-        nB = tokens.shape[0]
-        row_cache = self.model.make_cache(nB, self.max_len)
-        last_logits, rows = self.model.prefill(
-            params, tokens, attn_mask, row_cache
-        )
-        cache = copy_rows_into(cache, rows, slots)
-        first = self._sample_tokens(
-            last_logits, temps, topk, seeds, tok_idx, bias_ids, bias_vals,
-            topp,
-        )  # [nB]
-        return first, cache
-
-    def _prefill_paged_impl(self, params, tokmask, cache, meta_i, meta_f,
-                            bias_ids, bias_vals, write_pids):
-        """Paged mirror of :meth:`_prefill_impl`: the prompt runs on a
-        private row cache exactly as on the slab path (prefill math is
-        untouched), then the row is cut into pages and scattered at the
-        physical pages ``write_pids`` names — sentinel entries (shared
-        CoW pages, unallocated tail) drop. Same packed-transfer layout,
-        same sampling."""
-        tokens, attn_mask = tokmask[0], tokmask[1]
-        slots, topk, seeds, tok_idx = (
-            meta_i[0], meta_i[1], meta_i[2], meta_i[3]
-        )
-        temps, topp = meta_f[0], meta_f[1]
-        params = self._mp(params)
-        nB = tokens.shape[0]
-        row_cache = self.model.make_cache(nB, self._paged_capacity)
-        last_logits, rows = self.model.prefill(
-            params, tokens, attn_mask, row_cache
-        )
-        cache = copy_rows_into_paged(cache, rows, slots, write_pids)
-        first = self._sample_tokens(
-            last_logits, temps, topk, seeds, tok_idx, bias_ids, bias_vals,
-            topp,
-        )
-        return first, cache
-
     def _chunk_group_paged_impl(self, params, tokmask, cache, tables,
                                 meta_i, meta_f, bias_ids, bias_vals):
         """One chunk program for a GROUP of chunk trains, pages-direct
@@ -1478,21 +1220,16 @@ class DecodeEngine:
 
         def substep(carry, j):
             cache, tokens, counts = carry
-            # Paged pools round capacity up to whole pages; the engine's
-            # max_len stays the generation bound so paged and slab runs
-            # block (and capacity-finish) at the SAME length — the
-            # token-exactness contract. For slab caches the two bounds
-            # coincide (make_cache allocates exactly max_len).
-            limit = self.max_len if self.paged else cache.capacity
-            advanced = jnp.logical_and(active, cache.lengths < limit)
+            # The pool rounds capacity up to whole pages; the engine's
+            # max_len stays the generation bound, so a slot blocks (and
+            # capacity-finishes) at max_len whatever the page size.
+            advanced = jnp.logical_and(active, cache.lengths < self.max_len)
             # Dequantize INSIDE the scan body: hoisted outside, the bf16
             # tree becomes a loop-invariant XLA materializes once and
             # re-streams every substep — the exact bandwidth the int8
             # residency is supposed to save. In-body, the compiler may
             # fuse each convert+scale into its consuming matmul.
-            step_fn = (self.model.decode_step_paged if self.paged
-                       else self.model.decode_step)
-            logits, cache, *moe = step_fn(
+            logits, cache, *moe = self.model.decode_step_paged(
                 self._mp(params), tokens, cache, advanced, **self._moe_kw
             )
             # Repetition control: subtract presence (any prior emission)
@@ -1563,15 +1300,11 @@ class DecodeEngine:
         # the draft cache complete — it is never verified.
         d = drafts[:k].T  # [B, k]
         window = jnp.concatenate([tokens, d], axis=1)  # [B, k+1]
-        # Paged engines verify through the page-table scatter + the
-        # staircase paged read (scratch pages pre-arranged host-side by
-        # _reserve_spec_scratch); the slab path is unchanged. Same
-        # window, same greedy rule — ONE accept computation below serves
-        # both, which is what keeps paged+spec and slab+spec
-        # byte-identical.
-        verify = (self.model.verify_step_paged if self.paged
-                  else self.model.verify_step)
-        logits, cache = verify(params, window, cache, active)
+        # Verify through the page-table scatter + the staircase paged
+        # read (scratch pages pre-arranged host-side by
+        # _reserve_spec_scratch).
+        logits, cache = self.model.verify_step_paged(
+            params, window, cache, active)
         logits = logits.astype(jnp.float32)
         # Same per-request bias as the plain path (ONE rule — _apply_bias —
         # broadcast over the window) so biased greedy stays
@@ -1619,36 +1352,12 @@ class DecodeEngine:
             lengths=dcache.lengths + jnp.where(active, counts, 0)
         )
 
-    def _draft_prefill_impl(self, dparams, tokmask, dcache, meta_i):
-        """Mirror of ``_prefill_impl`` for the draft model: fill the draft
-        cache's rows for newly admitted prompts (no sampling — the draft
-        only ever proposes from its cache). Takes the target prefill's
-        packed device buffers verbatim — zero extra transfers."""
-        tokens, attn_mask, slots = tokmask[0], tokmask[1], meta_i[0]
-        nB = tokens.shape[0]
-        row_cache = self.draft_model.make_cache(nB, dcache.capacity)
-        _, rows = self.draft_model.prefill(dparams, tokens, attn_mask,
-                                           row_cache)
-        return copy_rows_into(dcache, rows, slots)
-
-    def _draft_prefill_fn(self, bucket: int, group: int) -> Callable:
-        fn = self._prefill_fns.get(("draft", bucket, group))
-        if fn is None:
-            # Donate the draft cache (arg 2 in the packed signature).
-            fn = instrument("draft_prefill", jax.jit(
-                self._draft_prefill_impl, donate_argnums=(2,)
-            ))
-            self._prefill_fns[("draft", bucket, group)] = fn
-        return fn
-
     def _admit_group_sizes(self) -> List[int]:
-        """Compiled prefill/chunk group widths: powers of two up to
+        """Compiled chunk group widths: powers of two up to
         ``max_admissions_per_step``, plus the cap itself when it isn't
-        one. The cap is a GROUP-WIDTH clamp on both arms — the legacy
-        mono arm's ``_admit`` batches that many full-prompt prefills,
-        and the chunked arm's ``_pump_prefill`` batches up to that many
-        same-width single-chunk trains per dispatch (its PACING is the
-        token budget, not this count). Either way, every group width
+        one. The cap is a GROUP-WIDTH clamp: ``_pump_prefill`` batches up
+        to that many same-width single-chunk trains per dispatch (its
+        PACING is the token budget, not this count). Every group width
         the engine can dispatch must round up to a width warmup
         compiled, or a burst pays a 20-40s XLA compile mid-serving —
         the warmup-coverage contract (``ops/jit_model.py``)."""
@@ -1660,34 +1369,18 @@ class DecodeEngine:
             sizes.append(self.max_admissions_per_step)
         return sizes
 
-    def _prefill_fn(self, bucket: int, group: int) -> Callable:
-        fn = self._prefill_fns.get((bucket, group))
-        if fn is None:
-            # Donate the big cache (arg 2) — updated in place in HBM.
-            name = ("prefill_group_paged" if self.paged
-                    else "prefill_group")
-            fn = instrument(name, jax.jit(
-                self._prefill_paged_impl if self.paged
-                else self._prefill_impl,
-                donate_argnums=(2,),
-            ))
-            self._prefill_fns[(bucket, group)] = fn
-        return fn
-
     def warmup(self) -> None:
-        """Compile every hot-path program before serving: the arm's
-        admission programs (chunked-paged: the chunk program over every
-        (bucket x group) shape; slab-chunked: the long chunk + fused
-        commit pair; mono: the (bucket x group) prefill grid) plus the
-        decode horizons {1, ttft, decode} and the spec/draft programs
-        when a draft rides along.
+        """Compile every hot-path program before serving: the chunk
+        program over every (bucket x group) shape, the decode horizons
+        {1, ttft, decode} and the spec/draft programs when a draft rides
+        along.
 
         Contract-bearing (ISSUE 20): the whole run is bracketed by the
         compile ledger's warmup phase — ``end_warmup`` arms the
         steady-state mark, after which ANY compile is a recorded
         violation — and the ledger's warmup counts are cross-checked
         against ``ops/jit_model.required_for``: a registered program
-        this arm needs that warmup did not compile raises HERE, at
+        this engine needs that warmup did not compile raises HERE, at
         startup, instead of stalling a request 20-40s mid-serving."""
         ledger = get_ledger()
         before = ledger.counts(phase=PHASE_WARMUP)
@@ -1698,9 +1391,7 @@ class DecodeEngine:
             # Zero new compiles: every program was already cached (this
             # engine was warmed before) — nothing to cross-check.
             return
-        required = jit_model.required_for(
-            self.chunked_prefill, self.paged, self.draft_model is not None
-        )
+        required = jit_model.required_for(self.draft_model is not None)
         gaps = [
             p.name for p in required
             if after.get(p.name, 0) <= before.get(p.name, 0)
@@ -1714,103 +1405,41 @@ class DecodeEngine:
             )
 
     def _warmup_impl(self) -> None:
-        if self.chunked_prefill and self.paged:
-            # Chunked-universal admission: warm the pages-direct chunk
-            # program at every (bucket, group) shape the pump can
-            # produce, plus the (1, C_max) long-train shape (covered by
-            # group size 1 at the largest bucket). All-sentinel tables:
-            # every page write drops, the lengths scatter steers to the
-            # sentinel slot — the full program compiles without touching
-            # a real page.
-            for b in self.prompt_buckets:
-                for g in self._admit_group_sizes():
-                    first, self._cache = self._chunk_paged_fn(
-                        self.params,
-                        jnp.stack([
-                            jnp.zeros((g, b), jnp.int32),
-                            jnp.ones((g, b), jnp.int32),
-                        ]),
-                        self._cache,
-                        jnp.full((g, self._n_table_entries),
-                                 self.num_pages, jnp.int32),
-                        jnp.stack([
-                            jnp.full((g,), self.num_slots, jnp.int32),
-                            jnp.zeros((g,), jnp.int32),
-                            jnp.zeros((g,), jnp.int32),
-                            jnp.zeros((g,), jnp.int32),
-                            jnp.zeros((g,), jnp.int32),
-                            jnp.zeros((g,), jnp.int32),
-                        ]),
-                        jnp.stack([
-                            jnp.zeros((g,), jnp.float32),
-                            jnp.ones((g,), jnp.float32),
-                        ]),
-                        jnp.zeros((g, self.max_bias_entries), jnp.int32),
-                        jnp.zeros((g, self.max_bias_entries),
-                                  jnp.float32),
-                    )
-                    first.block_until_ready()
-        elif self.chunked_prefill:
-            # Slab chunked trains ride the row-cache chunk + fused
-            # commit programs — warm THOSE, not the monolithic groups
-            # this engine never dispatches (a cold chunk program is a
-            # 20-40s XLA compile on the first real request, exactly
-            # what warmup exists to prevent).
-            C = self.prompt_buckets[-1]
-            chunk_fn, commit_fn, _seed, _ex = self._long_prefill_fns(C)
-            row = self.model.make_cache(1, self._long_row_cap(C))
-            last, row = chunk_fn(
-                self.params,
-                jnp.zeros((1, C), jnp.int32),
-                jnp.ones((1, C), jnp.int32),
-                row, jnp.int32(0), jnp.int32(0),
-            )
-            first, self._cache = commit_fn(
-                self._cache, row,
-                jnp.zeros((3,), jnp.int32),
-                last,
-                jnp.asarray([0.0, 1.0], jnp.float32),
-                jnp.zeros((1, self.max_bias_entries), jnp.int32),
-                jnp.zeros((1, self.max_bias_entries), jnp.float32),
-            )
-            first.block_until_ready()
-        else:
-            self._warmup_prefill_groups()
-        self._warmup_decode()
-
-    def _warmup_prefill_groups(self) -> None:
+        # The pages-direct chunk program at every (bucket, group) shape
+        # the pump can produce, plus the (1, C_max) long-train shape
+        # (covered by group size 1 at the largest bucket). All-sentinel
+        # tables: every page write drops, the lengths scatter steers to
+        # the sentinel slot — the full program compiles without touching
+        # a real page.
         for b in self.prompt_buckets:
             for g in self._admit_group_sizes():
-                tokmask = jnp.stack([
-                    jnp.zeros((g, b), dtype=jnp.int32),
-                    jnp.ones((g, b), dtype=jnp.int32),
-                ])
-                meta_i = jnp.stack([
-                    jnp.arange(g, dtype=jnp.int32) % self.num_slots,
-                    jnp.zeros((g,), jnp.int32),
-                    jnp.zeros((g,), jnp.int32),
-                    jnp.zeros((g,), jnp.int32),
-                ])
-                meta_f = jnp.stack([
-                    jnp.zeros((g,), jnp.float32),
-                    jnp.ones((g,), jnp.float32),
-                ])
-                extra = ()
-                if self.paged:
-                    # All-sentinel write pids: every page write drops, so
-                    # warmup compiles the full scatter without touching a
-                    # single real page (the table is still all-sentinel).
-                    extra = (jnp.full(
-                        (g, self._n_table_entries), self.num_pages,
-                        jnp.int32,
-                    ),)
-                first, self._cache = self._prefill_fn(b, g)(
-                    self.params, tokmask, self._cache, meta_i, meta_f,
+                first, self._cache = self._chunk_paged_fn(
+                    self.params,
+                    jnp.stack([
+                        jnp.zeros((g, b), jnp.int32),
+                        jnp.ones((g, b), jnp.int32),
+                    ]),
+                    self._cache,
+                    jnp.full((g, self._n_table_entries),
+                             self.num_pages, jnp.int32),
+                    jnp.stack([
+                        jnp.full((g,), self.num_slots, jnp.int32),
+                        jnp.zeros((g,), jnp.int32),
+                        jnp.zeros((g,), jnp.int32),
+                        jnp.zeros((g,), jnp.int32),
+                        jnp.zeros((g,), jnp.int32),
+                        jnp.zeros((g,), jnp.int32),
+                    ]),
+                    jnp.stack([
+                        jnp.zeros((g,), jnp.float32),
+                        jnp.ones((g,), jnp.float32),
+                    ]),
                     jnp.zeros((g, self.max_bias_entries), jnp.int32),
-                    jnp.zeros((g, self.max_bias_entries), jnp.float32),
-                    *extra,
+                    jnp.zeros((g, self.max_bias_entries),
+                              jnp.float32),
                 )
                 first.block_until_ready()
+        self._warmup_decode()
 
     def _warmup_decode(self) -> None:
         B = self.num_slots
@@ -1835,27 +1464,6 @@ class DecodeEngine:
             )
             packed.block_until_ready()
         if self._dcache is not None:
-            if not self.chunked_prefill:
-                # Draft group-prefill programs serve the MONO admission
-                # path only; chunked engines replay prompts through the
-                # lazily-compiled draft chunk program instead.
-                for b in self.prompt_buckets:
-                    for g in self._admit_group_sizes():
-                        self._dcache = self._draft_prefill_fn(b, g)(
-                            self.draft_params,
-                            jnp.stack([
-                                jnp.zeros((g, b), dtype=jnp.int32),
-                                jnp.ones((g, b), dtype=jnp.int32),
-                            ]),
-                            self._dcache,
-                            jnp.stack([
-                                jnp.arange(g, dtype=jnp.int32)
-                                % self.num_slots,
-                                jnp.zeros((g,), jnp.int32),
-                                jnp.zeros((g,), jnp.int32),
-                                jnp.zeros((g,), jnp.int32),
-                            ]),
-                        )
             packed, self._cache, self._dcache = self._spec_fn(
                 self.params,
                 self._cache,
@@ -1886,16 +1494,10 @@ class DecodeEngine:
         self._cache = self._cache.replace(
             lengths=self._put(np.zeros((self.num_slots,), np.int32))
         )
-        n_warm = len(self._prefill_fns)
-        if self.chunked_prefill and self.paged:
-            # Chunk shapes live in ONE retracing jit, not _prefill_fns.
-            n_warm = len(self.prompt_buckets) * len(
-                self._admit_group_sizes()
-            )
         logger.info(
-            "%s: warmed %d %s programs + decode horizons {1, %d, %d}",
-            self.model.name, n_warm,
-            "chunk" if self.chunked_prefill and self.paged else "prefill",
+            "%s: warmed %d chunk programs + decode horizons {1, %d, %d}",
+            self.model.name,
+            len(self.prompt_buckets) * len(self._admit_group_sizes()),
             self.ttft_horizon, self.decode_horizon,
         )
         for line in self._expert_paths():
@@ -1926,7 +1528,7 @@ class DecodeEngine:
             raise BadRequest(f"{req.request_id}: empty prompt")
         bucket = bucket_up(int(prompt.size), self.prompt_buckets)
         if bucket is None:
-            # Longer than every bucket: admit via CHUNKED prefill (bucket
+            # Longer than every bucket: a multi-chunk train (bucket
             # sentinel -1) as long as the cache can hold the prompt plus at
             # least one generated token.
             if prompt.size >= self.max_len:
@@ -2037,23 +1639,10 @@ class DecodeEngine:
         return ids, vals
 
     def _admit(self) -> int:
-        """Fill free slots from the queue (continuous batching join).
-        Chunked engines admit into chunk TRAINS — their prefill work is
-        paced by ``prefill_token_budget`` in ``_pump_prefill``, so
-        admission itself takes every free slot. The legacy monolithic
-        arm rations by COUNT instead (at most
-        ``max_admissions_per_step`` full-prompt prefills between decode
-        steps) so prefills interleave with decode turns.
-
-        Same-bucket prompts prefill as ONE batched program call (group
-        padded to the next compiled power-of-two width by duplicating row 0
-        — the duplicate writes identical data to the same slot, which is
-        idempotent), so a burst of admissions costs one dispatch per bucket
-        rather than one per request.
-
-        The mono count cap only applies while slots are actively decoding
-        (it exists to protect THEIR latency); an idle engine ramps by
-        filling every free slot at once — there is nothing to stall."""
+        """Fill free slots from the queue (continuous batching join):
+        every dequeued request becomes a chunk TRAIN holding a slot. The
+        prefill work is paced by ``prefill_token_budget`` in
+        ``_pump_prefill``, so admission itself takes every free slot."""
         with self._phase("rdb.engine.admit") as ph:
             free = self._free_slots()
             admitted = self._admit_into(free) if free else 0
@@ -2061,11 +1650,6 @@ class DecodeEngine:
             return admitted
 
     def _admit_into(self, free: List[int]) -> int:
-        if self._active_mask.any() and not self.chunked_prefill:
-            # Legacy monolithic rationing: the admission COUNT bounds the
-            # stall. Chunked engines admit into trains instead — the
-            # token budget, not this cap, paces their prefill work.
-            free = free[: self.max_admissions_per_step]
         batch = self.queue.get_batch(len(free), discard_stale=True)
         # Mid-admission visibility: these requests are in NEITHER the
         # queue nor a slot until their prefill registers (seconds for a
@@ -2081,114 +1665,10 @@ class DecodeEngine:
         # would otherwise strand them forever.
         self._admitting_batch = batch
         try:
-            if self.chunked_prefill:
-                return self._admit_chunked(batch, free)
-            return self._admit_batch(batch, free)
+            return self._admit_chunked(batch, free)
         finally:
             self._admitting = 0
             self._admitting_batch = []
-
-    def _admit_batch(self, batch: List[Request],
-                     free: List[int]) -> int:
-        t_dequeue = now_ms()
-        for req in batch:
-            # Dequeue stamp for the TTFT decomposition; a slot-starved
-            # requeue gets re-stamped on its next (sticking) dequeue.
-            req.admit_ms = t_dequeue
-        by_bucket: Dict[int, List[Tuple[Request, np.ndarray, Dict]]] = {}
-        session_items: List[Tuple[Request, np.ndarray, Dict, Tuple]] = []
-        sessions = (self.paged_sessions if self.paged
-                    else self.session_cache)
-        for req in batch:
-            try:
-                prompt, bucket, opts = self._prep_prompt(req)
-            except Exception as e:  # noqa: BLE001 — bad prompt must not kill loop
-                req.reject(e)
-                continue
-            hit = None
-            if sessions is not None and opts["session_id"]:
-                hit = sessions.lookup(opts["session_id"], prompt)
-                if hit is not None and self.paged:
-                    # Seed-read hold, taken AT LOOKUP: a long fill
-                    # admitted earlier in this same round interleaves
-                    # decode steps, whose finishes can store new session
-                    # turns and EVICT this entry — without the hold its
-                    # pages could be freed and rewritten before the seed
-                    # gather reads them. The hold also lets the
-                    # reservation below cover only the NON-shared tail.
-                    self._allocator.incref(hit[0])
-                    opts["_session_hold"] = list(hit[0])
-                    opts["_session_share"] = hit[1] // self.page_size
-                if hit is None:
-                    # Misses can be requeued (a missed LONG prompt):
-                    # mark now, count once at _register.
-                    opts["_session_miss"] = True
-            if self.paged and not self._alloc_admission_pages(
-                    req, prompt, opts):
-                continue  # page-starved: requeued (or shed) inside
-            if hit is not None:
-                # Counted at admission (_prefill_session), not here: a
-                # slot-starved requeue would re-look-up and double-count.
-                session_items.append((req, prompt, opts, hit))
-                continue
-            by_bucket.setdefault(bucket, []).append((req, prompt, opts))
-        admitted = 0
-        cap = self.max_admissions_per_step
-        long_items = by_bucket.pop(-1, [])
-        for bucket, items in by_bucket.items():
-            for off in range(0, len(items), cap):  # chunks round up to a
-                chunk = items[off : off + cap]     # compiled group width
-                slots = free[admitted : admitted + len(chunk)]
-                try:
-                    self._prefill_group(bucket, chunk, slots)
-                except Exception as e:  # noqa: BLE001 — dequeued requests
-                    # must never dangle: a failed group rejects its members
-                    logger.exception(
-                        "%s: prefill group failed", self.model.name
-                    )
-                    for req, _p, opts in chunk:
-                        self._release_pages(opts)
-                        req.reject(e)
-                    continue
-                admitted += len(chunk)
-        session_fill = (self._prefill_session_paged if self.paged
-                        else self._prefill_session)
-        singles = [
-            (self._prefill_long, (req, prompt, opts))
-            for req, prompt, opts in long_items
-        ] + [
-            (session_fill, (req, prompt, opts, hit))
-            for req, prompt, opts, hit in session_items
-        ]
-        for fill, args in singles:
-            req = args[0]
-            if admitted >= len(free):
-                # Ran out of slots this round — requeue untouched. A full
-                # or closed queue refuses WITHOUT rejecting (router-retry
-                # semantics), but here the engine holds the only reference:
-                # an unchecked drop would leave the future hanging forever.
-                self._release_pages(args[2])  # re-allocated on re-admission
-                if not self.queue.add_request(req, reject_on_full=False,
-                                              requeue=True):
-                    self.queue.count_external_drop(
-                        req, reason="requeue_refused"
-                    )
-                    req.reject(RequestDropped(
-                        f"{req.request_id}: queue refused requeue during "
-                        "chunked admission"
-                    ))
-                continue
-            try:
-                fill(*args, free[admitted])
-            except Exception as e:  # noqa: BLE001 — same no-dangle rule
-                logger.exception(
-                    "%s: chunked prefill failed", self.model.name
-                )
-                self._release_pages(args[2])
-                req.reject(e)
-                continue
-            admitted += 1
-        return admitted
 
     # --- token-budget chunked admission (ISSUE 15 tentpole) ----------------
     def _admit_chunked(self, batch: List[Request],
@@ -2222,103 +1702,70 @@ class DecodeEngine:
 
     def _start_train(self, req: Request, prompt: np.ndarray, bucket: int,
                      opts: Dict, slot_idx: int) -> None:
-        """Create the chunk train for one admission: resolve prefix /
-        session reuse (paged: CoW page borrows with the base floored to
-        a page boundary — the partial boundary page belongs to its owner
-        and its positions are in the prompt, so the train recomputes
-        them into its own pages; slab: row-cache seeding exactly like
-        the legacy long path) and park the train for the budget pump.
+        """Create the chunk train for one admission: resolve session
+        reuse (CoW page borrows with the base floored to a page
+        boundary — the partial boundary page belongs to its owner and
+        its positions are in the prompt, so the train recomputes them
+        into its own pages) and park the train for the budget pump.
         Fresh bucketed prompts keep their bucket as the chunk width so
         same-bucket trains group into one program; long prompts and
         seeded continuations chunk at the largest bucket."""
         C_max = self.prompt_buckets[-1]
         total = int(prompt.size)
         base = 0
-        row = None
-        insert_prefix = False
         W = bucket if bucket > 0 else C_max
-        sessions = (self.paged_sessions if self.paged
-                    else self.session_cache)
         hit = None
-        if sessions is not None and opts["session_id"]:
-            hit = sessions.lookup(opts["session_id"], prompt)
+        if self.paged_sessions is not None and opts["session_id"]:
+            hit = self.paged_sessions.lookup(opts["session_id"], prompt)
             if hit is None:
                 opts["_session_miss"] = True
-        if self.paged:
-            opts.setdefault("_pages", [])
-            opts["_shared_pages"] = 0
-            if hit is not None:
-                shared_ids, stored_len = hit
-                n_share = stored_len // self.page_size
-                # Counted at REGISTRATION (_register, via _session_hit):
-                # a starvation-valve requeue re-admits and re-looks-up —
-                # counting here would double-count, the same hazard the
-                # legacy path dodged by counting after the requeue
-                # window.
-                opts["_session_hit"] = True
-                if n_share > 0:
-                    head = list(shared_ids[:n_share])
-                    self._allocator.incref(head)
-                    opts["_pages"] = head
-                    opts["_shared_pages"] = n_share
-                    base = n_share * self.page_size
-                    self._page_journal.record(
-                        "cow_copy", n_share,
-                        self._allocator.allocated_pages, source="session",
-                    )
-                W = C_max
-            # NOTE: prefix-cache lookup is deferred to the train's FIRST
-            # chunk dispatch (_maybe_borrow_prefix) — the legacy fill
-            # path looked up at fill time, after earlier admissions in
-            # the same dequeue had published their pages, and two
-            # identical queued prompts must keep sharing.
-        else:
-            # Slab trains always chunk at the largest bucket: ONE
-            # compiled program set (chunk/commit/seed) serves every
-            # train, and the chunk-granular prefix cache's fixed width
-            # is exactly C_max.
+        opts.setdefault("_pages", [])
+        opts["_shared_pages"] = 0
+        if hit is not None:
+            shared_ids, stored_len = hit
+            n_share = stored_len // self.page_size
+            # Counted at REGISTRATION (_register, via _session_hit): a
+            # starvation-valve requeue re-admits and re-looks-up —
+            # counting here would double-count.
+            opts["_session_hit"] = True
+            if n_share > 0:
+                head = list(shared_ids[:n_share])
+                self._allocator.incref(head)
+                opts["_pages"] = head
+                opts["_shared_pages"] = n_share
+                base = n_share * self.page_size
+                self._page_journal.record(
+                    "cow_copy", n_share,
+                    self._allocator.allocated_pages, source="session",
+                )
             W = C_max
-            row = self.model.make_cache(1, self._long_row_cap(W))
-            if hit is not None:
-                ek, ev, eks, evs, elen = hit
-                opts["_session_hit"] = True  # counted at _register
-                seed_fn, _ = self._session_fns()
-                row = seed_fn(row, ek, ev, eks, evs, jnp.int32(elen))
-                base = int(elen)
-            elif self.prefix_cache is not None and total > W:
-                phit = self.prefix_cache.lookup(prompt)
-                if phit is not None:
-                    _c, _co, seed_fn, _ex = self._long_prefill_fns(W)
-                    row = seed_fn(row, *phit)
-                    base = W
-                    PREFIX_HITS.inc(tags={"model": self.model.name,
-                                          "granularity": "chunk"})
-                else:
-                    insert_prefix = True
-                    PREFIX_MISSES.inc(tags={"model": self.model.name,
-                                            "granularity": "chunk"})
+        # NOTE: prefix-cache lookup is deferred to the train's FIRST
+        # chunk dispatch (_maybe_borrow_prefix): earlier admissions of
+        # the same dequeue have published their pages by then, and two
+        # identical queued prompts must keep sharing.
         self._trains.append(_ChunkTrain(
             req=req, prompt=prompt, opts=opts, slot_idx=slot_idx, C=W,
-            pos=base, base=base, total=total, row=row,
-            insert_prefix=insert_prefix, started_ms=now_ms(),
+            pos=base, base=base, total=total, started_ms=now_ms(),
         ))
         self._train_slots.add(slot_idx)
 
-    def _pump_prefill(self) -> None:
+    def _pump_prefill(self) -> int:
         """Spend at most ``prefill_token_budget`` tokens advancing
-        pending chunk trains — the engine-owned interleave that replaced
-        the count-based admission cap. FCFS head-first (oldest train's
-        TTFT first); paged engines batch same-width trains into ONE
-        chunk program per dispatch. Page-starved trains park for the
+        pending chunk trains — the engine-owned interleave. FCFS
+        head-first (oldest train's TTFT first); same-width trains batch
+        into ONE chunk program per dispatch. Page-starved trains park for the
         round (counted) instead of evicting live streams; a round where
         NOTHING could progress while no stream is active triggers the
         starvation valve (requeue the newest train) so parked trains
-        can never deadlock the pool among themselves."""
+        can never deadlock the pool among themselves. Returns the
+        prefill tokens spent (0: nothing was dispatched)."""
         if not self._trains:
-            return
+            return 0
         with self._phase("rdb.engine.prefill",
                          trains=len(self._trains)) as ph:
-            ph.set_metadata(tokens=self._spend_prefill_budget())
+            tokens = self._spend_prefill_budget()
+            ph.set_metadata(tokens=tokens)
+            return tokens
 
     def _spend_prefill_budget(self) -> int:
         """One round of :meth:`_pump_prefill`; returns the tokens spent."""
@@ -2332,61 +1779,49 @@ class DecodeEngine:
             )
             if head is None or head.C > budget:
                 break
-            if self.paged:
-                members = [head]
-                # Group SINGLE-chunk trains only: a multi-chunk train
-                # dispatches solo so it can complete (and publish its
-                # prefix pages) before an identical queued prompt's
-                # first chunk looks the prefix up — batching two copies
-                # of the same long prompt would compute both.
-                if head.total - head.base <= head.C:
-                    cap = min(self.max_admissions_per_step,
-                              max(1, budget // head.C))
-                    for t in self._trains:
-                        if len(members) >= cap:
-                            break
-                        if (t is head or id(t) in parked
-                                or t.C != head.C
-                                or t.total - t.base > t.C):
-                            continue
-                        members.append(t)
-                ready = []
-                for t in members:
-                    self._maybe_borrow_prefix(t)
-                    if self._grant_train_pages(t):
-                        ready.append(t)
-                    else:
-                        parked.add(id(t))
-                        PREFILL_STARVED.inc(tags=model_tag)
-                if not ready:
-                    continue
-                try:
-                    self._dispatch_chunk_group(ready)
-                except Exception as e:  # noqa: BLE001 — no-dangle rule
-                    logger.exception(
-                        "%s: chunk dispatch failed", self.model.name
-                    )
-                    for t in ready:
-                        self._drop_train(t, e)
-                    continue
-                budget -= head.C * len(ready)
-            else:
-                try:
-                    self._advance_train_slab(head)
-                except Exception as e:  # noqa: BLE001 — no-dangle rule
-                    logger.exception(
-                        "%s: chunk dispatch failed", self.model.name
-                    )
-                    self._drop_train(head, e)
-                    continue
-                budget -= head.C
+            members = [head]
+            # Group SINGLE-chunk trains only: a multi-chunk train
+            # dispatches solo so it can complete (and publish its
+            # prefix pages) before an identical queued prompt's
+            # first chunk looks the prefix up — batching two copies
+            # of the same long prompt would compute both.
+            if head.total - head.base <= head.C:
+                cap = min(self.max_admissions_per_step,
+                          max(1, budget // head.C))
+                for t in self._trains:
+                    if len(members) >= cap:
+                        break
+                    if (t is head or id(t) in parked
+                            or t.C != head.C
+                            or t.total - t.base > t.C):
+                        continue
+                    members.append(t)
+            ready = []
+            for t in members:
+                self._maybe_borrow_prefix(t)
+                if self._grant_train_pages(t):
+                    ready.append(t)
+                else:
+                    parked.add(id(t))
+                    PREFILL_STARVED.inc(tags=model_tag)
+            if not ready:
+                continue
+            try:
+                self._dispatch_chunk_group(ready)
+            except Exception as e:  # noqa: BLE001 — no-dangle rule
+                logger.exception(
+                    "%s: chunk dispatch failed", self.model.name
+                )
+                for t in ready:
+                    self._drop_train(t, e)
+                continue
+            budget -= head.C * len(ready)
             dispatched_any = True
             if self.interleave_hook is not None:
                 # Colocation fairness: co-tenant engines get their scans
-                # between chunk dispatches, exactly as the legacy
-                # ``between=`` callback provided.
+                # between chunk dispatches.
                 self.interleave_hook()
-        if (self.paged and not dispatched_any and self._trains
+        if (not dispatched_any and self._trains
                 and not self._active_mask.any()):
             self._relieve_train_starvation()
         PREFILL_PENDING.set(float(len(self._trains)), tags=model_tag)
@@ -2421,12 +1856,12 @@ class DecodeEngine:
     def _maybe_borrow_prefix(self, train: _ChunkTrain) -> None:
         """Longest-shared-page-prefix CoW borrow, resolved at the
         train's FIRST chunk dispatch (not at dequeue): earlier trains
-        from the same burst publish their pages at completion, and the
-        legacy fill-time lookup let an identical queued prompt share
-        them — dequeue-time lookup would always miss. Borrowed pages
+        from the same burst publish their pages at completion, and an
+        identical queued prompt must share them — a dequeue-time lookup
+        would always miss. Borrowed pages
         become the train's head; ``pos``/``base`` jump past the shared
         positions."""
-        if (not self.paged or self.paged_prefix is None
+        if (self.paged_prefix is None
                 or train.pos != train.base or train.pos != 0
                 or train.opts.get("_shared_pages", 0)
                 or train.opts.get("_prefix_done")
@@ -2572,7 +2007,7 @@ class DecodeEngine:
                 if self.paged_prefix is not None:
                     # Publish BEFORE registration: a stop-on-first-token
                     # finish frees the slot's pages, and the insert must
-                    # pin them first (the legacy after_commit contract).
+                    # pin them first.
                     self.paged_prefix.insert(t.prompt, t.opts["_pages"])
                 if self._dcache is not None:
                     # The draft has no pages-direct path (its cache is a
@@ -2585,52 +2020,6 @@ class DecodeEngine:
                                t.opts, t_fetched)
         self._log_dispatch("chunk", t_dispatch, t_issued, t_fetched, 0,
                            W * n, active, pending, moe)
-
-    def _advance_train_slab(self, train: _ChunkTrain) -> None:
-        """One row-cache chunk for a slab train (the legacy chunk
-        program under the token budget); the final chunk flows into the
-        fused commit+sample dispatch via ``_commit_and_register``."""
-        C = train.C
-        active = int(self._active_mask.sum())
-        pending = len(self._trains)
-        with self._phase("rdb.engine.prefill.prepare"):
-            chunk_fn, commit_fn, _seed, extract_fn = \
-                self._long_prefill_fns(C)
-            piece = train.prompt[train.pos : train.pos + C]
-            take = int(piece.size)
-            tokens = np.zeros((1, C), np.int32)
-            mask = np.zeros((1, C), np.int32)
-            tokens[0, :take] = piece
-            mask[0, :take] = 1
-        t_dispatch = now_ms()
-        with self._phase("rdb.engine.prefill.dispatch"):
-            train.last, train.row = chunk_fn(
-                self.params, jnp.asarray(tokens), jnp.asarray(mask),
-                train.row, jnp.int32(train.pos), jnp.int32(take - 1),
-            )
-        t_issued = now_ms()
-        t_fetched = 0.0
-        with self._phase("rdb.engine.prefill.finish"):
-            if train.insert_prefix and train.pos == 0 and take == C:
-                # Chunk 0 was full: its k/v depend only on the first C
-                # token ids — exactly reusable (the legacy after_first
-                # hook).
-                self.prefix_cache.insert(
-                    train.prompt, *extract_fn(train.row, C)
-                )
-            train.pos += take
-            PREFILL_CHUNKS.inc(tags={"model": self.model.name})
-            if train.pos >= train.total:
-                # The fused commit fetches the first token: the slab
-                # train's one fetch, inside its finish.
-                self._retire_train(train)
-                self._commit_and_register(
-                    train.req, train.prompt, train.opts, train.slot_idx,
-                    commit_fn, train.row, train.last, C,
-                )
-                t_fetched = now_ms()
-        self._log_dispatch("chunk", t_dispatch, t_issued, t_fetched, 0, C,
-                           active, pending)
 
     def _retire_train(self, train: _ChunkTrain) -> None:
         if train in self._trains:
@@ -2654,8 +2043,8 @@ class DecodeEngine:
         slot's worth by construction, and with no actives + drained
         cache pins nothing else holds pages) — but if it ever happens,
         requeue it too: back in the queue, deadline-based staleness
-        eventually rejects it, the legacy page-starvation economics,
-        instead of the loop spinning on an unservable train forever.
+        eventually rejects it, instead of the loop spinning on an
+        unservable train forever.
         Prefer a train that has not dispatched yet (pos == base): zero
         sunk prefill cost AND no metrics to double-count."""
         if not self._trains:
@@ -2677,54 +2066,6 @@ class DecodeEngine:
             ))
 
     # --- paged admission bookkeeping ---------------------------------------
-    def _alloc_admission_pages(self, req: Request, prompt: np.ndarray,
-                               opts: Dict) -> bool:
-        """Reserve the pages an admission needs (prompt + the first
-        generated token's KV, MINUS any session pages the CoW borrow
-        already covers — a long-history continuation must not demand its
-        whole prompt's worth of free pages). Under pressure, cache pins
-        (prefix/session entries) are shed before giving up. Page
-        starvation is slot starvation's twin: the request goes back to
-        the queue untouched and waits for EOS frees, exactly like a
-        slot-starved single — never silently dropped.
-
-        Spec engines reserve the first verify window's headroom
-        alongside the KV (``pages_for(len + spec_tokens + 1)`` — THE
-        shared round rule, ``tile_math.spec_scratch_pages``, called
-        here with len = prompt size since the pending first token is
-        row 0 OF the window): a slot admitted into a pool that cannot
-        even host one round would otherwise thrash the round-scratch
-        reclaim path from its very first step."""
-        if self._dcache is not None:
-            need_pages = spec_scratch_pages(
-                int(prompt.size), self.spec_tokens + 1, self.page_size,
-                self._paged_capacity,
-            )
-        else:
-            need_pages = pages_for(int(prompt.size) + 1, self.page_size)
-        need = max(0, need_pages
-                   - int(opts.get("_session_share", 0)))
-        while True:
-            try:
-                opts["_pages"] = self._allocator.alloc(need)
-                return True
-            except OutOfPages:
-                if self._reclaim_cache_pins():
-                    continue
-                break
-        hold = opts.pop("_session_hold", None)
-        opts.pop("_session_share", None)
-        if hold:
-            self._allocator.decref(hold)
-        if not self.queue.add_request(req, reject_on_full=False,
-                                      requeue=True):
-            self.queue.count_external_drop(req, reason="requeue_refused")
-            req.reject(RequestDropped(
-                f"{req.request_id}: queue refused requeue during "
-                "page-starved admission"
-            ))
-        return False
-
     def _read_pages(self, page_ids: List[int]) -> Dict[str, np.ndarray]:
         """Gather the listed pages' contents to host (spill). The pages
         are pinned (prefix-cache refs) and never rewritten after
@@ -2845,533 +2186,26 @@ class DecodeEngine:
     def _release_pages(self, opts: Dict) -> None:
         """Undo an admission's page reservation (failed/requeued before
         a slot took ownership). Decrefs the whole list — borrowed CoW
-        pages release their borrow, private pages free — plus any
-        outstanding session seed-read hold (whole, or its post-swap
-        tail)."""
-        if not self.paged:
-            return
+        pages release their borrow, private pages free."""
         pages = opts.pop("_pages", None)
         opts.pop("_shared_pages", None)
-        opts.pop("_session_share", None)
         if pages:
             self._allocator.decref(pages)
-        for key in ("_session_hold", "_hold_tail"):
-            hold = opts.pop(key, None)
-            if hold:
-                self._allocator.decref(hold)
 
-    def _prefill_group(
-        self,
-        bucket: int,
-        items: List[Tuple[Request, np.ndarray, Dict]],
-        slot_ids: List[int],
-    ) -> None:
-        n = len(items)
-        group = next(s for s in self._admit_group_sizes() if s >= n)
-        tokens = np.zeros((group, bucket), dtype=np.int32)
-        mask = np.zeros((group, bucket), dtype=np.int32)
-        slots = np.zeros((group,), dtype=np.int32)
-        temps = np.zeros((group,), dtype=np.float32)
-        topk = np.zeros((group,), dtype=np.int32)
-        topp = np.ones((group,), dtype=np.float32)
-        seeds = np.zeros((group,), dtype=np.int32)
-        bias_ids = np.zeros((group, self.max_bias_entries), dtype=np.int32)
-        bias_vals = np.zeros((group, self.max_bias_entries),
-                             dtype=np.float32)
-        for i, (req, prompt, opts) in enumerate(items):
-            tokens[i, : prompt.size] = prompt
-            mask[i, : prompt.size] = 1
-            slots[i] = slot_ids[i]
-            temps[i] = opts["temperature"]
-            topk[i] = opts["top_k"]
-            topp[i] = opts.get("top_p", 1.0)
-            seeds[i] = opts["seed"]
-            bias_ids[i], bias_vals[i] = self._bias_arrays(opts)
-        # Pad rows duplicate row 0 (same slot, same data — idempotent write).
-        for i in range(n, group):
-            tokens[i] = tokens[0]
-            mask[i] = mask[0]
-            slots[i] = slots[0]
-            temps[i] = temps[0]
-            topk[i] = topk[0]
-            topp[i] = topp[0]
-            seeds[i] = seeds[0]
-            bias_ids[i] = bias_ids[0]
-            bias_vals[i] = bias_vals[0]
-
-        # Dtype-packed uploads: 5 transfers per admission group instead
-        # of 10 (tok_idx is constant zero — prefill samples token 0 — so
-        # it rides the int pack), and the draft prefill reuses the SAME
-        # device buffers instead of re-uploading tokens/mask/slots.
-        tokmask_d = jnp.asarray(np.stack([tokens, mask]))
-        meta_i_d = jnp.asarray(np.stack([
-            slots, topk, seeds, np.zeros((group,), np.int32),
-        ]))
-        meta_f_d = jnp.asarray(np.stack([temps, topp]))
-        extra = ()
-        if self.paged:
-            # Physical destination pages per admitted row (sentinel
-            # tail); pad rows duplicate row 0's pages — identical data
-            # to identical pages, idempotent like the slot duplicate.
-            pids = np.full((group, self._n_table_entries), self.num_pages,
-                           dtype=np.int32)
-            for i, (_req, _prompt, opts) in enumerate(items):
-                pids[i] = table_array(opts["_pages"],
-                                      self._n_table_entries, self.num_pages)
-            for i in range(n, group):
-                pids[i] = pids[0]
-            extra = (jnp.asarray(pids),)
-        active = int(self._active_mask.sum())
-        t_dispatch = now_ms()
-        with self._phase("rdb.engine.prefill.dispatch"):
-            first, self._cache = self._prefill_fn(bucket, group)(
-                self.params,
-                tokmask_d,
-                self._cache,
-                meta_i_d,
-                meta_f_d,
-                jnp.asarray(bias_ids),
-                jnp.asarray(bias_vals),
-                *extra,
-            )
-            if self._dcache is not None:
-                # The draft must see the same prompt: fill its cache rows
-                # too.
-                self._dcache = self._draft_prefill_fn(bucket, group)(
-                    self.draft_params,
-                    tokmask_d,
-                    self._dcache,
-                    meta_i_d,
-                )
-        t_issued = now_ms()
-        with self._phase("rdb.engine.prefill.fetch"):
-            first_host = np.asarray(first)  # ONE fetch for the whole group
-        t = now_ms()
-        for i, (req, _prompt, opts) in enumerate(items):
-            self._register(slot_ids[i], req, int(first_host[i]), opts, t)
-        self._log_dispatch("prefill", t_dispatch, t_issued, t, 0,
-                           bucket * n, active, len(self._trains))
-
-    # --- chunked prefill (long prompts) ------------------------------------
-    def _prefill_chunk_impl(self, params, tokens, attn_mask, row_cache,
-                            start, take_idx):
-        return self.model.prefill_chunk(
-            self._mp(params), tokens, attn_mask, row_cache, start, take_idx
-        )
-
-    def _commit_long_impl(self, cache, row_cache, meta_i, last_logits,
-                          meta_f, bias_ids, bias_vals):
-        """Copy the finished row cache into the big cache at ``slot`` and
-        sample the first token — one dispatch closes the admission. The row
-        cache is a whole number of chunks, so it can be LONGER than the
-        shared cache; the static slice keeps only real capacity (positions
-        past ``lengths`` are garbage either way and never attended).
-        ``meta_i`` [3] packs slot/top_k/seed, ``meta_f`` [2] packs
-        temperature/top_p (tok_idx is always 0 for a first sample)."""
-        cache = commit_row(cache, row_cache, meta_i[0])
-        first = self._sample_tokens(
-            last_logits, meta_f[0:1], meta_i[1:2], meta_i[2:3],
-            jnp.zeros((1,), jnp.int32), bias_ids, bias_vals, meta_f[1:2],
-        )
-        return first, cache
-
-    def _seed_prefix_impl(self, row_cache, pk, pv, pks, pvs):
-        """Copy a cached prefix segment into positions [0, C) of a fresh
-        row cache — the HBM-copy replacement for recomputing chunk 0.
-        One seed implementation serves both reuse paths (a parallel copy
-        here once dropped the scale planes): the prefix segment's valid
-        length is simply its width."""
-        return self._seed_session_impl(
-            row_cache, pk, pv, pks, pvs, pk.shape[2]
-        )
-
-    def _extract_prefix_impl(self, row_cache, width: int):
-        """Static slice of the first ``width`` cache positions (the just-
-        computed chunk 0) for insertion into the prefix cache — codes,
-        and scale planes when the cache is quantized."""
-        ks = vs = None
-        if row_cache.quantized:
-            ks = row_cache.k_scale[:, :, :width]
-            vs = row_cache.v_scale[:, :, :width]
-        return (row_cache.k[:, :, :width], row_cache.v[:, :, :width],
-                ks, vs)
-
-    def _commit_long_paged_impl(self, cache, row_cache, meta_i,
-                                last_logits, meta_f, bias_ids, bias_vals,
-                                write_pids):
-        """Paged mirror of :meth:`_commit_long_impl`: the finished row is
-        page-cut and scattered at ``write_pids`` [1, NP] (sentinel for
-        borrowed CoW pages — the shared prefix is never rewritten — and
-        the unallocated tail), then the first token samples."""
-        cache = copy_rows_into_paged(cache, row_cache, meta_i[0:1],
-                                     write_pids)
-        first = self._sample_tokens(
-            last_logits, meta_f[0:1], meta_i[1:2], meta_i[2:3],
-            jnp.zeros((1,), jnp.int32), bias_ids, bias_vals, meta_f[1:2],
-        )
-        return first, cache
-
-    def _seed_paged_impl(self, row_cache, cache, table_row, elen):
-        """Gather a page run (``table_row`` [NP] int32, sentinel-padded)
-        into positions [0, S) of a fresh row cache and mark ``elen``
-        valid — how a CoW borrower sees its shared prefix KV during the
-        tail prefill. Sentinel entries clamp to a real page; everything
-        past ``elen`` is garbage the tail fill overwrites or the mask
-        never attends (the standard invariant)."""
-        P = cache.k.shape[1]
-        safe = jnp.minimum(table_row, P - 1)
-        S = self._paged_capacity
-
-        def logical(arr):
-            g = arr[:, safe]  # [L, NP, ps, ...]
-            return g.reshape((arr.shape[0], 1, S) + arr.shape[3:])
-
-        # Lane-padded pool rows are cut back to the row cache's head.
-        H = row_cache.k.shape[-1]
-        k = jax.lax.dynamic_update_slice(
-            row_cache.k, fit_head_dim(logical(cache.k), H), (0, 0, 0, 0, 0)
-        )
-        v = jax.lax.dynamic_update_slice(
-            row_cache.v, fit_head_dim(logical(cache.v), H), (0, 0, 0, 0, 0)
-        )
-        scales = {}
-        if cache.k_scale is not None:
-            scales = {
-                "k_scale": jax.lax.dynamic_update_slice(
-                    row_cache.k_scale, logical(cache.k_scale), (0, 0, 0, 0)
-                ),
-                "v_scale": jax.lax.dynamic_update_slice(
-                    row_cache.v_scale, logical(cache.v_scale), (0, 0, 0, 0)
-                ),
-            }
-        return row_cache.replace(
-            k=k, v=v, lengths=jnp.full_like(row_cache.lengths, elen),
-            **scales,
-        )
-
-    def _paged_seed_fn(self) -> Callable:
-        fn = self._prefill_fns.get("paged_seed")
-        if fn is None:
-            fn = instrument("paged_seed", jax.jit(
-                self._seed_paged_impl, donate_argnums=(0,)
-            ))
-            self._prefill_fns["paged_seed"] = fn
-        return fn
-
-    def _long_prefill_fns(self, chunk: int):
-        """Lazily compiled (chunk, commit, seed, extract) fns — long
-        prompts may never arrive, so their programs are not part of warmup;
-        the persistent compilation cache absorbs the first-hit cost across
-        restarts."""
-        fns = self._prefill_fns.get(("long", chunk))
-        if fns is None:
-            fns = (
-                instrument("long_chunk", jax.jit(
-                    self._prefill_chunk_impl, donate_argnums=(3,)
-                )),
-                # Only the shared cache (arg 0) can alias the output; the
-                # row cache's [L,1,row_cap,K,H] matches no output shape, so
-                # donating it buys nothing and warns on every compile.
-                instrument(
-                    "long_commit_paged" if self.paged else "long_commit",
-                    jax.jit(self._commit_long_paged_impl if self.paged
-                            else self._commit_long_impl,
-                            donate_argnums=(0,)),
-                ),
-                instrument("prefix_seed", jax.jit(
-                    self._seed_prefix_impl, donate_argnums=(0,)
-                )),
-                instrument("prefix_extract", jax.jit(
-                    self._extract_prefix_impl, static_argnums=(1,)
-                )),
-            )
-            self._prefill_fns[("long", chunk)] = fns
-        return fns
-
-    def _long_row_cap(self, C: int) -> int:
-        """Row-cache capacity for chunked fills: whole chunks covering
-        max_len PLUS one spare chunk. The spare absorbs the final chunk of
-        an UNALIGNED continuation (session base need not be a multiple of
-        C) — without it, dynamic_update_slice CLAMPS the overrunning start
-        index and silently overwrites earlier positions. One static shape
-        for every prompt length and base, so all fills share programs; the
-        commit slices back down to shared capacity. Paged engines
-        additionally cover the page-rounded logical capacity, so the
-        commit's page cut always has whole pages to slice."""
-        cap = self._paged_capacity if self.paged else self.max_len
-        return ((cap + C - 1) // C) * C + C
-
+    # --- the draft model's prompt replay (speculative engines) -------------
     def _interleave_step(self) -> None:
-        """One plain decode step for the active batch between chunk
-        dispatches — the bound that keeps a long fill from stalling
-        in-flight requests for more than one chunk. When a colocation
-        executor hosts this engine it installs ``interleave_hook``, so
-        CO-TENANT engines get scans between chunks too — otherwise one
-        tenant's long-prompt admission would monopolize the shared chip
-        for the whole fill (engine/colocate.py)."""
+        """One plain decode step for the active batch between the chunk
+        dispatches of a draft replay — the bound that keeps a long fill
+        from stalling in-flight requests for more than one chunk. When a
+        colocation executor hosts this engine it installs
+        ``interleave_hook``, so CO-TENANT engines get scans between chunks
+        too — otherwise one tenant's long-prompt admission would
+        monopolize the shared chip for the whole fill
+        (engine/colocate.py)."""
         if self._active_mask.any():
             self._step(horizon=1)
         if self.interleave_hook is not None:
             self.interleave_hook()
-
-    def _commit_and_register(
-        self, req: Request, prompt: np.ndarray, opts: Dict, slot_idx: int,
-        commit_fn: Callable, row, last, C: int,
-        after_commit: Optional[Callable[[], None]] = None,
-    ) -> None:
-        """Shared tail of every chunked admission (long and session): one
-        commit dispatch (row -> shared cache + first-token sample), the
-        draft replay when speculation is on, then registration.
-        ``after_commit`` runs between commit and registration — the
-        paged prefix-publish hook, which must see committed pages but
-        must run BEFORE a stop-on-first-token registration can free
-        them."""
-        bids, bvals = self._bias_arrays(opts)
-        extra = ()
-        if self.paged:
-            shared = int(opts.get("_shared_pages", 0))
-            wp = list(opts["_pages"])
-            # Borrowed CoW pages: steered to the sentinel so the commit
-            # scatter cannot rewrite them (first divergent position lands
-            # in the first PRIVATE page by the share-length rule).
-            wp[:shared] = [self.num_pages] * shared
-            extra = (jnp.asarray(table_array(
-                wp, self._n_table_entries, self.num_pages
-            )[None]),)
-        first, self._cache = commit_fn(
-            self._cache,
-            row,
-            jnp.asarray(np.asarray(
-                [slot_idx, opts["top_k"], opts["seed"]], np.int32
-            )),
-            last,
-            jnp.asarray(np.asarray(
-                [opts["temperature"], opts["top_p"]], np.float32
-            )),
-            jnp.asarray(bids[None]),
-            jnp.asarray(bvals[None]),
-            *extra,
-        )
-        if after_commit is not None:
-            after_commit()
-        if self._dcache is not None:
-            self._draft_long_fill(prompt, slot_idx, C)
-        self._register(slot_idx, req, int(np.asarray(first)[0]), opts,
-                       now_ms())
-
-    def _prefill_long(
-        self, req: Request, prompt: np.ndarray, opts: Dict, slot_idx: int
-    ) -> None:
-        """Admit one prompt longer than every bucket: prefill it in
-        ``chunk``-token compiled pieces into a private single-row cache,
-        running ONE decode step for the active batch between chunks so a
-        10k-token prompt stalls decoding by at most one chunk's latency
-        (chunked-prefill admission), then commit the row into the shared
-        cache. The reference has no analogue (single-shot vision)."""
-        C = self.prompt_buckets[-1]
-        chunk_fn, commit_fn, seed_fn, extract_fn = self._long_prefill_fns(C)
-        L = int(prompt.size)
-        n_chunks = (L + C - 1) // C
-        row = self.model.make_cache(1, self._long_row_cap(C))
-        start_chunk = 0
-        base = 0
-        after_first = None
-        after_commit = None
-        if self.paged and self.paged_prefix is not None:
-            # Page-granular reuse: borrow the longest shared page-prefix
-            # by reference (CoW — the boundary partial page and the tail
-            # recompute into PRIVATE pages via the row), and publish this
-            # prompt's own full-page prefixes once they are committed.
-            hit = self.paged_prefix.lookup(prompt)
-            if hit is None and self.host_spill is not None:
-                # Host-RAM spill tier: a prefix shed under pool pressure
-                # reloads instead of recomputing (journaled as "reload").
-                hit = self._reload_spilled_prefix(prompt)
-            if hit is not None:
-                shared_ids, shared_len = hit
-                self._swap_in_shared(opts, shared_ids)
-                row = self._paged_seed_fn()(
-                    row, self._cache,
-                    jnp.asarray(table_array(
-                        shared_ids, self._n_table_entries, self.num_pages
-                    )),
-                    jnp.int32(shared_len),
-                )
-                base = shared_len
-                PREFIX_HITS.inc(tags={"model": self.model.name,
-                                      "granularity": "page"})
-            else:
-                PREFIX_MISSES.inc(tags={"model": self.model.name,
-                                        "granularity": "page"})
-            after_commit = lambda: self.paged_prefix.insert(  # noqa: E731
-                prompt, opts["_pages"]
-            )
-        elif self.prefix_cache is not None:
-            # Chunk 0 is full (n_chunks >= 2 on this path), so its k/v
-            # depend only on the first C token ids — exactly reusable.
-            hit = self.prefix_cache.lookup(prompt)
-            if hit is not None:
-                row = seed_fn(row, *hit)
-                start_chunk = 1
-                PREFIX_HITS.inc(tags={"model": self.model.name,
-                                      "granularity": "chunk"})
-            else:
-                after_first = lambda r: self.prefix_cache.insert(  # noqa: E731
-                    prompt, *extract_fn(r, C)
-                )
-                PREFIX_MISSES.inc(tags={"model": self.model.name,
-                                        "granularity": "chunk"})
-
-        last, row = run_chunked(
-            chunk_fn, self.params, prompt[base:], C, row,
-            start_chunk=start_chunk, between=self._interleave_step,
-            after_first=after_first, base=base,
-        )
-        self._commit_and_register(
-            req, prompt, opts, slot_idx, commit_fn, row, last, C,
-            after_commit=after_commit,
-        )
-
-    def _seed_session_impl(self, row_cache, ek, ev, eks, evs, elen):
-        """Copy a stored session row ([L,1,S,K,H]) into a fresh row cache
-        and mark ``elen`` positions valid. ``eks``/``evs`` are the row's
-        scale planes (int8 caches) or None."""
-        k = jax.lax.dynamic_update_slice(row_cache.k, ek, (0, 0, 0, 0, 0))
-        v = jax.lax.dynamic_update_slice(row_cache.v, ev, (0, 0, 0, 0, 0))
-        scales = {}
-        if eks is not None:
-            scales = {
-                "k_scale": jax.lax.dynamic_update_slice(
-                    row_cache.k_scale, eks, (0, 0, 0, 0)),
-                "v_scale": jax.lax.dynamic_update_slice(
-                    row_cache.v_scale, evs, (0, 0, 0, 0)),
-            }
-        return row_cache.replace(
-            k=k, v=v, lengths=jnp.full_like(row_cache.lengths, elen),
-            **scales,
-        )
-
-    def _extract_row_impl(self, cache, slot):
-        """Slice one slot's full cache row out of the shared cache (the
-        finished turn's KV, stored for the session's next turn) — codes
-        plus scale planes when the cache is quantized."""
-        k = jax.lax.dynamic_slice_in_dim(cache.k, slot, 1, axis=1)
-        v = jax.lax.dynamic_slice_in_dim(cache.v, slot, 1, axis=1)
-        ks = vs = None
-        if cache.quantized:
-            ks = jax.lax.dynamic_slice_in_dim(
-                cache.k_scale, slot, 1, axis=1)
-            vs = jax.lax.dynamic_slice_in_dim(
-                cache.v_scale, slot, 1, axis=1)
-        return k, v, ks, vs
-
-    def _session_fns(self):
-        fns = self._prefill_fns.get("session")
-        if fns is None:
-            fns = (
-                instrument("session_seed", jax.jit(
-                    self._seed_session_impl, donate_argnums=(0,)
-                )),
-                instrument("session_extract",
-                           jax.jit(self._extract_row_impl)),
-            )
-            self._prefill_fns["session"] = fns
-        return fns
-
-    def _prefill_session(
-        self, req: Request, prompt: np.ndarray, opts: Dict, hit: Tuple,
-        slot_idx: int,
-    ) -> None:
-        """Continue a conversation from its stored KV: seed the row cache
-        with the previous turn's row, chunk-prefill ONLY the new tail
-        (traced start — the base need not be chunk-aligned), and commit.
-        Turn-N admission cost scales with the new message, not the whole
-        history."""
-        ek, ev, eks, evs, elen = hit
-        SESSION_HITS.inc(tags={"model": self.model.name})
-        C = self.prompt_buckets[-1]
-        chunk_fn, commit_fn, _seed_prefix, _extract = \
-            self._long_prefill_fns(C)
-        seed_fn, _ = self._session_fns()
-        row = self.model.make_cache(1, self._long_row_cap(C))
-        row = seed_fn(row, ek, ev, eks, evs, jnp.int32(elen))
-        tail = prompt[elen:]
-        last, row = run_chunked(
-            chunk_fn, self.params, tail, C, row,
-            between=self._interleave_step, base=elen,
-        )
-        # The draft replay inside covers the WHOLE prompt (the draft has
-        # no stored row) so speculation starts synced.
-        self._commit_and_register(
-            req, prompt, opts, slot_idx, commit_fn, row, last, C
-        )
-
-    def _swap_in_shared(self, opts: Dict, shared_ids: List[int]) -> None:
-        """CoW borrow at admission: pin the shared pages (incref), hand
-        back the equivalent leading PRIVATE pages reserved at admission,
-        and splice — ``opts['_pages']`` stays the slot's full logical
-        run, with ``_shared_pages`` marking the borrowed (never-written)
-        head. Incref-before-decref so nothing transits refcount 0."""
-        n = len(shared_ids)
-        pages = opts["_pages"]
-        self._allocator.incref(shared_ids)
-        self._allocator.decref(pages[:n])
-        opts["_pages"] = list(shared_ids) + pages[n:]
-        opts["_shared_pages"] = n
-        self._page_journal.record(
-            "cow_copy", n, self._allocator.allocated_pages, source="prefix"
-        )
-
-    def _prefill_session_paged(
-        self, req: Request, prompt: np.ndarray, opts: Dict, hit: Tuple,
-        slot_idx: int,
-    ) -> None:
-        """Paged session continuation: borrow the stored turn's FULL
-        pages by reference, seed the row cache from the whole stored run
-        (the partial boundary page's content rides into the row — its
-        private copy is made by the commit, which is the copy-on-write),
-        chunk-prefill only the new tail, and commit tail pages as
-        private."""
-        shared_ids, stored_len = hit
-        SESSION_HITS.inc(tags={"model": self.model.name})
-        C = self.prompt_buckets[-1]
-        chunk_fn, commit_fn, _seed, _extract = self._long_prefill_fns(C)
-        # Only COMPLETE pages are borrowed: the boundary page would be
-        # written by the borrower (positions >= stored_len) and must
-        # diverge into a private copy. The admission hold (taken at
-        # lookup) pins ALL stored pages, and the admission reserved only
-        # the NON-shared tail: transfer the full-page head of the hold
-        # into the slot's borrow, keep the hold's tail pinned until the
-        # seed has read it and the commit has written its private copy.
-        n_share = stored_len // self.page_size
-        opts.pop("_session_hold", None)  # split into borrow + tail below
-        opts.pop("_session_share", None)
-        opts["_pages"] = list(shared_ids[:n_share]) + opts["_pages"]
-        opts["_shared_pages"] = n_share
-        opts["_hold_tail"] = list(shared_ids[n_share:])
-        self._page_journal.record(
-            "cow_copy", n_share, self._allocator.allocated_pages,
-            source="session",
-        )
-        row = self.model.make_cache(1, self._long_row_cap(C))
-        row = self._paged_seed_fn()(
-            row, self._cache,
-            jnp.asarray(table_array(
-                shared_ids, self._n_table_entries, self.num_pages
-            )),
-            jnp.int32(stored_len),
-        )
-        tail = prompt[stored_len:]
-        last, row = run_chunked(
-            chunk_fn, self.params, tail, C, row,
-            between=self._interleave_step, base=stored_len,
-        )
-        self._commit_and_register(
-            req, prompt, opts, slot_idx, commit_fn, row, last, C
-        )
-        hold_tail = opts.pop("_hold_tail", None)
-        if hold_tail:
-            self._allocator.decref(hold_tail)
 
     def _draft_long_fill(self, prompt: np.ndarray, slot_idx: int,
                          C: int) -> None:
@@ -3379,7 +2213,7 @@ class DecodeEngine:
         row, interleaving decode steps between chunks like the target fill
         — the chunked-prefill latency bound (one chunk's stall, not the
         whole prompt) must hold for the draft pass too."""
-        fns = self._prefill_fns.get(("draft_long", C))
+        fns = self._draft_fill_fns.get(C)
         if fns is None:
             def chunk_impl(dparams, tokens, attn_mask, row, start, take):
                 return self.draft_model.prefill_chunk(
@@ -3392,10 +2226,10 @@ class DecodeEngine:
                 instrument("draft_long_commit",
                            jax.jit(commit_row, donate_argnums=(0,))),
             )
-            self._prefill_fns[("draft_long", C)] = fns
+            self._draft_fill_fns[C] = fns
         chunk_fn, commit_fn = fns
-        # Chunk-aligned (base 0 always): the unaligned-base spare chunk of
-        # _long_row_cap is a target-path (session continuation) concern.
+        # Whole chunks covering the draft cache: the replay starts at
+        # position 0, so every chunk is aligned.
         dcap = self._dcache.capacity
         row = self.draft_model.make_cache(1, ((dcap + C - 1) // C) * C)
         _, row = run_chunked(
@@ -3419,16 +2253,15 @@ class DecodeEngine:
         slot.session_id = opts.get("session_id")
         slot.prompt_tokens = opts.get("_prompt_tokens")
         self._len_host[slot_idx] = int(opts.get("_cache_len", 0))
-        if self.paged:
-            # Ownership handoff: the slot now holds the admission's page
-            # reservation; the host table mirror maps it for the next
-            # dispatch's refresh.
-            slot.pages = list(opts.get("_pages", ()))
-            slot.shared_pages = int(opts.get("_shared_pages", 0))
-            self._table_host[slot_idx] = table_array(
-                slot.pages, self._n_table_entries, self.num_pages
-            )
-            self._table_dirty = True
+        # Ownership handoff: the slot now holds the admission's page
+        # reservation; the host table mirror maps it for the next
+        # dispatch's refresh.
+        slot.pages = list(opts.get("_pages", ()))
+        slot.shared_pages = int(opts.get("_shared_pages", 0))
+        self._table_host[slot_idx] = table_array(
+            slot.pages, self._n_table_entries, self.num_pages
+        )
+        self._table_dirty = True
         self._tokens[slot_idx, 0] = first_tok
         self._active_mask[slot_idx] = True
         self._temps[slot_idx] = opts["temperature"]
@@ -3451,9 +2284,7 @@ class DecodeEngine:
 
         PREFILLS_TOTAL.inc(tags={"model": self.model.name})
         if opts.get("_session_hit"):
-            # Chunked trains count their session hit here, past every
-            # requeue window (mono session fills count at fill start —
-            # they are equally past it).
+            # Counted here, past every requeue window.
             SESSION_HITS.inc(tags={"model": self.model.name})
         if opts.get("_session_miss"):
             SESSION_MISSES.inc(tags={"model": self.model.name})
@@ -3507,14 +2338,19 @@ class DecodeEngine:
         slot = self._slots[slot_idx]
         req = slot.request
         t = now_ms()
-        if self.paged and slot.pages:
+        if slot.pages:
             if (self.paged_sessions is not None and slot.session_id
                     and slot.prompt_tokens is not None):
                 # O(1) session store: pin the pages covering the turn's
-                # history (prompt + generated[:-1] — same stored-history
-                # rule as the slab path) instead of copying the row out.
-                # Incref (store) strictly before the slot's decref below,
-                # so the pages never transit the free list.
+                # history (prompt + generated[:-1]: the final token is
+                # still pending, never fed) instead of copying the row
+                # out. Cached positions past the history (spec rounds
+                # advance the cache through tokens the host truncated at
+                # a stop) sit beyond the stored length and are
+                # overwritten by the next turn's tail prefill before
+                # they can be attended. Incref (store) strictly before
+                # the slot's decref below, so the pages never transit
+                # the free list.
                 history = np.concatenate([
                     np.asarray(slot.prompt_tokens, np.int32),
                     np.asarray(slot.generated[:-1], np.int32),
@@ -3523,22 +2359,6 @@ class DecodeEngine:
                     slot.session_id, slot.pages, history
                 )
             self._free_slot_pages(slot_idx)
-        if (self.session_cache is not None and slot.session_id
-                and slot.prompt_tokens is not None):
-            # The cache row holds prompt + generated[:-1] (the final token
-            # is still pending, never fed). Store the row + that exact
-            # history so the session's next turn continues from it. Any
-            # cached positions past the history (spec rounds advance the
-            # cache through tokens the host truncated at a stop) sit
-            # beyond the stored length and are overwritten by the next
-            # turn's tail prefill before they can be attended.
-            _, extract_fn = self._session_fns()
-            seg = extract_fn(self._cache, jnp.int32(slot_idx))
-            history = np.concatenate([
-                np.asarray(slot.prompt_tokens, np.int32),
-                np.asarray(slot.generated[:-1], np.int32),
-            ])
-            self.session_cache.store(slot.session_id, seg, history)
         result = DecodeResult(
             tokens=list(slot.generated),
             finish_reason=reason,
@@ -3578,12 +2398,12 @@ class DecodeEngine:
         # overhead at high completion churn.
         self.completed += 1
 
-    # --- page-pool management (paged mode) --------------------------------
+    # --- page-pool management ----------------------------------------------
     def _free_slot_pages(self, slot_idx: int) -> None:
         """Return a finished slot's page references to the pool — EOS
         frees pages immediately mid-cycle: this runs inside ``_harvest``,
         before the next admission check, so a burst waiting on pages can
-        admit the moment a stream ends instead of at slab granularity.
+        admit the moment a stream ends.
         The device table row goes to sentinel at the next refresh, which
         happens before any dispatch could write through it."""
         slot = self._slots[slot_idx]
@@ -3797,9 +2617,9 @@ class DecodeEngine:
         Under pool pressure, cache pins shed first (same ladder as
         :meth:`_ensure_page_headroom`); if the pool still cannot host a
         window, every page taken for THIS round is returned and the
-        caller degrades to a plain paged step — speculation is an
-        optimization, and the degradation is bounded (the non-spec paged
-        arm), never a truncated live stream."""
+        caller degrades to a plain step — speculation is an
+        optimization, and the degradation is bounded (plain decode),
+        never a truncated live stream."""
         win = self.spec_tokens + 1
         for i in np.flatnonzero(self._active_mask):
             slot = self._slots[i]
@@ -3897,22 +2717,20 @@ class DecodeEngine:
         k = self.spec_tokens
         with self._phase("rdb.engine.turn") as ph:
             with self._phase("rdb.engine.turn.prepare"):
-                reserved = True
-                if self.paged:
-                    if self._spec_scratch:
-                        # A previous round died between reserve and
-                        # splice (a device error the loop swallowed): its
-                        # scratch would otherwise leak refcounts forever
-                        # and shadow-occupy the pool. Roll it back before
-                        # arranging a fresh window.
-                        self._rollback_spec_scratch()
-                    reserved = self._reserve_spec_scratch()
+                if self._spec_scratch:
+                    # A previous round died between reserve and splice
+                    # (a device error the loop swallowed): its scratch
+                    # would otherwise leak refcounts forever and
+                    # shadow-occupy the pool. Roll it back before
+                    # arranging a fresh window.
+                    self._rollback_spec_scratch()
+                reserved = self._reserve_spec_scratch()
             if not reserved:
                 # Pool too tight for a verify window this round: one
-                # plain paged step instead (its own headroom ladder may
+                # plain step instead (its own headroom ladder may
                 # capacity-evict, but the spec path never does) — under
-                # sustained pressure throughput degrades to the non-spec
-                # paged arm, not off a cliff.
+                # sustained pressure throughput degrades to plain decode,
+                # not off a cliff.
                 return self._plain_turn(ph, 1)
             active = int(self._active_mask.sum())
             kv_pages_live = self._kv_pages_live(k + 1)
@@ -3926,8 +2744,7 @@ class DecodeEngine:
                 # remaining lifetime, shadow-occupying the pool), then
                 # let the loop's error handling see the error.
                 with self._phase("rdb.engine.turn.prepare"):
-                    if self.paged:
-                        self._refresh_table()
+                    self._refresh_table()
                     (_samp_f, _samp_i, bias_ids_d, bias_vals_d) = \
                         self._sampling_arrays()
                     state = np.stack([
@@ -3948,8 +2765,7 @@ class DecodeEngine:
                 with self._phase("rdb.engine.turn.fetch"):
                     ph_host = np.asarray(packed)  # ONE fetch per round  # rdb-lint: disable=host-sync-in-hot-path (THE one fetch per spec round: ph_host carries tokens+counts+lengths packed)
             except BaseException:
-                if self.paged:
-                    self._rollback_spec_scratch()
+                self._rollback_spec_scratch()
                 raise
             t_fetched = now_ms()
             with self._phase("rdb.engine.turn.harvest"):
@@ -3957,16 +2773,13 @@ class DecodeEngine:
                 out = ph_host[: k + 1]        # [k+1, B]
                 n_out = ph_host[k + 1]        # [B]
                 lengths = ph_host[k + 2]      # [B]
-                if self.paged:
-                    # Accepted prefixes commit by page-table splice,
-                    # rejected tails free — resolved from the post-round
-                    # lengths BEFORE the harvest can finish (and free) any
-                    # slot.
-                    self._splice_spec_pages(lengths)
+                # Accepted prefixes commit by page-table splice, rejected
+                # tails free — resolved from the post-round lengths BEFORE
+                # the harvest can finish (and free) any slot.
+                self._splice_spec_pages(lengths)
                 self.steps += 1
                 DECODE_STEPS.inc(tags={"model": self.model.name})
-                tags = {"model": self.model.name,
-                        "paged": "true" if self.paged else "false"}
+                tags = {"model": self.model.name, "paged": "true"}
                 SPEC_ROUNDS.inc(tags=tags)
                 live = np.asarray([
                     not slot.free and self._active_mask[i] and n_out[i] > 0
@@ -4012,12 +2825,11 @@ class DecodeEngine:
         ``ph``: prepare, dispatch, fetch, harvest, and the ring's record."""
         with self._phase("rdb.engine.turn.prepare"):
             h = horizon if horizon is not None else self._pick_horizon()
-            if self.paged:
-                # Pages for every position this scan can write, allocated
-                # host-side before the dispatch (static shapes can't grow
-                # mid-scan), then one tiny [B, NP] table upload when dirty.
-                self._ensure_page_headroom(h)
-                self._refresh_table()
+            # Pages for every position this scan can write, allocated
+            # host-side before the dispatch (static shapes can't grow
+            # mid-scan), then one tiny [B, NP] table upload when dirty.
+            self._ensure_page_headroom(h)
+            self._refresh_table()
             # Per-slot index of the NEXT token to sample (prefill was
             # index 0).
             tok_idx = np.asarray(
@@ -4205,8 +3017,6 @@ class DecodeEngine:
         left decoding untouched on False/raise. Returns False if the
         stream is not live here (advisory — a stream that finishes
         before service is simply skipped, duplicates are harmless)."""
-        if not self.paged:
-            return False
         live = any(
             (not s.free) and s.request is not None
             and s.request.request_id == request_id
@@ -4224,7 +3034,7 @@ class DecodeEngine:
         """Thread-safe: export prefix-cache entry ``key`` as a push
         parcel through ``deliver`` at the next service point (skipped
         if evicted by then)."""
-        if not self.paged or self.paged_prefix is None:
+        if self.paged_prefix is None:
             return False
         with self._fabric_lock:
             self._push_out_q.append((key, deliver))
@@ -4238,7 +3048,7 @@ class DecodeEngine:
         chain (reclaim cache pins -> capacity-truncate), so a stale
         accept is honest, never corrupting. A False return leaves the
         source slot untouched — it simply resumes decoding."""
-        if not self.paged or parcel.page_size != self.page_size:
+        if parcel.page_size != self.page_size:
             return False
         if parcel.kind == STREAM:
             if parcel.resume_len > self.max_len:
@@ -4275,8 +3085,6 @@ class DecodeEngine:
         return True
 
     def _fabric_pending(self) -> bool:
-        if not self.paged:
-            return False
         with self._fabric_lock:
             return bool(self._parcel_in_q or self._migrate_out_q
                         or self._push_out_q)
@@ -4287,8 +3095,6 @@ class DecodeEngine:
         into locals FIRST, then processes unlocked — the handlers call
         into queue accounting (rank 80) and request futures (rank 90),
         which must never nest under rank 100."""
-        if not self.paged:
-            return
         with self._phase("rdb.engine.fabric"):
             with self._fabric_lock:
                 if not (self._parcel_in_q or self._migrate_out_q
@@ -4540,14 +3346,11 @@ class DecodeEngine:
         with self._phase("rdb.engine.publish"):
             tags = {"model": self.model.name}
             ACTIVE_SLOTS.set(float(self._active_mask.sum()), tags=tags)
-            if self.paged:
-                KV_PAGES_FREE.set(
-                    float(self._allocator.free_pages), tags=tags
-                )
-                KV_PAGE_OCCUPANCY.set(
-                    self._allocator.allocated_pages / self.num_pages,
-                    tags=tags,
-                )
+            KV_PAGES_FREE.set(float(self._allocator.free_pages), tags=tags)
+            KV_PAGE_OCCUPANCY.set(
+                self._allocator.allocated_pages / self.num_pages,
+                tags=tags,
+            )
 
     def _loop(self) -> None:
         with self._device_ctx():
@@ -4576,8 +3379,9 @@ class DecodeEngine:
         loop has stopped; the engine is unusable afterwards."""
         self._cache = None
         self.params = None
-        self._prefill_fns.clear()
+        self._draft_fill_fns.clear()
         self._decode_fn = None
+        self._chunk_paged_fn = None
         self._counts = None
         self._zero_counts_fn = None
         self._sampling_dev = None
@@ -4586,20 +3390,15 @@ class DecodeEngine:
             self.draft_params = None
             self._spec_fn = None
             self._draft_catchup_fn = None
-        if self.prefix_cache is not None:
-            self.prefix_cache.clear()  # device k/v entries freed on GC
-        if self.session_cache is not None:
-            self.session_cache.clear()
-        if self.paged:
-            # Drop cache pins first (clean decrefs), then the pool state.
-            if self.paged_prefix is not None:
-                self.paged_prefix.clear()
-            if self.paged_sessions is not None:
-                self.paged_sessions.clear()
-            if self.host_spill is not None:
-                self.host_spill.clear()  # host copies die with the pool
-            self._allocator = None
-            self._table_host = None
+        # Drop cache pins first (clean decrefs), then the pool state.
+        if self.paged_prefix is not None:
+            self.paged_prefix.clear()
+        if self.paged_sessions is not None:
+            self.paged_sessions.clear()
+        if self.host_spill is not None:
+            self.host_spill.clear()  # host copies die with the pool
+        self._allocator = None
+        self._table_host = None
 
     def abort_active(self, exc: Exception) -> None:
         """Reject every request still occupying a slot (replica shutdown:
@@ -4608,7 +3407,7 @@ class DecodeEngine:
         for i, slot in enumerate(self._slots):
             if not slot.free and slot.request is not None:
                 slot.request.reject(exc)
-                if self.paged and self._allocator is not None:
+                if self._allocator is not None:
                     self._free_slot_pages(i)
                 self._slots[i] = _Slot()
                 self._active_mask[i] = False
@@ -4624,14 +3423,13 @@ class DecodeEngine:
         # SOURCE already released (note_migrated_out closed its books);
         # reject them too — they entered no books here, so conservation
         # holds on both sides.
-        if self.paged:
-            with self._fabric_lock:
-                inbound, self._parcel_in_q = self._parcel_in_q, []
-                self._migrate_out_q.clear()
-                self._push_out_q.clear()
-            for parcel in inbound:
-                if parcel.kind == STREAM and parcel.request is not None:
-                    parcel.request.reject(exc)
+        with self._fabric_lock:
+            inbound, self._parcel_in_q = self._parcel_in_q, []
+            self._migrate_out_q.clear()
+            self._push_out_q.clear()
+        for parcel in inbound:
+            if parcel.kind == STREAM and parcel.request is not None:
+                parcel.request.reject(exc)
 
     def start(self) -> None:
         if self._thread is not None:
@@ -4665,20 +3463,13 @@ class DecodeEngine:
 
     def kv_occupancy(self) -> float:
         """Useful fraction of RESERVED KV positions — the decode
-        slot-occupancy metric the paged pool exists to raise. A slab
-        engine reserves ``num_slots * max_len`` up front (a slot's tail
-        tokens hold a whole slab whether it caches 3 tokens or 300); a
-        paged engine reserves only allocated pages, so at equal traffic
-        its value is >= the slab configuration's by construction —
-        pinned by the paged-vs-slab engine test. 1.0 when nothing is
-        reserved."""
+        slot-occupancy metric the paged pool exists to raise: only
+        allocated pages are reserved, so the value follows cached tokens
+        and not ``num_slots * max_len``. 1.0 when nothing is reserved."""
         used = float(self._len_host.sum())
-        if self.paged:
-            reserved = float(
-                self._allocator.allocated_pages * self.page_size
-            ) if self._allocator is not None else 0.0
-        else:
-            reserved = float(self.num_slots * self.max_len)
+        reserved = float(
+            self._allocator.allocated_pages * self.page_size
+        ) if self._allocator is not None else 0.0
         return used / reserved if reserved > 0 else 1.0
 
     def _expert_paths(self) -> List[str]:
@@ -4703,55 +3494,56 @@ class DecodeEngine:
         return summarize_turns(
             list(self.turns.copy()) if records is None else records,
             self.num_slots, self.turns_dropped, span_ms, longest,
-            self._n_table_entries if self.paged else 0,
+            self._n_table_entries,
         )
 
     def snapshot(self) -> Dict[str, Any]:
         """Operator-facing state dump (the engine analogue of
-        ``LiveScheduler.snapshot()``): slot/KV occupancy plus — in paged
-        mode — the allocator event journal (bounded ring; ``events``
+        ``LiveScheduler.snapshot()``): slot/KV occupancy plus the
+        allocator event journal (bounded ring; ``events``
         carries the retained tail, ``journal_total``/``journal_rotated``
         say how much history the ring has seen/shed, so a consumer can
         tell a quiet pool from a ring that wrapped). The journal feeds
         ``utils/trace_export.to_chrome_trace(spans, journal=...)`` for a
-        Perfetto lane time-aligned with decode-turn spans."""
+        Perfetto lane time-aligned with decode-turn spans. ``paged`` and
+        ``prefill.mode`` have one value each: kept for the dashboards
+        that read them."""
+        turns = self.turn_summary()
         out: Dict[str, Any] = {
             "model": self.model.name,
-            "paged": self.paged,
+            "paged": True,
             "num_slots": self.num_slots,
             "active_slots": self.active_slots,
             "kv_occupancy": self.kv_occupancy(),
             "ttft": self.ttft_breakdown(),
-            "turns": self.turn_summary(),
+            "turns": turns,
             "prefill": {
-                "mode": "chunked" if self.chunked_prefill else "mono",
+                "mode": "chunked",
                 "token_budget": self.prefill_token_budget,
                 "pending_trains": len(self._trains),
             },
-        }
-        if self.paged:
-            out["page_size"] = self.page_size
-            out["num_pages"] = self.num_pages
-            out["free_pages"] = self._allocator.free_pages
-            out["allocated_pages"] = self._allocator.allocated_pages
-            out["kv_pool"] = dict(
+            "page_size": self.page_size,
+            "num_pages": self.num_pages,
+            "free_pages": self._allocator.free_pages,
+            "allocated_pages": self._allocator.allocated_pages,
+            "kv_pool": dict(
                 self._pool_stats,
-                pages_live=out["turns"].get("kv_pages_live", 0),
-                pages_scanned=out["turns"].get("kv_pages_scanned", 0),
-            )
-            out["page_journal"] = {
+                pages_live=turns.get("kv_pages_live", 0),
+                pages_scanned=turns.get("kv_pages_scanned", 0),
+            ),
+            "page_journal": {
                 "events": self._page_journal.snapshot(),
                 "journal_total": self._page_journal.total,
                 "journal_rotated": self._page_journal.rotated_out,
-            }
-            out["fabric"] = {
+            },
+            "fabric": {
                 "migrated_out": self.migrated_out,
                 "migrated_in": self.migrated_in,
                 "pushes_out": self.pushes_out,
                 "pushes_in": self.pushes_in,
-            }
+            },
+        }
         if self._moe_kw:
-            turns = out["turns"]
             out["moe"] = {
                 "rows_per_expert": turns.get("moe_rows_per_expert"),
                 "imbalance": turns.get("moe_imbalance"),

@@ -304,8 +304,7 @@ class _PinnedLRU:
 
 
 class PagedPrefixCache(_PinnedLRU):
-    """Page-granular prompt-prefix index (the paged successor of the
-    chunk-granular ``decode.PrefixCache``).
+    """Page-granular prompt-prefix index.
 
     Insertion publishes EVERY full-page prefix of an admitted prompt:
     level ``j`` is keyed by a digest CHAIN — level j's key is
@@ -412,10 +411,9 @@ class PagedSessionCache(_PinnedLRU):
     """Session-id -> pinned page run of the finished turn.
 
     ``store`` pins the pages covering the stored history instead of
-    copying the KV row out of the cache (the slab SessionCache's
-    per-turn full-row device copy disappears); ``lookup`` returns the
-    page run + history length when the stored turn is a strict prefix
-    of the next prompt, exactly the slab semantics."""
+    copying the KV row out of the cache; ``lookup`` returns the page
+    run + history length when the stored turn is a strict prefix of
+    the next prompt."""
 
     def __init__(self, capacity: int, page_size: int,
                  allocator: PageAllocator):

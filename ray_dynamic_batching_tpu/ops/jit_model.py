@@ -6,9 +6,9 @@ retraces over, and which warmup routine is responsible for compiling it
 before serving. Consumed by BOTH enforcers (the ``tile_math`` /
 ``concurrency.LOCK_RANKS`` pattern applied to the jit layer):
 
-- at runtime, ``DecodeEngine._warmup_impl`` cross-checks the compile
+- at runtime, ``DecodeEngine.warmup`` cross-checks the compile
   ledger (``utils/compile_ledger.py``) against :func:`required_for` —
-  a registered program its arm needs that warmup did NOT compile is a
+  a registered program the engine needs that warmup did NOT compile is a
   hard error at startup, not a 20-40s XLA stall mid-serving;
 - statically, three rdb-lint rules load this module standalone
   (importlib, no jax): ``jit-retrace-hazard`` analyses the registered
@@ -31,15 +31,10 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, FrozenSet, Tuple
 
-# Engine arms a program serves. An engine instance activates a subset
-# (see required_for); warmup is judged per-arm, so the mono engine is
-# not required to warm chunk programs it never dispatches.
-ARM_ALWAYS = "always"            # every engine configuration
-ARM_CHUNKED_PAGED = "chunked_paged"  # chunked_prefill and paged
-ARM_CHUNKED_SLAB = "chunked_slab"    # chunked_prefill, slab cache
-ARM_MONO = "mono"                # legacy monolithic admission
+# Which engines run a program (see required_for): warmup is not
+# required to compile the draft's programs on an engine without a draft.
+ARM_ALWAYS = "always"            # every engine
 ARM_SPEC = "spec"                # draft model attached
-ARM_SPEC_MONO = "spec_mono"      # draft model AND mono admission
 
 
 @dataclass(frozen=True)
@@ -91,23 +86,7 @@ HOT_PROGRAMS: Tuple[JitProgram, ...] = (
         donate=(2,),
         donated=("pool cache",),
         grid="(bucket x group) via _admit_group_sizes",
-        warmed_by="_warmup_impl", arm=ARM_CHUNKED_PAGED,
-    ),
-    JitProgram(
-        name="prefill_group",
-        attr="_prefill_fn", impl="_prefill_impl",
-        donate=(2,),
-        donated=("cache",),
-        grid="(bucket x group) via _admit_group_sizes",
-        warmed_by="_warmup_prefill_groups", arm=ARM_MONO,
-    ),
-    JitProgram(
-        name="prefill_group_paged",
-        attr="_prefill_fn", impl="_prefill_paged_impl",
-        donate=(2,),
-        donated=("cache",),
-        grid="(bucket x group) via _admit_group_sizes",
-        warmed_by="_warmup_prefill_groups", arm=ARM_MONO,
+        warmed_by="_warmup_impl", arm=ARM_ALWAYS,
     ),
     JitProgram(
         name="spec_verify",
@@ -126,14 +105,6 @@ HOT_PROGRAMS: Tuple[JitProgram, ...] = (
         warmed_by="_warmup_decode", arm=ARM_SPEC,
     ),
     JitProgram(
-        name="draft_prefill",
-        attr="_draft_prefill_fn", impl="_draft_prefill_impl",
-        donate=(2,),
-        donated=("draft cache",),
-        grid="(bucket x group) via _admit_group_sizes",
-        warmed_by="_warmup_decode", arm=ARM_SPEC_MONO,
-    ),
-    JitProgram(
         name="zero_counts",
         attr="_zero_counts_fn", impl="_reset_counts",
         donate=(0,),
@@ -141,85 +112,10 @@ HOT_PROGRAMS: Tuple[JitProgram, ...] = (
         grid="one shape: (num_slots x vocab)",
         warmed_by="_warmup_decode", arm=ARM_ALWAYS,
     ),
-    # --- registered-lazy programs (legacy/slab arms and cold session
-    # moves). Each lazy_reason is load-bearing: warmup-coverage treats an
-    # UNregistered lazy jit as a finding, so adding a factory means
-    # writing down why its first-hit compile is acceptable.
-    JitProgram(
-        name="long_chunk",
-        attr="_long_prefill_fns", impl="_prefill_chunk_impl",
-        donate=(3,),
-        donated=("row cache",),
-        grid="chunk = largest bucket (one per engine)",
-        warmed_by="_warmup_impl", arm=ARM_CHUNKED_SLAB,
-    ),
-    JitProgram(
-        name="long_commit",
-        attr="_long_prefill_fns", impl="_commit_long_impl",
-        donate=(0,),
-        donated=("cache",),
-        grid="chunk = largest bucket (one per engine)",
-        warmed_by="_warmup_impl", arm=ARM_CHUNKED_SLAB,
-    ),
-    JitProgram(
-        name="long_commit_paged",
-        attr="_long_prefill_fns", impl="_commit_long_paged_impl",
-        donate=(0,),
-        donated=("cache",),
-        grid="chunk = largest bucket",
-        lazy_reason="mono-paged engines reach long fills only for "
-        "prompts past the largest bucket, which may never arrive; the "
-        "persistent compilation cache absorbs the first-hit cost",
-        arm=ARM_MONO,
-    ),
-    JitProgram(
-        name="prefix_seed",
-        attr="_long_prefill_fns", impl="_seed_prefix_impl",
-        donate=(0,),
-        donated=("row cache",),
-        grid="one shape per chunk size",
-        lazy_reason="prefix-cache CoW seeding rides the long-fill path; "
-        "slab engines with no long prompts never dispatch it",
-        arm=ARM_MONO,
-    ),
-    JitProgram(
-        name="prefix_extract",
-        attr="_long_prefill_fns", impl="_extract_prefix_impl",
-        static=(1,),
-        grid="one shape per (chunk, prefix length bucket)",
-        lazy_reason="runs once per prefix PUBLISH (cold, off the decode "
-        "turn); publishing is already an amortized slow path",
-        arm=ARM_MONO,
-    ),
-    JitProgram(
-        name="paged_seed",
-        attr="_paged_seed_fn", impl="_seed_paged_impl",
-        donate=(0,),
-        donated=("row cache",),
-        grid="one shape: (1 x row_cap)",
-        lazy_reason="legacy mono-paged session/prefix seeding only; the "
-        "chunked-universal arm seeds pages-direct through the chunk "
-        "program and never calls this",
-        arm=ARM_MONO,
-    ),
-    JitProgram(
-        name="session_seed",
-        attr="_session_fns", impl="_seed_session_impl",
-        donate=(0,),
-        donated=("row cache",),
-        grid="one shape: (1 x max_len)",
-        lazy_reason="slab session continuation only — sessions may "
-        "never be enabled; first turn-2 on a restart pays it once",
-        arm=ARM_MONO,
-    ),
-    JitProgram(
-        name="session_extract",
-        attr="_session_fns", impl="_extract_row_impl",
-        grid="one shape: (1 x max_len)",
-        lazy_reason="runs once per session FINISH (cold, off the "
-        "decode turn) to pin the finished row",
-        arm=ARM_MONO,
-    ),
+    # --- registered-lazy programs. Each lazy_reason is load-bearing:
+    # warmup-coverage treats an UNregistered lazy jit as a finding, so
+    # adding a factory means writing down why its first-hit compile is
+    # acceptable.
     JitProgram(
         name="draft_long_chunk",
         attr="_draft_long_fill", impl="chunk_impl",
@@ -282,38 +178,10 @@ def donation_contract(impl: str) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
     raise KeyError(impl)
 
 
-def required_for(chunked_prefill: bool, paged: bool,
-                 has_draft: bool) -> Tuple[JitProgram, ...]:
-    """Warmed programs an engine configuration MUST compile during
-    warmup — the runtime coverage check's ground truth. Mirrors the
-    dispatch in ``DecodeEngine._warmup_impl``: chunked+paged warms the
-    chunk program, slab-chunked the long chunk/commit pair, mono the
-    (bucket x group) prefill grid; spec engines add verify + catch-up,
-    and only MONO spec engines add the draft group-prefill grid."""
-    arms = {ARM_ALWAYS}
-    if chunked_prefill and paged:
-        arms.add(ARM_CHUNKED_PAGED)
-    elif chunked_prefill:
-        arms.add(ARM_CHUNKED_SLAB)
-    else:
-        arms.add(ARM_MONO)
-    if has_draft:
-        arms.add(ARM_SPEC)
-        if not chunked_prefill:
-            arms.add(ARM_SPEC_MONO)
-    out = []
-    for p in warmed_programs():
-        if p.arm not in arms:
-            continue
-        # The prefill_group pair is impl-dispatched on paged-ness; only
-        # one of the two compiles on a given engine.
-        if p.name == "prefill_group" and paged:
-            continue
-        if p.name == "prefill_group_paged" and not paged:
-            continue
-        # Slab-arm long programs: _commit_long_impl serves slab engines,
-        # _commit_long_paged_impl is registered lazy for mono-paged.
-        if p.name == "long_commit" and paged:
-            continue
-        out.append(p)
-    return tuple(out)
+def required_for(has_draft: bool) -> Tuple[JitProgram, ...]:
+    """Warmed programs an engine MUST compile during warmup — the
+    runtime coverage check's ground truth: the chunk program, the decode
+    scan and the counts reset; an engine with a draft model adds verify
+    + catch-up."""
+    arms = {ARM_ALWAYS, ARM_SPEC} if has_draft else {ARM_ALWAYS}
+    return tuple(p for p in warmed_programs() if p.arm in arms)

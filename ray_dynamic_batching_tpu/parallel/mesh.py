@@ -169,24 +169,13 @@ def _sharded_alloc(mesh: Mesh, make_fn, spec) -> Any:
     return jax.jit(make_fn, out_shardings=shardings)()  # rdb-lint: disable=jit-retrace-hazard (one-shot cache allocation at engine construction — jit only carries out_shardings so GSPMD places the buffers; never called on the serving path)
 
 
-def make_sharded_cache(
-    mesh: Mesh, model: Any, num_slots: int, max_len: Optional[int] = None
-) -> Any:
-    """Allocate a model's KV cache onto the mesh per its ``cache_pspec``
-    (kv heads over tp)."""
-    return _sharded_alloc(
-        mesh, lambda: model.make_cache(num_slots, max_len),
-        model.cache_pspec(),
-    )
-
-
 def make_sharded_paged_cache(
     mesh: Mesh, model: Any, num_slots: int, num_pages: int,
     page_size: int, max_len: int,
 ) -> Any:
     """Allocate a model's PAGED KV pool onto the mesh per its
     ``paged_cache_pspec`` (ROADMAP item 2): page planes split on the
-    kv-head dim like the slab cache, page table + lengths replicated —
+    kv-head dim, page table + lengths replicated —
     page indices are shard-invariant, so the host-side free-list
     allocator stays replica-global and untouched."""
     return _sharded_alloc(

@@ -10,12 +10,12 @@ contract to the continuous-batching DECODE engine, whose cost axes are
 different:
 
 - **Decode step**: per-substep latency + program HBM vs
-  ``num_slots`` (batch occupancy) x ``max_len`` (KV capacity). Static
-  shapes make attention cost a function of CAPACITY, not fill level, so a
-  fresh cache times identically to a mid-generation one — one row per
-  (slots, capacity) config covers the whole sequence.
-- **Prefill**: admission-group latency vs (prompt bucket x group width)
-  — the TTFT-side cost.
+  ``num_slots`` (batch occupancy) x ``max_len`` (KV capacity). The paged
+  kernel stops at a slot's length, so the step is timed with every slot
+  one position short of ``max_len``: one row per (slots, capacity) config
+  bounds the whole sequence from above.
+- **Prefill**: the chunk program's latency vs (prompt bucket x group
+  width) — the TTFT-side cost.
 
 Rows reuse :class:`~ray_dynamic_batching_tpu.profiles.table.ProfileRow`
 (decode: ``batch_size``=num_slots, ``seq_len``=KV capacity, throughput =
@@ -123,14 +123,23 @@ class DecodeProfiler:
                 engine._decode_impl, donate_argnums=(1, 8),
                 static_argnums=(3,),
             )
-            args = (engine.params, engine._cache, step_state, 1,
+            # Full rows (pages laid out slot by slot): the scan walks
+            # every page a slot can hold.
+            NP = engine._n_table_entries
+            cache = engine._cache.replace(
+                page_table=engine._put(np.arange(
+                    B * NP, dtype=np.int32).reshape(B, NP)),
+                lengths=engine._put(
+                    np.full((B,), max_len - 1, np.int32)),
+            )
+            args = (engine.params, cache, step_state, 1,
                     samp_f, samp_i, bias_ids, bias_vals, engine._counts)
             t0 = time.perf_counter()
             compiled = fn.lower(*args).compile()
             compile_ms = (time.perf_counter() - t0) * 1000.0
             hbm_bytes = _program_hbm(compiled)
 
-            cache, counts = engine._cache, engine._counts
+            counts = engine._counts
             run_args = lambda: (engine.params, cache, step_state,  # noqa: E731
                                 samp_f, samp_i, bias_ids,
                                 bias_vals, counts)
@@ -169,7 +178,9 @@ class DecodeProfiler:
     def profile_prefill_config(
         self, prompt_bucket: int, group: int, max_len: int
     ) -> Optional[ProfileRow]:
-        """One (prompt bucket, group width) admission program."""
+        """One (prompt bucket, group width) admission program: the
+        engine's chunk program, every row a whole prompt's final chunk
+        written into its own pages."""
         num_slots = max(2, group)
         engine = self._engine(num_slots, max_len, prompt_bucket, group)
         try:
@@ -177,11 +188,17 @@ class DecodeProfiler:
                 jnp.ones((group, prompt_bucket), jnp.int32),
                 jnp.ones((group, prompt_bucket), jnp.int32),
             ])
+            NP = engine._n_table_entries
+            tables = jnp.arange(group * NP, dtype=jnp.int32).reshape(
+                group, NP)
+            # slot / start / take_idx / top_k / seed / new_len
             meta_i = jnp.stack([
                 jnp.arange(group, dtype=jnp.int32) % num_slots,
                 jnp.zeros((group,), jnp.int32),
+                jnp.full((group,), prompt_bucket - 1, jnp.int32),
                 jnp.zeros((group,), jnp.int32),
                 jnp.zeros((group,), jnp.int32),
+                jnp.full((group,), prompt_bucket, jnp.int32),
             ])
             meta_f = jnp.stack([
                 jnp.zeros((group,), jnp.float32),
@@ -191,9 +208,10 @@ class DecodeProfiler:
             bias_vals = jnp.zeros(
                 (group, engine.max_bias_entries), jnp.float32
             )
-            fn = jax.jit(engine._prefill_impl, donate_argnums=(2,))
-            args = (engine.params, tokmask, engine._cache, meta_i, meta_f,
-                    bias_ids, bias_vals)
+            fn = jax.jit(engine._chunk_group_paged_impl,
+                         donate_argnums=(2,))
+            args = (engine.params, tokmask, engine._cache, tables, meta_i,
+                    meta_f, bias_ids, bias_vals)
             t0 = time.perf_counter()
             compiled = fn.lower(*args).compile()
             compile_ms = (time.perf_counter() - t0) * 1000.0
@@ -202,15 +220,16 @@ class DecodeProfiler:
             cache = engine._cache
             for _ in range(self.warmup_iters):
                 first, cache = compiled(engine.params, tokmask, cache,
-                                        meta_i, meta_f, bias_ids, bias_vals)
+                                        tables, meta_i, meta_f, bias_ids,
+                                        bias_vals)
             float(np.asarray(first)[0])
             samples = []
             for _ in range(3):
                 t0 = time.perf_counter()
                 for _ in range(self.timing_iters):
                     first, cache = compiled(engine.params, tokmask, cache,
-                                            meta_i, meta_f, bias_ids,
-                                            bias_vals)
+                                            tables, meta_i, meta_f,
+                                            bias_ids, bias_vals)
                 float(np.asarray(first)[0])
                 samples.append(
                     (time.perf_counter() - t0) * 1000.0 / self.timing_iters

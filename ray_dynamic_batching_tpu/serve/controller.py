@@ -631,7 +631,7 @@ class ServeController:
         it polls the drain for seconds. Peers resolve HERE, at run time,
         so replacements started in the same reconcile pass are already
         in ``state.replicas``. Replica kinds without a fabric surface
-        (batch replicas, slab engines) fall through to the stop()'s own
+        (batch replicas) fall through to the stop()'s own
         drain window — exactly the pre-fabric behavior. The heal path
         never routes here: a dead engine cannot export its pages, so
         salvage/requeue remains its only honest option."""

@@ -177,7 +177,7 @@ class _ReplicaGrayState:
     outlier_streak: int = 0
     clear_streak: int = 0
     probation_ticks: int = 0
-    last_probe_at: float = 0.0
+    last_probe_at: Optional[float] = None   # clock(); None = never probed
     since: float = 0.0            # clock() at the last transition
 
 
@@ -332,7 +332,10 @@ class GrayHealthMonitor:
             if st is None or st.state in ("healthy", "suspect"):
                 return True
             if st.state == "probation":
-                return (self._clock() - st.last_probe_at
+                # Never probed is due at once: the clock's zero is the
+                # host's boot, not this monitor's start.
+                return (st.last_probe_at is None
+                        or self._clock() - st.last_probe_at
                         >= self.policy.probe_interval_s)
             return False
 

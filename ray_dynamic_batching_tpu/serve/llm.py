@@ -35,7 +35,10 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
-from ray_dynamic_batching_tpu.engine.decode import DecodeEngine
+from ray_dynamic_batching_tpu.engine.decode import (
+    DecodeEngine,
+    require_paged,
+)
 from ray_dynamic_batching_tpu.engine.queue import RequestQueue
 from ray_dynamic_batching_tpu.engine.request import Request, RequestDropped
 from ray_dynamic_batching_tpu.serve.replica import Replica
@@ -53,13 +56,13 @@ class LLMReplica(Replica):
     (constructed, un-started) :class:`DecodeEngine` — weights loaded and
     sharded however the deployment wants (single chip, TP mesh slice).
 
-    **Capacity buckets are the TPU-first answer to paged KV**: decode
-    attention reads the FULL cache capacity every step (static shapes), so
-    a short request in a long cache pays long-cache bandwidth per token.
-    With several engines at different max_len, admission routes each
-    request to the smallest cache that fits prompt + max_new_tokens —
-    bandwidth per token scales with the request's own length class, no
-    gather-heavy paging kernels needed.
+    **Capacity buckets**: with several engines at different max_len,
+    admission routes each request to the smallest cache that fits prompt
+    + max_new_tokens. They date from the time when decode attention read
+    the FULL cache capacity every step; the paged kernel now stops at a
+    slot's length (a scan costs what its live pages cost), so what a
+    bucket still buys is a narrower page table and a smaller pool per
+    length class. No benchmark cell uses more than one (ROADMAP D16).
 
     Engine warmup (XLA compiles for every prompt bucket + both decode
     horizons) runs at construction, mirroring how the controller treats
@@ -259,20 +262,17 @@ class LLMReplica(Replica):
     # --- page fabric surface (live migration + prefix push) ---------------
     def live_stream_ids(self) -> List[str]:
         """Migration-eligible stream ids across this replica's bucket
-        engines (paged engines only; slab engines migrate nothing)."""
+        engines."""
         out: List[str] = []
         for engine in self.engines.values():
-            fn = getattr(engine, "live_stream_ids", None)
-            if fn is not None and engine.paged:
-                out.extend(fn())
+            out.extend(engine.live_stream_ids())
         return out
 
     def request_migration(self, request_id: str, deliver) -> bool:
         """Ask whichever bucket engine holds ``request_id`` to migrate it
         out through ``deliver`` (see DecodeEngine.request_migration)."""
         for engine in self.engines.values():
-            if engine.paged and engine.request_migration(
-                    request_id, deliver):
+            if engine.request_migration(request_id, deliver):
                 return True
         return False
 
@@ -419,11 +419,10 @@ class LLMDeployment:
         profiles_dir: Optional[str] = None,
         token_slo_ms: Optional[float] = None,
         ttft_slo_ms: Optional[float] = None,
-        paged: bool = False,
+        paged: bool = True,
         page_size: int = 128,
         kv_pool_pages: Optional[int] = None,
         host_spill_pages: int = 0,
-        chunked_prefill: Optional[bool] = None,
         prefill_token_budget: Optional[int] = None,
     ) -> None:
         self.model_name = model_name
@@ -447,9 +446,8 @@ class LLMDeployment:
         self.session_cache_size = session_cache_size
         self.warmup = warmup
         # KV-capacity buckets: one engine per entry, requests routed to the
-        # smallest cache fitting prompt + max_new (LLMReplica docstring —
-        # the static-shape alternative to paged attention). Default: one
-        # engine at max_len.
+        # smallest cache fitting prompt + max_new (LLMReplica docstring).
+        # Default: one engine at max_len.
         self.length_buckets = sorted(length_buckets or [max_len])
         # Speculative decoding: a smaller registry model drafts, the target
         # verifies (greedy-exact; see DecodeEngine._spec_impl).
@@ -472,28 +470,24 @@ class LLMDeployment:
         # TP meshes unsupported — see DecodeEngine).
         self.quantize_weights = quantize_weights
         # Int8 KV cache (codes + per-row scales, KVCache docstring):
-        # auto slot sizing sees the smaller kv_bytes_per_slot and fits
+        # auto slot sizing sees the smaller pool bytes per slot and fits
         # ~2x the slots in the same HBM; the decode-scan bandwidth win
         # additionally requires the dequant fused into the attention
         # read (kernel path) — see KVCache.
         self.quantize_kv = quantize_kv
-        # Paged KV pool (ISSUE 7): per-engine free-list pages replace the
-        # per-slot slabs — HBM occupancy follows cached tokens, admission
-        # waits on pages not slabs, prefix/session reuse is by reference
+        # Paged KV pool (ISSUE 7): HBM occupancy follows cached tokens,
+        # admission waits on pages, prefix/session reuse is by reference
         # (CoW). Draft models compose (ISSUE 13): speculative rounds
         # draft into scratch pages and commit accepted prefixes by
         # page-table splice — except on a multi-chip (TP) replica, where
-        # the pool shards over the mesh's kv-head axis (ROADMAP item 2)
-        # and paged+spec+mesh stays excluded (DecodeEngine raises loudly
-        # at build, the PR 10 pattern).
-        self.paged = bool(paged)
+        # the pool shards over the mesh's kv-head axis and spec+mesh
+        # stays excluded (DecodeEngine raises loudly at build).
+        # ``paged`` is a key with one legal value (ROADMAP D15).
+        require_paged(paged)
         self.page_size = int(page_size)
         self.kv_pool_pages = kv_pool_pages
-        # Token-budget chunked admission (ISSUE 15): None = the engine's
-        # default (chunked on paged engines — the universal path — mono
-        # on slabs); False forces the legacy monolithic arm (the
-        # ``bench.py --prefill mono`` A/B baseline).
-        self.chunked_prefill = chunked_prefill
+        # Token-budget chunked admission (ISSUE 15): the most prefill
+        # tokens one scheduler round spends between decode turns.
         self.prefill_token_budget = prefill_token_budget
         self._dtype = dtype
         self._model = model
@@ -575,6 +569,28 @@ class LLMDeployment:
                         jax.random.PRNGKey(1)
                     )
 
+    def pool_bytes_per_slot(self, model: Any, max_len: int) -> int:
+        """What one slot's full page run occupies in the engine's pool:
+        the bytes ``model.make_paged_cache`` allocates for
+        ``pages_for(max_len)`` pages — read from the shapes it would make
+        (the lane-padded head ``pool_head_dim``, whole pages, the scale
+        planes of an int8 pool), not from a second formula. A 64-wide
+        head holds twice what ``kv_bytes_per_slot`` counts."""
+        import math
+
+        import jax
+
+        from ray_dynamic_batching_tpu.ops.tile_math import pages_for
+
+        n = pages_for(max_len, self.page_size)
+        pool = jax.eval_shape(lambda: model.make_paged_cache(
+            1, n, self.page_size, n * self.page_size))
+        return sum(
+            math.prod(x.shape) * x.dtype.itemsize
+            for x in (pool.k, pool.v, pool.k_scale, pool.v_scale)
+            if x is not None
+        )
+
     def auto_num_slots(self, n_chips: int = 1,
                        max_len: Optional[int] = None,
                        budget_fraction: float = 1.0) -> int:
@@ -582,9 +598,12 @@ class LLMDeployment:
         from profile/HBM, not a guess): per CHIP, subtract this chip's
         weight shard, apply the planner's HBM fraction
         (``RDB_HBM_PLAN_FRACTION`` — same knob the Nexus packer uses), and
-        fill the rest with this chip's KV-row shards. TP replicas shard
-        both weights and KV 1/n_chips, so per-chip terms divide through.
-        Rounded down to a power of two (aligns prefill group widths)."""
+        fill the rest with slots, each priced at its full page run in the
+        pool (:meth:`pool_bytes_per_slot`). TP replicas shard both weights
+        and KV 1/n_chips, so per-chip terms divide through. Rounded down
+        to a power of two (aligns prefill group widths). Prefix and
+        session reuse pin pages INSIDE the pool (shed under pressure), so
+        they cost no HBM of their own."""
         import jax
         import numpy as np
 
@@ -611,32 +630,18 @@ class LLMDeployment:
         weights_bytes = tree_bytes(params) / max(1, n_chips)
         budget = float(cfg.hbm_budget_bytes)
         per_slot = float(
-            model.kv_bytes_per_slot(max_len or self.max_len)
+            self.pool_bytes_per_slot(model, max_len or self.max_len)
         ) / max(1, n_chips)
         if draft_model is not None:
             # Speculative decoding doubles the residency story: the draft's
             # weights leave the budget, and every slot also carries a draft
-            # KV row (with spec-token headroom) — omit either and the
-            # "fits" answer OOMs on the chip.
+            # KV row (a slab row with spec-token headroom, not pages) —
+            # omit either and the "fits" answer OOMs on the chip.
             weights_bytes += tree_bytes(draft_params) / max(1, n_chips)
             per_slot += float(
                 draft_model.kv_bytes_per_slot(
                     (max_len or self.max_len) + self.spec_tokens + 1
                 )
-            ) / max(1, n_chips)
-        if self.session_cache_size > 0:
-            # Each stored session turn pins a FULL kv row on device; the
-            # cache at capacity is that many phantom slots of residency —
-            # and EVERY length-bucket engine holds its own cache with rows
-            # sized by ITS bucket, while this call sees only a 1/n_buckets
-            # budget slice, so the whole deployment's session residency
-            # (summed over buckets) must come off the top here.
-            weights_bytes += (
-                self.session_cache_size
-                * float(sum(
-                    model.kv_bytes_per_slot(b)
-                    for b in self.length_buckets
-                ))
             ) / max(1, n_chips)
         usable = (
             (budget - weights_bytes) * cfg.hbm_plan_fraction * budget_fraction
@@ -881,11 +886,9 @@ class LLMDeployment:
             quantize_weights=self.quantize_weights,
             device=device,
             mesh=mesh,
-            paged=self.paged,
             page_size=self.page_size,
             kv_pool_pages=self.kv_pool_pages,
             host_spill_pages=self.host_spill_pages,
-            chunked_prefill=self.chunked_prefill,
             prefill_token_budget=self.prefill_token_budget,
         )
 

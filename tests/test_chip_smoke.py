@@ -82,7 +82,7 @@ def test_kernels_phase_fails_on_a_wrong_answer(monkeypatch):
 
 def test_serve_paged_phase(pallas_forced):
     out = chip_smoke.phase_serve(
-        "paged", "llama_tiny", prompt_buckets=(16, 32),
+        "llama_tiny", prompt_buckets=(16, 32),
         requests=_requests(), http_requests=3, allow_interpret=True,
         timeout_s=120, **TINY,
     )
@@ -95,21 +95,10 @@ def test_serve_paged_phase(pallas_forced):
     chunk = next(r for r in out["paths"] if r["program"] == "chunk_prefill")
     assert any("prefill-shaped" in why for why in chunk["declines"])
     assert serve.status() == {}  # the phase deleted its deployment
-
-
-def test_serve_slab_phase(pallas_forced):
-    out = chip_smoke.phase_serve(
-        "slab", "llama_tiny", prompt_buckets=(16, 32, 64),
-        requests=_requests(), http_requests=3, allow_interpret=True,
-        timeout_s=120, **TINY,
-    )
-    by_program = {(r["program"], r["path"]) for r in out["paths"]}
-    assert ("decode_step", "slab kernel") in by_program
-    assert ("prefill_group", "flash kernel") in by_program
     # On the chip no hot program may run interpreted; here every one
     # did, and the same record fails the table when that is not allowed.
     with pytest.raises(chip_smoke.PhaseFailed, match="interpret mode"):
-        chip_smoke.report_paths("slab", allow_interpret=False)
+        chip_smoke.report_paths(allow_interpret=False)
 
 
 def test_paths_table_fails_a_hot_program_on_the_xla_reference():
@@ -132,7 +121,7 @@ def test_paths_table_fails_a_hot_program_on_the_xla_reference():
     assert record.path == attention.PATH_XLA
     assert "pallas off" in record.declines[0]
     with pytest.raises(chip_smoke.PhaseFailed, match="XLA reference"):
-        chip_smoke.report_paths("slab", allow_interpret=True)
+        chip_smoke.report_paths(allow_interpret=True)
     attention.clear_attention_paths()
 
 

@@ -3,21 +3,18 @@
 
 Two contracts:
 
-- **Token exactness**: chunked-interleaved admission (the universal path
-  on paged engines; slab opt-in) emits BYTE-IDENTICAL tokens to the
-  monolithic-prefill arm — paged + slab, f32 + int8-KV, greedy + the
-  seeded sampled row, XLA fallback + CPU-interpreted Pallas kernel, and
-  the chunked+spec / chunked+mesh compositions. Pages-direct chunk k/v
-  (scatter through the slot's page table, no row cache, no commit copy)
-  is a pure layout/scheduling change.
+- **Token exactness**: chunked-interleaved admission (every admission is
+  a chunk train) is served EXACTLY the tokens of a reference that shares
+  no engine code (``tests/decode_reference.py``) — f32 + int8-KV, greedy
+  + the seeded sampled row, XLA fallback + CPU-interpreted Pallas kernel,
+  and the chunked+spec / chunked+mesh compositions. Pages-direct chunk
+  k/v (scatter through the slot's page table, no row cache, no commit
+  copy) is a pure layout/scheduling change.
 
 - **Stall bound**: with budget B, the engine's own step loop spends at
   most B prefill tokens between decode turns — under a saturating
   long-prompt burst, no active stream ever waits more than one chunk
-  program (the budget's worth) between its turns. The count-based
-  ``max_admissions_per_step`` rationing merely bounded how MANY
-  monolithic programs stalled each round; the budget bounds the stall
-  itself.
+  program (the budget's worth) between its turns.
 """
 
 import numpy as np
@@ -32,6 +29,7 @@ from ray_dynamic_batching_tpu.models import registry  # noqa: F401
 from ray_dynamic_batching_tpu.models.base import get_model
 from ray_dynamic_batching_tpu.ops.attention import set_attention_backend
 
+from tests.decode_reference import assert_served
 from tests.test_paged_decode import _workload
 
 
@@ -48,12 +46,12 @@ def lm_int8(lm):
     return model, lm[1]
 
 
-def _run(model, params, *, paged, chunked, queue_reqs=None, **kw):
+def _run(model, params, *, queue_reqs=None, **kw):
     queue = RequestQueue(model.name, max_len=256)
     defaults = dict(
         num_slots=4, max_len=96, prompt_buckets=[8, 16, 32],
         eos_token_id=None, default_max_new_tokens=8, decode_horizon=4,
-        paged=paged, page_size=128, chunked_prefill=chunked,
+        page_size=128,
     )
     defaults.update(kw)
     engine = DecodeEngine(model, params, queue, **defaults)
@@ -63,9 +61,8 @@ def _run(model, params, *, paged, chunked, queue_reqs=None, **kw):
         reqs = _workload(queue, model.name)
     engine.run_until_idle(timeout_s=300)
     tokens = [tuple(r.future.result(timeout=5).tokens) for r in reqs]
-    if paged:
-        engine._allocator.check()
-    return tokens, engine
+    engine._allocator.check()
+    return tokens, engine, reqs
 
 
 def _mixed_workload(queue, model_name, seed=3):
@@ -87,112 +84,59 @@ def _mixed_workload(queue, model_name, seed=3):
 
 
 class TestTokenExactness:
-    def test_paged_chunked_matches_paged_mono(self, lm):
-        """THE acceptance pin: chunked-interleaved admission on the
-        paged engine is byte-identical to the monolithic arm — short
-        bucketed prompts (single-chunk trains), long multi-chunk
-        trains, greedy and the seeded sampled row."""
-        model, params = lm
-        mono, _ = _run(model, params, paged=True, chunked=False,
-                       queue_reqs=_mixed_workload)
-        chunked, engine = _run(model, params, paged=True, chunked=True,
-                               queue_reqs=_mixed_workload)
-        assert chunked == mono
+    @pytest.mark.parametrize("case", [
+        "f32", "f32_standard_workload",
+        pytest.param("int8_kv", marks=pytest.mark.slow),
+        pytest.param("pallas_kernel", marks=pytest.mark.slow),
+        pytest.param("spec", marks=pytest.mark.slow),
+        pytest.param("mesh", marks=pytest.mark.slow),
+    ])
+    def test_served_tokens_match_the_reference(self, case, lm, lm_int8,
+                                               request):
+        """THE acceptance pin, the former arms as cases: short bucketed
+        prompts (single-chunk trains), long multi-chunk trains, greedy
+        and the seeded sampled row are served the tokens of the
+        model-level reference. ``int8_kv``: chunk writes quantize per
+        row at the pool write (reference: the model's own int8 slab
+        ``KVCache``). ``pallas_kernel``: decode turns ride the
+        page-table kernel (CPU interpret) while wide chunk windows
+        decline to the gather. ``spec``: a self-draft (acceptance 1.0)
+        replays the prompt through its own chunk program after the
+        target's final chunk. ``mesh``: the chunk program's scatter and
+        staircase gather partition under GSPMD over the TP=2 pool. The
+        last two also equal the same engine without the draft / on one
+        device."""
+        model, params = lm_int8 if case == "int8_kv" else lm
+        workload = None if case == "f32_standard_workload" \
+            else _mixed_workload
+        kw = {}
+        if case == "spec":
+            kw = dict(draft_model=model, draft_params=params,
+                      spec_tokens=3)
+        elif case == "mesh":
+            from ray_dynamic_batching_tpu.parallel.mesh import (
+                MeshConfig,
+                build_mesh,
+            )
+
+            request.getfixturevalue("eight_devices")
+            kw = dict(mesh=build_mesh(MeshConfig(tp=2),
+                                      jax.devices()[:2]))
+        set_attention_backend("pallas" if case == "pallas_kernel"
+                              else "auto")
+        try:
+            served, engine, reqs = _run(model, params,
+                                        queue_reqs=workload, **kw)
+        finally:
+            set_attention_backend("auto")
+        assert_served(model, params, reqs, served,
+                      cached=case == "int8_kv")
         # Drained chunked engine returns every page (per-chunk grants
         # all transferred to slots and freed at finish).
         assert engine._allocator.free_pages == engine.num_pages
-
-    def test_slab_chunked_matches_slab_mono(self, lm):
-        model, params = lm
-        mono, _ = _run(model, params, paged=False, chunked=False,
-                       queue_reqs=_mixed_workload)
-        chunked, _ = _run(model, params, paged=False, chunked=True,
-                          queue_reqs=_mixed_workload)
-        assert chunked == mono
-
-    def test_all_four_arms_agree(self, lm):
-        """paged/slab x chunked/mono on the standard seeded workload:
-        one token stream, four layouts."""
-        model, params = lm
-        arms = {
-            (paged, chunked): _run(model, params, paged=paged,
-                                   chunked=chunked)[0]
-            for paged in (False, True)
-            for chunked in (False, True)
-        }
-        baseline = arms[(False, False)]
-        assert all(v == baseline for v in arms.values())
-
-    @pytest.mark.slow
-    def test_int8_kv_chunked_matches_mono(self, lm_int8):
-        """Quantized pool: chunk writes quantize per row at the pool
-        write exactly as the commit scatter did — codes and scale
-        planes land identically."""
-        model, params = lm_int8
-        mono, _ = _run(model, params, paged=True, chunked=False,
-                       queue_reqs=_mixed_workload)
-        chunked, _ = _run(model, params, paged=True, chunked=True,
-                          queue_reqs=_mixed_workload)
-        assert chunked == mono
-        s_mono, _ = _run(model, params, paged=False, chunked=False,
-                         queue_reqs=_mixed_workload)
-        s_chunked, _ = _run(model, params, paged=False, chunked=True,
-                            queue_reqs=_mixed_workload)
-        assert s_chunked == s_mono
-        assert s_mono == mono
-
-    @pytest.mark.slow
-    def test_pallas_interpret_kernel_arm(self, lm):
-        """Forced-Pallas backend (CPU interpret): decode turns ride the
-        page-table kernel while wide chunk windows decline to the
-        gather — the mixed-path stream still matches the XLA arm."""
-        model, params = lm
-        xla, _ = _run(model, params, paged=True, chunked=True,
-                      queue_reqs=_mixed_workload)
-        set_attention_backend("pallas")
-        try:
-            kernel, _ = _run(model, params, paged=True, chunked=True,
-                             queue_reqs=_mixed_workload)
-        finally:
-            set_attention_backend("auto")
-        assert kernel == xla
-
-    @pytest.mark.slow
-    def test_chunked_spec_composition(self, lm):
-        """chunked+spec: the draft replays the prompt through its own
-        chunk program after the target's final chunk; a self-draft
-        (acceptance 1.0) spec engine on the chunked path stays
-        byte-identical to plain chunked and to mono."""
-        model, params = lm
-        plain, _ = _run(model, params, paged=True, chunked=True)
-        spec, engine = _run(
-            model, params, paged=True, chunked=True,
-            draft_model=model, draft_params=params, spec_tokens=3,
-        )
-        assert spec == plain
-        mono, _ = _run(model, params, paged=True, chunked=False)
-        assert plain == mono
-
-    @pytest.mark.slow
-    def test_chunked_mesh_token_exact(self, lm, eight_devices):
-        """chunked+mesh: the chunk program's scatter and staircase
-        gather partition under GSPMD over the sharded pool — TP=2
-        chunked matches single-chip chunked AND TP=2 mono."""
-        from ray_dynamic_batching_tpu.parallel.mesh import (
-            MeshConfig,
-            build_mesh,
-        )
-
-        model, params = lm
-        single, _ = _run(model, params, paged=True, chunked=True)
-        mesh = build_mesh(MeshConfig(tp=2), jax.devices()[:2])
-        tp_chunked, _ = _run(model, params, paged=True, chunked=True,
-                             mesh=mesh)
-        mesh2 = build_mesh(MeshConfig(tp=2), jax.devices()[:2])
-        tp_mono, _ = _run(model, params, paged=True, chunked=False,
-                          mesh=mesh2)
-        assert tp_chunked == single
-        assert tp_chunked == tp_mono
+        if kw:
+            plain, _, _ = _run(model, params, queue_reqs=workload)
+            assert served == plain
 
     @pytest.mark.slow
     def test_session_continuation_chunked(self, lm):
@@ -202,14 +146,13 @@ class TestTokenExactness:
         same concatenated history."""
         model, params = lm
 
-        def turns(session_cache_size, chunked):
+        def turns(session_cache_size):
             queue = RequestQueue(model.name, max_len=256)
             engine = DecodeEngine(
                 model, params, queue, num_slots=2, max_len=160,
                 prompt_buckets=[16], eos_token_id=None,
                 default_max_new_tokens=6, decode_horizon=2,
-                paged=True, page_size=128, chunked_prefill=chunked,
-                session_cache_size=session_cache_size,
+                page_size=128, session_cache_size=session_cache_size,
             )
             rng = np.random.default_rng(5)
             t1 = rng.integers(1, 500, 40).tolist()
@@ -228,13 +171,12 @@ class TestTokenExactness:
             queue.add_request(r2)
             engine.run_until_idle(timeout_s=300)
             out2 = r2.future.result(timeout=5).tokens
+            assert_served(model, params, [r1, r2], [out1, out2])
             return tuple(out1), tuple(out2), engine
 
-        o1_hit, o2_hit, engine = turns(4, chunked=True)
-        o1_cold, o2_cold, _ = turns(0, chunked=True)
-        o1_mono, o2_mono, _ = turns(4, chunked=False)
+        o1_hit, o2_hit, engine = turns(4)
+        o1_cold, o2_cold, _ = turns(0)
         assert (o1_hit, o2_hit) == (o1_cold, o2_cold)
-        assert (o1_hit, o2_hit) == (o1_mono, o2_mono)
         from ray_dynamic_batching_tpu.engine.decode import SESSION_HITS
 
         assert SESSION_HITS.get(tags={"model": model.name}) >= 1
@@ -251,8 +193,7 @@ class TestTokenExactness:
                 model, params, queue, num_slots=2, max_len=224,
                 prompt_buckets=[16], eos_token_id=None,
                 default_max_new_tokens=5, decode_horizon=2,
-                paged=True, page_size=128, chunked_prefill=True,
-                prefix_cache_size=prefix_cache_size,
+                page_size=128, prefix_cache_size=prefix_cache_size,
             )
             rng = np.random.default_rng(9)
             head = rng.integers(1, 500, 130).tolist()  # > one page
@@ -267,6 +208,7 @@ class TestTokenExactness:
                 queue.add_request(r)
                 engine.run_until_idle(timeout_s=300)
                 outs.append(tuple(r.future.result(timeout=5).tokens))
+                assert_served(model, params, [r], outs[-1:])
             return outs, engine
 
         cold, _ = run(0)
@@ -291,7 +233,7 @@ class TestStallBound:
             model, params, queue, num_slots=6, max_len=96,
             prompt_buckets=[8, 16], eos_token_id=None,
             default_max_new_tokens=48, decode_horizon=4,
-            paged=True, page_size=128, chunked_prefill=True,
+            page_size=128,
         )
         budget = engine.prefill_token_budget
         rng = np.random.default_rng(2)
@@ -335,7 +277,7 @@ class TestStallBound:
         queue = RequestQueue(model.name, max_len=256)
         engine = DecodeEngine(
             model, params, queue, num_slots=2, max_len=96,
-            prompt_buckets=[8, 32], paged=True, chunked_prefill=True,
+            prompt_buckets=[8, 32],
             prefill_token_budget=4,  # below one chunk: clamped up
         )
         assert engine.prefill_token_budget == 32
@@ -345,8 +287,7 @@ class TestStallBound:
         queue = RequestQueue(model.name, max_len=256)
         engine = DecodeEngine(
             model, params, queue, num_slots=2, max_len=96,
-            prompt_buckets=[8], decode_horizon=8, paged=True,
-            chunked_prefill=True,
+            prompt_buckets=[8], decode_horizon=8,
         )
         assert engine._pick_horizon() in (engine.ttft_horizon, 1)
         engine._trains.append(object())  # sentinel: a pending train
@@ -354,29 +295,6 @@ class TestStallBound:
             assert engine._pick_horizon() == 1
         finally:
             engine._trains.clear()
-
-    def test_paged_chunked_never_runs_monolithic_prefill(self, lm):
-        """First-token fusion: every admission flows through the chunk
-        program — the monolithic prefill programs are never compiled or
-        dispatched on the chunked paged path."""
-        model, params = lm
-        queue = RequestQueue(model.name, max_len=256)
-        engine = DecodeEngine(
-            model, params, queue, num_slots=4, max_len=96,
-            prompt_buckets=[8, 16], eos_token_id=None,
-            default_max_new_tokens=4, decode_horizon=2,
-            paged=True, chunked_prefill=True,
-        )
-
-        def boom(*a, **k):
-            raise AssertionError("monolithic prefill dispatched")
-
-        engine._prefill_fn = boom
-        reqs = _workload(queue, model.name, n=4)
-        engine.run_until_idle(timeout_s=300)
-        for r in reqs:
-            r.future.result(timeout=5)
-        assert engine.steps > 0
 
 
 class TestTrainLifecycle:
@@ -390,8 +308,7 @@ class TestTrainLifecycle:
             model, params, queue, num_slots=4, max_len=192,
             prompt_buckets=[16], eos_token_id=None,
             default_max_new_tokens=4, decode_horizon=1,
-            paged=True, page_size=128, kv_pool_pages=3,
-            chunked_prefill=True,
+            page_size=128, kv_pool_pages=3,
         )
         rng = np.random.default_rng(4)
         reqs = []
@@ -413,7 +330,7 @@ class TestTrainLifecycle:
         queue = RequestQueue(model.name, max_len=256)
         engine = DecodeEngine(
             model, params, queue, num_slots=2, max_len=96,
-            prompt_buckets=[8], paged=True, chunked_prefill=True,
+            prompt_buckets=[8],
         )
         r = Request(model=model.name, payload={
             "tokens": [1, 2, 3], "max_new_tokens": 4,
@@ -432,15 +349,10 @@ class TestTrainLifecycle:
         queue = RequestQueue(model.name, max_len=256)
         engine = DecodeEngine(
             model, params, queue, num_slots=2, max_len=96,
-            prompt_buckets=[8], paged=True,
+            prompt_buckets=[8],
         )
         snap = engine.snapshot()
         assert snap["prefill"]["mode"] == "chunked"
         assert snap["prefill"]["token_budget"] == \
             engine.prefill_token_budget
         assert snap["prefill"]["pending_trains"] == 0
-        slab = DecodeEngine(
-            model, params, RequestQueue(model.name, max_len=16),
-            num_slots=2, max_len=96, prompt_buckets=[8],
-        )
-        assert slab.snapshot()["prefill"]["mode"] == "mono"

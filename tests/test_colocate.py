@@ -322,8 +322,8 @@ class TestOccupancyModelValidation:
                 ))
             while e_b.active_slots == 0:
                 ex.step_once()
-            # A's long prompt: 120 tokens over 8-wide chunks = 15 chunk
-            # dispatches in ONE admission call.
+            # A's long prompt: 120 tokens over 8-wide chunks = a train of
+            # 15 chunk dispatches, one prefill budget a turn.
             prompt = np.arange(1, 121, dtype=np.int32)
             n_chunks = (len(prompt) + 7) // 8
             q_a.add_request(Request(
@@ -331,13 +331,12 @@ class TestOccupancyModelValidation:
                 payload={"tokens": prompt, "max_new_tokens": 4},
                 slo_ms=600_000.0,
             ))
-            b_steps0 = None
+            b_steps0 = e_b.steps
             while e_a.active_slots == 0:
-                b_steps0 = e_b.steps
                 assert ex.step_once(), "executor stalled before admission"
-            # The pass that admitted A ran its 15-chunk fill; B must have
-            # scanned between chunks (one yield per gap, minus slack for
-            # B's own-turn share of that same pass).
+            # From A's dequeue to its first token its 15-chunk train ran;
+            # B must have scanned between chunks (the yield after each
+            # dispatch, and B's own turns between A's).
             gained = e_b.steps - b_steps0
             assert gained >= n_chunks - 3, (
                 f"co-tenant starved during long fill: B stepped {gained} "

@@ -56,6 +56,9 @@ class InstantEngine:
         self._active_mask[: min(len(self._pending), self.num_slots)] = True
         return len(batch)
 
+    def _pump_prefill(self) -> int:
+        return 0  # admission is instant: no chunk train ever pends
+
     def _step(self, horizon=None) -> None:
         assert not self.released, "stepped after release_buffers"
         for req in self._pending:
@@ -67,6 +70,10 @@ class InstantEngine:
     @property
     def active_slots(self) -> int:
         return int(self._active_mask.sum())
+
+    @property
+    def busy(self) -> bool:
+        return bool(self._pending)
 
     def abort_active(self, exc) -> None:
         for req in self._pending:

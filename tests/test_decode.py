@@ -19,6 +19,8 @@ from ray_dynamic_batching_tpu.engine.request import Request
 from ray_dynamic_batching_tpu.models import registry  # noqa: F401 — registers models
 from ray_dynamic_batching_tpu.models.base import get_model
 
+from tests.decode_reference import teacher_forced
+
 
 @pytest.fixture(scope="module")
 def lm():
@@ -48,15 +50,22 @@ def submit(queue, prompt, slo_ms=60_000.0, **payload):
     return req
 
 
-def count_chunk_dispatches(engine, C=8):
+def count_chunk_dispatches(engine):
     """Wrap the COMPILED chunk fn so every dispatch counts (wrapping the
     impl would count jit traces — one per shape — not dispatches)."""
     calls = []
-    fns = list(engine._long_prefill_fns(C))
-    real = fns[0]
-    fns[0] = lambda *a: (calls.append(1), real(*a))[1]
-    engine._prefill_fns[("long", C)] = tuple(fns)
+    real = engine._chunk_paged_fn
+    engine._chunk_paged_fn = lambda *a: (calls.append(1), real(*a))[1]
     return calls
+
+
+def admit(engine):
+    """Dequeue into chunk trains and run them to their first tokens: the
+    admission as a hand-driven test sees it (the serving loop spends one
+    prefill budget a turn instead)."""
+    n = engine._admit()
+    engine._drain_prefill()
+    return n
 
 
 class TestDecodeEngine:
@@ -97,7 +106,7 @@ class TestDecodeEngine:
         """Requests admitted mid-stream decode correctly alongside tenants."""
         engine, queue = make_engine(lm, num_slots=2, max_len=32)
         first = submit(queue, [1, 2], max_new_tokens=10)
-        engine._admit()
+        admit(engine)
         for _ in range(3):
             engine._step()
         # Join a second request while the first is mid-decode.
@@ -351,7 +360,7 @@ class TestPenalties:
                               prompt_buckets=[8], draft_model=model,
                               draft_params=params, spec_tokens=3)
         submit(q, [1, 2, 3], max_new_tokens=8, frequency_penalty=2.0)
-        engine._admit()
+        admit(engine)
         assert not engine._use_spec()
         engine.run_until_idle(timeout_s=120)
         assert engine.completed == 1
@@ -396,29 +405,29 @@ class TestMoEDecode:
 class TestSessionCache:
     def test_multi_turn_parity_and_tail_only_prefill(self, lm):
         """Turn 2 resends the whole history with the same session_id: the
-        engine must continue from the stored row (chunk dispatches cover
-        only the NEW tail) and generate exactly what a sessionless engine
-        does on the full prompt."""
-        sess, q1 = make_engine(lm, prompt_buckets=[8], max_len=96,
+        engine must continue from the stored turn's pages (chunk
+        dispatches cover only what lies past the last WHOLE shared page)
+        and generate exactly what the model's full forward does on the
+        full prompt."""
+        model, params = lm
+        sess, q1 = make_engine(lm, prompt_buckets=[8], max_len=192,
                                session_cache_size=4)
-        plain, q2 = make_engine(lm, prompt_buckets=[8], max_len=96)
-        turn1 = [(i * 7) % 50 + 1 for i in range(6)]
+        turn1 = [(i * 7) % 50 + 1 for i in range(130)]
         r1 = submit(q1, turn1, max_new_tokens=5, session_id="chat-1")
         sess.run_until_idle(timeout_s=120)
         gen1 = r1.future.result(timeout=5).tokens
-        assert len(sess.session_cache) == 1
+        assert len(sess.paged_sessions) == 1
         # Turn 2: history + reply + new user tokens (chat shape).
         turn2 = turn1 + gen1 + [17, 23, 29]
         chunk_calls = count_chunk_dispatches(sess)
         r2 = submit(q1, turn2, max_new_tokens=5, session_id="chat-1")
-        ref = submit(q2, turn2, max_new_tokens=5)
         sess.run_until_idle(timeout_s=120)
-        plain.run_until_idle(timeout_s=120)
         assert (r2.future.result(timeout=5).tokens
-                == ref.future.result(timeout=5).tokens)
-        # Stored history = turn1 + gen1[:-1] (last token pending), so the
-        # tail is [gen1[-1], 17, 23, 29] = 4 tokens -> ONE 8-wide chunk.
-        assert len(chunk_calls) == 1, chunk_calls
+                == teacher_forced(model, params, turn2, 5))
+        # Stored history = turn1 + gen1[:-1] = 134 positions: one whole
+        # 128-position page is borrowed, positions 128..137 are computed
+        # -> TWO 8-wide chunks, not the eighteen of the whole prompt.
+        assert len(chunk_calls) == 2, chunk_calls
 
     def test_session_mismatched_history_falls_back(self, lm):
         """Same session id but a DIFFERENT history prefix must miss (full
@@ -436,21 +445,6 @@ class TestSessionCache:
         plain.run_until_idle(timeout_s=120)
         assert (r2.future.result(timeout=5).tokens
                 == ref.future.result(timeout=5).tokens)
-
-    def test_session_lru_eviction(self):
-        from ray_dynamic_batching_tpu.engine.decode import SessionCache
-        sc = SessionCache(capacity=2)
-        z = jnp.zeros((1,))
-        seg = (z, z, None, None)  # _extract_row_impl's (k, v, ks, vs)
-        sc.store("a", seg, np.asarray([1, 2], np.int32))
-        sc.store("b", seg, np.asarray([3, 4], np.int32))
-        assert sc.lookup("a", np.asarray([1, 2, 5], np.int32)) is not None
-        sc.store("c", seg, np.asarray([5, 6], np.int32))  # evicts b
-        assert sc.lookup("b", np.asarray([3, 4, 5], np.int32)) is None
-        assert len(sc) == 2
-        # Exact-length (no tail) and non-prefix lookups miss.
-        assert sc.lookup("a", np.asarray([1, 2], np.int32)) is None
-        assert sc.lookup("a", np.asarray([1, 9, 5], np.int32)) is None
 
 
 @pytest.fixture(scope="module")
@@ -509,7 +503,7 @@ class TestSpeculativeDecode:
         spec, q1, _, _ = self._engines(lm, draft_lm)
         req = submit(q1, [1, 2, 3], max_new_tokens=6, temperature=0.8,
                      seed=7)
-        spec._admit()
+        admit(spec)
         assert not spec._use_spec()
         spec.run_until_idle(timeout_s=120)
         assert len(req.future.result(timeout=5).tokens) == 6
@@ -529,16 +523,14 @@ class TestSpeculativeDecode:
                             draft_params=params, spec_tokens=3)
         # Greedy request decoding...
         r1 = submit(q, [5, 9, 2, 7], max_new_tokens=30)
-        spec._admit()
+        admit(spec)
         spec._step()
         # ...then a long admission forces plain interleave steps.
         r2 = submit(q, [(i * 7) % 50 + 1 for i in range(20)],
                     max_new_tokens=30)
-        # Stale-read fix (ISSUE 15 ride-along): PR 13 split these
-        # counters by a ``paged`` tag — the old model-only read keyed a
-        # series nothing ever increments, so this test silently graded
-        # zero rounds. Slab engine: paged="false".
-        tags = {"model": model.name, "paged": "false"}
+        # The counters carry a ``paged`` tag, always "true": a
+        # model-only read keys a series nothing ever increments.
+        tags = {"model": model.name, "paged": "true"}
         rounds0 = SPEC_ROUNDS.get(tags=tags)
         acc0 = SPEC_ACCEPTED.get(tags=tags)
         spec.run_until_idle(timeout_s=180)
@@ -588,7 +580,7 @@ class TestStreamingAndHorizon:
         queue.add_request(req)
 
         seen_before_done = []
-        engine._admit()                    # prefill -> first token
+        admit(engine)                      # prefill -> first token
         assert not req.future.done()
         seen_before_done.append(req.stream.get(timeout_s=5))
         engine._step(horizon=1)            # second token, still unfinished
@@ -639,11 +631,11 @@ class TestStreamingAndHorizon:
         assert engine.ttft_horizon == 2
         # Free slots + empty queue -> ttft tier.
         r1 = submit(queue, [1, 2], max_new_tokens=16)
-        engine._admit()
+        admit(engine)
         assert engine._pick_horizon() == 2
         # Batch full -> full horizon regardless of the queue.
         r2 = submit(queue, [3, 4], max_new_tokens=16)
-        engine._admit()
+        admit(engine)
         assert not engine._free_slots()
         assert engine._pick_horizon() == 8
         # Free slot + waiting request -> single step (admit ASAP).
@@ -657,32 +649,6 @@ class TestStreamingAndHorizon:
         assert derived.ttft_horizon == 2
         clamped, _ = make_engine(lm, decode_horizon=2, ttft_horizon=64)
         assert clamped.ttft_horizon == 2
-
-    def test_admission_cap_interleaves(self, lm):
-        """While slots are DECODING, _admit is capped (prefills must
-        interleave with decode steps); an idle engine ramps by filling every
-        free slot in one call (nothing to stall)."""
-        engine, queue = make_engine(
-            lm, num_slots=4, max_admissions_per_step=2
-        )
-        # Idle ramp: all four queued requests admitted at once.
-        for _ in range(4):
-            submit(queue, [1, 2], max_new_tokens=4)
-        assert engine._admit() == 4
-        assert engine.active_slots == 4
-        engine.run_until_idle()
-        assert engine.completed == 4
-        # Active engine: the cap protects running slots — 3 slots are free
-        # and 3 requests wait, but only max_admissions_per_step=2 join.
-        first = submit(queue, [1, 2], max_new_tokens=6)
-        assert engine._admit() == 1          # idle again -> admitted
-        for _ in range(3):
-            submit(queue, [1, 2], max_new_tokens=4)
-        assert engine.active_slots == 1       # still decoding
-        assert engine._admit() == 2           # capped, despite 3 free slots
-        engine.run_until_idle()
-        assert engine.completed == 8
-        assert len(first.future.result(timeout=5).tokens) == 6
 
     def test_long_prompt_chunked_parity(self, lm):
         """A prompt longer than every bucket admits via chunked prefill and
@@ -708,22 +674,18 @@ class TestStreamingAndHorizon:
             decode_horizon=1,
         )
         short = submit(queue, [1, 2, 3], max_new_tokens=40)
-        assert engine._admit() == 1
+        assert admit(engine) == 1
         engine._step()  # short request actively decoding
-        decode_calls = []
-        real_decode = engine._decode_fn
-
-        def counting(*args):
-            decode_calls.append(1)
-            return real_decode(*args)
-
-        engine._decode_fn = counting
+        engine.reset_ttft_window()
         submit(queue, [(i * 3) % 40 + 1 for i in range(20)],
                max_new_tokens=4)
-        assert engine._admit() == 1  # 20 tokens / 8-chunks = 3 chunks
-        # 2 inter-chunk decode steps ran while the long prompt prefilled.
-        assert len(decode_calls) >= 2
         engine.run_until_idle(timeout_s=120)
+        # 20 tokens / 8-chunks = 3 chunks, a decode turn after each: the
+        # ring never shows two chunk dispatches in a row.
+        kinds = [t.kind for t in engine.turns]
+        assert kinds.count("chunk") == 3
+        assert all(a != "chunk" or b == "turn"
+                   for a, b in zip(kinds, kinds[1:]))
         assert len(short.future.result(timeout=5).tokens) == 40
 
     def test_long_prompt_capacity_not_chunk_multiple(self, lm):
@@ -741,45 +703,30 @@ class TestStreamingAndHorizon:
                 == r2.future.result(timeout=5).tokens)
 
     def test_prefix_cache_hit_parity_and_skip(self, lm):
-        """Two long prompts sharing the first chunk: the second admission
-        must reuse the cached prefix KV (one fewer chunk dispatch) and
-        generate exactly the tokens of a cache-off engine."""
-        shared = [(i * 7) % 50 + 1 for i in range(8)]      # = chunk width
+        """Two long prompts sharing their first page: the second admission
+        must borrow the published page by reference (its chunk dispatches
+        cover the tail only) and generate exactly the tokens of the
+        model's full forward."""
+        model, params = lm
+        shared = [(i * 7) % 50 + 1 for i in range(128)]    # = one page
         p1 = shared + [(i * 3) % 40 + 1 for i in range(10)]
         p2 = shared + [(i * 11) % 40 + 1 for i in range(7)]
-        cached, q1 = make_engine(lm, prompt_buckets=[8], max_len=64,
+        cached, q1 = make_engine(lm, prompt_buckets=[8], max_len=192,
                                  prefix_cache_size=4)
-        plain, q2 = make_engine(lm, prompt_buckets=[8], max_len=64)
         chunk_calls = count_chunk_dispatches(cached)
         r1 = submit(q1, p1, max_new_tokens=4)
         cached.run_until_idle(timeout_s=120)
-        first_calls = len(chunk_calls)   # miss: all 3 chunks computed
-        assert first_calls == 3          # p1 = 18 tokens / 8-chunks
+        first_calls = len(chunk_calls)   # miss: every chunk computed
+        assert first_calls == 18         # p1 = 138 tokens / 8-chunks
         r2 = submit(q1, p2, max_new_tokens=4)
         cached.run_until_idle(timeout_s=120)
-        # p2 = 15 tokens -> 2 chunks; the hit skips chunk 0 -> exactly 1.
+        # p2 = 135 tokens; the hit skips the shared page -> the 7-token
+        # tail is exactly 1 chunk.
         assert len(chunk_calls) - first_calls == 1
-        assert len(cached.prefix_cache) == 1
+        assert len(cached.paged_prefix) == 1
         for p, r in ((p1, r1), (p2, r2)):
-            ref = submit(q2, p, max_new_tokens=4)
-            plain.run_until_idle(timeout_s=120)
             assert r.future.result(timeout=5).tokens == \
-                ref.future.result(timeout=5).tokens
-
-    def test_prefix_cache_lru_eviction(self, lm):
-        from ray_dynamic_batching_tpu.engine.decode import PrefixCache
-        import numpy as np
-        pc = PrefixCache(capacity=2, width=4)
-        a = np.arange(8, dtype=np.int32)
-        b = a + 1
-        c = a + 2
-        pc.insert(a, jnp.zeros((1,)), jnp.zeros((1,)))
-        pc.insert(b, jnp.ones((1,)), jnp.ones((1,)))
-        assert pc.lookup(a) is not None      # refresh a
-        pc.insert(c, jnp.ones((1,)), jnp.ones((1,)))  # evicts b (LRU)
-        assert pc.lookup(b) is None
-        assert pc.lookup(a) is not None and pc.lookup(c) is not None
-        assert len(pc) == 2
+                teacher_forced(model, params, p, 4)
 
     def test_prompt_beyond_capacity_rejected(self, lm):
         engine, queue = make_engine(lm, prompt_buckets=[8], max_len=16)
@@ -931,19 +878,22 @@ class TestMidAdmissionVisibility:
         engine, queue = make_engine(lm, num_slots=2)
         try:
             seen = {}
-            real = engine._prefill_group
+            real = engine._start_train
 
-            def spy(bucket, chunk, slots):
+            def spy(*args):
                 seen["busy"] = engine.busy
                 seen["admitting"] = engine._admitting
-                return real(bucket, chunk, slots)
+                return real(*args)
 
-            engine._prefill_group = spy
+            engine._start_train = spy
             submit(queue, [1, 2, 3], max_new_tokens=2)
             engine._admit()
             assert seen == {"busy": True, "admitting": 1}
-            # Admission done: the ledger is clear, the slot carries it.
+            # Dequeue done: the ledger is clear, the chunk train carries
+            # the request (no slot is active before its first token).
             assert engine._admitting == 0
+            assert engine.busy and engine.active_slots == 0
+            engine._drain_prefill()
             assert engine.busy and engine.active_slots == 1
             engine.run_until_idle(timeout_s=60)
             assert not engine.busy

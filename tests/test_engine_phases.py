@@ -361,7 +361,7 @@ CHUNK = Turn("chunk", 4.0, 5.0, 0.0, 6.0, 0, 512, 2, 1, 0, 8, 100, False)
     ([_scan(1, 32), _scan(1, 32)], 8, 1.0),            # every entry live
     ([_scan(8, 4), _scan(8, 4)], 8, 1 / 8),            # idle: page 0 a slot
     ([_scan(2), _scan(8)], 8, None),       # records without the field: 0
-    ([_scan(2, 6), _scan(8, 10)], 0, None),            # a slab engine
+    ([_scan(2, 6), _scan(8, 10)], 0, None),            # no table width
     ([CHUNK, CHUNK], 8, None),                         # no scan
 ])
 def test_summarize_turns_gives_the_live_page_share_by_hand(
@@ -400,13 +400,3 @@ def test_a_paged_scan_counts_the_entries_its_first_substep_may_attend(lm):
     assert snap["turns"]["kv_live_page_share"] == pytest.approx(live / walked)
     assert snap["kv_pool"]["pages_live"] == live
     assert snap["kv_pool"]["pages_scanned"] == walked
-
-
-def test_a_slab_engine_counts_no_pages(lm):
-    engine, queue = _engine(lm, paged=False)
-    reqs = _submit(queue, engine.model.name, lens=(5, 12))
-    engine.run_until_idle(timeout_s=300)
-    for r in reqs:
-        r.future.result(timeout=5)
-    assert engine.turns and all(t.kv_pages_live == 0 for t in engine.turns)
-    assert "kv_live_page_share" not in engine.snapshot()["turns"]

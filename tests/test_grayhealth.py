@@ -325,6 +325,30 @@ class TestProbationRouting:
             r0.stop()
             r1.stop()
 
+    @pytest.mark.parametrize("uptime_s", [5.0, 10_000.0])
+    def test_a_never_probed_replica_is_due_at_any_uptime(self, uptime_s):
+        """``time.monotonic`` counts from the host's boot: on a host up
+        for less than ``probe_interval_s`` a probationed replica that was
+        never probed must still get its probe at once, and the next one
+        only an interval after the first."""
+        now = [uptime_s]
+        mon = GrayHealthMonitor(
+            "d", GrayHealthPolicy(min_samples=1, min_peers=1,
+                                  suspect_after=1, probation_after=1,
+                                  probe_interval_s=3600.0),
+            clock=lambda: now[0])
+        outlier = {"r0": (500.0, 500.0, 8), "r1": (10.0, 10.0, 8)}
+        mon.tick(outlier)
+        mon.tick(outlier)
+        assert mon.state("r0") == "probation"
+        assert mon.is_candidate("r0")
+        mon.mark_probe("r0")
+        assert not mon.is_candidate("r0")
+        now[0] += 3599.0
+        assert not mon.is_candidate("r0")
+        now[0] += 1.0
+        assert mon.is_candidate("r0")
+
     def test_all_probationed_falls_back_instead_of_blackholing(self):
         r0, r1, router = self._routed_pair()
         try:

@@ -131,7 +131,7 @@ class TestDecodeParity:
             assert (row > 0).all() and not np.allclose(row, 0.0)
 
     def test_engine_serves_with_quantized_cache(self):
-        """End to end through the replica: admission (copy_rows_into
+        """End to end through the replica: admission (chunk program
         must carry scale planes), decode scan, completion."""
         from ray_dynamic_batching_tpu.engine.request import (
             Request, TokenStream,
@@ -252,62 +252,57 @@ class TestDecodeParity:
         return req
 
     def test_session_continuation_with_quantized_cache(self):
-        """Multi-turn chat over an int8 cache: the stored row's SCALE
-        planes must ride the extract/seed round trip — turn 2 continues
-        from the quantized row and matches a sessionless int8 engine on
-        the full history."""
-        sess, q1 = self._int8_engine(session_cache_size=4)
-        plain, q2 = self._int8_engine()
-        turn1 = [(i * 7) % 50 + 1 for i in range(6)]
+        """Multi-turn chat over an int8 pool: the stored turn's pages
+        hold codes AND scale planes — turn 2 continues from the borrowed
+        page and matches the model's own int8 ``KVCache`` on the full
+        history."""
+        from tests.decode_reference import cached_greedy
+        from tests.test_decode import count_chunk_dispatches
+
+        sess, q1 = self._int8_engine(max_len=192, session_cache_size=4)
+        turn1 = [(i * 7) % 50 + 1 for i in range(130)]
         r1 = self._submit(q1, turn1, max_new_tokens=5,
                           session_id="chat-1")
         sess.run_until_idle(timeout_s=120)
         gen1 = r1.future.result(timeout=5).tokens
-        # the stored segment carries its scale planes
-        (seg, _hist) = next(iter(sess.session_cache._entries.values()))
-        assert seg[2] is not None and seg[3] is not None
+        assert len(sess.paged_sessions) == 1
         turn2 = turn1 + gen1 + [17, 23, 29]
-        from tests.test_decode import count_chunk_dispatches
-
         chunk_calls = count_chunk_dispatches(sess)
         r2 = self._submit(q1, turn2, max_new_tokens=5,
                           session_id="chat-1")
-        ref = self._submit(q2, turn2, max_new_tokens=5)
         sess.run_until_idle(timeout_s=120)
-        plain.run_until_idle(timeout_s=120)
-        # the REUSE path ran: only the 4-token tail (one chunk) was
-        # prefilled — a silent cache miss would re-chunk the whole
-        # 14-token history (2+ chunks) and still match tokens.
-        assert len(chunk_calls) == 1, chunk_calls
+        # the REUSE path ran: one whole page (128 of the 134 stored
+        # positions) was borrowed and only positions 128..137 prefilled
+        # (two 8-wide chunks) — a silent cache miss would re-chunk the
+        # whole 138-token history (18 chunks) and still match tokens.
+        assert len(chunk_calls) == 2, chunk_calls
         assert (r2.future.result(timeout=5).tokens
-                == ref.future.result(timeout=5).tokens)
+                == cached_greedy(sess.model, sess.params, turn2, 5))
 
     def test_prefix_cache_with_quantized_cache(self):
-        """Shared-prefix reuse over an int8 cache: the cached chunk's
-        codes AND scales seed the second admission, which must match a
-        prefix-cache-off int8 engine exactly."""
-        shared = [(i * 7) % 50 + 1 for i in range(8)]  # = chunk width
-        p1 = shared + [(i * 3) % 40 + 1 for i in range(10)]
-        p2 = shared + [(i * 11) % 40 + 1 for i in range(7)]
-        cached, q1 = self._int8_engine(max_len=64, prefix_cache_size=4)
-        plain, q2 = self._int8_engine(max_len=64)
+        """Shared-prefix reuse over an int8 pool: the published page's
+        codes AND scales serve the second admission by reference, which
+        must match the model's own int8 ``KVCache`` exactly."""
+        from tests.decode_reference import cached_greedy
         from tests.test_decode import count_chunk_dispatches
 
+        shared = [(i * 7) % 50 + 1 for i in range(128)]  # = one page
+        p1 = shared + [(i * 3) % 40 + 1 for i in range(10)]
+        p2 = shared + [(i * 11) % 40 + 1 for i in range(7)]
+        cached, q1 = self._int8_engine(max_len=192, prefix_cache_size=4)
         chunk_calls = count_chunk_dispatches(cached)
         r1 = self._submit(q1, p1, max_new_tokens=4)
         cached.run_until_idle(timeout_s=120)
-        first_calls = len(chunk_calls)  # miss: all 3 chunks computed
-        (entry,) = cached.prefix_cache._entries.values()
-        assert entry[2] is not None and entry[3] is not None
+        first_calls = len(chunk_calls)  # miss: all 18 chunks computed
+        assert len(cached.paged_prefix) == 1
+        assert cached._cache.k_scale is not None
         r2 = self._submit(q1, p2, max_new_tokens=4)
         cached.run_until_idle(timeout_s=120)
-        # the hit skipped chunk 0: p2 (15 tokens, 2 chunks) paid one.
+        # the hit skipped the shared page: p2's 7-token tail paid one.
         assert len(chunk_calls) - first_calls == 1, chunk_calls
         for p, r in ((p1, r1), (p2, r2)):
-            ref = self._submit(q2, p, max_new_tokens=4)
-            plain.run_until_idle(timeout_s=120)
             assert r.future.result(timeout=5).tokens == \
-                ref.future.result(timeout=5).tokens
+                cached_greedy(cached.model, cached.params, p, 4)
 
     def test_engine_under_pallas_backend_matches_xla_backend(self):
         """The quantized cache must serve equivalent streams whether the
@@ -406,7 +401,7 @@ class TestDecodeParity:
             ex.shutdown()
 
     def test_tp_mesh_shards_scale_planes(self):
-        """make_sharded_cache must shard the quantized cache's scale
+        """make_sharded_paged_cache must shard the quantized pool's scale
         planes alongside k/v (a hand-listed constructor dropped them
         once) and TP decode must run with the int8 cache."""
         import numpy as np

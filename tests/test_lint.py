@@ -411,7 +411,7 @@ class TestHostSync:
         from tools.lint.host_sync import HOT_FUNCTIONS
 
         assert {"_pump_prefill", "_dispatch_chunk_group",
-                "_advance_train_slab", "_grant_train_pages"} <= \
+                "_spend_prefill_budget", "_grant_train_pages"} <= \
             HOT_FUNCTIONS["engine/decode.py"]
         report = lint_fixture(tmp_path, "engine/decode.py", """
             import numpy as np

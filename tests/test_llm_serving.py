@@ -418,7 +418,7 @@ class TestAutoSlots:
         assert n1 >= 1
         assert n1 & (n1 - 1) == 0  # power of two
         # The chosen count must actually fit the budget.
-        kv_total = n1 * dep._model.kv_bytes_per_slot(64)
+        kv_total = n1 * dep.pool_bytes_per_slot(dep._model, 64)
         assert kv_total <= (1 << 30)
         # A tighter budget yields fewer slots.
         set_config(RDBConfig.from_env(hbm_budget_bytes=64 << 20))

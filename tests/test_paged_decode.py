@@ -1,15 +1,17 @@
-"""Paged KV decode vs the slab path — token-exactness + pool behavior.
+"""Paged KV decode — token-exactness + pool behavior.
 
-The paged pool's contract is byte-identical tokens: the same logical KV
-positions land in pages instead of a slab row, the same decode-mask
-window bounds attention, the same dequant rule reads int8 codes — so a
-seeded workload must produce EXACTLY the slab path's tokens, f32 and
-int8-KV, through the XLA gather fallback AND through the
-CPU-interpreted Pallas page-table kernel (ISSUE 7 acceptance; tier-1).
+The paged pool's contract is the tokens the model gives: logical KV
+positions land in pages, the decode-mask window bounds attention, the
+dequant rule reads int8 codes — so a seeded workload must be served
+EXACTLY the tokens of a reference that shares no engine code
+(``tests/decode_reference.py``: the model's full forward for f32, the
+model's own slab ``KVCache`` for int8 KV), through the XLA gather
+fallback AND through the CPU-interpreted Pallas page-table kernel
+(ISSUE 7 acceptance; tier-1).
 
 The tiny-model engine tests here stay un-marked (tier-1): llama_tiny
 compiles in seconds and the paged plane is exactly the code the rest of
-the PR stands on. The chunked/long-prompt CoW paths ride the `slow`
+the PR stands on. The long-prompt CoW paths ride the `slow`
 mark with the rest of the compile-heavy decode suites.
 """
 
@@ -34,6 +36,8 @@ from ray_dynamic_batching_tpu.ops.attention import (
     set_attention_backend,
 )
 
+from tests.decode_reference import assert_served
+
 
 @pytest.fixture(scope="module")
 def lm():
@@ -45,8 +49,7 @@ def lm():
 @pytest.fixture(scope="module")
 def lm_int8(lm):
     model = get_model("llama_tiny_int8kv", dtype=jnp.float32)
-    # Same weights as the f32 fixture: only the cache dtype differs, so
-    # slab-vs-paged comparisons isolate the paging change.
+    # Same weights as the f32 fixture: only the cache dtype differs.
     return model, lm[1]
 
 
@@ -69,50 +72,41 @@ def _workload(queue, model_name, seed=7, n=6, sampled_row=True):
     return reqs
 
 
-def _run(model, params, paged, **kw):
+def _run(model, params, **kw):
     queue = RequestQueue(model.name, max_len=256)
     defaults = dict(
         num_slots=4, max_len=64, prompt_buckets=[8, 16], eos_token_id=None,
-        default_max_new_tokens=8, decode_horizon=4,
-        paged=paged, page_size=128,
+        default_max_new_tokens=8, decode_horizon=4, page_size=128,
     )
     defaults.update(kw)
     engine = DecodeEngine(model, params, queue, **defaults)
     reqs = _workload(queue, model.name)
     engine.run_until_idle(timeout_s=180)
     tokens = [tuple(r.future.result(timeout=5).tokens) for r in reqs]
-    return tokens, engine
+    return tokens, engine, reqs
 
 
 class TestTokenExactness:
-    def test_paged_matches_slab_f32(self, lm):
-        model, params = lm
-        slab, _ = _run(model, params, paged=False)
-        paged, engine = _run(model, params, paged=True)
-        assert slab == paged
+    @pytest.mark.parametrize("case", ["f32", "int8_kv", "pallas_kernel"])
+    def test_served_tokens_match_the_reference(self, case, lm, lm_int8):
+        """The former slab-against-paged arms, each against a reference
+        that shares no engine code: the model's full forward (f32, and
+        the page-table Pallas kernel in CPU interpret mode — the fused
+        gather is a pure layout change), the model's own int8 slab
+        ``KVCache`` for the quantized pool."""
+        model, params = lm_int8 if case == "int8_kv" else lm
+        set_attention_backend("pallas" if case == "pallas_kernel"
+                              else "auto")
+        try:
+            served, engine, reqs = _run(model, params)
+        finally:
+            set_attention_backend("auto")
+        assert_served(model, params, reqs, served,
+                      cached=case == "int8_kv")
         # Drained engine: every page either free or pinned by a cache
         # (none configured here -> all free), invariants intact.
         engine._allocator.check()
         assert engine._allocator.free_pages == engine.num_pages
-
-    def test_paged_matches_slab_int8_kv(self, lm_int8):
-        model, params = lm_int8
-        slab, _ = _run(model, params, paged=False)
-        paged, _ = _run(model, params, paged=True)
-        assert slab == paged
-
-    def test_paged_pallas_kernel_matches_slab(self, lm):
-        """The page-table Pallas kernel (CPU interpret mode) must emit
-        the same tokens as the slab path — the fused gather is a pure
-        layout change."""
-        model, params = lm
-        set_attention_backend("pallas")
-        try:
-            paged, _ = _run(model, params, paged=True)
-        finally:
-            set_attention_backend("auto")
-        slab, _ = _run(model, params, paged=False)
-        assert slab == paged
 
 
 class TestPagedKernel:
@@ -322,44 +316,43 @@ class TestPagedKernelStopsAtTheLength:
 
 
 class TestPoolBehavior:
-    def test_kv_occupancy_paged_beats_slab(self, lm):
+    def test_kv_occupancy_follows_allocated_pages(self, lm):
         """The decode slot-occupancy criterion, measured at the engine:
-        mid-stream, the paged pool's reserved KV (allocated pages) holds
-        a higher useful fraction than the slab reservation
-        (num_slots x max_len) on the SAME traffic."""
+        mid-stream, the reserved KV is the allocated pages, so the useful
+        fraction is higher than a reservation of num_slots x max_len
+        would give on the SAME traffic."""
         model, params = lm
-        occ = {}
-        for paged in (False, True):
-            queue = RequestQueue(model.name, max_len=256)
-            # max_len must exceed the page size for pages to be the
-            # FINER reservation (the realistic serving geometry: slabs
-            # of 256+ positions vs 128-position pages).
-            engine = DecodeEngine(
-                model, params, queue, num_slots=4, max_len=256,
-                prompt_buckets=[8, 16], eos_token_id=None,
-                default_max_new_tokens=32, decode_horizon=2,
-                paged=paged, page_size=128,
-            )
-            rng = np.random.default_rng(11)
-            reqs = []
-            for _ in range(3):  # 3 of 4 slots live: slabs idle, pages don't
-                r = Request(model=model.name, payload={
-                    "tokens": rng.integers(1, 500, 6).tolist(),
-                    "max_new_tokens": 32,
-                }, slo_ms=60_000.0)
-                queue.add_request(r)
-                reqs.append(r)
-            engine._admit()
-            if engine.chunked_prefill:
-                engine._drain_prefill()
-            for _ in range(4):
-                engine._step(horizon=1)
-            occ[paged] = engine.kv_occupancy()
-            engine.run_until_idle(timeout_s=120)
-            for r in reqs:
-                r.future.result(timeout=5)
-        assert occ[True] > occ[False]
-        assert occ[True] >= 0.05  # useful fraction of one 128-page/slot
+        queue = RequestQueue(model.name, max_len=256)
+        # max_len must exceed the page size for pages to be the FINER
+        # reservation (the realistic serving geometry: 256+ positions a
+        # slot, 128-position pages).
+        engine = DecodeEngine(
+            model, params, queue, num_slots=4, max_len=256,
+            prompt_buckets=[8, 16], eos_token_id=None,
+            default_max_new_tokens=32, decode_horizon=2, page_size=128,
+        )
+        rng = np.random.default_rng(11)
+        reqs = []
+        for _ in range(3):  # 3 of 4 slots live: an idle slot holds no page
+            r = Request(model=model.name, payload={
+                "tokens": rng.integers(1, 500, 6).tolist(),
+                "max_new_tokens": 32,
+            }, slo_ms=60_000.0)
+            queue.add_request(r)
+            reqs.append(r)
+        engine._admit()
+        engine._drain_prefill()
+        for _ in range(4):
+            engine._step(horizon=1)
+        occ = engine.kv_occupancy()
+        used = float(engine._len_host.sum())
+        assert engine._allocator.allocated_pages == 3
+        assert occ == used / (3 * 128)
+        assert occ > used / (4 * 256)
+        assert occ >= 0.05  # useful fraction of one 128-page/slot
+        engine.run_until_idle(timeout_s=120)
+        for r in reqs:
+            r.future.result(timeout=5)
 
     def test_eos_frees_pages_mid_cycle(self, lm):
         """A finished stream's pages return to the free list inside the
@@ -519,16 +512,6 @@ class TestPoolBehavior:
         doc = to_chrome_trace([], journal=journal["events"])
         assert any(e["ph"] == "C" for e in doc["traceEvents"])
 
-    def test_slab_snapshot_has_no_journal(self, lm):
-        model, params = lm
-        queue = RequestQueue(model.name, max_len=16)
-        engine = DecodeEngine(
-            model, params, queue, num_slots=2, max_len=64,
-            prompt_buckets=[8], eos_token_id=None, paged=False,
-        )
-        snap = engine.snapshot()
-        assert snap["paged"] is False and "page_journal" not in snap
-
     def test_paged_rejects_bad_config(self, lm):
         # (TP meshes no longer reject — ROADMAP item 2 shards the pool,
         # tests/test_tp_paged_decode.py — and neither do draft models:
@@ -565,7 +548,7 @@ class TestPagedServing:
             "llama_tiny#p", DeploymentConfig(name="llama_tiny"))
         replica.start()
         try:
-            assert replica.engine.paged
+            assert replica.engine.snapshot()["paged"] is True
             assert replica.engine.page_size == 128
             router = Router("llama_tiny", replicas=[replica])
             handle = DeploymentHandle(router, default_slo_ms=60_000.0)
@@ -585,7 +568,7 @@ class TestPagedServing:
 
         dep = LLMDeployment("llama_tiny", paged=True,
                             draft_model_name="llama_tiny")
-        assert dep.paged and dep.draft_model_name == "llama_tiny"
+        assert dep.draft_model_name == "llama_tiny"
 
 
 @pytest.mark.slow  # chunked-prefill paths compile several extra programs
@@ -593,10 +576,10 @@ class TestPagedCoW:
     """Copy-on-write sharing through the chunked admission paths: paged
     prefix (longest shared page-prefix, by reference) and session
     continuation (O(1) store pinning the finished turn's pages) must
-    stay token-exact vs the slab equivalents AND leave the allocator
+    serve the tokens of the model-level reference AND leave the allocator
     conserved with only cache pins outstanding."""
 
-    def _engines(self, lm, paged, model=None, params=None):
+    def _engines(self, lm, model=None, params=None):
         model_, params_ = lm
         model = model or model_
         params = params if params is not None else params_
@@ -604,8 +587,7 @@ class TestPagedCoW:
         engine = DecodeEngine(
             model, params, queue, num_slots=4, max_len=192,
             prompt_buckets=[16, 32, 64, 128], eos_token_id=None,
-            default_max_new_tokens=6, decode_horizon=4,
-            paged=paged, page_size=128,
+            default_max_new_tokens=6, decode_horizon=4, page_size=128,
             prefix_cache_size=8, session_cache_size=4,
         )
         return engine, queue
@@ -628,20 +610,19 @@ class TestPagedCoW:
             queue.add_request(r)
             reqs.append(r)
         engine.run_until_idle(timeout_s=300)
-        return [tuple(r.future.result(timeout=5).tokens) for r in reqs]
+        return [tuple(r.future.result(timeout=5).tokens)
+                for r in reqs], reqs
 
     def test_long_prefix_session_exact_and_conserved(self, lm):
         from ray_dynamic_batching_tpu.engine.decode import PREFIX_HITS
 
-        model, _ = lm
+        model, params = lm
         prompts = self._prompts()
-        e_slab, q_slab = self._engines(lm, paged=False)
-        slab = self._run(e_slab, q_slab, model.name, prompts)
         before = PREFIX_HITS.get(
             tags={"model": model.name, "granularity": "page"})
-        e_paged, q_paged = self._engines(lm, paged=True)
-        paged = self._run(e_paged, q_paged, model.name, prompts)
-        assert slab == paged
+        e_paged, q_paged = self._engines(lm)
+        served, reqs = self._run(e_paged, q_paged, model.name, prompts)
+        assert_served(model, params, reqs, served)
         # The shared 128-token head actually shared: page-granular hits
         # fired (prompts 3 and 4 reuse prompt 2's first page).
         after = PREFIX_HITS.get(
@@ -661,17 +642,16 @@ class TestPagedCoW:
         model8 = get_model("llama_tiny_int8kv", dtype=jnp.float32)
         params = lm[1]
         prompts = self._prompts()
-        e_slab, q_slab = self._engines(lm, False, model8, params)
-        e_paged, q_paged = self._engines(lm, True, model8, params)
-        assert self._run(e_slab, q_slab, model8.name, prompts) == \
-            self._run(e_paged, q_paged, model8.name, prompts)
+        engine, queue = self._engines(lm, model8, params)
+        served, reqs = self._run(engine, queue, model8.name, prompts)
+        assert_served(model8, params, reqs, served, cached=True)
 
     def test_session_store_is_by_reference(self, lm):
         """A finished session turn pins the slot's pages instead of
         copying a row: the stored entry's page ids are exactly the
         pages the slot held."""
         model, _ = lm
-        engine, queue = self._engines(lm, paged=True)
+        engine, queue = self._engines(lm)
         rng = np.random.default_rng(9)
         prompt = rng.integers(1, 500, 140).tolist()
         r = Request(model=model.name, payload={

@@ -2,10 +2,11 @@
 
 The contract is threefold:
 
-- **Token-exactness**: paged+spec produces byte-identical greedy tokens
-  vs slab+spec AND vs paged-plain on the same prompts (f32 and int8-KV,
-  XLA fallback and CPU-interpreted Pallas kernel) — speculation with a
-  paged pool is a pure latency transform, never a sampling one.
+- **Token-exactness**: the spec engine serves the greedy tokens of the
+  model-level reference (``tests/decode_reference.py``) AND of the same
+  engine without the draft on the same prompts (f32 and int8-KV, XLA
+  fallback and CPU-interpreted Pallas kernel) — speculation is a pure
+  latency transform, never a sampling one.
 - **Splice semantics**: accepted prefixes commit by PAGE-TABLE SPLICE
   (scratch pages re-pointed into the slot's table, zero KV bytes copied
   — the journal shows ``spec_commit`` and no ``cow_copy`` on the accept
@@ -45,6 +46,8 @@ from ray_dynamic_batching_tpu.ops.attention import (
     set_attention_backend,
 )
 from ray_dynamic_batching_tpu.ops.tile_math import spec_scratch_pages
+
+from tests.decode_reference import assert_served
 
 
 @pytest.fixture(scope="module")
@@ -87,12 +90,11 @@ def _workload(queue, model_name, seed=7, n=6):
     return reqs
 
 
-def _run(model, params, *, paged, draft=None, **kw):
+def _run(model, params, *, draft=None, **kw):
     queue = RequestQueue(model.name, max_len=256)
     defaults = dict(
         num_slots=4, max_len=64, prompt_buckets=[8, 16], eos_token_id=None,
-        default_max_new_tokens=8, decode_horizon=4,
-        paged=paged, page_size=128,
+        default_max_new_tokens=8, decode_horizon=4, page_size=128,
     )
     if draft is not None:
         dmodel, dparams = draft
@@ -103,27 +105,25 @@ def _run(model, params, *, paged, draft=None, **kw):
     reqs = _workload(queue, model.name)
     engine.run_until_idle(timeout_s=300)
     tokens = [tuple(r.future.result(timeout=5).tokens) for r in reqs]
-    return tokens, engine
+    return tokens, engine, reqs
 
 
 class TestTokenExactness:
-    def test_paged_spec_matches_slab_spec_and_plain_f32(self, lm, draft_lm):
-        """The ISSUE 13 acceptance pin: same prompts through paged+spec,
-        slab+spec, and paged-plain — three byte-identical token streams,
-        with a DIVERGENT draft so partial acceptance is exercised."""
-        model, params = lm
-        plain_paged, _ = _run(model, params, paged=True)
-        slab_spec, _ = _run(model, params, paged=False, draft=draft_lm)
-        paged_spec, engine = _run(model, params, paged=True, draft=draft_lm)
-        assert paged_spec == slab_spec == plain_paged
+    @pytest.mark.parametrize("case", ["f32", "int8_kv"])
+    def test_spec_serves_the_reference_and_the_plain_engines_tokens(
+            self, case, lm, lm_int8, draft_lm):
+        """The ISSUE 13 acceptance pin: the same prompts through the spec
+        engine and the same engine without the draft give one token
+        stream, the model-level reference's — with a DIVERGENT draft so
+        partial acceptance is exercised."""
+        model, params = lm_int8 if case == "int8_kv" else lm
+        plain, _, _ = _run(model, params)
+        spec, engine, reqs = _run(model, params, draft=draft_lm)
+        assert spec == plain
+        assert_served(model, params, reqs, spec,
+                      cached=case == "int8_kv")
         engine._allocator.check()
         assert engine._allocator.free_pages == engine.num_pages
-
-    def test_paged_spec_matches_slab_spec_int8_kv(self, lm_int8, draft_lm):
-        model, params = lm_int8
-        slab_spec, _ = _run(model, params, paged=False, draft=draft_lm)
-        paged_spec, _ = _run(model, params, paged=True, draft=draft_lm)
-        assert paged_spec == slab_spec
 
     def test_paged_spec_pallas_kernel_matches_xla(self, lm, draft_lm):
         """The staircase paged kernel (CPU interpret mode) must emit the
@@ -132,20 +132,20 @@ class TestTokenExactness:
         model, params = lm
         set_attention_backend("pallas")
         try:
-            kernel_toks, _ = _run(model, params, paged=True, draft=draft_lm)
+            kernel_toks, _, _ = _run(model, params, draft=draft_lm)
         finally:
             set_attention_backend("auto")
-        xla_toks, _ = _run(model, params, paged=True, draft=draft_lm)
+        xla_toks, _, _ = _run(model, params, draft=draft_lm)
         assert kernel_toks == xla_toks
 
     def test_paged_spec_pallas_kernel_int8(self, lm_int8, draft_lm):
         model, params = lm_int8
         set_attention_backend("pallas")
         try:
-            kernel_toks, _ = _run(model, params, paged=True, draft=draft_lm)
+            kernel_toks, _, _ = _run(model, params, draft=draft_lm)
         finally:
             set_attention_backend("auto")
-        xla_toks, _ = _run(model, params, paged=True, draft=draft_lm)
+        xla_toks, _, _ = _run(model, params, draft=draft_lm)
         assert kernel_toks == xla_toks
 
     def test_self_draft_accepts_everything_paged(self, lm):
@@ -228,7 +228,7 @@ class TestSpliceSemantics:
         tags = {"model": model.name, "paged": "true"}
         before = (SPEC_ACCEPTED.get(tags=tags), SPEC_REJECTED.get(tags=tags),
                   SPEC_DRAFTED.get(tags=tags))
-        _run(model, lm[1], paged=True, draft=draft_lm)
+        _run(model, lm[1], draft=draft_lm)
         a = SPEC_ACCEPTED.get(tags=tags) - before[0]
         rj = SPEC_REJECTED.get(tags=tags) - before[1]
         d = SPEC_DRAFTED.get(tags=tags) - before[2]
@@ -415,14 +415,15 @@ class TestExclusions:
             model, params, queue, paged=True, page_size=128,
             draft_model=model, draft_params=params,
         )
-        assert engine.paged and engine.draft_model is not None
+        assert engine.snapshot()["paged"] is True
+        assert engine.draft_model is not None
 
     def test_llm_deployment_accepts_paged_spec(self):
         from ray_dynamic_batching_tpu.serve.llm import LLMDeployment
 
         dep = LLMDeployment("llama_tiny", paged=True,
                             draft_model_name="llama_tiny")
-        assert dep.paged and dep.draft_model_name == "llama_tiny"
+        assert dep.draft_model_name == "llama_tiny"
 
 
 class TestPagedWindowKernel:

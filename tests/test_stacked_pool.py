@@ -188,8 +188,3 @@ def test_snapshot_says_how_the_pool_lies_on_the_device():
     assert pool["layout"] == [0, 1, 2, 3, 4]
     assert pool["resident_bytes"] == (
         engine._cache.k.nbytes + engine._cache.v.nbytes)
-    slab = DecodeEngine(
-        model, params, RequestQueue(model.name, max_len=64), num_slots=2,
-        max_len=64, prompt_buckets=[8], eos_token_id=None, paged=False,
-    )
-    assert "kv_pool" not in slab.snapshot()

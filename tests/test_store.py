@@ -474,7 +474,7 @@ class TestPgroupCatalog:
 
 
 class TestSecondReviewRegressions:
-    def _build_leader(self, **cfg_kw):
+    def _build_leader(self, start=True, **cfg_kw):
         log = StoreLog()
         lease = LeaderLease(duration_s=0.5)
         catalog = ReplicaCatalog()
@@ -487,7 +487,8 @@ class TestSecondReviewRegressions:
                              max_restarts=4, **cfg_kw),
             factory=lambda: double_batch,
         )
-        ctl_a.start()
+        if start:
+            ctl_a.start()
         return log, lease, catalog, ctl_a, router
 
     def test_deferred_stops_still_run_when_fenced_mid_step(self):
@@ -495,18 +496,20 @@ class TestSecondReviewRegressions:
         be stopped and released — skipping the deferred actions on
         StaleEpochError leaks its thread forever (no successor will ever
         adopt a replica the fenced step already unpublished)."""
-        log, lease, catalog, ctl, router = self._build_leader()
+        # The control loop's thread is not started: the steps are driven
+        # by hand, so the fence lands exactly between two of them.
+        log, lease, catalog, ctl, router = self._build_leader(start=False)
         try:
-            # Let the first control steps land their one-time governor/
-            # gray mirror writes; from then on steady state elides, so
-            # the NEXT append is the scale-down we stage below.
-            time.sleep(0.3)
+            # The first control steps land their one-time governor/gray
+            # mirror writes; from then on steady state elides, so the
+            # NEXT append is the scale-down staged below.
+            for _ in range(3):
+                ctl._control_step()
+            assert not ctl._fenced
             log.fence_to(2)  # a standby fenced the log...
             with ctl._lock:  # ...while a scale-down is pending
                 ctl._deployments["doubler"].config.num_replicas = 1
-            deadline = time.monotonic() + 5
-            while time.monotonic() < deadline and not ctl._fenced:
-                time.sleep(0.02)
+            ctl._control_step()  # its append is refused MID-step
             assert ctl._fenced
             # Exactly one replica keeps serving; the victim was STOPPED
             # (deferred ran despite the fence), not leaked.

@@ -1,13 +1,14 @@
 """TP-mesh paged decode — the PR 7 exclusion lifted (ROADMAP item 2).
 
-``DecodeEngine(paged=True, mesh=...)`` shards the page pool over the
+``DecodeEngine(mesh=...)`` shards the page pool over the
 mesh's kv-head (tp) axis — codes AND int8 scale planes — while the page
 table, lengths, and the host-side free-list allocator stay
 replica-global (page indices are shard-invariant). The contract is the
 same byte-identical-tokens bar every other cache layout meets: a seeded
 workload (greedy rows + one seeded sampled row) through a TP=2 paged
-engine must emit EXACTLY the tokens of (a) the single-chip paged engine
-and (b) the TP=2 slab engine, f32 and int8-KV, on the forced-8-device
+engine must emit EXACTLY the tokens of (a) the single-chip engine
+and (b) the model-level reference that shares no engine code
+(``tests/decode_reference.py``), f32 and int8-KV, on the forced-8-device
 CPU host (tier-1 — the fake-chip cluster runs the real GSPMD paths).
 
 Kept un-marked (tier-1) like the rest of test_paged_decode's tiny-model
@@ -25,6 +26,7 @@ from ray_dynamic_batching_tpu.models import registry  # noqa: F401
 from ray_dynamic_batching_tpu.models.base import get_model
 from ray_dynamic_batching_tpu.parallel.mesh import MeshConfig, build_mesh
 
+from tests.decode_reference import assert_served
 from tests.test_paged_decode import _workload
 
 
@@ -46,26 +48,26 @@ def tp2_mesh():
     return build_mesh(MeshConfig(tp=2), jax.devices()[:2])
 
 
-def _run(model, params, paged, mesh=None):
+def _run(model, params, mesh=None):
     queue = RequestQueue(model.name, max_len=256)
     engine = DecodeEngine(
         model, params, queue,
         num_slots=4, max_len=64, prompt_buckets=[8, 16],
         default_max_new_tokens=8, decode_horizon=4,
-        paged=paged, page_size=128, mesh=mesh,
+        page_size=128, mesh=mesh,
     )
     reqs = _workload(queue, model.name)
     engine.run_until_idle(timeout_s=180)
     tokens = [tuple(r.future.result(timeout=5).tokens) for r in reqs]
-    return tokens, engine
+    return tokens, engine, reqs
 
 
 class TestTPPagedTokenExactness:
     def test_tp2_paged_matches_single_chip_paged_f32(self, lm,
                                                      eight_devices):
         model, params = lm
-        single, _ = _run(model, params, paged=True)
-        tp, engine = _run(model, params, paged=True, mesh=tp2_mesh())
+        single, _, _ = _run(model, params)
+        tp, engine, _ = _run(model, params, mesh=tp2_mesh())
         assert tp == single
         # The replica-global allocator's conservation invariants hold
         # under the sharded pool, and a drained engine returns every
@@ -73,25 +75,18 @@ class TestTPPagedTokenExactness:
         engine._allocator.check()
         assert engine._allocator.free_pages == engine.num_pages
 
-    def test_tp2_paged_matches_tp_slab_f32(self, lm, eight_devices):
-        """Same mesh, page pool vs slab: paging is a pure layout change
-        under TP exactly as it is on one chip."""
-        model, params = lm
-        mesh = tp2_mesh()
-        slab, _ = _run(model, params, paged=False, mesh=mesh)
-        paged, _ = _run(model, params, paged=True, mesh=mesh)
-        assert paged == slab
-
-    def test_tp2_paged_int8_kv_matches_both(self, lm_int8, eight_devices):
-        """int8-KV pool under TP: codes and scale planes shard together;
-        tokens match the single-chip paged AND the TP slab engines."""
-        model, params = lm_int8
-        single, _ = _run(model, params, paged=True)
-        mesh = tp2_mesh()
-        tp_paged, _ = _run(model, params, paged=True, mesh=mesh)
-        tp_slab, _ = _run(model, params, paged=False, mesh=mesh)
-        assert tp_paged == single
-        assert tp_paged == tp_slab
+    @pytest.mark.parametrize("case", ["f32", "int8_kv"])
+    def test_tp2_serves_the_reference(self, case, lm, lm_int8,
+                                      eight_devices):
+        """The sharded pool against the model itself: the full forward
+        for f32; for the int8-KV pool (codes and scale planes shard
+        together) the model's own int8 slab ``KVCache``, and the
+        single-chip engine."""
+        model, params = lm_int8 if case == "int8_kv" else lm
+        tp, _, reqs = _run(model, params, mesh=tp2_mesh())
+        assert_served(model, params, reqs, tp, cached=case == "int8_kv")
+        if case == "int8_kv":
+            assert tp == _run(model, params)[0]
 
 
 class TestTPPagedKernel:

@@ -60,6 +60,7 @@ def substeps_to_admission(engine, queue):
     must wait out the whole scan before the next admission point."""
     submit(queue, [1, 2, 3], max_new_tokens=500)
     assert engine._admit() == 1
+    engine._drain_prefill()             # first token: the slot is active
     h = engine._pick_horizon()          # chosen with queue empty,
     steps0 = engine.steps               # slots free — the in-flight scan
     engine._step(horizon=h)             # ...during which B arrives
@@ -93,10 +94,13 @@ class TestAdmissionBound:
         engine, queue = make_engine(lm, num_slots=2, decode_horizon=16)
         submit(queue, [1, 2, 3], max_new_tokens=500)
         engine._admit()
+        assert engine._pick_horizon() == 1          # a chunk train pending
+        engine._drain_prefill()
         assert engine._pick_horizon() == engine.ttft_horizon  # free + empty
         submit(queue, [4, 5], max_new_tokens=500)
         assert engine._pick_horizon() == 1                    # queued + free
-        engine._admit()                                       # batch now full
+        engine._admit()
+        engine._drain_prefill()                               # batch now full
         submit(queue, [6, 7], max_new_tokens=2)
         assert engine._pick_horizon() == engine.decode_horizon
 

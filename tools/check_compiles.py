@@ -68,7 +68,7 @@ def _serve_segment():
         model, params, queue,
         num_slots=4, max_len=96, prompt_buckets=[8, 16],
         eos_token_id=None, default_max_new_tokens=8, decode_horizon=4,
-        paged=True, page_size=128, chunked_prefill=True,
+        page_size=128,
     )
     ledger = get_ledger()
     engine.warmup()  # brackets the warmup phase; arms the steady mark

@@ -42,7 +42,6 @@ HOT_FUNCTIONS: Dict[str, Set[str]] = {
         # serving latency exactly like the scan, with ONE designed
         # fetch (the fused first-token ids) per chunk program.
         "_pump_prefill", "_spend_prefill_budget", "_dispatch_chunk_group",
-        "_advance_train_slab",
         "_grant_train_pages",
     },
     "engine/worker.py": {"_run_placement"},

@@ -81,22 +81,15 @@ def _is_jit_call(node: ast.AST) -> bool:
 
 def _wrapped_tails(node: ast.Call) -> List[str]:
     """Trailing names of the callable(s) a jax.jit call wraps:
-    ``self._decode_impl`` -> ``_decode_impl``; an IfExp (the paged/slab
-    commit dispatch) yields both branches; a lambda yields none."""
+    ``self._decode_impl`` -> ``_decode_impl``; a lambda yields none."""
     if not node.args:
         return []
     target = node.args[0]
-    exprs = (
-        [target.body, target.orelse] if isinstance(target, ast.IfExp)
-        else [target]
-    )
-    tails: List[str] = []
-    for e in exprs:
-        if isinstance(e, ast.Attribute):
-            tails.append(e.attr)
-        elif isinstance(e, ast.Name):
-            tails.append(e.id)
-    return tails
+    if isinstance(target, ast.Attribute):
+        return [target.attr]
+    if isinstance(target, ast.Name):
+        return [target.id]
+    return []
 
 
 def _literal_int_tuple(node: ast.AST) -> Optional[Tuple[int, ...]]:
@@ -435,12 +428,8 @@ class DonationDisciplineChecker(Checker):
     def _program_attr(
         call: ast.Call, donating: Dict[str, Tuple[int, ...]]
     ) -> Optional[str]:
-        """'_decode_fn' for ``self._decode_fn(...)`` or for the
-        factory-then-call form ``self._prefill_fn(b, g)(...)``."""
-        func = call.func
-        if isinstance(func, ast.Call):
-            func = func.func  # factory-produced callables
-        d = _dotted(func) or ""
+        """'_decode_fn' for ``self._decode_fn(...)``."""
+        d = _dotted(call.func) or ""
         if d.startswith("self."):
             attr = d[len("self."):]
             if attr in donating:
