@@ -1502,6 +1502,8 @@ class DecodeEngine:
         )
         for line in self._expert_paths():
             logger.info("%s: experts: %s", self.model.name, line)
+        for line in self._decode_paths():
+            logger.info("%s: paged decode: %s", self.model.name, line)
 
     # --- admission ---------------------------------------------------------
     def _free_slots(self) -> List[int]:
@@ -3483,6 +3485,17 @@ class DecodeEngine:
         return sorted({f"{p.program}: {p.rows} rows -> {p.describe()}"
                        for p in moe_paths() if p.program})
 
+    def _decode_paths(self) -> List[str]:
+        """Which body each program's paged-kernel calls took
+        (``ops/decode_attention.py::decode_paths``: flat heads or per
+        head, and why; the process's trace-time record, as above)."""
+        from ray_dynamic_batching_tpu.ops.decode_attention import (
+            decode_paths,
+        )
+
+        return sorted({f"{p.program}: {p.describe()}"
+                       for p in decode_paths() if p.program})
+
     def turn_summary(self, records: Optional[Sequence[Turn]] = None,
                      span_ms: Optional[float] = None,
                      longest: int = 8) -> Dict[str, Any]:
@@ -3530,6 +3543,7 @@ class DecodeEngine:
                 self._pool_stats,
                 pages_live=turns.get("kv_pages_live", 0),
                 pages_scanned=turns.get("kv_pages_scanned", 0),
+                decode_paths=self._decode_paths(),
             ),
             "page_journal": {
                 "events": self._page_journal.snapshot(),

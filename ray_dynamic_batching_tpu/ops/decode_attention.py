@@ -15,8 +15,9 @@ costs on that scan (``ops/attention.py::_xla_attention``):
   VMEM.
 
 This kernel fuses the scan FlashAttention-style over a grid
-(B, K // kb, S // Sb): each program instance owns one slot's block of
-``kb`` KV heads for one [Sb] KV tile. The S grid axis IS the KV tiling:
+(B, K // kb, S // Sb) — the paged kernel's (B, K // kb, NP), a page a
+tile —: each program instance owns one slot's block of ``kb`` KV heads
+for one [Sb] KV tile. The S grid axis IS the KV tiling:
 TPU grid steps run sequentially with the innermost axis fastest, so the
 online-softmax state (m, l, acc) lives in VMEM scratch carried across
 the S steps of each (slot, head-block) — initialized at s == 0,
@@ -25,6 +26,28 @@ next tile's HBM->VMEM copy behind the current tile's compute. Every
 [Sb, H] K/V slab is read exactly once (all Tq window rows and all
 G = N/K query heads sharing a KV head ride the same read) — GQA via
 layout, no repeat, any capacity.
+
+The body of a grid step (``_accumulate_tile``, shared by the slab and
+the paged kernel) takes one of two forms, chosen from the tile's static
+shape alone (``tile_math.flat_heads``):
+
+- **flat heads** (a block of 8 KV heads; score tiles within
+  ``tile_math.FLAT_SCORE_MAX_BYTES``): the tile arrives as [Sb, kb, H],
+  whose trailing (kb, H) dims are whole (8, 128) tiles, so [Sb * kb, H]
+  is the same bytes — column c is position c // kb of head c % kb. The
+  block's kb * R query rows (q, out and the f32 state are laid out
+  [kb * R, ...], rows ordered head, t, g) are scored against all
+  Sb * kb columns in ONE contraction; row i keeps only the columns of
+  its own head i // R, then one softmax update and one value
+  contraction. The MXU does kb times the useful multiply-adds of a
+  step that is memory-bound anyway; what the body no longer pays for
+  is eight sublane-strided head slices, sixteen one-to-four-row dots
+  and two dozen small scratch updates a step. Measured on a v5e
+  (PERF.md, PR 31): a live step of the paged kernel 1.75 -> 0.85 us
+  against a 0.64 us copy, and cheaper than the per-head form at every
+  row count tried (1 to 32 a head).
+- **per head** (K < 8, or tiles past the cap): each head's [Sb, H]
+  slice against its own R rows, state [kb, R].
 
 Two TPU lowering rules shape the blocking (trailing two block dims must
 be (8, 128)-tile-aligned or span the array):
@@ -51,6 +74,8 @@ gate.
 
 from __future__ import annotations
 
+import collections
+import dataclasses
 import functools
 from typing import Any, List, Optional
 
@@ -68,6 +93,7 @@ from ray_dynamic_batching_tpu.ops.tile_math import (
     VMEM_BLOCK_BUDGET_BYTES,
     VMEM_LIMIT_BYTES,
 )
+from ray_dynamic_batching_tpu.utils.compile_ledger import current_program
 
 # Grid (slot, head block, KV tile): the KV axis carries the
 # online-softmax scratch, so it is sequential; the scoped-VMEM limit is
@@ -86,45 +112,106 @@ NEG_INF = -1e30
 MAX_WINDOW_FOR_KERNEL = 8
 
 
+FORM_FLAT = "flat heads"
+FORM_PER_HEAD = "per head"
+
+
+@dataclasses.dataclass(frozen=True)
+class DecodePath:
+    """The body one paged-kernel call took, recorded while its program
+    traced (as ``ops/moe.py::moe_paths`` records the expert path)."""
+
+    program: str     # compile-ledger program ("" outside one)
+    kb: int          # KV heads a grid step (a TP shard's block)
+    rows: int        # query rows a head: window * G
+    page_size: int
+    head_dim: int    # the pool's row width (lane-padded)
+    kv_dtype: str
+    form: str        # FORM_*
+    why: str
+
+    def describe(self) -> str:
+        return (f"{self.kb} heads x {self.rows} rows over "
+                f"[{self.page_size}, {self.kb}, {self.head_dim}] "
+                f"{self.kv_dtype} pages -> {self.form} ({self.why})")
+
+
+# Bounded, trace-time only: a call a layer of each traced program.
+_PATHS: collections.deque = collections.deque(maxlen=4096)
+
+
+def decode_paths() -> List[DecodePath]:
+    """The recorded paged-kernel calls, oldest first."""
+    return list(_PATHS)
+
+
+def clear_decode_paths() -> None:
+    _PATHS.clear()
+
+
+def _record_path(kb: int, rows: int, ps: int, H: int, dtype) -> None:
+    if tile_math.flat_heads(kb, rows, ps):
+        form, why = FORM_FLAT, (
+            f"{kb * rows} rows x {ps * kb} columns in one contraction")
+    elif kb % 8:
+        form, why = FORM_PER_HEAD, (
+            f"a {kb}-head block is no whole (8, 128) tile")
+    else:
+        form, why = FORM_PER_HEAD, (
+            f"flat score tiles of {kb * rows} rows x {ps * kb} columns "
+            f"pass {tile_math.FLAT_SCORE_MAX_BYTES >> 20} MiB")
+    _PATHS.append(DecodePath(
+        program=current_program(), kb=kb, rows=rows, page_size=ps,
+        head_dim=H, kv_dtype=str(jnp.dtype(dtype)), form=form, why=why))
+
+
+def _window_rows(mask_ref, rows: int, R: int, window: int):
+    """The slab kernel's streamed int8 window [Tq, cols] as one boolean
+    row per query row: row ``i`` of ``rows`` (R of them, or the block's
+    kb * R in the flat form, whose heads share the window) is window
+    row ``(i % R) // G`` — g shares t's window."""
+    # Widen the streamed int8 to 32 bits BEFORE any comparison and pick
+    # each row's window with iota selects: Mosaic refuses to carry an i1
+    # vector born from an 8-bit tile into the f32 select below
+    # ("changeBitwidth when src bitwidth and dst bitwidth differs too
+    # much", TPU v5e, Tq*G rows with G > 1), and a [1, cols] row
+    # broadcast over sublanes is a layout it always has.
+    m32 = mask_ref[0, :, :].astype(jnp.int32)  # [Tq, cols]
+    cols = m32.shape[1]
+    t_of_row = (jax.lax.broadcasted_iota(
+        jnp.int32, (rows, cols), 0) % R) // (R // window)
+    picked = jnp.zeros((rows, cols), jnp.int32)
+    for t in range(window):  # static unroll: window <= 8
+        picked = jnp.where(t_of_row == t, m32[t:t + 1, :], picked)
+    return picked != 0
+
+
 def _decode_kernel(
-    q_ref,      # [1, kb, Tq*G, H]   rows ordered (t, g)
+    q_ref,      # [1, kb*R, H]       rows ordered (head, t, g); R = Tq*G
     k_ref,      # [1, Sb, kb, H]     this grid step's KV tile
     v_ref,      # [1, Sb, kb, H]
-    mask_ref,   # [1, Tq, Sb] int8, or None
-    ks_ref,     # [1, kb, Sb] f32 per-row K scales (int8 cache), or None
-    vs_ref,     # [1, kb, Sb] f32 per-row V scales, or None
-    o_ref,      # [1, kb, Tq*G, H]
-    m_ref,      # VMEM scratch [kb, Tq*G] f32 — carried across S steps
-    l_ref,      # VMEM scratch [kb, Tq*G] f32
-    acc_ref,    # VMEM scratch [kb, Tq*G, H] f32
+    mask_ref,   # [1, Tq, Sb] int8 ([1, Tq, Sb*kb] flat form), or None
+    ks_ref,     # f32 per-row K scales (int8 cache), or None: [1, kb, Sb],
+    vs_ref,     # in the flat form [1, 1, 1, 1, Sb*kb] (``_flat_columns``)
+    o_ref,      # [1, kb*R, H]
+    m_ref,      # VMEM scratch f32 [kb*R, 1] (per head: [kb, R]) —
+    l_ref,      # carried across S steps
+    acc_ref,    # VMEM scratch [kb*R, H] f32
     *,
     scale: float,
     num_s: int,
     window: int,
 ):
-    R = q_ref.shape[2]          # Tq * G
-    Sb = k_ref.shape[1]
-    G = R // window
-
+    Sb, kb = k_ref.shape[1], k_ref.shape[2]
+    R = q_ref.shape[1] // kb
     # Head-invariant per-tile validity: every head block shares the
     # per-(t, g)-row window. Sb divides S (``_pick_sb``), so there is no
-    # ragged tail to mask.
+    # ragged tail to mask. The flat form's mask arrives in its column
+    # order (each position kb times), one row per row of the block.
+    valid = None
     if mask_ref is not None:
-        # [Tq, Sb] -> one row per (t, g): g shares t's window. Widen the
-        # streamed int8 to 32 bits BEFORE any comparison and pick each
-        # row's window with iota selects: Mosaic refuses to carry an i1
-        # vector born from an 8-bit tile into the f32 select below
-        # ("changeBitwidth when src bitwidth and dst bitwidth differs
-        # too much", TPU v5e, Tq*G rows with G > 1), and a [1, Sb] row
-        # broadcast over sublanes is a layout it always has.
-        m32 = mask_ref[0, :, :].astype(jnp.int32)  # [Tq, Sb]
-        t_of_row = jax.lax.broadcasted_iota(jnp.int32, (R, Sb), 0) // G
-        rows = jnp.zeros((R, Sb), jnp.int32)
-        for t in range(window):  # static unroll: window <= 8
-            rows = jnp.where(t_of_row == t, m32[t:t + 1, :], rows)
-        valid = rows != 0
-    else:
-        valid = None
+        flat = tile_math.flat_heads(kb, R, Sb)
+        valid = _window_rows(mask_ref, kb * R if flat else R, R, window)
     _scan_tile(
         q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref,
         acc_ref, valid=valid, scale=scale, num_s=num_s,
@@ -136,7 +223,7 @@ def _scan_tile(
     *, valid, scale: float, num_s: int,
 ):
     """One KV tile of the online-softmax scan: init scratch at tile 0
-    (:func:`_scan_begin`), accumulate this tile per head
+    (:func:`_scan_begin`), accumulate this tile
     (:func:`_accumulate_tile`), finalize into the output on the last
     tile (:func:`_scan_end`). The slab kernel (S-axis tiles,
     mask-derived ``valid``) runs it whole on every tile; the paged
@@ -161,61 +248,108 @@ def _scan_begin(m_ref, l_ref, acc_ref):
 def _scan_end(o_ref, m_ref, l_ref, acc_ref, *, num_s: int):
     @pl.when(pl.program_id(2) == num_s - 1)
     def _finalize():
-        for h in range(acc_ref.shape[0]):
-            l = l_ref[h, :]
-            # A fully-masked row (inactive spec rows are steered out of
-            # bounds; their outputs are never consumed) -> zeros, not NaN.
-            l = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0, h, :, :] = (
-                acc_ref[h, :, :] / l[:, None]
+        # A fully-masked row (inactive spec rows are steered out of
+        # bounds; their outputs are never consumed) -> zeros, not NaN.
+        if l_ref.shape[1] == 1:     # flat heads: [kb * R, 1] (and the
+            # per-head [kb, R] at R == 1, the same layout and division)
+            l = l_ref[...]
+            o_ref[0, :, :] = (
+                acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
             ).astype(o_ref.dtype)
+            return
+        kb, R = l_ref.shape         # per head: [kb, R]
+        for h in range(kb):
+            l = l_ref[h, :]
+            l = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, h * R:(h + 1) * R, :] = (
+                acc_ref[h * R:(h + 1) * R, :] / l[:, None]
+            ).astype(o_ref.dtype)
+
+
+def _softmax_fold(s, v, vs, m_prev, l_prev, acc_prev):
+    """One online-softmax update from masked scores ``s`` [n, cols] and
+    the values ``v`` [cols, H] they weigh: the new running max, running
+    sum and accumulator [n, H]. The state is [n, 1] columns (the flat
+    form's scratch) or [n] vectors (a head's row of the per-head
+    scratch); ``vs`` [1, cols] is the int8 pool's V scale, riding on p."""
+    keep = m_prev.ndim == 2
+    col = (lambda x: x) if keep else (lambda x: x[:, None])
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=keep))
+    alpha = jnp.exp(m_prev - m_cur)
+    p = jnp.exp(s - col(m_cur))                              # [n, cols]
+    l_cur = l_prev * alpha + jnp.sum(p, axis=1, keepdims=keep)
+    if vs is not None:
+        p = p * vs
+    return m_cur, l_cur, acc_prev * col(alpha) + jax.lax.dot_general(
+        p.astype(v.dtype), v,
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )                                                        # [n, H]
 
 
 def _accumulate_tile(
     q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref, acc_ref,
     *, valid, scale: float,
 ):
-    """Fold this grid step's KV tile into the online-softmax scratch,
-    one KV head of the block at a time."""
-    kb = q_ref.shape[1]
-    compute_dtype = q_ref.dtype  # int8 codes cast exactly (<= +-127)
-    for h in range(kb):         # static unroll: this program's KV heads
-        q = q_ref[0, h, :, :]        # [R, H]
-        k_tile = k_ref[0, :, h, :]   # [Sb, H]
-        v_tile = v_ref[0, :, h, :]
-        if ks_ref is not None:
-            # Int8 cache: the per-row scale factors OUT of both dots —
-            # scores scale per key column, and V's scale rides on p —
-            # so the kernel reads 1-byte codes and never materializes
-            # an H-wide dequantized tile (this is the bandwidth win).
-            k_tile = k_tile.astype(compute_dtype)
-            v_tile = v_tile.astype(compute_dtype)
+    """Fold this grid step's KV tile [Sb, kb, H] into the online-softmax
+    scratch of the block's kb * R query rows. One algorithm in the form
+    its shapes allow (``tile_math.flat_heads``):
+
+    - **flat heads**: the tile is read as [Sb * kb, H], the layout it
+      arrives in (column ``c`` is position ``c // kb`` of head
+      ``c % kb``), and ALL the block's rows are scored against ALL its
+      columns in one contraction; row ``i`` keeps only the columns of
+      its own head ``i // R`` (the rest go to ``NEG_INF`` before the
+      running maximum, so they add exactly 0 to ``l`` and to the value
+      dot), then ONE softmax update and ONE value contraction. The MXU
+      does kb times the useful multiply-adds of a memory-bound step;
+      the body pays for no strided head slice and no one-row dot.
+      ``valid`` is [kb * R, Sb * kb], the scales [1, Sb * kb].
+    - **per head**: each head's [Sb, H] slice of the tile against its
+      own R rows (``valid`` [R, Sb], the scales [kb, Sb]).
+
+    Either way the int8 cache's per-row scales factor OUT of both dots
+    — scores scale per key column, and V's scale rides on p — so the
+    kernel reads 1-byte codes and never materializes an H-wide
+    dequantized tile (the bandwidth win); codes cast exactly (<= +-127).
+    """
+    Sb, kb, H = k_ref.shape[1], k_ref.shape[2], k_ref.shape[3]
+    R = q_ref.shape[1] // kb
+    compute_dtype = q_ref.dtype
+
+    def scores(q, k_tile, ks):
         s = jax.lax.dot_general(
-            q, k_tile,
+            q, k_tile.astype(compute_dtype),
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale  # [R, Sb] f32
-        if ks_ref is not None:
-            s = s * ks_ref[0, h, :][None, :]
+        ) * scale                                            # [n, cols]
+        return s if ks is None else s * ks
+
+    if tile_math.flat_heads(kb, R, Sb):
+        rows, cols = kb * R, Sb * kb
+        k_flat = k_ref[0].reshape(cols, H)
+        v_flat = v_ref[0].reshape(cols, H).astype(compute_dtype)
+        s = scores(q_ref[0], k_flat,
+                   None if ks_ref is None else ks_ref[0, 0, 0])
+        own = (jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1) % kb
+               == jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0) // R)
+        if valid is not None:
+            own = own & valid
+        m_ref[...], l_ref[...], acc_ref[...] = _softmax_fold(
+            jnp.where(own, s, NEG_INF), v_flat,
+            None if vs_ref is None else vs_ref[0, 0, 0],
+            m_ref[...], l_ref[...], acc_ref[...])
+        return
+    for h in range(kb):         # static unroll: this program's KV heads
+        rows = slice(h * R, (h + 1) * R)
+        s = scores(q_ref[0, rows, :], k_ref[0, :, h, :],
+                   None if ks_ref is None else ks_ref[0, h:h + 1, :])
         if valid is not None:
             s = jnp.where(valid, s, NEG_INF)
-
-        m_prev = m_ref[h, :]
-        l_prev = l_ref[h, :]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))  # [R]
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])  # [R, Sb]
-        m_ref[h, :] = m_cur
-        l_ref[h, :] = l_prev * alpha + jnp.sum(p, axis=1)
-        if vs_ref is not None:
-            p = p * vs_ref[0, h, :][None, :]
-        acc_ref[h, :, :] = acc_ref[h, :, :] * alpha[:, None] + (
-            jax.lax.dot_general(
-                p.astype(compute_dtype), v_tile,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-        )  # [R, H]
+        m_ref[h, :], l_ref[h, :], acc_ref[rows, :] = _softmax_fold(
+            s, v_ref[0, :, h, :].astype(compute_dtype),
+            None if vs_ref is None else vs_ref[0, h:h + 1, :],
+            m_ref[h, :], l_ref[h, :], acc_ref[rows, :])
 
 
 def _pick_heads_block(K: int) -> int:
@@ -239,16 +373,18 @@ def _pick_heads_block(K: int) -> int:
 
 def _pick_sb(S: int, kb: int, H: int, kv_itemsize: int,
              with_mask: bool, target: Optional[int] = None,
-             with_scales: bool = False) -> int:
+             with_scales: bool = False, rows: int = 0) -> int:
     """Largest KV tile Sb that (a) divides S, (b) is mask-tile-legal
     (a multiple of 128, or S itself — the mask block's trailing dim is
     Sb), and (c) fits the VMEM budget with double buffering. A
     ``target`` caps the tile when a legal tile under it exists
     (callers tune pipeline granularity; tests force multi-tile scans
-    on small capacities)."""
+    on small capacities). ``rows`` (Tq * G) budgets each candidate in
+    the form its body would take (``tile_math.decode_tile_bytes``)."""
     def tile_bytes(sb: int) -> int:
         return tile_math.decode_tile_bytes(
-            sb, kb, H, kv_itemsize, with_mask, with_scales=with_scales
+            sb, kb, H, kv_itemsize, with_mask, with_scales=with_scales,
+            rows=rows,
         )
 
     cands = [S] + [
@@ -263,6 +399,37 @@ def _pick_sb(S: int, kb: int, H: int, kv_itemsize: int,
         if capped:
             return max(capped)
     return max(cands)
+
+
+def _flat_columns(x: jax.Array, kb: int, sb: int) -> jax.Array:
+    """Per-position planes [lead, S, K] (the int8 cache's scales) in the
+    flat form's column order: [lead, K // kb, S // sb, 1, sb * kb],
+    whose [.., j, s, 0, :] is tile ``s`` of head block ``j`` as one lane
+    row, column ``c`` = position ``c // kb`` of head ``c % kb``. A copy
+    of a plane H times smaller than the codes it scales."""
+    lead, S, K = x.shape
+    x = x.reshape(lead, S // sb, sb, K // kb, kb).transpose(0, 3, 1, 2, 4)
+    return x.reshape(lead, K // kb, S // sb, 1, sb * kb)
+
+
+def _flat_scale_spec(cols: int, index_map) -> pl.BlockSpec:
+    """One tile of :func:`_flat_columns`: a [1, cols] lane row, what a
+    per-column factor of a [rows, cols] score tile broadcasts from."""
+    return pl.BlockSpec(  # rdb-lint: disable=tile-alignment (a [1, cols] f32 lane row pads to 8 sublanes: 32 KB for 4 KB of scales beside a 256 KB code tile; any taller layout would need an in-kernel relayout to lanes)
+        (1, 1, 1, 1, cols), index_map)
+
+
+def _scratch(kb: int, R: int, H: int, flat: bool):
+    """The online-softmax state of a block's kb * R rows, f32: running
+    max and running sum (one [rows, 1] column for the flat form's one
+    update; [kb, R], a row a head, for the per-head form) and the
+    accumulator [rows, H]."""
+    state = (kb * R, 1) if flat else (kb, R)
+    return [
+        pltpu.VMEM(state, jnp.float32),
+        pltpu.VMEM(state, jnp.float32),
+        pltpu.VMEM((kb * R, H), jnp.float32),
+    ]
 
 
 @functools.partial(
@@ -285,20 +452,34 @@ def _decode_attention(
     S = k.shape[1]
     kb = _pick_heads_block(K)
     num_s = S // sb
+    flat = tile_math.flat_heads(kb, R, sb)
+    rows_spec = pl.BlockSpec((1, kb * R, H), lambda b, j, s: (b, j, 0))
     in_specs = [
-        pl.BlockSpec((1, kb, R, H), lambda b, j, s: (b, j, 0, 0)),
+        rows_spec,
         pl.BlockSpec((1, sb, kb, H), lambda b, j, s: (b, s, j, 0)),
         pl.BlockSpec((1, sb, kb, H), lambda b, j, s: (b, s, j, 0)),
     ]
-    args = [q, k, v]
+    # A head block's rows are one contiguous [kb * R, H] tile (free: the
+    # same bytes).
+    args = [q.reshape(B, K * R, H), k, v]
     has_mask = mask is not None
     has_scales = k_scale is not None
     if has_mask:
+        # The flat form's columns are (position, head): each position's
+        # window byte kb times (Tq * kb bytes a position against the
+        # 2 * kb * H-byte K/V read they gate).
+        cols = sb * kb if flat else sb
         in_specs.append(
-            pl.BlockSpec((1, window, sb), lambda b, j, s: (b, 0, s))
+            pl.BlockSpec((1, window, cols), lambda b, j, s: (b, 0, s))
         )
-        args.append(mask)
-    if has_scales:
+        args.append(jnp.repeat(mask, kb, axis=2) if flat else mask)
+    if has_scales and flat:
+        scale_spec = _flat_scale_spec(
+            sb * kb, lambda b, j, s: (b, j, s, 0, 0))
+        in_specs += [scale_spec, scale_spec]
+        args += [_flat_columns(k_scale, kb, sb),
+                 _flat_columns(v_scale, kb, sb)]
+    elif has_scales:
         # Scales travel as [B, K, S]: block (1, kb, sb) has trailing
         # dims (kb -> 8-sublane pad, sb = lane multiple of 128) — pad
         # free. A [B, S, K, 1] layout would be tile-legal but its
@@ -325,23 +506,16 @@ def _decode_attention(
             scale=scale, num_s=num_s, window=window,
         )
 
-    out_dtype = q.dtype
     return pl.pallas_call(
         kernel,
         grid=(B, K // kb, num_s),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, kb, R, H), lambda b, j, s: (b, j, 0, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, K, R, H), out_dtype),
-        scratch_shapes=[
-            pltpu.VMEM((kb, R), jnp.float32),
-            pltpu.VMEM((kb, R), jnp.float32),
-            pltpu.VMEM((kb, R, H), jnp.float32),
-        ],
+        out_specs=rows_spec,
+        out_shape=jax.ShapeDtypeStruct((B, K * R, H), q.dtype),
+        scratch_shapes=_scratch(kb, R, H, flat),
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(*args)
+    )(*args).reshape(B, K, R, H)
 
 
 @functools.partial(
@@ -354,7 +528,7 @@ def _paged_decode_attention(
     page_table: jax.Array,  # [B, NP] int32, sentinel P
     lengths: jax.Array,     # [B] int32 — row t attends pos <= lengths[b]+t
     layer: jax.Array,       # [1] int32 — which layer of the stack to read
-    k_scale: Optional[jax.Array],  # [P, K, ps] f32 (int8 pool), or None
+    k_scale: Optional[jax.Array],  # [P, ps, K] f32 (int8 pool), or None
     v_scale: Optional[jax.Array],
     *,
     scale: float,
@@ -367,6 +541,7 @@ def _paged_decode_attention(
     NP = page_table.shape[1]
     kb = _pick_heads_block(K)
     has_scales = k_scale is not None
+    flat = tile_math.flat_heads(kb, R, ps)
 
     # The page axis IS the KV tiling: grid step (b, j, p) streams slot
     # b's p-th page — whichever physical page the PREFETCHED table names.
@@ -393,22 +568,37 @@ def _paged_decode_attention(
     def kv_index(b, j, p, pt, ln, ly):
         return (ly[0], page_index(b, p, pt, ln), 0, j, 0)
 
-    def q_index(b, j, p, pt, ln, ly):
-        return (b, j, 0, 0)
+    def rows_index(b, j, p, pt, ln, ly):
+        return (b, j, 0)
 
+    rows_spec = pl.BlockSpec((1, kb * R, H), rows_index)
     in_specs = [
-        pl.BlockSpec((1, kb, R, H), q_index),
+        rows_spec,
         pl.BlockSpec((None, 1, ps, kb, H), kv_index),
         pl.BlockSpec((None, 1, ps, kb, H), kv_index),
     ]
-    args = [q, k, v]
-    if has_scales:
+    # A head block's rows are one contiguous [kb * R, H] tile (free: the
+    # same bytes).
+    args = [q.reshape(B, K * R, H), k, v]
+    if has_scales and flat:
+        # A page's scales as ONE lane row in the flat column order.
+        scale_spec = _flat_scale_spec(
+            ps * kb, lambda b, j, p, pt, ln, ly: (
+                page_index(b, p, pt, ln), j, 0, 0, 0))
+        in_specs += [scale_spec, scale_spec]
+        args += [_flat_columns(k_scale, kb, ps),
+                 _flat_columns(v_scale, kb, ps)]
+    elif has_scales:
+        # [P, ps, K] -> [P, K, ps]: the page becomes the (lane) trailing
+        # dim of the scale tile — pad-free because pages are lane-aligned
+        # (the [B, S, K, 1]-layout ~128x blowup documented on the slab
+        # path is the same trap this transpose avoids).
         scale_spec = pl.BlockSpec(
             (1, kb, ps),
             lambda b, j, p, pt, ln, ly: (page_index(b, p, pt, ln), j, 0),
         )
         in_specs += [scale_spec, scale_spec]
-        args += [k_scale, v_scale]
+        args += [k_scale.transpose(0, 2, 1), v_scale.transpose(0, 2, 1)]
 
     def kernel(pt_ref, len_ref, ly_ref, q_ref, k_ref, v_ref, *rest):
         ks_ref = rest[0] if has_scales else None
@@ -422,13 +612,16 @@ def _paged_decode_attention(
         def _live_page():
             # In-kernel STAIRCASE validity from the prefetched lengths:
             # page p covers logical positions [p*ps, (p+1)*ps); window
-            # row t (row r = t*G + g) attends pos <= lengths[b] + t —
-            # the spec-verify window rule, whose Tq == 1 degenerate case
-            # is exactly the slab decode_mask bound. No mask array is
-            # streamed at all.
-            pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, (R, ps), 1)
-            t_of_row = jax.lax.broadcasted_iota(
-                jnp.int32, (R, ps), 0) // G
+            # row t (row r = t*G + g of its head) attends pos <=
+            # lengths[b] + t — the spec-verify window rule, whose
+            # Tq == 1 degenerate case is exactly the slab decode_mask
+            # bound. No mask array is streamed at all. In the flat form
+            # a column is (position, head) and a row (head, t, g).
+            shape = (kb * R, ps * kb) if flat else (R, ps)
+            col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            pos = p * ps + (col // kb if flat else col)
+            t_of_row = (jax.lax.broadcasted_iota(
+                jnp.int32, shape, 0) % R) // G
             _accumulate_tile(
                 q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref, acc_ref,
                 valid=pos <= len_ref[b] + t_of_row, scale=scale,
@@ -440,20 +633,16 @@ def _paged_decode_attention(
         num_scalar_prefetch=3,
         grid=(B, K // kb, NP),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, kb, R, H), q_index),
-        scratch_shapes=[
-            pltpu.VMEM((kb, R), jnp.float32),
-            pltpu.VMEM((kb, R), jnp.float32),
-            pltpu.VMEM((kb, R, H), jnp.float32),
-        ],
+        out_specs=rows_spec,
+        scratch_shapes=_scratch(kb, R, H, flat),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K, R, H), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, K * R, H), q.dtype),
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(page_table, lengths, layer, *args)
+    )(page_table, lengths, layer, *args).reshape(B, K, R, H)
 
 
 def paged_decode_attention(
@@ -573,6 +762,7 @@ def paged_decode_attention(
             why, f"paged kernel: page tile (ps={ps}, kb={kb}, H={Hk}) "
             "exceeds the VMEM block budget")
     interpret = resolve_interpret(interpret)
+    _record_path(kb, Tq * G, ps, Hk, k.dtype)
     scale = scale if scale is not None else H ** -0.5
     # Rows ordered (t, g) per kv head: [B, Tq, K, G, H] ->
     # [B, K, Tq*G, H] (Tq == 1 collapses to the historical layout),
@@ -582,17 +772,9 @@ def paged_decode_attention(
     )
     if Hk > H:
         q_r = jnp.pad(q_r, ((0, 0), (0, 0), (0, 0), (0, Hk - H)))
-    ks = vs = None
-    if k_scale is not None:
-        # [P, ps, K] -> [P, K, ps]: the page becomes the (lane) trailing
-        # dim of the scale tile — pad-free because pages are lane-aligned
-        # (the [B, S, K, 1]-layout ~128x blowup documented on the slab
-        # path is the same trap this transpose avoids).
-        ks = k_scale.transpose(0, 2, 1)
-        vs = v_scale.transpose(0, 2, 1)
     operands = (q_r, k, v, page_table.astype(jnp.int32),
                 kv_lengths.astype(jnp.int32),
-                jnp.full((1,), layer, jnp.int32), ks, vs)
+                jnp.full((1,), layer, jnp.int32), k_scale, v_scale)
     static = dict(scale=float(scale), window=int(Tq),
                   interpret=bool(interpret))
     if tp > 1:
@@ -628,7 +810,7 @@ def _paged_decode_attention_tp(
     has_scales = ks is not None
     if has_scales:
         args += [ks, vs]
-        in_specs += [P(None, axis, None), P(None, axis, None)]
+        in_specs += [P(None, None, axis), P(None, None, axis)]
 
     def local(q_l, k_l, v_l, pt, ln, ly, *rest):
         ks_l = rest[0] if has_scales else None
@@ -715,7 +897,7 @@ def decode_attention(
     # double-buffered. 0 = no legal tile (pathological S) -> XLA.
     sb = _pick_sb(S, _pick_heads_block(K), H, k.dtype.itemsize,
                   mask is not None, target=block_k,
-                  with_scales=k_scale is not None)
+                  with_scales=k_scale is not None, rows=Tq * G)
     if sb == 0:
         return declined(
             why, f"decode kernel: no KV tile of S={S} is a 128-multiple "

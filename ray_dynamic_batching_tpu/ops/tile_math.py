@@ -135,6 +135,42 @@ def lane_aligned_page(page_size: int) -> bool:
     return page_size > 0 and page_size % LANE == 0
 
 
+# The flat-heads form of a decode grid step's body
+# (``ops/decode_attention.py::_accumulate_tile``) scores all kb heads'
+# query rows against the whole [sb * kb] tile, so its two f32
+# intermediates (masked scores, probabilities) are [kb * rows, sb * kb]:
+# kb times a per-head body's. Measured on a v5e (PERF.md, PR 31) the
+# flat form is the cheaper at every row count tried, 1 to 32 rows a
+# head over a 128-position page of 8 heads (2 MiB of score tiles at 32
+# rows: 1.32 us a step against 2.05 per head); past that nothing is
+# measured and the tiles keep growing, so the per-head form, whose
+# tiles are [rows, sb], takes over.
+FLAT_SCORE_MAX_BYTES = 2 * 1024 * 1024
+
+
+def _flat_score_bytes(sb: int, kb: int, rows: int) -> int:
+    return 2 * padded_block_bytes((kb * rows, sb * kb), 4)
+
+
+def flat_heads(kb: int, rows: int, sb: int) -> bool:
+    """Whether a decode grid step folds its [sb, kb, H] tile with ALL kb
+    heads in one contraction (True) or one head at a time — decided from
+    the static shape alone, for the kernels, the VMEM model below and
+    the ``decode_paths()`` record alike. ``kb`` must be a multiple of 8:
+    then the tile's trailing (kb, H) dims are whole (8, 128) tiles and
+    [sb * kb, H] is the same bytes, no relayout; a narrower head block
+    (K < 8) has no such view. ``rows`` is a head's query rows, Tq * G."""
+    return (kb % 8 == 0
+            and _flat_score_bytes(sb, kb, rows) <= FLAT_SCORE_MAX_BYTES)
+
+
+def flat_score_bytes(sb: int, kb: int, rows: int) -> int:
+    """In-VMEM bytes of the flat-heads form's two f32 intermediates (0
+    for the per-head form, whose [rows, sb] tiles are a few registers);
+    at most ``FLAT_SCORE_MAX_BYTES`` by the rule above."""
+    return _flat_score_bytes(sb, kb, rows) if flat_heads(kb, rows, sb) else 0
+
+
 def paged_tile_bytes(
     page_size: int,
     kb: int,
@@ -152,7 +188,11 @@ def paged_tile_bytes(
     - K and V page tiles [1, page_size, kb, H] at the cache itemsize
       (trailing dims (kb, H), same padding story as the slab tile);
     - optional K/V scale tiles [1, kb, page_size] f32 (page_size is the
-      LANE dim — hence :func:`lane_aligned_page`);
+      LANE dim — hence :func:`lane_aligned_page`), in the flat-heads
+      form one [1, page_size * kb] lane row each;
+    - the flat-heads form's two f32 score tiles
+      (:func:`flat_score_bytes`; ``window`` rows x ``G`` decide the form,
+      :func:`flat_heads`), not streamed but as large as a page;
     - NO mask tile: validity is computed in-kernel from the prefetched
       per-slot lengths, so the paged path streams no mask at all.
 
@@ -161,20 +201,19 @@ def paged_tile_bytes(
     the f32 online-softmax accumulator ([kb, window*G, H] VMEM scratch)
     grow with the window's row count, and for decode's Tq == 1 they are
     the small riders the base model documents away — a wide window makes
-    them first-class. ``window == 1`` returns EXACTLY the historical
-    value (agreement pins in tests/test_lint.py stay byte-stable).
+    them first-class.
     """
+    rows = int(window) * max(1, int(G))
+    flat = flat_heads(kb, rows, page_size)
     kv = 2 * padded_block_bytes((1, page_size, kb, H), kv_itemsize)
-    scale_b = (
-        2 * padded_block_bytes((1, kb, page_size), 4) if with_scales else 0
-    )
+    scale_shape = (1, 1, page_size * kb) if flat else (1, kb, page_size)
+    scale_b = 2 * padded_block_bytes(scale_shape, 4) if with_scales else 0
     total = DOUBLE_BUFFER * (kv + scale_b)
     if window > 1:
-        rows = int(window) * max(1, int(G))
         qo = 2 * padded_block_bytes((1, kb, rows, H), kv_itemsize)
         acc = padded_block_bytes((kb, rows, H), 4)  # f32 scratch, single
         total += DOUBLE_BUFFER * qo + acc
-    return total
+    return total + flat_score_bytes(page_size, kb, rows)
 
 
 def decode_tile_bytes(
@@ -185,6 +224,7 @@ def decode_tile_bytes(
     with_mask: bool,
     with_scales: bool = False,
     window: int = 1,
+    rows: int = 0,
 ) -> int:
     """Double-buffered VMEM footprint of one decode-attention grid
     step's streamed blocks — the exact model ``_pick_sb`` budgets
@@ -196,8 +236,18 @@ def decode_tile_bytes(
     - optional mask tile [1, window, sb] int8 (window <= 8 pads to the
       int8 tile height 32; sb is the lane dim);
     - optional K/V scale tiles [1, kb, sb] f32.
+
+    ``rows`` (a head's query rows, Tq * G) lets the model follow the
+    body's form (:func:`flat_heads`): the flat form streams its mask and
+    scale tiles in its own column order ([1, window, sb * kb] and one
+    [1, sb * kb] lane row) and carries its two f32 score tiles. 0 is the
+    per-head model, whatever the shape.
     """
+    flat = rows > 0 and flat_heads(kb, rows, sb)
+    cols = sb * kb if flat else sb
     kv = 2 * padded_block_bytes((1, sb, kb, H), kv_itemsize)
-    mask_b = padded_block_bytes((1, window, sb), 1) if with_mask else 0
-    scale_b = 2 * padded_block_bytes((1, kb, sb), 4) if with_scales else 0
-    return DOUBLE_BUFFER * (kv + mask_b + scale_b)
+    mask_b = padded_block_bytes((1, window, cols), 1) if with_mask else 0
+    scale_b = (2 * padded_block_bytes((1, 1 if flat else kb, cols), 4)
+               if with_scales else 0)
+    return DOUBLE_BUFFER * (kv + mask_b + scale_b) + (
+        flat_score_bytes(sb, kb, rows) if flat else 0)

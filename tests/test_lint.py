@@ -1091,8 +1091,13 @@ class TestSharedTileMath:
                             )
         # A page is one KV tile without the mask: the two models must
         # coincide where they describe the same bytes.
+        # (rows=1: both in the form their body takes, flat heads here,
+        # whose two f32 score tiles both count since PR 31.)
         assert tm.paged_tile_bytes(128, 8, 64, 2, with_scales=True) == \
-            tm.decode_tile_bytes(128, 8, 64, 2, False, with_scales=True)
+            tm.decode_tile_bytes(128, 8, 64, 2, False, with_scales=True,
+                                 rows=1)
+        assert tm.paged_tile_bytes(128, 4, 64, 2, with_scales=True) == \
+            tm.decode_tile_bytes(128, 4, 64, 2, False, with_scales=True)
         assert lm.lane_aligned_page(128) and not lm.lane_aligned_page(100)
 
     def test_paged_runtime_guard_declines_fat_pages(self):

@@ -188,20 +188,19 @@ class TestPagedKernelLowersForTPU:
         "olmoe-1b-7b": dict(L=12, P=256, B=32, NP=8, N=16, K=16, H=128),
     }
 
-    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8])
-    @pytest.mark.parametrize("window", [1, 5])
-    @pytest.mark.parametrize("config", sorted(GEOMETRIES))
-    def test_stacked_pool_block(self, config, window, dtype):
-        g = self.GEOMETRIES[config]
-        ps = 128
-        q = jnp.zeros((g["B"], window, g["N"], g["H"]), jnp.bfloat16)
+    @staticmethod
+    def _call(g, window, dtype, struct=jnp.zeros):
+        """The jitted call and its arguments (``struct(shape, dtype)``
+        makes each: arrays for an export, shapes for a compile)."""
         from ray_dynamic_batching_tpu.models.decoder import pool_head_dim
 
-        pool = jnp.zeros(
+        ps = 128
+        q = struct((g["B"], window, g["N"], g["H"]), jnp.bfloat16)
+        pool = struct(
             (g["L"], g["P"], ps, g["K"], pool_head_dim(g["H"])), dtype)
-        table = jnp.zeros((g["B"], g["NP"]), jnp.int32)
-        lengths = jnp.zeros((g["B"],), jnp.int32)
-        scale = (jnp.zeros((g["P"], ps, g["K"]), jnp.float32)
+        table = struct((g["B"], g["NP"]), jnp.int32)
+        lengths = struct((g["B"],), jnp.int32)
+        scale = (struct((g["P"], ps, g["K"]), jnp.float32)
                  if dtype == jnp.int8 else None)
 
         def f(q, pool, table, lengths, scale):
@@ -211,8 +210,86 @@ class TestPagedKernelLowersForTPU:
             assert out is not None, "paged kernel declined"
             return out
 
-        export.export(jax.jit(f), platforms=["tpu"])(
-            q, pool, table, lengths, scale)
+        return jax.jit(f), (q, pool, table, lengths, scale)
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8])
+    @pytest.mark.parametrize("window", [1, 5])
+    @pytest.mark.parametrize("config", sorted(GEOMETRIES))
+    def test_stacked_pool_block(self, config, window, dtype):
+        f, args = self._call(self.GEOMETRIES[config], window, dtype)
+        export.export(f, platforms=["tpu"])(*args)
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8])
+    @pytest.mark.parametrize("window", [2, 8])
+    def test_gqa_window_rows(self, window, dtype):
+        """Mistral's GQA at the widest window: R = window * G = 8 x 4
+        query rows a KV head, 256 rows a block against a page's 1,024
+        columns in the flat-heads form (2 MiB of score tiles: the last
+        shape that takes it, ``tile_math.FLAT_SCORE_MAX_BYTES``)."""
+        f, args = self._call(self.GEOMETRIES["mistral-7b"], window, dtype)
+        da.clear_decode_paths()
+        export.export(f, platforms=["tpu"])(*args)
+        assert da.decode_paths()[-1].form == da.FORM_FLAT
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described, unattached v5e chip: ``.compile()`` for it runs the
+    Mosaic compiler itself (vector layouts, VMEM), which an export does
+    not. Only inside a fixture: one process may load the TPU's library,
+    and every xdist worker imports this file."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+class TestPagedKernelCompilesForV5e:
+    """The Mosaic compiler accepts both forms of the body at the
+    benchmark's geometries: the flat form's [ps, kb, H] -> [ps * kb, H]
+    view of a page, its [kb * R, ps * kb] score tiles and lane-row
+    scales; the per-head form's strided head slices under the flat
+    q/out layout (a 4-head block). Nothing runs: what a step costs is
+    ``tools/run_kernel_ab.py --paged`` on the chip."""
+
+    CASES = {
+        **{(c, 1): g
+           for c, g in TestPagedKernelLowersForTPU.GEOMETRIES.items()},
+        ("gpt2-medium", 5): TestPagedKernelLowersForTPU.GEOMETRIES[
+            "gpt2-medium"],
+        ("mistral-7b", 8): TestPagedKernelLowersForTPU.GEOMETRIES[
+            "mistral-7b"],
+        ("four-kv-heads", 1): dict(L=2, P=16, B=4, NP=4, N=8, K=4, H=128),
+        ("four-kv-heads", 5): dict(L=2, P=16, B=4, NP=4, N=8, K=4, H=128),
+    }
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8])
+    @pytest.mark.parametrize(
+        "case", sorted(CASES), ids=lambda c: f"{c[0]}-w{c[1]}")
+    def test_both_forms_compile(self, case, dtype, one_chip):
+        from jax.experimental.compilation_cache import compilation_cache
+
+        struct = lambda shape, dt: jax.ShapeDtypeStruct(
+            shape, dt, sharding=one_chip)
+        f, args = TestPagedKernelLowersForTPU._call(
+            self.CASES[case], case[1], dtype, struct)
+        # A compile for a described chip is written to the persistent
+        # cache but cannot be read back without one: keep it out.
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            f.lower(*args).compile()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+            compilation_cache.reset_cache()
+        want = da.FORM_PER_HEAD if case[0] == "four-kv-heads" \
+            else da.FORM_FLAT
+        assert da.decode_paths()[-1].form == want
 
 
 class TestRegisteredDecodersLowerForTPU:
