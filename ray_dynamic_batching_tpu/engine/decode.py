@@ -104,7 +104,7 @@ from ray_dynamic_batching_tpu.engine.pagefabric import (
 from ray_dynamic_batching_tpu.engine.queue import RequestQueue
 from ray_dynamic_batching_tpu.models.causal_lm import merge_routing_counters
 from ray_dynamic_batching_tpu.models.decoder import fit_head_dim
-from ray_dynamic_batching_tpu.ops import jit_model
+from ray_dynamic_batching_tpu.ops import jit_model, tile_math
 from ray_dynamic_batching_tpu.ops.tile_math import (
     lane_aligned_page,
     pages_for,
@@ -224,14 +224,18 @@ class Turn(NamedTuple):
     (token-expert pairs routed, all layers and substeps), ``moe_experts_hit``
     (experts with at least one real row, summed over layers and substeps)
     and ``moe_max_rows`` (the most rows one expert took in one layer of one
-    substep). All 0 for a dense model and for a chunk group that finished
-    no prompt (nothing of it was fetched).
+    substep). Where the model holds one rank's share of its experts these
+    three count the experts HELD here, and ``moe_pairs`` all real pairs
+    wherever they were routed. All 0 for a dense model and for a chunk
+    group that finished no prompt (nothing of it was fetched).
 
     A scan carries ``kv_pages_live``: the sum over ALL slots,
     from their cached lengths at the dispatch, of the page-table entries
     that hold a position the scan's first substep may attend (an idle slot
     counts its page 0, as the paged kernel does): the part of the table the
-    kernel's scan has to compute. 0 for a chunk group."""
+    kernel's scan has to compute. Where layers differ (sliding-window
+    layers walk and find live the columns of their window only) it is the
+    mean over layers. 0 for a chunk group."""
 
     kind: str
     t_dispatch: float
@@ -249,7 +253,8 @@ class Turn(NamedTuple):
     moe_rows: int = 0
     moe_experts_hit: int = 0
     moe_max_rows: int = 0
-    kv_pages_live: int = 0
+    kv_pages_live: float = 0
+    moe_pairs: int = 0
 
 
 # Sized for the benchmark's 51 s window at several times the cells'
@@ -280,7 +285,11 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
     model's records add ``moe_rows_per_expert`` (rows routed over experts
     hit: how many rows share one read of an expert's weights) and
     ``moe_imbalance`` (the most rows one expert took in a layer of a
-    substep, over that mean). With ``table_entries`` (a slot's) they
+    substep, over that mean), over the experts held here, and
+    ``moe_held_rows_share``: the routed pairs that landed on them, of all
+    real pairs (1 where every expert is held; an even router gives a rank
+    its share of the experts). With ``table_entries`` (a slot's; where
+    layers differ, the mean over layers of the columns each walks) they
     add ``kv_pages_live`` and ``kv_pages_scanned``, each scan weighed by its
     substeps (live page-table entries; slots x ``table_entries``), and their
     ratio ``kv_live_page_share``: how much of the grid the paged kernel
@@ -299,6 +308,9 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
         out["moe_rows_per_expert"] = sum(t.moe_rows for t in turns) / hit
         out["moe_imbalance"] = (max(t.moe_max_rows for t in turns)
                                 / out["moe_rows_per_expert"])
+    pairs = sum(t.moe_pairs for t in turns)
+    if pairs:
+        out["moe_held_rows_share"] = sum(t.moe_rows for t in turns) / pairs
     if len(turns) < 2:
         return out
     substeps = sum(t.substeps for t in scans)
@@ -673,6 +685,17 @@ class DecodeEngine:
                     num_slots, self.num_pages, self.page_size,
                     self._paged_capacity,
                 ))
+        # Each layer's sliding window (0: a full layer), and the table
+        # columns a slot's decode scan walks, averaged over layers.
+        self._layer_windows: Tuple[int, ...] = tuple(
+            getattr(model, "layer_windows", None)
+            or (0,) * self._cache.k.shape[0])
+        self._layer_table_widths = [
+            tile_math.window_table_width(
+                w, 1, self.page_size, self._n_table_entries)
+            for w in self._layer_windows]
+        self._table_walked = (sum(self._layer_table_widths)
+                              / len(self._layer_table_widths))
         # The head's true width, as the model's row caches have it
         # (the pool's rows are lane-padded: pool_head_dim).
         self._kv_head_dim = jax.eval_shape(
@@ -952,8 +975,8 @@ class DecodeEngine:
     def _log_dispatch(self, kind: str, t_dispatch: float, t_issued: float,
                       t_fetched: float, substeps: int, tokens: int,
                       active: int, trains: int,
-                      moe: Sequence[int] = (0, 0, 0),
-                      kv_pages_live: int = 0) -> Turn:
+                      moe: Sequence[int] = (0, 0, 0, 0),
+                      kv_pages_live: float = 0) -> Turn:
         """Append this dispatch's record to the turn ring (its work on the
         host is done: ``t_done`` is now). ``moe``: the dispatch's routing
         counters as fetched (``Turn``'s ``moe_*`` fields);
@@ -963,8 +986,8 @@ class DecodeEngine:
             kind, t_dispatch, t_issued, t_fetched, now_ms(),
             substeps, tokens, active, trains, len(self.queue),
             self._allocator.allocated_pages,
-            int(self._len_host.sum()), self._idled, *(int(c) for c in moe),
-            kv_pages_live,
+            int(self._len_host.sum()), self._idled,
+            *(int(c) for c in moe[:3]), kv_pages_live, int(moe[3]),
         )
         if len(self.turns) == self.turns.maxlen:
             self.turns_dropped += 1
@@ -978,9 +1001,20 @@ class DecodeEngine:
         """Page-table entries, summed over all slots, that hold a position
         the scan about to be dispatched may attend in its first substep:
         the last row of a ``window`` attends positions <= length +
-        ``window`` - 1 (the paged kernel's own bound)."""
-        last = (self._len_host + (window - 1)) // self.page_size
-        return int(np.minimum(last + 1, self._n_table_entries).sum())
+        ``window`` - 1 (the paged kernel's own bound). A sliding layer's
+        scan starts at the column of its window's oldest position
+        (``tile_math.window_first_page``, the kernel's own rule); where
+        layers differ the count is the mean over layers."""
+        last = np.minimum((self._len_host + (window - 1)) // self.page_size,
+                          self._n_table_entries - 1)
+        live = {0: int((last + 1).sum())}
+        for w in set(self._layer_windows) - {0}:
+            live[w] = int((last + 1 - tile_math.window_first_page(
+                self._len_host, w, self.page_size)).sum())
+        if len(live) == 1:
+            return live[0]
+        return sum(live[w] for w in self._layer_windows) / len(
+            self._layer_windows)
 
     def _device_ctx(self):
         """The scope everything this engine allocates, traces and
@@ -1163,7 +1197,7 @@ class DecodeEngine:
         )
         temps, topp = meta_f[0], meta_f[1]
         params = self._mp(params)
-        # An expert model's routing counters ride the ids fetch: [g + 3].
+        # An expert model's routing counters ride the ids fetch: [g + 4].
         taken, pools, *moe = self.model.prefill_chunk_paged(
             params, tokens, attn_mask, cache, tables, starts, take_idx,
             **self._moe_kw,
@@ -1202,8 +1236,8 @@ class DecodeEngine:
         Everything the host needs comes back PACKED in one int32 array
         [2h+1, B] (h token rows, h advanced rows, 1 lengths row) so the
         device→host boundary is crossed once per dispatch, not three times.
-        An expert model adds three rows, each one routing counter of the
-        whole scan broadcast over B (``Turn``'s ``moe_*``): [2h+4, B].
+        An expert model adds four rows, each one routing counter of the
+        whole scan broadcast over B (``Turn``'s ``moe_*``): [2h+5, B].
         """
         tokens = step_state[0][:, None]
         active = step_state[1].astype(bool)
@@ -1255,7 +1289,7 @@ class DecodeEngine:
         if moe:
             packed.append(jnp.broadcast_to(
                 merge_routing_counters(moe[0])[:, None],
-                (3, tokens.shape[0])))
+                (4, tokens.shape[0])))
         return (jnp.concatenate(packed, axis=0), cache,
                 self._pin_counts(counts))
 
@@ -1993,7 +2027,7 @@ class DecodeEngine:
             )
         t_issued = now_ms()
         t_fetched = 0.0
-        moe = (0, 0, 0)
+        moe = (0, 0, 0, 0)
         if finals:
             with self._phase("rdb.engine.prefill.fetch"):
                 first_host = np.asarray(first)  # rdb-lint: disable=host-sync-in-hot-path (THE one fetch per chunk dispatch: the fused first-token ids — TTFT ends here, never at a logits round-trip)
@@ -2896,7 +2930,7 @@ class DecodeEngine:
         rec = self._log_dispatch(
             "turn", t_dispatch, t_issued, t_fetched, h, 0, active,
             len(self._trains),
-            packed_host[2 * h + 1:, 0] if self._moe_kw else (0, 0, 0),
+            packed_host[2 * h + 1:, 0] if self._moe_kw else (0, 0, 0, 0),
             kv_pages_live=kv_pages_live)
         if links is not None:
             self._record_turn_span(rec, links, h)
@@ -3507,7 +3541,7 @@ class DecodeEngine:
         return summarize_turns(
             list(self.turns.copy()) if records is None else records,
             self.num_slots, self.turns_dropped, span_ms, longest,
-            self._n_table_entries,
+            self._table_walked,
         )
 
     def snapshot(self) -> Dict[str, Any]:
@@ -3544,6 +3578,10 @@ class DecodeEngine:
                 pages_live=turns.get("kv_pages_live", 0),
                 pages_scanned=turns.get("kv_pages_scanned", 0),
                 decode_paths=self._decode_paths(),
+                # each layer's window (0: it attends its whole prefix) and
+                # the table columns its decode scan walks a slot
+                layer_windows=list(self._layer_windows),
+                layer_table_widths=list(self._layer_table_widths),
             ),
             "page_journal": {
                 "events": self._page_journal.snapshot(),
@@ -3558,10 +3596,19 @@ class DecodeEngine:
             },
         }
         if self._moe_kw:
+            from ray_dynamic_batching_tpu.models.moe import routing_rule
+
+            cfg = self.model.cfg
+            first = cfg.moe_first_expert
             out["moe"] = {
                 "rows_per_expert": turns.get("moe_rows_per_expert"),
                 "imbalance": turns.get("moe_imbalance"),
                 "paths": self._expert_paths(),
+                # the experts held here, of the router's, and how it routes
+                "held_experts": [first, first + cfg.held_experts],
+                "num_experts": cfg.num_experts,
+                "routing": routing_rule(cfg).describe(),
+                "held_rows_share": turns.get("moe_held_rows_share"),
             }
         if self.draft_model is not None:
             out["spec"] = {

@@ -30,32 +30,36 @@ from ray_dynamic_batching_tpu.models.decoder import (
 )
 
 
-def routing_counters(routing: Any, valid: jax.Array,
-                     num_experts: int) -> jax.Array:
-    """``[rows, experts_hit, max_rows]`` int32 of one forward's expert
-    routing, over REAL tokens only: ``routing`` is the ``moe_routing``
-    collection (each expert layer's chosen experts ``[B, T, k]``) and
-    ``valid`` ``[B, T]`` says which tokens are real (pad tokens of a bucket
-    and inactive decode slots are computed and not counted). ``rows`` is the
-    token-expert pairs routed, ``experts_hit`` the experts with at least one
-    real row, both summed over layers; ``max_rows`` the most one expert took
-    in one layer."""
-    experts = jnp.arange(num_experts, dtype=jnp.int32)
-    rows = hit = most = jnp.zeros((), jnp.int32)
+def routing_counters(routing: Any, valid: jax.Array, first_expert: int,
+                     held_experts: int) -> jax.Array:
+    """``[rows, experts_hit, max_rows, pairs]`` int32 of one forward's
+    expert routing, over REAL tokens only: ``routing`` is the
+    ``moe_routing`` collection (each expert layer's chosen experts
+    ``[B, T, k]``, ids among all the router's) and ``valid`` ``[B, T]``
+    says which tokens are real (pad tokens of a bucket and inactive decode
+    slots are computed and not counted). ``rows`` is the token-expert pairs
+    routed to the experts HELD here (``[first_expert, first_expert +
+    held_experts)``: all of them, or one rank's share), ``experts_hit`` the
+    held experts with at least one real row, both summed over layers;
+    ``max_rows`` the most one held expert took in one layer; ``pairs`` all
+    real pairs, wherever they went (``rows`` where every expert is held)."""
+    experts = first_expert + jnp.arange(held_experts, dtype=jnp.int32)
+    real = valid.astype(bool)[..., None, None]
+    rows = hit = most = pairs = jnp.zeros((), jnp.int32)
     for idx in jax.tree_util.tree_leaves(routing):
-        took = ((idx[..., None] == experts)
-                & valid.astype(bool)[..., None, None]).sum(axis=(0, 1, 2))
+        took = ((idx[..., None] == experts) & real).sum(axis=(0, 1, 2))
         rows += took.sum()
         hit += (took > 0).sum()
         most = jnp.maximum(most, took.max())
-    return jnp.stack([rows, hit, most]).astype(jnp.int32)
+        pairs += real.sum() * idx.shape[-1]
+    return jnp.stack([rows, hit, most, pairs]).astype(jnp.int32)
 
 
 def merge_routing_counters(counters: jax.Array) -> jax.Array:
-    """Several forwards' counters ``[n, 3]`` as one dispatch's ``[3]``:
-    rows and experts hit add, the most rows is the largest."""
+    """Several forwards' counters ``[n, 4]`` as one dispatch's ``[4]``:
+    rows, experts hit and pairs add, the most rows is the largest."""
     return jnp.stack([counters[:, 0].sum(), counters[:, 1].sum(),
-                      counters[:, 2].max()])
+                      counters[:, 2].max(), counters[:, 3].sum()])
 
 
 class CausalLM(ServableModel):
@@ -81,6 +85,12 @@ class CausalLM(ServableModel):
     def has_experts(self) -> bool:
         return self.cfg.num_experts > 0
 
+    @property
+    def layer_windows(self) -> Tuple[int, ...]:
+        """Each layer's sliding window (0: it attends its whole prefix)."""
+        return tuple(self.cfg.layer_kind(i).window
+                     for i in range(self.cfg.num_layers))
+
     def _forward(self, params, *args, moe_valid=None, **kwargs):
         """``module.apply``; with ``moe_valid`` [B, T] (an expert model's
         caller asking for them) also this forward's
@@ -90,7 +100,8 @@ class CausalLM(ServableModel):
         (logits, cache), state = self.module.apply(
             params, *args, mutable=["moe_routing"], **kwargs)
         return logits, cache, routing_counters(
-            state["moe_routing"], moe_valid, self.cfg.num_experts)
+            state["moe_routing"], moe_valid, self.cfg.moe_first_expert,
+            self.cfg.held_experts)
 
     # --- ServableModel interface (apply == prefill logits for profiling) ---
     def init(self, rng: jax.Array):
@@ -400,14 +411,21 @@ class CausalLM(ServableModel):
     def flops_per_sample(self, seq_len: Optional[int] = None) -> float:
         T = seq_len or 128
         c = self.cfg
-        per_tok = 2 * (
-            c.d_model * c.head_dim * (c.num_heads + 2 * c.num_kv_heads)
-            + c.num_heads * c.head_dim * c.d_model
-            + (3 if c.gated_mlp else 2) * c.d_model * c.mlp_dim
-            * (c.moe_top_k if c.num_experts else 1)
-        )
-        attn = 4 * T * c.d_model  # score+value flops per token, avg T/2 ctx * 2
-        return c.num_layers * (per_tok + attn) * T + 2 * c.d_model * c.vocab_size * T
+        total = 0
+        for i in range(c.num_layers):
+            kind = c.layer_kind(i)
+            mlp = kind.mlp_dim * (
+                c.moe_top_k + c.moe_shared_experts if kind.sparse else 1)
+            per_tok = 2 * (
+                c.d_model * c.head_dim * (c.num_heads + 2 * c.num_kv_heads)
+                + c.num_heads * c.head_dim * c.d_model
+                + (3 if c.gated_mlp else 2) * c.d_model * mlp
+            )
+            # score+value flops per token, avg T/2 ctx * 2
+            attn = 4 * (min(T, 2 * kind.window) if kind.window else T) * (
+                c.num_heads * c.head_dim)
+            total += (per_tok + attn) * T
+        return total + 2 * c.d_model * c.vocab_size * T
 
     def kv_bytes_per_slot(self, max_len: Optional[int] = None) -> int:
         c = self.cfg
@@ -431,6 +449,9 @@ class CausalLM(ServableModel):
             (r"moe/wi", P("ep", None, "tp")),
             (r"moe/wg", P("ep", None, "tp")),
             (r"moe/wo", P("ep", "tp", None)),
+            (r"moe/shared_gate/kernel", P(None, "tp")),
+            (r"moe/shared_up/kernel", P(None, "tp")),
+            (r"moe/shared_down/kernel", P("tp", None)),
             (r"tok_embed/embedding", P("tp", None)),
             (r"lm_head/kernel", P(None, "tp")),
         ]
@@ -569,6 +590,45 @@ OLMOE_1B_7B = DecoderConfig(
 )
 
 
+# LGAI-EXAONE/K-EXAONE-236B-A23B as published: layers LLLG (three sliding
+# over 128 positions, one full and without positions), GQA 64/8 heads of 128
+# (not 6144 / 64), RMSNorm per head on q and k; layer 0 a dense SwiGLU of
+# 18,432, the other 47 an expert layer: 128 routed experts of 2,048, 8 a
+# token by sigmoid scores plus a selection bias, renormalised, x 2.5, and
+# one shared expert. No chip holds one such layer whole: a deployment gives
+# each rank ``moe_first_expert`` / ``moe_held_experts`` (benchmark/configs).
+# The multi-token-prediction layer is not part of this decoder.
+K_EXAONE_236B = DecoderConfig(
+    vocab_size=153600,
+    d_model=6144,
+    num_layers=48,
+    num_heads=64,
+    num_kv_heads=8,
+    head_dim=128,
+    mlp_dim=2048,
+    max_seq_len=262144,
+    pos="rope",
+    norm="rms",
+    gated_mlp=True,
+    use_bias=False,
+    rope_theta=1000000.0,
+    qk_norm=True,
+    qk_norm_per_head=True,
+    sliding_window=128,
+    layer_pattern="LLLG",
+    rope_sliding_only=True,
+    num_dense_layers=1,
+    dense_mlp_dim=18432,
+    num_experts=128,
+    moe_top_k=8,
+    moe_renormalize=True,
+    moe_scoring="sigmoid",
+    moe_selection_bias=True,
+    moe_gate_scale=2.5,
+    moe_shared_experts=1,
+)
+
+
 @register_model("gpt2_medium", slo=ModelSLO(latency_slo_ms=500.0))
 def _gpt2_medium(**kwargs) -> CausalLM:
     return CausalLM(GPT2_MEDIUM, name="gpt2_medium", **kwargs)
@@ -607,3 +667,8 @@ def _moe_tiny(**kwargs) -> CausalLM:
 @register_model("olmoe_1b_7b", slo=ModelSLO(latency_slo_ms=1000.0))
 def _olmoe_1b_7b(**kwargs) -> CausalLM:
     return CausalLM(OLMOE_1B_7B, name="olmoe_1b_7b", **kwargs)
+
+
+@register_model("k_exaone_236b", slo=ModelSLO(latency_slo_ms=1000.0))
+def _k_exaone_236b(**kwargs) -> CausalLM:
+    return CausalLM(K_EXAONE_236B, name="k_exaone_236b", **kwargs)

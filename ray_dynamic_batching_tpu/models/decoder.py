@@ -59,10 +59,83 @@ class DecoderConfig:
     # RMSNorm on the q and k PROJECTIONS (one scale over all heads' width),
     # before the split into heads and before RoPE (OLMoE).
     qk_norm: bool = False
+    # ... over each HEAD's values instead, one scale of width ``head_dim``
+    # shared by all heads (needs ``qk_norm``).
+    qk_norm_per_head: bool = False
+    # A head's width; 0 = ``d_model // num_heads`` (``__post_init__`` fills
+    # it in, so every reader sees the true width).
+    head_dim: int = 0
+    # Sliding-window attention: a layer whose letter in ``layer_pattern``
+    # is "L" attends the last ``sliding_window`` positions (itself
+    # included), one whose letter is "G" its whole prefix. Layer i takes
+    # letter ``i % len(layer_pattern)``, so a pattern serves any depth. 0:
+    # every layer is full.
+    sliding_window: int = 0
+    layer_pattern: str = "L"
+    # Rotary positions on the sliding layers only: a full layer then has
+    # no positional signal at all.
+    rope_sliding_only: bool = False
+    # An expert model's first ``num_dense_layers`` layers carry a dense MLP
+    # of width ``dense_mlp_dim``; ``mlp_dim`` stays ONE routed expert's.
+    num_dense_layers: int = 0
+    dense_mlp_dim: int = 0
+    # The routing rule (models/moe.py::RoutingRule): "softmax" | "sigmoid"
+    # scores, a learned bias added for the CHOICE only, the chosen gates
+    # times ``moe_gate_scale`` (after ``moe_renormalize``).
+    moe_scoring: str = "softmax"
+    moe_selection_bias: bool = False
+    moe_gate_scale: float = 1.0
+    # One rank's share of an expert-parallel layer: the router scores all
+    # ``num_experts``, this program holds and computes experts
+    # [moe_first_expert, moe_first_expert + moe_held_experts). 0 = all.
+    moe_first_expert: int = 0
+    moe_held_experts: int = 0
+    # Shared experts beside the routed ones: one dense SwiGLU of width
+    # ``moe_shared_experts * mlp_dim`` on every token.
+    moe_shared_experts: int = 0
+
+    def __post_init__(self):
+        if not self.head_dim:
+            object.__setattr__(
+                self, "head_dim", self.d_model // self.num_heads)
+        if self.sliding_window and set(self.layer_pattern) - set("LG"):
+            raise ValueError(
+                f"layer_pattern {self.layer_pattern!r}: letters are L "
+                "(sliding) and G (full)")
+        if self.moe_first_expert + self.held_experts > self.num_experts:
+            raise ValueError(
+                f"held experts [{self.moe_first_expert}, "
+                f"{self.moe_first_expert + self.held_experts}) are not "
+                f"among {self.num_experts}")
 
     @property
-    def head_dim(self) -> int:
-        return self.d_model // self.num_heads
+    def held_experts(self) -> int:
+        """Experts this program holds: ``moe_held_experts``, or all."""
+        return self.moe_held_experts or self.num_experts
+
+    def layer_kind(self, i: int) -> "LayerKind":
+        """What layer ``i`` is: THE place a layer asks."""
+        slides = bool(self.sliding_window) and (
+            self.layer_pattern[i % len(self.layer_pattern)] == "L")
+        sparse = self.num_experts > 0 and i >= self.num_dense_layers
+        return LayerKind(
+            window=self.sliding_window if slides else 0,
+            rope=self.pos == "rope" and (
+                slides or not self.rope_sliding_only),
+            sparse=sparse,
+            mlp_dim=(self.mlp_dim if sparse or not self.num_experts
+                     else self.dense_mlp_dim),
+        )
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """One layer's kind (``DecoderConfig.layer_kind``)."""
+
+    window: int     # positions it attends back, itself included; 0 = all
+    rope: bool      # rotary positions on q and k
+    sparse: bool    # an expert MLP (models/moe.py), else a dense one
+    mlp_dim: int    # the dense MLP's width, or ONE routed expert's
 
 
 @pytree_dataclass
@@ -264,6 +337,17 @@ class RMSNorm(nn.Module):
         return (norm * scale).astype(x.dtype)
 
 
+def swiglu(dense, y: jax.Array, width: int, d_model: int,
+           names: Tuple[str, str, str] = ("mlp_gate", "mlp_up", "mlp_down"),
+           ) -> jax.Array:
+    """The dense gated MLP, ``(silu(y Wg) * (y Wu)) Wd``, from a layer's
+    ``dense(features, name)`` factory: a dense layer's MLP and an expert
+    layer's shared expert are this one code."""
+    gate = dense(width, names[0])(y)
+    up = dense(width, names[1])(y)
+    return dense(d_model, names[2])(nn.silu(gate) * up)
+
+
 class DecoderLayer(nn.Module):
     cfg: DecoderConfig
     dtype: Any = jnp.bfloat16
@@ -288,6 +372,7 @@ class DecoderLayer(nn.Module):
         kv_lengths: Optional[jax.Array] = None,  # [B] paged validity bound
     ) -> Tuple[jax.Array, Optional[Tuple[jax.Array, jax.Array]]]:
         cfg = self.cfg
+        kind = cfg.layer_kind(layer_idx)
         dense = lambda feats, name, axis=-1: nn.DenseGeneral(  # noqa: E731
             feats,
             axis=axis,
@@ -300,14 +385,23 @@ class DecoderLayer(nn.Module):
         q = dense((cfg.num_heads, cfg.head_dim), "q")(y)
         k = dense((cfg.num_kv_heads, cfg.head_dim), "k")(y)
         v = dense((cfg.num_kv_heads, cfg.head_dim), "v")(y)
-        if cfg.qk_norm:
+        if cfg.qk_norm and cfg.qk_norm_per_head:
+            q = RMSNorm(name="q_norm")(q)
+            k = RMSNorm(name="k_norm")(k)
+        elif cfg.qk_norm:
             q = RMSNorm(name="q_norm")(
                 q.reshape(*q.shape[:2], -1)).reshape(q.shape)
             k = RMSNorm(name="k_norm")(
                 k.reshape(*k.shape[:2], -1)).reshape(k.shape)
-        if cfg.pos == "rope":
+        if kind.rope:
             q = apply_rope(q, positions, cfg.rope_theta)
             k = apply_rope(k, positions, cfg.rope_theta)
+        if kind.window and mask is not None:
+            # An explicit mask (the slab cache's, or a whole prompt's)
+            # indexes keys by their position: a sliding layer cuts its
+            # lower edge.
+            mask = mask & sliding_edge(
+                positions, mask.shape[-1], kind.window)[:, None]
 
         if cache_kv is not None:
             # The layer scatters into the FULL stacked [L, B, S, K, H] cache
@@ -455,11 +549,20 @@ class DecoderLayer(nn.Module):
                 # the kernel (a Mosaic operand is a buffer).
                 kv = (k_full, v_full)
                 scale_kwargs.update(page_table=page_table,
-                                    kv_lengths=kv_lengths, layer=layer_idx)
+                                    kv_lengths=kv_lengths, layer=layer_idx,
+                                    sliding=kind.window)
             else:
                 kv = (k_full[layer_idx], v_full[layer_idx])
             attn_out = attn_ops.dot_product_attention(
                 q, *kv, mask=mask, **scale_kwargs)
+        elif token_mask is not None and kind.window:
+            # A sliding layer's whole-sequence attention: the causal
+            # kernel under the window's lower edge (no ring form).
+            attn_out = attn_ops.dot_product_attention(
+                q, k, v, causal=True,
+                mask=token_mask[:, None, None, :].astype(bool)
+                & sliding_edge(positions, k.shape[1], kind.window)[:, None])
+            new_cache = None
         elif token_mask is not None:
             # Full-sequence self-attention: routes through ring attention
             # over the sp mesh axis under a sequence_parallel context.
@@ -473,26 +576,29 @@ class DecoderLayer(nn.Module):
         x = x + attn_out
 
         y = self._norm("mlp_norm")(x).astype(self.dtype)
-        if cfg.num_experts > 0:
-            from ray_dynamic_batching_tpu.models.moe import MoEBlock
+        if kind.sparse:
+            from ray_dynamic_batching_tpu.models.moe import (
+                MoEBlock,
+                routing_rule,
+            )
 
             y = MoEBlock(
                 d_model=cfg.d_model,
                 mlp_dim=cfg.mlp_dim,
                 num_experts=cfg.num_experts,
                 top_k=cfg.moe_top_k,
-                renormalize=cfg.moe_renormalize,
+                rule=routing_rule(cfg),
+                first_expert=cfg.moe_first_expert,
+                held_experts=cfg.held_experts,
+                shared_dim=cfg.moe_shared_experts * cfg.mlp_dim,
                 gated=cfg.gated_mlp,
                 dtype=self.dtype,
                 name="moe",
             )(y)
         elif cfg.gated_mlp:
-            gate = dense(cfg.mlp_dim, "mlp_gate")(y)
-            up = dense(cfg.mlp_dim, "mlp_up")(y)
-            y = nn.silu(gate) * up
-            y = dense(cfg.d_model, "mlp_down")(y)
+            y = swiglu(dense, y, kind.mlp_dim, cfg.d_model)
         else:
-            y = nn.gelu(dense(cfg.mlp_dim, "mlp_up")(y))
+            y = nn.gelu(dense(kind.mlp_dim, "mlp_up")(y))
             y = dense(cfg.d_model, "mlp_down")(y)
         return x + y, new_cache
 
@@ -597,11 +703,28 @@ def decode_mask(lengths: jax.Array, capacity: int) -> jax.Array:
     return pos <= lengths[:, None, None, None]
 
 
+def sliding_edge(positions: jax.Array, capacity: int,
+                 sliding: int) -> jax.Array:
+    """The LOWER edge of a sliding layer: the query at position i attends
+    key j only if ``i - j < sliding`` (``sliding`` positions, itself
+    included). positions [B, T] -> [B, T, S] over keys 0..capacity-1. The
+    upper edge (j <= i) is the caller's mask. The paged kernel computes
+    the same two edges in-kernel, and takes from the slot's table only the
+    columns this edge leaves (``ops/tile_math.py::window_first_page``)."""
+    pos = jnp.arange(capacity)[None, None, :]
+    return pos > positions[:, :, None] - sliding
+
+
 def paged_window_mask(lengths: jax.Array, capacity: int,
-                      window: int) -> jax.Array:
+                      window: int, sliding: int = 0,
+                      base: Optional[jax.Array] = None) -> jax.Array:
     """STAIRCASE window over the paged logical view: verify-window row t
     (the token written at position ``lengths + t``) attends positions
-    [0, lengths + t] inclusive. lengths [B] -> [B, 1, window, S].
+    [0, lengths + t] inclusive, and of those a ``sliding`` layer the last
+    ``sliding`` only (:func:`sliding_edge`). lengths [B] ->
+    [B, 1, window, S]. ``base`` [B]: the view's column 0 is logical
+    position ``base[b]`` (a sliding layer's view starts at its window's
+    first page, ``ops/decode_attention.py::window_table``).
 
     This is THE paged window rule — the Pallas paged kernel computes the
     same staircase in-kernel from the prefetched lengths, and the gather
@@ -610,4 +733,9 @@ def paged_window_mask(lengths: jax.Array, capacity: int,
     exactly :func:`decode_mask` (plain paged decode)."""
     pos = jnp.arange(capacity)[None, None, None, :]
     bound = (lengths[:, None] + jnp.arange(window)[None, :])
-    return pos <= bound[:, None, :, None]
+    if base is not None:     # positions relative to the view's first one
+        bound = bound - base[:, None]
+    mask = pos <= bound[:, None, :, None]
+    if sliding:
+        mask = mask & sliding_edge(bound, capacity, sliding)[:, None]
+    return mask

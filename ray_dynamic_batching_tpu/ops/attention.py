@@ -60,6 +60,7 @@ class AttentionPath:
     kv_shape: Tuple[int, ...]
     kv_dtype: str
     declines: Tuple[str, ...]  # why each kernel tried first said no
+    sliding: int = 0          # a sliding layer's window on a paged read
 
     def describe(self) -> str:
         name = {
@@ -69,7 +70,8 @@ class AttentionPath:
             PATH_XLA: "XLA einsum",
         }[self.path]
         how = (["stacked pool"] if self.stacked else []) + (
-            [f"shard_map tp={self.tp}"] if self.tp > 1 else [])
+            [f"shard_map tp={self.tp}"] if self.tp > 1 else []) + (
+            [f"window {self.sliding}"] if self.sliding else [])
         if how:
             name += f" ({', '.join(how)})"
         return ("gather-then-" if self.gathered else "") + name
@@ -91,14 +93,14 @@ def clear_attention_paths() -> None:
 
 
 def _record(path: str, q, k, declines: List[str], *, gathered: bool = False,
-            stacked: bool = False, tp: int = 1) -> None:
+            stacked: bool = False, tp: int = 1, sliding: int = 0) -> None:
     kernel = path in (PATH_PAGED_KERNEL, PATH_SLAB_KERNEL, PATH_FLASH)
     _PATHS.append(AttentionPath(
         program=current_program(), path=path, gathered=gathered,
         stacked=stacked, tp=tp,
         interpret=kernel and resolve_interpret(None),
         q_shape=tuple(q.shape), kv_shape=tuple(k.shape),
-        kv_dtype=str(k.dtype), declines=tuple(declines),
+        kv_dtype=str(k.dtype), declines=tuple(declines), sliding=sliding,
     ))
 
 
@@ -228,6 +230,7 @@ def dot_product_attention(
     page_table: Optional[jax.Array] = None,
     kv_lengths: Optional[jax.Array] = None,
     layer: int = 0,
+    sliding: int = 0,
 ) -> jax.Array:
     """Multi-head attention.
 
@@ -251,13 +254,19 @@ def dot_product_attention(
     materialization in HBM); everywhere else ONE explicit gather over
     (layer, page) rebuilds the slab view and re-enters this function —
     one mask/dequant rule, so paged and slab reads are token-exact
-    against each other.
+    against each other. ``sliding`` > 0 (paged reads only; elsewhere the
+    window rides the caller's mask) is a sliding-window layer: each row
+    attends its last ``sliding`` positions, by the one rule of
+    ``models/decoder.py::paged_window_mask``.
     """
     if page_table is not None:
         return _paged_attention(
             q, k, v, page_table, kv_lengths, layer, mask=mask,
-            scale=scale, k_scale=k_scale, v_scale=v_scale,
+            scale=scale, k_scale=k_scale, v_scale=v_scale, sliding=sliding,
         )
+    if sliding:
+        raise ValueError("sliding is the paged read's; elsewhere a window "
+                         "rides the mask")
     return _dense_attention(
         q, k, v, causal=causal, mask=mask, scale=scale,
         k_scale=k_scale, v_scale=v_scale, declines=[], gathered=False,
@@ -336,6 +345,7 @@ def _dense_attention(
     v_scale: Optional[jax.Array],
     declines: List[str],
     gathered: bool,
+    sliding: int = 0,   # for the record only: the window is in ``mask``
 ) -> jax.Array:
     """Slab-layout attention: decode kernel, else flash kernel, else the
     XLA reference — each decline's reason lands in ``declines`` (seeded
@@ -362,7 +372,7 @@ def _dense_attention(
             )
             if out is not None:
                 _record(PATH_SLAB_KERNEL, q, k, declines,
-                        gathered=gathered, tp=tp)
+                        gathered=gathered, tp=tp, sliding=sliding)
                 return out
         else:
             declines.append("decode kernel: causal=True call (its "
@@ -381,7 +391,8 @@ def _dense_attention(
             q, k, v, mask, None, None, declines,
         )
         if out is not None:
-            _record(PATH_FLASH, q, k, declines, gathered=gathered, tp=tp)
+            _record(PATH_FLASH, q, k, declines, gathered=gathered, tp=tp,
+                    sliding=sliding)
             return out
         if _BACKEND == "pallas":
             raise AttentionDeclined(
@@ -396,7 +407,7 @@ def _dense_attention(
     if k_scale is not None:
         k, v = _dequantize(k, k_scale, q.dtype), _dequantize(
             v, v_scale, q.dtype)
-    _record(PATH_XLA, q, k, declines, gathered=gathered)
+    _record(PATH_XLA, q, k, declines, gathered=gathered, sliding=sliding)
     return _xla_attention(q, k, v, causal=causal, mask=mask, scale=scale)
 
 
@@ -412,6 +423,7 @@ def _paged_attention(
     scale: Optional[float],
     k_scale: Optional[jax.Array],   # [P, ps, K] or None
     v_scale: Optional[jax.Array],
+    sliding: int = 0,
 ) -> jax.Array:
     """Paged decode read: fused page-table KV scan on the Pallas path,
     explicit gather back to the slab view otherwise (the token-exact
@@ -437,11 +449,12 @@ def _paged_attention(
             mesh_kwargs = {"mesh": tp_mesh, "mesh_axis": tp_axis}
         out = decode_attention.paged_decode_attention(
             q, k, v, page_table, kv_lengths, layer=layer, scale=scale,
-            k_scale=k_scale, v_scale=v_scale, why=declines, **mesh_kwargs,
+            k_scale=k_scale, v_scale=v_scale, why=declines,
+            sliding=sliding, **mesh_kwargs,
         )
         if out is not None:
             _record(PATH_PAGED_KERNEL, q, k, declines, stacked=stacked,
-                    tp=tp)
+                    tp=tp, sliding=sliding)
             return out
     # Gather fallback: rebuild each slot's logical KV run [B, S, K, H]
     # (S = NP * ps) and re-enter the slab path. Sentinel/garbage pages
@@ -456,6 +469,22 @@ def _paged_attention(
     from ray_dynamic_batching_tpu.models.decoder import paged_window_mask
 
     P, ps = k.shape[1], k.shape[2]
+    base = real = None
+    if sliding:
+        # A sliding layer's view is the table columns its rows' windows
+        # cover (the kernel's own sub-table), not the slot's whole run: a
+        # 512-token chunk reads 6 pages of a 32-page table.
+        from ray_dynamic_batching_tpu.ops.decode_attention import (
+            window_table,
+        )
+
+        capacity = page_table.shape[1] * ps
+        page_table, first = window_table(
+            page_table, kv_lengths, sliding, q.shape[1], ps)
+        base = first * ps
+        # columns past the table's end repeat its last entry: no position
+        real = (base[:, None] + jnp.arange(page_table.shape[1] * ps)
+                < capacity)[:, None, None, :]
     safe = jnp.minimum(page_table, P - 1)
     B, NP = page_table.shape
 
@@ -474,10 +503,13 @@ def _paged_attention(
     ks_g = vs_g = None
     if k_scale is not None:
         ks_g, vs_g = logical(k_scale[safe]), logical(v_scale[safe])
-    win = paged_window_mask(kv_lengths, NP * ps, q.shape[1])
+    win = paged_window_mask(kv_lengths, NP * ps, q.shape[1], sliding, base)
+    if real is not None:
+        win = win & real
     return _dense_attention(
         q, k_g, v_g, causal=False, mask=win, scale=scale,
         k_scale=ks_g, v_scale=vs_g, declines=declines, gathered=True,
+        sliding=sliding,
     )
 
 

@@ -129,11 +129,15 @@ class DecodePath:
     kv_dtype: str
     form: str        # FORM_*
     why: str
+    sliding: int = 0      # a sliding layer's window (0: a full layer)
+    table_width: int = 0  # page-table columns the grid walks a slot
 
     def describe(self) -> str:
         return (f"{self.kb} heads x {self.rows} rows over "
                 f"[{self.page_size}, {self.kb}, {self.head_dim}] "
-                f"{self.kv_dtype} pages -> {self.form} ({self.why})")
+                f"{self.kv_dtype} pages -> {self.form} ({self.why}); "
+                + (f"window {self.sliding}: the " if self.sliding else "")
+                + f"{self.table_width} table columns a slot")
 
 
 # Bounded, trace-time only: a call a layer of each traced program.
@@ -149,7 +153,8 @@ def clear_decode_paths() -> None:
     _PATHS.clear()
 
 
-def _record_path(kb: int, rows: int, ps: int, H: int, dtype) -> None:
+def _record_path(kb: int, rows: int, ps: int, H: int, dtype,
+                 sliding: int, table_width: int) -> None:
     if tile_math.flat_heads(kb, rows, ps):
         form, why = FORM_FLAT, (
             f"{kb * rows} rows x {ps * kb} columns in one contraction")
@@ -162,7 +167,8 @@ def _record_path(kb: int, rows: int, ps: int, H: int, dtype) -> None:
             f"pass {tile_math.FLAT_SCORE_MAX_BYTES >> 20} MiB")
     _PATHS.append(DecodePath(
         program=current_program(), kb=kb, rows=rows, page_size=ps,
-        head_dim=H, kv_dtype=str(jnp.dtype(dtype)), form=form, why=why))
+        head_dim=H, kv_dtype=str(jnp.dtype(dtype)), form=form, why=why,
+        sliding=sliding, table_width=table_width))
 
 
 def _window_rows(mask_ref, rows: int, R: int, window: int):
@@ -518,8 +524,27 @@ def _decode_attention(
     )(*args).reshape(B, K, R, H)
 
 
+def window_table(page_table: jax.Array, lengths: jax.Array, sliding: int,
+                 rows: int, page_size: int):
+    """The columns of a slot's page table that a SLIDING layer's ``rows``
+    window rows (the first at position ``lengths``) can attend, as a
+    narrower table ``[B, tile_math.window_table_width(...)]``, and the
+    logical page ``[B]`` of its first column
+    (``tile_math.window_first_page``). Columns past the table's end repeat
+    its last entry: their positions lie past the capacity, where nothing
+    is attended. The paged kernel's grid and the gather fallback's view
+    are both this wide."""
+    n_entries = page_table.shape[1]
+    width = tile_math.window_table_width(sliding, rows, page_size, n_entries)
+    first = tile_math.window_first_page(
+        lengths.astype(jnp.int32), sliding, page_size)
+    cols = first[:, None] + jnp.arange(width, dtype=jnp.int32)[None, :]
+    return jnp.take_along_axis(
+        page_table, jnp.minimum(cols, n_entries - 1), axis=1), first
+
+
 @functools.partial(
-    jax.jit, static_argnames=("scale", "window", "interpret")
+    jax.jit, static_argnames=("scale", "window", "sliding", "interpret")
 )
 def _paged_decode_attention(
     q: jax.Array,          # [B, K, Tq*G, H]  rows ordered (t, g)
@@ -534,14 +559,29 @@ def _paged_decode_attention(
     scale: float,
     window: int,
     interpret: bool,
+    sliding: int = 0,
 ) -> jax.Array:
     B, K, R, H = q.shape
     G = R // window
     P, ps = k.shape[1], k.shape[2]
-    NP = page_table.shape[1]
+    capacity = page_table.shape[1] * ps
     kb = _pick_heads_block(K)
     has_scales = k_scale is not None
     flat = tile_math.flat_heads(kb, R, ps)
+    # A SLIDING layer (row t attends the last ``sliding`` positions up to
+    # its own) is handed the table columns its window covers, not the
+    # table: column ``first`` holds the oldest position the first row
+    # sees, and ``NP`` columns from there cover every row's window
+    # (``tile_math``'s two window functions). The grid is NP wide whatever
+    # the slot's length: pages wholly behind the window are not walked.
+    # ``first`` is prefetched with the lengths; step p is logical page
+    # ``first[b] + p``. A full layer has no such operand and its program is
+    # the one it was.
+    first = None
+    if sliding:
+        page_table, first = window_table(
+            page_table, lengths, sliding, window, ps)
+    NP = page_table.shape[1]
 
     # The page axis IS the KV tiling: grid step (b, j, p) streams slot
     # b's p-th page — whichever physical page the PREFETCHED table names.
@@ -561,14 +601,22 @@ def _paged_decode_attention(
     # within the bound, so page 0 is always live. A sentinel/garbage
     # entry of a live step (an idle slot's page 0) still clamps to a
     # real page; the length bound masks everything it could contribute.
-    def page_index(b, p, pt, ln):
-        last_live = (ln[b] + (window - 1)) // ps
+    def last_position(b, ln):
+        # the last position any row attends (a sliding layer's columns may
+        # run past the table's end: nothing lives there)
+        last = ln[b] + (window - 1)
+        return jnp.minimum(last, capacity - 1) if sliding else last
+
+    def page_index(b, p, pt, ln, *fp):
+        last_live = last_position(b, ln) // ps
+        if sliding:
+            last_live = last_live - fp[0][b]
         return jnp.minimum(pt[b, jnp.minimum(p, last_live)], P - 1)
 
-    def kv_index(b, j, p, pt, ln, ly):
-        return (ly[0], page_index(b, p, pt, ln), 0, j, 0)
+    def kv_index(b, j, p, pt, ln, ly, *fp):
+        return (ly[0], page_index(b, p, pt, ln, *fp), 0, j, 0)
 
-    def rows_index(b, j, p, pt, ln, ly):
+    def rows_index(b, j, p, pt, ln, ly, *fp):
         return (b, j, 0)
 
     rows_spec = pl.BlockSpec((1, kb * R, H), rows_index)
@@ -583,8 +631,8 @@ def _paged_decode_attention(
     if has_scales and flat:
         # A page's scales as ONE lane row in the flat column order.
         scale_spec = _flat_scale_spec(
-            ps * kb, lambda b, j, p, pt, ln, ly: (
-                page_index(b, p, pt, ln), j, 0, 0, 0))
+            ps * kb, lambda b, j, p, pt, ln, ly, *fp: (
+                page_index(b, p, pt, ln, *fp), j, 0, 0, 0))
         in_specs += [scale_spec, scale_spec]
         args += [_flat_columns(k_scale, kb, ps),
                  _flat_columns(v_scale, kb, ps)]
@@ -595,20 +643,26 @@ def _paged_decode_attention(
         # path is the same trap this transpose avoids).
         scale_spec = pl.BlockSpec(
             (1, kb, ps),
-            lambda b, j, p, pt, ln, ly: (page_index(b, p, pt, ln), j, 0),
+            lambda b, j, p, pt, ln, ly, *fp: (
+                page_index(b, p, pt, ln, *fp), j, 0),
         )
         in_specs += [scale_spec, scale_spec]
         args += [k_scale.transpose(0, 2, 1), v_scale.transpose(0, 2, 1)]
 
-    def kernel(pt_ref, len_ref, ly_ref, q_ref, k_ref, v_ref, *rest):
+    def kernel(pt_ref, len_ref, ly_ref, *rest):
+        first_ref = None
+        if sliding:
+            first_ref, *rest = rest
+        q_ref, k_ref, v_ref, *rest = rest
         ks_ref = rest[0] if has_scales else None
         vs_ref = rest[1] if has_scales else None
         o_ref, m_ref, l_ref, acc_ref = rest[2 if has_scales else 0:][:4]
         b = pl.program_id(0)
         p = pl.program_id(2)
+        page = p + first_ref[b] if sliding else p   # the logical page
         _scan_begin(m_ref, l_ref, acc_ref)
 
-        @pl.when(p * ps <= len_ref[b] + (window - 1))
+        @pl.when(page * ps <= last_position(b, len_ref))
         def _live_page():
             # In-kernel STAIRCASE validity from the prefetched lengths:
             # page p covers logical positions [p*ps, (p+1)*ps); window
@@ -619,18 +673,24 @@ def _paged_decode_attention(
             # a column is (position, head) and a row (head, t, g).
             shape = (kb * R, ps * kb) if flat else (R, ps)
             col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-            pos = p * ps + (col // kb if flat else col)
+            pos = page * ps + (col // kb if flat else col)
             t_of_row = (jax.lax.broadcasted_iota(
                 jnp.int32, shape, 0) % R) // G
+            bound = len_ref[b] + t_of_row
+            valid = pos <= bound
+            if sliding:
+                # the lower edge (``models/decoder.py::sliding_edge``)
+                valid = valid & (pos > bound - sliding)
             _accumulate_tile(
                 q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref, acc_ref,
-                valid=pos <= len_ref[b] + t_of_row, scale=scale,
+                valid=valid, scale=scale,
             )
 
         _scan_end(o_ref, m_ref, l_ref, acc_ref, num_s=NP)
 
+    prefetch = [page_table, lengths, layer] + ([first] if sliding else [])
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(prefetch),
         grid=(B, K // kb, NP),
         in_specs=in_specs,
         out_specs=rows_spec,
@@ -642,7 +702,7 @@ def _paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct((B, K * R, H), q.dtype),
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(page_table, lengths, layer, *args).reshape(B, K, R, H)
+    )(*prefetch, *args).reshape(B, K, R, H)
 
 
 def paged_decode_attention(
@@ -660,6 +720,7 @@ def paged_decode_attention(
     mesh: Optional[Any] = None,
     mesh_axis: str = "tp",
     why: Optional[List[str]] = None,
+    sliding: int = 0,
 ) -> Optional[jax.Array]:
     """Fused page-table decode attention; returns None when the shapes
     aren't the paged decode pattern (caller falls back to the explicit
@@ -682,7 +743,12 @@ def paged_decode_attention(
     costs no copy and no arithmetic whatever it holds, so a slot's scan
     costs what its live KV costs, not what the table is wide.
     ``k_scale``/``v_scale`` [P, ps, K] (this layer's planes, H times
-    smaller than the codes) enable the int8-pool path.
+    smaller than the codes) enable the int8-pool path. ``sliding`` > 0 is
+    a sliding-window layer: row t attends the last ``sliding`` of those
+    positions only, and the scan is bounded from BELOW too — its grid
+    walks the ``tile_math.window_table_width`` table columns the window
+    covers (2 for a window of one page), so it costs what the window
+    costs, flat in the slot's length.
 
     Eligibility is the lane-alignment + VMEM-budget contract of
     ``ops/tile_math.py``: the page IS the KV tile, so its streamed
@@ -762,7 +828,9 @@ def paged_decode_attention(
             why, f"paged kernel: page tile (ps={ps}, kb={kb}, H={Hk}) "
             "exceeds the VMEM block budget")
     interpret = resolve_interpret(interpret)
-    _record_path(kb, Tq * G, ps, Hk, k.dtype)
+    _record_path(kb, Tq * G, ps, Hk, k.dtype, int(sliding),
+                 tile_math.window_table_width(
+                     int(sliding), Tq, ps, page_table.shape[1]))
     scale = scale if scale is not None else H ** -0.5
     # Rows ordered (t, g) per kv head: [B, Tq, K, G, H] ->
     # [B, K, Tq*G, H] (Tq == 1 collapses to the historical layout),
@@ -776,7 +844,7 @@ def paged_decode_attention(
                 kv_lengths.astype(jnp.int32),
                 jnp.full((1,), layer, jnp.int32), k_scale, v_scale)
     static = dict(scale=float(scale), window=int(Tq),
-                  interpret=bool(interpret))
+                  interpret=bool(interpret), sliding=int(sliding))
     if tp > 1:
         out = _paged_decode_attention_tp(mesh, mesh_axis, *operands,
                                          **static)
@@ -788,7 +856,7 @@ def paged_decode_attention(
 
 def _paged_decode_attention_tp(
     mesh, axis: str, q_r, k, v, page_table, kv_lengths, layer, ks, vs,
-    *, scale: float, window: int, interpret: bool,
+    *, scale: float, window: int, interpret: bool, sliding: int = 0,
 ):
     """The TP wrapper: ``shard_map`` the paged kernel over the mesh's
     ``axis`` with q/pools split on the kv-head dim and the page
@@ -818,6 +886,7 @@ def _paged_decode_attention_tp(
         return _paged_decode_attention(
             q_l, k_l, v_l, pt, ln, ly, ks_l, vs_l,
             scale=scale, window=window, interpret=interpret,
+            sliding=sliding,
         )
 
     # check_vma=False: pallas_call declares no varying-axes rule, and

@@ -4,7 +4,10 @@ expert products from, in the shape of :mod:`ops.attention`.
 
 The caller has already routed: ``xs`` [M, D] holds the M = tokens x top_k
 routed rows SORTED BY EXPERT and ``group_sizes`` [E] says how many rows
-each expert drew (they sum to M; a group may be empty). Both paths compute,
+each expert drew (a group may be empty; they sum to M, or to less where
+the caller holds some of the experts only: the rows behind the last group
+are no expert's, cost no work item, and their results are undefined). Both
+paths compute,
 for a row r of expert e,
 
     ys[r] = act(xs[r] @ wi[e]) @ wo[e]          act = gelu
@@ -40,7 +43,10 @@ from ray_dynamic_batching_tpu.ops.pallas_common import (
     declined,
     resolve_interpret,
 )
-from ray_dynamic_batching_tpu.ops.tile_math import VMEM_LIMIT_BYTES
+from ray_dynamic_batching_tpu.ops.tile_math import (
+    VMEM_LIMIT_BYTES,
+    moe_tile_cols,
+)
 from ray_dynamic_batching_tpu.utils.compile_ledger import current_program
 
 # "auto": the kernel on a TPU backend, ragged_dot elsewhere. "xla":
@@ -54,11 +60,6 @@ PATH_XLA = "ragged_dot"
 # The kernel's name in a device trace (``benchmark/trace_reduce.py``'s
 # ``stable_name``): readers find it by this.
 KERNEL_NAME = "moe_grouped_matmul"
-
-# Output-column tile: a [D, 512] bf16 weight block at D 2,048 is 2 MiB, so
-# a gated step (two weights, double-buffered) holds 8 MiB of the 32 MiB
-# scoped VMEM and moves 4 MiB of weights, far above the per-step cost.
-_TILE_COLS = 512
 
 
 class MoEDeclined(ValueError):
@@ -122,7 +123,7 @@ def _under_mesh() -> bool:
 def expert_mlp(xs: jax.Array, group_sizes: jax.Array, wi: jax.Array,
                wo: jax.Array, wg: Optional[jax.Array] = None) -> jax.Array:
     """The experts' MLPs on rows sorted by expert. xs [M, D];
-    group_sizes [E] int32 summing to M; wi (and wg, for gated experts)
+    group_sizes [E] int32 summing to at most M; wi (and wg, for gated experts)
     [E, D, F]; wo [E, F, D]. Returns [M, D] in ``xs.dtype`` (accumulation
     in float32 on both paths)."""
     M, D = xs.shape
@@ -194,9 +195,14 @@ def _work_items(group_sizes: jax.Array, tiles: int, tm: int):
     n_tiles = jnp.where(sizes > 0, (ends - 1) // tm - first + 1, 0)
     item_end = jnp.cumsum(n_tiles)
     num = item_end[-1]
-    item = jnp.minimum(jnp.arange(tiles + E - 1, dtype=jnp.int32), num - 1)
+    # (no row at all, where this rank's experts drew none: one unreal
+    # item, on an expert and a tile that exist)
+    item = jnp.maximum(
+        jnp.minimum(jnp.arange(tiles + E - 1, dtype=jnp.int32), num - 1), 0)
     # the first expert whose items end past this one
-    group = (item[:, None] >= item_end[None, :]).sum(-1).astype(jnp.int32)
+    group = jnp.minimum(
+        (item[:, None] >= item_end[None, :]).sum(-1), E - 1
+    ).astype(jnp.int32)
     tile = first[group] + item - (item_end[group] - n_tiles[group])
     return group, tile.astype(jnp.int32), starts, ends, num[None]
 
@@ -208,7 +214,7 @@ def _grouped_matmul(xs, tables, w, w_gate, act: Optional[str], tm: int,
     sorted by expert; xs [Mp, K] with Mp a multiple of ``tm``."""
     Mp, K = xs.shape
     E, _, N = w.shape
-    tn = _TILE_COLS if N % _TILE_COLS == 0 else 128
+    tn = moe_tile_cols(K, N, 1 if w_gate is None else 2, w.dtype.itemsize)
     n_items = Mp // tm + E - 1
 
     def moe_grouped_matmul(group_ref, tile_ref, start_ref, end_ref, num_ref,

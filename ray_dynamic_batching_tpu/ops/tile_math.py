@@ -251,3 +251,47 @@ def decode_tile_bytes(
                if with_scales else 0)
     return DOUBLE_BUFFER * (kv + mask_b + scale_b) + (
         flat_score_bytes(sb, kb, rows) if flat else 0)
+
+
+def window_table_width(sliding: int, rows: int, page_size: int,
+                       n_entries: int) -> int:
+    """Page-table columns a paged decode scan walks: all ``n_entries`` of
+    a full layer (``sliding`` 0); for a sliding layer the most pages that
+    the ``sliding + rows - 1`` positions its ``rows`` window rows attend
+    between them can touch (window 128 on 128-position pages, one row: 2).
+    Static: the kernel's grid is this wide whatever the slot's length."""
+    if sliding <= 0:
+        return n_entries
+    span = sliding + rows - 1
+    return min(n_entries, (span + page_size - 2) // page_size + 1)
+
+
+def window_first_page(lengths, sliding: int, page_size: int):
+    """The table column of the oldest position a sliding layer's scan
+    attends: its first window row sits at position ``lengths`` and sees
+    back to ``lengths - sliding + 1``. Plain arithmetic, so that the
+    kernel's wrapper (traced), the engine's page counts (numpy) and a
+    test (ints) share the one rule."""
+    oldest = lengths - (sliding - 1)
+    return (oldest > 0) * (oldest // page_size)
+
+
+def moe_tile_cols(k_dim: int, n_dim: int, n_weights: int,
+                  itemsize: int) -> int:
+    """Output-column tile of the grouped expert matmul
+    (``ops/moe.py::_grouped_matmul``): the widest of 512, 256, 128 that
+    divides ``n_dim`` and whose weight blocks, ``n_weights`` of
+    [k_dim, tile] double-buffered (the blocks a grid step streams; the row
+    tile and the f32 products ride alongside, as q and the scratch do in
+    the decode kernels), fit ``VMEM_BLOCK_BUDGET_BYTES``. At a contracted
+    width of 2,048 a gated step's two blocks of 512 are 8 MiB; at 6,144
+    they would be 24 of the 32 MiB limit, so that step takes 256 (12 MiB).
+    A wide tile matters: a block's rows are ``tile`` contiguous elements
+    in HBM, and a step moves megabytes against its fixed cost."""
+    for tn in (512, 256):
+        if n_dim % tn == 0 and (
+                DOUBLE_BUFFER * n_weights
+                * padded_block_bytes((k_dim, tn), itemsize)
+                <= VMEM_BLOCK_BUDGET_BYTES):
+            return tn
+    return 128

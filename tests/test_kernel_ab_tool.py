@@ -35,13 +35,17 @@ def test_the_geometries_are_the_benchmarks_configurations():
     import json
 
     root = Path(__file__).resolve().parents[1]
-    for tag, L, P, B, NP, N, K, H, _ in ab.PAGED_GEOMETRIES[:3]:
+    named = [g for g in ab.PAGED_GEOMETRIES
+             if (root / "benchmark" / "configs" / f"{g[0]}.json").exists()]
+    assert len(named) == 4
+    for tag, L, P, B, NP, N, K, H, _ in named:
         cfg = json.loads((root / "benchmark" / "configs"
                           / f"{tag}.json").read_text())
         dec, llm = cfg["program"]["decoder_config"], cfg["deployment"]["llm"]
         assert (L, N, K, H) == (dec["num_layers"], dec["num_heads"],
                                 dec["num_kv_heads"],
-                                dec["d_model"] // dec["num_heads"])
+                                dec.get("head_dim")
+                                or dec["d_model"] // dec["num_heads"])
         assert (B, P) == (llm["num_slots"], llm["kv_pool_pages"])
         assert NP == -(-llm["max_len"] // llm["page_size"])
         assert llm["page_size"] == ab.PAGE

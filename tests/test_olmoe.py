@@ -29,7 +29,7 @@ from ray_dynamic_batching_tpu.models import registry  # noqa: F401
 from ray_dynamic_batching_tpu.models.base import get_model
 from ray_dynamic_batching_tpu.models.causal_lm import OLMOE_1B_7B, CausalLM
 from ray_dynamic_batching_tpu.models.decoder import DecoderConfig
-from ray_dynamic_batching_tpu.models.moe import MoEBlock
+from ray_dynamic_batching_tpu.models.moe import MoEBlock, RoutingRule
 from ray_dynamic_batching_tpu.ops import moe as moe_ops
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -268,7 +268,8 @@ def test_grouped_path_matches_every_expert_then_the_chosen_k(backend, skew):
     D, F, E = 128, 256, 8
     k = 1 if skew == "all_to_one_expert" else 3
     block = MoEBlock(d_model=D, mlp_dim=F, num_experts=E, top_k=k,
-                     renormalize=False, dtype=jnp.float32)
+                     rule=RoutingRule(renormalize=False),
+                     dtype=jnp.float32)
     x = jnp.asarray(
         np.random.default_rng(1).standard_normal((2, 9, D)), jnp.float32)
     p = block.init(jax.random.PRNGKey(2), x)
@@ -331,8 +332,8 @@ def test_pad_tokens_and_idle_slots_change_nothing(
 
 def test_counters_count_real_tokens_only(served, params, view, ref, tokens,
                                          other):
-    """Each chunk's and each decode step's [rows, experts hit, most rows]
-    against counts made by hand from the reference's top-k of the same
+    """Each chunk's and each decode step's [rows, experts hit, most rows,
+    pairs] (every expert is held: pairs = rows) against counts made by hand from the reference's top-k of the same
     tokens."""
     k, E = TINY.moe_top_k, TINY.num_experts
     routing = {}
@@ -343,8 +344,9 @@ def test_counters_count_real_tokens_only(served, params, view, ref, tokens,
     def count(picks):  # picks: per layer, the chosen experts of real tokens
         took = [np.bincount(np.asarray(p).ravel(), minlength=E)
                 for p in picks]
-        return [sum(t.sum() for t in took), sum((t > 0).sum() for t in took),
-                max(t.max() for t in took)]
+        rows = sum(t.sum() for t in took)
+        return [rows, sum((t > 0).sum() for t in took),
+                max(t.max() for t in took), rows]
 
     want = []
     for start in range(0, PROMPT, W):
@@ -462,9 +464,9 @@ def test_an_expert_models_programs_carry_the_counters(model, params):
     engine, _ = _engine(model, params)
     low = _lowered(engine)
     assert jax.tree_util.tree_leaves(
-        low["decode_step"].out_info)[0].shape == (5 + 3, 4)
+        low["decode_step"].out_info)[0].shape == (5 + 4, 4)
     assert jax.tree_util.tree_leaves(
-        low["chunk_prefill"].out_info)[0].shape == (1 + 3,)
+        low["chunk_prefill"].out_info)[0].shape == (1 + 4,)
 
 
 def test_the_published_preset_is_registered_with_the_published_sizes():

@@ -1,8 +1,11 @@
 """Served logits against the plain reference, for an expert configuration
-under ``benchmark/configs/``, at the widths the file gives. By hand, on the
-chip (or at ``--tiny`` widths on the CPU); outside any timed window.
+under ``benchmark/configs/`` named by ``--config``, at the widths the file
+gives. By hand, on the chip (or at ``--tiny`` widths on the CPU); outside any
+timed window.
 
     python3 -m tools.moe_logits_check --config olmoe-1b-7b-1chip --seed 7
+    python3 -m tools.moe_logits_check --config k-exaone-236b-ep8-1chip \
+        --prompt 1100 --chunk 512 --decode 8 --sample 4
 
 Seeded weights (``benchmark/weights.py``) and a full batch of seeded
 sequences go through the program's model as the engine drives it: prompts
@@ -13,22 +16,108 @@ dispatcher's own paths (paged and flash kernels, the grouped expert kernel).
 A sample of the sequences goes through the reference's ONE full forward
 (float32, precision "highest"), and the two are compared on LOGITS at every
 position. Printed: the worst gap; the positions where the served and the
-reference top-k expert SETS differ in some layer (a near-tie between the
-k-th and the next gate, decided differently in bfloat16: the token then
+reference top-k expert SETS differ in some expert layer (a near-tie between
+the k-th and the next gate, decided differently in bfloat16: the token then
 goes through another expert, which is a different function, not an error of
 arithmetic); the worst gap without those positions; and the benchmark's own
 measure (``run.py::REF_TOL``): how far below the reference's top-1 the
 served argmax lies in the reference's logits.
+
+Where the reference reports how far the held experts lie from the edge of
+its chosen set (``logits(..., edges=)``: K-EXAONE's), also printed: that
+distance at the positions where a swapped expert is held here, and for a row
+of thresholds the share of positions the reference would excuse as undecided
+(``reference_check.undecided_score_gap``) and what is left beyond ``REF_TOL``.
+
+``--control`` serves a deliberately WRONG program against the same
+reference, to show what the gap reads when something is wrong:
+``drop_bias`` (the router's selection bias left out of the choice),
+``drop_gate_scale`` (the gates not multiplied by the routed scaling factor)
+or ``int8_pool`` (the KV pool in int8 codes).
+
+``--harness SEED[,SEED..]`` is the HARNESS'S OWN comparison instead: the
+configuration (wrong on purpose under ``--control``) deployed through
+``benchmark.run.Deployed`` and judged by ``benchmark.run.reference_check``,
+once a seed, each with its verdict; ``--few-programs`` warms one bucket and
+one horizon (a control's programs are compiled for it alone).
 """
 
 from __future__ import annotations
 
 import argparse
+import inspect
 import json
 import pathlib
 import sys
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
+CONTROLS = ("none", "drop_bias", "drop_gate_scale", "int8_pool")
+GAPS = (0.002, 0.004, 0.006, 2.0 ** -7, 0.012, 0.016)
+
+
+def harness_checks(cfg, control: str, seeds, few_programs: bool) -> int:
+    """``run.py``'s deployment and its reference check, a seed at a time; a
+    control changes what is SERVED and nothing the reference reads."""
+    import copy
+    import gc
+    import types
+
+    import jax
+
+    from benchmark import run, weights
+
+    make_params = weights.make_params
+    failed = 0
+    for n, seed in enumerate(seeds):
+        c = copy.deepcopy(cfg)
+        c["program"]["register_as"] += f"_{control}_{n}"
+        llm, dc = c["deployment"]["llm"], c["program"]["decoder_config"]
+        if few_programs:
+            llm.update(prompt_buckets=[max(llm["prompt_buckets"])],
+                       decode_horizon=1, ttft_horizon=1,
+                       max_admissions_per_step=1)
+        if control == "drop_gate_scale":
+            dc["moe_gate_scale"] = 1.0
+        elif control == "int8_pool":
+            llm["quantize_kv"] = True
+        biases = {}
+        if control == "drop_bias":
+            # served with a bias of zero; the reference keeps the one drawn
+            def without(*args, **kw):
+                def leaf(path, x):
+                    if "selection_bias" not in jax.tree_util.keystr(path):
+                        return x
+                    biases[jax.tree_util.keystr(path)] = x
+                    return jax.numpy.zeros_like(x)
+                return jax.tree_util.tree_map_with_path(
+                    leaf, make_params(*args, **kw))
+            weights.make_params = without
+        try:
+            dep = run.Deployed(c, seed, jax.devices()[:1], {})
+        finally:
+            weights.make_params = make_params
+        try:
+            if biases:
+                view = dep.view.view
+                dep.view = types.SimpleNamespace(view=lambda params, config: (
+                    view(jax.tree_util.tree_map_with_path(
+                        lambda path, x: biases.get(
+                            jax.tree_util.keystr(path), x), params), config)))
+            got = run.reference_check(dep, seed)
+        finally:
+            dep.close()
+        failed += not got["ok"]
+        print(f"harness: control {control} seed {seed}: ok={got['ok']} worst "
+              f"margin {got['worst_gap']:.4f} (tolerance {got['tol']})",
+              flush=True)
+        del dep
+        gc.collect()
+        live = sum(x.nbytes for x in jax.live_arrays())
+        print(f"harness: {live / 2**30:.2f} GiB of arrays alive after the "
+              "deployment closed", flush=True)
+    print(f"harness: control {control}: {failed} of {len(seeds)} seeds not "
+          "correct", flush=True)
+    return 0
 
 
 def main() -> int:
@@ -40,9 +129,18 @@ def main() -> int:
     ap.add_argument("--decode", type=int, default=16)
     ap.add_argument("--sample", type=int, default=8,
                     help="sequences of the batch the reference computes")
+    ap.add_argument("--slots", type=int, default=0,
+                    help="sequences in the batch (default: the "
+                         "configuration's slots)")
     ap.add_argument("--tiny", action="store_true",
                     help="benchmark/tests/tiny.py's widths (CPU rehearsal)")
+    ap.add_argument("--control", default="none", choices=CONTROLS)
+    ap.add_argument("--harness", default="", metavar="SEEDS",
+                    help="run.py's own reference_check, once a seed")
+    ap.add_argument("--few-programs", action="store_true")
     a = ap.parse_args()
+
+    import dataclasses
 
     import jax
     import jax.numpy as jnp
@@ -51,7 +149,7 @@ def main() -> int:
     from benchmark import reference, views
     from benchmark.run import REF_TOL, model_factory
     from benchmark.weights import make_params
-    from ray_dynamic_batching_tpu.models.decoder import PagedKVCache
+    from ray_dynamic_batching_tpu.models.causal_lm import CausalLM
     from ray_dynamic_batching_tpu.ops.attention import attention_paths
     from ray_dynamic_batching_tpu.ops.moe import moe_paths
 
@@ -62,41 +160,63 @@ def main() -> int:
         cell = next(w["name"] for w in bench["workloads"]
                     if w["config"] == a.config)
         cfg = tiny_cell(cell).config
+        # tiny.py cuts max_len to 256 and leaves the check's prompts
+        cfg["reference_check"]["prompt_lens"] = [
+            min(n, 150 + 80 * i) for i, n in enumerate(
+                cfg["reference_check"]["prompt_lens"])]
     else:
         entry = next(c for c in bench["configs"] if c["name"] == a.config)
         cfg = json.loads((REPO / entry["file"]).read_text())
+    if a.harness:
+        return harness_checks(cfg, a.control,
+                              [int(x) for x in a.harness.split(",")],
+                              a.few_programs)
     prog, llm = cfg["program"], cfg["deployment"]["llm"]
     dtype = jnp.dtype(prog["dtype"])
     model = model_factory(prog, "logits_check")(dtype=dtype)
     view, ref = views.get(cfg["view"]), reference.get(cfg["reference"])
     params = make_params(model, a.seed, dtype, getattr(view, "seeding", None))
-    B, ps = int(llm["num_slots"]), int(llm["page_size"])
+    if a.control == "drop_bias":
+        model = CausalLM(dataclasses.replace(
+            model.cfg, moe_selection_bias=False), name="no_bias", dtype=dtype)
+    elif a.control == "drop_gate_scale":
+        model = CausalLM(dataclasses.replace(
+            model.cfg, moe_gate_scale=1.0), name="no_scale", dtype=dtype)
+    elif a.control == "int8_pool":
+        model = CausalLM(model.cfg, name="int8_pool", dtype=dtype,
+                         kv_dtype=jnp.int8)
+    sparse = [i for i in range(model.cfg.num_layers)
+              if model.cfg.layer_kind(i).sparse]
+    B, ps = a.slots or int(llm["num_slots"]), int(llm["page_size"])
     P, W, n_dec = a.prompt, a.chunk, a.decode
     T = P + n_dec
     per_slot = -(-T // ps)
     print(f"device: {jax.devices()[0].device_kind!r}; {a.config}: "
-          f"{model.cfg.num_layers} layers, {B} sequences of {P} + {n_dec} "
-          f"tokens, chunks of {W}", flush=True)
+          f"{model.cfg.num_layers} layers ({len(sparse)} of experts), {B} "
+          f"sequences of {P} + {n_dec} tokens, chunks of {W}; control "
+          f"{a.control}", flush=True)
     rng = np.random.default_rng(a.seed)
     seqs = rng.integers(1, model.cfg.vocab_size, size=(B, T)).astype(np.int32)
     pool = model.make_paged_cache(B, B * per_slot, ps, per_slot * ps)
-    pool_k, pool_v = pool.k, pool.v
     tables = jnp.asarray(
         rng.permutation(B * per_slot).reshape(B, per_slot), jnp.int32)
 
-    def forward(params, tokens, positions, k, v, tables, lengths):
+    def forward(params, tokens, positions, pool, tables, lengths):
         (logits, new), state = model.module.apply(
             params, tokens, positions, None,
-            PagedKVCache(k=k, v=v, page_table=tables, lengths=lengths),
+            pool.replace(page_table=tables, lengths=lengths),
             scatter_writes=tokens.shape[1] > 1, page_table=tables,
             kv_lengths=lengths, mutable=["moe_routing"])
-        # by layer NUMBER (the tree's own order is layer0, layer1, layer10..)
+        # the expert layers, by layer NUMBER (the tree's own order is
+        # layer0, layer1, layer10..)
         picks = jnp.stack([
             state["moe_routing"][f"layer{i}"]["moe"]["top_idx"][0]
-            for i in range(model.cfg.num_layers)])
-        return logits.astype(jnp.float32), new.k, new.v, picks  # [L,B,T,k]
+            for i in sparse])
+        return (logits.astype(jnp.float32),
+                new.replace(page_table=pool.page_table,
+                            lengths=pool.lengths), picks)   # [L, B, T, k]
 
-    step = jax.jit(forward, donate_argnums=(3, 4))   # the pool, in place
+    step = jax.jit(forward, donate_argnums=(3,))   # the pool, in place
     sample = list(range(0, B, max(B // a.sample, 1)))[:a.sample]
     served = np.zeros((len(sample), T, model.cfg.vocab_size), np.float32)
     picked = [None] * T
@@ -108,8 +228,8 @@ def main() -> int:
             toks = np.zeros((g, W), np.int32)
             toks[:, :take] = seqs[rows, start:start + take]
             pos = jnp.asarray(start + np.arange(W)[None].repeat(g, 0))
-            logits, pool_k, pool_v, picks = step(
-                params, jnp.asarray(toks), pos, pool_k, pool_v, tables[rows],
+            logits, pool, picks = step(
+                params, jnp.asarray(toks), pos, pool, tables[rows],
                 jnp.full((g,), start, jnp.int32))
             for i, b in enumerate(sample):
                 if r0 <= b < r0 + g:
@@ -119,13 +239,20 @@ def main() -> int:
                         picked[start + t] = picked[start + t] or {}
                         picked[start + t][i] = np.asarray(
                             picks[:, b - r0, t])
+    load = []   # a decode step: held share of its pairs, held experts hit
     for t in range(P, T):
         lengths = jnp.full((B,), t, jnp.int32)
-        logits, pool_k, pool_v, picks = step(
-            params, jnp.asarray(seqs[:, t:t + 1]), lengths[:, None], pool_k,
-            pool_v, tables, lengths)
+        logits, pool, picks = step(
+            params, jnp.asarray(seqs[:, t:t + 1]), lengths[:, None], pool,
+            tables, lengths)
         picked[t] = {i: np.asarray(picks[:, b, 0])
                      for i, b in enumerate(sample)}
+        allp = np.asarray(picks)[:, :, 0]                  # [L, B, k]
+        lo = model.cfg.moe_first_expert
+        mine = (allp >= lo) & (allp < lo + model.cfg.held_experts)
+        load.append((float(mine.mean()), float(np.mean(
+            [len(set(allp[layer][mine[layer]])) for layer in
+             range(len(sparse))]))))
         served[:, t] = np.asarray(logits[jnp.asarray(sample), 0])
     for line in sorted({f"{r.program or '<tool>'}: q{list(r.q_shape)} -> "
                         f"{r.describe()}" for r in attention_paths()}
@@ -133,14 +260,29 @@ def main() -> int:
                           for m in moe_paths()}):
         print("paths:", line, flush=True)
 
+    if load:
+        print(f"load: over {len(load)} decode steps of {B} rows, "
+              f"{100.0 * np.mean([s for s, _ in load]):.2f}% of the pairs "
+              f"land on the {model.cfg.held_experts} held experts, "
+              f"{np.mean([h for _, h in load]):.2f} of which a layer a "
+              "step draws a row", flush=True)
     weights = view.view(params, cfg)
     worst = worst_same = margin = 0.0
     differ = positions = 0
-    by_layer = np.zeros(model.cfg.num_layers, np.int64)   # positions
+    by_layer = np.zeros(len(sparse), np.int64)            # positions
     swapped = 0                                           # experts
+    first, held = model.cfg.moe_first_expert, model.cfg.held_experts
+    beyond = beyond_here = differ_here = 0   # positions past REF_TOL
+    has_edges = "edges" in inspect.signature(ref.logits).parameters
+    swapped_at = []    # positions with a held expert swapped: the least
+    # distance of the reference's held experts from its edge, over layers
+    by_gap = {g: [0, 0, 0] for g in GAPS}   # positions: excused; of the
+    # others: beyond REF_TOL, with a held expert swapped
     for i, b in enumerate(sample):
-        routing = []
-        want = np.asarray(ref.logits(weights, seqs[b], cfg, routing))
+        routing, edges = [], []
+        want = np.asarray(ref.logits(
+            weights, seqs[b], cfg, routing,
+            **({"edges": edges} if has_edges else {})))
         routing = np.stack([np.asarray(r) for r in routing])   # [L, T, k]
         gap = np.abs(served[i] - want).max(axis=-1)            # [T]
         # experts the served top-k holds and the reference's does not
@@ -148,16 +290,38 @@ def main() -> int:
             [len(set(picked[t][i][layer]) - set(routing[layer, t]))
              for layer in range(routing.shape[0])] for t in range(T)])
         same = off.sum(axis=1) == 0
+        # ... and positions where a swapped expert (in or out) is HELD here:
+        # only those change what this rank computes
+        here = np.asarray([any(
+            first <= e < first + held
+            for layer in range(routing.shape[0])
+            for e in set(picked[t][i][layer]) ^ set(routing[layer, t]))
+            for t in range(T)])
         by_layer += (off > 0).sum(axis=0)
         swapped += int(off.sum())
         top = want.max(axis=-1)
         got = np.take_along_axis(
             want, served[i].argmax(axis=-1)[:, None], axis=-1)[:, 0]
+        if has_edges:
+            edges = np.stack([np.asarray(e) for e in edges])    # [L, T]
+            here_at = np.asarray([[any(
+                first <= e < first + held
+                for e in set(picked[t][i][layer]) ^ set(routing[layer, t]))
+                for t in range(T)] for layer in range(routing.shape[0])])
+            swapped_at.extend(edges.min(axis=0)[here_at.any(axis=0)].tolist())
+            for g, row in by_gap.items():
+                excused = edges.min(axis=0) < g
+                row[0] += int(excused.sum())
+                row[1] += int(((top - got > REF_TOL) & ~excused).sum())
+                row[2] += int((here_at.any(axis=0) & ~excused).sum())
         positions += T
         differ += int((~same).sum())
         worst = max(worst, float(gap.max()))
         worst_same = max(worst_same, float(gap[same].max(initial=0.0)))
         margin = max(margin, float((top - got).max()))
+        beyond += int((top - got > REF_TOL).sum())
+        beyond_here += int(((top - got > REF_TOL) & here).sum())
+        differ_here += int(here.sum())
         print(f"sequence {b}: worst gap {gap.max():.4f} (prefill "
               f"{gap[:P].max():.4f}, decode {gap[P:].max():.4f}); top-k sets "
               f"differ at {int((~same).sum())} of {T} positions; logits' "
@@ -172,7 +336,23 @@ def main() -> int:
           f"gap {worst:.4f}; top-k expert sets differ in some layer at "
           f"{differ} positions; worst gap without them {worst_same:.4f}; "
           f"served argmax below the reference's top-1 by at most "
-          f"{margin:.4f} (run.py's REF_TOL {REF_TOL})", flush=True)
+          f"{margin:.4f} (run.py's REF_TOL {REF_TOL}); beyond it at "
+          f"{beyond} positions ({100.0 * beyond / positions:.3f}%), "
+          f"{beyond_here} of them among the {differ_here} where a swapped "
+          "expert is held here", flush=True)
+    if swapped_at:
+        q = np.quantile(swapped_at, [0.5, 0.9, 0.99, 1.0])
+        print(f"undecided: at the {len(swapped_at)} positions where a swapped "
+              "expert is held here, the reference's held experts lay this "
+              f"near the chosen set's edge in some layer: median {q[0]:.5f}, "
+              f"p90 {q[1]:.5f}, p99 {q[2]:.5f}, most {q[3]:.5f} (a gap above "
+              "the most excuses them all)", flush=True)
+    for g, (excused, left, swaps) in by_gap.items():
+        if has_edges:
+            print(f"undecided: gap {g:.5f}: {excused} of {positions} "
+                  f"positions excused ({100.0 * excused / positions:.1f}%); "
+                  f"of the others {left} beyond REF_TOL and {swaps} with a "
+                  "swapped expert held here", flush=True)
     return 0
 
 

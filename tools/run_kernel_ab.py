@@ -65,7 +65,15 @@ PAGED_GEOMETRIES = [
     ("mistral-7b-v0.3-1chip", 16, 160, 8, 32, 32, 8, 128, False),
     ("olmoe-1b-7b-1chip", 12, 256, 32, 8, 16, 16, 128, False),
     ("gpt2-medium-int8kv", 24, 128, 16, 8, 16, 16, 64, True),
+    # the same pool read by a full layer's call and by a sliding layer's
+    # (SLIDING below: its grid is the window's table columns wide, flat in
+    # the slot's length)
+    ("k-exaone-236b-ep8-1chip", 5, 2048, 64, 32, 64, 8, 128, False),
+    ("k-exaone-236b-ep8-1chip-window128", 5, 2048, 64, 32, 64, 8, 128,
+     False),
 ]
+# Geometries whose every call is a sliding layer's, and their window.
+SLIDING = {"k-exaone-236b-ep8-1chip-window128": 128}
 LIVE_SHARES = (0.125, 0.5, 1.0)
 PAGE = 128
 
@@ -100,9 +108,10 @@ def step_costs_us(rows):
                      / least["dead_steps"])
 
 
-def _time_paged(tag, L, P, B, NP, N, K, H, int8, iters):
+def _time_paged(tag, L, P, B, NP, N, K, H, int8, iters, sliding=0):
     """Rows (one a live share) of the paged kernel's time a call, with
-    its worst gap to the gather path on the same inputs."""
+    its worst gap to the gather path on the same inputs. ``sliding``: every
+    call is a sliding layer's, over that window."""
     import jax
     import jax.numpy as jnp
 
@@ -111,6 +120,7 @@ def _time_paged(tag, L, P, B, NP, N, K, H, int8, iters):
         quantize_kv_rows,
     )
     from ray_dynamic_batching_tpu.ops import attention as attn
+    from ray_dynamic_batching_tpu.ops import tile_math
     from ray_dynamic_batching_tpu.ops.decode_attention import (
         _pick_heads_block,
     )
@@ -137,7 +147,8 @@ def _time_paged(tag, L, P, B, NP, N, K, H, int8, iters):
                     q, k, v, page_table=table, kv_lengths=lengths,
                     layer=layer,
                     k_scale=None if ks is None else ks[layer],
-                    v_scale=None if vs is None else vs[layer])
+                    v_scale=None if vs is None else vs[layer],
+                    sliding=sliding)
             return q
         return jax.jit(run)
 
@@ -168,12 +179,16 @@ def _time_paged(tag, L, P, B, NP, N, K, H, int8, iters):
                 res.block_until_ready()
                 samples.append(
                     (time.perf_counter() - t0) * 1e6 / (iters * L))
+            walked = tile_math.window_table_width(sliding, 1, PAGE, NP)
+            if sliding:    # the window's columns that hold a position
+                live = int((live - tile_math.window_first_page(
+                    lengths, sliding, PAGE)).sum()) / B
             rows.append({
                 "geometry": tag, "live_share": share,
                 "call_us": statistics.median(samples),
                 "call_us_min_max": [min(samples), max(samples)],
-                "live_steps": B * blocks * live,
-                "dead_steps": B * blocks * (NP - live),
+                "live_steps": round(B * blocks * live),
+                "dead_steps": round(B * blocks * (walked - live)),
                 "max_abs_diff": float(jnp.max(jnp.abs(
                     out.astype(jnp.float32) - ref.astype(jnp.float32)))),
             })
@@ -196,14 +211,17 @@ def paged_main(out_dir: str, out_name: str, iters: int, only) -> int:
     ok = True
     for g in geometries:
         try:
-            rows = _time_paged(*g, iters)
+            rows = _time_paged(*g, iters, SLIDING.get(g[0], 0))
         except Exception as exc:  # noqa: BLE001
             ok = False
             record["geometries"].append(
                 {"geometry": g[0], "error": repr(exc)[:500]})
             print(f"{g[0]}: FAILED {exc!r}", file=sys.stderr, flush=True)
             continue
-        live_us, dead_us = step_costs_us(rows)
+        # a sliding call's grid is as wide at every length: nothing to
+        # tell a live step's cost from a dead one's by
+        live_us, dead_us = ((None, None) if g[0] in SLIDING
+                            else step_costs_us(rows))
         record["geometries"].append({
             "geometry": g[0], "rows": rows,
             "live_step_us": live_us, "dead_step_us": dead_us})
@@ -213,8 +231,9 @@ def paged_main(out_dir: str, out_name: str, iters: int, only) -> int:
                   f"({r['live_steps']} live + {r['dead_steps']} dead "
                   f"steps), max |kernel - gather| "
                   f"{r['max_abs_diff']:.2e}", flush=True)
-        print(f"{g[0]}: a live grid step {live_us:.3f} us, a dead one "
-              f"{dead_us:.3f} us", flush=True)
+        if live_us is not None:
+            print(f"{g[0]}: a live grid step {live_us:.3f} us, a dead one "
+                  f"{dead_us:.3f} us", flush=True)
         ok = ok and all(r["max_abs_diff"] < 0.1 for r in rows)
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, out_name), "w") as f:
