@@ -998,21 +998,18 @@ class DecodeEngine:
         return rec
 
     def _kv_pages_live(self, window: int = 1) -> int:
-        """Page-table entries, summed over all slots, that hold a position
-        the scan about to be dispatched may attend in its first substep:
-        the last row of a ``window`` attends positions <= length +
-        ``window`` - 1 (the paged kernel's own bound). A sliding layer's
-        scan starts at the column of its window's oldest position
-        (``tile_math.window_first_page``, the kernel's own rule); where
+        """Page-table entries, summed over all slots, that the scan about
+        to be dispatched walks in its first substep: the kernel's own
+        loop bounds (``tile_math.live_pages``: up to the column of the
+        last position a ``window``'s last row attends, for a sliding
+        layer from the column of its window's oldest position); where
         layers differ the count is the mean over layers."""
-        last = np.minimum((self._len_host + (window - 1)) // self.page_size,
-                          self._n_table_entries - 1)
-        live = {0: int((last + 1).sum())}
-        for w in set(self._layer_windows) - {0}:
-            live[w] = int((last + 1 - tile_math.window_first_page(
-                self._len_host, w, self.page_size)).sum())
+        live = {w: int(tile_math.live_pages(
+            self._len_host, window, w, self.page_size,
+            self._n_table_entries)[1].sum())
+            for w in set(self._layer_windows)}
         if len(live) == 1:
-            return live[0]
+            return live[self._layer_windows[0]]
         return sum(live[w] for w in self._layer_windows) / len(
             self._layer_windows)
 

@@ -15,9 +15,8 @@ costs on that scan (``ops/attention.py::_xla_attention``):
   VMEM.
 
 This kernel fuses the scan FlashAttention-style over a grid
-(B, K // kb, S // Sb) — the paged kernel's (B, K // kb, NP), a page a
-tile —: each program instance owns one slot's block of ``kb`` KV heads
-for one [Sb] KV tile. The S grid axis IS the KV tiling:
+(B, K // kb, S // Sb): each program instance owns one slot's block of
+``kb`` KV heads for one [Sb] KV tile. The S grid axis IS the KV tiling:
 TPU grid steps run sequentially with the innermost axis fastest, so the
 online-softmax state (m, l, acc) lives in VMEM scratch carried across
 the S steps of each (slot, head-block) — initialized at s == 0,
@@ -27,9 +26,21 @@ next tile's HBM->VMEM copy behind the current tile's compute. Every
 G = N/K query heads sharing a KV head ride the same read) — GQA via
 layout, no repeat, any capacity.
 
-The body of a grid step (``_accumulate_tile``, shared by the slab and
-the paged kernel) takes one of two forms, chosen from the tile's static
-shape alone (``tile_math.flat_heads``):
+The PAGED kernel (``_paged_decode_attention``) has no tile axis in its
+grid, which is (B, K // kb): a slot's pages are walked by a LOOP inside
+the step, over the table columns its length makes live
+(``tile_math.live_pages``), a page a tile, each copied by the kernel
+itself out of the pool in HBM into a small VMEM ring that runs on from
+one step into the next. A table entry past the length, or behind a
+sliding layer's window, costs nothing: no step, no copy, no arithmetic.
+Measured on a v5e (PERF.md, PR 33): a live page 0.69 us against its
+0.64 us copy, a step about nothing beyond its pages, where the grid
+that walked the whole table paid 0.85 us a live step and 0.27 a dead
+one.
+
+The fold of one tile (``_accumulate_tile``, shared by the slab kernel's
+grid step and the paged kernel's loop) takes one of two forms, chosen
+from the tile's static shape alone (``tile_math.flat_heads``):
 
 - **flat heads** (a block of 8 KV heads; score tiles within
   ``tile_math.FLAT_SCORE_MAX_BYTES``): the tile arrives as [Sb, kb, H],
@@ -43,7 +54,7 @@ shape alone (``tile_math.flat_heads``):
   step that is memory-bound anyway; what the body no longer pays for
   is eight sublane-strided head slices, sixteen one-to-four-row dots
   and two dozen small scratch updates a step. Measured on a v5e
-  (PERF.md, PR 31): a live step of the paged kernel 1.75 -> 0.85 us
+  (PERF.md, PR 31): a live page of the paged kernel 1.75 -> 0.85 us
   against a 0.64 us copy, and cheaper than the per-head form at every
   row count tried (1 to 32 a head).
 - **per head** (K < 8, or tiles past the cap): each head's [Sb, H]
@@ -114,6 +125,7 @@ MAX_WINDOW_FOR_KERNEL = 8
 
 FORM_FLAT = "flat heads"
 FORM_PER_HEAD = "per head"
+WALK_LOOP = "a loop over the live pages"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -122,7 +134,7 @@ class DecodePath:
     traced (as ``ops/moe.py::moe_paths`` records the expert path)."""
 
     program: str     # compile-ledger program ("" outside one)
-    kb: int          # KV heads a grid step (a TP shard's block)
+    kb: int          # KV heads a tile (a TP shard's block)
     rows: int        # query rows a head: window * G
     page_size: int
     head_dim: int    # the pool's row width (lane-padded)
@@ -130,12 +142,15 @@ class DecodePath:
     form: str        # FORM_*
     why: str
     sliding: int = 0      # a sliding layer's window (0: a full layer)
-    table_width: int = 0  # page-table columns the grid walks a slot
+    table_width: int = 0  # page-table columns a slot's walk can reach
+    walk: str = ""        # WALK_*: how the pages of a slot are walked
+    depth: int = 0        # pages in the walk's VMEM ring
 
     def describe(self) -> str:
         return (f"{self.kb} heads x {self.rows} rows over "
                 f"[{self.page_size}, {self.kb}, {self.head_dim}] "
                 f"{self.kv_dtype} pages -> {self.form} ({self.why}); "
+                f"{self.walk}, a ring of {self.depth}, of "
                 + (f"window {self.sliding}: the " if self.sliding else "")
                 + f"{self.table_width} table columns a slot")
 
@@ -154,7 +169,7 @@ def clear_decode_paths() -> None:
 
 
 def _record_path(kb: int, rows: int, ps: int, H: int, dtype,
-                 sliding: int, table_width: int) -> None:
+                 sliding: int, table_width: int, depth: int) -> None:
     if tile_math.flat_heads(kb, rows, ps):
         form, why = FORM_FLAT, (
             f"{kb * rows} rows x {ps * kb} columns in one contraction")
@@ -168,7 +183,8 @@ def _record_path(kb: int, rows: int, ps: int, H: int, dtype,
     _PATHS.append(DecodePath(
         program=current_program(), kb=kb, rows=rows, page_size=ps,
         head_dim=H, kv_dtype=str(jnp.dtype(dtype)), form=form, why=why,
-        sliding=sliding, table_width=table_width))
+        sliding=sliding, table_width=table_width, walk=WALK_LOOP,
+        depth=depth))
 
 
 def _window_rows(mask_ref, rows: int, R: int, window: int):
@@ -228,48 +244,44 @@ def _scan_tile(
     q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref,
     *, valid, scale: float, num_s: int,
 ):
-    """One KV tile of the online-softmax scan: init scratch at tile 0
-    (:func:`_scan_begin`), accumulate this tile
+    """One KV tile of the slab kernel's online-softmax scan: init scratch
+    at tile 0 (:func:`_scan_begin`), accumulate this tile
     (:func:`_accumulate_tile`), finalize into the output on the last
-    tile (:func:`_scan_end`). The slab kernel (S-axis tiles,
-    mask-derived ``valid``) runs it whole on every tile; the paged
-    kernel (page-table tiles, length-derived ``valid``) calls the same
-    three pieces and guards the middle one by the slot's length. The
+    tile (:func:`_scan_end`). The paged kernel calls the same three
+    pieces round a loop of its own: begin, a fold a live page, end. The
     math being ONE function is what keeps the paged and slab kernels
     numerically identical."""
-    _scan_begin(m_ref, l_ref, acc_ref)
+    pl.when(pl.program_id(2) == 0)(
+        lambda: _scan_begin(m_ref, l_ref, acc_ref))
     _accumulate_tile(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref,
                      acc_ref, valid=valid, scale=scale)
-    _scan_end(o_ref, m_ref, l_ref, acc_ref, num_s=num_s)
+    pl.when(pl.program_id(2) == num_s - 1)(
+        lambda: _scan_end(o_ref, m_ref, l_ref, acc_ref))
 
 
 def _scan_begin(m_ref, l_ref, acc_ref):
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
 
-def _scan_end(o_ref, m_ref, l_ref, acc_ref, *, num_s: int):
-    @pl.when(pl.program_id(2) == num_s - 1)
-    def _finalize():
-        # A fully-masked row (inactive spec rows are steered out of
-        # bounds; their outputs are never consumed) -> zeros, not NaN.
-        if l_ref.shape[1] == 1:     # flat heads: [kb * R, 1] (and the
-            # per-head [kb, R] at R == 1, the same layout and division)
-            l = l_ref[...]
-            o_ref[0, :, :] = (
-                acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
-            ).astype(o_ref.dtype)
-            return
-        kb, R = l_ref.shape         # per head: [kb, R]
-        for h in range(kb):
-            l = l_ref[h, :]
-            l = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0, h * R:(h + 1) * R, :] = (
-                acc_ref[h * R:(h + 1) * R, :] / l[:, None]
-            ).astype(o_ref.dtype)
+def _scan_end(o_ref, m_ref, l_ref, acc_ref):
+    # A fully-masked row (inactive spec rows are steered out of
+    # bounds; their outputs are never consumed) -> zeros, not NaN.
+    if l_ref.shape[1] == 1:     # flat heads: [kb * R, 1] (and the
+        # per-head [kb, R] at R == 1, the same layout and division)
+        l = l_ref[...]
+        o_ref[0, :, :] = (
+            acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+        ).astype(o_ref.dtype)
+        return
+    kb, R = l_ref.shape         # per head: [kb, R]
+    for h in range(kb):
+        l = l_ref[h, :]
+        l = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0, h * R:(h + 1) * R, :] = (
+            acc_ref[h * R:(h + 1) * R, :] / l[:, None]
+        ).astype(o_ref.dtype)
 
 
 def _softmax_fold(s, v, vs, m_prev, l_prev, acc_prev):
@@ -297,7 +309,8 @@ def _accumulate_tile(
     q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref, acc_ref,
     *, valid, scale: float,
 ):
-    """Fold this grid step's KV tile [Sb, kb, H] into the online-softmax
+    """Fold one KV tile [Sb, kb, H] (the slab kernel's grid step, a page
+    of the paged kernel's loop) into the online-softmax
     scratch of the block's kb * R query rows. One algorithm in the form
     its shapes allow (``tile_math.flat_heads``):
 
@@ -532,8 +545,9 @@ def window_table(page_table: jax.Array, lengths: jax.Array, sliding: int,
     logical page ``[B]`` of its first column
     (``tile_math.window_first_page``). Columns past the table's end repeat
     its last entry: their positions lie past the capacity, where nothing
-    is attended. The paged kernel's grid and the gather fallback's view
-    are both this wide."""
+    is attended. The gather fallback's view (a chunk's rows), this wide;
+    the paged kernel walks the same columns from the table itself
+    (``tile_math.live_pages``)."""
     n_entries = page_table.shape[1]
     width = tile_math.window_table_width(sliding, rows, page_size, n_entries)
     first = tile_math.window_first_page(
@@ -564,106 +578,108 @@ def _paged_decode_attention(
     B, K, R, H = q.shape
     G = R // window
     P, ps = k.shape[1], k.shape[2]
-    capacity = page_table.shape[1] * ps
+    NP = page_table.shape[1]
     kb = _pick_heads_block(K)
+    nj = K // kb
+    steps = B * nj
     has_scales = k_scale is not None
     flat = tile_math.flat_heads(kb, R, ps)
-    # A SLIDING layer (row t attends the last ``sliding`` positions up to
-    # its own) is handed the table columns its window covers, not the
-    # table: column ``first`` holds the oldest position the first row
-    # sees, and ``NP`` columns from there cover every row's window
-    # (``tile_math``'s two window functions). The grid is NP wide whatever
-    # the slot's length: pages wholly behind the window are not walked.
-    # ``first`` is prefetched with the lengths; step p is logical page
-    # ``first[b] + p``. A full layer has no such operand and its program is
-    # the one it was.
-    first = None
-    if sliding:
-        page_table, first = window_table(
-            page_table, lengths, sliding, window, ps)
-    NP = page_table.shape[1]
+    depth = tile_math.paged_walk_depth(
+        ps, kb, H, k.dtype.itemsize, has_scales, window, G)
+    ahead = depth - 1
 
-    # The page axis IS the KV tiling: grid step (b, j, p) streams slot
-    # b's p-th page — whichever physical page the PREFETCHED table names.
-    # Pages replace the slab kernel's S-axis tiles one-for-one, so the
-    # online-softmax scratch carry works unchanged. The LAYER is the
-    # block index of a squeezed leading axis, prefetched like the table:
-    # the operand is the pool itself, so XLA has no layer slice to
-    # materialise in front of the custom call, and the refs the body
-    # sees keep their [1, ps, kb, H] shape.
+    # The grid is (slot, head block) and nothing else: the PAGES of a slot
+    # are a loop inside the step, over the table columns
+    # ``tile_math.live_pages`` names from the prefetched length (a full
+    # layer: column 0 to the last window row's; a sliding layer: from its
+    # window's oldest position), so an entry past the length or behind
+    # the window is never read: no step, no copy, no arithmetic, whatever
+    # it holds (the sentinel, a page reserved ahead of the length, a freed
+    # page's NaN). The pool stays in HBM, WHOLE (the layer is a prefetched
+    # scalar, so XLA has no layer slice to materialise in front of the
+    # call), and the kernel copies each live page's [ps, kb, H] K and V
+    # tiles (and the int8 pool's scale rows) itself into a ring of
+    # ``depth`` scratch slots, ``ahead`` copies in flight behind the page
+    # being folded.
     #
-    # The scan STOPS at the slot's length: the last window row attends
-    # pos <= lengths[b] + window - 1, so a page that starts past that is
-    # dead — its step repeats the last live page's block index (Pallas
-    # issues no copy for a repeated block, whatever the table holds
-    # there: the sentinel, or a page allocated ahead of the length) and
-    # skips the arithmetic (``_live_page`` below). Position 0 is always
-    # within the bound, so page 0 is always live. A sentinel/garbage
-    # entry of a live step (an idle slot's page 0) still clamps to a
-    # real page; the length bound masks everything it could contribute.
-    def last_position(b, ln):
-        # the last position any row attends (a sliding layer's columns may
-        # run past the table's end: nothing lives there)
-        last = ln[b] + (window - 1)
-        return jnp.minimum(last, capacity - 1) if sliding else last
+    # The ring does not stop at a step's edge. The live pages of all
+    # (slot, head block) steps are ONE stream, item n in ring slot
+    # n % depth; each fold first starts the copy of the item ``ahead``
+    # places on, which near a step's end is the NEXT step's first page
+    # (its slot, head block and column worked out from the same
+    # prefetched tables), so a step finds its first page arriving and not
+    # a cold copy's latency. Both grid axes are sequential for that. The
+    # cursor (the step and page of the next item to start) and the
+    # stream index of this step's first item ride in SMEM across steps.
+    def bounds(b, len_ref):
+        return tile_math.live_pages(len_ref[b], window, sliding, ps, NP)
 
-    def page_index(b, p, pt, ln, *fp):
-        last_live = last_position(b, ln) // ps
-        if sliding:
-            last_live = last_live - fp[0][b]
-        return jnp.minimum(pt[b, jnp.minimum(p, last_live)], P - 1)
+    def kernel(pt_ref, len_ref, ly_ref, q_ref, k_hbm, v_hbm, *rest):
+        ks_hbm = vs_hbm = ks_buf = vs_buf = None
+        if has_scales:
+            ks_hbm, vs_hbm, *rest = rest
+        o_ref, k_buf, v_buf, *rest = rest
+        if has_scales:
+            ks_buf, vs_buf, *rest = rest
+        sem, cur, m_ref, l_ref, acc_ref = rest
+        s = pl.program_id(0) * nj + pl.program_id(1)
 
-    def kv_index(b, j, p, pt, ln, ly, *fp):
-        return (ly[0], page_index(b, p, pt, ln, *fp), 0, j, 0)
+        def copies(t, page, slot):
+            """The copies of step ``t``'s table column ``page`` into ring
+            slot ``slot`` (a sentinel or garbage entry clamps to a real
+            page; only an idle slot's column 0 can hold one, and the
+            length bound masks everything it could contribute)."""
+            b, j = (t, 0) if nj == 1 else (t // nj, t % nj)
+            phys = jnp.minimum(pt_ref[b, page], P - 1)
+            heads = (slice(None) if nj == 1
+                     else pl.ds(pl.multiple_of(j * kb, kb), kb))
+            pairs = [(hbm.at[ly_ref[0], pl.ds(phys, 1), :, heads, :], buf)
+                     for hbm, buf in ((k_hbm, k_buf), (v_hbm, v_buf))]
+            if has_scales and flat:
+                pairs += [(hbm.at[pl.ds(phys, 1), pl.ds(j, 1)], buf)
+                          for hbm, buf in ((ks_hbm, ks_buf),
+                                           (vs_hbm, vs_buf))]
+            elif has_scales:
+                pairs += [(hbm.at[pl.ds(phys, 1), heads, :], buf)
+                          for hbm, buf in ((ks_hbm, ks_buf),
+                                           (vs_hbm, vs_buf))]
+            return [pltpu.make_async_copy(
+                src, buf.at[pl.ds(slot, 1)], sem.at[n, slot])
+                for n, (src, buf) in enumerate(pairs)]
 
-    def rows_index(b, j, p, pt, ln, ly, *fp):
-        return (b, j, 0)
+        def start_next(t, i, slot):
+            """Start the copy of the cursor's item (step ``t``, its
+            ``i``-th live page) if there is one, and move the cursor on."""
+            t_in = jnp.minimum(t, steps - 1)
+            first, count = bounds(t_in if nj == 1 else t_in // nj, len_ref)
 
-    rows_spec = pl.BlockSpec((1, kb * R, H), rows_index)
-    in_specs = [
-        rows_spec,
-        pl.BlockSpec((None, 1, ps, kb, H), kv_index),
-        pl.BlockSpec((None, 1, ps, kb, H), kv_index),
-    ]
-    # A head block's rows are one contiguous [kb * R, H] tile (free: the
-    # same bytes).
-    args = [q.reshape(B, K * R, H), k, v]
-    if has_scales and flat:
-        # A page's scales as ONE lane row in the flat column order.
-        scale_spec = _flat_scale_spec(
-            ps * kb, lambda b, j, p, pt, ln, ly, *fp: (
-                page_index(b, p, pt, ln, *fp), j, 0, 0, 0))
-        in_specs += [scale_spec, scale_spec]
-        args += [_flat_columns(k_scale, kb, ps),
-                 _flat_columns(v_scale, kb, ps)]
-    elif has_scales:
-        # [P, ps, K] -> [P, K, ps]: the page becomes the (lane) trailing
-        # dim of the scale tile — pad-free because pages are lane-aligned
-        # (the [B, S, K, 1]-layout ~128x blowup documented on the slab
-        # path is the same trap this transpose avoids).
-        scale_spec = pl.BlockSpec(
-            (1, kb, ps),
-            lambda b, j, p, pt, ln, ly, *fp: (
-                page_index(b, p, pt, ln, *fp), j, 0),
-        )
-        in_specs += [scale_spec, scale_spec]
-        args += [k_scale.transpose(0, 2, 1), v_scale.transpose(0, 2, 1)]
+            @pl.when(t < steps)
+            def _start():
+                for c in copies(t_in, first + i, slot):
+                    c.start()
 
-    def kernel(pt_ref, len_ref, ly_ref, *rest):
-        first_ref = None
-        if sliding:
-            first_ref, *rest = rest
-        q_ref, k_ref, v_ref, *rest = rest
-        ks_ref = rest[0] if has_scales else None
-        vs_ref = rest[1] if has_scales else None
-        o_ref, m_ref, l_ref, acc_ref = rest[2 if has_scales else 0:][:4]
+            roll = i + 1 >= count
+            return (jnp.where(roll, t + 1, t),
+                    jnp.where(roll, 0, i + 1))
+
+        @pl.when(s == 0)
+        def _stream_begins():
+            cursor = (jnp.int32(0), jnp.int32(0))
+            for n in range(ahead):
+                cursor = start_next(*cursor, n)
+            cur[0] = 0
+            cur[1], cur[2] = cursor
+
         b = pl.program_id(0)
-        p = pl.program_id(2)
-        page = p + first_ref[b] if sliding else p   # the logical page
+        first, count = bounds(b, len_ref)
+        base = cur[0]
         _scan_begin(m_ref, l_ref, acc_ref)
 
-        @pl.when(page * ps <= last_position(b, len_ref))
-        def _live_page():
+        def fold(i, cursor):
+            cursor = start_next(*cursor, (base + i + ahead) % depth)
+            page, slot = first + i, (base + i) % depth
+            for c in copies(s, page, slot):
+                c.wait()
             # In-kernel STAIRCASE validity from the prefetched lengths:
             # page p covers logical positions [p*ps, (p+1)*ps); window
             # row t (row r = t*G + g of its head) attends pos <=
@@ -681,28 +697,58 @@ def _paged_decode_attention(
             if sliding:
                 # the lower edge (``models/decoder.py::sliding_edge``)
                 valid = valid & (pos > bound - sliding)
+            tile = lambda buf: (
+                None if buf is None else buf.at[pl.ds(slot, 1)])
             _accumulate_tile(
-                q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref, acc_ref,
-                valid=valid, scale=scale,
+                q_ref, tile(k_buf), tile(v_buf), tile(ks_buf), tile(vs_buf),
+                m_ref, l_ref, acc_ref, valid=valid, scale=scale,
             )
+            return cursor
 
-        _scan_end(o_ref, m_ref, l_ref, acc_ref, num_s=NP)
+        cur[1], cur[2] = jax.lax.fori_loop(
+            0, count, fold, (cur[1], cur[2]))
+        cur[0] = (base + count) % depth
+        _scan_end(o_ref, m_ref, l_ref, acc_ref)
 
-    prefetch = [page_table, lengths, layer] + ([first] if sliding else [])
+    rows_spec = pl.BlockSpec(
+        (1, kb * R, H), lambda b, j, pt, ln, ly: (b, j, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    # A head block's rows are one contiguous [kb * R, H] tile (free: the
+    # same bytes).
+    args = [q.reshape(B, K * R, H), k, v]
+    ring = [pltpu.VMEM((depth, ps, kb, H), k.dtype)] * 2
+    if has_scales and flat:
+        # A page's scales as ONE lane row in the flat column order.
+        args += [_flat_columns(k_scale, kb, ps),
+                 _flat_columns(v_scale, kb, ps)]
+        ring += [pltpu.VMEM((depth, 1, 1, 1, ps * kb), jnp.float32)] * 2
+    elif has_scales:
+        # [P, ps, K] -> [P, K, ps]: the page becomes the (lane) trailing
+        # dim of the scale tile — pad-free because pages are lane-aligned
+        # (the [B, S, K, 1]-layout ~128x blowup documented on the slab
+        # path is the same trap this transpose avoids).
+        args += [k_scale.transpose(0, 2, 1), v_scale.transpose(0, 2, 1)]
+        ring += [pltpu.VMEM((depth, kb, ps), jnp.float32)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
-        grid=(B, K // kb, NP),
-        in_specs=in_specs,
+        num_scalar_prefetch=3,
+        grid=(B, nj),
+        in_specs=[rows_spec] + [in_hbm] * (len(args) - 1),
         out_specs=rows_spec,
-        scratch_shapes=_scratch(kb, R, H, flat),
+        scratch_shapes=ring + [
+            pltpu.SemaphoreType.DMA((len(ring), depth)),
+            pltpu.SMEM((3,), jnp.int32),
+        ] + _scratch(kb, R, H, flat),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K * R, H), q.dtype),
-        compiler_params=_COMPILER_PARAMS,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
         interpret=interpret,
-    )(*prefetch, *args).reshape(B, K, R, H)
+    )(page_table, lengths, layer, *args).reshape(B, K, R, H)
 
 
 def paged_decode_attention(
@@ -738,24 +784,28 @@ def paged_decode_attention(
     t attends logical positions <= kv_lengths[b] + t — the STAIRCASE
     rule of the speculative-verify window
     (``models/decoder.py::paged_window_mask`` owns it), whose Tq == 1
-    case is exactly the plain-decode ``decode_mask`` bound. The scan
-    stops there: a table entry past ``(kv_lengths[b] + Tq - 1) // ps``
-    costs no copy and no arithmetic whatever it holds, so a slot's scan
-    costs what its live KV costs, not what the table is wide.
+    case is exactly the plain-decode ``decode_mask`` bound. The scan is
+    a loop over the slot's LIVE pages and stops there: a table entry
+    past ``(kv_lengths[b] + Tq - 1) // ps`` is never visited, whatever it
+    holds, so a slot's scan costs what its live KV costs, not what the
+    table is wide.
     ``k_scale``/``v_scale`` [P, ps, K] (this layer's planes, H times
     smaller than the codes) enable the int8-pool path. ``sliding`` > 0 is
     a sliding-window layer: row t attends the last ``sliding`` of those
-    positions only, and the scan is bounded from BELOW too — its grid
-    walks the ``tile_math.window_table_width`` table columns the window
-    covers (2 for a window of one page), so it costs what the window
-    costs, flat in the slot's length.
+    positions only, and the loop is bounded from BELOW too — it starts
+    at the column of the window's oldest position
+    (``tile_math.live_pages``: at most ``window_table_width`` columns, 2
+    for a window of one page), so it costs what the window costs, flat
+    in the slot's length.
 
     Eligibility is the lane-alignment + VMEM-budget contract of
-    ``ops/tile_math.py``: the page IS the KV tile, so its streamed
-    footprint (``paged_tile_bytes``) must fit the shared budget
-    double-buffered, and the page size must be a 128-lane multiple (the
-    int8 scale tile's lane dim is the page). The static ``vmem-budget``
-    lint rule re-evaluates this same model over the BlockSpecs above.
+    ``ops/tile_math.py``: the page IS the KV tile, so the least ring of
+    them the walk needs (``paged_tile_bytes``: a page folding, a page
+    arriving) must fit the shared budget (the ring it takes,
+    ``paged_walk_depth``, is the deepest up to three that does), and
+    the page size must be a 128-lane multiple (the int8 scale tile's
+    lane dim is the page). The static ``vmem-budget`` lint rule holds
+    the call's scratch to the same budget.
 
     ``mesh`` (a TP serving slice; ROADMAP item 2) runs the SAME kernel
     per shard under ``shard_map`` over ``mesh_axis``: q and the pools
@@ -830,7 +880,10 @@ def paged_decode_attention(
     interpret = resolve_interpret(interpret)
     _record_path(kb, Tq * G, ps, Hk, k.dtype, int(sliding),
                  tile_math.window_table_width(
-                     int(sliding), Tq, ps, page_table.shape[1]))
+                     int(sliding), Tq, ps, page_table.shape[1]),
+                 tile_math.paged_walk_depth(
+                     ps, kb, Hk, k.dtype.itemsize, k_scale is not None,
+                     Tq, G))
     scale = scale if scale is not None else H ** -0.5
     # Rows ordered (t, g) per kv head: [B, Tq, K, G, H] ->
     # [B, K, Tq*G, H] (Tq == 1 collapses to the historical layout),

@@ -16,7 +16,8 @@ The model (Mosaic's VMEM tiling rules):
 - its LANE (last) dim pads up to a multiple of 128;
 - leading dims multiply unpadded;
 - Pallas double-buffers streamed blocks (``DOUBLE_BUFFER``), so the
-  in-flight footprint of a grid step is twice the padded block sum.
+  in-flight footprint of a grid step is twice the padded block sum (the
+  paged kernel copies its pages itself: ``paged_walk_depth`` of them).
 
 Deliberately dependency-free (no jax import): the linter loads this
 module standalone so ``python -m tools.lint`` stays fast and runs in
@@ -171,7 +172,16 @@ def flat_score_bytes(sb: int, kb: int, rows: int) -> int:
     return _flat_score_bytes(sb, kb, rows) if flat_heads(kb, rows, sb) else 0
 
 
-def paged_tile_bytes(
+# The most pages the paged kernel's walk holds in VMEM at once: the one
+# being folded and the copies in flight behind it. Measured on a v5e
+# (PERF.md, PR 33): a ring of two, a grid pipeline's double buffer, keeps
+# ONE copy in flight and leaves the DMA's issue latency in every page
+# (0.82 us a live page against a 0.64 us copy, what the grid-walk kernel's
+# live step cost too); a ring of three reads 0.69; four reads the same.
+PAGED_WALK_MAX_DEPTH = 3
+
+
+def paged_walk_depth(
     page_size: int,
     kb: int,
     H: int,
@@ -180,10 +190,37 @@ def paged_tile_bytes(
     window: int = 1,
     G: int = 1,
 ) -> int:
-    """Double-buffered VMEM footprint of one PAGED decode-attention grid
-    step's streamed blocks — the model the paged kernel's runtime guard
-    budgets against and the static ``vmem-budget`` checker re-evaluates
-    (the paged analogue of :func:`decode_tile_bytes`):
+    """Pages of K and V (and their scale rows) the paged kernel's page
+    walk keeps in its own VMEM scratch: the most, up to
+    ``PAGED_WALK_MAX_DEPTH``, that :func:`paged_tile_bytes` prices within
+    ``VMEM_BLOCK_BUDGET_BYTES``, and never fewer than ``DOUBLE_BUFFER``
+    (a page folding, a page arriving: where even that does not fit, the
+    kernel's guard declines)."""
+    for depth in range(PAGED_WALK_MAX_DEPTH, DOUBLE_BUFFER, -1):
+        if paged_tile_bytes(page_size, kb, H, kv_itemsize, with_scales,
+                            window, G, depth) <= VMEM_BLOCK_BUDGET_BYTES:
+            return depth
+    return DOUBLE_BUFFER
+
+
+def paged_tile_bytes(
+    page_size: int,
+    kb: int,
+    H: int,
+    kv_itemsize: int,
+    with_scales: bool = False,
+    window: int = 1,
+    G: int = 1,
+    depth: int = DOUBLE_BUFFER,
+) -> int:
+    """VMEM footprint of one PAGED decode-attention grid step whose page
+    walk keeps a ring of ``depth`` pages in scratch it fills itself. The
+    default is the least ring (a page folding, a page arriving): what
+    must fit for the kernel to engage at all, so what its runtime guard
+    declines by; :func:`paged_walk_depth` is the ring it then takes. The
+    model the guard budgets against and the static ``vmem-budget``
+    checker holds the call to (the paged analogue of
+    :func:`decode_tile_bytes`):
 
     - K and V page tiles [1, page_size, kb, H] at the cache itemsize
       (trailing dims (kb, H), same padding story as the slab tile);
@@ -192,7 +229,7 @@ def paged_tile_bytes(
       form one [1, page_size * kb] lane row each;
     - the flat-heads form's two f32 score tiles
       (:func:`flat_score_bytes`; ``window`` rows x ``G`` decide the form,
-      :func:`flat_heads`), not streamed but as large as a page;
+      :func:`flat_heads`), one set whatever the ring, as large as a page;
     - NO mask tile: validity is computed in-kernel from the prefetched
       per-slot lengths, so the paged path streams no mask at all.
 
@@ -208,7 +245,7 @@ def paged_tile_bytes(
     kv = 2 * padded_block_bytes((1, page_size, kb, H), kv_itemsize)
     scale_shape = (1, 1, page_size * kb) if flat else (1, kb, page_size)
     scale_b = 2 * padded_block_bytes(scale_shape, 4) if with_scales else 0
-    total = DOUBLE_BUFFER * (kv + scale_b)
+    total = depth * (kv + scale_b)
     if window > 1:
         qo = 2 * padded_block_bytes((1, kb, rows, H), kv_itemsize)
         acc = padded_block_bytes((kb, rows, H), 4)  # f32 scratch, single
@@ -255,11 +292,12 @@ def decode_tile_bytes(
 
 def window_table_width(sliding: int, rows: int, page_size: int,
                        n_entries: int) -> int:
-    """Page-table columns a paged decode scan walks: all ``n_entries`` of
-    a full layer (``sliding`` 0); for a sliding layer the most pages that
-    the ``sliding + rows - 1`` positions its ``rows`` window rows attend
-    between them can touch (window 128 on 128-position pages, one row: 2).
-    Static: the kernel's grid is this wide whatever the slot's length."""
+    """Page-table columns a paged decode scan CAN walk: all ``n_entries``
+    of a full layer (``sliding`` 0); for a sliding layer the most pages
+    that the ``sliding + rows - 1`` positions its ``rows`` window rows
+    attend between them can touch (window 128 on 128-position pages, one
+    row: 2). Static: the most :func:`live_pages` counts at any length, the
+    gather fallback's view, the denominator of the engine's live share."""
     if sliding <= 0:
         return n_entries
     span = sliding + rows - 1
@@ -274,6 +312,32 @@ def window_first_page(lengths, sliding: int, page_size: int):
     test (ints) share the one rule."""
     oldest = lengths - (sliding - 1)
     return (oldest > 0) * (oldest // page_size)
+
+
+def _at_most(x, cap):
+    """``min(x, cap)`` in plain arithmetic (ints, numpy arrays and a
+    kernel's traced scalars alike)."""
+    return x - (x > cap) * (x - cap)
+
+
+def live_pages(lengths, rows: int, sliding: int, page_size: int,
+               n_entries: int):
+    """The page-table columns a paged decode scan walks for a slot of
+    ``lengths`` cached positions, as ``(first, count)``: the ONE rule of
+    the kernel's loop, the engine's ``kv_pages_live`` counter and the
+    tests. Window row t of ``rows`` attends positions <= ``lengths`` + t,
+    so the last column is that of position ``lengths + rows - 1``, held
+    inside the table's ``n_entries``; the first is column 0, or, for a
+    layer ``sliding`` over a window, :func:`window_first_page`'s. Position
+    0 is always within the bound: ``count`` >= 1 (an idle slot walks its
+    first page), and <= :func:`window_table_width`. Plain arithmetic, as
+    :func:`window_first_page` is."""
+    last = _at_most(lengths + (rows - 1),
+                    n_entries * page_size - 1) // page_size
+    if sliding <= 0:
+        return 0, last + 1
+    first = _at_most(window_first_page(lengths, sliding, page_size), last)
+    return first, last + 1 - first
 
 
 def moe_tile_cols(k_dim: int, n_dim: int, n_weights: int,

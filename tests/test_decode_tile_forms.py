@@ -339,3 +339,198 @@ class TestVmemModelFollowsTheBody:
         # ... while a 12-head block of the same page, scored per head,
         # is held to its streamed bytes alone.
         assert tile_math.flat_score_bytes(ps, 12, 4) == 0
+
+
+# --- the page walk: a loop over a slot's live pages (ISSUE 33) ----------------
+
+WALK_NP = 5     # table entries a slot in the walk's cases
+
+
+def walk_case(config, dtype, window, lens, seed=0):
+    """``make_case`` with a length a slot and a ``WALK_NP``-entry table:
+    every slot's pages its own, in a shuffled physical order, and one
+    spare page that no table names."""
+    g = GEOMETRIES[config]
+    N, K, H = g["N"], g["K"], g["H"]
+    B = len(lens)
+    rng = np.random.default_rng(seed)
+    P = B * WALK_NP + 1
+    q = jnp.asarray(rng.standard_normal((B, window, N, H)), jnp.bfloat16)
+    shape = (L, P, PS, K, pool_head_dim(H))
+    ks = vs = None
+    if dtype == jnp.int8:
+        k = rng.integers(-127, 127, shape).astype(np.int8)
+        v = rng.integers(-127, 127, shape).astype(np.int8)
+        ks = rng.uniform(0.01, 0.1, (L, P, PS, K)).astype(np.float32)
+        vs = rng.uniform(0.01, 0.1, (L, P, PS, K)).astype(np.float32)
+    else:
+        k = rng.standard_normal(shape).astype(np.float32)
+        v = rng.standard_normal(shape).astype(np.float32)
+    k[..., H:] = 0
+    v[..., H:] = 0
+    table = rng.permutation(B * WALK_NP).reshape(B, WALK_NP).astype(np.int32)
+    return q, k, v, ks, vs, table, np.asarray(lens, np.int32)
+
+
+def run_walk(q, k, v, ks, vs, table, lens, dtype, sliding=0, layer=L - 1):
+    out = da.paged_decode_attention(
+        q, jnp.asarray(k, dtype), jnp.asarray(v, dtype),
+        jnp.asarray(table), jnp.asarray(lens), layer=layer, interpret=True,
+        sliding=sliding,
+        k_scale=None if ks is None else jnp.asarray(ks[layer]),
+        v_scale=None if vs is None else jnp.asarray(vs[layer]))
+    assert out is not None, "paged kernel declined"
+    return np.asarray(out.astype(jnp.float32))
+
+
+def walk_reference(q, k, v, ks, vs, table, lens, window, sliding):
+    """XLA's softmax over the gathered rows under the model's own mask."""
+    H = q.shape[-1]
+    B = table.shape[0]
+    safe = np.minimum(table, k.shape[1] - 1)    # the sentinel clamps
+    rows = lambda pool: jnp.asarray(pool[L - 1][safe].reshape(
+        (B, WALK_NP * PS) + pool.shape[3:]), jnp.float32)
+    kg, vg = rows(k)[..., :H], rows(v)[..., :H]
+    if ks is not None:
+        kg = dequantize_kv(kg, rows(ks), jnp.float32)
+        vg = dequantize_kv(vg, rows(vs), jnp.float32)
+    return np.asarray(_xla_attention(
+        q.astype(jnp.float32), kg, vg, causal=False, scale=None,
+        mask=paged_window_mask(jnp.asarray(lens), WALK_NP * PS, window,
+                               sliding)))
+
+
+# Lengths at every edge of a page and of the table; a window of 128 lies
+# astride two pages, of 200 astride two or three, of 300 three or four.
+WALK_LENGTHS = [0, 1, PS - 1, PS, PS + 40, 2 * PS - 1, 2 * PS, 3 * PS + 7,
+                WALK_NP * PS - 5, WALK_NP * PS - 1]
+
+
+class TestWalkBounds:
+    """``tile_math.live_pages`` is the ONE rule of the kernel's loop, the
+    engine's ``kv_pages_live`` and the window's two older functions: its
+    columns are exactly the pages that hold a position some window row
+    attends under the model's own mask."""
+
+    @pytest.mark.parametrize("sliding", [0, 128, 200, 300])
+    @pytest.mark.parametrize("rows", [1, 5])
+    @pytest.mark.parametrize("length", WALK_LENGTHS)
+    def test_bounds_are_the_pages_the_mask_attends(
+            self, length, rows, sliding):
+        length = min(length, WALK_NP * PS - rows)   # the rows fit
+        first, count = tile_math.live_pages(
+            length, rows, sliding, PS, WALK_NP)
+        mask = np.asarray(paged_window_mask(
+            jnp.asarray([length]), WALK_NP * PS, rows, sliding))
+        pages = np.flatnonzero(
+            mask.reshape(rows, WALK_NP, PS).any(axis=(0, 2)))
+        assert (first, count) == (pages[0], len(pages))
+        assert (pages == np.arange(first, first + count)).all()
+        assert 1 <= count <= tile_math.window_table_width(
+            sliding, rows, PS, WALK_NP)
+        if sliding:
+            assert first == tile_math.window_first_page(length, sliding, PS)
+
+    @pytest.mark.parametrize("windows", [(0,), (128, 128, 0), (200, 0)],
+                             ids=["full", "LLG", "LG200"])
+    @pytest.mark.parametrize("rows", [1, 5])
+    def test_the_engines_counter_is_the_same_rule(self, rows, windows):
+        from types import SimpleNamespace
+
+        from ray_dynamic_batching_tpu.engine.decode import DecodeEngine
+
+        lens = np.minimum(np.asarray(WALK_LENGTHS), WALK_NP * PS - rows)
+        engine = SimpleNamespace(
+            _len_host=lens, page_size=PS, _n_table_entries=WALK_NP,
+            _layer_windows=windows)
+        want = np.mean([
+            sum(tile_math.live_pages(int(n), rows, w, PS, WALK_NP)[1]
+                for n in lens) for w in windows])
+        assert DecodeEngine._kv_pages_live(engine, rows) == want
+
+    def test_a_length_past_the_table_still_walks_inside_it(self):
+        """A stale length (a freed slot's) past the capacity: the walk
+        ends at the table's last column and starts no later."""
+        for sliding in (0, 128):
+            first, count = tile_math.live_pages(
+                10 * PS, 1, sliding, PS, WALK_NP)
+            assert first + count == WALK_NP and count >= 1
+
+
+WALK_CONFIGS = ["four-kv-heads", "gpt2-medium", "mistral-7b"]
+
+
+class TestWalkReadsOnlyItsLivePages:
+    """The loop visits the table columns ``live_pages`` names and no
+    other: the sentinel in every other entry, NaN in every page those
+    columns do not name, leave every output bit as it was; and the
+    result is the gather path's, at odd and even live counts (the ring
+    slot's parity), a one-page slot, and from one step into the next."""
+
+    @pytest.mark.parametrize("sliding", [0, 128, 200])
+    @pytest.mark.parametrize("window", [1, 5])
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
+                             ids=["bf16", "int8"])
+    @pytest.mark.parametrize("config", WALK_CONFIGS)
+    def test_poison_outside_the_bounds_changes_no_bit(
+            self, config, dtype, window, sliding):
+        # live counts 1, 2, 3, 4, 5 in a full layer: both parities of a
+        # ring of two, and every step hands the ring to the next
+        lens = [40, 2 * PS - window, 2 * PS + 9, 4 * PS - window,
+                WALK_NP * PS - window]
+        q, k, v, ks, vs, table, lens = walk_case(config, dtype, window, lens)
+        base = run_walk(q, k, v, ks, vs, table, lens, dtype, sliding)
+        ref = walk_reference(q, k, v, ks, vs, table, lens, window, sliding)
+        assert np.isfinite(base).all()
+        np.testing.assert_allclose(
+            base, ref, atol=3e-2 * max(1.0, np.abs(ref).max()), rtol=3e-2)
+        P = k.shape[1]
+        live = np.zeros(P, bool)
+        dead_table = np.full_like(table, P)            # the sentinel
+        for b, n in enumerate(lens):
+            first, count = tile_math.live_pages(
+                int(n), window, sliding, PS, WALK_NP)
+            cols = slice(first, first + count)
+            dead_table[b, cols] = table[b, cols]
+            live[table[b, cols]] = True
+        nan = lambda x: np.where(
+            live.reshape((1, P) + (1,) * (x.ndim - 2)), x, np.nan)
+        if dtype == jnp.int8:   # codes cannot hold one: the scales do
+            poisoned = (k, v, nan(ks), nan(vs))
+        else:
+            poisoned = (nan(k), nan(v), ks, vs)
+        for tab in (dead_table, table):
+            got = run_walk(q, *poisoned, tab, lens, dtype, sliding)
+            np.testing.assert_array_equal(got, base)
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
+                             ids=["bf16", "int8"])
+    @pytest.mark.parametrize("config", WALK_CONFIGS)
+    def test_empty_and_idle_slots_write_finite_rows(self, config, dtype):
+        """A slot of length 0 attends position 0 alone; an idle slot's
+        table holds the sentinel everywhere and its column 0 clamps to a
+        real page: both rows finite, the live slot between them right."""
+        q, k, v, ks, vs, table, lens = walk_case(
+            config, dtype, 1, [0, 3 * PS + 1, 0, 0])
+        table[2] = k.shape[1]                           # idle: no pages
+        table[3] = k.shape[1]
+        lens[3] = 2 * PS + 5                            # ...a stale length
+        out = run_walk(q, k, v, ks, vs, table, lens, dtype)
+        assert np.isfinite(out).all()
+        ref = walk_reference(q, k, v, ks, vs, table, lens, 1, 0)
+        np.testing.assert_allclose(
+            out[:2], ref[:2], atol=3e-2 * max(1.0, np.abs(ref).max()),
+            rtol=3e-2)
+
+    def test_the_record_names_the_walk_and_its_depth(self):
+        q, k, v, ks, vs, table, lens = walk_case(
+            "gpt2-medium", jnp.bfloat16, 1, [40, 300])
+        da.clear_decode_paths()
+        run_walk(q, k, v, ks, vs, table, lens, jnp.bfloat16, sliding=128)
+        (path,) = da.decode_paths()
+        assert path.walk == da.WALK_LOOP
+        assert path.depth == tile_math.paged_walk_depth(PS, 8, 128, 2)
+        assert path.depth >= tile_math.DOUBLE_BUFFER
+        assert (path.sliding, path.table_width) == (128, 2)
+        assert path.walk in path.describe()
+        assert f"a ring of {path.depth}" in path.describe()

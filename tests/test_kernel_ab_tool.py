@@ -1,6 +1,7 @@
 """``tools/run_kernel_ab.py --paged``: the cases it times and the arithmetic
-that turns three call times into a live and a dead grid step (ISSUE 28).
-The times themselves come from the chip only."""
+that turns three call times into a (slot, head block) step's fixed cost and
+a live page's (ISSUE 33; a live and a dead grid step before it). The times
+themselves come from the chip only."""
 
 import importlib.util
 from pathlib import Path
@@ -51,11 +52,9 @@ def test_the_geometries_are_the_benchmarks_configurations():
         assert llm["page_size"] == ab.PAGE
 
 
-def test_step_costs_solve_the_two_ends():
-    rows = [{"call_us": 32 * 1.75 + 224 * 0.3, "live_steps": 32,
-             "dead_steps": 224},
-            {"call_us": 128 * 1.75 + 128 * 0.3, "live_steps": 128,
-             "dead_steps": 128},
-            {"call_us": 256 * 1.75, "live_steps": 256, "dead_steps": 0}]
-    live, dead = ab.step_costs_us(rows)
-    assert live == pytest.approx(1.75) and dead == pytest.approx(0.3)
+@pytest.mark.parametrize("fixed, page", [(0.4, 0.7), (1.9, 0.66)])
+def test_walk_costs_fit_the_three_shares(fixed, page):
+    rows = [{"call_us": 32 * fixed + live * page, "steps": 32,
+             "live_pages": live} for live in (32, 128, 256)]
+    got = ab.walk_costs_us(rows)
+    assert got == pytest.approx((fixed, page))

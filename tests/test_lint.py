@@ -279,6 +279,77 @@ class TestGridSpecCollection:
         assert rules_found(report) == []
 
 
+SCRATCH_RING = """
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    {imports}
+
+    def call(kernel, {params}args):
+        ring = [pltpu.VMEM(({depth}, {ps}, 8, 128), "float32")] * 2
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(1, 1),
+                in_specs=[
+                    pl.BlockSpec((1, 8, 128), lambda b, j, pt: (b, j, 0)),
+                    pl.BlockSpec(memory_space=pl.ANY),
+                    pl.BlockSpec(memory_space=pl.ANY),
+                ],
+                out_specs=pl.BlockSpec(
+                    (1, 8, 128), lambda b, j, pt: (b, j, 0)),
+                scratch_shapes=ring + [
+                    pltpu.SemaphoreType.DMA((2, {depth})),
+                    pltpu.SMEM((3,), "int32"),
+                ],
+            ),
+        )(*args)
+"""
+
+
+class TestScratchRing:
+    """ISSUE 33: a kernel that leaves its pool in HBM and copies pages
+    into VMEM scratch itself (the paged decode kernel's ring) is held to
+    the same budget: the scratch counts once, an HBM-resident operand
+    nothing, a semaphore or SMEM scratch nothing."""
+
+    @pytest.mark.parametrize("depth, ps, imports, params, want", [
+        # 2 x 2 x 128 x 8 x 128 f32 = 2 MiB: fits
+        (2, 128, "", "", []),
+        # 2 x 4 x 1,024 x 8 x 128 f32 = 32 MiB of scratch: over
+        (4, 1024, "", "", ["vmem-budget"]),
+        # runtime-shaped ring, no guard in the module: flagged
+        ("depth", "ps", "", "depth, ps, ", ["vmem-budget"]),
+        # runtime-shaped ring beside the shared model: trusted
+        ("depth", "ps",
+         "from ray_dynamic_batching_tpu.ops import tile_math",
+         "depth, ps, ", []),
+    ], ids=["fits", "over", "unguarded", "guarded"])
+    def test_ring_is_held_to_the_budget(
+            self, tmp_path, depth, ps, imports, params, want):
+        report = lint_fixture(
+            tmp_path, "ops/ring.py", SCRATCH_RING.format(
+                depth=depth, ps=ps, imports=imports, params=params),
+            rules=["vmem-budget"])
+        assert rules_found(report) == want
+        if want and depth == 4:
+            assert "32.0 MB" in report.new[0].message
+
+    def test_the_paged_kernels_ring_is_what_the_model_prices(self):
+        """The kernel's scratch and ``tile_math.paged_tile_bytes`` are
+        one number at the benchmark's geometry: depth x (K + V tiles) +
+        the flat form's two score tiles."""
+        depth = tm.paged_walk_depth(128, 8, 128, 2)
+        assert tm.DOUBLE_BUFFER < depth == tm.PAGED_WALK_MAX_DEPTH
+        assert tm.paged_tile_bytes(128, 8, 128, 2, depth=depth) == (
+            depth * 2 * tm.padded_block_bytes((1, 128, 8, 128), 2)
+            + tm.flat_score_bytes(128, 8, 1))
+        # ... and a page whose least ring alone fits takes no more
+        assert tm.paged_tile_bytes(896, 12, 128, 2) \
+            <= tm.VMEM_BLOCK_BUDGET_BYTES
+        assert tm.paged_walk_depth(896, 12, 128, 2) == tm.DOUBLE_BUFFER
+
+
 class TestTileAlignment:
     def test_lane_dim_one_flags_the_128x_blowup(self, tmp_path):
         # The documented (kb, 1) trailing-dims case from

@@ -173,9 +173,11 @@ class TestDecodeKernelLowersForTPU:
 
 
 class TestPagedKernelLowersForTPU:
-    """The paged kernel's 5-D K/V block — ``(None, 1, ps, kb, Hp)`` on the
-    STACKED pool, the layer a prefetched block index, and the body's
-    branch on the slot's length — at the benchmark's three configurations
+    """The paged kernel over the STACKED pool left in HBM — the layer a
+    prefetched scalar, each live page's ``[1, ps, kb, Hp]`` K and V tiles
+    (a strided copy of an 8-head block where the pool holds 16) copied by
+    the kernel into its VMEM ring inside a loop bounded by the slot's
+    length — at the benchmark's three configurations
     (``benchmark/configs/``): gpt2-medium (24 layers, 128 pages x 128,
     MHA 16x64 in lane-padded 128-wide pool rows, 16 slots x 8 table
     entries), Mistral 7B cut to 16 layers (160 pages x 128, GQA 32/8 x
@@ -189,7 +191,7 @@ class TestPagedKernelLowersForTPU:
     }
 
     @staticmethod
-    def _call(g, window, dtype, struct=jnp.zeros):
+    def _call(g, window, dtype, struct=jnp.zeros, sliding=0):
         """The jitted call and its arguments (``struct(shape, dtype)``
         makes each: arrays for an export, shapes for a compile)."""
         from ray_dynamic_batching_tpu.models.decoder import pool_head_dim
@@ -206,7 +208,8 @@ class TestPagedKernelLowersForTPU:
         def f(q, pool, table, lengths, scale):
             out = da.paged_decode_attention(
                 q, pool, pool, table, lengths, layer=g["L"] - 1,
-                k_scale=scale, v_scale=scale, interpret=False)
+                k_scale=scale, v_scale=scale, interpret=False,
+                sliding=sliding)
             assert out is not None, "paged kernel declined"
             return out
 
@@ -254,8 +257,12 @@ class TestPagedKernelCompilesForV5e:
     benchmark's geometries: the flat form's [ps, kb, H] -> [ps * kb, H]
     view of a page, its [kb * R, ps * kb] score tiles and lane-row
     scales; the per-head form's strided head slices under the flat
-    q/out layout (a 4-head block). Nothing runs: what a step costs is
-    ``tools/run_kernel_ab.py --paged`` on the chip."""
+    q/out layout (a 4-head block); and the walk itself (the kernel's own
+    copies out of the pool in HBM, an 8-head block of 16 among them,
+    the ring's dynamic slot, the loop to the slot's length, the cursor
+    in SMEM), for a full layer and for a sliding one. Nothing runs: what
+    a step and a page cost is ``tools/run_kernel_ab.py --paged`` on the
+    chip."""
 
     CASES = {
         **{(c, 1): g
@@ -266,18 +273,26 @@ class TestPagedKernelCompilesForV5e:
             "mistral-7b"],
         ("four-kv-heads", 1): dict(L=2, P=16, B=4, NP=4, N=8, K=4, H=128),
         ("four-kv-heads", 5): dict(L=2, P=16, B=4, NP=4, N=8, K=4, H=128),
+        # K-EXAONE's pool (benchmark/configs/k-exaone-236b-ep8-1chip.json)
+        # read by a window-128 layer, and gpt2-medium's two head blocks
+        # under a window astride three pages
+        ("k-exaone", 1, 128): dict(
+            L=5, P=2048, B=64, NP=32, N=64, K=8, H=128),
+        ("gpt2-medium", 5, 200): TestPagedKernelLowersForTPU.GEOMETRIES[
+            "gpt2-medium"],
     }
 
     @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8])
     @pytest.mark.parametrize(
-        "case", sorted(CASES), ids=lambda c: f"{c[0]}-w{c[1]}")
+        "case", sorted(CASES),
+        ids=lambda c: f"{c[0]}-w{c[1]}" + (f"-sliding{c[2]}" if c[2:] else ""))
     def test_both_forms_compile(self, case, dtype, one_chip):
         from jax.experimental.compilation_cache import compilation_cache
 
         struct = lambda shape, dt: jax.ShapeDtypeStruct(
             shape, dt, sharding=one_chip)
         f, args = TestPagedKernelLowersForTPU._call(
-            self.CASES[case], case[1], dtype, struct)
+            self.CASES[case], case[1], dtype, struct, *case[2:])
         # A compile for a described chip is written to the persistent
         # cache but cannot be read back without one: keep it out.
         jax.config.update("jax_enable_compilation_cache", False)

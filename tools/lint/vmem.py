@@ -10,7 +10,11 @@ implementation the static model and ``_pick_sb`` cannot drift apart.
 
 - **vmem-budget**: per ``pl.pallas_call``, sum the padded bytes of
   every statically-resolvable BlockSpec (in_specs + out_specs), apply
-  the double-buffering multiplier, and compare against
+  the double-buffering multiplier, add the ``pltpu.VMEM`` scratch the
+  call allocates (once each: a kernel that leaves an operand in HBM,
+  ``memory_space=pl.ANY``, and copies its tiles into scratch itself — the
+  paged decode kernel's page ring — holds them there, not in pipelined
+  blocks), and compare against
   ``VMEM_BLOCK_BUDGET_BYTES``. Dims are resolved through module- and
   function-level integer-constant assignments; the footprint assumes
   f32 (itemsize 4) — provably the worst case, since sublane packing
@@ -169,6 +173,20 @@ def _blockspec_shape(node: ast.Call) -> Optional[ast.Tuple]:
     return None
 
 
+def _stays_in_hbm(node: ast.Call) -> bool:
+    """A BlockSpec that names a memory space and no block
+    (``memory_space=pl.ANY``): the operand is not brought into VMEM by
+    the pipeline; what the kernel copies of it lands in its scratch."""
+    return _blockspec_shape(node) is None and any(
+        kw.arg == "memory_space" and isinstance(kw.value, ast.Attribute)
+        and kw.value.attr in ("ANY", "HBM") for kw in node.keywords)
+
+
+def _is_vmem_scratch(node: ast.Call) -> bool:
+    fn = node.func
+    return isinstance(fn, ast.Attribute) and fn.attr == "VMEM"
+
+
 def _imports_tile_math(tree: ast.AST) -> bool:
     """True only for a REAL import of the shared model (``tile_math`` or
     ``VMEM_BLOCK_BUDGET_BYTES``) — a comment or docstring mention must
@@ -241,6 +259,11 @@ class TileAlignmentChecker(_BlockSpecMixin):
                 )
 
 
+_SPEC_KWARGS = ("in_specs", "out_specs")
+# Scratch shapes that hold no VMEM: semaphores and scalar memory.
+_NOT_VMEM = ("DMA", "REGULAR", "BARRIER", "SMEM")
+
+
 class VmemBudgetChecker(_BlockSpecMixin):
     rule = "vmem-budget"
 
@@ -251,22 +274,31 @@ class VmemBudgetChecker(_BlockSpecMixin):
             and node.func.attr == "pallas_call"
         ):
             return
-        specs = self._collect_specs(node, scope)
-        if not specs:
+        blocks = [s for s in self._collect(node, scope, _SPEC_KWARGS,
+                                           _is_blockspec_call)
+                  if s is None or not _stays_in_hbm(s)]
+        scratch = self._collect(node, scope, ("scratch_shapes",),
+                                _is_vmem_scratch)
+        if not blocks and not scratch:
             return
         env = self._env_for(scope)
-        total = 0
-        unresolved = False
-        for spec in specs:
-            shape = _blockspec_shape(spec)
-            if shape is None:
-                unresolved = True
-                continue
-            dims = [resolve_dim(d, env) for d in shape.elts]
-            if any(d is None or d <= 0 for d in dims):
-                unresolved = True
-                continue
-            total += _tile_math.padded_block_bytes(dims, ASSUMED_ITEMSIZE)
+
+        def padded(calls) -> Optional[int]:
+            """Padded bytes of the calls' shapes; None if one cannot be
+            read (an opaque entry, a runtime dim)."""
+            total = 0
+            for call in calls:
+                shape = None if call is None else _blockspec_shape(call)
+                dims = [] if shape is None else [
+                    resolve_dim(d, env) for d in shape.elts]
+                if not dims or any(d is None or d <= 0 for d in dims):
+                    return None
+                total += _tile_math.padded_block_bytes(
+                    dims, ASSUMED_ITEMSIZE)
+            return total
+
+        total, held = padded(blocks), padded(scratch)
+        unresolved = total is None or held is None
         if unresolved:
             # Runtime-shaped tiles: fine only when the module shares the
             # runtime/static footprint model (a picker like _pick_sb
@@ -283,88 +315,96 @@ class VmemBudgetChecker(_BlockSpecMixin):
                 )
             return
         budget = _tile_math.VMEM_BLOCK_BUDGET_BYTES
-        footprint = _tile_math.DOUBLE_BUFFER * total
+        footprint = _tile_math.DOUBLE_BUFFER * total + held
         if footprint > budget:
             self.report(
                 ctx, node,
                 f"pallas_call block footprint "
-                f"{footprint / 2 ** 20:.1f} MB (padded, double-buffered, "
+                f"{footprint / 2 ** 20:.1f} MB (padded, blocks "
+                f"double-buffered, VMEM scratch once, "
                 f"f32-itemsize upper bound) exceeds "
                 f"VMEM_BLOCK_BUDGET_BYTES = {budget / 2 ** 20:.0f} MB — "
                 "shrink the tile (this is the H=64 lane-padding "
                 "undercount class PR 1 fixed in _pick_sb)", scope,
             )
 
-    def _collect_specs(self, call: ast.Call, scope: Scope
-                       ) -> List[ast.Call]:
-        """BlockSpec calls reachable from in_specs/out_specs kwargs:
-        literal lists inline; a Name resolves through every list
+    def _collect(self, call: ast.Call, scope: Scope,
+                 kwargs: Sequence[str], wanted
+                 ) -> List[Optional[ast.Call]]:
+        """The ``wanted`` calls (BlockSpecs, or VMEM scratch shapes)
+        reachable from the ``kwargs`` of a pallas_call: literal lists
+        inline; a Name resolves through every list
         assignment/append/extend in the enclosing function (an
         over-approximation — conservative for a budget). A
         ``grid_spec=`` kwarg (``PrefetchScalarGridSpec`` — the
         page-table-indexed decode kernel's form — or a plain
-        ``GridSpec``) is transparent: its own in_specs/out_specs are
-        collected as if passed directly, so moving specs into a grid
-        spec cannot silently exempt a kernel from the budget."""
-        specs: List[ast.Call] = []
+        ``GridSpec``), inline or reached through a Name, is transparent:
+        its own kwargs are collected as if passed directly, so moving
+        specs into a grid spec cannot silently exempt a kernel from the
+        budget. Where a list is built by something this pass cannot read
+        (a helper's return value, ``[spec] * n`` with a runtime n) the
+        result holds a None: the call then counts as unresolved."""
+        calls: List[ast.Call] = [call]
         for kw in call.keywords:
-            if kw.arg in ("in_specs", "out_specs"):
-                specs.extend(self._specs_from(kw.value, scope))
-            elif kw.arg == "grid_spec":
-                specs.extend(self._specs_from_grid_spec(kw.value, scope))
-        return specs
-
-    def _specs_from_grid_spec(self, node: ast.AST, scope: Scope
-                              ) -> List[ast.Call]:
-        """in_specs/out_specs inside a grid-spec constructor call — the
-        call may be inline or reached through a Name bound in the
-        enclosing function (same over-approximation as _specs_from)."""
-        out: List[ast.Call] = []
-        calls: List[ast.Call] = []
-        if isinstance(node, ast.Call):
-            calls.append(node)
-        elif isinstance(node, ast.Name):
-            fn = scope.current_function()
-            if fn is not None:
-                for sub in ast.walk(fn):
-                    if isinstance(sub, ast.Assign) and any(
-                        isinstance(t, ast.Name) and t.id == node.id
-                        for t in sub.targets
-                    ) and isinstance(sub.value, ast.Call):
-                        calls.append(sub.value)
+            if kw.arg != "grid_spec":
+                continue
+            if isinstance(kw.value, ast.Call):
+                calls.append(kw.value)
+            elif isinstance(kw.value, ast.Name):
+                fn = scope.current_function()
+                calls += [
+                    sub.value for sub in (ast.walk(fn) if fn else ())
+                    if isinstance(sub, ast.Assign)
+                    and isinstance(sub.value, ast.Call)
+                    and any(isinstance(t, ast.Name)
+                            and t.id == kw.value.id for t in sub.targets)]
+        out: List[Optional[ast.Call]] = []
         for c in calls:
             for kw in c.keywords:
-                if kw.arg in ("in_specs", "out_specs"):
-                    out.extend(self._specs_from(kw.value, scope))
+                if kw.arg in kwargs:
+                    out.extend(self._from(kw.value, scope, wanted, set()))
         return out
 
-    def _specs_from(self, node: ast.AST, scope: Scope,
-                    seen: Optional[set] = None) -> List[ast.Call]:
-        seen = set() if seen is None else seen
-        out: List[ast.Call] = []
-        if isinstance(node, ast.Call) and _is_blockspec_call(node):
+    def _from(self, node: ast.AST, scope: Scope, wanted,
+              seen: set) -> List[Optional[ast.Call]]:
+        out: List[Optional[ast.Call]] = []
+        if isinstance(node, ast.Call) and wanted(node):
             out.append(node)
         elif isinstance(node, (ast.List, ast.Tuple)):
             for elt in node.elts:
-                out.extend(self._specs_from(elt, scope, seen))
+                out.extend(self._from(elt, scope, wanted, seen))
+        elif isinstance(node, ast.BinOp) and isinstance(node.op, ast.Add):
+            out.extend(self._from(node.left, scope, wanted, seen))
+            out.extend(self._from(node.right, scope, wanted, seen))
+        elif isinstance(node, ast.BinOp) and isinstance(node.op, ast.Mult):
+            # [spec] * n: n copies (an unreadable n: opaque)
+            times = _const_int(node.right)
+            out.extend([None] if times is None else
+                       self._from(node.left, scope, wanted, seen) * times)
+        elif isinstance(node, ast.Call):
+            # a helper's return value: unreadable here, unless it is one
+            # of the OTHER kind's constructors (a semaphore among the
+            # scratch shapes holds no VMEM)
+            if not (isinstance(node.func, ast.Attribute)
+                    and node.func.attr in _NOT_VMEM):
+                out.append(None)
         elif isinstance(node, ast.Name):
             if node.id in seen:  # e.g. specs = specs[:3] self-reference
                 return out
             seen.add(node.id)
             fn = scope.current_function()
-            root = fn if fn is not None else None
-            if root is None:
+            if fn is None:
                 return out
-            for sub in ast.walk(root):
+            for sub in ast.walk(fn):
                 if isinstance(sub, ast.Assign) and any(
                     isinstance(t, ast.Name) and t.id == node.id
                     for t in sub.targets
                 ):
-                    out.extend(self._specs_from(sub.value, scope, seen))
+                    out.extend(self._from(sub.value, scope, wanted, seen))
                 elif isinstance(sub, ast.AugAssign) and isinstance(
                     sub.target, ast.Name
                 ) and sub.target.id == node.id:
-                    out.extend(self._specs_from(sub.value, scope, seen))
+                    out.extend(self._from(sub.value, scope, wanted, seen))
                 elif (
                     isinstance(sub, ast.Call)
                     and isinstance(sub.func, ast.Attribute)
@@ -373,7 +413,7 @@ class VmemBudgetChecker(_BlockSpecMixin):
                     and sub.func.value.id == node.id
                 ):
                     for arg in sub.args:
-                        out.extend(self._specs_from(arg, scope, seen))
+                        out.extend(self._from(arg, scope, wanted, seen))
         return out
 
 

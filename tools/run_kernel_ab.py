@@ -17,12 +17,15 @@ between the two backends on the same inputs, and writes one JSON record.
 
 ``--paged`` measures the PAGED kernel alone instead
 (``ops/decode_attention.py::_paged_decode_attention``), at the stacked
-pools of the three benchmark configurations, with an eighth, a half and
-all of each slot's page table live: what a grid step costs whose page
-holds positions the slot attends (live) and one whose page lies past the
-slot's length (dead). One jitted program chains a call a layer, as a
-decode substep does, so the launch is paid once a program and not once a
-kernel. Read every kernel PR's step costs with it (``PERF.md``).
+pools of the benchmark's configurations, with an eighth, a half and
+all of each slot's page table live: what a (slot, head block) grid step
+costs before it folds a page (fixed) and what each live page its loop
+walks adds, a two-parameter fit over the three live shares (an entry
+past the length is never visited: it costs nothing). One jitted program
+chains a call a layer, as a decode substep does, so the launch is paid
+once a program and not once a kernel. Read every kernel PR's costs with
+it (``PERF.md``); the file keeps the parent commit's rows (the grid-walk
+kernel's live and dead grid steps) under ``parent``.
 
 Usage: python tools/run_kernel_ab.py [out_dir] [--iters N] [--paged]
                                      [--only tag1,tag2] [--out-name F]
@@ -95,17 +98,20 @@ def paged_case(seed: int, B: int, NP: int, P: int, share: float):
     return table, lengths.astype(np.int32), live
 
 
-def step_costs_us(rows):
-    """Microseconds a live and a dead grid step from one geometry's rows
-    (a row: ``call_us`` of one kernel call, ``live_steps``, ``dead_steps``):
-    live = the fully live call over its grid, dead = what the emptiest
-    call took beyond its live steps over its dead ones. A call's fixed
-    cost is in both (it is ~1% of a 256-step grid)."""
-    full = max(rows, key=lambda r: r["live_steps"])
-    least = min(rows, key=lambda r: r["live_steps"])
-    live_us = full["call_us"] / (full["live_steps"] + full["dead_steps"])
-    return live_us, ((least["call_us"] - least["live_steps"] * live_us)
-                     / least["dead_steps"])
+def walk_costs_us(rows):
+    """Microseconds a (slot, head block) grid step costs before it folds
+    a page, and microseconds a live page adds, from one geometry's rows
+    (a row: ``call_us`` of one kernel call, its grid ``steps``, the
+    ``live_pages`` its loops walk between them): the least-squares line
+    ``call_us = fixed * steps + page * live_pages`` through the rows. The
+    call's own launch is in the fixed cost (a program chains a call a
+    layer, so it is a layer's share of one launch)."""
+    import numpy as np
+
+    a = np.array([[r["steps"], r["live_pages"]] for r in rows], float)
+    (fixed, page), *_ = np.linalg.lstsq(
+        a, np.array([r["call_us"] for r in rows], float), rcond=None)
+    return float(fixed), float(page)
 
 
 def _time_paged(tag, L, P, B, NP, N, K, H, int8, iters, sliding=0):
@@ -187,8 +193,9 @@ def _time_paged(tag, L, P, B, NP, N, K, H, int8, iters, sliding=0):
                 "geometry": tag, "live_share": share,
                 "call_us": statistics.median(samples),
                 "call_us_min_max": [min(samples), max(samples)],
-                "live_steps": round(B * blocks * live),
-                "dead_steps": round(B * blocks * (walked - live)),
+                "steps": B * blocks,
+                "live_pages": round(B * blocks * live),
+                "table_entries": B * blocks * walked,
                 "max_abs_diff": float(jnp.max(jnp.abs(
                     out.astype(jnp.float32) - ref.astype(jnp.float32)))),
             })
@@ -218,33 +225,40 @@ def paged_main(out_dir: str, out_name: str, iters: int, only) -> int:
                 {"geometry": g[0], "error": repr(exc)[:500]})
             print(f"{g[0]}: FAILED {exc!r}", file=sys.stderr, flush=True)
             continue
-        # a sliding call's grid is as wide at every length: nothing to
-        # tell a live step's cost from a dead one's by
-        live_us, dead_us = ((None, None) if g[0] in SLIDING
-                            else step_costs_us(rows))
+        # a sliding call walks its window's pages at every length:
+        # nothing to tell a page's cost from a step's by
+        fixed_us, page_us = ((None, None) if g[0] in SLIDING
+                             else walk_costs_us(rows))
         record["geometries"].append({
             "geometry": g[0], "rows": rows,
-            "live_step_us": live_us, "dead_step_us": dead_us})
+            "fixed_step_us": fixed_us, "live_page_us": page_us})
         for r in rows:
             print(f"{g[0]}: live share {r['live_share']:.3f}: "
                   f"{r['call_us']:.1f} us a call "
-                  f"({r['live_steps']} live + {r['dead_steps']} dead "
-                  f"steps), max |kernel - gather| "
-                  f"{r['max_abs_diff']:.2e}", flush=True)
-        if live_us is not None:
-            print(f"{g[0]}: a live grid step {live_us:.3f} us, a dead one "
-                  f"{dead_us:.3f} us", flush=True)
+                  f"({r['steps']} steps walk {r['live_pages']} of "
+                  f"{r['table_entries']} table entries), "
+                  f"max |kernel - gather| {r['max_abs_diff']:.2e}",
+                  flush=True)
+        if fixed_us is not None:
+            print(f"{g[0]}: a (slot, head block) step {fixed_us:.3f} us, "
+                  f"a live page {page_us:.3f} us", flush=True)
         ok = ok and all(r["max_abs_diff"] < 0.1 for r in rows)
     os.makedirs(out_dir, exist_ok=True)
-    with open(os.path.join(out_dir, out_name), "w") as f:
+    path = os.path.join(out_dir, out_name)
+    if os.path.exists(path):    # the parent commit's rows stay beside
+        with open(path) as f:
+            kept = json.load(f).get("parent")
+        if kept is not None:
+            record["parent"] = kept
+    with open(path, "w") as f:
         json.dump(record, f, indent=1)
         f.write("\n")
     print(json.dumps({
-        "metric": "paged_decode_step_us", "backend": backend,
-        "live": {g["geometry"]: g.get("live_step_us")
-                 for g in record["geometries"]},
-        "dead": {g["geometry"]: g.get("dead_step_us")
-                 for g in record["geometries"]},
+        "metric": "paged_decode_walk_us", "backend": backend,
+        "fixed_step": {g["geometry"]: g.get("fixed_step_us")
+                       for g in record["geometries"]},
+        "live_page": {g["geometry"]: g.get("live_page_us")
+                      for g in record["geometries"]},
     }), flush=True)
     return 0 if ok and backend != "cpu" else 1
 
