@@ -235,7 +235,15 @@ class Turn(NamedTuple):
     counts its page 0, as the paged kernel does): the part of the table the
     kernel's scan has to compute. Where layers differ (sliding-window
     layers walk and find live the columns of their window only) it is the
-    mean over layers. 0 for a chunk group."""
+    mean over layers. 0 for a chunk group.
+
+    A scan of a model whose layers SELECT what they attend (an indexer,
+    ``ops/sparse_attention.py``) also carries ``kv_rows_live``, the sum over
+    all slots and selecting layers of the positions the scan's first substep
+    may attend (the cached length and the token being written; from the
+    host's lengths, as ``kv_pages_live`` is), and ``kv_rows_selected``, the
+    sum of ``min(that, index_topk)``: the rows its attention folds. Both 0
+    for any other model."""
 
     kind: str
     t_dispatch: float
@@ -255,6 +263,8 @@ class Turn(NamedTuple):
     moe_max_rows: int = 0
     kv_pages_live: float = 0
     moe_pairs: int = 0
+    kv_rows_live: int = 0
+    kv_rows_selected: int = 0
 
 
 # Sized for the benchmark's 51 s window at several times the cells'
@@ -293,7 +303,10 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
     add ``kv_pages_live`` and ``kv_pages_scanned``, each scan weighed by its
     substeps (live page-table entries; slots x ``table_entries``), and their
     ratio ``kv_live_page_share``: how much of the grid the paged kernel
-    walks holds KV a slot attends."""
+    walks holds KV a slot attends. A selecting model's scans add
+    ``kv_rows_live``, ``kv_rows_selected`` (each weighed by its substeps)
+    and ``kv_selected_row_share``: of the cached rows a query could attend,
+    the share its indexer keeps."""
     scans = [t for t in turns if t.kind == "turn"]
     out: Dict[str, Any] = {"dispatches": len(turns), "scans": len(scans),
                            "dropped": dropped}
@@ -303,6 +316,12 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
         out["kv_pages_scanned"] = num_slots * table_entries * sum(
             t.substeps for t in scans)
         out["kv_live_page_share"] = live / out["kv_pages_scanned"]
+    rows_live = sum(t.kv_rows_live * t.substeps for t in scans)
+    if rows_live:
+        out["kv_rows_live"] = rows_live
+        out["kv_rows_selected"] = sum(
+            t.kv_rows_selected * t.substeps for t in scans)
+        out["kv_selected_row_share"] = out["kv_rows_selected"] / rows_live
     hit = sum(t.moe_experts_hit for t in turns)
     if hit:
         out["moe_rows_per_expert"] = sum(t.moe_rows for t in turns) / hit
@@ -696,6 +715,13 @@ class DecodeEngine:
             for w in self._layer_windows]
         self._table_walked = (sum(self._layer_table_widths)
                               / len(self._layer_table_widths))
+        # Positions a selecting layer's indexer keeps a query (0: no layer
+        # selects), and how many layers select.
+        cfg = getattr(model, "cfg", None)
+        self._index_topk = int(getattr(cfg, "index_topk", 0) or 0)
+        self._select_layers = (
+            sum(1 for i in range(cfg.num_layers) if cfg.layer_kind(i).select)
+            if self._index_topk else 0)
         # The head's true width, as the model's row caches have it
         # (the pool's rows are lane-padded: pool_head_dim).
         self._kv_head_dim = jax.eval_shape(
@@ -705,7 +731,8 @@ class DecodeEngine:
         # and the page write read; the pool's lane-padded rows make
         # it the device's default) and its bytes there.
         planes = [x for x in (self._cache.k, self._cache.v,
-                              self._cache.k_scale, self._cache.v_scale)
+                              self._cache.k_scale, self._cache.v_scale,
+                              self._cache.index_k)
                   if x is not None]
         layout = self._cache.k.format.layout
         self._pool_stats = {
@@ -976,18 +1003,20 @@ class DecodeEngine:
                       t_fetched: float, substeps: int, tokens: int,
                       active: int, trains: int,
                       moe: Sequence[int] = (0, 0, 0, 0),
-                      kv_pages_live: float = 0) -> Turn:
+                      kv_pages_live: float = 0,
+                      kv_rows: Tuple[int, int] = (0, 0)) -> Turn:
         """Append this dispatch's record to the turn ring (its work on the
         host is done: ``t_done`` is now). ``moe``: the dispatch's routing
         counters as fetched (``Turn``'s ``moe_*`` fields);
         ``kv_pages_live``: :meth:`_kv_pages_live` as the scan was
-        dispatched."""
+        dispatched; ``kv_rows``: :meth:`_kv_rows` then."""
         rec = Turn(
             kind, t_dispatch, t_issued, t_fetched, now_ms(),
             substeps, tokens, active, trains, len(self.queue),
             self._allocator.allocated_pages,
             int(self._len_host.sum()), self._idled,
             *(int(c) for c in moe[:3]), kv_pages_live, int(moe[3]),
+            *kv_rows,
         )
         if len(self.turns) == self.turns.maxlen:
             self.turns_dropped += 1
@@ -1012,6 +1041,18 @@ class DecodeEngine:
             return live[self._layer_windows[0]]
         return sum(live[w] for w in self._layer_windows) / len(
             self._layer_windows)
+
+    def _kv_rows(self, window: int = 1) -> Tuple[int, int]:
+        """(rows live, rows selected) of the scan about to be dispatched,
+        summed over all slots and selecting layers (``Turn.kv_rows_live``);
+        (0, 0) where no layer selects."""
+        if not self._index_topk:
+            return 0, 0
+        rows = np.minimum(self._len_host.astype(np.int64) + window,
+                          self._paged_capacity)
+        return (int(rows.sum()) * self._select_layers,
+                int(np.minimum(rows, self._index_topk).sum())
+                * self._select_layers)
 
     def _device_ctx(self):
         """The scope everything this engine allocates, traces and
@@ -1203,6 +1244,7 @@ class DecodeEngine:
         cache = cache.replace(
             k=pools.k, v=pools.v, lengths=lengths,
             k_scale=pools.k_scale, v_scale=pools.v_scale,
+            index_k=pools.index_k,
         )
         first = self._sample_tokens(
             taken, temps, topk, seeds, jnp.zeros_like(slots), bias_ids,
@@ -2113,6 +2155,10 @@ class DecodeEngine:
         if self._cache.quantized:
             out["k_scale"] = np.asarray(self._cache.k_scale[:, idx])
             out["v_scale"] = np.asarray(self._cache.v_scale[:, idx])
+        if self._cache.index_k is not None:
+            # A page travels with its index keys: without them a selecting
+            # layer would score zeros there after a migration.
+            out["index_k"] = np.asarray(self._cache.index_k[:, idx])
         return out
 
     def _write_pages(self, page_ids: List[int],
@@ -2134,6 +2180,10 @@ class DecodeEngine:
                     jnp.asarray(payload["k_scale"], jnp.float32))
                 repl["v_scale"] = self._cache.v_scale.at[:, idx].set(
                     jnp.asarray(payload["v_scale"], jnp.float32))
+            if self._cache.index_k is not None:
+                repl["index_k"] = self._cache.index_k.at[:, idx].set(
+                    jnp.asarray(payload["index_k"],
+                                self._cache.index_k.dtype))
             self._cache = self._cache.replace(**repl)
 
     def _reload_spilled_prefix(
@@ -2767,6 +2817,7 @@ class DecodeEngine:
                 return self._plain_turn(ph, 1)
             active = int(self._active_mask.sum())
             kv_pages_live = self._kv_pages_live(k + 1)
+            kv_rows = self._kv_rows(k + 1)
             ph.set_metadata(horizon=k, active=active, spec=1)
             try:
                 # From here to the packed fetch, scratch is armed but
@@ -2843,7 +2894,8 @@ class DecodeEngine:
                 )
             rec = self._log_dispatch("turn", t_dispatch, t_issued, t_fetched,
                                      1, 0, active, len(self._trains),
-                                     kv_pages_live=kv_pages_live)
+                                     kv_pages_live=kv_pages_live,
+                                     kv_rows=kv_rows)
             if links is not None:
                 self._record_turn_span(rec, links, k, spec=True)
 
@@ -2873,6 +2925,7 @@ class DecodeEngine:
             active_at_dispatch = self._active_mask.copy()
             active = int(active_at_dispatch.sum())
             kv_pages_live = self._kv_pages_live()
+            kv_rows = self._kv_rows()
             samp_f, samp_i, bias_ids_d, bias_vals_d = self._sampling_arrays()
             # ONE per-dispatch upload: tokens / active / sample index.
             state = np.stack([
@@ -2928,7 +2981,7 @@ class DecodeEngine:
             "turn", t_dispatch, t_issued, t_fetched, h, 0, active,
             len(self._trains),
             packed_host[2 * h + 1:, 0] if self._moe_kw else (0, 0, 0, 0),
-            kv_pages_live=kv_pages_live)
+            kv_pages_live=kv_pages_live, kv_rows=kv_rows)
         if links is not None:
             self._record_turn_span(rec, links, h)
 
@@ -3527,6 +3580,28 @@ class DecodeEngine:
         return sorted({f"{p.program}: {p.describe()}"
                        for p in decode_paths() if p.program})
 
+    def _index_pool_stats(self, turns: Dict[str, Any]) -> Dict[str, Any]:
+        """A selecting model's lines of ``snapshot()["kv_pool"]``: the index
+        keys' pool, what the indexer keeps, and how each program's selecting
+        layers were read (``ops/sparse_attention.py::sparse_forms``).
+        Nothing for a model without an indexer."""
+        pool = self._cache.index_k
+        if pool is None:
+            return {}
+        from ray_dynamic_batching_tpu.ops.sparse_attention import (
+            sparse_forms,
+        )
+
+        return {
+            "index_pool": {
+                "shape": list(pool.shape), "dtype": str(pool.dtype),
+                "resident_bytes": pool.on_device_size_in_bytes()},
+            "index_topk": self._index_topk,
+            "select_layers": self._select_layers,
+            "selected_row_share": turns.get("kv_selected_row_share"),
+            "sparse_forms": sparse_forms(),
+        }
+
     def turn_summary(self, records: Optional[Sequence[Turn]] = None,
                      span_ms: Optional[float] = None,
                      longest: int = 8) -> Dict[str, Any]:
@@ -3579,6 +3654,7 @@ class DecodeEngine:
                 # the table columns its decode scan walks a slot
                 layer_windows=list(self._layer_windows),
                 layer_table_widths=list(self._layer_table_widths),
+                **self._index_pool_stats(turns),
             ),
             "page_journal": {
                 "events": self._page_journal.snapshot(),

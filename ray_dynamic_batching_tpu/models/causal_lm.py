@@ -375,7 +375,7 @@ class CausalLM(ServableModel):
         allocation — ``engine/paging.py``)."""
         return PagedKVCache.zeros(
             self.cfg, batch_size, num_pages, page_size, max_len,
-            dtype=self.kv_dtype or self.dtype,
+            dtype=self.kv_dtype or self.dtype, index_dtype=self.dtype,
         )
 
     def decode_step_paged(
@@ -435,7 +435,10 @@ class CausalLM(ServableModel):
         if self.kv_dtype is not None and jnp.dtype(
                 self.kv_dtype) == jnp.dtype(jnp.int8):
             per_row += 4  # one f32 scale per cached (token, head) row
-        return 2 * c.num_layers * S * c.num_kv_heads * per_row
+        # an indexer's ONE key a position a layer, in the model's own dtype
+        index_row = (c.index_head_dim * jnp.dtype(self.dtype).itemsize
+                     if c.index_topk else 0)
+        return c.num_layers * S * (2 * c.num_kv_heads * per_row + index_row)
 
     def sharding_rules(self):
         return [
@@ -479,7 +482,7 @@ class CausalLM(ServableModel):
         shard's slice of page ``p`` backs the same logical positions),
         which is what lets the host-side ``PageAllocator`` stay
         replica-global. Scale planes (``[L, P, ps, K]``) shard with
-        their heads."""
+        their heads; a selecting model's index keys replicate."""
         scale_spec = None
         if self.kv_dtype is not None and jnp.dtype(
                 self.kv_dtype) == jnp.dtype(jnp.int8):
@@ -491,6 +494,9 @@ class CausalLM(ServableModel):
             lengths=P(None),                      # type: ignore[arg-type]
             k_scale=scale_spec,                   # type: ignore[arg-type]
             v_scale=scale_spec,                   # type: ignore[arg-type]
+            # ONE index key a position, whatever the head shard: replicated
+            index_k=(P(None, None, None, None)    # type: ignore[arg-type]
+                     if self.cfg.index_topk else None),
         )
 
 

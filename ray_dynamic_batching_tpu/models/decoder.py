@@ -93,6 +93,16 @@ class DecoderConfig:
     # Shared experts beside the routed ones: one dense SwiGLU of width
     # ``moe_shared_experts * mlp_dim`` on every token.
     moe_shared_experts: int = 0
+    # A learned indexer (ops/sparse_attention.py): ``index_heads`` heads of
+    # ``index_head_dim`` score every cached position against ONE index key a
+    # position, and a query attends only its ``index_topk`` best positions
+    # (all of them while there are no more). 0: no indexer, every layer
+    # attends its whole prefix or window.
+    index_topk: int = 0
+    index_heads: int = 0
+    index_head_dim: int = 0
+    # Every RMSNorm's epsilon (the published ``rms_norm_eps``).
+    rms_eps: float = 1e-5
 
     def __post_init__(self):
         if not self.head_dim:
@@ -107,6 +117,12 @@ class DecoderConfig:
                 f"held experts [{self.moe_first_expert}, "
                 f"{self.moe_first_expert + self.held_experts}) are not "
                 f"among {self.num_experts}")
+        if self.index_topk and not (self.index_heads and self.index_head_dim):
+            raise ValueError("index_topk needs index_heads and "
+                             "index_head_dim")
+        if self.index_topk and self.sliding_window:
+            raise ValueError("a selecting layer attends its best positions "
+                             "of the whole prefix: no sliding_window")
 
     @property
     def held_experts(self) -> int:
@@ -125,6 +141,7 @@ class DecoderConfig:
             sparse=sparse,
             mlp_dim=(self.mlp_dim if sparse or not self.num_experts
                      else self.dense_mlp_dim),
+            select=self.index_topk,
         )
 
 
@@ -136,6 +153,7 @@ class LayerKind:
     rope: bool      # rotary positions on q and k
     sparse: bool    # an expert MLP (models/moe.py), else a dense one
     mlp_dim: int    # the dense MLP's width, or ONE routed expert's
+    select: int = 0  # positions its indexer keeps a query; 0 = no indexer
 
 
 @pytree_dataclass
@@ -206,7 +224,11 @@ class PagedKVCache:
 
     Quantized pools mirror the slab layout: k/v hold int8 codes,
     ``k_scale``/``v_scale`` ``[L, P, page_size, K]`` hold the per-row
-    f32 scales, paged with the SAME page table."""
+    f32 scales, paged with the SAME page table. So is ``index_k``
+    ``[L, P, page_size, Hip]``, a selecting model's index keys (one a
+    position a layer, ``Hip`` the indexer's head lane-padded; the model's
+    own dtype in an int8 pool too): a second kind of per-position state
+    in the one pool, None for a model without an indexer."""
 
     k: jax.Array
     v: jax.Array
@@ -214,12 +236,14 @@ class PagedKVCache:
     lengths: jax.Array     # [B] valid logical prefix per slot
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
+    index_k: Optional[jax.Array] = None
 
     @staticmethod
     def zeros(
         cfg: DecoderConfig, batch_size: int, num_pages: int,
         page_size: int, max_len: int,
         dtype: jnp.dtype = jnp.bfloat16,
+        index_dtype: jnp.dtype = jnp.bfloat16,
     ) -> "PagedKVCache":
         if max_len % page_size != 0:
             raise ValueError(
@@ -238,6 +262,9 @@ class PagedKVCache:
             lengths=jnp.zeros((batch_size,), dtype=jnp.int32),
             k_scale=jnp.zeros(shape[:-1], jnp.float32) if quantized else None,
             v_scale=jnp.zeros(shape[:-1], jnp.float32) if quantized else None,
+            index_k=jnp.zeros(
+                shape[:3] + (pool_head_dim(cfg.index_head_dim),),
+                index_dtype) if cfg.index_topk else None,
         )
 
     @property
@@ -354,7 +381,7 @@ class DecoderLayer(nn.Module):
 
     def _norm(self, name: str):
         if self.cfg.norm == "rms":
-            return RMSNorm(name=name)
+            return RMSNorm(name=name, eps=self.cfg.rms_eps)
         return nn.LayerNorm(dtype=jnp.float32, name=name)
 
     @nn.compact
@@ -370,7 +397,10 @@ class DecoderLayer(nn.Module):
         scatter_writes: bool = False,  # per-row writes at ``positions``
         page_table: Optional[jax.Array] = None,  # [B, NP]: paged decode
         kv_lengths: Optional[jax.Array] = None,  # [B] paged validity bound
-    ) -> Tuple[jax.Array, Optional[Tuple[jax.Array, jax.Array]]]:
+        index_pool: Optional[jax.Array] = None,  # [L, P, ps, Hip] index keys
+    ) -> Tuple[jax.Array, ...]:
+        """(x, updated cache or None); a selecting layer handed the paged
+        ``index_pool`` returns it, updated, as a third result."""
         cfg = self.cfg
         kind = cfg.layer_kind(layer_idx)
         dense = lambda feats, name, axis=-1: nn.DenseGeneral(  # noqa: E731
@@ -385,17 +415,37 @@ class DecoderLayer(nn.Module):
         q = dense((cfg.num_heads, cfg.head_dim), "q")(y)
         k = dense((cfg.num_kv_heads, cfg.head_dim), "k")(y)
         v = dense((cfg.num_kv_heads, cfg.head_dim), "v")(y)
+        qk_norm = lambda name: RMSNorm(  # noqa: E731
+            name=name, eps=cfg.rms_eps)
         if cfg.qk_norm and cfg.qk_norm_per_head:
-            q = RMSNorm(name="q_norm")(q)
-            k = RMSNorm(name="k_norm")(k)
+            q = qk_norm("q_norm")(q)
+            k = qk_norm("k_norm")(k)
         elif cfg.qk_norm:
-            q = RMSNorm(name="q_norm")(
+            q = qk_norm("q_norm")(
                 q.reshape(*q.shape[:2], -1)).reshape(q.shape)
-            k = RMSNorm(name="k_norm")(
+            k = qk_norm("k_norm")(
                 k.reshape(*k.shape[:2], -1)).reshape(k.shape)
         if kind.rope:
             q = apply_rope(q, positions, cfg.rope_theta)
             k = apply_rope(k, positions, cfg.rope_theta)
+        select = None
+        if kind.select:
+            # Imported here: a model without an indexer never loads it.
+            from ray_dynamic_batching_tpu.ops import sparse_attention
+
+            with jax.named_scope("sparse_index"):
+                q_i = dense((cfg.index_heads, cfg.index_head_dim),
+                            "index_q")(y)
+                k_i = dense((1, cfg.index_head_dim), "index_k")(y)
+                w_i = dense(cfg.index_heads, "index_w")(y)
+                if kind.rope:
+                    q_i = apply_rope(q_i, positions, cfg.rope_theta)
+                    k_i = apply_rope(k_i, positions, cfg.rope_theta)
+                k_i = k_i[:, :, 0]                       # ONE key a position
+            if cache_kv is not None and index_pool is None:
+                raise NotImplementedError(
+                    "a selecting layer's index keys live in the paged pool "
+                    "(PagedKVCache.index_k): the slab cache has none")
         if kind.window and mask is not None:
             # An explicit mask (the slab cache's, or a whole prompt's)
             # indexes keys by their position: a sliding layer cuts its
@@ -471,6 +521,14 @@ class DecoderLayer(nn.Module):
                     vs_full = vs_full.at[layer_idx, pid, off].set(
                         v_s, mode="drop"
                     )
+                if kind.select:
+                    # The index key rides the SAME (page, offset): written
+                    # before it is scored, as k and v are.
+                    index_pool = index_pool.at[layer_idx, pid, off].set(
+                        fit_head_dim(k_i, index_pool.shape[-1]).astype(
+                            index_pool.dtype), mode="drop")
+                    select = sparse_attention.Selection(
+                        q_i, w_i, index_pool, kind.select)
             elif scatter_writes:
                 # Batched multi-token writes at PER-ROW positions (the
                 # speculative-verify path: each slot's window starts at its
@@ -551,10 +609,27 @@ class DecoderLayer(nn.Module):
                 scale_kwargs.update(page_table=page_table,
                                     kv_lengths=kv_lengths, layer=layer_idx,
                                     sliding=kind.window)
+                if select is not None:
+                    scale_kwargs["select"] = select
             else:
                 kv = (k_full[layer_idx], v_full[layer_idx])
             attn_out = attn_ops.dot_product_attention(
                 q, *kv, mask=mask, **scale_kwargs)
+        elif kind.select:
+            # A selecting layer's whole-sequence attention: the causal
+            # (and valid-token) mask, of which each query keeps its best.
+            B, T = positions.shape
+            if token_mask is not None:
+                allowed = prefill_mask(token_mask)
+            elif mask is not None:
+                allowed = mask
+            else:
+                allowed = jnp.ones((B, 1, T, T), bool)
+            attn_out = attn_ops.dot_product_attention(
+                q, k, v, mask=sparse_attention.select_mask(
+                    q_i, w_i, k_i,
+                    jnp.broadcast_to(allowed, (B, 1, T, T)), kind.select))
+            new_cache = None
         elif token_mask is not None and kind.window:
             # A sliding layer's whole-sequence attention: the causal
             # kernel under the window's lower edge (no ring form).
@@ -600,6 +675,8 @@ class DecoderLayer(nn.Module):
         else:
             y = nn.gelu(dense(kind.mlp_dim, "mlp_up")(y))
             y = dense(cfg.d_model, "mlp_down")(y)
+        if index_pool is not None:
+            return x + y, new_cache, index_pool
         return x + y, new_cache
 
 
@@ -645,17 +722,24 @@ class DecoderModule(nn.Module):
                 (cache.k, cache.v, cache.k_scale, cache.v_scale)
                 if cache.quantized else (cache.k, cache.v)
             )
+        # A selecting model's index keys, paged beside k and v.
+        index_kw = {}
+        if getattr(cache, "index_k", None) is not None:
+            index_kw["index_pool"] = cache.index_k
         for i in range(cfg.num_layers):
-            x, updated = DecoderLayer(cfg, dtype=self.dtype, name=f"layer{i}")(
+            x, updated, *index = DecoderLayer(
+                cfg, dtype=self.dtype, name=f"layer{i}")(
                 x, positions, mask, cache_kv, token_mask, layer_idx=i,
                 write_start=write_start, scatter_writes=scatter_writes,
-                page_table=page_table, kv_lengths=kv_lengths,
+                page_table=page_table, kv_lengths=kv_lengths, **index_kw,
             )
             if updated is not None:
                 cache_kv = updated
+            if index:
+                index_kw["index_pool"] = index[0]
 
         if cfg.norm == "rms":
-            x = RMSNorm(name="final_norm")(x)
+            x = RMSNorm(name="final_norm", eps=cfg.rms_eps)(x)
         else:
             x = nn.LayerNorm(dtype=jnp.float32, name="final_norm")(x)
 
@@ -680,6 +764,7 @@ class DecoderModule(nn.Module):
                 out_cache = PagedKVCache(
                     k=cache_kv[0], v=cache_kv[1], page_table=page_table,
                     lengths=cache.lengths, **scales,
+                    index_k=index_kw.get("index_pool"),
                 )
             else:
                 out_cache = KVCache(

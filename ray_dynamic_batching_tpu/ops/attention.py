@@ -231,6 +231,7 @@ def dot_product_attention(
     kv_lengths: Optional[jax.Array] = None,
     layer: int = 0,
     sliding: int = 0,
+    select: Optional[tuple] = None,
 ) -> jax.Array:
     """Multi-head attention.
 
@@ -257,13 +258,19 @@ def dot_product_attention(
     against each other. ``sliding`` > 0 (paged reads only; elsewhere the
     window rides the caller's mask) is a sliding-window layer: each row
     attends its last ``sliding`` positions, by the one rule of
-    ``models/decoder.py::paged_window_mask``.
+    ``models/decoder.py::paged_window_mask``. ``select`` (paged reads
+    only; ``ops/sparse_attention.py::Selection``) is a layer with an
+    indexer: each row attends only its best-scored positions of those.
     """
     if page_table is not None:
         return _paged_attention(
             q, k, v, page_table, kv_lengths, layer, mask=mask,
             scale=scale, k_scale=k_scale, v_scale=v_scale, sliding=sliding,
+            select=select,
         )
+    if select is not None:
+        raise ValueError("select is the paged read's; elsewhere a "
+                         "selection rides the mask")
     if sliding:
         raise ValueError("sliding is the paged read's; elsewhere a window "
                          "rides the mask")
@@ -424,11 +431,16 @@ def _paged_attention(
     k_scale: Optional[jax.Array],   # [P, ps, K] or None
     v_scale: Optional[jax.Array],
     sliding: int = 0,
+    select: Optional[tuple] = None,
 ) -> jax.Array:
     """Paged decode read: fused page-table KV scan on the Pallas path,
     explicit gather back to the slab view otherwise (the token-exact
     fallback — identical values land in identical logical positions, and
-    the shared ``decode_mask`` rule bounds what is attended)."""
+    the shared ``decode_mask`` rule bounds what is attended). A layer with
+    an indexer (``select``) never takes the paged kernel, which attends a
+    contiguous prefix: ``ops/sparse_attention.py`` reads it, in its decode
+    form or through the gather fallback below with the selection in the
+    mask."""
     if mask is not None:
         raise ValueError(
             "paged attention derives its window from kv_lengths; an "
@@ -440,7 +452,19 @@ def _paged_attention(
         # One layer's pool: a one-layer stack (a free reshape).
         k, v, layer = k[None], v[None], 0
     declines: List[str] = []
-    if _use_pallas():
+    if select is not None:
+        from ray_dynamic_batching_tpu.ops import sparse_attention
+
+        if sliding:
+            raise ValueError("a selecting layer has no sliding window")
+        out = sparse_attention.paged_decode(
+            q, k, v, page_table, kv_lengths, layer, select, scale=scale,
+            k_scale=k_scale, why=declines)
+        if out is not None:
+            return out
+        declines.append("paged kernel: a selecting layer attends a "
+                        "learned subset, not a prefix")
+    elif _use_pallas():
         from ray_dynamic_batching_tpu.ops import decode_attention
 
         tp_mesh, tp_axis, tp = _tp_slice()
@@ -506,6 +530,8 @@ def _paged_attention(
     win = paged_window_mask(kv_lengths, NP * ps, q.shape[1], sliding, base)
     if real is not None:
         win = win & real
+    if select is not None:
+        win = sparse_attention.paged_select_mask(select, layer, safe, win)
     return _dense_attention(
         q, k_g, v_g, causal=False, mask=win, scale=scale,
         k_scale=ks_g, v_scale=vs_g, declines=declines, gathered=True,

@@ -587,7 +587,8 @@ class LLMDeployment:
             1, n, self.page_size, n * self.page_size))
         return sum(
             math.prod(x.shape) * x.dtype.itemsize
-            for x in (pool.k, pool.v, pool.k_scale, pool.v_scale)
+            for x in (pool.k, pool.v, pool.k_scale, pool.v_scale,
+                      pool.index_k)
             if x is not None
         )
 

@@ -58,3 +58,41 @@ def test_walk_costs_fit_the_three_shares(fixed, page):
              "live_pages": live} for live in (32, 128, 256)]
     got = ab.walk_costs_us(rows)
     assert got == pytest.approx((fixed, page))
+
+
+# --- ``--sparse``: a selecting layer's decode read ----------------------------
+@pytest.mark.parametrize("length", ab.SPARSE_LENGTHS)
+def test_a_sparse_case_has_every_slot_near_the_stated_length(length):
+    _, L, P, B, NP, *_ = ab.SPARSE_GEOMETRY
+    table, lengths = ab.sparse_case(0, B, NP, P, length)
+    assert table.shape == (B, NP) and lengths.shape == (B,)
+    assert (lengths <= length).all() and (lengths > length - ab.PAGE).all()
+    for b in range(B):
+        live = lengths[b] // ab.PAGE + 1
+        assert (table[b, :live] < P).all() and (table[b, live:] == P).all()
+    again = ab.sparse_case(0, B, NP, P, length)
+    assert (again[0] == table).all() and (again[1] == lengths).all()
+
+
+def test_the_sparse_geometry_is_the_benchmarks_configuration():
+    import json
+
+    from ray_dynamic_batching_tpu.ops import sparse_attention as sparse
+
+    root = Path(__file__).resolve().parents[1]
+    tag, L, P, B, NP, N, K, H, n_index, Hi, topk = ab.SPARSE_GEOMETRY
+    cfg = json.loads((root / "benchmark" / "configs"
+                      / f"{tag}.json").read_text())
+    dec, llm = cfg["program"]["decoder_config"], cfg["deployment"]["llm"]
+    assert (L, N, K, H) == (dec["num_layers"], dec["num_heads"],
+                            dec["num_kv_heads"], dec["head_dim"])
+    assert (n_index, Hi, topk) == (dec["index_heads"], dec["index_head_dim"],
+                                   dec["index_topk"])
+    assert (B, P) == (llm["num_slots"], llm["kv_pool_pages"])
+    assert NP == llm["max_len"] // llm["page_size"]
+    assert max(ab.SPARSE_LENGTHS) < llm["max_len"]
+    assert set(ab.SPARSE_FORMS) == {
+        sparse.FORM_FLOOR, sparse.FORM_MASK, "gather"}
+    sa = cfg["sa_config"]
+    assert (sa["indexer_num_heads"], sa["indexer_head_dim"], sa["topk"],
+            sa["indexer_num_kv_heads"]) == (n_index, Hi, topk, 1)

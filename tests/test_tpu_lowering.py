@@ -307,6 +307,145 @@ class TestPagedKernelCompilesForV5e:
         assert da.decode_paths()[-1].form == want
 
 
+class TestSparseKernelCompilesForV5e:
+    """``ops/sparse_attention.py``'s mask-form kernel at the configuration
+    that has an indexer (24 slots, a table of 144 pages, 32/4 heads of 128:
+    a block of FOUR key heads folded in one contraction, ``[128, 4, 128] ->
+    [512, 128]``, which the paged kernel's own rule leaves to the per-head
+    fold) and at 8 key heads (the shared flat fold); ONE bf16 key head is
+    under the page copy's tiling and declines by name
+    (``_mask_form_declines``). Nothing runs:
+    ``tools/run_kernel_ab.py --sparse`` on the chip says what it costs."""
+
+    @pytest.mark.parametrize("kv", [4, 8])
+    def test_the_mask_form_compiles(self, kv, one_chip):
+        from jax.experimental.compilation_cache import compilation_cache
+
+        from ray_dynamic_batching_tpu.ops import sparse_attention as sparse
+
+        B, NP, ps, H, N, L, P = 24, 144, 128, 128, 32, 8, 3456
+        struct = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dt, sharding=one_chip)
+        f = jax.jit(lambda q, k, v, table, lengths, chosen:
+                    sparse.sparse_paged_decode_attention(
+                        q, k, v, table, lengths, chosen, layer=3,
+                        interpret=False))
+        pool = struct((L, P, ps, kv, H), jnp.bfloat16)
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            f.lower(struct((B, 1, N, H), jnp.bfloat16), pool, pool,
+                    struct((B, NP), jnp.int32), struct((B,), jnp.int32),
+                    struct((B, NP * ps), jnp.bool_)).compile()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+            compilation_cache.reset_cache()
+
+
+class TestSelectionsOperationsInTheCompiledPrograms:
+    """``sparse_select_dev_share_pct.batch`` finds the index scan and the
+    top-k in a device trace by the HLO lines of the cell's programs (the
+    TPU's trace carries no scope). Compiled here for the described chip at
+    the cell's own shapes, the decode step and the widest chunk: with the
+    selection ON each layer's k-th-key search is a loop the metric's
+    ``loops`` takes whole and the scores are among its ``ops``; with the
+    selection OFF (``index_topk`` as large as the cache: the scores are
+    dead code) no loop is left and ``ops`` take no more than a staircase
+    mask. Nothing runs: the trace of a chip run says what each costs."""
+
+    @staticmethod
+    def _taken(text, args):
+        """(loops the metric takes whole, stable names its ``ops`` take
+        outside them and outside fusions) of a compiled program's text."""
+        import re
+
+        from benchmark.trace_reduce import Event, stable_name
+
+        comps, cur = {}, None
+        for line in text.splitlines():
+            head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
+            if head:
+                cur = comps.setdefault(head.group(1), [])
+            elif cur is not None and line.startswith("  "):
+                cur.append(line.strip().removeprefix("ROOT "))
+        loop_rx = re.compile(args["loops"])
+        loops = [ln for lines in comps.values() for ln in lines
+                 if " while(" in ln and loop_rx.search(ln)]
+        inside = set()
+        for ln in loops:
+            inside |= set(re.findall(r"(?:body|condition)=%?([\w.\-]+)", ln))
+        ops_rx = [re.compile(p) for p in args["ops"]]
+        names = set()
+        for comp, lines in comps.items():
+            if comp in inside or "fused_computation" in comp:
+                continue
+            for ln in lines:
+                name = stable_name(Event(ln, 0.0, 1.0, {}))
+                if any(rx.search(name) for rx in ops_rx):
+                    names.add(name)
+        return loops, names
+
+    @pytest.mark.parametrize("selection", ["on", "off"])
+    def test_the_metrics_patterns_take_the_selection_and_nothing_else(
+            self, selection, one_chip, monkeypatch):
+        import dataclasses
+        import json
+        import pathlib
+
+        from jax.experimental.compilation_cache import compilation_cache
+
+        from ray_dynamic_batching_tpu.models.causal_lm import CausalLM
+        from ray_dynamic_batching_tpu.models.decoder import DecoderConfig
+
+        root = pathlib.Path(__file__).resolve().parent.parent / "benchmark"
+        cfg = json.loads(
+            (root / "configs" / "keye-vl2-30b-ep8-1chip.json").read_text())
+        args = json.loads(
+            (root / "layer_metrics" / "sparse_select_dev_share_pct.batch.json"
+             ).read_text())["args"]
+        llm = cfg["deployment"]["llm"]
+        dc = DecoderConfig(**cfg["program"]["decoder_config"])
+        if selection == "off":
+            dc = dataclasses.replace(dc, index_topk=dc.max_seq_len)
+        m = CausalLM(dc, name="m", dtype=jnp.bfloat16)
+        B, ps = llm["num_slots"], llm["page_size"]
+        W, NP = max(llm["prompt_buckets"]), llm["max_len"] // ps
+        struct = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dt, sharding=one_chip)
+        placed = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+            lambda x: struct(x.shape, x.dtype), tree)
+        cache = placed(jax.eval_shape(lambda: m.make_paged_cache(
+            B, llm["kv_pool_pages"], ps, llm["max_len"])))
+        # weights as served: bfloat16
+        p = jax.tree_util.tree_map(
+            lambda x: struct(x.shape, jnp.bfloat16),
+            jax.eval_shape(m.init, jax.random.PRNGKey(0)))
+        # the dispatchers ask the backend: take the chip's branches
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            decode = jax.jit(lambda *a: m.decode_step_paged(*a)).lower(
+                p, struct((B, 1), jnp.int32), cache,
+                struct((B,), jnp.bool_)).compile().as_text()
+            chunk = jax.jit(lambda *a: m.prefill_chunk_paged(*a)).lower(
+                p, struct((2, W), jnp.int32), struct((2, W), jnp.int32),
+                cache, struct((2, NP), jnp.int32), struct((2,), jnp.int32),
+                struct((2,), jnp.int32)).compile().as_text()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+            compilation_cache.reset_cache()
+        for text, scores in ((decode, f"fusion_f32_{B}_{NP * ps}_"),
+                             (chunk, f"fusion_u32_2_{W}_{NP * ps}_")):
+            loops, names = self._taken(text, args)
+            if selection == "on":
+                assert len(loops) == dc.num_layers
+                assert scores in names
+            else:
+                assert not loops
+                assert all("_pred_" in n for n in names), names
+
+
 class TestRegisteredDecodersLowerForTPU:
     """Geometries discovered from the MODEL REGISTRY — not hand-picked
     shapes — so a new decoder family is covered the moment it registers.

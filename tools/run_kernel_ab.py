@@ -27,7 +27,15 @@ once a program and not once a kernel. Read every kernel PR's costs with
 it (``PERF.md``); the file keeps the parent commit's rows (the grid-walk
 kernel's live and dead grid steps) under ``parent``.
 
-Usage: python tools/run_kernel_ab.py [out_dir] [--iters N] [--paged]
+``--sparse`` measures a selecting layer's decode read (index scores, top-k
+and attention over the selection) at the configuration that has an indexer,
+at three cached lengths, into ``sparse_decode.json``: the two forms of
+``ops/sparse_attention.py`` (the mask form it ships; the floor, which the
+tool reaches by making the mask form's kernel decline) and a GATHER form
+that lives here (:func:`gather_form_decode`: the selected rows only; slower
+below ~30k positions a slot, so the program does not carry it).
+
+Usage: python tools/run_kernel_ab.py [out_dir] [--iters N] [--paged|--sparse]
                                      [--only tag1,tag2] [--out-name F]
 Writes <out_dir>/<F> (default kernel_ab.json, paged_steps.json with
 ``--paged``, in profiles/tpu_v5e) and prints one JSON summary line.
@@ -79,6 +87,32 @@ PAGED_GEOMETRIES = [
 SLIDING = {"k-exaone-236b-ep8-1chip-window128": 128}
 LIVE_SHARES = (0.125, 0.5, 1.0)
 PAGE = 128
+
+# ``--sparse``: a selecting layer's decode read (ops/sparse_attention.py) in
+# each of its forms, at the configuration that has an indexer: (tag, L, P,
+# B, NP, N, K, H, index heads, index head, topk), and the cached lengths
+# the cell's slots decode at (its shortest, its mean, a full table).
+SPARSE_GEOMETRY = ("keye-vl2-30b-ep8-1chip", 8, 3456, 24, 144, 32, 4, 128,
+                   16, 64, 2048)
+SPARSE_LENGTHS = (4608, 9216, 18300)
+SPARSE_FORMS = ("floor", "mask", "gather")
+
+
+def sparse_case(seed: int, B: int, NP: int, P: int, length: int):
+    """A page table and lengths with every slot at ``length`` cached
+    positions, less a draw of up to a page from ``seed``; physical pages a
+    permutation of the pool, entries past the length the sentinel ``P``."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    lengths = length - rng.integers(0, PAGE, B)
+    live = int(lengths.max()) // PAGE + 1
+    pages = np.resize(rng.permutation(P), B * live).reshape(B, live)
+    table = np.full((B, NP), P, np.int32)
+    table[:, :live] = pages
+    for b in range(B):
+        table[b, lengths[b] // PAGE + 1:] = P
+    return table, lengths.astype(np.int32)
 
 
 def paged_case(seed: int, B: int, NP: int, P: int, share: float):
@@ -263,6 +297,137 @@ def paged_main(out_dir: str, out_name: str, iters: int, only) -> int:
     return 0 if ok and backend != "cpu" else 1
 
 
+def gather_form_decode(q, k, v, page_table, kv_lengths, layer: int, select):
+    """A selecting layer's decode read that touches the SELECTED rows only:
+    ``k[layer, page_of(s), s % ps]`` gathered for each slot's top-k
+    positions and folded by the slab path over ``[B, topk, K, H]``. Flat in
+    the slot's length (4.0 ms a layer at the benchmark's widths on a v5e,
+    the row gather 2.2 of it) where the mask form costs 0.42 ms + 0.12 a
+    thousand positions: they cross near 30k positions a slot."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_dynamic_batching_tpu.models.decoder import paged_window_mask
+    from ray_dynamic_batching_tpu.ops import attention as attn
+    from ray_dynamic_batching_tpu.ops import sparse_attention as sparse
+
+    P, ps = k.shape[1], k.shape[2]
+    NP, H = page_table.shape[1], q.shape[-1]
+    safe = jnp.minimum(page_table, P - 1)
+    win = paged_window_mask(kv_lengths, NP * ps, 1)[:, 0]      # [B, 1, S]
+    scores = sparse.index_scores(select.q, select.w,
+                                 sparse._index_keys(select, layer, safe))
+    # top_k is stable: of equal scores the lower position comes first.
+    vals, pos = jax.lax.top_k(jnp.where(win, scores, -jnp.inf)[:, 0],
+                              min(select.topk, NP * ps))
+    page, off = jnp.take_along_axis(safe, pos // ps, axis=1), pos % ps
+    return attn._dense_attention(
+        q, k[layer, page, off][..., :H], v[layer, page, off][..., :H],
+        causal=False, mask=(vals > -jnp.inf)[:, None, None, :], scale=None,
+        k_scale=None, v_scale=None,
+        declines=["A/B tool: the gather form"], gathered=True)
+
+
+def _time_sparse(iters: int):
+    """Rows (one a form a length) of a selecting layer's decode read: us a
+    layer of a program that chains one read a layer (scores, top-k and
+    attention together: what a decode substep pays a layer), and the worst
+    gap to the floor on the same inputs."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_dynamic_batching_tpu.models.decoder import pool_head_dim
+    from ray_dynamic_batching_tpu.ops import attention as attn
+    from ray_dynamic_batching_tpu.ops import sparse_attention as sparse
+
+    tag, L, P, B, NP, N, K, H, n_index, Hi, topk = SPARSE_GEOMETRY
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    shape = (L, P, PAGE, K, pool_head_dim(H))
+    k = jax.random.normal(keys[0], shape, jnp.bfloat16)
+    v = jax.random.normal(keys[1], shape, jnp.bfloat16)
+    q = jax.random.normal(keys[2], (B, 1, N, H), jnp.bfloat16)
+    pool = jax.random.normal(
+        keys[3], (L, P, PAGE, pool_head_dim(Hi)), jnp.bfloat16)
+    q_i = jax.random.normal(keys[4], (B, 1, n_index, Hi), jnp.bfloat16)
+    w_i = jax.random.normal(keys[5], (B, 1, n_index), jnp.bfloat16)
+
+    def chain(form, layers):
+        def run(q, k, v, pool, table, lengths):
+            for layer in layers:
+                select = sparse.Selection(q_i, w_i, pool, topk)
+                if form == "gather":
+                    q = gather_form_decode(q, k, v, table, lengths, layer,
+                                           select)
+                else:
+                    q = attn.dot_product_attention(
+                        q, k, v, page_table=table, kv_lengths=lengths,
+                        layer=layer, select=select)
+            return q
+        return jax.jit(run)
+
+    cases = [(n, *map(jnp.asarray, sparse_case(0, B, NP, P, n)))
+             for n in SPARSE_LENGTHS]
+    rows, refs = [], {}
+    declines = sparse._mask_form_declines
+    for form in SPARSE_FORMS:
+        if form == "floor":     # what a read the kernel declines takes
+            sparse._mask_form_declines = lambda *a: "A/B tool: the floor"
+        one, program = chain(form, [L - 1]), chain(form, range(L))
+        try:
+            for n, table, lengths in cases:
+                out = one(q, k, v, pool, table, lengths)
+                refs.setdefault(n, out)            # the floor comes first
+                program(q, k, v, pool, table, lengths).block_until_ready()
+                samples = []
+                for _ in range(5):
+                    t0 = time.perf_counter()
+                    for _ in range(iters):
+                        res = program(q, k, v, pool, table, lengths)
+                    res.block_until_ready()
+                    samples.append(
+                        (time.perf_counter() - t0) * 1e6 / (iters * L))
+                rows.append({
+                    "geometry": tag, "form": form, "length": n,
+                    "layer_us": statistics.median(samples),
+                    "layer_us_min_max": [min(samples), max(samples)],
+                    "rows_live": int(lengths.sum()) + B,
+                    "rows_selected": int(jnp.minimum(
+                        lengths + 1, topk).sum()),
+                    "max_abs_diff": float(jnp.max(jnp.abs(
+                        out.astype(jnp.float32)
+                        - refs[n].astype(jnp.float32)))),
+                })
+        finally:
+            sparse._mask_form_declines = declines
+    return rows
+
+
+def sparse_main(out_dir: str, out_name: str, iters: int) -> int:
+    import jax
+
+    backend = jax.default_backend()
+    rows = _time_sparse(iters)
+    record = {"backend": backend,
+              "device_kind": jax.devices()[0].device_kind,
+              "captured": time.strftime("%Y%m%dT%H%M%S"), "iters": iters,
+              "geometry": SPARSE_GEOMETRY[0], "rows": rows}
+    for r in rows:
+        print(f"{r['geometry']}: {r['form']} at {r['length']} positions: "
+              f"{r['layer_us']:.1f} us a layer ({r['rows_selected']} of "
+              f"{r['rows_live']} rows selected), max |form - floor| "
+              f"{r['max_abs_diff']:.2e}", flush=True)
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, out_name), "w") as f:
+        json.dump(record, f, indent=1)
+        f.write("\n")
+    print(json.dumps({
+        "metric": "sparse_decode_layer_us", "backend": backend,
+        "layer_us": {f"{r['form']}@{r['length']}": r["layer_us"]
+                     for r in rows}}), flush=True)
+    ok = all(r["max_abs_diff"] < 0.1 for r in rows)
+    return 0 if ok and backend != "cpu" else 1
+
+
 def _time_attention(backend: str, q, k, v, mask, iters: int,
                     k_scale=None, v_scale=None):
     """Median ms/step for the dispatched attention substep."""
@@ -297,6 +462,10 @@ def main() -> int:
     iters = 20
     if "--iters" in sys.argv:
         iters = int(sys.argv[sys.argv.index("--iters") + 1])
+    if "--sparse" in sys.argv:
+        out_name = (sys.argv[sys.argv.index("--out-name") + 1]
+                    if "--out-name" in sys.argv else "sparse_decode.json")
+        return sparse_main(out_dir, out_name, iters)
     if "--paged" in sys.argv:
         only = (set(sys.argv[sys.argv.index("--only") + 1].split(","))
                 if "--only" in sys.argv else None)
