@@ -203,6 +203,41 @@ class _ChunkTrain:
     started_ms: float = 0.0
 
 
+class _IssuedTurn(NamedTuple):
+    """A decode scan dispatched and not fetched yet: what
+    ``DecodeEngine._complete_turn`` needs to fetch and harvest it, as taken
+    at the dispatch (the loop runs admission and the next chunk group's
+    dispatch between the two)."""
+
+    packed: Any                     # device array: tokens, advanced, lengths
+    seq: int                        # the program's number (``_note_issue``)
+    h: int
+    t_dispatch: float
+    t_issued: float
+    queued_behind: int
+    active_at_dispatch: np.ndarray  # the mask the scan ran with
+    prev_tokens: np.ndarray         # draft catch-up window head
+    trains: int
+    kv_pages_live: float
+    kv_rows: Tuple[int, int]
+
+
+class _IssuedGroup(NamedTuple):
+    """A chunk group dispatched and not completed yet (``finals``: rows that
+    end their prompt, whose first tokens ``first`` carries)."""
+
+    first: Any                      # device array: first tokens (+ routing)
+    seq: int
+    trains: List[_ChunkTrain]
+    finals: List[Tuple[int, _ChunkTrain]]
+    group: int                      # compiled rows (>= len(trains))
+    t_dispatch: float
+    t_issued: float
+    queued_behind: int
+    active: int
+    pending: int
+
+
 class Turn(NamedTuple):
     """One device dispatch of the engine, as the engine thread saw it: a
     record of ``DecodeEngine.turns``. ``kind`` is ``"turn"`` (a decode or
@@ -211,8 +246,15 @@ class Turn(NamedTuple):
     jitted call returned), ``t_fetched`` (the result reached the host;
     0.0 where nothing was fetched — a non-final chunk — so the device may
     still be running it), ``t_done`` (harvest / registration done). The
-    host gap before a dispatch is its ``t_dispatch`` less the previous
-    record's ``t_fetched``: host time with an empty device. The load the
+    ring is in ``t_dispatch`` order; a scan is fetched LAST (the loop issues
+    the next chunk group behind it first), so a record's ``t_dispatch`` may
+    precede the previous record's ``t_fetched``. ``queued_behind`` counts
+    the programs issued before this one whose completion the host had not
+    seen at ``t_dispatch`` (a fetch of program k proves every program
+    issued up to k done; 0: the device was known empty). The host gap
+    before a dispatch with ``queued_behind`` 0 is its ``t_dispatch`` less
+    the previous record's ``t_fetched``: host time with an empty device.
+    The load the
     engine stood under — ``trains`` at the dispatch; ``queue_len``,
     ``pages_allocated`` and ``positions_cached`` as the record is written,
     at ``t_done`` — is what :func:`summarize_turns` shows beside each of
@@ -265,6 +307,7 @@ class Turn(NamedTuple):
     moe_pairs: int = 0
     kv_rows_live: int = 0
     kv_rows_selected: int = 0
+    queued_behind: int = 0
 
 
 # Sized for the benchmark's 51 s window at several times the cells'
@@ -282,8 +325,12 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
     ring): dispatches and scans held (and ``dropped`` by the bounded ring),
     decode substeps per scan, mean slot occupancy over the scans' substeps,
     and the host gaps — a dispatch's ``t_dispatch`` less the previous
-    record's ``t_fetched``, where that one was fetched and no idle wait lay
-    between: host time with an empty device. ``host_gap_share`` is their
+    record's ``t_fetched``, where that one was fetched, no idle wait lay
+    between and the dispatch found nothing queued on the device
+    (``queued_behind`` 0): host time with an empty device.
+    ``overlapped_dispatch_share`` is the dispatches with ``queued_behind``
+    above 0 over all dispatches: how often the host's work for a program
+    hid behind another's run. ``host_gap_share`` is the gaps'
     sum over ``span_ms`` (default: first dispatch to last ``t_done``),
     ``host_gap_ms`` their median, p99, maximum and sum with its split:
     ``harvest`` (fetch -> the earlier record's work done) and ``feed`` (from
@@ -341,8 +388,10 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
     gaps = [
         (cur.t_dispatch - prev.t_fetched, prev, cur)
         for prev, cur in zip(turns, turns[1:])
-        if prev.t_fetched and not cur.after_idle
+        if prev.t_fetched and not cur.after_idle and not cur.queued_behind
     ]
+    out["overlapped_dispatch_share"] = sum(
+        1 for t in turns if t.queued_behind) / len(turns)
     if span_ms is None:
         span_ms = turns[-1].t_done - turns[0].t_dispatch
     if span_ms > 0:
@@ -828,6 +877,17 @@ class DecodeEngine:
         self.turns_dropped = 0
         self._last_scan: Optional[Turn] = None  # newest "turn" record
         self._idled = False
+        # Programs issued, and the newest of them a fetch has proven done:
+        # their difference at a dispatch is its ``Turn.queued_behind``.
+        self._programs_issued = 0
+        self._programs_seen = 0
+        # What ``_iterate`` has issued and not completed: the scan, and the
+        # chunk groups dispatched behind it (completed after it, in order).
+        self._issued_turn: Optional[_IssuedTurn] = None
+        self._issued_groups: List[_IssuedGroup] = []
+        # Prefill tokens spent behind the scan in flight: the one budget
+        # of a turn, so the pump before the NEXT scan spends only the rest.
+        self._prefill_spent = 0
         # Every phase span names its engine, by the process's count of
         # engines and the chip it is pinned to: all Python threads of a
         # process share one line name in the profiler's trace, and a
@@ -1004,19 +1064,21 @@ class DecodeEngine:
                       active: int, trains: int,
                       moe: Sequence[int] = (0, 0, 0, 0),
                       kv_pages_live: float = 0,
-                      kv_rows: Tuple[int, int] = (0, 0)) -> Turn:
+                      kv_rows: Tuple[int, int] = (0, 0),
+                      queued_behind: int = 0) -> Turn:
         """Append this dispatch's record to the turn ring (its work on the
         host is done: ``t_done`` is now). ``moe``: the dispatch's routing
         counters as fetched (``Turn``'s ``moe_*`` fields);
         ``kv_pages_live``: :meth:`_kv_pages_live` as the scan was
-        dispatched; ``kv_rows``: :meth:`_kv_rows` then."""
+        dispatched; ``kv_rows``: :meth:`_kv_rows` then; ``queued_behind``:
+        :meth:`_note_issue` then."""
         rec = Turn(
             kind, t_dispatch, t_issued, t_fetched, now_ms(),
             substeps, tokens, active, trains, len(self.queue),
             self._allocator.allocated_pages,
             int(self._len_host.sum()), self._idled,
             *(int(c) for c in moe[:3]), kv_pages_live, int(moe[3]),
-            *kv_rows,
+            *kv_rows, queued_behind,
         )
         if len(self.turns) == self.turns.maxlen:
             self.turns_dropped += 1
@@ -1025,6 +1087,18 @@ class DecodeEngine:
             self._last_scan = rec
         self._idled = False
         return rec
+
+    def _note_issue(self) -> Tuple[int, int]:
+        """Number the program about to be dispatched; returns (its number,
+        the programs issued before it that no fetch has proven done)."""
+        behind = self._programs_issued - self._programs_seen
+        self._programs_issued += 1
+        return self._programs_issued, behind
+
+    def _note_fetched(self, seq: int) -> None:
+        """Program ``seq``'s result reached the host: the device runs its
+        programs in order, so every one issued up to it is done."""
+        self._programs_seen = max(self._programs_seen, seq)
 
     def _kv_pages_live(self, window: int = 1) -> int:
         """Page-table entries, summed over all slots, that the scan about
@@ -1824,33 +1898,45 @@ class DecodeEngine:
         ))
         self._train_slots.add(slot_idx)
 
-    def _pump_prefill(self) -> int:
-        """Spend at most ``prefill_token_budget`` tokens advancing
-        pending chunk trains — the engine-owned interleave. FCFS
+    def _pump_prefill(self, budget: Optional[int] = None,
+                      behind_turn: bool = False) -> int:
+        """Spend at most ``prefill_token_budget`` tokens (or ``budget``,
+        what is left of it) advancing pending chunk trains — the
+        engine-owned interleave. FCFS
         head-first (oldest train's TTFT first); same-width trains batch
         into ONE chunk program per dispatch. Page-starved trains park for the
         round (counted) instead of evicting live streams; a round where
         NOTHING could progress while no stream is active triggers the
         starvation valve (requeue the newest train) so parked trains
         can never deadlock the pool among themselves. Returns the
-        prefill tokens spent (0: nothing was dispatched)."""
+        prefill tokens spent (0: nothing was dispatched).
+
+        ``behind_turn``: a scan is issued and not fetched (``_iterate``).
+        Groups are then dispatched and NOT completed (``_issued_groups``),
+        up to the first that ends a prompt; pages come from the free list
+        alone, and a train that would need a cache pin shed or a spilled
+        prefix read back parks, uncounted, for the pump after the harvest."""
         if not self._trains:
             return 0
         with self._phase("rdb.engine.prefill",
                          trains=len(self._trains)) as ph:
-            tokens = self._spend_prefill_budget()
+            tokens = self._spend_prefill_budget(
+                self.prefill_token_budget if budget is None else budget,
+                behind_turn)
             ph.set_metadata(tokens=tokens)
             return tokens
 
-    def _spend_prefill_budget(self) -> int:
+    def _spend_prefill_budget(self, budget: int, behind_turn: bool) -> int:
         """One round of :meth:`_pump_prefill`; returns the tokens spent."""
         model_tag = {"model": self.model.name}
-        budget = self.prefill_token_budget
+        offered = budget
         parked: set = set()
         dispatched_any = False
         while budget > 0:
+            # A train at its prompt's end waits for its group's completion.
             head = next(
-                (t for t in self._trains if id(t) not in parked), None
+                (t for t in self._trains
+                 if id(t) not in parked and t.pos < t.total), None
             )
             if head is None or head.C > budget:
                 break
@@ -1867,22 +1953,28 @@ class DecodeEngine:
                     if len(members) >= cap:
                         break
                     if (t is head or id(t) in parked
-                            or t.C != head.C
+                            or t.C != head.C or t.pos >= t.total
                             or t.total - t.base > t.C):
                         continue
                     members.append(t)
             ready = []
             for t in members:
-                self._maybe_borrow_prefix(t)
-                if self._grant_train_pages(t):
+                if (self._maybe_borrow_prefix(t, spilled=not behind_turn)
+                        and self._grant_train_pages(
+                            t, reclaim=not behind_turn)):
                     ready.append(t)
                 else:
                     parked.add(id(t))
-                    PREFILL_STARVED.inc(tags=model_tag)
+                    if not behind_turn:
+                        PREFILL_STARVED.inc(tags=model_tag)
             if not ready:
                 continue
             try:
-                self._dispatch_chunk_group(ready)
+                issued = self._issue_chunk_group(ready)
+                if behind_turn:
+                    self._issued_groups.append(issued)
+                else:
+                    self._complete_chunk_group(issued)
             except Exception as e:  # noqa: BLE001 — no-dangle rule
                 logger.exception(
                     "%s: chunk dispatch failed", self.model.name
@@ -1896,11 +1988,15 @@ class DecodeEngine:
                 # Colocation fairness: co-tenant engines get their scans
                 # between chunk dispatches.
                 self.interleave_hook()
-        if (not dispatched_any and self._trains
+            if behind_turn and issued.finals:
+                # Its prefix pages publish at its completion, after the
+                # scan's harvest: the rest of the budget waits for that.
+                break
+        if (parked and not dispatched_any
                 and not self._active_mask.any()):
             self._relieve_train_starvation()
         PREFILL_PENDING.set(float(len(self._trains)), tags=model_tag)
-        return self.prefill_token_budget - budget
+        return offered - budget
 
     def _drain_prefill(self) -> None:
         """Pump pending chunk trains to completion (tests and manual
@@ -1928,28 +2024,34 @@ class DecodeEngine:
                 "(page-starved with no active streams)"
             )
 
-    def _maybe_borrow_prefix(self, train: _ChunkTrain) -> None:
+    def _maybe_borrow_prefix(self, train: _ChunkTrain,
+                             spilled: bool = True) -> bool:
         """Longest-shared-page-prefix CoW borrow, resolved at the
         train's FIRST chunk dispatch (not at dequeue): earlier trains
         from the same burst publish their pages at completion, and an
         identical queued prompt must share them — a dequeue-time lookup
         would always miss. Borrowed pages
         become the train's head; ``pos``/``base`` jump past the shared
-        positions."""
+        positions. False, with nothing resolved, where the lookup missed
+        in HBM and the spill tier may not be read now (``spilled`` False:
+        a scan is in flight, and a reload writes the pool)."""
         if (self.paged_prefix is None
                 or train.pos != train.base or train.pos != 0
                 or train.opts.get("_shared_pages", 0)
                 or train.opts.get("_prefix_done")
                 or train.total <= self.page_size):
-            return
-        train.opts["_prefix_done"] = True
+            return True
         phit = self.paged_prefix.lookup(train.prompt)
+        if (phit is None and not spilled and self.host_spill is not None
+                and len(self.host_spill)):
+            return False
+        train.opts["_prefix_done"] = True
         if phit is None and self.host_spill is not None:
             phit = self._reload_spilled_prefix(train.prompt)
         if phit is None:
             PREFIX_MISSES.inc(tags={"model": self.model.name,
                                     "granularity": "page"})
-            return
+            return True
         shared_ids, shared_len = phit
         head = list(shared_ids)
         self._allocator.incref(head)
@@ -1962,13 +2064,17 @@ class DecodeEngine:
         )
         PREFIX_HITS.inc(tags={"model": self.model.name,
                               "granularity": "page"})
+        return True
 
-    def _grant_train_pages(self, train: _ChunkTrain) -> bool:
+    def _grant_train_pages(self, train: _ChunkTrain,
+                           reclaim: bool = True) -> bool:
         """Per-chunk page grant: extend the train's page run to cover
         the NEXT chunk's real positions (final chunks also cover the
         first generated token — or the first spec verify window on spec
         engines, the shared ``spec_scratch_pages`` rule). Cache pins
-        shed first; a still-starved train parks (False) — live streams
+        shed first (not with ``reclaim`` False: behind a scan in flight
+        the free list alone is taken; a spill reads the pool); a
+        still-starved train parks (False) — live streams
         are never evicted to feed an admission."""
         take = min(train.C, train.total - train.pos)
         final = train.pos + take >= train.total
@@ -1988,7 +2094,7 @@ class DecodeEngine:
         delta = need - len(train.opts["_pages"])
         if delta <= 0:
             return True
-        while not self._allocator.can_alloc(delta):
+        while reclaim and not self._allocator.can_alloc(delta):
             if not self._reclaim_cache_pins():
                 break
         if not self._allocator.can_alloc(delta):
@@ -1996,12 +2102,15 @@ class DecodeEngine:
         train.opts["_pages"].extend(self._allocator.alloc(delta))
         return True
 
-    def _dispatch_chunk_group(self, trains: List[_ChunkTrain]) -> None:
+    def _issue_chunk_group(self, trains: List[_ChunkTrain]) -> _IssuedGroup:
         """ONE pages-direct chunk program for up to a compiled group of
         same-width trains: chunk k/v scatter through per-row page-table
         rows, first token sampled in-program for final rows. Pad rows
         duplicate row 0 (identical data to identical pages — idempotent,
-        the group-admission convention)."""
+        the group-admission convention). Prepared and dispatched, nothing
+        fetched: the trains stand at their next position, and a train at
+        its prompt's end stays in ``_trains`` until
+        :meth:`_complete_chunk_group`."""
         W = trains[0].C
         n = len(trains)
         active = int(self._active_mask.sum())
@@ -2052,6 +2161,7 @@ class DecodeEngine:
                 bias_ids[i] = bias_ids[0]
                 bias_vals[i] = bias_vals[0]
             tokmask = np.stack([tokens, mask])
+        seq, behind = self._note_issue()
         t_dispatch = now_ms()
         with self._phase("rdb.engine.prefill.dispatch"):
             first, self._cache = self._chunk_paged_fn(
@@ -2065,19 +2175,27 @@ class DecodeEngine:
                 jnp.asarray(bias_vals),
             )
         t_issued = now_ms()
+        for t in trains:
+            t.pos = min(t.pos + W, t.total)
+        PREFILL_CHUNKS.inc(n, tags={"model": self.model.name})
+        return _IssuedGroup(first, seq, trains, finals, group, t_dispatch,
+                            t_issued, behind, active, pending)
+
+    def _complete_chunk_group(self, issued: _IssuedGroup) -> None:
+        """What a dispatched group leaves to do: where rows ended their
+        prompt, fetch their first tokens, retire the trains, publish their
+        prefix pages and register the slots; then the ring's record."""
         t_fetched = 0.0
         moe = (0, 0, 0, 0)
-        if finals:
+        if issued.finals:
             with self._phase("rdb.engine.prefill.fetch"):
-                first_host = np.asarray(first)  # rdb-lint: disable=host-sync-in-hot-path (THE one fetch per chunk dispatch: the fused first-token ids — TTFT ends here, never at a logits round-trip)
+                first_host = np.asarray(issued.first)  # rdb-lint: disable=host-sync-in-hot-path (THE one fetch per chunk dispatch: the fused first-token ids — TTFT ends here, never at a logits round-trip)
             t_fetched = now_ms()
+            self._note_fetched(issued.seq)
             if self._moe_kw:
-                moe = first_host[group:]
+                moe = first_host[issued.group:]
         with self._phase("rdb.engine.prefill.finish"):
-            for t in trains:
-                t.pos = min(t.pos + W, t.total)
-            PREFILL_CHUNKS.inc(n, tags={"model": self.model.name})
-            for i, t in finals:
+            for i, t in issued.finals:
                 self._retire_train(t)
                 if self.paged_prefix is not None:
                     # Publish BEFORE registration: a stop-on-first-token
@@ -2093,8 +2211,11 @@ class DecodeEngine:
                     )
                 self._register(t.slot_idx, t.req, int(first_host[i]),
                                t.opts, t_fetched)
-        self._log_dispatch("chunk", t_dispatch, t_issued, t_fetched, 0,
-                           W * n, active, pending, moe)
+        self._log_dispatch("chunk", issued.t_dispatch, issued.t_issued,
+                           t_fetched, 0,
+                           issued.trains[0].C * len(issued.trains),
+                           issued.active, issued.pending, moe,
+                           queued_behind=issued.queued_behind)
 
     def _retire_train(self, train: _ChunkTrain) -> None:
         if train in self._trains:
@@ -2835,6 +2956,7 @@ class DecodeEngine:
                         self._tokens[:, 0],
                         self._active_mask.astype(np.int32),
                     ])
+                seq, behind = self._note_issue()
                 t_dispatch = now_ms()
                 with self._phase("rdb.engine.turn.dispatch"):
                     packed, self._cache, self._dcache = self._spec_fn(
@@ -2852,6 +2974,7 @@ class DecodeEngine:
                 self._rollback_spec_scratch()
                 raise
             t_fetched = now_ms()
+            self._note_fetched(seq)
             with self._phase("rdb.engine.turn.harvest"):
                 links = self._turn_links(self._active_mask)
                 out = ph_host[: k + 1]        # [k+1, B]
@@ -2895,7 +3018,7 @@ class DecodeEngine:
             rec = self._log_dispatch("turn", t_dispatch, t_issued, t_fetched,
                                      1, 0, active, len(self._trains),
                                      kv_pages_live=kv_pages_live,
-                                     kv_rows=kv_rows)
+                                     kv_rows=kv_rows, queued_behind=behind)
             if links is not None:
                 self._record_turn_span(rec, links, k, spec=True)
 
@@ -2907,7 +3030,17 @@ class DecodeEngine:
 
     def _plain_turn(self, ph: Any, horizon: Optional[int]) -> None:
         """One plain decode scan inside the open ``rdb.engine.turn`` phase
-        ``ph``: prepare, dispatch, fetch, harvest, and the ring's record."""
+        ``ph``, fetched at once: prepare, dispatch, fetch, harvest, and the
+        ring's record."""
+        self._complete_turn(ph, self._issue_turn(ph, horizon))
+
+    def _issue_turn(self, ph: Any, horizon: Optional[int]) -> _IssuedTurn:
+        """Prepare and dispatch one plain decode scan inside the open
+        ``rdb.engine.turn`` phase ``ph``; nothing is fetched. Until
+        :meth:`_complete_turn` the slots' host state (mask, tokens, lengths,
+        tables, sampling arrays) must stand as dispatched: admission and a
+        chunk group's DISPATCH touch none of it, a group's completion
+        (``_register``) does."""
         with self._phase("rdb.engine.turn.prepare"):
             h = horizon if horizon is not None else self._pick_horizon()
             # Pages for every position this scan can write, allocated
@@ -2934,6 +3067,7 @@ class DecodeEngine:
                 tok_idx,
             ])
         ph.set_metadata(horizon=h, active=active, spec=0)
+        seq, behind = self._note_issue()
         t_dispatch = now_ms()
         with self._phase("rdb.engine.turn.dispatch"):
             packed, self._cache, self._counts = self._decode_fn(
@@ -2947,10 +3081,22 @@ class DecodeEngine:
                 bias_vals_d,
                 self._counts,
             )
-        t_issued = now_ms()
+        return _IssuedTurn(packed, seq, h, t_dispatch, now_ms(), behind,
+                           active_at_dispatch, prev_tokens,
+                           len(self._trains), kv_pages_live, kv_rows)
+
+    def _complete_turn(self, ph: Any, issued: _IssuedTurn) -> None:
+        """Fetch and harvest an issued scan inside the open
+        ``rdb.engine.turn`` phase ``ph``, and write the ring's record. The
+        harvest goes by the mask the scan was DISPATCHED with: a slot
+        registered since took no part in it."""
+        h, active_at_dispatch = issued.h, issued.active_at_dispatch
+        active = int(active_at_dispatch.sum())
+        ph.set_metadata(horizon=h, active=active, spec=0)
         with self._phase("rdb.engine.turn.fetch"):
-            packed_host = np.asarray(packed)          # ONE fetch per dispatch  # rdb-lint: disable=host-sync-in-hot-path (THE one fetch per dispatch: packed carries tokens+advanced+lengths)
+            packed_host = np.asarray(issued.packed)   # ONE fetch per dispatch  # rdb-lint: disable=host-sync-in-hot-path (THE one fetch per dispatch: packed carries tokens+advanced+lengths)
         t_fetched = now_ms()
+        self._note_fetched(issued.seq)
         with self._phase("rdb.engine.turn.harvest"):
             links = self._turn_links(active_at_dispatch)
             toks_host = packed_host[:h]               # [h, B]
@@ -2966,7 +3112,7 @@ class DecodeEngine:
                 # k/v landed at positions [len, len+h) are
                 # [pending, emitted[:-1]].
                 window = np.concatenate(
-                    [prev_tokens, toks_host[: h - 1].T], axis=1
+                    [issued.prev_tokens, toks_host[: h - 1].T], axis=1
                 )  # [B, h]
                 counts = advanced_host.sum(axis=0).astype(np.int32)
                 self._dcache = self._draft_catchup_fn(
@@ -2976,18 +3122,23 @@ class DecodeEngine:
                     jnp.asarray(active_at_dispatch),
                     jnp.asarray(counts),
                 )
-            self._harvest(toks_host, advanced_host, lengths_host, h)
+            self._harvest(toks_host, advanced_host, lengths_host, h,
+                          active_mask=active_at_dispatch)
         rec = self._log_dispatch(
-            "turn", t_dispatch, t_issued, t_fetched, h, 0, active,
-            len(self._trains),
+            "turn", issued.t_dispatch, issued.t_issued, t_fetched, h, 0,
+            active, issued.trains,
             packed_host[2 * h + 1:, 0] if self._moe_kw else (0, 0, 0, 0),
-            kv_pages_live=kv_pages_live, kv_rows=kv_rows)
+            kv_pages_live=issued.kv_pages_live, kv_rows=issued.kv_rows,
+            queued_behind=issued.queued_behind)
         if links is not None:
             self._record_turn_span(rec, links, h)
 
     def _harvest(self, toks_host, advanced_host, lengths_host, h: int,
-                 blocked_finishes_capacity: bool = True) -> None:
-        """Distribute a scan's [h, B] outputs to their slots.
+                 blocked_finishes_capacity: bool = True,
+                 active_mask: Optional[np.ndarray] = None) -> None:
+        """Distribute a scan's [h, B] outputs to their slots: those of
+        ``active_mask``, the mask the scan was dispatched with (default:
+        the live one — nothing registered since the dispatch).
 
         Vectorized: at 64 slots x a 32-substep horizon the former
         per-token Python loop executed ~2k interpreter iterations per
@@ -3010,9 +3161,11 @@ class DecodeEngine:
         n_out == 0 (no room for even the target's own token) finishes,
         plus the shared trailing max_len check.
         """
+        if active_mask is None:
+            active_mask = self._active_mask
         active_idx = [
             i for i, slot in enumerate(self._slots)
-            if not slot.free and self._active_mask[i]
+            if not slot.free and active_mask[i]
         ]
         if not active_idx:
             return
@@ -3412,20 +3565,76 @@ class DecodeEngine:
     # --- loop --------------------------------------------------------------
     def run_until_idle(self, timeout_s: float = 60.0) -> None:
         """Drive admissions + steps until queue and slots are empty (tests,
-        offline batch generation)."""
+        offline batch generation): the serving loop's own iteration."""
         deadline = time.monotonic() + timeout_s
         with self._device_ctx():
             while time.monotonic() < deadline:
-                self._service_fabric()
-                admitted = self._admit()
-                self._pump_prefill()
-                if self._active_mask.any():
-                    self._step()
-                elif (not admitted and not self._trains
+                admitted, turned = self._iterate()
+                if (not turned and not admitted and not self._trains
                         and len(self.queue) == 0
                         and not self._fabric_pending()):
                     return
         raise TimeoutError(f"{self.model.name}: decode did not drain")
+
+    def _iterate(self) -> Tuple[int, bool]:
+        """ONE iteration of the engine (``_loop`` and ``run_until_idle``
+        both run it): fabric, admission, the prefill budget, then a decode
+        scan that is FETCHED LAST. Between the scan's dispatch and its fetch
+        run what does not need its result — admission, and the dispatch of
+        the next chunk group — so the device holds the next program while
+        the host harvests this one, and the host prepares behind a running
+        program. A turn has ONE prefill budget, spent at the earliest point
+        a train is pending: what the section behind the scan spent, the pump
+        before the next scan does not spend again (never two budgets between
+        two scans). Everything issued is completed before the iteration
+        ends, so the fabric, the headroom's evictions, ``stop``,
+        ``abort_active`` and ``release_buffers`` find nothing in flight.
+        Returns (requests admitted before the scan, whether a scan ran)."""
+        self._service_fabric()
+        admitted = self._admit()
+        left = self.prefill_token_budget - self._prefill_spent
+        self._prefill_spent = 0
+        if left > 0:
+            self._pump_prefill(left)
+        if not self._active_mask.any():
+            return admitted, False
+        if self._dcache is not None:
+            # A draft model's rounds and catch-up keep their order: each
+            # scan fetched at once.
+            self._step()
+        else:
+            with self._phase("rdb.engine.turn") as ph:
+                self._issued_turn = self._issue_turn(ph, None)
+            try:
+                self._admit()
+                self._prefill_spent = self._pump_prefill(behind_turn=True)
+            finally:
+                self._complete_issued()
+        self._publish_gauges()
+        return admitted, True
+
+    def _complete_issued(self) -> None:
+        """Fetch and harvest the issued scan, then complete the chunk
+        groups dispatched behind it, in their order (first tokens,
+        ``_register``, the ring's records after the scan's)."""
+        issued, self._issued_turn = self._issued_turn, None
+        groups, self._issued_groups = self._issued_groups, []
+        try:
+            with self._phase("rdb.engine.turn") as ph:
+                self._complete_turn(ph, issued)
+        finally:
+            if groups:
+                with self._phase("rdb.engine.prefill",
+                                 trains=len(self._trains), tokens=0):
+                    for group in groups:
+                        try:
+                            self._complete_chunk_group(group)
+                        except Exception as e:  # noqa: BLE001 — no-dangle rule
+                            logger.exception(
+                                "%s: chunk completion failed",
+                                self.model.name)
+                            for t in group.trains:
+                                self._drop_train(t, e)
 
     def _publish_gauges(self) -> None:
         """The gauge writes after a turn."""
@@ -3442,13 +3651,8 @@ class DecodeEngine:
         with self._device_ctx():
             while self._run.is_set():
                 try:
-                    self._service_fabric()
-                    self._admit()
-                    self._pump_prefill()
-                    if self._active_mask.any():
-                        self._step()
-                        self._publish_gauges()
-                    elif not self._trains:
+                    _admitted, turned = self._iterate()
+                    if not turned and not self._trains:
                         with self._phase("rdb.engine.idle_wait"):
                             self.queue.wait_for_requests(self.idle_wait_s)
                         self._idled = True

@@ -151,9 +151,10 @@ def test_every_phase_is_recorded_on_the_engine_thread_with_its_attributes(
                and a["horizon"] in (1, engine.ttft_horizon, engine.decode_horizon)
                for a in turns)
     # the ring and the trace count the same scans (the trace may have
-    # started inside one)
+    # started inside one); the loop's scan opens the phase twice: once
+    # round its preparation and dispatch, once round its fetch and harvest
     scans = sum(1 for t in engine.turns if t.kind == "turn")
-    assert scans - 1 <= len(turns) <= scans
+    assert 2 * scans - 2 <= len(turns) <= 2 * scans
 
 
 def test_children_nest_inside_their_parents(traced):
@@ -172,11 +173,26 @@ def test_children_nest_inside_their_parents(traced):
         assert kids
         for a, b in zip(kids, kids[1:]):
             assert a[2] <= b[1]
-    complete = [p for p in parents if p[0] == "rdb.engine.turn"]
-    for p in complete:     # every scan: prepare, dispatch, fetch, harvest
+    halves = [[s[0].rsplit(".", 1)[1] for s in spans
+               if s[0] in CHILDREN[p[0]] and p[1] <= s[1] and s[2] <= p[2]]
+              for p in parents if p[0] == "rdb.engine.turn"]
+    # every scan of the loop: prepare and dispatch, then (behind the
+    # admission and the chunk group issued in between) fetch and harvest
+    assert all(h in (["prepare", "dispatch"], ["fetch", "harvest"])
+               for h in halves)
+    if halves[0] == ["fetch", "harvest"]:      # the trace began inside one
+        halves = halves[1:]
+    assert halves[0::2] == [["prepare", "dispatch"]] * len(halves[0::2])
+    assert halves[1::2] == [["fetch", "harvest"]] * len(halves[1::2])
+    # a chunk group's two halves likewise: dispatched under one prefill
+    # phase; where it ended a prompt, fetched and finished under another
+    # when it was issued behind a scan
+    for p in parents:
+        if p[0] != "rdb.engine.prefill":
+            continue
         kids = [s[0].rsplit(".", 1)[1] for s in spans
                 if s[0] in CHILDREN[p[0]] and p[1] <= s[1] and s[2] <= p[2]]
-        assert kids == ["prepare", "dispatch", "fetch", "harvest"]
+        assert kids[0] in ("prepare", "fetch", "finish"), kids
 
 
 def test_the_phases_tile_the_engine_threads_time(traced):
@@ -187,9 +203,16 @@ def test_the_phases_tile_the_engine_threads_time(traced):
     covered = sum(e - s for _, s, e, _ in tops)
     extent = tops[-1][2] - tops[0][1]
     assert (extent - covered) / extent < UNCOVERED_SHARE
-    # one loop iteration with work: fabric, admit, [prefill], turn, publish
+    # one loop iteration with work: fabric, admit, [prefill], turn (issued),
+    # admit, [prefill: the next group issued behind the scan], turn
+    # (fetched, harvested), [prefill: that group completed], publish
     i = next(i for i, s in enumerate(tops) if s[0] == "rdb.engine.turn")
-    assert tops[i + 1][0] == "rdb.engine.publish"
+    after = [s[0] for s in tops[i + 1:i + 6]]
+    assert after[0] == "rdb.engine.admit"
+    j = after.index("rdb.engine.turn")
+    assert after[1:j] in ([], ["rdb.engine.prefill"])
+    k = after.index("rdb.engine.publish")
+    assert after[j + 1:k] in ([], ["rdb.engine.prefill"])
     before = [s[0] for s in tops[max(i - 3, 0):i]]
     assert before[-1] in ("rdb.engine.prefill", "rdb.engine.admit")
     assert "rdb.engine.fabric" in before and "rdb.engine.admit" in before
@@ -226,8 +249,13 @@ def test_ring_holds_one_record_per_dispatch_with_monotone_stamps(lm):
             assert t.active >= 1
         else:
             assert t.substeps == 0 and t.tokens > 0 and t.trains >= 1
+    # in dispatch order; a program is dispatched before the previous one's
+    # work is done only behind a scan not fetched yet, and says so
+    assert all(t.queued_behind >= 0 for t in ring)
     for a, b in zip(ring, ring[1:]):
-        assert a.t_dispatch <= b.t_dispatch and a.t_done <= b.t_dispatch
+        assert a.t_dispatch <= b.t_dispatch
+        assert a.t_done <= b.t_dispatch or (
+            b.queued_behind > 0 and a.kind == "turn" and b.kind == "chunk")
     # a chunk that finishes no prompt fetches nothing; one that does, does
     assert any(t.t_fetched == 0.0 for t in chunks)
     assert any(t.t_fetched for t in chunks)
@@ -331,7 +359,11 @@ def test_snapshot_sums_the_ring(lm):
         assert g["pages_allocated"] == prev.pages_allocated > 0
         assert g["positions_cached"] == prev.positions_cached > 0
     hg = s["host_gap_ms"]
-    assert hg["n"] == sum(1 for a in ring[:-1] if a.t_fetched)
+    # a gap: the earlier record fetched, and nothing queued on the device
+    # at the later one's dispatch
+    fetched = sum(1 for a, b in zip(ring, ring[1:])
+                  if a.t_fetched and not b.queued_behind)
+    assert hg["n"] == fetched
     assert hg["sum"] == pytest.approx(hg["harvest_sum"] + hg["feed_sum"])
     assert hg["p50"] <= hg["p99"] <= hg["max"] == pytest.approx(
         gaps[0]["gap_ms"], abs=1e-3)
@@ -340,9 +372,9 @@ def test_snapshot_sums_the_ring(lm):
     assert part["dispatches"] == len(ring) // 2
     assert part["host_gap_share"] == pytest.approx(
         part["host_gap_ms"]["sum"] / 1e6)
-    # a gap is counted only after a dispatch whose result was fetched
-    fetched = sum(1 for a in ring[:-1] if a.t_fetched)
     assert len(engine.turn_summary(longest=10 ** 6)["longest_gaps"]) == fetched
+    assert s["overlapped_dispatch_share"] == pytest.approx(
+        sum(1 for t in ring if t.queued_behind) / len(ring))
 
 
 # --- the ring's count of live page-table entries (ISSUE 28) ----------------------
@@ -400,3 +432,104 @@ def test_a_paged_scan_counts_the_entries_its_first_substep_may_attend(lm):
     assert snap["turns"]["kv_live_page_share"] == pytest.approx(live / walked)
     assert snap["kv_pool"]["pages_live"] == live
     assert snap["kv_pool"]["pages_scanned"] == walked
+
+
+# --- the ring's order and its gaps since a scan is fetched last (ISSUE 37) -----------
+def _at(kind, dispatch, fetched, done, behind=0, substeps=0, after_idle=False):
+    return Turn(kind, dispatch, dispatch + 1.0, fetched, done, substeps, 0, 2,
+                1, 0, 8, 100, after_idle)._replace(queued_behind=behind)
+
+
+# scan; the next chunk issued behind it before it is fetched; the next scan
+# behind that chunk (it ended no prompt: never fetched); a chunk that ends a
+# prompt, fetched after the scan before it; a scan to a device known empty;
+# a scan right after it
+OVERLAPPED = [
+    _at("turn", 100.0, 150.0, 152.0, substeps=1),
+    _at("chunk", 103.0, 0.0, 153.0, behind=1),
+    _at("turn", 156.0, 200.0, 202.0, behind=1, substeps=1),
+    _at("chunk", 159.0, 206.0, 208.0, behind=1),
+    _at("turn", 212.0, 260.0, 262.0, substeps=2),      # gap 6 = 2 + 4
+    _at("turn", 265.0, 300.0, 301.0, substeps=2),      # gap 5 = 2 + 3
+]
+
+
+def test_a_gap_counts_only_before_a_dispatch_that_found_nothing_queued():
+    from ray_dynamic_batching_tpu.engine.decode import summarize_turns
+
+    s = summarize_turns(OVERLAPPED, 4, span_ms=1000.0, longest=10)
+    hg = s["host_gap_ms"]
+    # not 103 - 150 (the chunk went out BEFORE that scan was fetched), not
+    # 159 - 200 either: the device held a program both times
+    assert hg["n"] == 2 and hg["sum"] == pytest.approx(6.0 + 5.0)
+    assert hg["harvest_sum"] == pytest.approx(2.0 + 2.0)
+    assert hg["feed_sum"] == pytest.approx(4.0 + 3.0)
+    assert s["host_gap_share"] == pytest.approx(11.0 / 1000.0)
+    assert [(g["at_ms"], g["after"], g["before"]) for g in s["longest_gaps"]] \
+        == [(212.0, "chunk", "turn"), (265.0, "turn", "turn")]
+    assert all(g["gap_ms"] >= 0 for g in s["longest_gaps"])
+    assert s["overlapped_dispatch_share"] == pytest.approx(3 / 6)
+    assert s["substeps_per_dispatch"] == pytest.approx(6 / 4)
+
+
+@pytest.mark.parametrize("behind, gaps, share", [
+    ((0, 0, 0), 2, 0.0),          # nothing queued anywhere: both gaps count
+    ((0, 1, 0), 1, 1 / 3),        # the second dispatch hid behind the first
+    ((0, 1, 1), 0, 2 / 3),
+    ((0, 0, 2), 1, 1 / 3),        # a count above one is one dispatch
+])
+def test_overlapped_dispatch_share_counts_dispatches_not_programs(
+        behind, gaps, share):
+    from ray_dynamic_batching_tpu.engine.decode import summarize_turns
+
+    ring = [_at("turn", 10.0 * i, 10.0 * i + 5, 10.0 * i + 6, behind=b,
+                substeps=1) for i, b in enumerate(behind)]
+    s = summarize_turns(ring, 4)
+    assert s["overlapped_dispatch_share"] == pytest.approx(share)
+    assert s.get("host_gap_ms", {"n": 0})["n"] == gaps
+    assert len(s["longest_gaps"]) == gaps
+
+
+def test_a_ring_with_no_overlap_sums_as_it_did():
+    """The old order's ring (every record's work done before the next
+    dispatch, ``queued_behind`` 0 wherever the earlier record was fetched):
+    every field of the summary the parent gave, at the parent's value."""
+    from ray_dynamic_batching_tpu.engine.decode import summarize_turns
+
+    ring = [
+        _at("turn", 100.0, 150.0, 152.0, substeps=2),
+        _at("chunk", 155.0, 0.0, 157.0),                     # gap 5 = 2 + 3
+        _at("turn", 158.0, 210.0, 211.0, behind=1, substeps=2),
+        _at("turn", 300.0, 340.0, 341.0, substeps=8, after_idle=True),
+        _at("turn", 345.0, 380.0, 382.0, substeps=4),        # gap 5 = 1 + 4
+    ]
+    s = summarize_turns(ring, 4, span_ms=1000.0)
+    assert s.pop("overlapped_dispatch_share") == pytest.approx(1 / 5)
+    gaps = s.pop("longest_gaps")
+    assert [g["gap_ms"] for g in gaps] == [5.0, 5.0]
+    assert s.pop("host_gap_ms") == {
+        "n": 2, "p50": 5.0, "p99": 5.0, "max": 5.0, "sum": 10.0,
+        "harvest_sum": 3.0, "feed_sum": 7.0, "harvest_p50": 1.5,
+        "feed_p50": 3.5}
+    assert s == {"dispatches": 5, "scans": 4, "dropped": 0,
+                 "substeps_per_dispatch": 4.0,
+                 "mean_occupancy": 2 * 16 / (4 * 16),
+                 "host_gap_share": 10.0 / 1000.0}
+
+
+def test_the_engines_ring_is_in_dispatch_order_with_overlap(lm):
+    """A started engine's ring: records in ``t_dispatch`` order though a
+    scan's record is written after the chunk's dispatch behind it, and the
+    summary's gaps are all non-negative."""
+    engine, queue = _engine(lm)
+    reqs = _submit(queue, engine.model.name, lens=(5, 40, 30, 44), new=12)
+    engine.run_until_idle(timeout_s=300)
+    for r in reqs:
+        r.future.result(timeout=5)
+    ring = list(engine.turns)
+    assert [t.t_dispatch for t in ring] == sorted(t.t_dispatch for t in ring)
+    assert any(t.queued_behind for t in ring)
+    s = engine.turn_summary(longest=10 ** 6)
+    assert all(g["gap_ms"] >= 0 and g["harvest_ms"] >= 0 and g["feed_ms"] >= 0
+               for g in s["longest_gaps"])
+    assert 0.0 < s["overlapped_dispatch_share"] < 1.0

@@ -481,14 +481,14 @@ class TestHostSync:
         chunk dispatch is a finding without a reasoned pragma."""
         from tools.lint.host_sync import HOT_FUNCTIONS
 
-        assert {"_pump_prefill", "_dispatch_chunk_group",
+        assert {"_pump_prefill", "_complete_chunk_group",
                 "_spend_prefill_budget", "_grant_train_pages"} <= \
             HOT_FUNCTIONS["engine/decode.py"]
         report = lint_fixture(tmp_path, "engine/decode.py", """
             import numpy as np
 
-            def _dispatch_chunk_group(self, trains):
-                return np.asarray(trains[0].first)
+            def _complete_chunk_group(self, issued):
+                return np.asarray(issued.first)
         """)
         assert rules_found(report) == ["host-sync-in-hot-path"]
 

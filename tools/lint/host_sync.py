@@ -37,11 +37,16 @@ HOT_FUNCTIONS: Dict[str, Set[str]] = {
     "engine/decode.py": {
         "_step", "_spec_step", "_plain_turn", "_harvest",
         "_interleave_step",
+        # ISSUE 37: one iteration of the loop, its scan issued and fetched
+        # LAST (the fetch lives in ``_complete_turn``), and what it
+        # completes behind the scan.
+        "_iterate", "_issue_turn", "_complete_turn", "_complete_issued",
         # ISSUE 15: the token-budget prefill scheduler runs between
         # every decode turn — its chunk dispatches are steady-state
         # serving latency exactly like the scan, with ONE designed
         # fetch (the fused first-token ids) per chunk program.
-        "_pump_prefill", "_spend_prefill_budget", "_dispatch_chunk_group",
+        "_pump_prefill", "_spend_prefill_budget",
+        "_issue_chunk_group", "_complete_chunk_group",
         "_grant_train_pages",
     },
     "engine/worker.py": {"_run_placement"},
