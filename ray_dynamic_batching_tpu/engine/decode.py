@@ -57,6 +57,7 @@ generator batches, ``serve/batching.py:209-276``).
 from __future__ import annotations
 
 import collections
+import contextlib
 import heapq
 import itertools
 import math
@@ -68,6 +69,7 @@ from typing import (
     Any,
     Callable,
     Dict,
+    Iterator,
     List,
     NamedTuple,
     Optional,
@@ -314,6 +316,65 @@ class Turn(NamedTuple):
 # highest dispatch rate (~30/s at a 33 ms one-substep scan): 160/s.
 _TURN_RING = 8192
 _ENGINE_ORDINAL = itertools.count()   # numbers the engines of a process
+
+STARTUP_PROGRAM = "rdb.startup.warmup.program"
+STARTUP_BUILD = "rdb.startup.engine_build"
+STARTUP_DEPLOY = "rdb.startup.deploy"
+# What the compile ledger leaves on a start-up span it charged.
+_STARTUP_COMPILE_MS = ("trace_ms", "lower_ms", "backend_ms")
+
+
+def startup_rows(spans: Sequence[Any]) -> List[Dict[str, Any]]:
+    """Start-up spans (``utils/tracing.py``: ``Tracer.startup``) as rows
+    for an operator, oldest first: ``id`` / ``parent``, ``name``, the
+    span's attributes (``replica``, ``program``, ``key``, and what the
+    compile ledger charged it: ``trace_ms``, ``lower_ms``, ``backend_ms``,
+    ``cache``, ...), ``start_ms`` from the first row's start, ``dur_ms``,
+    and ``self_ms``: the time no child span covers."""
+    spans = sorted(spans, key=lambda sp: sp.start_ms)
+    covered: Dict[int, float] = collections.defaultdict(float)
+    for sp in spans:
+        if sp.parent_id is not None:
+            covered[sp.parent_id] += sp.duration_ms()
+    t0 = spans[0].start_ms if spans else 0.0
+    return [dict(sp.attributes, id=sp.span_id, parent=sp.parent_id,
+                 name=sp.name, start_ms=sp.start_ms - t0,
+                 dur_ms=sp.duration_ms(),
+                 self_ms=sp.duration_ms() - covered[sp.span_id])
+            for sp in spans]
+
+
+def startup_sums(rows: Sequence[Dict[str, Any]]) -> Dict[str, Any]:
+    """Where a start's seconds went, from :func:`startup_rows` (the one
+    definition of the benchmark's ``startup_*`` metrics): over the warmed
+    programs' spans, ``trace_lower_s`` (Python, paid at every start),
+    ``backend_s`` (a cache read when warm, XLA and Mosaic when cold) and
+    ``first_run_s`` (their ``run_ms``: executable load, uploads, the first
+    execution), which three are those spans' time; ``engine_build_s``;
+    and, under a ``rdb.startup.deploy`` span (``deploy_s``; else None),
+    ``unaccounted_s``: the deploy less those four (controller, router,
+    gaps between replicas, the spans' self time). ``cache_hits`` /
+    ``cache_misses`` count over every row."""
+    programs = [r for r in rows if r["name"] == STARTUP_PROGRAM]
+    out: Dict[str, Any] = {
+        "trace_lower_s": sum(r.get("trace_ms", 0.0) + r.get("lower_ms", 0.0)
+                             for r in programs) / 1000.0,
+        "backend_s": sum(r.get("backend_ms", 0.0)
+                         for r in programs) / 1000.0,
+        "first_run_s": sum(r.get("run_ms", 0.0) for r in programs) / 1000.0,
+        "engine_build_s": sum(r["dur_ms"] for r in rows
+                              if r["name"] == STARTUP_BUILD) / 1000.0,
+        "cache_hits": int(sum(r.get("cache_hits", 0) for r in rows)),
+        "cache_misses": int(sum(r.get("cache_misses", 0) for r in rows)),
+        "deploy_s": None, "unaccounted_s": None,
+    }
+    deploys = [r for r in rows if r["name"] == STARTUP_DEPLOY]
+    if deploys:
+        out["deploy_s"] = sum(r["dur_ms"] for r in deploys) / 1000.0
+        out["unaccounted_s"] = out["deploy_s"] - (
+            out["trace_lower_s"] + out["backend_s"] + out["first_run_s"]
+            + out["engine_build_s"])
+    return out
 
 
 def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
@@ -573,6 +634,7 @@ class DecodeEngine:
     ``make_cache``, ``prefill``, ``decode_step``, and ``cfg``.
     """
 
+    @_tracer().startup(STARTUP_BUILD)
     def __init__(
         self,
         model: Any,
@@ -1053,6 +1115,9 @@ class DecodeEngine:
         # SUCCESSFUL loop iterations, so a perpetually-failing _step (device
         # OOM, corrupt params) reads as a stall even though the thread lives.
         self.last_heartbeat = time.monotonic()
+        _tracer().open_startup().attributes.update(
+            replica=self._phase_tag, slots=num_slots, pages=self.num_pages,
+            pool_bytes=self._pool_stats["resident_bytes"])
 
     def _phase(self, name: str, **attrs: Any):
         """One engine-loop phase on the profiler's clock
@@ -1531,8 +1596,13 @@ class DecodeEngine:
         startup, instead of stalling a request 20-40s mid-serving."""
         ledger = get_ledger()
         before = ledger.counts(phase=PHASE_WARMUP)
-        with ledger.warming(), self._device_ctx():
+        with _tracer().startup("rdb.startup.warmup",
+                               replica=self._phase_tag) as span, \
+                ledger.warming(), self._device_ctx():
             self._warmup_impl()
+            span.attributes["programs"] = sum(
+                1 for sp in _tracer().startup_spans()
+                if sp.parent_id == span.span_id)
         after = ledger.counts(phase=PHASE_WARMUP)
         if after == before:
             # Zero new compiles: every program was already cached (this
@@ -1551,6 +1621,23 @@ class DecodeEngine:
                 "whichever is wrong before this engine serves"
             )
 
+    @contextlib.contextmanager
+    def _warming(self, program: str, key: str) -> Iterator[None]:
+        """One warmed program's start-up span: its arguments, its first
+        call and the wait for its result. The compile ledger charges the
+        span with what traced, lowered and compiled (or was read from the
+        cache) under it; ``run_ms`` is the rest of it: the executable's
+        load, the uploads and the first execution."""
+        with _tracer().startup(STARTUP_PROGRAM, replica=self._phase_tag,
+                               program=program, key=key) as span:
+            try:
+                yield
+            finally:
+                span.end_ms = time.monotonic() * 1000.0
+                a = span.attributes
+                a["run_ms"] = span.duration_ms() - sum(
+                    a.get(k, 0.0) for k in _STARTUP_COMPILE_MS)
+
     def _warmup_impl(self) -> None:
         # The pages-direct chunk program at every (bucket, group) shape
         # the pump can produce, plus the (1, C_max) long-train shape
@@ -1560,32 +1647,33 @@ class DecodeEngine:
         # a real page.
         for b in self.prompt_buckets:
             for g in self._admit_group_sizes():
-                first, self._cache = self._chunk_paged_fn(
-                    self.params,
-                    jnp.stack([
-                        jnp.zeros((g, b), jnp.int32),
-                        jnp.ones((g, b), jnp.int32),
-                    ]),
-                    self._cache,
-                    jnp.full((g, self._n_table_entries),
-                             self.num_pages, jnp.int32),
-                    jnp.stack([
-                        jnp.full((g,), self.num_slots, jnp.int32),
-                        jnp.zeros((g,), jnp.int32),
-                        jnp.zeros((g,), jnp.int32),
-                        jnp.zeros((g,), jnp.int32),
-                        jnp.zeros((g,), jnp.int32),
-                        jnp.zeros((g,), jnp.int32),
-                    ]),
-                    jnp.stack([
-                        jnp.zeros((g,), jnp.float32),
-                        jnp.ones((g,), jnp.float32),
-                    ]),
-                    jnp.zeros((g, self.max_bias_entries), jnp.int32),
-                    jnp.zeros((g, self.max_bias_entries),
-                              jnp.float32),
-                )
-                first.block_until_ready()
+                with self._warming("chunk_prefill", f"b={b},g={g}"):
+                    first, self._cache = self._chunk_paged_fn(
+                        self.params,
+                        jnp.stack([
+                            jnp.zeros((g, b), jnp.int32),
+                            jnp.ones((g, b), jnp.int32),
+                        ]),
+                        self._cache,
+                        jnp.full((g, self._n_table_entries),
+                                 self.num_pages, jnp.int32),
+                        jnp.stack([
+                            jnp.full((g,), self.num_slots, jnp.int32),
+                            jnp.zeros((g,), jnp.int32),
+                            jnp.zeros((g,), jnp.int32),
+                            jnp.zeros((g,), jnp.int32),
+                            jnp.zeros((g,), jnp.int32),
+                            jnp.zeros((g,), jnp.int32),
+                        ]),
+                        jnp.stack([
+                            jnp.zeros((g,), jnp.float32),
+                            jnp.ones((g,), jnp.float32),
+                        ]),
+                        jnp.zeros((g, self.max_bias_entries), jnp.int32),
+                        jnp.zeros((g, self.max_bias_entries),
+                                  jnp.float32),
+                    )
+                    first.block_until_ready()
         self._warmup_decode()
 
     def _warmup_decode(self) -> None:
@@ -1597,40 +1685,45 @@ class DecodeEngine:
             jnp.zeros((B,), jnp.float32),
         ])
         warm_samp_i = jnp.zeros((2, B), jnp.int32)
-        for h in {1, self.ttft_horizon, self.decode_horizon}:
-            packed, self._cache, self._counts = self._decode_fn(
-                self.params,
-                self._cache,
-                jnp.zeros((3, B), dtype=jnp.int32),
-                h,
-                warm_samp_f,
-                warm_samp_i,
-                jnp.zeros((B, self.max_bias_entries), jnp.int32),
-                jnp.zeros((B, self.max_bias_entries), jnp.float32),
-                self._counts,
-            )
-            packed.block_until_ready()
+        horizons = sorted({1, self.ttft_horizon, self.decode_horizon})
+        for h in horizons:
+            with self._warming("decode_step", f"h={h}"):
+                packed, self._cache, self._counts = self._decode_fn(
+                    self.params,
+                    self._cache,
+                    jnp.zeros((3, B), dtype=jnp.int32),
+                    h,
+                    warm_samp_f,
+                    warm_samp_i,
+                    jnp.zeros((B, self.max_bias_entries), jnp.int32),
+                    jnp.zeros((B, self.max_bias_entries), jnp.float32),
+                    self._counts,
+                )
+                packed.block_until_ready()
         if self._dcache is not None:
-            packed, self._cache, self._dcache = self._spec_fn(
-                self.params,
-                self._cache,
-                self._dcache,
-                jnp.zeros((2, self.num_slots), dtype=jnp.int32),
-                jnp.zeros((self.num_slots, self.max_bias_entries), jnp.int32),
-                jnp.zeros((self.num_slots, self.max_bias_entries), jnp.float32),
-            )
-            packed.block_until_ready()
+            with self._warming("spec_verify", f"k={self.spec_tokens}"):
+                packed, self._cache, self._dcache = self._spec_fn(
+                    self.params,
+                    self._cache,
+                    self._dcache,
+                    jnp.zeros((2, B), dtype=jnp.int32),
+                    jnp.zeros((B, self.max_bias_entries), jnp.int32),
+                    jnp.zeros((B, self.max_bias_entries), jnp.float32),
+                )
+                packed.block_until_ready()
             # The catch-up runs after every PLAIN step of a spec engine —
             # one window shape per horizon; compile them now, not at the
             # first sampled request mid-serving.
-            for h in {1, self.ttft_horizon, self.decode_horizon}:
-                self._dcache = self._draft_catchup_fn(
-                    self.draft_params,
-                    self._dcache,
-                    jnp.zeros((self.num_slots, h), dtype=jnp.int32),
-                    jnp.zeros((self.num_slots,), dtype=bool),
-                    jnp.zeros((self.num_slots,), dtype=jnp.int32),
-                )
+            for h in horizons:
+                with self._warming("draft_catchup", f"h={h}"):
+                    self._dcache = self._draft_catchup_fn(
+                        self.draft_params,
+                        self._dcache,
+                        jnp.zeros((B, h), dtype=jnp.int32),
+                        jnp.zeros((B,), dtype=bool),
+                        jnp.zeros((B,), dtype=jnp.int32),
+                    )
+                    self._dcache.lengths.block_until_ready()
             self._dcache = self._dcache.replace(
                 lengths=self._put(np.zeros((self.num_slots,), np.int32))
             )
@@ -3820,6 +3913,28 @@ class DecodeEngine:
             self._table_walked,
         )
 
+    def startup_summary(self) -> Dict[str, Any]:
+        """This engine's start, from the process's start-up log
+        (``Tracer.startup_spans``): its own spans (``replica`` is its
+        phase tag), the replica's and the deploy's above them and the
+        deploy's register as ``rows`` (:func:`startup_rows`), and
+        :func:`startup_sums` of them. Under a deploy of several replicas
+        the others' time is in this engine's ``unaccounted_s``. Empty of
+        rows once the bounded log has turned over."""
+        log = {sp.span_id: sp for sp in _tracer().startup_spans()}
+        mine = {i: sp for i, sp in log.items()
+                if sp.attributes.get("replica") == self._phase_tag}
+        for sp in list(mine.values()):
+            while sp.parent_id in log and sp.parent_id not in mine:
+                sp = log[sp.parent_id]
+                mine[sp.span_id] = sp
+        # ... and what stands beside them for all replicas (the register).
+        mine.update((i, sp) for i, sp in log.items()
+                    if sp.parent_id in mine
+                    and "replica" not in sp.attributes)
+        rows = startup_rows(list(mine.values()))
+        return dict(startup_sums(rows), rows=rows)
+
     def snapshot(self) -> Dict[str, Any]:
         """Operator-facing state dump (the engine analogue of
         ``LiveScheduler.snapshot()``): slot/KV occupancy plus the
@@ -3871,6 +3986,7 @@ class DecodeEngine:
                 "pushes_out": self.pushes_out,
                 "pushes_in": self.pushes_in,
             },
+            "startup": self.startup_summary(),
         }
         if self._moe_kw:
             from ray_dynamic_batching_tpu.models.moe import routing_rule

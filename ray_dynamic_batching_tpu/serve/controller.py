@@ -73,6 +73,7 @@ from ray_dynamic_batching_tpu.serve.store import (
 )
 from ray_dynamic_batching_tpu.utils.logging import get_logger
 from ray_dynamic_batching_tpu.utils.sketch import QuantileSketch
+from ray_dynamic_batching_tpu.utils.tracing import tracer
 
 logger = get_logger("controller")
 
@@ -330,7 +331,9 @@ class ServeController:
         failover adoption: it re-binds the SAME config, so the restart
         budget / unhealthy verdict restored by ``_adopt`` must survive
         (only a genuinely fresh user deploy resets them)."""
-        with self._lock:
+        with self._lock, tracer().startup(
+                "rdb.startup.deploy", deployment=config.name,
+                replicas=config.num_replicas):
             if factory is not None:
                 self.register_factory(config.name, factory)
             if config.name not in self._factories:
@@ -521,6 +524,7 @@ class ServeController:
         })
 
     # --- state machine (ref deployment_state.py scale/heal) ---------------
+    @tracer().startup("rdb.startup.replica")
     def _start_replica(self, state: _DeploymentState) -> Replica:
         cfg = state.config
         with self.store.txn() as txn:
@@ -547,6 +551,9 @@ class ServeController:
                 strategy=cfg.placement_strategy,
             )
             devices = pg.bundle_devices(0)
+        tracer().open_startup().attributes.update(
+            replica=rid,
+            chips=",".join(str(getattr(d, "id", d)) for d in devices or ()))
         try:
             factory = state.factory
             if hasattr(factory, "make_replica"):
@@ -873,7 +880,9 @@ class ServeController:
             if [r.replica_id for r in state.replicas] != [
                 r.replica_id for r in state.router.replicas()
             ]:
-                self._publish(state)  # routing stops before deferred drains
+                # routing stops before deferred drains
+                with tracer().startup("rdb.startup.register"):
+                    self._publish(state)
             self._persist(txn, state)
         return deferred
 

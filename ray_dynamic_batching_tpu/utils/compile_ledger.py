@@ -20,14 +20,25 @@ compile to the jit program that triggered it:
   violation carrying the function, argument shapes, and triggering
   callsite — a named guilty hop, never a mystery stall.
 - every episode increments ``rdb_jit_compiles_total{fn,phase}`` (fn
-  label bounded — an unbounded cardinality bug cannot mint series) and
-  emits a ``jit.compile`` tracer span so recompiles join the PR-1
-  flight record and the PR-8 hop ledger.
+  label bounded — an unbounded cardinality bug cannot mint series).
+- an episode's time is split by what JAX reports: ``trace_ms``,
+  ``lower_ms`` and ``compile_ms`` (the backend: XLA and Mosaic on a
+  persistent-cache miss, the cache READ on a hit), each an event's SELF
+  time (a jit traced inside a trace reports inside its caller's
+  interval), so the three never exceed the wall time they were taken
+  over; ``cache_read_ms`` (a part OF ``compile_ms``), ``saved_ms`` and
+  the cache's hits and misses say which kind of start it was.
+- an episode is charged to ``(name, key)``: the key is the open
+  ``rdb.startup.warmup.program`` span's (``b=256,g=2`` / ``h=8``; ""
+  elsewhere), and that span — any open start-up span of
+  ``utils/tracing.py`` — is given the episode's parts as attributes.
 
-Compiles with no wrapped call on the stack land under
-``__unattributed__`` with a callsite walked from the Python stack; for
-those the episode unit degrades to one-per-``backend_compile``-burst
-(there is no call boundary to coalesce on — documented, not hidden).
+Compiles with no wrapped call on the stack are charged to the thread's
+innermost open start-up span's name (``rdb.startup.engine_build``,
+``rdb.startup.warmup.program``, ...: a closed set) and only with none
+open to ``__unattributed__``; for those the episode unit degrades to
+one-per-``backend_compile``-burst (there is no call boundary to
+coalesce on — documented, not hidden).
 
 ``tools/check_compiles.py`` is the CI gate over this ledger: warmup
 plus a canonical serving segment must stay inside the ratcheted budget
@@ -36,6 +47,7 @@ plus a canonical serving segment must stay inside the ratcheted budget
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
 import threading
@@ -59,12 +71,29 @@ PHASE_STARTUP = "startup"
 PHASE_WARMUP = "warmup"
 PHASE_STEADY = "steady"
 
-# Event names jax.monitoring emits per compilation stage (duration
-# listeners). Any of them firing means real (re)compilation work — a
-# cached dispatch emits none.
+# Event names jax.monitoring emits per compilation stage. Any of them
+# firing means real (re)compilation work — a cached dispatch emits none.
+# The first five are duration events, the last two plain ones; the cache's
+# all fire INSIDE the backend event's interval.
 _EV_TRACE = "/jax/core/compile/jaxpr_trace_duration"
 _EV_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _EV_BACKEND = "/jax/core/compile/backend_compile_duration"
+_EV_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+_EV_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
+_EV_HIT = "/jax/compilation_cache/cache_hits"
+_EV_MISS = "/jax/compilation_cache/cache_misses"
+
+# Event -> the part of an episode it adds to (milliseconds, or a count).
+_PART = {
+    _EV_TRACE: "trace_ms", _EV_LOWER: "lower_ms", _EV_BACKEND: "compile_ms",
+    _EV_CACHE_READ: "cache_read_ms", _EV_SAVED: "saved_ms",
+    _EV_HIT: "cache_hits", _EV_MISS: "cache_misses",
+}
+PARTS = tuple(_PART.values())
+# The three stages whose intervals nest (a jit called while another is
+# traced; an eager op run while one is lowered): each is booked its self
+# time.
+_STAGES = (_EV_TRACE, _EV_LOWER, _EV_BACKEND)
 
 # Hot-path fn labels are a small closed set (ops/jit_model.py registry
 # + __unattributed__); 16 leaves headroom without unbounding the series.
@@ -85,14 +114,13 @@ _tls = threading.local()
 
 
 class _Frame:
-    __slots__ = ("name", "fired", "trace_ms", "lower_ms", "compile_ms")
+    __slots__ = ("name", "parts")
 
     def __init__(self, name: str) -> None:
         self.name = name
-        self.fired = False
-        self.trace_ms = 0.0
-        self.lower_ms = 0.0
-        self.compile_ms = 0.0
+        # What fired under the call, by part; None until something does
+        # (a cached dispatch leaves it so).
+        self.parts: Optional[Dict[str, float]] = None
 
 
 def _frames() -> List[_Frame]:
@@ -100,6 +128,31 @@ def _frames() -> List[_Frame]:
     if stack is None:
         stack = _tls.frames = []
     return stack
+
+
+def _self_ms(duration_ms: float) -> float:
+    """A stage event's SELF time: its duration less the stage events of
+    this thread that fired inside its interval. An event fires at its
+    interval's end, on the thread that ran it, so an earlier event lies
+    inside this one exactly when it ENDED after this one started (two
+    intervals of one thread nest or follow each other). The thread keeps
+    the (end, duration) no enclosing interval has claimed yet: a first
+    trace holds some hundreds of inner ones; what is never claimed ages
+    out."""
+    seen = getattr(_tls, "stages", None)
+    if seen is None:
+        seen = _tls.stages = collections.deque(maxlen=4096)
+    end = time.monotonic() * 1000.0
+    start = end - duration_ms
+    inner = 0.0
+    while seen and seen[-1][0] > start:
+        inner += seen.pop()[1]
+    seen.append((end, duration_ms))
+    return max(duration_ms - inner, 0.0)
+
+
+def _new_parts() -> Dict[str, float]:
+    return dict.fromkeys(PARTS, 0.0)
 
 
 def current_program() -> str:
@@ -156,8 +209,8 @@ class CompileLedger:
         self._phase = PHASE_STARTUP
         self._warmup_depth = 0
         self._armed = False  # a warmup has completed; next phase steady
-        # fn -> {"episodes": int, "by_phase": {phase: int},
-        #        "trace_ms"/"lower_ms"/"compile_ms": float}
+        # fn -> {"episodes": int, "by_phase": {phase: int}, every one
+        #        of PARTS: float, "by_key": {key: the same less by_phase}}
         self._fns: Dict[str, Dict[str, Any]] = {}
         self._violations: List[Dict[str, Any]] = []
 
@@ -198,79 +251,95 @@ class CompileLedger:
             self._phase = PHASE_STEADY
 
     # --- recording ------------------------------------------------------
-    def _on_event(self, event: str, duration_ms: float) -> None:
+    def _on_event(self, event: str, value: float) -> None:
+        """One monitoring event on the thread that compiled: ``value`` is
+        milliseconds (a duration event) or 1 (a plain one)."""
+        part = _PART[event]
+        if event in _STAGES:
+            value = _self_ms(value)
         stack = _frames()
         if stack:
             fr = stack[-1]
-            fr.fired = True
-            if event == _EV_TRACE:
-                fr.trace_ms += duration_ms
-            elif event == _EV_LOWER:
-                fr.lower_ms += duration_ms
-            else:
-                fr.compile_ms += duration_ms
+            if fr.parts is None:
+                fr.parts = _new_parts()
+            fr.parts[part] += value
             return
-        # No wrapped call on this thread's stack: un-coalesced. Count
-        # one episode per backend burst; fold trace/lower time into the
-        # same bucket so the ms totals stay honest.
+        # No wrapped call on this thread's stack: un-coalesced. The open
+        # start-up span owns it, else nobody. Count one episode per
+        # backend burst; book every other part as it comes so the totals
+        # stay honest.
+        span = tracer().open_startup()
+        name = span.name if span is not None else UNATTRIBUTED
         if event == _EV_BACKEND:
-            self._record(
-                UNATTRIBUTED, shapes="", callsite=_callsite(),
-                trace_ms=0.0, lower_ms=0.0, compile_ms=duration_ms,
-            )
+            self._record(name, span, {part: value}, args=())
         else:
-            with self._lock:
-                rec = self._fn_rec(UNATTRIBUTED)
-                key = "trace_ms" if event == _EV_TRACE else "lower_ms"
-                rec[key] += duration_ms
+            self._book(name, span, {part: value}, episodes=0)
 
     def _fn_rec(self, name: str) -> Dict[str, Any]:
         assert_owner(self._lock)
         rec = self._fns.get(name)
         if rec is None:
-            rec = self._fns[name] = {
-                "episodes": 0, "by_phase": {},
-                "trace_ms": 0.0, "lower_ms": 0.0, "compile_ms": 0.0,
-            }
+            rec = self._fns[name] = dict(
+                _new_parts(), episodes=0, by_phase={}, by_key={})
         return rec
 
-    def _record(self, name: str, shapes: str, callsite: str,
-                trace_ms: float, lower_ms: float,
-                compile_ms: float) -> None:
-        end = time.monotonic() * 1000.0
+    def _book(self, name: str, span: Any, parts: Dict[str, float],
+              episodes: int) -> str:
+        """Add ``parts`` to ``name``'s totals, to its row for the open
+        start-up span's ``key`` and to that span's attributes; returns
+        the phase it was booked in."""
+        key = "" if span is None else str(span.attributes.get("key", ""))
         with self._lock:
             phase = self._phase
             rec = self._fn_rec(name)
-            rec["episodes"] += 1
-            rec["by_phase"][phase] = rec["by_phase"].get(phase, 0) + 1
-            rec["trace_ms"] += trace_ms
-            rec["lower_ms"] += lower_ms
-            rec["compile_ms"] += compile_ms
-            if phase == PHASE_STEADY:
-                self._violations.append({
-                    "fn": name, "phase": phase, "shapes": shapes,
-                    "callsite": callsite,
-                    "trace_ms": round(trace_ms, 3),
-                    "lower_ms": round(lower_ms, 3),
-                    "compile_ms": round(compile_ms, 3),
-                })
-        # Outside the ledger lock on purpose: the metric and tracer have
-        # their own (metrics-rank / plain) locks and neither needs ours.
+            row = rec["by_key"].get(key)
+            if row is None:
+                row = rec["by_key"][key] = dict(_new_parts(), episodes=0)
+            for tot in (rec, row):
+                tot["episodes"] += episodes
+                for k, v in parts.items():
+                    tot[k] += v
+            if episodes:
+                rec["by_phase"][phase] = (
+                    rec["by_phase"].get(phase, 0) + episodes)
+        if span is not None:
+            # The span is this thread's own until it closes. Its name for
+            # the backend's time is ``backend_ms``.
+            a = span.attributes
+            for k, v in parts.items():
+                k = "backend_ms" if k == "compile_ms" else k
+                a[k] = a.get(k, 0.0) + v
+            a["cache"] = ("miss" if a.get("cache_misses") else
+                          "hit" if a.get("cache_hits") else "off")
+        return phase
+
+    def _record(self, name: str, span: Any, parts: Dict[str, float],
+                args: Tuple[Any, ...]) -> None:
+        """One episode of ``name``. Who called and with what shapes is
+        worked out only for a steady-phase violation: nothing else
+        reads it."""
+        phase = self._book(name, span, parts, episodes=1)
+        # Outside the ledger lock on purpose: the metric has its own
+        # (metrics-rank) lock and does not need ours.
         COMPILES.inc(tags={"fn": name, "phase": phase})
-        total = trace_ms + lower_ms + compile_ms
-        tracer().record_span(
-            "jit.compile",
-            start_ms=end - total, end_ms=end,
-            fn=name, phase=phase, shapes=shapes, callsite=callsite,
-            trace_ms=round(trace_ms, 3), lower_ms=round(lower_ms, 3),
-            compile_ms=round(compile_ms, 3),
+        if phase != PHASE_STEADY:
+            return
+        violation = {
+            "fn": name, "phase": phase,
+            "shapes": _shape_sig(args) if args else "",
+            "callsite": _callsite(),
+            **{k: round(parts.get(k, 0.0), 3)
+               for k in ("trace_ms", "lower_ms", "compile_ms")},
+        }
+        with self._lock:
+            self._violations.append(violation)
+        logger.warning(
+            "steady-state compile: fn=%s shapes=%s at %s "
+            "(%.1f ms trace, %.1f ms lower, %.1f ms backend)",
+            name, violation["shapes"], violation["callsite"],
+            violation["trace_ms"], violation["lower_ms"],
+            violation["compile_ms"],
         )
-        if phase == PHASE_STEADY:
-            logger.warning(
-                "steady-state compile: fn=%s shapes=%s at %s "
-                "(%.1f ms trace, %.1f ms lower, %.1f ms backend)",
-                name, shapes, callsite, trace_ms, lower_ms, compile_ms,
-            )
 
     # --- instrumentation ------------------------------------------------
     def instrument(self, name: str,
@@ -285,15 +354,9 @@ class CompileLedger:
                 return fn(*args, **kwargs)
             finally:
                 stack.pop()
-                if frame.fired:
-                    self._record(
-                        name,
-                        shapes=_shape_sig(args),
-                        callsite=_callsite(),
-                        trace_ms=frame.trace_ms,
-                        lower_ms=frame.lower_ms,
-                        compile_ms=frame.compile_ms,
-                    )
+                if frame.parts is not None:
+                    self._record(name, tracer().open_startup(),
+                                 frame.parts, args)
         wrapper.__name__ = f"ledger[{name}]"
         wrapper.__wrapped__ = fn
         return wrapper
@@ -330,15 +393,18 @@ class CompileLedger:
     def report(self) -> Dict[str, Any]:
         """Deterministically ordered snapshot (ms rounded to whole
         milliseconds so serializing the same state is byte-stable)."""
+        def totals(rec: Dict[str, Any]) -> Dict[str, int]:
+            return dict({k: int(round(rec[k])) for k in PARTS},
+                        episodes=rec["episodes"])
+
         with self._lock:
             fns = {
-                name: {
-                    "episodes": rec["episodes"],
-                    "by_phase": dict(sorted(rec["by_phase"].items())),
-                    "trace_ms": int(round(rec["trace_ms"])),
-                    "lower_ms": int(round(rec["lower_ms"])),
-                    "compile_ms": int(round(rec["compile_ms"])),
-                }
+                name: dict(
+                    totals(rec),
+                    by_phase=dict(sorted(rec["by_phase"].items())),
+                    by_key={key: totals(row) for key, row
+                            in sorted(rec["by_key"].items())},
+                )
                 for name, rec in sorted(self._fns.items())
             }
             violations = list(self._violations)
@@ -388,13 +454,19 @@ def get_ledger() -> CompileLedger:
                 monitoring.register_event_duration_secs_listener(
                     _dispatch_event
                 )
+                monitoring.register_event_listener(_dispatch_count)
                 _listener_installed = True
     return _ledger
 
 
 def _dispatch_event(event: str, duration_secs: float, **_kw: Any) -> None:
-    if event in (_EV_TRACE, _EV_LOWER, _EV_BACKEND):
+    if event in _PART:
         _ledger._on_event(event, duration_secs * 1000.0)
+
+
+def _dispatch_count(event: str, **_kw: Any) -> None:
+    if event in _PART:
+        _ledger._on_event(event, 1)
 
 
 def instrument(name: str, fn: Callable[..., Any]) -> Callable[..., Any]:

@@ -13,6 +13,11 @@ member's execution span links back to the batch. HTTP/gRPC ingest honors
 inbound W3C ``traceparent`` headers (:func:`parse_traceparent`), and
 :func:`format_traceparent` mints one for clients that want to originate the
 trace — there is no downstream HTTP hop here to forward it to.
+
+A replica's START is traced by the same ``Span`` through
+:meth:`Tracer.startup`: a handful of ``rdb.startup.*`` stages a replica,
+recorded whether or not an exporter is set, in a bounded log of their own
+(:meth:`Tracer.startup_spans`) that the ring of serving spans cannot evict.
 """
 
 from __future__ import annotations
@@ -20,11 +25,12 @@ from __future__ import annotations
 import contextvars
 import random
 import re
+import sys
 import threading
 import time
 import uuid
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterator, List, Optional
 
@@ -40,6 +46,9 @@ _current_span: contextvars.ContextVar[Optional["Span"]] = contextvars.ContextVar
 
 # Finished spans kept in-process are bounded; the exporter is the durable sink.
 _FINISHED_SPAN_CAP = 10_000
+# Start-up spans kept in-process: a replica's start is a few dozen (one a
+# stage, one a warmed program), so this holds the newest ten or so starts.
+_STARTUP_SPAN_CAP = 512
 
 _TRACEPARENT_RE = re.compile(
     r"^([0-9a-f]{2})-([0-9a-f]{32})-([0-9a-f]{16})-([0-9a-f]{2})$"
@@ -108,6 +117,8 @@ def parse_traceparent(header: Optional[str]) -> Dict[str, Any]:
 class Tracer:
     def __init__(self) -> None:
         self._finished: deque = deque(maxlen=_FINISHED_SPAN_CAP)
+        self._startup: deque = deque(maxlen=_STARTUP_SPAN_CAP)
+        self._startup_open = threading.local()  # .stack: this thread's
         self._lock = threading.Lock()
         self._exporter: Optional[Callable[[Span], None]] = None
         self._export_error_logged = False
@@ -123,10 +134,15 @@ class Tracer:
         self._exporter = None
         self.enabled = False
         self.clear()
+        with self._lock:
+            self._startup.clear()
 
     def _finish(self, s: Span) -> None:
         with self._lock:
             self._finished.append(s)
+        self._export(s)
+
+    def _export(self, s: Span) -> None:
         exporter = self._exporter
         if exporter is None:
             return
@@ -254,6 +270,70 @@ class Tracer:
         )
         self._finish(s)
         return s
+
+    # --- a replica's start ----------------------------------------------
+    @contextmanager
+    def startup(self, name: str, **attributes: Any) -> Iterator[Span]:
+        """One stage of a replica's start (``rdb.startup.<stage>``), as a
+        ``Span`` on ``time.monotonic()`` — the clock of the turn ring and of
+        :meth:`span` — AND as a :meth:`phase` of the same name, so that
+        under a ``jax.profiler`` session the stage also lies beside the
+        device's operations. Recorded whether or not an exporter is set (a
+        start runs these a few dozen times, the serving loop never), in
+        the bounded start-up log (:meth:`startup_spans`); an exporter
+        gets the span too. The parent is this thread's innermost open
+        start-up span. Attributes are small ints / strings; those known
+        only later are set on the yielded span (or on
+        :meth:`open_startup`'s) before it closes. A body may stamp
+        ``end_ms`` itself to derive an attribute from the duration. Works
+        as a decorator too (``@tracer().startup(name)``)."""
+        stack = self._startup_stack()
+        parent = stack[-1] if stack else None
+        s = Span(
+            name=name,
+            trace_id=parent.trace_id if parent else uuid.uuid4().hex,
+            span_id=_new_span_id(),
+            parent_id=parent.span_id if parent else None,
+            start_ms=time.monotonic() * 1000.0,
+            attributes=dict(attributes),
+        )
+        stack.append(s)
+        # No JAX loaded, no profiler session to land in (the sim and the
+        # linters drive controllers without it). A TraceAnnotation packs
+        # its attributes as ``name#k=v,k=v#``: a value that holds one of
+        # its separators (a program's key) stays on the Span alone.
+        on_profiler = nullcontext()
+        if "jax" in sys.modules:
+            on_profiler = self.phase(name, **{
+                k: v for k, v in attributes.items()
+                if not (isinstance(v, str) and set(v) & set("#,="))})
+        try:
+            with on_profiler:
+                yield s
+        finally:
+            stack.pop()
+            if s.end_ms is None:
+                s.end_ms = time.monotonic() * 1000.0
+            with self._lock:
+                self._startup.append(s)
+            self._export(s)
+
+    def _startup_stack(self) -> List[Span]:
+        stack = getattr(self._startup_open, "stack", None)
+        if stack is None:
+            stack = self._startup_open.stack = []
+        return stack
+
+    def open_startup(self) -> Optional[Span]:
+        """This thread's innermost open start-up span (the compile ledger
+        charges it with what compiles under it), or None."""
+        stack = self._startup_stack()
+        return stack[-1] if stack else None
+
+    def startup_spans(self) -> List[Span]:
+        """The start-up log: closed start-up spans, oldest first."""
+        with self._lock:
+            return list(self._startup)
 
     def phase(self, name: str, **attrs: Any) -> Any:
         """A loop phase on the PROFILER's clock: a
