@@ -34,7 +34,11 @@ Forms of the paged read (``sparse_forms()`` says which each program took):
   of pages copied out of the pool whole, running on across grid steps) with
   the selection as one more operand, a row of the slot's mask a page, ANDed
   into the length bound before the fold. Reads every live page, gathers
-  nothing. A kernel of its own, here, and not an option of the paged
+  nothing. A head block narrower than 8 (4 key heads) is read through a
+  free view of the pool in which two positions' heads are one (8, 128)
+  tile (:func:`_page_fold`), so its page arrives as whole tiles and folds
+  in one contraction with no relayout, as a block of 8 heads does.
+  A kernel of its own, here, and not an option of the paged
   kernel: a Mosaic module carries its source lines, and a line moved in
   that file compiles every program of every other model again. The fold,
   the softmax and the scratch are that file's own functions.
@@ -245,7 +249,12 @@ def paged_decode(q, k, v, page_table, kv_lengths, layer: int,
     with jax.named_scope("sparse_select"):
         chosen = exact_topk_mask(scores, win, select.topk)[:, 0]
     with jax.named_scope("sparse_attend"):
-        _record(f"{FORM_MASK} (live pages, the selection a page's row)")
+        K = k.shape[3]
+        fold = _page_fold(_pick_heads_block(K), q.shape[2] // K, ps)
+        page = (f"{K}-head page as {K * fold}-row tiles, "
+                if fold > 1 else "")
+        _record(f"{FORM_MASK} (live pages, {page}the selection a page's "
+                "row)")
         return sparse_paged_decode_attention(
             q, k, v, page_table, kv_lengths, chosen, layer=layer, scale=scale)
 
@@ -339,11 +348,10 @@ def _mask_form_declines(q, k, page_table, k_scale) -> str:
         # (2)": one bf16 head is half a packed row of the page's copy.
         return f"sparse kernel: {K} key head(s) of {k.dtype} under the " \
                "copy's tiling"
-    kb = _pick_heads_block(K)
-    if tile_math.paged_tile_bytes(
-            ps, kb, Hk, k.dtype.itemsize, window=1, G=N // K,
-    ) + 2 * tile_math.padded_block_bytes(
-            _select_block(page_table.shape[1], ps, kb, N // K), 4,
+    kb, G = _pick_heads_block(K), N // K
+    if tile_math.sparse_tile_bytes(
+            ps, kb, Hk, k.dtype.itemsize, G, page_table.shape[1],
+            _page_fold(kb, G, ps), _flat(kb, G, ps),
     ) > VMEM_BLOCK_BUDGET_BYTES:
         return (f"sparse kernel: page tile (ps={ps}, kb={kb}, H={Hk}) and "
                 "the slot's selection exceed the VMEM block budget")
@@ -360,13 +368,32 @@ def _flat(kb: int, G: int, ps: int) -> bool:
         kb < 8 and tile_math.flat_heads(8, G, ps))
 
 
-def _fold_flat(q_ref, k_tile, v_tile, m_ref, l_ref, acc_ref, *, valid,
-               scale: float):
+def _own_fold(kb: int, G: int, ps: int) -> bool:
+    """Whether the flat fold is this file's :func:`_fold_flat` (a narrow
+    block) and not the paged kernel's (``_accumulate_tile``: 8 heads)."""
+    return _flat(kb, G, ps) and not tile_math.flat_heads(kb, G, ps)
+
+
+def _page_fold(kb: int, G: int, ps: int) -> int:
+    """``f`` > 1 where the kernel reads the pool through the view
+    [L, P, ps // f, kb * f, H] (``tile_math.page_view_fold``: a narrow
+    block's page as whole (8, 128) tiles, ``f`` positions' heads a tile),
+    which it does wherever :func:`_fold_flat` takes the page and there is
+    such a view; 1 where it reads [L, P, ps, kb, H] as it is (a block of 8
+    heads, whose tiles are whole already; 3 heads; the per-head fold)."""
+    return tile_math.page_view_fold(kb, ps) if _own_fold(kb, G, ps) else 1
+
+
+def _fold_flat(q_ref, k_tile, v_tile, m_ref, l_ref, acc_ref, *, ps: int,
+               kb: int, valid, scale: float):
     """``decode_attention._accumulate_tile``'s flat-heads fold for a head
-    block of any width: the page [ps, kb, H] read as [ps * kb, H] (column
-    ``c`` = position ``c // kb`` of head ``c % kb``), every row scored
-    against every column, a row keeping its own head's."""
-    ps, kb, H = k_tile.shape[1:]
+    block of any width: the page of ``ps`` positions of ``kb`` heads read
+    as [ps * kb, H] (column ``c`` = position ``c // kb`` of head
+    ``c % kb``), every row scored against every column, a row keeping its
+    own head's. The tile is [1, ps, kb, H] or the view
+    [1, ps // f, kb * f, H] of the same bytes (:func:`_page_fold`), whose
+    rows flatten in the same order with no relayout."""
+    H = k_tile.shape[-1]
     rows, cols = q_ref.shape[1], ps * kb
     R = rows // kb
     s = jax.lax.dot_general(
@@ -378,13 +405,6 @@ def _fold_flat(q_ref, k_tile, v_tile, m_ref, l_ref, acc_ref, *, valid,
     m_ref[...], l_ref[...], acc_ref[...] = _softmax_fold(
         jnp.where(own & valid, s, NEG_INF), v_tile[0].reshape(cols, H),
         None, m_ref[...], l_ref[...], acc_ref[...])
-
-
-def _select_block(NP: int, ps: int, kb: int, G: int):
-    """A slot's selection as the kernel reads it: a row a page, in the
-    column order of the fold's score tile (the flat form's columns are
-    (position, head): each position kb times)."""
-    return (NP, ps * kb if _flat(kb, G, ps) else ps)
 
 
 def sparse_paged_decode_attention(
@@ -417,11 +437,12 @@ def sparse_paged_decode_attention(
     out = _sparse_paged_decode_attention(
         q_r, k, v, page_table.astype(jnp.int32),
         kv_lengths.astype(jnp.int32), jnp.full((1,), layer, jnp.int32), sel,
-        scale=float(scale), interpret=bool(resolve_interpret(interpret)))
+        fold=_page_fold(kb, G, ps), scale=float(scale),
+        interpret=bool(resolve_interpret(interpret)))
     return out[..., :H].reshape(B, 1, N, H)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("fold", "scale", "interpret"))
 def _sparse_paged_decode_attention(
     q: jax.Array,           # [B, K, G, H]
     k: jax.Array,           # [L, P, ps, K, H]
@@ -431,6 +452,7 @@ def _sparse_paged_decode_attention(
     layer: jax.Array,       # [1] int32
     sel: jax.Array,         # [B, NP, cols] int32, != 0: selected
     *,
+    fold: int,              # _page_fold: > 1 reads the pool's tile view
     scale: float,
     interpret: bool,
 ) -> jax.Array:
@@ -438,18 +460,24 @@ def _sparse_paged_decode_attention(
     slot and a bf16 pool, with ``sel``: the same (slot, head block) grid,
     the same ring of ``depth`` pages copied by the kernel itself and
     running on across steps, the same cursor in SMEM; page ``p``'s fold is
-    handed ``pos <= length`` AND row ``p`` of the slot's selection."""
+    handed ``pos <= length`` AND row ``p`` of the slot's selection. With
+    ``fold`` > 1 the two pools are read through their tile view (a reshape
+    the compiler takes as a bitcast) and the ring holds a page as
+    [ps // fold, kb * fold, H]: whole (8, 128) tiles."""
     B, K, R, H = q.shape
-    P, ps = k.shape[1], k.shape[2]
+    L, P, ps = k.shape[:3]
     NP = page_table.shape[1]
     kb = _pick_heads_block(K)
     nj = K // kb
     steps = B * nj
     flat = _flat(kb, R, ps)
-    own_fold = flat and not tile_math.flat_heads(kb, R, ps)
-    depth = tile_math.paged_walk_depth(ps, kb, H, k.dtype.itemsize, False,
-                                       1, R)
+    own_fold = _own_fold(kb, R, ps)
+    depth = tile_math.sparse_walk_depth(ps, kb, H, k.dtype.itemsize, R, NP,
+                                        fold, flat)
     ahead = depth - 1
+    page = (ps // fold, kb * fold, H)       # as the ring holds it
+    if fold > 1:                            # nj == 1: the block is all K
+        k, v = k.reshape(L, P, *page), v.reshape(L, P, *page)
 
     def bounds(b, len_ref):
         return tile_math.live_pages(len_ref[b], 1, 0, ps, NP)
@@ -508,8 +536,8 @@ def _sparse_paged_decode_attention(
             valid = (pos <= len_ref[b]) & picked
             tiles = k_buf.at[pl.ds(slot, 1)], v_buf.at[pl.ds(slot, 1)]
             if own_fold:
-                _fold_flat(q_ref, *tiles, m_ref, l_ref, acc_ref,
-                           valid=valid, scale=scale)
+                _fold_flat(q_ref, *tiles, m_ref, l_ref, acc_ref, ps=ps,
+                           kb=kb, valid=valid, scale=scale)
             else:
                 _accumulate_tile(q_ref, *tiles, None, None, m_ref, l_ref,
                                  acc_ref, valid=valid, scale=scale)
@@ -532,7 +560,7 @@ def _sparse_paged_decode_attention(
                          lambda b, j, pt, ln, ly: (b, 0, 0)),
             in_hbm, in_hbm],
         out_specs=rows_spec,
-        scratch_shapes=[pltpu.VMEM((depth, ps, kb, H), k.dtype)] * 2 + [
+        scratch_shapes=[pltpu.VMEM((depth,) + page, k.dtype)] * 2 + [
             pltpu.SemaphoreType.DMA((2, depth)),
             pltpu.SMEM((3,), jnp.int32),
         ] + _scratch(kb, R, H, flat),

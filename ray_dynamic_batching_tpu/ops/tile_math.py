@@ -359,3 +359,64 @@ def moe_tile_cols(k_dim: int, n_dim: int, n_weights: int,
                 <= VMEM_BLOCK_BUDGET_BYTES):
             return tn
     return 128
+
+
+# --- a narrow head block's page as whole (8, 128) tiles -------------------
+# (Appended BELOW everything a kernel of ``ops/decode_attention.py`` traces:
+# a Mosaic module carries the source lines of ``live_pages`` and its
+# helpers, and a line moved above them compiles every model's programs
+# again.)
+def page_view_fold(kb: int, page_size: int) -> int:
+    """``f``, the positions whose ``kb`` heads make up ONE (8, 128) tile of
+    a page [page_size, kb, H] with a head block NARROWER than 8. The pool
+    lies in HBM a (kb, 128) tile a position, ``f = 8 // kb`` positions'
+    tiles in a row, so [page_size // f, kb * f, H] is the same bytes (the
+    compiler takes the pool's reshape as a bitcast), arrives in VMEM as
+    whole tiles, and flattens to [page_size * kb, H] with no relayout, as
+    an 8-head block does (:func:`flat_heads`). 1 where there is no such
+    view: 8 heads or more, a width that does not divide 8, a page that
+    ``f`` does not."""
+    if 0 < kb < 8 and 8 % kb == 0 and page_size % (8 // kb) == 0:
+        return 8 // kb
+    return 1
+
+
+def sparse_tile_bytes(
+    page_size: int,
+    kb: int,
+    H: int,
+    kv_itemsize: int,
+    G: int,
+    n_entries: int,
+    fold: int = 1,
+    flat: bool = True,
+    depth: int = DOUBLE_BUFFER,
+) -> int:
+    """VMEM footprint of one grid step of the sparse mask-form kernel
+    (``ops/sparse_attention.py::_sparse_paged_decode_attention``), the
+    tiles AS THEY LIE: a ring of ``depth`` K and V pages in scratch, each
+    [page_size // fold, kb * fold, H] (``fold`` > 1: the view above, whole
+    tiles where [page_size, kb, H] pads ``kb`` up to the dtype's tile
+    height); the slot's selection [n_entries, cols] int32, a pipelined
+    block; and, where the fold is ``flat`` (every head in one
+    contraction), its two f32 score tiles [kb * G, cols]. The padding is
+    :func:`padded_block_bytes`'s, the one the ``vmem-budget`` rule prices
+    a scratch shape by (bf16's 8-row tiles count as 16: never under)."""
+    cols = page_size * kb if flat else page_size
+    ring = 2 * padded_block_bytes(
+        (1, page_size // fold, kb * fold, H), kv_itemsize)
+    sel = DOUBLE_BUFFER * padded_block_bytes((1, n_entries, cols), 4)
+    scores = 2 * padded_block_bytes((kb * G, cols), 4) if flat else 0
+    return depth * ring + sel + scores
+
+
+def sparse_walk_depth(page_size: int, kb: int, H: int, kv_itemsize: int,
+                      G: int, n_entries: int, fold: int = 1,
+                      flat: bool = True) -> int:
+    """:func:`paged_walk_depth` for the sparse kernel's ring, priced by
+    :func:`sparse_tile_bytes`."""
+    for depth in range(PAGED_WALK_MAX_DEPTH, DOUBLE_BUFFER, -1):
+        if sparse_tile_bytes(page_size, kb, H, kv_itemsize, G, n_entries,
+                             fold, flat, depth) <= VMEM_BLOCK_BUDGET_BYTES:
+            return depth
+    return DOUBLE_BUFFER

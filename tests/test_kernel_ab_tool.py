@@ -92,7 +92,32 @@ def test_the_sparse_geometry_is_the_benchmarks_configuration():
     assert NP == llm["max_len"] // llm["page_size"]
     assert max(ab.SPARSE_LENGTHS) < llm["max_len"]
     assert set(ab.SPARSE_FORMS) == {
-        sparse.FORM_FLOOR, sparse.FORM_MASK, "gather"}
+        sparse.FORM_FLOOR, sparse.FORM_MASK, "mask_untiled", "gather"}
     sa = cfg["sa_config"]
     assert (sa["indexer_num_heads"], sa["indexer_head_dim"], sa["topk"],
             sa["indexer_num_kv_heads"]) == (n_index, Hi, topk, 1)
+
+
+@pytest.mark.parametrize("rows, form, low, high", [
+    ("shipped_pr35", "mask", 0.62, 0.66),     # the page as it lies
+    ("rows", "mask_untiled", 0.62, 0.66),     # ... measured again, PR 39
+    ("rows", "mask", 0.44, 0.49),             # as whole (8, 128) tiles
+    ("rows", "gather", -0.01, 0.01),          # flat in the length
+])
+def test_the_recorded_rows_slope_is_the_page_cost(rows, form, low, high):
+    """``page_slopes_us`` over the committed capture: microseconds a live
+    page of a slot adds to a layer's read, a form (records, not timings
+    taken here)."""
+    import json
+
+    root = Path(__file__).resolve().parents[1]
+    record = json.loads((root / "profiles" / "tpu_v5e"
+                         / "sparse_decode.json").read_text())
+    _, L, P, B, NP, *_ = ab.SPARSE_GEOMETRY
+    pages = {n: int((ab.sparse_case(0, B, NP, P, n)[1] // ab.PAGE + 1).sum())
+             for n in ab.SPARSE_LENGTHS}
+    slopes = ab.page_slopes_us(
+        [dict(r, pages_live=pages[r["length"]]) for r in record[rows]])
+    assert low < slopes[form] < high
+    if rows == "rows":
+        assert slopes[form] == pytest.approx(record["page_us"][form])

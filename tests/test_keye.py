@@ -281,6 +281,99 @@ def test_mask_form_and_floor_agree_at_every_length(heads, kv):
                                rtol=2e-5, atol=2e-5)
 
 
+# --- a narrow head block's page read as whole (8, 128) tiles ---------------------
+VIEW = "-head page as 8-row tiles"
+
+
+def _selected_attention(q, k, v, table, lengths, chosen, layer):
+    """float32, a slot and a head at a time: softmax over the chosen
+    positions <= the slot's length, rows looked up through the table."""
+    q, k, v = (np.asarray(x, np.float32) for x in (q, k, v))
+    B, _, N, H = q.shape
+    ps, K = k.shape[2], k.shape[3]
+    out = np.zeros((B, 1, N, H), np.float32)
+    for b in range(B):
+        pos = np.flatnonzero(np.asarray(chosen[b])[:int(lengths[b]) + 1])
+        page, off = np.asarray(table)[b, pos // ps], pos % ps
+        for n in range(N):
+            s = k[layer, page, off, n // (N // K)] @ q[b, 0, n] * H ** -0.5
+            w = np.exp(s - s.max())
+            out[b, 0, n] = (w / w.sum()) @ v[layer, page, off, n // (N // K)]
+    return out
+
+
+@pytest.mark.parametrize("heads, kv, page, dtype, fold", [
+    (32, 4, 128, jnp.bfloat16, 2),     # the cell's block: 8 query rows a head
+    (8, 4, 128, jnp.float32, 2),
+    (16, 2, 128, jnp.bfloat16, 4),
+    (4, 2, 128, jnp.float32, 4),
+    (8, 4, 7, jnp.float32, 1),         # an odd page has no halves
+    (4, 2, 6, jnp.float32, 1),         # ... and 6 positions no quarters
+    (6, 3, 128, jnp.float32, 1),       # 3 heads make up no 8-row tile
+    (16, 8, 128, jnp.bfloat16, 1),     # 8 heads: whole tiles as they lie
+], ids=lambda x: getattr(x, "__name__", str(x)))
+def test_a_narrow_page_is_read_as_whole_tiles(
+        heads, kv, page, dtype, fold, monkeypatch):
+    """The mask form's kernel (interpreted) with the pool read through its
+    tile view against the SAME kernel with the view refused, bit for bit,
+    and against float32 arithmetic: a random selection, permuted page
+    tables, a slot on its first page, a slot at the table's last column.
+    Where there is no view (an odd page, 3 heads, 8 heads) the kernel runs
+    as it did; ``sparse_forms()`` names what each program took."""
+    from ray_dynamic_batching_tpu.utils import compile_ledger
+
+    rng = np.random.default_rng(zlib.crc32(f"{heads}/{kv}/{page}".encode()))
+    H, NP, n_pages, layer = 128, 4, 24, 1
+    lengths = np.asarray([0, page - 1, page + 2, 2 * page + 9, NP * page - 1])
+    B = len(lengths)
+    q = jnp.asarray(rng.normal(size=(B, 1, heads, H)), dtype)
+    k, v = (jnp.asarray(rng.normal(size=(2, n_pages, page, kv, H)), dtype)
+            for _ in range(2))
+    table = rng.permutation(n_pages)[:B * NP].reshape(B, NP)
+    for b in range(B):                  # unallocated past the last live page
+        table[b, lengths[b] // page + 1:] = n_pages
+    chosen = rng.random((B, NP * page)) < 0.4
+    chosen[np.arange(B), lengths] = True            # never an empty row
+    kb, G = kv, heads // kv
+    assert sparse._page_fold(kb, G, page) == fold
+
+    def kernel():
+        return np.asarray(sparse.sparse_paged_decode_attention(
+            q, k, v, jnp.asarray(table, jnp.int32),
+            jnp.asarray(lengths, jnp.int32), jnp.asarray(chosen),
+            layer=layer, interpret=True), np.float32)
+
+    got = kernel()
+    with monkeypatch.context() as refused:
+        refused.setattr(sparse, "_page_fold", lambda *a: 1)
+        np.testing.assert_array_equal(got, kernel())
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(
+        got, _selected_attention(q, k, v, table, lengths, chosen, layer),
+        rtol=tol, atol=tol)
+    if page % 128:
+        # not a page the dispatcher hands the kernel: nothing to name
+        return
+    # the dispatcher's own call, under a program's name
+    name = f"narrow_page_{heads}_{kv}"
+    select = sparse.Selection(
+        q=jnp.asarray(rng.normal(size=(B, 1, 2, 8)), jnp.float32),
+        w=jnp.asarray(rng.normal(size=(B, 1, 2)), jnp.float32),
+        pool=jnp.asarray(rng.normal(size=(2, n_pages, page, 128)),
+                         jnp.float32), topk=40)
+    attn_ops.set_attention_backend("pallas")
+    try:
+        compile_ledger.instrument(name, attn_ops.dot_product_attention)(
+            q, k, v, page_table=jnp.asarray(table, jnp.int32),
+            kv_lengths=jnp.asarray(lengths, jnp.int32), layer=layer,
+            select=select)
+    finally:
+        attn_ops.set_attention_backend("auto")
+    took = [f for f in sparse.sparse_forms() if f.startswith(name + ":")]
+    assert len(took) == 1 and sparse.FORM_MASK in took[0]
+    assert (f"{kv}{VIEW}" in took[0]) == (fold > 1), took
+
+
 def test_mask_form_declines_by_name(monkeypatch):
     q = jnp.zeros((2, 1, 8, 128))
     k = jnp.zeros((1, 4, 128, 4, 128))

@@ -1189,6 +1189,49 @@ class TestSharedTileMath:
             q, k, k, pt, lens, interpret=True
         ) is None
 
+    @pytest.mark.parametrize("kb", [4, 2])
+    def test_the_viewed_page_is_priced_as_it_lies(self, kb, tmp_path):
+        """ISSUE 39: a head block narrower than 8 arrives in the sparse
+        kernel's ring through the pool's tile view, [ps // f, kb * f, H]
+        with f = 8 // kb. The runtime guard (``tile_math.
+        sparse_tile_bytes``), the linter's standalone copy and the
+        ``vmem-budget`` rule's own price of that scratch shape are ONE
+        number; the page as it lay pads ``kb`` rows up to a tile."""
+        from tools.lint.vmem import ASSUMED_ITEMSIZE
+
+        lm = tile_math_module()
+        ps, H, G, NP = 128, 128, 32 // kb, 144
+        f = tm.page_view_fold(kb, ps)
+        assert f == lm.page_view_fold(kb, ps) == 8 // kb
+        for depth in (2, 3):
+            for fold in (1, f):
+                assert lm.sparse_tile_bytes(
+                    ps, kb, H, 2, G, NP, fold, True, depth
+                ) == tm.sparse_tile_bytes(
+                    ps, kb, H, 2, G, NP, fold, True, depth)
+        ring = lambda fold, depth: (  # noqa: E731 (ring alone: less depth 0)
+            tm.sparse_tile_bytes(ps, kb, H, 2, G, NP, fold, True, depth)
+            - tm.sparse_tile_bytes(ps, kb, H, 2, G, NP, fold, True, 0))
+        # what the rule charges a pltpu.VMEM((depth, ps // f, 8, H)) pair
+        # (f32-itemsize upper bound: 8 rows are a whole tile there)
+        viewed = (3, ps // f, kb * f, H)
+        assert ring(f, 3) == 2 * lm.padded_block_bytes(
+            viewed, ASSUMED_ITEMSIZE) == 2 * 3 * ps * kb * H * 4
+        # ... and the same ring read as it lay: kb rows padded to 16
+        assert ring(1, 3) == f * ring(f, 3) == 2 * 3 * ps * 16 * H * 2
+        assert tm.sparse_walk_depth(ps, kb, H, 2, G, NP, f) \
+            == tm.PAGED_WALK_MAX_DEPTH
+        # no view: 8 heads, 3 heads, a page f does not divide
+        assert [tm.page_view_fold(k, p) for k, p in (
+            (8, 128), (16, 128), (3, 128), (kb, 8 // kb + 1), (0, 128))
+        ] == [1] * 5
+        # the rule reads that very scratch ring and holds it to the budget
+        report = lint_fixture(
+            tmp_path, "ops/ring.py", SCRATCH_RING.format(
+                depth=3, ps=ps // f, imports="", params=""),
+            rules=["vmem-budget"])
+        assert rules_found(report) == []
+
     def test_shard_heads_agreement_pin(self):
         # ROADMAP item 2: the per-shard footprint rule (a head-sharded
         # paged kernel budgets K/tp heads; an indivisible head axis

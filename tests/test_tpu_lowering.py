@@ -310,15 +310,19 @@ class TestPagedKernelCompilesForV5e:
 class TestSparseKernelCompilesForV5e:
     """``ops/sparse_attention.py``'s mask-form kernel at the configuration
     that has an indexer (24 slots, a table of 144 pages, 32/4 heads of 128:
-    a block of FOUR key heads folded in one contraction, ``[128, 4, 128] ->
-    [512, 128]``, which the paged kernel's own rule leaves to the per-head
-    fold) and at 8 key heads (the shared flat fold); ONE bf16 key head is
-    under the page copy's tiling and declines by name
+    a block of FOUR key heads folded in one contraction over the pool's
+    tile view, ``[8, 3456, 128, 4, 128] -> [8, 3456, 64, 8, 128]``: two
+    positions' heads an (8, 128) tile, which the compiler must take as a
+    BITCAST behind the page write, never as a copy of the pool) and at 8
+    key heads (the shared flat fold, the pool as it lies); ONE bf16 key
+    head is under the page copy's tiling and declines by name
     (``_mask_form_declines``). Nothing runs:
     ``tools/run_kernel_ab.py --sparse`` on the chip says what it costs."""
 
     @pytest.mark.parametrize("kv", [4, 8])
     def test_the_mask_form_compiles(self, kv, one_chip):
+        import re
+
         from jax.experimental.compilation_cache import compilation_cache
 
         from ray_dynamic_batching_tpu.ops import sparse_attention as sparse
@@ -326,20 +330,43 @@ class TestSparseKernelCompilesForV5e:
         B, NP, ps, H, N, L, P = 24, 144, 128, 128, 32, 8, 3456
         struct = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
             shape, dt, sharding=one_chip)
-        f = jax.jit(lambda q, k, v, table, lengths, chosen:
-                    sparse.sparse_paged_decode_attention(
-                        q, k, v, table, lengths, chosen, layer=3,
-                        interpret=False))
+
+        def step(q, k, v, table, lengths, chosen, page, row):
+            # the decode program's order: this step's row written into
+            # the (donated) pool, then the read
+            k, v = k.at[3, page, 0].set(row), v.at[3, page, 0].set(row)
+            return sparse.sparse_paged_decode_attention(
+                q, k, v, table, lengths, chosen, layer=3,
+                interpret=False), k, v
+
         pool = struct((L, P, ps, kv, H), jnp.bfloat16)
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            f.lower(struct((B, 1, N, H), jnp.bfloat16), pool, pool,
-                    struct((B, NP), jnp.int32), struct((B,), jnp.int32),
-                    struct((B, NP * ps), jnp.bool_)).compile()
+            compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+                struct((B, 1, N, H), jnp.bfloat16), pool, pool,
+                struct((B, NP), jnp.int32), struct((B,), jnp.int32),
+                struct((B, NP * ps), jnp.bool_), struct((B,), jnp.int32),
+                struct((B, kv, H), jnp.bfloat16)).compile()
         finally:
             jax.config.update("jax_enable_compilation_cache", True)
             compilation_cache.reset_cache()
+        text = compiled.as_text()
+        view = f"bf16[{L},{P},{ps // 2},8,{H}]"
+        casts = [ln for ln in text.splitlines()
+                 if re.search(rf"= {re.escape(view)}\S* bitcast\(", ln)]
+        # k and v, each a bitcast of the written pool (4 heads); 8 heads
+        # are read as they lie
+        assert len(casts) == (2 if kv == 4 else 0), casts
+        # nothing but the two in-place page writes makes an array of the
+        # pool's size: no copy, no transpose, no temporary
+        made = re.findall(
+            rf"= bf16\[{L},{P},[\d,]*\]\{{[^}}]*\}} ([\w\-]+)\(", text)
+        made = [op for op in made if op != "parameter"]
+        assert sorted(set(made)) == (
+            ["bitcast", "fusion", "scatter"] if kv == 4
+            else ["fusion", "scatter"]), made
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
 class TestSelectionsOperationsInTheCompiledPrograms:

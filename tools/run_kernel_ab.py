@@ -31,9 +31,12 @@ kernel's live and dead grid steps) under ``parent``.
 and attention over the selection) at the configuration that has an indexer,
 at three cached lengths, into ``sparse_decode.json``: the two forms of
 ``ops/sparse_attention.py`` (the mask form it ships; the floor, which the
-tool reaches by making the mask form's kernel decline) and a GATHER form
-that lives here (:func:`gather_form_decode`: the selected rows only; slower
-below ~30k positions a slot, so the program does not carry it).
+tool reaches by making the mask form's kernel decline), the mask form with
+its tile view of a 4-head page refused (``mask_untiled``: PR 35's kernel,
+the page relaid in every fold) and a GATHER form that lives here
+(:func:`gather_form_decode`: the selected rows only; slower below ~30k
+positions a slot, so the program does not carry it). ``page_us`` is the
+slope of a form's three rows: microseconds a live page of a slot adds.
 
 Usage: python tools/run_kernel_ab.py [out_dir] [--iters N] [--paged|--sparse]
                                      [--only tag1,tag2] [--out-name F]
@@ -95,7 +98,7 @@ PAGE = 128
 SPARSE_GEOMETRY = ("keye-vl2-30b-ep8-1chip", 8, 3456, 24, 144, 32, 4, 128,
                    16, 64, 2048)
 SPARSE_LENGTHS = (4608, 9216, 18300)
-SPARSE_FORMS = ("floor", "mask", "gather")
+SPARSE_FORMS = ("floor", "mask", "mask_untiled", "gather")
 
 
 def sparse_case(seed: int, B: int, NP: int, P: int, length: int):
@@ -368,10 +371,12 @@ def _time_sparse(iters: int):
     cases = [(n, *map(jnp.asarray, sparse_case(0, B, NP, P, n)))
              for n in SPARSE_LENGTHS]
     rows, refs = [], {}
-    declines = sparse._mask_form_declines
+    declines, page_fold = sparse._mask_form_declines, sparse._page_fold
     for form in SPARSE_FORMS:
         if form == "floor":     # what a read the kernel declines takes
             sparse._mask_form_declines = lambda *a: "A/B tool: the floor"
+        if form == "mask_untiled":  # the page as it lies: [ps, 4, H]
+            sparse._page_fold = lambda *a: 1
         one, program = chain(form, [L - 1]), chain(form, range(L))
         try:
             for n, table, lengths in cases:
@@ -391,6 +396,7 @@ def _time_sparse(iters: int):
                     "layer_us": statistics.median(samples),
                     "layer_us_min_max": [min(samples), max(samples)],
                     "rows_live": int(lengths.sum()) + B,
+                    "pages_live": int((lengths // PAGE + 1).sum()),
                     "rows_selected": int(jnp.minimum(
                         lengths + 1, topk).sum()),
                     "max_abs_diff": float(jnp.max(jnp.abs(
@@ -399,7 +405,20 @@ def _time_sparse(iters: int):
                 })
         finally:
             sparse._mask_form_declines = declines
+            sparse._page_fold = page_fold
     return rows
+
+
+def page_slopes_us(rows) -> dict:
+    """Microseconds a live page of a slot adds to a layer's read, a form:
+    the least-squares slope of ``layer_us`` over ``pages_live``."""
+    out = {}
+    for form in dict.fromkeys(r["form"] for r in rows):
+        xs, ys = zip(*((r["pages_live"], r["layer_us"])
+                       for r in rows if r["form"] == form))
+        if len(set(xs)) > 1:
+            out[form] = statistics.covariance(xs, ys) / statistics.variance(xs)
+    return out
 
 
 def sparse_main(out_dir: str, out_name: str, iters: int) -> int:
@@ -410,12 +429,16 @@ def sparse_main(out_dir: str, out_name: str, iters: int) -> int:
     record = {"backend": backend,
               "device_kind": jax.devices()[0].device_kind,
               "captured": time.strftime("%Y%m%dT%H%M%S"), "iters": iters,
-              "geometry": SPARSE_GEOMETRY[0], "rows": rows}
+              "geometry": SPARSE_GEOMETRY[0], "rows": rows,
+              "page_us": page_slopes_us(rows)}
     for r in rows:
         print(f"{r['geometry']}: {r['form']} at {r['length']} positions: "
               f"{r['layer_us']:.1f} us a layer ({r['rows_selected']} of "
               f"{r['rows_live']} rows selected), max |form - floor| "
               f"{r['max_abs_diff']:.2e}", flush=True)
+    for form, us in record["page_us"].items():
+        print(f"{SPARSE_GEOMETRY[0]}: {form}: {us:.3f} us a live page",
+              flush=True)
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, out_name), "w") as f:
         json.dump(record, f, indent=1)
@@ -423,7 +446,8 @@ def sparse_main(out_dir: str, out_name: str, iters: int) -> int:
     print(json.dumps({
         "metric": "sparse_decode_layer_us", "backend": backend,
         "layer_us": {f"{r['form']}@{r['length']}": r["layer_us"]
-                     for r in rows}}), flush=True)
+                     for r in rows},
+        "page_us": record["page_us"]}), flush=True)
     ok = all(r["max_abs_diff"] < 0.1 for r in rows)
     return 0 if ok and backend != "cpu" else 1
 
