@@ -105,7 +105,10 @@ from ray_dynamic_batching_tpu.engine.pagefabric import (
 )
 from ray_dynamic_batching_tpu.engine.queue import RequestQueue
 from ray_dynamic_batching_tpu.models.causal_lm import merge_routing_counters
-from ray_dynamic_batching_tpu.models.decoder import fit_head_dim
+from ray_dynamic_batching_tpu.models.decoder import (
+    fit_head_dim,
+    ring_table,
+)
 from ray_dynamic_batching_tpu.ops import jit_model, tile_math
 from ray_dynamic_batching_tpu.ops.tile_math import (
     lane_aligned_page,
@@ -222,6 +225,7 @@ class _IssuedTurn(NamedTuple):
     trains: int
     kv_pages_live: float
     kv_rows: Tuple[int, int]
+    kv_full_pages_live: int = 0
 
 
 class _IssuedGroup(NamedTuple):
@@ -287,7 +291,15 @@ class Turn(NamedTuple):
     may attend (the cached length and the token being written; from the
     host's lengths, as ``kv_pages_live`` is), and ``kv_rows_selected``, the
     sum of ``min(that, index_topk)``: the rows its attention folds. Both 0
-    for any other model."""
+    for any other model.
+
+    A scan of a model with state BY LAYER KIND (``PagedKVCache.ring_k``)
+    also carries ``kv_full_pages_live``: the pool's pages, summed over all
+    slots, that a FULL layer's scan walks in its first substep (the
+    allocator's pages are the full layers' alone). A sliding layer's ring
+    is as many pages a slot whatever the traffic, so it is no counter: the
+    engine says it once (``snapshot()["kv_pool"]["ring_pages_per_slot"]``,
+    the gauge ``rdb_decode_kv_pool_bytes{kind}``). 0 for any other model."""
 
     kind: str
     t_dispatch: float
@@ -310,7 +322,11 @@ class Turn(NamedTuple):
     kv_rows_live: int = 0
     kv_rows_selected: int = 0
     queued_behind: int = 0
+    kv_full_pages_live: int = 0
 
+
+# An engine's prompt buckets where its builder names none.
+DEFAULT_PROMPT_BUCKETS = (16, 32, 64, 128)
 
 # Sized for the benchmark's 51 s window at several times the cells'
 # highest dispatch rate (~30/s at a 33 ms one-substep scan): 160/s.
@@ -380,7 +396,8 @@ def startup_sums(rows: Sequence[Dict[str, Any]]) -> Dict[str, Any]:
 def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
                     span_ms: Optional[float] = None,
                     longest: int = 8,
-                    table_entries: int = 0) -> Dict[str, Any]:
+                    table_entries: int = 0,
+                    full_table_entries: int = 0) -> Dict[str, Any]:
     """Turn records summed, for an operator asking a slow replica where
     its time goes (the one definition of every quantity read from the
     ring): dispatches and scans held (and ``dropped`` by the bounded ring),
@@ -414,7 +431,10 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
     walks holds KV a slot attends. A selecting model's scans add
     ``kv_rows_live``, ``kv_rows_selected`` (each weighed by its substeps)
     and ``kv_selected_row_share``: of the cached rows a query could attend,
-    the share its indexer keeps."""
+    the share its indexer keeps. With ``full_table_entries`` (a model with
+    state by layer kind: a slot's table width) they add
+    ``kv_full_pages_live`` and ``kv_full_live_page_share``, the same two of
+    the FULL layers' pages alone."""
     scans = [t for t in turns if t.kind == "turn"]
     out: Dict[str, Any] = {"dispatches": len(turns), "scans": len(scans),
                            "dropped": dropped}
@@ -424,6 +444,12 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
         out["kv_pages_scanned"] = num_slots * table_entries * sum(
             t.substeps for t in scans)
         out["kv_live_page_share"] = live / out["kv_pages_scanned"]
+    full_live = sum(t.kv_full_pages_live * t.substeps for t in scans)
+    if full_live and full_table_entries:
+        out["kv_full_pages_live"] = full_live
+        out["kv_full_live_page_share"] = full_live / (
+            num_slots * full_table_entries * sum(
+                t.substeps for t in scans))
     rows_live = sum(t.kv_rows_live * t.substeps for t in scans)
     if rows_live:
         out["kv_rows_live"] = rows_live
@@ -522,6 +548,12 @@ PREFIX_MISSES = m.Counter(
 KV_PAGES_FREE = m.Gauge(
     "rdb_decode_kv_pages_free", "Free pages in the paged KV pool",
     tag_keys=("model",),
+)
+KV_POOL_BYTES = m.Gauge(
+    "rdb_decode_kv_pool_bytes",
+    "Resident bytes of the KV state of one kind of layer (a model with "
+    "state by layer kind): 'full' the paged pool, 'ring' the sliding "
+    "layers' rings; set once, at build", tag_keys=("model", "kind"),
 )
 KV_PAGE_OCCUPANCY = m.Gauge(
     "rdb_decode_kv_page_occupancy",
@@ -672,6 +704,39 @@ class DecodeEngine:
         self.model = model
         self.device = device
         self.mesh = mesh
+        # State by layer kind: the sliding layers keep a ring of their
+        # window a slot (models/decoder.py::PagedKVCache). What reaches a
+        # slot's KV by PAGE REFERENCE cannot work with it and is refused
+        # here, when the engine is built (ROADMAP D10).
+        by_kind = bool(getattr(getattr(model, "cfg", None), "kv_by_kind",
+                               False))
+        if by_kind:
+            refused = {
+                "prefix_cache_size": prefix_cache_size and (
+                    "a borrowed page holds the full layers' KV of a shared "
+                    "prefix and nothing of the sliding layers', whose ring "
+                    "is the slot's own"),
+                "session_cache_size": session_cache_size and (
+                    "a stored session pins pages; the slot's ring is "
+                    "overwritten by its next tenant"),
+                "host_spill_pages": host_spill_pages and (
+                    "it spills the prefix cache, which is refused"),
+                "draft_model": draft_model is not None and (
+                    "spec verify writes a window into scratch pages and "
+                    "rolls a rejected tail back; a ring's write is over "
+                    "the position 6 pages back and cannot be undone"),
+                "mesh": mesh is not None and (
+                    "the ring's pool has no sharding layout"),
+                "kv_dtype int8": jnp.dtype(
+                    getattr(model, "kv_dtype", None) or jnp.bfloat16
+                ) == jnp.dtype(jnp.int8) and (
+                    "the ring has no scale planes"),
+            }
+            for option, why in refused.items():
+                if why:
+                    raise ValueError(
+                        f"{getattr(model, 'name', 'model')}: {option} "
+                        f"cannot be used with state by layer kind: {why}")
         if draft_model is not None and mesh is not None:
             # Loud, like the draft-model conflict ISSUE 13 lifted (and
             # the PR 10 TP-paged pattern): the spec verify window would
@@ -721,7 +786,7 @@ class DecodeEngine:
         self.queue = queue
         self.num_slots = num_slots
         self.max_len = max_len
-        self.prompt_buckets = sorted(prompt_buckets or [16, 32, 64, 128])
+        self.prompt_buckets = sorted(prompt_buckets or DEFAULT_PROMPT_BUCKETS)
         self.prompt_buckets = [b for b in self.prompt_buckets if b <= max_len]
         self.eos_token_id = eos_token_id
         self.default_max_new_tokens = default_max_new_tokens
@@ -814,12 +879,16 @@ class DecodeEngine:
                 self._cache = self._put(model.make_paged_cache(
                     num_slots, self.num_pages, self.page_size,
                     self._paged_capacity,
+                    widest_chunk=max(self.prompt_buckets, default=None),
                 ))
+        # Pages in a slot's ring (0: one pool for every layer), sized for
+        # the widest chunk a program writes at once.
+        self._ring_pages = self._cache.ring_pages
         # Each layer's sliding window (0: a full layer), and the table
         # columns a slot's decode scan walks, averaged over layers.
         self._layer_windows: Tuple[int, ...] = tuple(
             getattr(model, "layer_windows", None)
-            or (0,) * self._cache.k.shape[0])
+            or (0,) * self._cache.k.shape[0])  # (a model without a cfg)
         self._layer_table_widths = [
             tile_math.window_table_width(
                 w, 1, self.page_size, self._n_table_entries)
@@ -835,15 +904,17 @@ class DecodeEngine:
             if self._index_topk else 0)
         # The head's true width, as the model's row caches have it
         # (the pool's rows are lane-padded: pool_head_dim).
-        self._kv_head_dim = jax.eval_shape(
-            lambda: model.make_cache(1, self.page_size)).k.shape[-1]
+        self._kv_head_dim = model.cfg.head_dim if by_kind else (
+            jax.eval_shape(lambda: model.make_cache(
+                1, self.page_size)).k.shape[-1])
         # How the pool lies on the device, once, for snapshot():
         # the order of its axes (row-major is what the paged kernel
         # and the page write read; the pool's lane-padded rows make
         # it the device's default) and its bytes there.
         planes = [x for x in (self._cache.k, self._cache.v,
                               self._cache.k_scale, self._cache.v_scale,
-                              self._cache.index_k)
+                              self._cache.index_k, self._cache.ring_k,
+                              self._cache.ring_v)
                   if x is not None]
         layout = self._cache.k.format.layout
         self._pool_stats = {
@@ -852,6 +923,17 @@ class DecodeEngine:
             "resident_bytes": sum(
                 x.on_device_size_in_bytes() for x in planes),
         }
+        if by_kind:
+            kinds = {"full": (self._cache.k, self._cache.v),
+                     "ring": (self._cache.ring_k, self._cache.ring_v)}
+            self._pool_stats.update(
+                ring_pages_per_slot=self._ring_pages,
+                bytes_by_kind={
+                    kind: sum(x.on_device_size_in_bytes() for x in pools)
+                    for kind, pools in kinds.items()})
+            for kind, n in self._pool_stats["bytes_by_kind"].items():
+                KV_POOL_BYTES.set(n, tags={"model": model.name,
+                                           "kind": kind})
         self._tokens = np.zeros((num_slots, 1), dtype=np.int32)
         self._active_mask = np.zeros((num_slots,), dtype=bool)
         # Per-slot sampling params (temperature 0 == greedy).
@@ -1130,7 +1212,8 @@ class DecodeEngine:
                       moe: Sequence[int] = (0, 0, 0, 0),
                       kv_pages_live: float = 0,
                       kv_rows: Tuple[int, int] = (0, 0),
-                      queued_behind: int = 0) -> Turn:
+                      queued_behind: int = 0,
+                      kv_full_pages_live: int = 0) -> Turn:
         """Append this dispatch's record to the turn ring (its work on the
         host is done: ``t_done`` is now). ``moe``: the dispatch's routing
         counters as fetched (``Turn``'s ``moe_*`` fields);
@@ -1143,7 +1226,7 @@ class DecodeEngine:
             self._allocator.allocated_pages,
             int(self._len_host.sum()), self._idled,
             *(int(c) for c in moe[:3]), kv_pages_live, int(moe[3]),
-            *kv_rows, queued_behind,
+            *kv_rows, queued_behind, kv_full_pages_live,
         )
         if len(self.turns) == self.turns.maxlen:
             self.turns_dropped += 1
@@ -1180,6 +1263,15 @@ class DecodeEngine:
             return live[self._layer_windows[0]]
         return sum(live[w] for w in self._layer_windows) / len(
             self._layer_windows)
+
+    def _kv_full_pages_live(self) -> int:
+        """``Turn.kv_full_pages_live`` of the scan about to be dispatched;
+        0 where the model has one pool for every layer."""
+        if not self._ring_pages:
+            return 0
+        return int(tile_math.live_pages(
+            self._len_host, 1, 0, self.page_size,
+            self._n_table_entries)[1].sum())
 
     def _kv_rows(self, window: int = 1) -> Tuple[int, int]:
         """(rows live, rows selected) of the scan about to be dispatched,
@@ -1374,16 +1466,23 @@ class DecodeEngine:
         )
         temps, topp = meta_f[0], meta_f[1]
         params = self._mp(params)
+        rings, ring_pools = {}, {}
+        if tables.ndim == 3:
+            # state by layer kind: [2, g, NP], the rows' page-table rows
+            # and their slots' ring tables
+            tables, rings["ring_tables"] = tables[0], tables[1]
         # An expert model's routing counters ride the ids fetch: [g + 4].
         taken, pools, *moe = self.model.prefill_chunk_paged(
             params, tokens, attn_mask, cache, tables, starts, take_idx,
-            **self._moe_kw,
+            **self._moe_kw, **rings,
         )
         lengths = cache.lengths.at[slots].set(new_len, mode="drop")
+        if rings:
+            ring_pools = {"ring_k": pools.ring_k, "ring_v": pools.ring_v}
         cache = cache.replace(
             k=pools.k, v=pools.v, lengths=lengths,
             k_scale=pools.k_scale, v_scale=pools.v_scale,
-            index_k=pools.index_k,
+            index_k=pools.index_k, **ring_pools,
         )
         first = self._sample_tokens(
             taken, temps, topk, seeds, jnp.zeros_like(slots), bias_ids,
@@ -2253,6 +2352,12 @@ class DecodeEngine:
                 meta_f[:, i] = meta_f[:, 0]
                 bias_ids[i] = bias_ids[0]
                 bias_vals[i] = bias_vals[0]
+            if self._ring_pages:
+                # ... and each row's slot's ring (a filler row row 0's)
+                slots = np.full(group, trains[0].slot_idx, np.int32)
+                slots[:n] = [t.slot_idx for t in trains]
+                tables = np.stack([tables, ring_table(
+                    slots, self._ring_pages, self._n_table_entries)])
             tokmask = np.stack([tokens, mask])
         seq, behind = self._note_issue()
         t_dispatch = now_ms()
@@ -3152,6 +3257,7 @@ class DecodeEngine:
             active = int(active_at_dispatch.sum())
             kv_pages_live = self._kv_pages_live()
             kv_rows = self._kv_rows()
+            kv_full = self._kv_full_pages_live()
             samp_f, samp_i, bias_ids_d, bias_vals_d = self._sampling_arrays()
             # ONE per-dispatch upload: tokens / active / sample index.
             state = np.stack([
@@ -3176,7 +3282,8 @@ class DecodeEngine:
             )
         return _IssuedTurn(packed, seq, h, t_dispatch, now_ms(), behind,
                            active_at_dispatch, prev_tokens,
-                           len(self._trains), kv_pages_live, kv_rows)
+                           len(self._trains), kv_pages_live, kv_rows,
+                           kv_full)
 
     def _complete_turn(self, ph: Any, issued: _IssuedTurn) -> None:
         """Fetch and harvest an issued scan inside the open
@@ -3222,7 +3329,8 @@ class DecodeEngine:
             active, issued.trains,
             packed_host[2 * h + 1:, 0] if self._moe_kw else (0, 0, 0, 0),
             kv_pages_live=issued.kv_pages_live, kv_rows=issued.kv_rows,
-            queued_behind=issued.queued_behind)
+            queued_behind=issued.queued_behind,
+            kv_full_pages_live=issued.kv_full_pages_live)
         if links is not None:
             self._record_turn_span(rec, links, h)
 
@@ -3337,6 +3445,14 @@ class DecodeEngine:
                 out.append(s.request.request_id)
         return out
 
+    def _refuse_parcels(self) -> None:
+        """A parcel carries pages; a slot's ring is no page of the pool."""
+        if self._ring_pages:
+            raise ValueError(
+                f"{self.model.name}: the page fabric moves a stream as the "
+                "pages of its table; with state by layer kind the sliding "
+                "layers' ring is not among them")
+
     def request_migration(
         self, request_id: str,
         deliver: Callable[[PageParcel], bool],
@@ -3349,6 +3465,7 @@ class DecodeEngine:
         left decoding untouched on False/raise. Returns False if the
         stream is not live here (advisory — a stream that finishes
         before service is simply skipped, duplicates are harmless)."""
+        self._refuse_parcels()
         live = any(
             (not s.free) and s.request is not None
             and s.request.request_id == request_id
@@ -3380,6 +3497,7 @@ class DecodeEngine:
         chain (reclaim cache pins -> capacity-truncate), so a stale
         accept is honest, never corrupting. A False return leaves the
         source slot untouched — it simply resumes decoding."""
+        self._refuse_parcels()
         if parcel.page_size != self.page_size:
             return False
         if parcel.kind == STREAM:
@@ -3911,6 +4029,7 @@ class DecodeEngine:
             list(self.turns.copy()) if records is None else records,
             self.num_slots, self.turns_dropped, span_ms, longest,
             self._table_walked,
+            self._n_table_entries if self._ring_pages else 0,
         )
 
     def startup_summary(self) -> Dict[str, Any]:
@@ -3973,6 +4092,8 @@ class DecodeEngine:
                 # the table columns its decode scan walks a slot
                 layer_windows=list(self._layer_windows),
                 layer_table_widths=list(self._layer_table_widths),
+                **({"full_pages_live": turns.get("kv_full_pages_live", 0)}
+                   if self._ring_pages else {}),
                 **self._index_pool_stats(turns),
             ),
             "page_journal": {

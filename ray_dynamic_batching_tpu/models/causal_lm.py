@@ -263,6 +263,7 @@ class CausalLM(ServableModel):
         starts: jax.Array,     # [B] global position of tokens[:, 0] per row
         take_idx: jax.Array,   # [B] per-row logits row to return
         moe_counters: bool = False,
+        ring_tables: Optional[jax.Array] = None,  # [B, NP] the rows' rings
     ) -> Tuple[jax.Array, ...]:
         """Pages-DIRECT chunked prefill: one chunk of B independent (and
         independently-positioned) prompt fills, written straight through
@@ -282,7 +283,10 @@ class CausalLM(ServableModel):
         owns both (the engine scatters verified lengths itself at the
         final chunk). Returns (logits at ``take_idx`` [B, V], cache) and,
         with ``moe_counters`` (an expert model), the chunk's
-        :func:`routing_counters` over ``attn_mask``'s real tokens."""
+        :func:`routing_counters` over ``attn_mask``'s real tokens.
+        ``ring_tables`` (a model with state by layer kind): each row's
+        slot's ring table (``models/decoder.py::ring_table``), through
+        which its sliding layers write and read."""
         B, W = tokens.shape
         S = tables.shape[1] * cache.page_size
         positions = starts[:, None] + jnp.broadcast_to(
@@ -296,6 +300,7 @@ class CausalLM(ServableModel):
             params, tokens, positions, None, cache, scatter_writes=True,
             page_table=tables, kv_lengths=starts,
             moe_valid=attn_mask if moe_counters else None,
+            ring_tables=ring_tables,
         )
         taken = jnp.take_along_axis(
             logits, take_idx[:, None, None], axis=1
@@ -368,14 +373,17 @@ class CausalLM(ServableModel):
 
     def make_paged_cache(
         self, batch_size: int, num_pages: int, page_size: int,
-        max_len: int,
+        max_len: int, widest_chunk: Optional[int] = None,
     ) -> PagedKVCache:
         """A paged KV pool: ``num_pages`` fixed HBM pages + a
         ``[batch_size, max_len // page_size]`` page table (engine-owned
-        allocation — ``engine/paging.py``)."""
+        allocation — ``engine/paging.py``). ``widest_chunk``: the most
+        rows one program writes to a slot at once, which sizes the
+        sliding layers' ring where state is by layer kind."""
         return PagedKVCache.zeros(
             self.cfg, batch_size, num_pages, page_size, max_len,
             dtype=self.kv_dtype or self.dtype, index_dtype=self.dtype,
+            widest_chunk=widest_chunk,
         )
 
     def decode_step_paged(
@@ -438,6 +446,12 @@ class CausalLM(ServableModel):
         # an indexer's ONE key a position a layer, in the model's own dtype
         index_row = (c.index_head_dim * jnp.dtype(self.dtype).itemsize
                      if c.index_topk else 0)
+        if c.kv_by_kind:
+            # the full layers a position, the sliding layers their window
+            row = (c.head_dim + c.v_head_dim) * itemsize
+            return (c.layers_of(False) * S * c.num_kv_heads * row
+                    + c.layers_of(True) * min(S, c.sliding_window)
+                    * (c.sliding_kv_heads or c.num_kv_heads) * row)
         return c.num_layers * S * (2 * c.num_kv_heads * per_row + index_row)
 
     def sharding_rules(self):

@@ -26,6 +26,7 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax.struct import dataclass as pytree_dataclass
 
 from ray_dynamic_batching_tpu.ops import attention as attn_ops
@@ -103,11 +104,38 @@ class DecoderConfig:
     index_head_dim: int = 0
     # Every RMSNorm's epsilon (the published ``rms_norm_eps``).
     rms_eps: float = 1e-5
+    # A VALUE head's width where it is not the key's (0: ``head_dim``).
+    v_head_dim: int = 0
+    # A sliding layer's own KV head count and rotary base (0: the full
+    # layers' ``num_kv_heads`` / ``rope_theta``).
+    sliding_kv_heads: int = 0
+    sliding_rope_theta: float = 0.0
+    # Rotary positions over the first ``rope_dim`` values of a head only,
+    # the rest passing unrotated (0: the whole head).
+    rope_dim: int = 0
+    # A sliding layer's softmax carries a learned SINK, one scalar a query
+    # head: it takes probability mass and adds no value.
+    sliding_sink: bool = False
+    # Values times this, before they are cached.
+    value_scale: float = 1.0
 
     def __post_init__(self):
         if not self.head_dim:
             object.__setattr__(
                 self, "head_dim", self.d_model // self.num_heads)
+        if not self.v_head_dim:
+            object.__setattr__(self, "v_head_dim", self.head_dim)
+        if (self.kv_by_kind or self.sliding_rope_theta
+                or self.sliding_sink) and not (
+                    self.sliding_window and "L" in self.layer_pattern):
+            raise ValueError(
+                "sliding_kv_heads, sliding_rope_theta and sliding_sink are "
+                "a sliding layer's, and a v_head_dim of its own needs "
+                "state by layer kind, a ring for the sliding layers: no "
+                "layer slides")
+        if self.rope_dim % 2 or self.rope_dim > self.head_dim:
+            raise ValueError(f"rope_dim {self.rope_dim} is not an even "
+                             f"part of a head of {self.head_dim}")
         if self.sliding_window and set(self.layer_pattern) - set("LG"):
             raise ValueError(
                 f"layer_pattern {self.layer_pattern!r}: letters are L "
@@ -129,11 +157,29 @@ class DecoderConfig:
         """Experts this program holds: ``moe_held_experts``, or all."""
         return self.moe_held_experts or self.num_experts
 
+    @property
+    def kv_by_kind(self) -> bool:
+        """State by layer kind (``PagedKVCache``): the full layers page in
+        a pool of their own head count, the sliding layers keep a ring of
+        their window a slot. It is what a model whose kinds differ in head
+        count, or whose values are narrower than its keys, cannot do
+        without: one pool holds one head count and one row width."""
+        return bool(self.sliding_kv_heads
+                    or self.v_head_dim != self.head_dim)
+
+    def _slides(self, i: int) -> bool:
+        return bool(self.sliding_window) and (
+            self.layer_pattern[i % len(self.layer_pattern)] == "L")
+
     def layer_kind(self, i: int) -> "LayerKind":
         """What layer ``i`` is: THE place a layer asks."""
-        slides = bool(self.sliding_window) and (
-            self.layer_pattern[i % len(self.layer_pattern)] == "L")
+        slides = self._slides(i)
         sparse = self.num_experts > 0 and i >= self.num_dense_layers
+        by_kind = {}
+        if self.kv_by_kind:
+            # its place among the layers of its own kind: its pool's layer
+            by_kind = dict(ring=slides, pool_layer=sum(
+                1 for j in range(i) if self._slides(j) == slides))
         return LayerKind(
             window=self.sliding_window if slides else 0,
             rope=self.pos == "rope" and (
@@ -142,7 +188,16 @@ class DecoderConfig:
             mlp_dim=(self.mlp_dim if sparse or not self.num_experts
                      else self.dense_mlp_dim),
             select=self.index_topk,
+            kv_heads=self.sliding_kv_heads if slides else 0,
+            rope_theta=self.sliding_rope_theta if slides else 0.0,
+            sink=slides and self.sliding_sink,
+            **by_kind,
         )
+
+    def layers_of(self, ring: bool) -> int:
+        """How many layers keep a ring (``ring``) or pages (not)."""
+        return sum(1 for i in range(self.num_layers)
+                   if self._slides(i) == ring)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -154,6 +209,16 @@ class LayerKind:
     sparse: bool    # an expert MLP (models/moe.py), else a dense one
     mlp_dim: int    # the dense MLP's width, or ONE routed expert's
     select: int = 0  # positions its indexer keeps a query; 0 = no indexer
+    # Its own KV head count and rotary base, where they are not the
+    # configuration's ``num_kv_heads`` / ``rope_theta`` (0: they are).
+    kv_heads: int = 0
+    rope_theta: float = 0.0
+    sink: bool = False       # a learned sink in its softmax
+    # Under ``kv_by_kind``: whether its state is a ring of its window (else
+    # pages), and its layer among its pool's; -1: the model has ONE pool
+    # and the layer's own index is its place there.
+    ring: bool = False
+    pool_layer: int = -1
 
 
 @pytree_dataclass
@@ -228,7 +293,21 @@ class PagedKVCache:
     ``[L, P, page_size, Hip]``, a selecting model's index keys (one a
     position a layer, ``Hip`` the indexer's head lane-padded; the model's
     own dtype in an int8 pool too): a second kind of per-position state
-    in the one pool, None for a model without an indexer."""
+    in the one pool, None for a model without an indexer.
+
+    State BY LAYER KIND (``DecoderConfig.kv_by_kind``): ``k``/``v`` hold
+    the FULL layers only (``L`` their count, ``K`` their head count; a v
+    row as wide as a value head, lane-padded, where that is narrower than
+    a key's), and the sliding layers keep ``ring_k``/``ring_v``
+    ``[L_w, B * R, page_size, K_w, Hp]``: a ring of ``R`` pages a slot
+    (:attr:`ring_pages`), read and written through :func:`ring_table`, a
+    page table that is arithmetic (logical column ``c`` of slot ``b`` is
+    page ``b * R + c % R``), so a window layer uses the paged write, the
+    gather and the kernel's window walk as they are and the allocator
+    hands out full-layer pages only. A position older than the ring is
+    overwritten by a newer one; nothing attends it (the window's lower
+    edge is the kernel's and the fallback's mask, by position), so a
+    reused slot's ring is never cleared. None for every other model."""
 
     k: jax.Array
     v: jax.Array
@@ -237,6 +316,8 @@ class PagedKVCache:
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
     index_k: Optional[jax.Array] = None
+    ring_k: Optional[jax.Array] = None
+    ring_v: Optional[jax.Array] = None
 
     @staticmethod
     def zeros(
@@ -244,16 +325,52 @@ class PagedKVCache:
         page_size: int, max_len: int,
         dtype: jnp.dtype = jnp.bfloat16,
         index_dtype: jnp.dtype = jnp.bfloat16,
+        widest_chunk: Optional[int] = None,
     ) -> "PagedKVCache":
+        """``widest_chunk`` (state by layer kind only): the most rows one
+        program writes to a slot at once, which with the window sets the
+        pages of a slot's ring (:attr:`ring_pages`)."""
         if max_len % page_size != 0:
             raise ValueError(
                 f"max_len {max_len} must be a multiple of page_size "
                 f"{page_size} (logical capacity is whole pages)"
             )
         n_entries = max_len // page_size
+        quantized = jnp.dtype(dtype) == jnp.dtype(jnp.int8)
+        if cfg.kv_by_kind:
+            if quantized or cfg.index_topk:
+                raise NotImplementedError(
+                    "kv_by_kind: the ring has no scale planes and no index "
+                    "keys (an int8 pool, an indexer)")
+            rows = lambda layers, pages, heads, width: jnp.zeros(  # noqa: E731
+                (layers, pages, page_size, heads, pool_head_dim(width)),
+                dtype)
+            if widest_chunk is None:
+                raise ValueError(
+                    "state by layer kind: a slot's ring is sized for the "
+                    "widest chunk written to it at once; pass widest_chunk")
+            from ray_dynamic_batching_tpu.ops.tile_math import (
+                window_table_width,
+            )
+
+            full, slide = cfg.layers_of(False), cfg.layers_of(True)
+            # The table columns that a chunk's rows can attend between
+            # them (window 128, 512 rows, pages of 128: 6), so that no row
+            # of a chunk is written over a position another row attends.
+            ring = batch_size * window_table_width(
+                cfg.sliding_window, widest_chunk, page_size, n_entries)
+            k_w = cfg.sliding_kv_heads or cfg.num_kv_heads
+            return PagedKVCache(
+                k=rows(full, num_pages, cfg.num_kv_heads, cfg.head_dim),
+                v=rows(full, num_pages, cfg.num_kv_heads, cfg.v_head_dim),
+                page_table=jnp.full((batch_size, n_entries), num_pages,
+                                    dtype=jnp.int32),
+                lengths=jnp.zeros((batch_size,), dtype=jnp.int32),
+                ring_k=rows(slide, ring, k_w, cfg.head_dim),
+                ring_v=rows(slide, ring, k_w, cfg.v_head_dim),
+            )
         shape = (cfg.num_layers, num_pages, page_size,
                  cfg.num_kv_heads, pool_head_dim(cfg.head_dim))
-        quantized = jnp.dtype(dtype) == jnp.dtype(jnp.int8)
         return PagedKVCache(
             k=jnp.zeros(shape, dtype=dtype),
             v=jnp.zeros(shape, dtype=dtype),
@@ -284,6 +401,22 @@ class PagedKVCache:
     @property
     def quantized(self) -> bool:
         return self.k_scale is not None
+
+    @property
+    def ring_pages(self) -> int:
+        """Pages in a slot's ring; 0: one pool for every layer."""
+        if self.ring_k is None:
+            return 0
+        return self.ring_k.shape[1] // self.page_table.shape[0]
+
+
+def ring_table(slots, ring: int, n_entries: int):
+    """The sliding layers' page table, ``[len(slots), n_entries]``: logical
+    column ``c`` of slot ``b`` is ring page ``b * ring + c % ring``.
+    Arithmetic on ``slots`` (a numpy or a traced array alike): nothing is
+    allocated, freed or stored."""
+    cols = np.arange(n_entries, dtype=np.int32) % ring
+    return slots[:, None] * ring + cols[None, :]
 
 
 def pool_head_dim(head_dim: int) -> int:
@@ -337,9 +470,16 @@ def dequantize_kv(codes: jax.Array, scale: jax.Array,
 
 
 def apply_rope(
-    x: jax.Array, positions: jax.Array, theta: float = 10000.0
+    x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+    rope_dim: int = 0,
 ) -> jax.Array:
-    """Rotary embedding. x [B, T, N, H], positions [B, T]."""
+    """Rotary embedding. x [B, T, N, H], positions [B, T]. ``rope_dim``:
+    over the head's first ``rope_dim`` values only (rotate-half within
+    them), the rest unrotated; 0: the whole head."""
+    if rope_dim and rope_dim < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rope_dim], positions, theta),
+             x[..., rope_dim:]], axis=-1)
     H = x.shape[-1]
     half = H // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
@@ -413,8 +553,19 @@ class DecoderLayer(nn.Module):
         )
         y = self._norm("attn_norm")(x).astype(self.dtype)
         q = dense((cfg.num_heads, cfg.head_dim), "q")(y)
-        k = dense((cfg.num_kv_heads, cfg.head_dim), "k")(y)
-        v = dense((cfg.num_kv_heads, cfg.head_dim), "v")(y)
+        kv_heads = kind.kv_heads or cfg.num_kv_heads
+        k = dense((kv_heads, cfg.head_dim), "k")(y)
+        v = dense((kv_heads, cfg.v_head_dim), "v")(y)
+        if cfg.value_scale != 1.0:
+            v = v * cfg.value_scale
+        # What only a model with state by layer kind has: a sink, a value
+        # head narrower than a key's. Such a layer's attention is
+        # ``ops/kind_attention.py``'s wherever the paged kernel is not.
+        sink = (self.param("sink", nn.initializers.zeros, (cfg.num_heads,),
+                           jnp.float32) if kind.sink else None)
+        odd = kind.sink or cfg.v_head_dim != cfg.head_dim
+        # This layer's place in its pool.
+        li = layer_idx if kind.pool_layer < 0 else kind.pool_layer
         qk_norm = lambda name: RMSNorm(  # noqa: E731
             name=name, eps=cfg.rms_eps)
         if cfg.qk_norm and cfg.qk_norm_per_head:
@@ -426,8 +577,10 @@ class DecoderLayer(nn.Module):
             k = qk_norm("k_norm")(
                 k.reshape(*k.shape[:2], -1)).reshape(k.shape)
         if kind.rope:
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k = apply_rope(k, positions, cfg.rope_theta)
+            rope = (kind.rope_theta or cfg.rope_theta,) + (
+                (cfg.rope_dim,) if cfg.rope_dim else ())
+            q = apply_rope(q, positions, *rope)
+            k = apply_rope(k, positions, *rope)
         select = None
         if kind.select:
             # Imported here: a model without an indexer never loads it.
@@ -475,6 +628,10 @@ class DecoderLayer(nn.Module):
                 ks_full = vs_full = None
                 k_w, v_w = k, v
             B, T = positions.shape
+            if page_table is None and kind.pool_layer >= 0:
+                raise NotImplementedError(
+                    "state by layer kind is the paged cache's: the slab "
+                    "cache has one shape for every layer")
             if page_table is not None:
                 # Paged writes: the cache arrays are page POOLS
                 # [L, P, ps, K, H]; each token's logical position maps
@@ -508,23 +665,23 @@ class DecoderLayer(nn.Module):
                     idx < n_entries * ps, page_table[rows, pidx], P
                 )
                 off = idx % ps
-                k_full = k_full.at[layer_idx, pid, off].set(
+                k_full = k_full.at[li, pid, off].set(
                     k_w, mode="drop"
                 )
-                v_full = v_full.at[layer_idx, pid, off].set(
+                v_full = v_full.at[li, pid, off].set(
                     v_w, mode="drop"
                 )
                 if quantized:
-                    ks_full = ks_full.at[layer_idx, pid, off].set(
+                    ks_full = ks_full.at[li, pid, off].set(
                         k_s, mode="drop"
                     )
-                    vs_full = vs_full.at[layer_idx, pid, off].set(
+                    vs_full = vs_full.at[li, pid, off].set(
                         v_s, mode="drop"
                     )
                 if kind.select:
                     # The index key rides the SAME (page, offset): written
                     # before it is scored, as k and v are.
-                    index_pool = index_pool.at[layer_idx, pid, off].set(
+                    index_pool = index_pool.at[li, pid, off].set(
                         fit_head_dim(k_i, index_pool.shape[-1]).astype(
                             index_pool.dtype), mode="drop")
                     select = sparse_attention.Selection(
@@ -535,17 +692,17 @@ class DecoderLayer(nn.Module):
                 # own length). mode="drop" voids rows steered out of
                 # bounds, exactly like the single-token decode scatter.
                 rows = jnp.arange(B)[:, None]
-                k_full = k_full.at[layer_idx, rows, positions].set(
+                k_full = k_full.at[li, rows, positions].set(
                     k_w, mode="drop"
                 )
-                v_full = v_full.at[layer_idx, rows, positions].set(
+                v_full = v_full.at[li, rows, positions].set(
                     v_w, mode="drop"
                 )
                 if quantized:
-                    ks_full = ks_full.at[layer_idx, rows, positions].set(
+                    ks_full = ks_full.at[li, rows, positions].set(
                         k_s, mode="drop"
                     )
-                    vs_full = vs_full.at[layer_idx, rows, positions].set(
+                    vs_full = vs_full.at[li, rows, positions].set(
                         v_s, mode="drop"
                     )
             elif T == 1:
@@ -554,17 +711,17 @@ class DecoderLayer(nn.Module):
                 # instead of clamping onto (and corrupting) the last slot.
                 idx = positions[:, 0]
                 rows = jnp.arange(B)
-                k_full = k_full.at[layer_idx, rows, idx].set(
+                k_full = k_full.at[li, rows, idx].set(
                     k_w[:, 0], mode="drop"
                 )
-                v_full = v_full.at[layer_idx, rows, idx].set(
+                v_full = v_full.at[li, rows, idx].set(
                     v_w[:, 0], mode="drop"
                 )
                 if quantized:
-                    ks_full = ks_full.at[layer_idx, rows, idx].set(
+                    ks_full = ks_full.at[li, rows, idx].set(
                         k_s[:, 0], mode="drop"
                     )
-                    vs_full = vs_full.at[layer_idx, rows, idx].set(
+                    vs_full = vs_full.at[li, rows, idx].set(
                         v_s[:, 0], mode="drop"
                     )
             else:
@@ -574,25 +731,25 @@ class DecoderLayer(nn.Module):
                 # (dynamic start, static chunk shape).
                 start = write_start if write_start is not None else 0
                 k_full = jax.lax.dynamic_update_slice(
-                    k_full, k_w[None], (layer_idx, 0, start, 0, 0)
+                    k_full, k_w[None], (li, 0, start, 0, 0)
                 )
                 v_full = jax.lax.dynamic_update_slice(
-                    v_full, v_w[None], (layer_idx, 0, start, 0, 0)
+                    v_full, v_w[None], (li, 0, start, 0, 0)
                 )
                 if quantized:
                     ks_full = jax.lax.dynamic_update_slice(
-                        ks_full, k_s[None], (layer_idx, 0, start, 0)
+                        ks_full, k_s[None], (li, 0, start, 0)
                     )
                     vs_full = jax.lax.dynamic_update_slice(
-                        vs_full, v_s[None], (layer_idx, 0, start, 0)
+                        vs_full, v_s[None], (li, 0, start, 0)
                     )
             # Quantized caches hand CODES + scales to the dispatcher:
             # the decode kernel scans the 1-byte codes directly (the
             # bandwidth win); non-kernel paths dequantize there.
             scale_kwargs = {}
             if quantized:
-                scale_kwargs = {"k_scale": ks_full[layer_idx],
-                                "v_scale": vs_full[layer_idx]}
+                scale_kwargs = {"k_scale": ks_full[li],
+                                "v_scale": vs_full[li]}
                 new_cache = (k_full, v_full, ks_full, vs_full)
             else:
                 new_cache = (k_full, v_full)
@@ -602,19 +759,35 @@ class DecoderLayer(nn.Module):
                 # Pallas paged kernel's block map, or in the fallback's
                 # one (layer, page) gather + the shared decode mask: one
                 # mask rule, token-exact either way. Slicing
-                # ``k_full[layer_idx]`` here would make XLA materialise
+                # ``k_full[li]`` here would make XLA materialise
                 # a layer of the pool per layer per substep in front of
                 # the kernel (a Mosaic operand is a buffer).
                 kv = (k_full, v_full)
                 scale_kwargs.update(page_table=page_table,
-                                    kv_lengths=kv_lengths, layer=layer_idx,
+                                    kv_lengths=kv_lengths, layer=li,
                                     sliding=kind.window)
                 if select is not None:
                     scale_kwargs["select"] = select
+                if odd:
+                    scale_kwargs.update(sink=sink, v_dim=cfg.v_head_dim)
             else:
-                kv = (k_full[layer_idx], v_full[layer_idx])
+                kv = (k_full[li], v_full[li])
             attn_out = attn_ops.dot_product_attention(
                 q, *kv, mask=mask, **scale_kwargs)
+        elif odd:
+            # Whole-sequence attention with a sink or a narrower value
+            # head: plain XLA under the causal (and valid-token) mask.
+            from ray_dynamic_batching_tpu.ops import kind_attention
+
+            B, T = positions.shape
+            allowed = (prefill_mask(token_mask) if token_mask is not None
+                       else mask if mask is not None
+                       else jnp.ones((B, 1, T, T), bool))
+            if kind.window and mask is None:
+                allowed = allowed & sliding_edge(
+                    positions, T, kind.window)[:, None]
+            attn_out = kind_attention.dense(q, k, v, allowed, sink)
+            new_cache = None
         elif kind.select:
             # A selecting layer's whole-sequence attention: the causal
             # (and valid-token) mask, of which each query keeps its best.
@@ -696,6 +869,7 @@ class DecoderModule(nn.Module):
         scatter_writes: bool = False,  # per-row multi-token cache writes
         page_table: Optional[jax.Array] = None,  # paged decode (T == 1)
         kv_lengths: Optional[jax.Array] = None,
+        ring_tables: Optional[jax.Array] = None,  # [B, NP]: rows' rings
     ) -> Tuple[jax.Array, Optional[KVCache]]:
         cfg = self.cfg
         embed = nn.Embed(
@@ -726,14 +900,30 @@ class DecoderModule(nn.Module):
         index_kw = {}
         if getattr(cache, "index_k", None) is not None:
             index_kw["index_pool"] = cache.index_k
+        # State by layer kind: the sliding layers' ring rides beside the
+        # full layers' pool, each layer handed its own kind's and its table
+        # (a row's ring table is its slot's: the caller's for a chunk's
+        # rows, slot b's for row b of a decode step).
+        ring_kv = None
+        if getattr(cache, "ring_k", None) is not None:
+            ring_kv = (cache.ring_k, cache.ring_v)
+            if ring_tables is None:
+                ring_tables = ring_table(
+                    jnp.arange(tokens.shape[0], dtype=jnp.int32),
+                    cache.ring_pages, page_table.shape[1])
         for i in range(cfg.num_layers):
+            ring = ring_kv is not None and cfg.layer_kind(i).ring
             x, updated, *index = DecoderLayer(
                 cfg, dtype=self.dtype, name=f"layer{i}")(
-                x, positions, mask, cache_kv, token_mask, layer_idx=i,
+                x, positions, mask, ring_kv if ring else cache_kv,
+                token_mask, layer_idx=i,
                 write_start=write_start, scatter_writes=scatter_writes,
-                page_table=page_table, kv_lengths=kv_lengths, **index_kw,
+                page_table=ring_tables if ring else page_table,
+                kv_lengths=kv_lengths, **index_kw,
             )
-            if updated is not None:
+            if updated is not None and ring:
+                ring_kv = updated
+            elif updated is not None:
                 cache_kv = updated
             if index:
                 index_kw["index_pool"] = index[0]
@@ -765,6 +955,8 @@ class DecoderModule(nn.Module):
                     k=cache_kv[0], v=cache_kv[1], page_table=page_table,
                     lengths=cache.lengths, **scales,
                     index_k=index_kw.get("index_pool"),
+                    **({} if ring_kv is None else
+                       {"ring_k": ring_kv[0], "ring_v": ring_kv[1]}),
                 )
             else:
                 out_cache = KVCache(

@@ -39,6 +39,7 @@ PATH_PAGED_KERNEL = "paged_kernel"
 PATH_SLAB_KERNEL = "slab_kernel"
 PATH_FLASH = "flash"
 PATH_XLA = "xla"
+PATH_BLOCKED = "blocked"
 
 
 class AttentionDeclined(ValueError):
@@ -61,6 +62,8 @@ class AttentionPath:
     kv_dtype: str
     declines: Tuple[str, ...]  # why each kernel tried first said no
     sliding: int = 0          # a sliding layer's window on a paged read
+    v_dim: int = 0            # a value row's width where not the key's
+    sink: bool = False        # a learned sink in the softmax
 
     def describe(self) -> str:
         name = {
@@ -68,10 +71,13 @@ class AttentionPath:
             PATH_SLAB_KERNEL: "slab kernel",
             PATH_FLASH: "flash kernel",
             PATH_XLA: "XLA einsum",
+            PATH_BLOCKED: "XLA online softmax over blocks of pages",
         }[self.path]
         how = (["stacked pool"] if self.stacked else []) + (
             [f"shard_map tp={self.tp}"] if self.tp > 1 else []) + (
-            [f"window {self.sliding}"] if self.sliding else [])
+            [f"window {self.sliding}"] if self.sliding else []) + (
+            [f"v rows {self.v_dim}"] if self.v_dim else []) + (
+            ["sink"] if self.sink else [])
         if how:
             name += f" ({', '.join(how)})"
         return ("gather-then-" if self.gathered else "") + name
@@ -93,7 +99,8 @@ def clear_attention_paths() -> None:
 
 
 def _record(path: str, q, k, declines: List[str], *, gathered: bool = False,
-            stacked: bool = False, tp: int = 1, sliding: int = 0) -> None:
+            stacked: bool = False, tp: int = 1, sliding: int = 0,
+            v_dim: int = 0, sink: bool = False) -> None:
     kernel = path in (PATH_PAGED_KERNEL, PATH_SLAB_KERNEL, PATH_FLASH)
     _PATHS.append(AttentionPath(
         program=current_program(), path=path, gathered=gathered,
@@ -101,6 +108,7 @@ def _record(path: str, q, k, declines: List[str], *, gathered: bool = False,
         interpret=kernel and resolve_interpret(None),
         q_shape=tuple(q.shape), kv_shape=tuple(k.shape),
         kv_dtype=str(k.dtype), declines=tuple(declines), sliding=sliding,
+        v_dim=v_dim, sink=sink,
     ))
 
 
@@ -232,6 +240,8 @@ def dot_product_attention(
     layer: int = 0,
     sliding: int = 0,
     select: Optional[tuple] = None,
+    sink: Optional[jax.Array] = None,
+    v_dim: int = 0,
 ) -> jax.Array:
     """Multi-head attention.
 
@@ -261,13 +271,20 @@ def dot_product_attention(
     ``models/decoder.py::paged_window_mask``. ``select`` (paged reads
     only; ``ops/sparse_attention.py::Selection``) is a layer with an
     indexer: each row attends only its best-scored positions of those.
+    ``v_dim`` > 0 (paged reads only; the value head's width) marks a layer
+    of a model with state by layer kind: its v rows may be narrower than
+    its k rows and its softmax may carry a learned ``sink`` ([N] float32,
+    or None): the paged kernel takes both, and ``ops/kind_attention.py``
+    every other read.
     """
     if page_table is not None:
         return _paged_attention(
             q, k, v, page_table, kv_lengths, layer, mask=mask,
             scale=scale, k_scale=k_scale, v_scale=v_scale, sliding=sliding,
-            select=select,
+            select=select, sink=sink, v_dim=v_dim,
         )
+    if v_dim or sink is not None:
+        raise ValueError("sink and v_dim are the paged read's")
     if select is not None:
         raise ValueError("select is the paged read's; elsewhere a "
                          "selection rides the mask")
@@ -432,6 +449,8 @@ def _paged_attention(
     v_scale: Optional[jax.Array],
     sliding: int = 0,
     select: Optional[tuple] = None,
+    sink: Optional[jax.Array] = None,
+    v_dim: int = 0,
 ) -> jax.Array:
     """Paged decode read: fused page-table KV scan on the Pallas path,
     explicit gather back to the slab view otherwise (the token-exact
@@ -452,6 +471,10 @@ def _paged_attention(
         # One layer's pool: a one-layer stack (a free reshape).
         k, v, layer = k[None], v[None], 0
     declines: List[str] = []
+    # what only a layer of a model with state by layer kind passes on
+    kind = {"sink": sink, "v_dim": v_dim} if v_dim else {}
+    kind_record = ({"v_dim": int(v.shape[-1]), "sink": sink is not None}
+                   if v_dim else {})
     if select is not None:
         from ray_dynamic_batching_tpu.ops import sparse_attention
 
@@ -474,12 +497,31 @@ def _paged_attention(
         out = decode_attention.paged_decode_attention(
             q, k, v, page_table, kv_lengths, layer=layer, scale=scale,
             k_scale=k_scale, v_scale=v_scale, why=declines,
-            sliding=sliding, **mesh_kwargs,
+            sliding=sliding, **mesh_kwargs, **kind,
         )
         if out is not None:
             _record(PATH_PAGED_KERNEL, q, k, declines, stacked=stacked,
-                    tp=tp, sliding=sliding)
+                    tp=tp, sliding=sliding, **kind_record)
             return out
+    if kind:
+        # A sink, a narrower value row: no gather-then-kernel form takes
+        # them. The table is walked in blocks of pages in plain XLA (a
+        # chunk's rows on the chip; every read where Pallas is off).
+        from ray_dynamic_batching_tpu.ops import kind_attention
+
+        if k_scale is not None:
+            raise ValueError("a layer with a sink has no int8 pool")
+        if _BACKEND == "pallas" and q.shape[1] <= 8:
+            raise AttentionDeclined(
+                "attention backend 'pallas' is strict and the paged kernel "
+                f"declined q{tuple(q.shape)}: " + "; ".join(declines))
+        _record(PATH_BLOCKED, q, k, declines, stacked=stacked,
+                sliding=sliding, **kind_record)
+        with jax.named_scope("chunk_attention_window" if sliding
+                             else "chunk_attention_full"):
+            return kind_attention.paged(
+                q, k, v, page_table, kv_lengths, layer, sliding=sliding,
+                scale=scale, sink=sink)[..., :v_dim]
     # Gather fallback: rebuild each slot's logical KV run [B, S, K, H]
     # (S = NP * ps) and re-enter the slab path. Sentinel/garbage pages
     # clamp to a real page, then the length mask voids their positions —

@@ -145,6 +145,9 @@ class DecodePath:
     table_width: int = 0  # page-table columns a slot's walk can reach
     walk: str = ""        # WALK_*: how the pages of a slot are walked
     depth: int = 0        # pages in the walk's VMEM ring
+    kv_heads: int = 0     # the pool's KV heads (a layer kind's own)
+    v_dim: int = 0        # a v row's width where not the k row's
+    sink: bool = False    # a learned sink joins the sum at the scan's end
 
     def describe(self) -> str:
         return (f"{self.kb} heads x {self.rows} rows over "
@@ -152,7 +155,9 @@ class DecodePath:
                 f"{self.kv_dtype} pages -> {self.form} ({self.why}); "
                 f"{self.walk}, a ring of {self.depth}, of "
                 + (f"window {self.sliding}: the " if self.sliding else "")
-                + f"{self.table_width} table columns a slot")
+                + f"{self.table_width} table columns a slot"
+                + (f"; v rows {self.v_dim} wide" if self.v_dim else "")
+                + ("; a sink" if self.sink else ""))
 
 
 # Bounded, trace-time only: a call a layer of each traced program.
@@ -169,7 +174,8 @@ def clear_decode_paths() -> None:
 
 
 def _record_path(kb: int, rows: int, ps: int, H: int, dtype,
-                 sliding: int, table_width: int, depth: int) -> None:
+                 sliding: int, table_width: int, depth: int,
+                 fold: int = 1, **kind) -> None:
     if tile_math.flat_heads(kb, rows, ps):
         form, why = FORM_FLAT, (
             f"{kb * rows} rows x {ps * kb} columns in one contraction")
@@ -180,11 +186,15 @@ def _record_path(kb: int, rows: int, ps: int, H: int, dtype,
         form, why = FORM_PER_HEAD, (
             f"flat score tiles of {kb * rows} rows x {ps * kb} columns "
             f"pass {tile_math.FLAT_SCORE_MAX_BYTES >> 20} MiB")
+    if fold > 1:    # _narrow_fold
+        form, why = FORM_FLAT, (
+            f"a {kb}-head page read as {kb * fold}-row tiles: {kb * rows} "
+            f"rows x {ps * kb} columns in one contraction")
     _PATHS.append(DecodePath(
         program=current_program(), kb=kb, rows=rows, page_size=ps,
         head_dim=H, kv_dtype=str(jnp.dtype(dtype)), form=form, why=why,
         sliding=sliding, table_width=table_width, walk=WALK_LOOP,
-        depth=depth))
+        depth=depth, **kind))
 
 
 def _window_rows(mask_ref, rows: int, R: int, window: int):
@@ -265,20 +275,26 @@ def _scan_begin(m_ref, l_ref, acc_ref):
     acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
 
-def _scan_end(o_ref, m_ref, l_ref, acc_ref):
+def _scan_end(o_ref, m_ref, l_ref, acc_ref, sink_ref=None):
     # A fully-masked row (inactive spec rows are steered out of
     # bounds; their outputs are never consumed) -> zeros, not NaN.
+    # ``sink_ref`` (laid out as the state is): a learned sink a row, one
+    # more term of the sum and nothing of the accumulator.
+    def denominator(at):
+        l = l_ref[at]
+        if sink_ref is not None:
+            l = l + jnp.where(
+                l > 0.0, jnp.exp(sink_ref[at] - m_ref[at]), 0.0)
+        return jnp.where(l == 0.0, 1.0, l)
+
     if l_ref.shape[1] == 1:     # flat heads: [kb * R, 1] (and the
         # per-head [kb, R] at R == 1, the same layout and division)
-        l = l_ref[...]
         o_ref[0, :, :] = (
-            acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
-        ).astype(o_ref.dtype)
+            acc_ref[...] / denominator(...)).astype(o_ref.dtype)
         return
     kb, R = l_ref.shape         # per head: [kb, R]
     for h in range(kb):
-        l = l_ref[h, :]
-        l = jnp.where(l == 0.0, 1.0, l)
+        l = denominator((h, slice(None)))
         o_ref[0, h * R:(h + 1) * R, :] = (
             acc_ref[h * R:(h + 1) * R, :] / l[:, None]
         ).astype(o_ref.dtype)
@@ -347,7 +363,7 @@ def _accumulate_tile(
     if tile_math.flat_heads(kb, R, Sb):
         rows, cols = kb * R, Sb * kb
         k_flat = k_ref[0].reshape(cols, H)
-        v_flat = v_ref[0].reshape(cols, H).astype(compute_dtype)
+        v_flat = v_ref[0].reshape(cols, v_ref.shape[3]).astype(compute_dtype)
         s = scores(q_ref[0], k_flat,
                    None if ks_ref is None else ks_ref[0, 0, 0])
         own = (jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1) % kb
@@ -436,6 +452,47 @@ def _flat_scale_spec(cols: int, index_map) -> pl.BlockSpec:
     per-column factor of a [rows, cols] score tile broadcasts from."""
     return pl.BlockSpec(  # rdb-lint: disable=tile-alignment (a [1, cols] f32 lane row pads to 8 sublanes: 32 KB for 4 KB of scales beside a 256 KB code tile; any taller layout would need an in-kernel relayout to lanes)
         (1, 1, 1, 1, cols), index_map)
+
+
+def _fold_flat(q_ref, k_tile, v_tile, m_ref, l_ref, acc_ref, *, ps: int,
+               kb: int, valid, scale: float):
+    """:func:`_accumulate_tile`'s flat-heads fold for a head block of any
+    width (no scales: a bf16 pool): the page of ``ps`` positions of ``kb``
+    heads read as [ps * kb, H] (column ``c`` = position ``c // kb`` of
+    head ``c % kb``), every row scored against every column, a row keeping
+    its own head's. The tile is [1, ps, kb, H] or the view
+    [1, ps // f, kb * f, H] of the same bytes
+    (``tile_math.page_view_fold``), whose rows flatten in the same order
+    with no relayout. A v row may be narrower than a k row."""
+    H = k_tile.shape[-1]
+    rows, cols = q_ref.shape[1], ps * kb
+    R = rows // kb
+    s = jax.lax.dot_general(
+        q_ref[0], k_tile[0].reshape(cols, H),
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    own = (jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1) % kb
+           == jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0) // R)
+    m_ref[...], l_ref[...], acc_ref[...] = _softmax_fold(
+        jnp.where(own & valid, s, NEG_INF),
+        v_tile[0].reshape(cols, v_tile.shape[-1]),
+        None, m_ref[...], l_ref[...], acc_ref[...])
+
+
+def _narrow_fold(K: int, kb: int, R: int, ps: int, has_scales: bool) -> int:
+    """``f`` > 1 where the paged kernel folds a head block NARROWER than 8
+    that is all of K in one contraction (:func:`_fold_flat`), from the
+    shapes alone: the per-head fold's strided head slices of a (4, 128)
+    tile a position cost 6.6 us a live page on a v5e against the copy's
+    0.7 (PERF.md, PR 41). ``f`` is ``tile_math.page_view_fold``: a pool
+    whose rows are one lane tile wide is then read through its tile view.
+    1 (the form :func:`tile_math.flat_heads` picks) for a block of 8, an
+    int8 pool (the fold reads no scales), rows that are not whole
+    sublane tiles, and score tiles past the flat form's budget."""
+    if (kb == K and not has_scales and (kb * R) % 8 == 0
+            and tile_math.flat_heads(8, R, ps)):
+        return tile_math.page_view_fold(kb, ps)
+    return 1
 
 
 def _scratch(kb: int, R: int, H: int, flat: bool):
@@ -569,6 +626,7 @@ def _paged_decode_attention(
     layer: jax.Array,       # [1] int32 — which layer of the stack to read
     k_scale: Optional[jax.Array],  # [P, ps, K] f32 (int8 pool), or None
     v_scale: Optional[jax.Array],
+    sink: Optional[jax.Array] = None,  # [K, R] f32: a sink a query row
     *,
     scale: float,
     window: int,
@@ -578,12 +636,26 @@ def _paged_decode_attention(
     B, K, R, H = q.shape
     G = R // window
     P, ps = k.shape[1], k.shape[2]
+    Hv = v.shape[-1]            # a v row may be narrower than a k row
     NP = page_table.shape[1]
     kb = _pick_heads_block(K)
     nj = K // kb
     steps = B * nj
     has_scales = k_scale is not None
-    flat = tile_math.flat_heads(kb, R, ps)
+    # A narrow block that is all of K (:func:`_narrow_fold`) is folded by
+    # :func:`_fold_flat`. A pool whose rows are ONE lane tile wide (128) is
+    # read through its tile view [L, P, ps // view, kb * view, 128]
+    # (``tile_math.page_view_fold``: a reshape XLA takes as a bitcast), so
+    # its page arrives as whole (8, 128) tiles; a wider row's view is no
+    # bitcast (a position's two lane tiles lie side by side: XLA would
+    # copy the pool), so that pool is read as it lies and its page relaid
+    # in the fold.
+    view = _narrow_fold(K, kb, R, ps, has_scales)
+    flat = tile_math.flat_heads(kb, R, ps) or view > 1
+    view_k, view_v = (view if H == 128 else 1), (view if Hv == 128 else 1)
+    if view > 1:
+        k = k.reshape(k.shape[:2] + (ps // view_k, K * view_k, H))
+        v = v.reshape(v.shape[:2] + (ps // view_v, K * view_v, Hv))
     depth = tile_math.paged_walk_depth(
         ps, kb, H, k.dtype.itemsize, has_scales, window, G)
     ahead = depth - 1
@@ -618,6 +690,9 @@ def _paged_decode_attention(
         ks_hbm = vs_hbm = ks_buf = vs_buf = None
         if has_scales:
             ks_hbm, vs_hbm, *rest = rest
+        sink_ref = None
+        if sink is not None:
+            sink_ref, *rest = rest
         o_ref, k_buf, v_buf, *rest = rest
         if has_scales:
             ks_buf, vs_buf, *rest = rest
@@ -699,6 +774,10 @@ def _paged_decode_attention(
                 valid = valid & (pos > bound - sliding)
             tile = lambda buf: (
                 None if buf is None else buf.at[pl.ds(slot, 1)])
+            if view > 1:
+                _fold_flat(q_ref, tile(k_buf), tile(v_buf), m_ref, l_ref,
+                           acc_ref, ps=ps, kb=kb, valid=valid, scale=scale)
+                return cursor
             _accumulate_tile(
                 q_ref, tile(k_buf), tile(v_buf), tile(ks_buf), tile(vs_buf),
                 m_ref, l_ref, acc_ref, valid=valid, scale=scale,
@@ -708,15 +787,17 @@ def _paged_decode_attention(
         cur[1], cur[2] = jax.lax.fori_loop(
             0, count, fold, (cur[1], cur[2]))
         cur[0] = (base + count) % depth
-        _scan_end(o_ref, m_ref, l_ref, acc_ref)
+        _scan_end(o_ref, m_ref, l_ref, acc_ref, sink_ref)
 
-    rows_spec = pl.BlockSpec(
-        (1, kb * R, H), lambda b, j, pt, ln, ly: (b, j, 0))
+    a_block = lambda b, j, pt, ln, ly: (b, j, 0)  # noqa: E731
+    rows_spec = pl.BlockSpec((1, kb * R, H), a_block)
+    out_spec = pl.BlockSpec((1, kb * R, Hv), a_block)
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     # A head block's rows are one contiguous [kb * R, H] tile (free: the
     # same bytes).
     args = [q.reshape(B, K * R, H), k, v]
-    ring = [pltpu.VMEM((depth, ps, kb, H), k.dtype)] * 2
+    ring = [pltpu.VMEM((depth, ps // view_k, kb * view_k, H), k.dtype),
+            pltpu.VMEM((depth, ps // view_v, kb * view_v, Hv), v.dtype)]
     if has_scales and flat:
         # A page's scales as ONE lane row in the flat column order.
         args += [_flat_columns(k_scale, kb, ps),
@@ -729,26 +810,34 @@ def _paged_decode_attention(
         # path is the same trap this transpose avoids).
         args += [k_scale.transpose(0, 2, 1), v_scale.transpose(0, 2, 1)]
         ring += [pltpu.VMEM((depth, kb, ps), jnp.float32)] * 2
+    in_specs = [rows_spec] + [in_hbm] * (len(args) - 1)
+    if sink is not None:
+        # laid out as the softmax state is: a column a row of the flat
+        # form, [kb, R] a head block of the per-head form
+        state = (kb * R, 1) if flat or R == 1 else (kb, R)
+        args.append(sink.reshape(K * R // state[1], state[1]))
+        in_specs.append(pl.BlockSpec(
+            state, lambda b, j, pt, ln, ly: (j, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, nj),
-        in_specs=[rows_spec] + [in_hbm] * (len(args) - 1),
-        out_specs=rows_spec,
+        in_specs=in_specs,
+        out_specs=out_spec,
         scratch_shapes=ring + [
             pltpu.SemaphoreType.DMA((len(ring), depth)),
             pltpu.SMEM((3,), jnp.int32),
-        ] + _scratch(kb, R, H, flat),
+        ] + _scratch(kb, R, Hv, flat),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K * R, H), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, K * R, Hv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
-    )(page_table, lengths, layer, *args).reshape(B, K, R, H)
+    )(page_table, lengths, layer, *args).reshape(B, K, R, Hv)
 
 
 def paged_decode_attention(
@@ -767,6 +856,8 @@ def paged_decode_attention(
     mesh_axis: str = "tp",
     why: Optional[List[str]] = None,
     sliding: int = 0,
+    sink: Optional[jax.Array] = None,
+    v_dim: int = 0,
 ) -> Optional[jax.Array]:
     """Fused page-table decode attention; returns None when the shapes
     aren't the paged decode pattern (caller falls back to the explicit
@@ -817,6 +908,14 @@ def paged_decode_attention(
     by the TP degree). Declines (None) when the head axis does not
     divide — replicated heads fall back to the gather path, which GSPMD
     partitions from the pool's NamedSharding.
+
+    ``v_dim`` > 0 (a model with state by layer kind) is the value head's
+    width where it is not the key's: the v pool's rows may then be
+    narrower than the k pool's (their own lane-padded width: the ring of v
+    tiles, the accumulator and the output are that wide), and the output
+    is ``v_dim`` wide. ``sink`` [N] float32 is a learned sink a query
+    head: ``exp(sink - m)`` joins the softmax's sum when a slot's scan
+    ends and adds nothing to the accumulator.
     """
     if k.ndim == 4 and v.ndim == 4:
         k, v = k[None], v[None]  # one layer's pool: a one-layer stack
@@ -835,7 +934,8 @@ def paged_decode_attention(
     # Pool rows may be wider than the head: lane-padded with zeros
     # (models/decoder.py::pool_head_dim). q is padded to match below and
     # the output cut back; zeros add nothing to a score or an output.
-    if Hk < H or v.shape != k.shape or K == 0 or N % K != 0:
+    if Hk < H or v.shape[:-1] != k.shape[:-1] or K == 0 or N % K != 0 or (
+            v.shape[-1] != Hk and not v_dim) or v.shape[-1] < v_dim:
         return declined(
             why, f"paged kernel: q heads {N}x{H} do not group over "
             f"pool heads {K}x{Hk}")
@@ -878,12 +978,21 @@ def paged_decode_attention(
             why, f"paged kernel: page tile (ps={ps}, kb={kb}, H={Hk}) "
             "exceeds the VMEM block budget")
     interpret = resolve_interpret(interpret)
+    kind = {}
+    if v_dim or sink is not None:
+        if tp > 1 or k_scale is not None:
+            return declined(why, "paged kernel: a sink or a narrower v row "
+                                 "under a mesh or over an int8 pool")
+        kind = dict(kv_heads=K, v_dim=int(v.shape[-1]),
+                    sink=sink is not None)
     _record_path(kb, Tq * G, ps, Hk, k.dtype, int(sliding),
                  tile_math.window_table_width(
                      int(sliding), Tq, ps, page_table.shape[1]),
                  tile_math.paged_walk_depth(
                      ps, kb, Hk, k.dtype.itemsize, k_scale is not None,
-                     Tq, G))
+                     Tq, G),
+                 fold=_narrow_fold(
+                     k_local, kb, Tq * G, ps, k_scale is not None), **kind)
     scale = scale if scale is not None else H ** -0.5
     # Rows ordered (t, g) per kv head: [B, Tq, K, G, H] ->
     # [B, K, Tq*G, H] (Tq == 1 collapses to the historical layout),
@@ -901,6 +1010,14 @@ def paged_decode_attention(
     if tp > 1:
         out = _paged_decode_attention_tp(mesh, mesh_axis, *operands,
                                          **static)
+    elif v_dim or sink is not None:
+        if sink is not None:
+            # a row's sink is its query head's: rows are (kv head, t, g)
+            sink = jnp.broadcast_to(
+                sink.astype(jnp.float32).reshape(K, 1, G), (K, Tq, G)
+            ).reshape(K, Tq * G)
+        out = _paged_decode_attention(*operands, sink, **static)
+        H = v_dim or H
     else:
         out = _paged_decode_attention(*operands, **static)
     return out[..., :H].reshape(B, K, Tq, G, H).transpose(

@@ -62,6 +62,7 @@ from ray_dynamic_batching_tpu.ops import tile_math
 from ray_dynamic_batching_tpu.ops.decode_attention import (
     NEG_INF,
     _accumulate_tile,
+    _fold_flat,
     _pick_heads_block,
     _scan_begin,
     _scan_end,
@@ -363,13 +364,13 @@ def _flat(kb: int, G: int, ps: int) -> bool:
     contraction: where the paged kernel does (``tile_math.flat_heads``: a
     block of 8 heads), and for a NARROWER block too (4 key heads: the
     per-head fold's strided head slices cost 3.9 us a page on a v5e
-    against a 0.32 us copy), by :func:`_fold_flat` below."""
+    against a 0.32 us copy), by ``decode_attention._fold_flat``."""
     return tile_math.flat_heads(kb, G, ps) or (
         kb < 8 and tile_math.flat_heads(8, G, ps))
 
 
 def _own_fold(kb: int, G: int, ps: int) -> bool:
-    """Whether the flat fold is this file's :func:`_fold_flat` (a narrow
+    """Whether the flat fold is ``decode_attention._fold_flat`` (a narrow
     block) and not the paged kernel's (``_accumulate_tile``: 8 heads)."""
     return _flat(kb, G, ps) and not tile_math.flat_heads(kb, G, ps)
 
@@ -378,33 +379,10 @@ def _page_fold(kb: int, G: int, ps: int) -> int:
     """``f`` > 1 where the kernel reads the pool through the view
     [L, P, ps // f, kb * f, H] (``tile_math.page_view_fold``: a narrow
     block's page as whole (8, 128) tiles, ``f`` positions' heads a tile),
-    which it does wherever :func:`_fold_flat` takes the page and there is
+    which it does wherever ``_fold_flat`` takes the page and there is
     such a view; 1 where it reads [L, P, ps, kb, H] as it is (a block of 8
     heads, whose tiles are whole already; 3 heads; the per-head fold)."""
     return tile_math.page_view_fold(kb, ps) if _own_fold(kb, G, ps) else 1
-
-
-def _fold_flat(q_ref, k_tile, v_tile, m_ref, l_ref, acc_ref, *, ps: int,
-               kb: int, valid, scale: float):
-    """``decode_attention._accumulate_tile``'s flat-heads fold for a head
-    block of any width: the page of ``ps`` positions of ``kb`` heads read
-    as [ps * kb, H] (column ``c`` = position ``c // kb`` of head
-    ``c % kb``), every row scored against every column, a row keeping its
-    own head's. The tile is [1, ps, kb, H] or the view
-    [1, ps // f, kb * f, H] of the same bytes (:func:`_page_fold`), whose
-    rows flatten in the same order with no relayout."""
-    H = k_tile.shape[-1]
-    rows, cols = q_ref.shape[1], ps * kb
-    R = rows // kb
-    s = jax.lax.dot_general(
-        q_ref[0], k_tile[0].reshape(cols, H),
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
-    own = (jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1) % kb
-           == jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0) // R)
-    m_ref[...], l_ref[...], acc_ref[...] = _softmax_fold(
-        jnp.where(own & valid, s, NEG_INF), v_tile[0].reshape(cols, H),
-        None, m_ref[...], l_ref[...], acc_ref[...])
 
 
 def sparse_paged_decode_attention(

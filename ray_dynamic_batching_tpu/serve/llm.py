@@ -36,6 +36,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
 from ray_dynamic_batching_tpu.engine.decode import (
+    DEFAULT_PROMPT_BUCKETS,
     DecodeEngine,
     require_paged,
 )
@@ -569,6 +570,14 @@ class LLMDeployment:
                         jax.random.PRNGKey(1)
                     )
 
+    def _prompt_buckets_for(self, max_len: int) -> Optional[List[int]]:
+        """The prompt buckets an engine of ``max_len`` is built with
+        (None: the engine's own)."""
+        if self.prompt_buckets is None:
+            return None
+        fitting = [b for b in self.prompt_buckets if b <= max_len]
+        return fitting or [max_len]
+
     def pool_bytes_per_slot(self, model: Any, max_len: int) -> int:
         """What one slot's full page run occupies in the engine's pool:
         the bytes ``model.make_paged_cache`` allocates for
@@ -583,12 +592,16 @@ class LLMDeployment:
         from ray_dynamic_batching_tpu.ops.tile_math import pages_for
 
         n = pages_for(max_len, self.page_size)
+        # a slot's ring (state by layer kind) is sized for the widest chunk
+        buckets = self._prompt_buckets_for(max_len) or [
+            b for b in DEFAULT_PROMPT_BUCKETS if b <= max_len]
         pool = jax.eval_shape(lambda: model.make_paged_cache(
-            1, n, self.page_size, n * self.page_size))
+            1, n, self.page_size, n * self.page_size,
+            widest_chunk=max(buckets, default=None)))
         return sum(
             math.prod(x.shape) * x.dtype.itemsize
             for x in (pool.k, pool.v, pool.k_scale, pool.v_scale,
-                      pool.index_k)
+                      pool.index_k, pool.ring_k, pool.ring_v)
             if x is not None
         )
 
@@ -863,10 +876,7 @@ class LLMDeployment:
                 n_chips, max_len=max_len,
                 budget_fraction=1.0 / len(self.length_buckets),
             )
-        prompt_buckets = self.prompt_buckets
-        if prompt_buckets is not None:
-            fitting = [b for b in prompt_buckets if b <= max_len]
-            prompt_buckets = fitting or [max_len]
+        prompt_buckets = self._prompt_buckets_for(max_len)
         return DecodeEngine(
             model,
             params,

@@ -104,11 +104,15 @@ def run_paged(q, k, v, ks, vs, table, lens, layer=L - 1):
     return out
 
 
-def expected_form(config, window):
+def expected_form(config, window, dtype=jnp.bfloat16):
     g = GEOMETRIES[config]
     kb = da._pick_heads_block(g["K"])
+    rows = window * g["N"] // g["K"]
+    # a block of 8 by the score tiles' size; a narrower one that is all of
+    # K folds flat too, but for an int8 pool (the fold reads no scales)
     return (da.FORM_FLAT
-            if tile_math.flat_heads(kb, window * g["N"] // g["K"], PS)
+            if tile_math.flat_heads(kb, rows, PS) or da._narrow_fold(
+                g["K"], kb, rows, PS, dtype == jnp.int8) > 1
             else da.FORM_PER_HEAD)
 
 
@@ -128,7 +132,8 @@ class TestKernelAgainstTheGatherPath:
         H = q.shape[-1]
         da.clear_decode_paths()
         out = np.asarray(run_paged(*args).astype(jnp.float32))
-        assert da.decode_paths()[-1].form == expected_form(config, window)
+        assert da.decode_paths()[-1].form == expected_form(
+            config, window, dtype)
         kg = gathered(k, table, L - 1)[..., :H]
         vg = gathered(v, table, L - 1)[..., :H]
         if ks is not None:
@@ -195,9 +200,15 @@ class TestSlabAndPagedAreOneBody:
             k_scale=None if ks is None else gathered(ks, table, L - 1),
             v_scale=None if vs is None else gathered(vs, table, L - 1))
         assert slab is not None
-        np.testing.assert_array_equal(
-            np.asarray(paged.astype(jnp.float32)),
-            np.asarray(slab[..., :q.shape[-1]].astype(jnp.float32)))
+        paged = np.asarray(paged.astype(jnp.float32))
+        slab = np.asarray(slab[..., :q.shape[-1]].astype(jnp.float32))
+        if config == "four-kv-heads" and dtype == jnp.bfloat16:
+            # not one body: the paged kernel folds a narrow block that is
+            # all of K in one contraction (``_fold_flat``), the slab kernel
+            # a head at a time; f32 summation order, then bf16's rounding
+            np.testing.assert_allclose(paged, slab, rtol=1e-2, atol=1e-3)
+        else:
+            np.testing.assert_array_equal(paged, slab)
 
 
 class TestFormsAgree:
@@ -224,6 +235,31 @@ class TestFormsAgree:
             atol=1e-2 * max(1.0, np.abs(per_head).max()))
 
 
+    @pytest.mark.parametrize("window", [1, 5])
+    def test_a_narrow_blocks_fold_equals_per_head(self, window, monkeypatch):
+        """Four KV heads that are all of K: the one contraction over the
+        page's tile view against the per-head form on the same pool (the
+        rule turned off), and an int8 pool keeps the per-head form."""
+        args = make_case("four-kv-heads", jnp.bfloat16, window,
+                         lengths_for("mid_page", window))
+        flat = np.asarray(run_paged(*args).astype(jnp.float32))
+        assert da.decode_paths()[-1].form == da.FORM_FLAT
+        monkeypatch.setattr(da, "_narrow_fold", lambda *a: 1)
+        da._paged_decode_attention.clear_cache()
+        try:
+            per_head = np.asarray(run_paged(*args).astype(jnp.float32))
+            assert da.decode_paths()[-1].form == da.FORM_PER_HEAD
+        finally:
+            da._paged_decode_attention.clear_cache()
+        np.testing.assert_allclose(
+            flat, per_head, rtol=1e-2,
+            atol=1e-2 * max(1.0, np.abs(per_head).max()))
+        monkeypatch.undo()
+        run_paged(*make_case("four-kv-heads", jnp.int8, window,
+                             lengths_for("mid_page", window)))
+        assert da.decode_paths()[-1].form == da.FORM_PER_HEAD
+
+
 class TestDecodePaths:
     """Which body a program took is on record (``decode_paths()``), as
     ``ops/moe.py::moe_paths`` records the expert path."""
@@ -234,7 +270,8 @@ class TestDecodePaths:
         ("olmoe-1b-7b", 1, da.FORM_FLAT),
         ("gpt2-medium", 5, da.FORM_FLAT),
         ("mistral-7b", 8, da.FORM_FLAT),      # 8 x G 4: 2 MiB of tiles
-        ("four-kv-heads", 1, da.FORM_PER_HEAD),
+        ("four-kv-heads", 1, da.FORM_FLAT),   # all of K in one fold
+        ("four-kv-heads", 5, da.FORM_FLAT),
         ("wide-gqa", 1, da.FORM_FLAT),
         ("wide-gqa", 8, da.FORM_PER_HEAD),    # 8 x G 8: 4 MiB
     ])

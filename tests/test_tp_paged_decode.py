@@ -93,7 +93,10 @@ class TestTPPagedKernel:
     """The shard_map wrapper around the Pallas page-table kernel
     (interpret mode — the CPU-runnable half of the TPU lowering):
     per-shard head slices through the same ``_accumulate_tile`` body must
-    reproduce the unsharded kernel bit-for-bit, f32 and int8."""
+    reproduce the unsharded kernel bit-for-bit for an int8 pool; a float
+    pool's block narrower than 8 heads is folded in ONE contraction over
+    the page's tile view (``_fold_flat``), whose width the shard halves:
+    the same numbers in another order of f32 sums."""
 
     def _mesh_out(self, dtype, eight_devices):
         import numpy as np
@@ -112,7 +115,11 @@ class TestTPPagedKernel:
             mesh=mesh,
         )
         assert out is not None and base is not None
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(base))
+        if ks is None:
+            np.testing.assert_allclose(
+                np.asarray(out), np.asarray(base), rtol=2e-6, atol=1e-6)
+        else:
+            np.testing.assert_array_equal(np.asarray(out), np.asarray(base))
 
     def test_tp2_kernel_matches_unsharded_f32(self, eight_devices):
         self._mesh_out(jnp.float32, eight_devices)

@@ -273,6 +273,10 @@ class TestPagedKernelCompilesForV5e:
             "mistral-7b"],
         ("four-kv-heads", 1): dict(L=2, P=16, B=4, NP=4, N=8, K=4, H=128),
         ("four-kv-heads", 5): dict(L=2, P=16, B=4, NP=4, N=8, K=4, H=128),
+        # narrower still (a shard of a TP mesh; one KV head): four and
+        # eight positions' heads an (8, 128) tile of the page's view
+        ("two-kv-heads", 1): dict(L=2, P=16, B=4, NP=4, N=8, K=2, H=128),
+        ("one-kv-head", 1): dict(L=2, P=16, B=4, NP=4, N=8, K=1, H=128),
         # K-EXAONE's pool (benchmark/configs/k-exaone-236b-ep8-1chip.json)
         # read by a window-128 layer, and gpt2-medium's two head blocks
         # under a window astride three pages
@@ -289,6 +293,10 @@ class TestPagedKernelCompilesForV5e:
     def test_both_forms_compile(self, case, dtype, one_chip):
         from jax.experimental.compilation_cache import compilation_cache
 
+        if dtype == jnp.int8 and self.CASES[case]["K"] < 4:
+            pytest.skip("an int8 pool of fewer than 4 heads keeps the "
+                        "per-head form, whose (4, 128) int8 tile Mosaic "
+                        "refuses to slice: no configuration has one")
         struct = lambda shape, dt: jax.ShapeDtypeStruct(
             shape, dt, sharding=one_chip)
         f, args = TestPagedKernelLowersForTPU._call(
@@ -302,8 +310,11 @@ class TestPagedKernelCompilesForV5e:
         finally:
             jax.config.update("jax_enable_compilation_cache", True)
             compilation_cache.reset_cache()
-        want = da.FORM_PER_HEAD if case[0] == "four-kv-heads" \
-            else da.FORM_FLAT
+        # four heads that are all of K fold in one contraction over the
+        # page's tile view; an int8 pool's (no scales there) one at a time
+        want = da.FORM_PER_HEAD if (
+            case[0].endswith(("-kv-heads", "-kv-head")) and dtype == jnp.int8
+        ) else da.FORM_FLAT
         assert da.decode_paths()[-1].form == want
 
 
@@ -367,6 +378,153 @@ class TestSparseKernelCompilesForV5e:
             ["bitcast", "fusion", "scatter"] if kv == 4
             else ["fusion", "scatter"]), made
         assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+class TestKindsKernelCompilesForV5e:
+    """The paged kernel handed a pool of ONE LAYER KIND of a model whose
+    kinds differ (``benchmark/configs/mimo-v2-flash-ep16-1chip.json``): the
+    full layers' pages (40 slots, a table of 144, 64/4 heads, k rows 256
+    lanes and v rows 128: a block of FOUR key heads folded in one
+    contraction; the v pool, a lane tile a row, read through its tile
+    view, two positions' heads an (8, 128) tile, which the compiler must
+    take as a BITCAST behind the page write; the k pool, two lane tiles a
+    row, as it lies: its view would be a 3 GB copy)
+    and the window layers' ring (64/8 heads, 240 pages, a window of 128, a
+    sink a query head). Nothing runs: the cell's traced run says what a
+    page costs."""
+
+    @pytest.mark.parametrize("kv, pages, sliding", [
+        (4, 5760, 0), (8, 240, 128)])
+    def test_a_narrower_v_pool_and_a_sink_compile(
+            self, kv, pages, sliding, one_chip):
+        import re
+
+        from jax.experimental.compilation_cache import compilation_cache
+
+        from ray_dynamic_batching_tpu.ops import decode_attention
+
+        B, NP, ps, N, L = 40, 144, 128, 64, 2
+        struct = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dt, sharding=one_chip)
+
+        def step(q, k, v, table, lengths, sink, page, krow, vrow):
+            k, v = k.at[1, page, 0].set(krow), v.at[1, page, 0].set(vrow)
+            out = decode_attention.paged_decode_attention(
+                q, k, v, table, lengths, layer=1, sliding=sliding,
+                sink=sink if sliding else None, v_dim=128, interpret=False)
+            assert out is not None
+            return out, k, v
+
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        decode_attention.clear_decode_paths()
+        try:
+            compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+                struct((B, 1, N, 192), jnp.bfloat16),
+                struct((L, pages, ps, kv, 256), jnp.bfloat16),
+                struct((L, pages, ps, kv, 128), jnp.bfloat16),
+                struct((B, NP), jnp.int32), struct((B,), jnp.int32),
+                struct((N,), jnp.float32), struct((B,), jnp.int32),
+                struct((B, kv, 256), jnp.bfloat16),
+                struct((B, kv, 128), jnp.bfloat16)).compile()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+            compilation_cache.reset_cache()
+        (path,) = decode_attention.decode_paths()
+        assert (path.form, path.kv_heads, path.v_dim, path.sink) == (
+            decode_attention.FORM_FLAT, kv, 128, bool(sliding))
+        text = compiled.as_text()
+        casts = [ln for ln in text.splitlines() if re.search(
+            rf"= bf16\[{L},{pages},{ps // 2},8,(256|128)\]\S* bitcast\(", ln)]
+        assert len(casts) == (1 if kv == 4 else 0), casts      # the v pool
+        made = re.findall(
+            rf"= bf16\[{L},{pages},[\d,]*\]\{{[^}}]*\}} ([\w\-]+)\(", text)
+        made = sorted({op for op in made if op != "parameter"})
+        assert made == (["bitcast", "fusion", "scatter"] if kv == 4
+                        else ["fusion", "scatter"]), made
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+class TestFullLayersChunkAttentionInTheCompiledProgram:
+    """``chunk_attention_full_dev_share_pct.batch`` finds the full layers'
+    chunk attention in a device trace by XLA's fusion names, because the
+    TPU's trace carries no ``jax.named_scope``. The compiled program does
+    (each instruction's ``op_name``): here the two are held together. In
+    ``mimo-v2-flash-ep16-1chip``'s widest chunk program, compiled for a
+    described v5e, every operation the pattern takes that says where it
+    came from came from ``chunk_attention_full`` (none from
+    ``chunk_attention_window``), and the walk's parts are all there under
+    the names the cell's traced run recorded
+    (``benchmark/tests/data/mimo_chunk_ops.txt``)."""
+
+    def test_the_metrics_pattern_is_the_scopes_operations(
+            self, one_chip, monkeypatch):
+        import json
+        import re
+        from pathlib import Path
+        from types import SimpleNamespace
+
+        from jax.experimental.compilation_cache import compilation_cache
+
+        from benchmark import trace_reduce
+        from benchmark.tests.test_kind_readers import FULL_WALK
+        from ray_dynamic_batching_tpu.models.causal_lm import CausalLM
+        from ray_dynamic_batching_tpu.models.decoder import DecoderConfig
+
+        root = Path(__file__).resolve().parents[1] / "benchmark"
+        cfg = json.loads((root / "configs"
+                          / "mimo-v2-flash-ep16-1chip.json").read_text())
+        args = json.loads((root / "layer_metrics" / (
+            "chunk_attention_full_dev_share_pct.batch.json")).read_text())[
+                "args"]
+        llm = cfg["deployment"]["llm"]
+        m = CausalLM(DecoderConfig(**cfg["program"]["decoder_config"]),
+                     name="m", dtype=jnp.bfloat16)
+        B, ps = llm["num_slots"], llm["page_size"]
+        W, NP = max(llm["prompt_buckets"]), llm["max_len"] // ps
+        struct = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dt, sharding=one_chip)
+        placed = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+            lambda x: struct(x.shape, x.dtype), tree)
+        cache = placed(jax.eval_shape(lambda: m.make_paged_cache(
+            B, llm["kv_pool_pages"], ps, llm["max_len"], widest_chunk=W)))
+        p = jax.tree_util.tree_map(
+            lambda x: struct(x.shape, jnp.bfloat16),
+            jax.eval_shape(m.init, jax.random.PRNGKey(0)))
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            text = jax.jit(
+                lambda *a: m.prefill_chunk_paged(*a[:-1], ring_tables=a[-1]),
+                donate_argnums=(3,)).lower(
+                p, struct((1, W), jnp.int32), struct((1, W), jnp.int32),
+                cache, struct((1, NP), jnp.int32), struct((1,), jnp.int32),
+                struct((1,), jnp.int32), struct((1, NP), jnp.int32),
+            ).compile().as_text()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+            compilation_cache.reset_cache()
+        rx = re.compile(args["op"])
+        scopes = {}         # a taken operation's name -> where it came from
+        for line in text.splitlines():
+            if not line.startswith("  ") or "fused_computation" in (
+                    line.split("=")[0]):
+                continue
+            line = line.strip().removeprefix("ROOT ")
+            name = trace_reduce.stable_name(SimpleNamespace(name=line))
+            if not rx.search(name):
+                continue
+            said = re.search(r'op_name="([^"]*)"', line)
+            scopes.setdefault(name, set()).add(
+                "full" if said and "chunk_attention_full" in said.group(1)
+                else "window" if said and "chunk_attention_window" in (
+                    said.group(1)) else "")
+        assert FULL_WALK <= set(scopes), sorted(scopes)
+        assert all("window" not in where for where in scopes.values())
+        # the walk's arithmetic says its scope (a bare copy says nothing)
+        assert all("full" in scopes[n] for n in FULL_WALK
+                   if not n.startswith("copy_"))
 
 
 class TestSelectionsOperationsInTheCompiledPrograms:

@@ -53,7 +53,10 @@ programs the cell serves with).
 configuration (wrong on purpose under ``--control``) deployed through
 ``benchmark.run.Deployed`` and judged by ``benchmark.run.reference_check``,
 once a seed, each with its verdict; ``--few-programs`` warms one bucket and
-one horizon (a control's programs are compiled for it alone).
+one horizon (a control's programs are compiled for it alone). With
+``--gap-sweep GAP[,GAP..]`` (and ``--prompt-lens`` for prompts beside the
+configuration's) it reads instead what each ``undecided_score_gap`` would
+excuse of the checked rows and what margin it would leave (:func:`gap_sweep`).
 """
 
 from __future__ import annotations
@@ -161,6 +164,104 @@ def harness_checks(cfg, control: str, seeds, few_programs: bool) -> int:
     return 0
 
 
+def gap_sweep(cfg, seeds, gaps, prompt_lens) -> int:
+    """What ``reference_check.undecided_score_gap`` excuses and what is left
+    to check, a gap at a time: the configuration deployed through
+    ``benchmark.run.Deployed`` once a seed, ``reference_check``'s own
+    prompts (and ``prompt_lens`` more) served greedy, the reference
+    teacher-forced with every row as computed and each expert layer's
+    distances from the chosen set's edge (``edges=``). For each gap: the
+    positions excused of those CHECKED (the served tokens' rows, all past
+    the prompt), by expert layer and in all; the worst margin of the rest;
+    and the same for the tokens a reference with its weights rounded to
+    float8_e4m3 would have served, which a sound limit must refuse."""
+    import gc
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import reference, run
+
+    check = cfg["reference_check"]
+    ref = reference.get(cfg["reference"])
+    lens = [int(n) for n in check["prompt_lens"]] + list(prompt_lens)
+    n_new = int(check["new_tokens"])
+    tally = {g: dict(excused=0, rows=0, worst=0.0, low=0.0, fails=0)
+             for g in gaps}
+    for n, seed in enumerate(seeds):
+        c = json.loads(json.dumps(cfg))
+        c["program"]["register_as"] += f"_gaps_{n}"
+        dep = run.Deployed(c, seed, jax.devices()[:1], {})
+        read = []       # (prompt length, rows, sequence, served, logits, edge)
+        try:
+            weights = dep.view.view(dep.params, dep.config)
+            rng = np.random.default_rng(int(seed) ^ 0x5EED)
+            for L in lens:
+                prompt = rng.integers(1, dep.vocab_size, size=L).tolist()
+                _stream, fut = dep.submit(
+                    {"tokens": prompt, "max_new_tokens": n_new})
+                served = list(fut.result(timeout=600.0).tokens)
+                rows = np.arange(L - 1, L - 1 + n_new)
+                edges = []
+                seq = (prompt + served)[:-1]
+                logits = np.asarray(ref.logits(
+                    weights, seq, dep.config, edges=edges))[rows]
+                read.append((L, rows, seq, served, logits, np.stack(
+                    [np.asarray(e) for e in edges])[:, rows]))
+            # The weights again, rounded to float8_e4m3: through the host,
+            # a leaf at a time (the chip does not hold them twice), and
+            # put back once the deployment has let go of its own.
+            low = jax.tree_util.tree_map(
+                lambda x: np.asarray(
+                    x.astype(jnp.float8_e4m3fn).astype(x.dtype))
+                if jnp.issubdtype(x.dtype, jnp.floating) else np.asarray(x),
+                weights)
+            config = dep.config
+        finally:
+            dep.close()
+        del dep, weights
+        gc.collect()
+        live = sum(x.nbytes for x in jax.live_arrays())
+        print(f"gaps: {live / 2**30:.2f} GiB of arrays alive after the "
+              "deployment closed", flush=True)
+        low = jax.device_put(low)
+        for L, rows, seq, served, logits, edge in read:
+            lowered = np.asarray(ref.logits(
+                low, seq, config, edges=[]))[rows].argmax(-1)
+            top = logits.max(-1)
+            margin = top - logits[np.arange(n_new), served]
+            low_margin = top - logits[np.arange(n_new), lowered]
+            for g in gaps:
+                excused = edge.min(0) < g
+                left = ~excused
+                worst = float(margin[left].max()) if left.any() else 0.0
+                worst_low = float(
+                    low_margin[left].max()) if left.any() else 0.0
+                t = tally[g]
+                t["excused"] += int(excused.sum())
+                t["rows"] += n_new
+                t["worst"] = max(t["worst"], worst)
+                t["low"] = max(t["low"], worst_low)
+                t["fails"] += worst > run.REF_TOL
+                by_layer = " ".join(f"{int((e < g).sum())}" for e in edge)
+                print(f"gaps: seed {seed} prompt {L} (checked rows "
+                      f"{rows[0]}-{rows[-1]}) gap {g:g}: excused "
+                      f"{int(excused.sum())} of {n_new} (by expert layer "
+                      f"{by_layer}); worst margin of the rest "
+                      f"{worst:.4f}; float8 reference's tokens "
+                      f"{worst_low:.4f} (tolerance {run.REF_TOL})",
+                      flush=True)
+        del low
+        gc.collect()
+    for g, t in tally.items():
+        print(f"gaps: gap {g:g}: excused {t['excused']} of {t['rows']} "
+              f"checked rows; worst margin served {t['worst']:.4f}, "
+              f"{t['fails']} prompts not correct; worst margin of the float8 "
+              f"reference's tokens {t['low']:.4f}", flush=True)
+    return 0
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--config", required=True)
@@ -179,6 +280,12 @@ def main() -> int:
     ap.add_argument("--harness", default="", metavar="SEEDS",
                     help="run.py's own reference_check, once a seed")
     ap.add_argument("--few-programs", action="store_true")
+    ap.add_argument("--gap-sweep", default="", metavar="GAPS",
+                    help="with --harness: what each undecided_score_gap "
+                         "excuses and leaves, once a seed")
+    ap.add_argument("--prompt-lens", default="", metavar="LENS",
+                    help="with --gap-sweep: prompts beside the "
+                         "configuration's own")
     ap.add_argument("--dtype", default="",
                     help="the program's type (default: the configuration's)")
     ap.add_argument("--seeding", action="append", default=[],
@@ -215,6 +322,11 @@ def main() -> int:
     else:
         entry = next(c for c in bench["configs"] if c["name"] == a.config)
         cfg = json.loads((REPO / entry["file"]).read_text())
+    if a.harness and a.gap_sweep:
+        return gap_sweep(
+            cfg, [int(x) for x in a.harness.split(",")],
+            [float(x) for x in a.gap_sweep.split(",")],
+            [int(x) for x in a.prompt_lens.split(",") if x])
     if a.harness:
         return harness_checks(cfg, a.control,
                               [int(x) for x in a.harness.split(",")],
@@ -250,7 +362,8 @@ def main() -> int:
           f"{a.control}", flush=True)
     rng = np.random.default_rng(a.seed)
     seqs = rng.integers(1, model.cfg.vocab_size, size=(B, T)).astype(np.int32)
-    pool = model.make_paged_cache(B, B * per_slot, ps, per_slot * ps)
+    pool = model.make_paged_cache(B, B * per_slot, ps, per_slot * ps,
+                                  widest_chunk=W)
     tables = jnp.asarray(
         rng.permutation(B * per_slot).reshape(B, per_slot), jnp.int32)
 
