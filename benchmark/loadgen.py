@@ -10,9 +10,12 @@ One general generator reads a traffic file (``traffic/<mix>.json``):
   "lo": a, "hi": b}``.
 
 Every seed offers the same work: the sizes of a window are the stated
-distribution's own quantiles (one multiset for every seed), and ``--seed``
-draws the arrival times over the whole window, the order of the sizes and
-the token ids (and the weights).
+distribution's own quantiles (one multiset for every seed). In an open loop
+``--seed`` draws the arrival times over the whole window, the order of the
+sizes and the token ids; in a closed loop the token ids ALONE (the order is
+the traffic file's ``base_seed``'s, the same in every run). It draws the
+weights too, unless the configuration file states one draw
+(``weights_seed``, ``run.py::seeded_params``).
 """
 
 from __future__ import annotations

@@ -155,7 +155,6 @@ class Deployed:
         import jax.numpy as jnp
 
         from benchmark import views
-        from benchmark.weights import make_params
         from ray_dynamic_batching_tpu.models.base import (
             ModelSLO,
             get_model,
@@ -184,8 +183,7 @@ class Deployed:
         self.vocab_size = int(prog["decoder_config"]["vocab_size"])
 
         t = time.monotonic()
-        self.params = make_params(
-            self.model, seed, dtype, getattr(self.view, "seeding", None))
+        self.params = seeded_params(config, self.model, self.view, seed, dtype)
         jax.block_until_ready(self.params)
         split["weights_s"] = time.monotonic() - t
 
@@ -239,6 +237,22 @@ class Deployed:
             self.controller.shutdown()
 
 
+def seeded_params(config: Dict[str, Any], model: Any, view: Any, seed: int,
+                  dtype: Any) -> Any:
+    """The served tree, from --seed; or, where the configuration file
+    states ONE draw (``weights_seed``), from that, at every --seed. A file
+    states one where the draw of the weights changes the WORK a run does (a
+    seeded router behind seeded layers gives a rank's held experts anything
+    from 0.7 to 1.6 of their even share of the routed rows, and the tokens
+    completed follow it), and says under ``assumed`` by what rule the draw
+    was chosen; --seed then draws the token ids alone, of the traffic and
+    of the reference check's prompts."""
+    from benchmark.weights import make_params
+
+    return make_params(model, int(config.get("weights_seed", seed)), dtype,
+                       getattr(view, "seeding", None))
+
+
 def model_factory(prog: Dict[str, Any], name: str):
     """What builds the configuration's model, under ``name``, from
     ``decoder_config`` and the ``dtype`` the registry passes on:
@@ -285,7 +299,7 @@ def reference_check(dep: Deployed, seed: int) -> Dict[str, Any]:
     rng = np.random.default_rng(int(seed) ^ 0x5EED)
     ref = reference.get(dep.config["reference"])
     weights = dep.view.view(dep.params, dep.config)  # no copies
-    worst, ok, served_all = 0.0, True, []
+    worst, ok, short, served_all = 0.0, True, 0, []
     # The reference's own programs compile here, after the replicas armed
     # the compile ledger's steady mark: bracket them as set-up.
     with get_ledger().warming():
@@ -297,7 +311,7 @@ def reference_check(dep: Deployed, seed: int) -> Dict[str, Any]:
             served = list(fut.result(timeout=600.0).tokens)
             served_all.append(served)
             if len(served) != n_new:
-                ok = False
+                ok, short = False, short + 1
                 continue
             seq = prompt + served
             logits = np.asarray(ref.logits(weights, seq[:-1], dep.config))
@@ -307,7 +321,7 @@ def reference_check(dep: Deployed, seed: int) -> Dict[str, Any]:
                 worst = max(worst, gap)
                 ok = ok and gap <= REF_TOL
     return {"ok": bool(ok), "worst_gap": worst, "tol": REF_TOL,
-            "served": served_all}
+            "short_answers": short, "served": served_all}
 
 
 # --- end-to-end metrics -------------------------------------------------------
@@ -415,6 +429,45 @@ def distribution_lines(run: Dict[str, Any], traffic: Dict[str, Any],
             f"parts: queue_wait over one full scan ({h} x tpot p50 = "
             f"{scan_ms:.0f} ms): {100.0 * waited / len(parts):.1f}% of "
             f"{len(parts)} admissions")
+    return lines
+
+
+def work_lines(run: Dict[str, Any], config: Dict[str, Any],
+               engines: Sequence[Any]) -> List[str]:
+    """For a configuration that holds a share of its experts: what the
+    window's work was, beside the rate it gave. One line an engine, from
+    the ring's records dispatched inside the window (always on, so an
+    untraced run prints it too): the share of the routed pairs that landed
+    on the experts held here, beside the even share; the requests sent and
+    completed inside the window; tokens per second. A printed line, no
+    metric: two runs whose rates differ can be told apart by their work."""
+    from benchmark import stats
+
+    sizes = config["program"]["decoder_config"]
+    held = int(sizes.get("moe_held_experts", 0))
+    if not held:
+        return []
+    recs, window_s = run["records"], run["window_s"]
+    rate = stats.window_rate([t for r in recs for t in r["stamps"]], window_s)
+    done = sum(1 for r in recs
+               if r["closed"] is not None and r["closed"] <= window_s)
+    lo = run["t0"] * 1000.0
+    lines = []
+    for i, eng in enumerate(engines):
+        inside = [t for t in eng.turns.copy()   # one call: it may append
+                  if lo <= t.t_dispatch < lo + window_s * 1000.0]
+        summary = eng.turn_summary(records=inside, span_ms=window_s * 1000.0)
+        share = summary.get("moe_held_rows_share")
+        if share is None:
+            continue
+        lines.append(
+            f"work: engine {i}: moe_held_rows_share {100.0 * share:.3f}% of "
+            f"the routed pairs (even {100.0 * held / int(sizes['num_experts']):.3f}: "
+            f"{held} of {sizes['num_experts']} held), "
+            f"{summary.get('moe_rows_per_expert', 0.0):.3f} rows an expert "
+            f"hit, turns_dropped={summary['dropped']}; requests sent "
+            f"{len(recs)}, completed in the window {done}; "
+            f"out_tok_per_s {rate:.3f}")
     return lines
 
 
@@ -569,6 +622,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                 say(line)
         for line in stall_log(run, beat, gcw, compiles, completed):
             say(line)
+        for line in work_lines(run, cell.config, dep.engines):
+            say(line)
         say(memory_stats_line(device["devices"]))
 
         metrics: Dict[str, Dict[str, Any]] = {}
@@ -617,6 +672,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
             result["breakdown"] = {"device_ops": trace_obj.top_ops(10),
                                    "idle_gaps": trace_obj.idle_gaps(10)}
             shutil.rmtree(tracer.dir, ignore_errors=True)
+        # What `correct` compared, each number beside its limit; last in
+        # the line, and the last lines of standard error (main).
+        result["compared"] = {
+            "ref_worst_gap": {"value": ref["worst_gap"], "limit": ref["tol"]},
+            "ref_short_answers": {"value": ref["short_answers"], "limit": 0},
+            "window_wrong_answers": {"value": len(wrong), "limit": 0},
+        }
         return result
     finally:
         dep.close()
@@ -634,6 +696,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     result = run_cell(cell, a.seed, a.seconds, bool(a.trace),
                       require_tpu=True)
     say(json.dumps(result))
+    for name, c in result["compared"].items():
+        print(f"compared: {name} = {c['value']} (limit {c['limit']})",
+              file=sys.stderr, flush=True)
     return 0
 
 

@@ -77,3 +77,59 @@ def test_the_measuring_path_refuses_without_a_tpu():
     assert proc.returncode != 0
     assert "no TPU" in proc.stderr
     assert not any(line.startswith("{") for line in proc.stdout.splitlines())
+
+
+# --- one stated draw of the weights (``weights_seed``) -----------------------
+CONFIGS = {c["name"]: json.loads((ROOT / c["file"]).read_text())
+           for c in BENCH["configs"]}
+STATED = sorted(n for n, c in CONFIGS.items() if "weights_seed" in c)
+
+
+@pytest.mark.parametrize("stated", [True, False])
+def test_a_stated_draw_gives_every_seed_the_same_weights_and_its_own_tokens(
+        stated):
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import loadgen, views
+    from benchmark.run import model_factory, seeded_params
+    from benchmark.tests.tiny import tiny_cell
+
+    cell = tiny_cell("mimo-longdoc-batch")
+    prog = cell.config["program"]
+    model = model_factory(prog, prog["register_as"])(dtype=jnp.float32)
+    view = views.get(cell.config["view"])
+    assert "weights_seed" in cell.config
+    if not stated:      # as every other configuration: the seed's, as today
+        del cell.config["weights_seed"]
+    seeds = (11, 2 ** 31 + 12)
+    a, b = (jax.tree_util.tree_leaves(
+        seeded_params(cell.config, model, view, s, jnp.float32))
+        for s in seeds)
+    same = [bool((x == y).all()) for x, y in zip(a, b)]
+    drawn = [x.size > 1 and float(x.std()) > 0 for x in a]  # not the ones
+    assert any(drawn)
+    if stated:
+        assert all(same)
+    else:
+        assert not any(s for s, d in zip(same, drawn) if d)
+    ra, rb = (loadgen.build_requests(cell.traffic, 512, s, 3.0) for s in seeds)
+    assert [r["tokens"] for r in ra] != [r["tokens"] for r in rb]
+    assert [len(r["tokens"]) for r in ra] == [len(r["tokens"]) for r in rb]
+
+
+def test_the_stated_draw_follows_from_the_rule_its_file_states():
+    """Of the 16 integers from ``base``, the one whose held share of the
+    routed rows, as read on the chip, lies nearest the even share: a
+    reviewer can recompute the choice from the file alone."""
+    assert STATED == ["mimo-v2-flash-ep16-1chip"]   # no other file names it
+    for name in STATED:
+        cfg = CONFIGS[name]
+        rule = cfg["assumed"]["weights_seed"]
+        shares = rule["held_share_pct"]
+        assert len(shares) == 16
+        dc = cfg["program"]["decoder_config"]
+        even = 100.0 * dc["moe_held_experts"] / dc["num_experts"]
+        assert rule["even_share_pct"] == even == 6.25
+        nearest = min(range(16), key=lambda i: abs(shares[i] - even))
+        assert cfg["weights_seed"] == rule["base"] + nearest

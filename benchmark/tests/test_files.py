@@ -101,11 +101,19 @@ def test_published_sizes_and_the_programs_config_agree(name):
                  ("vocab_size", "vocab_size"), ("n_positions", "max_seq_len")]
         assert dc["mlp_dim"] == 4 * cfg["n_embd"]
     else:
+        # A file with routed experts: ``mlp_dim`` is ONE expert's width and
+        # ``intermediate_size`` the leading dense layers', where it has any.
+        experts = "moe_intermediate_size" in cfg
         pairs = [("num_hidden_layers", "num_layers"), ("hidden_size", "d_model"),
                  ("num_attention_heads", "num_heads"),
                  ("num_key_value_heads", "num_kv_heads"),
-                 ("intermediate_size", "mlp_dim"), ("vocab_size", "vocab_size"),
-                 ("rope_theta", "rope_theta")]
+                 ("moe_intermediate_size" if experts else "intermediate_size",
+                  "mlp_dim"),
+                 ("vocab_size", "vocab_size")]
+        if "dense_mlp_dim" in dc:
+            pairs.append(("intermediate_size", "dense_mlp_dim"))
+        rope = cfg.get("rope_parameters", cfg)     # nested in newer files
+        assert rope["rope_theta"] == dc["rope_theta"]
     for pub, prog in pairs:
         assert cfg[pub] == dc[prog], (pub, prog)
 

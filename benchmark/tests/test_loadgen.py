@@ -46,8 +46,12 @@ def test_every_seed_offers_the_same_work_in_its_own_order(mix):
         assert [r["due"] for r in a] != [r["due"] for r in b]
     else:
         assert len(a) == t["set_size"]
-        assert prompts(a) == prompts(b)     # one fixed set, one order
-        assert answers(a) == answers(b)
+        assert prompts(a) == prompts(b)     # one fixed set, one order:
+        assert answers(a) == answers(b)     # the file's base_seed's, not --seed's
+        other = loadgen.build_requests(
+            dict(t, base_seed=int(t["base_seed"]) + 1), 1000, 1, 48.0)
+        assert prompts(other) != prompts(a)
+        assert sorted(prompts(other)) == sorted(prompts(a))
 
 
 def test_quantile_lengths_are_the_distribution_itself():

@@ -22,6 +22,17 @@ def tiny_cell(workload: str, rate_rps: float = 30.0) -> Cell:
                      ("num_key_value_heads", 2 if gqa else 4)):
         if key in cfg:
             cfg[key] = val
+    if cfg.get("view") == "mimo":
+        # State by layer kind and a rank's share of the experts: the widths
+        # the common cut leaves (keys 192 / values 128, 8 window KV heads,
+        # 16 held of 256 experts).
+        dc.update(head_dim=24, v_head_dim=16, rope_dim=8, num_experts=8,
+                  moe_top_k=2, moe_held_experts=4, dense_mlp_dim=96,
+                  mlp_dim=32, sliding_kv_heads=4)
+        cfg.update(head_dim=24, num_experts_per_tok=2)
+    check = cfg["reference_check"]      # a long-context file's prompts
+    if max(check["prompt_lens"]) + check["new_tokens"] > 256:   # max_len
+        check["prompt_lens"] = [150, 200]
     cfg["program"]["register_as"] += "_tiny"
     llm = cfg["deployment"]["llm"]
     llm.update(num_slots=4, max_len=256, prompt_buckets=[32, 64],
