@@ -226,6 +226,7 @@ class _IssuedTurn(NamedTuple):
     kv_pages_live: float
     kv_rows: Tuple[int, int]
     kv_full_pages_live: int = 0
+    kv_latent_rows: int = 0
 
 
 class _IssuedGroup(NamedTuple):
@@ -299,7 +300,13 @@ class Turn(NamedTuple):
     allocator's pages are the full layers' alone). A sliding layer's ring
     is as many pages a slot whatever the traffic, so it is no counter: the
     engine says it once (``snapshot()["kv_pool"]["ring_pages_per_slot"]``,
-    the gauge ``rdb_decode_kv_pool_bytes{kind}``). 0 for any other model."""
+    the gauge ``rdb_decode_kv_pool_bytes{kind}``). 0 for any other model.
+
+    A scan of a model with a LATENT pool (``PagedKVCache.latent``) also
+    carries ``kv_latent_rows``: the pool rows, summed over all slots and
+    layers, that its first substep's scans read (whole live pages, by
+    ``tile_math.live_pages``' rule, from the host's lengths). 0 for any
+    other model."""
 
     kind: str
     t_dispatch: float
@@ -323,6 +330,7 @@ class Turn(NamedTuple):
     kv_rows_selected: int = 0
     queued_behind: int = 0
     kv_full_pages_live: int = 0
+    kv_latent_rows: int = 0
 
 
 # An engine's prompt buckets where its builder names none.
@@ -434,7 +442,9 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
     the share its indexer keeps. With ``full_table_entries`` (a model with
     state by layer kind: a slot's table width) they add
     ``kv_full_pages_live`` and ``kv_full_live_page_share``, the same two of
-    the FULL layers' pages alone."""
+    the FULL layers' pages alone. A latent model's scans add
+    ``kv_latent_rows`` (each weighed by its substeps): the pool rows its
+    decode scans read."""
     scans = [t for t in turns if t.kind == "turn"]
     out: Dict[str, Any] = {"dispatches": len(turns), "scans": len(scans),
                            "dropped": dropped}
@@ -450,6 +460,9 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
         out["kv_full_live_page_share"] = full_live / (
             num_slots * full_table_entries * sum(
                 t.substeps for t in scans))
+    latent_rows = sum(t.kv_latent_rows * t.substeps for t in scans)
+    if latent_rows:
+        out["kv_latent_rows"] = latent_rows
     rows_live = sum(t.kv_rows_live * t.substeps for t in scans)
     if rows_live:
         out["kv_rows_live"] = rows_live
@@ -737,6 +750,30 @@ class DecodeEngine:
                     raise ValueError(
                         f"{getattr(model, 'name', 'model')}: {option} "
                         f"cannot be used with state by layer kind: {why}")
+        # A LATENT pool (``PagedKVCache.latent``): one row a position, no
+        # head axis, no k/v pair. What moves or scales a slot's KV as k/v
+        # pages cannot work with it and is refused here, by name (D10).
+        latent = bool(getattr(getattr(model, "cfg", None), "latent", False))
+        if latent:
+            refused = {
+                "host_spill_pages": host_spill_pages and (
+                    "a spilled page is stored and restored as a k/v pair "
+                    "of heads; a latent page has neither"),
+                "draft_model": draft_model is not None and (
+                    "spec verify scores a window of rows a slot; the "
+                    "absorbed decode kernel folds one row a slot"),
+                "mesh": mesh is not None and (
+                    "a latent row has no head axis to shard"),
+                "kv_dtype int8": jnp.dtype(
+                    getattr(model, "kv_dtype", None) or jnp.bfloat16
+                ) == jnp.dtype(jnp.int8) and (
+                    "a latent row has no scale plane"),
+            }
+            for option, why in refused.items():
+                if why:
+                    raise ValueError(
+                        f"{getattr(model, 'name', 'model')}: {option} "
+                        f"cannot be used with a latent pool: {why}")
         if draft_model is not None and mesh is not None:
             # Loud, like the draft-model conflict ISSUE 13 lifted (and
             # the PR 10 TP-paged pattern): the spec verify window would
@@ -888,7 +925,7 @@ class DecodeEngine:
         # columns a slot's decode scan walks, averaged over layers.
         self._layer_windows: Tuple[int, ...] = tuple(
             getattr(model, "layer_windows", None)
-            or (0,) * self._cache.k.shape[0])  # (a model without a cfg)
+            or (0,) * self._cache.pages.shape[0])  # (a model without a cfg)
         self._layer_table_widths = [
             tile_math.window_table_width(
                 w, 1, self.page_size, self._n_table_entries)
@@ -904,7 +941,7 @@ class DecodeEngine:
             if self._index_topk else 0)
         # The head's true width, as the model's row caches have it
         # (the pool's rows are lane-padded: pool_head_dim).
-        self._kv_head_dim = model.cfg.head_dim if by_kind else (
+        self._kv_head_dim = model.cfg.head_dim if by_kind or latent else (
             jax.eval_shape(lambda: model.make_cache(
                 1, self.page_size)).k.shape[-1])
         # How the pool lies on the device, once, for snapshot():
@@ -914,9 +951,9 @@ class DecodeEngine:
         planes = [x for x in (self._cache.k, self._cache.v,
                               self._cache.k_scale, self._cache.v_scale,
                               self._cache.index_k, self._cache.ring_k,
-                              self._cache.ring_v)
+                              self._cache.ring_v, self._cache.latent)
                   if x is not None]
-        layout = self._cache.k.format.layout
+        layout = self._cache.pages.format.layout
         self._pool_stats = {
             "layout": (None if layout is None
                        else list(layout.major_to_minor)),
@@ -934,6 +971,17 @@ class DecodeEngine:
             for kind, n in self._pool_stats["bytes_by_kind"].items():
                 KV_POOL_BYTES.set(n, tags={"model": model.name,
                                            "kind": kind})
+        # Latent layers (0: k/v pairs), and the pool's own lines.
+        self._latent_layers = self._cache.latent.shape[0] if latent else 0
+        if latent:
+            rows = self._cache.latent
+            self._pool_stats.update(
+                kind="latent", shape=list(rows.shape),
+                row_width=rows.shape[-1],
+                row_bytes=rows.shape[-1] * rows.dtype.itemsize,
+                bytes_by_kind={"latent": rows.on_device_size_in_bytes()})
+            KV_POOL_BYTES.set(rows.on_device_size_in_bytes(),
+                              tags={"model": model.name, "kind": "latent"})
         self._tokens = np.zeros((num_slots, 1), dtype=np.int32)
         self._active_mask = np.zeros((num_slots,), dtype=bool)
         # Per-slot sampling params (temperature 0 == greedy).
@@ -1213,7 +1261,8 @@ class DecodeEngine:
                       kv_pages_live: float = 0,
                       kv_rows: Tuple[int, int] = (0, 0),
                       queued_behind: int = 0,
-                      kv_full_pages_live: int = 0) -> Turn:
+                      kv_full_pages_live: int = 0,
+                      kv_latent_rows: int = 0) -> Turn:
         """Append this dispatch's record to the turn ring (its work on the
         host is done: ``t_done`` is now). ``moe``: the dispatch's routing
         counters as fetched (``Turn``'s ``moe_*`` fields);
@@ -1226,7 +1275,7 @@ class DecodeEngine:
             self._allocator.allocated_pages,
             int(self._len_host.sum()), self._idled,
             *(int(c) for c in moe[:3]), kv_pages_live, int(moe[3]),
-            *kv_rows, queued_behind, kv_full_pages_live,
+            *kv_rows, queued_behind, kv_full_pages_live, kv_latent_rows,
         )
         if len(self.turns) == self.turns.maxlen:
             self.turns_dropped += 1
@@ -1479,6 +1528,8 @@ class DecodeEngine:
         lengths = cache.lengths.at[slots].set(new_len, mode="drop")
         if rings:
             ring_pools = {"ring_k": pools.ring_k, "ring_v": pools.ring_v}
+        if pools.latent is not None:
+            ring_pools = {"latent": pools.latent}
         cache = cache.replace(
             k=pools.k, v=pools.v, lengths=lengths,
             k_scale=pools.k_scale, v_scale=pools.v_scale,
@@ -3258,6 +3309,10 @@ class DecodeEngine:
             kv_pages_live = self._kv_pages_live()
             kv_rows = self._kv_rows()
             kv_full = self._kv_full_pages_live()
+            # a latent model's layers all walk the whole prefix: the
+            # pool rows its scans read are the live pages' (0: k/v pairs)
+            kv_latent = (int(kv_pages_live) * self.page_size
+                         * self._latent_layers)
             samp_f, samp_i, bias_ids_d, bias_vals_d = self._sampling_arrays()
             # ONE per-dispatch upload: tokens / active / sample index.
             state = np.stack([
@@ -3283,7 +3338,7 @@ class DecodeEngine:
         return _IssuedTurn(packed, seq, h, t_dispatch, now_ms(), behind,
                            active_at_dispatch, prev_tokens,
                            len(self._trains), kv_pages_live, kv_rows,
-                           kv_full)
+                           kv_full, kv_latent)
 
     def _complete_turn(self, ph: Any, issued: _IssuedTurn) -> None:
         """Fetch and harvest an issued scan inside the open
@@ -3330,7 +3385,8 @@ class DecodeEngine:
             packed_host[2 * h + 1:, 0] if self._moe_kw else (0, 0, 0, 0),
             kv_pages_live=issued.kv_pages_live, kv_rows=issued.kv_rows,
             queued_behind=issued.queued_behind,
-            kv_full_pages_live=issued.kv_full_pages_live)
+            kv_full_pages_live=issued.kv_full_pages_live,
+            kv_latent_rows=issued.kv_latent_rows)
         if links is not None:
             self._record_turn_span(rec, links, h)
 
@@ -3446,7 +3502,13 @@ class DecodeEngine:
         return out
 
     def _refuse_parcels(self) -> None:
-        """A parcel carries pages; a slot's ring is no page of the pool."""
+        """A parcel carries pages; a slot's ring is no page of the pool,
+        and a latent page is no k/v pair of heads."""
+        if self._latent_layers:
+            raise ValueError(
+                f"{self.model.name}: the page fabric moves a stream as k/v "
+                "pages of heads; a latent pool has one row a position and "
+                "no such pair")
         if self._ring_pages:
             raise ValueError(
                 f"{self.model.name}: the page fabric moves a stream as the "
@@ -4094,6 +4156,8 @@ class DecodeEngine:
                 layer_table_widths=list(self._layer_table_widths),
                 **({"full_pages_live": turns.get("kv_full_pages_live", 0)}
                    if self._ring_pages else {}),
+                **({"latent_rows_read": turns.get("kv_latent_rows", 0)}
+                   if self._latent_layers else {}),
                 **self._index_pool_stats(turns),
             ),
             "page_journal": {

@@ -100,7 +100,9 @@ class CausalLM(ServableModel):
         (logits, cache), state = self.module.apply(
             params, *args, mutable=["moe_routing"], **kwargs)
         return logits, cache, routing_counters(
-            state["moe_routing"], moe_valid, self.cfg.moe_first_expert,
+            # (nothing sown: a depth cut that leaves the dense layers alone)
+            state.get("moe_routing", {}), moe_valid,
+            self.cfg.moe_first_expert,
             self.cfg.held_experts)
 
     # --- ServableModel interface (apply == prefill logits for profiling) ---
@@ -424,10 +426,21 @@ class CausalLM(ServableModel):
             kind = c.layer_kind(i)
             mlp = kind.mlp_dim * (
                 c.moe_top_k + c.moe_shared_experts if kind.sparse else 1)
+            if kind.latent:
+                # the low-rank q and kv paths, keys and values expanded
+                nope = c.head_dim - c.rope_dim
+                proj = (c.d_model * (c.q_lora_rank + c.kv_lora_rank
+                                     + c.rope_dim)
+                        + c.num_heads * (
+                            c.q_lora_rank * c.head_dim
+                            + c.kv_lora_rank * (nope + c.v_head_dim)
+                            + c.v_head_dim * c.d_model))
+            else:
+                proj = (c.d_model * c.head_dim * (
+                    c.num_heads + 2 * c.num_kv_heads)
+                    + c.num_heads * c.head_dim * c.d_model)
             per_tok = 2 * (
-                c.d_model * c.head_dim * (c.num_heads + 2 * c.num_kv_heads)
-                + c.num_heads * c.head_dim * c.d_model
-                + (3 if c.gated_mlp else 2) * c.d_model * mlp
+                proj + (3 if c.gated_mlp else 2) * c.d_model * mlp
             )
             # score+value flops per token, avg T/2 ctx * 2
             attn = 4 * (min(T, 2 * kind.window) if kind.window else T) * (
@@ -446,6 +459,10 @@ class CausalLM(ServableModel):
         # an indexer's ONE key a position a layer, in the model's own dtype
         index_row = (c.index_head_dim * jnp.dtype(self.dtype).itemsize
                      if c.index_topk else 0)
+        if c.latent:
+            # ONE row a position a layer: the latent and the shared key
+            return c.num_layers * S * (
+                c.kv_lora_rank + c.rope_dim) * itemsize
         if c.kv_by_kind:
             # the full layers a position, the sliding layers their window
             row = (c.head_dim + c.v_head_dim) * itemsize
@@ -497,6 +514,9 @@ class CausalLM(ServableModel):
         which is what lets the host-side ``PageAllocator`` stay
         replica-global. Scale planes (``[L, P, ps, K]``) shard with
         their heads; a selecting model's index keys replicate."""
+        if self.cfg.latent:
+            raise NotImplementedError(
+                "a latent pool's rows have no head axis to shard over tp")
         scale_spec = None
         if self.kv_dtype is not None and jnp.dtype(
                 self.kv_dtype) == jnp.dtype(jnp.int8):

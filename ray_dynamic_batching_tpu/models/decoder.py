@@ -118,8 +118,37 @@ class DecoderConfig:
     sliding_sink: bool = False
     # Values times this, before they are cached.
     value_scale: float = 1.0
+    # LATENT attention (models/latent.py): q through a ``q_lora_rank``
+    # bottleneck, k and v expanded from ONE ``kv_lora_rank``-wide latent a
+    # position; a head is ``head_dim`` = a part without positions + the
+    # LAST ``rope_dim`` values, rotary, whose key is one a position shared
+    # by all heads; values ``v_head_dim``. The paged cache holds the latent
+    # and that key alone (``PagedKVCache.latent``). 0: k/v pairs.
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    # YaRN on the rotary frequencies (a latent layer's): positions
+    # stretched ``rope_yarn_factor`` times over ``rope_yarn_original``,
+    # blended by dimension between ``beta_fast`` and ``beta_slow`` turns;
+    # the softmax scale times ``mscale(factor, mscale_all_dim)^2``. 1: none.
+    rope_yarn_factor: float = 1.0
+    rope_yarn_original: int = 0
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_mscale: float = 1.0
+    rope_yarn_mscale_all_dim: float = 0.0
+    # A residual path of ``hc_mult`` STREAMS (models/hyper_connections.py):
+    # every sublayer reads one mix of them and writes into all, through
+    # maps computed from the streams; the stream-to-stream map is
+    # Sinkhorn-normalised, ``hc_sinkhorn_iters`` rounds with ``hc_eps`` in
+    # the denominators, its exponent clamped to ``hc_res_clamp``. 1: the
+    # one stream, ``x + F(norm(x))``.
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
 
     def __post_init__(self):
+        object.__setattr__(self, "hc_res_clamp", tuple(self.hc_res_clamp))
         if not self.head_dim:
             object.__setattr__(
                 self, "head_dim", self.d_model // self.num_heads)
@@ -133,6 +162,23 @@ class DecoderConfig:
                 "a sliding layer's, and a v_head_dim of its own needs "
                 "state by layer kind, a ring for the sliding layers: no "
                 "layer slides")
+        if self.latent and not (
+                self.q_lora_rank and 0 < self.rope_dim < self.head_dim
+                and self.pos == "rope" and self.norm == "rms"
+                and self.num_kv_heads == self.num_heads):
+            raise ValueError(
+                "kv_lora_rank: a latent layer needs q_lora_rank, a head "
+                "split by rope_dim, rotary positions, RMSNorm and as many "
+                "KV heads as heads")
+        if self.latent and (self.sliding_window or self.index_topk
+                            or self.qk_norm or self.use_bias):
+            raise ValueError(
+                "kv_lora_rank: a latent layer attends its whole prefix, "
+                "without an indexer, a q/k norm per head or biases")
+        if (self.rope_yarn_factor > 1.0) and not (
+                self.latent and self.rope_yarn_original):
+            raise ValueError("rope_yarn_factor is a latent layer's and "
+                             "needs rope_yarn_original")
         if self.rope_dim % 2 or self.rope_dim > self.head_dim:
             raise ValueError(f"rope_dim {self.rope_dim} is not an even "
                              f"part of a head of {self.head_dim}")
@@ -164,8 +210,14 @@ class DecoderConfig:
         their window a slot. It is what a model whose kinds differ in head
         count, or whose values are narrower than its keys, cannot do
         without: one pool holds one head count and one row width."""
-        return bool(self.sliding_kv_heads
-                    or self.v_head_dim != self.head_dim)
+        return not self.latent and bool(
+            self.sliding_kv_heads or self.v_head_dim != self.head_dim)
+
+    @property
+    def latent(self) -> bool:
+        """Every layer's attention is latent (``kv_lora_rank``): the paged
+        cache is a pool of rows with no head axis and no k/v pair."""
+        return self.kv_lora_rank > 0
 
     def _slides(self, i: int) -> bool:
         return bool(self.sliding_window) and (
@@ -191,6 +243,7 @@ class DecoderConfig:
             kv_heads=self.sliding_kv_heads if slides else 0,
             rope_theta=self.sliding_rope_theta if slides else 0.0,
             sink=slides and self.sliding_sink,
+            latent=self.latent,
             **by_kind,
         )
 
@@ -219,6 +272,9 @@ class LayerKind:
     # and the layer's own index is its place there.
     ring: bool = False
     pool_layer: int = -1
+    # Its attention is latent: its state is ONE row a position
+    # (``PagedKVCache.latent``), no k/v pair.
+    latent: bool = False
 
 
 @pytree_dataclass
@@ -307,10 +363,16 @@ class PagedKVCache:
     hands out full-layer pages only. A position older than the ring is
     overwritten by a newer one; nothing attends it (the window's lower
     edge is the kernel's and the fallback's mask, by position), so a
-    reused slot's ring is never cleared. None for every other model."""
+    reused slot's ring is never cleared. None for every other model.
 
-    k: jax.Array
-    v: jax.Array
+    A LATENT model (``DecoderConfig.latent``) has no k/v pair at all:
+    ``k`` and ``v`` are None and ``latent`` ``[L, P, page_size, Wp]`` holds
+    one row a position a layer, ``[c_kv | k_r | 0]`` (``Wp``:
+    ``ops/latent_attention.py::row_width``), with NO head axis, paged with
+    the same table. None for every other model."""
+
+    k: Optional[jax.Array]
+    v: Optional[jax.Array]
     page_table: jax.Array  # [B, NP] int32, sentinel P = unallocated
     lengths: jax.Array     # [B] valid logical prefix per slot
     k_scale: Optional[jax.Array] = None
@@ -318,6 +380,7 @@ class PagedKVCache:
     index_k: Optional[jax.Array] = None
     ring_k: Optional[jax.Array] = None
     ring_v: Optional[jax.Array] = None
+    latent: Optional[jax.Array] = None
 
     @staticmethod
     def zeros(
@@ -337,6 +400,23 @@ class PagedKVCache:
             )
         n_entries = max_len // page_size
         quantized = jnp.dtype(dtype) == jnp.dtype(jnp.int8)
+        if cfg.latent:
+            if quantized:
+                raise NotImplementedError(
+                    "kv_lora_rank: a latent row has no scale plane (an "
+                    "int8 pool)")
+            from ray_dynamic_batching_tpu.ops.latent_attention import (
+                row_width,
+            )
+
+            return PagedKVCache(
+                k=None, v=None,
+                page_table=jnp.full((batch_size, n_entries), num_pages,
+                                    dtype=jnp.int32),
+                lengths=jnp.zeros((batch_size,), dtype=jnp.int32),
+                latent=jnp.zeros(
+                    (cfg.num_layers, num_pages, page_size,
+                     row_width(cfg.kv_lora_rank, cfg.rope_dim)), dtype))
         if cfg.kv_by_kind:
             if quantized or cfg.index_topk:
                 raise NotImplementedError(
@@ -385,18 +465,24 @@ class PagedKVCache:
         )
 
     @property
+    def pages(self) -> jax.Array:
+        """The paged pool whose axes 1 and 2 are (page, position): ``k``,
+        or a latent model's rows."""
+        return self.latent if self.k is None else self.k
+
+    @property
     def page_size(self) -> int:
-        return self.k.shape[2]
+        return self.pages.shape[2]
 
     @property
     def num_pages(self) -> int:
-        return self.k.shape[1]
+        return self.pages.shape[1]
 
     @property
     def capacity(self) -> int:
         """Per-slot LOGICAL capacity (page_table width x page size) —
         the same contract as ``KVCache.capacity``."""
-        return self.page_table.shape[1] * self.k.shape[2]
+        return self.page_table.shape[1] * self.page_size
 
     @property
     def quantized(self) -> bool:
@@ -551,7 +637,92 @@ class DecoderLayer(nn.Module):
             param_dtype=jnp.float32,
             name=name,
         )
-        y = self._norm("attn_norm")(x).astype(self.dtype)
+        # A residual path of STREAMS: x is [B, T, n, D], each sublayer
+        # reads one mix of it and writes into all (a model with ``hc_mult``
+        # alone loads the module).
+        hc = None
+        if cfg.hc_mult > 1:
+            from ray_dynamic_batching_tpu.models import hyper_connections
+
+            hc = lambda name: hyper_connections.HyperConnection(  # noqa: E731
+                n=cfg.hc_mult, iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps,
+                rms_eps=cfg.rms_eps, clamp=cfg.hc_res_clamp,
+                dtype=self.dtype, name=name)
+        h, maps = hc("attn_hc")(x) if hc else (x, None)
+        y = self._norm("attn_norm")(h).astype(self.dtype)
+        if kind.latent:
+            attn_out, new_cache = self._latent_attention(
+                y, positions, mask, cache_kv, token_mask, layer_idx,
+                page_table, kv_lengths)
+        else:
+            attn_out, new_cache, index_pool = self._kv_attention(
+                dense, kind, y, positions, mask, cache_kv, token_mask,
+                layer_idx, write_start, scatter_writes, page_table,
+                kv_lengths, index_pool)
+        attn_out = dense(cfg.d_model, "o", axis=(-2, -1))(attn_out)
+        x = hyper_connections.mix(x, attn_out, maps) if hc else x + attn_out
+
+        h, maps = hc("mlp_hc")(x) if hc else (x, None)
+        y = self._norm("mlp_norm")(h).astype(self.dtype)
+        if kind.sparse:
+            from ray_dynamic_batching_tpu.models.moe import (
+                MoEBlock,
+                routing_rule,
+            )
+
+            y = MoEBlock(
+                d_model=cfg.d_model,
+                mlp_dim=cfg.mlp_dim,
+                num_experts=cfg.num_experts,
+                top_k=cfg.moe_top_k,
+                rule=routing_rule(cfg),
+                first_expert=cfg.moe_first_expert,
+                held_experts=cfg.held_experts,
+                shared_dim=cfg.moe_shared_experts * cfg.mlp_dim,
+                gated=cfg.gated_mlp,
+                dtype=self.dtype,
+                name="moe",
+            )(y)
+        elif cfg.gated_mlp:
+            y = swiglu(dense, y, kind.mlp_dim, cfg.d_model)
+        else:
+            y = nn.gelu(dense(kind.mlp_dim, "mlp_up")(y))
+            y = dense(cfg.d_model, "mlp_down")(y)
+        x = hyper_connections.mix(x, y, maps) if hc else x + y
+        if index_pool is not None:
+            return x, new_cache, index_pool
+        return x, new_cache
+
+    def _latent_attention(self, y, positions, mask, cache_kv, token_mask,
+                          layer_idx, page_table, kv_lengths):
+        """A latent layer's heads' outputs and its pool, updated, as a
+        1-tuple (``models/latent.py``; its model alone loads it)."""
+        from ray_dynamic_batching_tpu.models import latent
+
+        if cache_kv is not None and page_table is None:
+            raise NotImplementedError(
+                "a latent layer's rows live in the paged pool "
+                "(PagedKVCache.latent): the slab cache has none")
+        allowed = None
+        if cache_kv is None:
+            B, T = positions.shape
+            allowed = (prefill_mask(token_mask) if token_mask is not None
+                       else mask if mask is not None
+                       else jnp.ones((B, 1, T, T), bool))
+        out, pool = latent.attention(
+            self.cfg, self.dtype,
+            lambda name: RMSNorm(name=name, eps=self.cfg.rms_eps),
+            y, positions, pool=None if cache_kv is None else cache_kv[0],
+            layer=layer_idx, page_table=page_table, kv_lengths=kv_lengths,
+            allowed=allowed)
+        return out, None if pool is None else (pool,)
+
+    def _kv_attention(self, dense, kind, y, positions, mask, cache_kv,
+                      token_mask, layer_idx, write_start, scatter_writes,
+                      page_table, kv_lengths, index_pool):
+        """A layer of k/v pairs: the heads' outputs, the cache updated (or
+        None) and a selecting layer's index pool."""
+        cfg = self.cfg
         q = dense((cfg.num_heads, cfg.head_dim), "q")(y)
         kv_heads = kind.kv_heads or cfg.num_kv_heads
         k = dense((kv_heads, cfg.head_dim), "k")(y)
@@ -820,37 +991,7 @@ class DecoderLayer(nn.Module):
             attn_out = attn_ops.dot_product_attention(q, k, v, mask=mask)
             new_cache = None
 
-        attn_out = dense(cfg.d_model, "o", axis=(-2, -1))(attn_out)
-        x = x + attn_out
-
-        y = self._norm("mlp_norm")(x).astype(self.dtype)
-        if kind.sparse:
-            from ray_dynamic_batching_tpu.models.moe import (
-                MoEBlock,
-                routing_rule,
-            )
-
-            y = MoEBlock(
-                d_model=cfg.d_model,
-                mlp_dim=cfg.mlp_dim,
-                num_experts=cfg.num_experts,
-                top_k=cfg.moe_top_k,
-                rule=routing_rule(cfg),
-                first_expert=cfg.moe_first_expert,
-                held_experts=cfg.held_experts,
-                shared_dim=cfg.moe_shared_experts * cfg.mlp_dim,
-                gated=cfg.gated_mlp,
-                dtype=self.dtype,
-                name="moe",
-            )(y)
-        elif cfg.gated_mlp:
-            y = swiglu(dense, y, kind.mlp_dim, cfg.d_model)
-        else:
-            y = nn.gelu(dense(kind.mlp_dim, "mlp_up")(y))
-            y = dense(cfg.d_model, "mlp_down")(y)
-        if index_pool is not None:
-            return x + y, new_cache, index_pool
-        return x + y, new_cache
+        return attn_out, new_cache, index_pool
 
 
 class DecoderModule(nn.Module):
@@ -890,8 +1031,15 @@ class DecoderModule(nn.Module):
             )
             x = x + pos_embed(positions)
 
+        if cfg.hc_mult > 1:
+            # every stream starts as the embedding row
+            x = jnp.broadcast_to(
+                x[:, :, None, :], x.shape[:2] + (cfg.hc_mult, cfg.d_model))
+
         cache_kv = None
-        if cache is not None:
+        if getattr(cache, "latent", None) is not None:
+            cache_kv = (cache.latent,)
+        elif cache is not None:
             cache_kv = (
                 (cache.k, cache.v, cache.k_scale, cache.v_scale)
                 if cache.quantized else (cache.k, cache.v)
@@ -928,6 +1076,9 @@ class DecoderModule(nn.Module):
             if index:
                 index_kw["index_pool"] = index[0]
 
+        if cfg.hc_mult > 1:
+            # ... and the streams' sum is what the head reads
+            x = x.astype(jnp.float32).sum(axis=2)
         if cfg.norm == "rms":
             x = RMSNorm(name="final_norm", eps=cfg.rms_eps)(x)
         else:
@@ -945,7 +1096,11 @@ class DecoderModule(nn.Module):
             )(x)
 
         out_cache = None
-        if cache is not None:
+        if cache is not None and len(cache_kv) == 1:
+            out_cache = PagedKVCache(
+                k=None, v=None, page_table=page_table,
+                lengths=cache.lengths, latent=cache_kv[0])
+        elif cache is not None:
             scales = dict(
                 k_scale=cache_kv[2] if len(cache_kv) == 4 else None,
                 v_scale=cache_kv[3] if len(cache_kv) == 4 else None,

@@ -601,7 +601,7 @@ class LLMDeployment:
         return sum(
             math.prod(x.shape) * x.dtype.itemsize
             for x in (pool.k, pool.v, pool.k_scale, pool.v_scale,
-                      pool.index_k, pool.ring_k, pool.ring_v)
+                      pool.index_k, pool.ring_k, pool.ring_v, pool.latent)
             if x is not None
         )
 

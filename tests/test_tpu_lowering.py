@@ -527,6 +527,143 @@ class TestFullLayersChunkAttentionInTheCompiledProgram:
                    if not n.startswith("copy_"))
 
 
+class TestLatentProgramsCompileForV5e:
+    """``xing4-29b-ep8-1chip``'s own kernels and programs at the published
+    widths, for a described v5e: the absorbed decode kernel
+    (``ops/latent_attention.py``: 40 slots, 32 heads against a pool of
+    ``[10, 5760, 128, 640]`` rows, a page copied once into a ring of VMEM
+    slots and contracted twice), the Sinkhorn kernel at a decode step's 40
+    tokens and a chunk group's 1,024 (``models/hyper_connections.py``), and
+    the decode and the widest chunk program of the first three layers (two
+    dense, one of experts): nothing but the in-place row write makes an
+    array of the pool's size, and the operations under the PR's scopes are
+    the ones the two pattern metrics take
+    (``chunk_attention_latent_dev_share_pct.batch``,
+    ``hc_mix_dev_share_pct.batch``: the TPU's trace carries no scope).
+    Nothing runs: ``tools/latent_ab.py`` on the chip says what they cost."""
+
+    @staticmethod
+    def _compile(f, *args, donate=()):
+        from jax.experimental.compilation_cache import compilation_cache
+
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            return jax.jit(f, donate_argnums=donate).lower(*args).compile()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+            compilation_cache.reset_cache()
+
+    def test_the_decode_kernel_and_the_sinkhorn_kernel_compile(
+            self, one_chip):
+        from ray_dynamic_batching_tpu.models import hyper_connections as hc
+        from ray_dynamic_batching_tpu.ops import latent_attention as la
+
+        struct = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dt, sharding=one_chip)
+        B, N, NP, L, P, ps = 40, 32, 144, 10, 5760, 128
+        W = la.row_width(512, 64)
+        assert W == 640
+        self._compile(
+            lambda q, pool, t, n, ly: la._latent_paged_decode_attention(
+                q, pool, t, n, ly, rank=512, scale=0.1, interpret=False),
+            struct((B, N, W), jnp.bfloat16),
+            struct((L, P, ps, W), jnp.bfloat16), struct((B, NP), jnp.int32),
+            struct((B,), jnp.int32), struct((1,), jnp.int32))
+        for tokens in (40, 1024):
+            self._compile(
+                lambda m: hc._hc_sinkhorn(m, iters=20, eps=1e-6,
+                                          interpret=False),
+                struct((16, tokens), jnp.float32))
+
+    def test_the_programs_compile_and_the_patterns_are_the_scopes_operations(
+            self, one_chip, monkeypatch):
+        import json
+        import re
+        from pathlib import Path
+        from types import SimpleNamespace
+
+        from benchmark import trace_reduce
+        from ray_dynamic_batching_tpu.models.causal_lm import CausalLM
+        from ray_dynamic_batching_tpu.models.decoder import DecoderConfig
+
+        root = Path(__file__).resolve().parents[1] / "benchmark"
+        cfg = json.loads((root / "configs"
+                          / "xing4-29b-ep8-1chip.json").read_text())
+        # a metric's pattern, and what an operation it takes may say of
+        # where it came from: the scopes; the latent sublayer's own method
+        # (the walk's mask, the last division); for the streams the products
+        # XLA fuses a mix INTO (the mix is their epilogue), the experts' sum
+        # on its way into the mix and the embedding's copy into the streams
+        pattern = {
+            metric: (re.compile(json.loads((root / "layer_metrics" / (
+                f"{metric}.json")).read_text())["args"]["op"]),
+                re.compile(said))
+            for metric, said in (
+                ("chunk_attention_latent_dev_share_pct.batch",
+                 r"latent_chunk_|\._latent_attention/"),
+                ("hc_mix_dev_share_pct.batch",
+                 r"hc_maps|hc_mix|/(o|mlp_down|shared_down)/dot_general$"
+                 r"|moe_combine/convert_element_type$"
+                 r"|DecoderModule/broadcast_in_dim$"))}
+        llm = cfg["deployment"]["llm"]
+        dc = dict(cfg["program"]["decoder_config"], num_layers=3)
+        m = CausalLM(DecoderConfig(**dc), name="m", dtype=jnp.bfloat16)
+        B, ps = llm["num_slots"], llm["page_size"]
+        W, NP = max(llm["prompt_buckets"]), llm["max_len"] // ps
+        struct = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dt, sharding=one_chip)
+        cache = jax.tree_util.tree_map(
+            lambda x: struct(x.shape, x.dtype),
+            jax.eval_shape(lambda: m.make_paged_cache(
+                B, llm["kv_pool_pages"], ps, llm["max_len"])))
+        p = jax.tree_util.tree_map(
+            lambda x: struct(x.shape, jnp.bfloat16),
+            jax.eval_shape(m.init, jax.random.PRNGKey(0)))
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        chunk = self._compile(
+            lambda *a: m.prefill_chunk_paged(*a, moe_counters=True),
+            p, struct((2, W), jnp.int32), struct((2, W), jnp.int32), cache,
+            struct((2, NP), jnp.int32), struct((2,), jnp.int32),
+            struct((2,), jnp.int32), donate=(3,))
+        decode = self._compile(
+            lambda *a: m.decode_step_paged(*a, moe_counters=True),
+            p, struct((B, 1), jnp.int32), cache, struct((B,), jnp.bool_),
+            donate=(2,))
+        pool = rf"bf16\[3,{llm['kv_pool_pages']},{ps},640\]"
+        for compiled in (chunk, decode):
+            text = compiled.as_text()
+            made = re.findall(rf"= {pool}\{{[^}}]*\}} ([\w\-]+)\(", text)
+            assert set(made) <= {"parameter", "scatter", "fusion",
+                                 "bitcast", "get-tuple-element"}, made
+            assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
+            # every top-level operation a pattern takes says it came from
+            # that pattern's scopes, or says nothing (a bare copy)
+            comp = None
+            for line in text.splitlines():
+                opened = re.match(r"^(ENTRY )?%?([\w\.\-]+) \(.*\{\s*$",
+                                  line)
+                if opened:
+                    comp = opened.group(2)
+                    continue
+                if (comp is None or not line.startswith("  ")
+                        or comp.startswith(("fused_computation", "region"))):
+                    continue
+                line = line.strip().removeprefix("ROOT ")
+                if " parameter(" in line:    # an argument: no operation
+                    continue
+                name = trace_reduce.stable_name(SimpleNamespace(name=line))
+                said = re.search(r'op_name="([^"]*)"', line)
+                for metric, (rx, may_say) in pattern.items():
+                    if (rx.search(name) and said
+                            and ("chunk" not in metric or compiled is chunk)):
+                        assert may_say.search(said.group(1)), (
+                            metric, name, said.group(1))
+        assert "_latent_paged_decode_attention" in decode.as_text()
+        assert "_hc_sinkhorn" in decode.as_text()
+        assert "_hc_sinkhorn" in chunk.as_text()
+
+
 class TestSelectionsOperationsInTheCompiledPrograms:
     """``sparse_select_dev_share_pct.batch`` finds the index scan and the
     top-k in a device trace by the HLO lines of the cell's programs (the
