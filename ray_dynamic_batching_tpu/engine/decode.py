@@ -245,6 +245,23 @@ class _IssuedGroup(NamedTuple):
     pending: int
 
 
+class _ChunkFields(NamedTuple):
+    """A chunk group's ONE int32 ``[group, cols]`` buffer cut into its
+    fields, a row a train (``DecodeEngine._cut_chunk_group``: on the host
+    numpy views to fill, in the program slices of the upload). The two
+    float32 fields lie in the buffer as their bits."""
+
+    tokens: Any      # [g, W]
+    mask: Any        # [g, W] 1 on a row's real tokens
+    table: Any       # [g, NP] the row's page-table row
+    ring: Any        # [g, NP] its slot's ring table; None without rings
+    meta_i: Any      # [g, 6] slot-or-sentinel, start, take_idx, top_k,
+    #                  seed, new_len
+    meta_f: Any      # [g, 2] float32: temperature, top_p
+    bias_ids: Any    # [g, E]
+    bias_vals: Any   # [g, E] float32
+
+
 class Turn(NamedTuple):
     """One device dispatch of the engine, as the engine thread saw it: a
     record of ``DecodeEngine.turns``. ``kind`` is ``"turn"`` (a decode or
@@ -1487,42 +1504,91 @@ class DecodeEngine:
         )
         return jnp.where(temps > 0.0, sampled, greedy)
 
-    def _chunk_group_paged_impl(self, params, tokmask, cache, tables,
-                                meta_i, meta_f, bias_ids, bias_vals):
+    def _chunk_group_widths(self, W: int) -> Tuple[int, ...]:
+        """The layout of a chunk group's one int32 buffer ``[g, cols]``, a
+        row = ``tokens[W] | mask[W] | table[NP] | ring table[NP] (0 wide
+        unless the model keeps rings) | meta_i[6] | meta_f[2] |
+        bias_ids[E] | bias_vals[E]``: every width a shape the engine
+        holds."""
+        NP, E = self._n_table_entries, self.max_bias_entries
+        return (W, W, NP, NP if self._ring_pages else 0, 6, 2, E, E)
+
+    def _cut_chunk_group(self, packed) -> _ChunkFields:
+        """The fields of a chunk group's buffer
+        (:meth:`_chunk_group_widths`; ``W`` follows from the buffer's
+        width). The float32 fields are the values whose bits the buffer
+        holds: views of the host's numpy buffer (to fill), a bitcast of
+        the program's upload."""
+        W = (packed.shape[1] - sum(self._chunk_group_widths(0))) // 2
+        cuts, at = [], 0
+        for width in self._chunk_group_widths(W):
+            cuts.append(packed[:, at:at + width])
+            at += width
+        tokens, mask, table, ring, meta_i, meta_f, bias_ids, bias_vals = cuts
+        if isinstance(packed, np.ndarray):
+            meta_f, bias_vals = (
+                a.view(np.float32) for a in (meta_f, bias_vals))
+        else:
+            meta_f, bias_vals = (
+                jax.lax.bitcast_convert_type(a, jnp.float32)
+                for a in (meta_f, bias_vals))
+        return _ChunkFields(tokens, mask, table,
+                            ring if self._ring_pages else None,
+                            meta_i, meta_f, bias_ids, bias_vals)
+
+    def _new_chunk_group(self, group: int,
+                         W: int) -> Tuple[np.ndarray, _ChunkFields]:
+        """A chunk group's host buffer and its fields as views to fill,
+        every row one that writes nothing: zero tokens under a zero mask,
+        an all-sentinel table (every page write drops), the sentinel
+        slot's ring and lengths entry, greedy. Warm-up uploads it as it
+        stands; a served group overwrites its rows."""
+        packed = np.zeros((group, sum(self._chunk_group_widths(W))), np.int32)
+        f = self._cut_chunk_group(packed)
+        f.table[:] = self.num_pages
+        if f.ring is not None:
+            f.ring[:] = ring_table(np.full(group, self.num_slots, np.int32),
+                                   self._ring_pages, self._n_table_entries)
+        f.meta_i[:, 0] = self.num_slots
+        f.meta_f[:, 1] = 1.0
+        return packed, f
+
+    def _chunk_group_paged_impl(self, params, packed, cache):
         """One chunk program for a GROUP of chunk trains, pages-direct
         (ISSUE 15 tentpole): each row is one train's next ``<=W``-token
         chunk, scattered straight through its own page-table row
-        (``tables`` [g, NP] — CoW-borrowed head pages sit below the
+        (``table`` [g, NP] — CoW-borrowed head pages sit below the
         row's ``start`` and are never written; the unallocated tail is
         sentinel-steered and drops, like the spec verify scatter), with
         the staircase read bounded by the row's own start. The cache
         argument is DONATED across chunks — XLA updates the pool in
         place, no row cache, no commit copy.
 
+        The group's per-dispatch state arrives as ONE upload, ``packed``
+        int32 [g, cols], cut here by static offsets
+        (:meth:`_cut_chunk_group`; the float32 fields travel as their
+        bits): a transfer is a host call on the first token's path
+        whatever its size, and under several engines' threads a hand-back
+        of the interpreter lock.
+
         First-token fusion: ``_sample_tokens`` runs in-program on every
         row's take-row logits, so a FINAL chunk's admission ends at a
         ``[g]`` ids fetch — never a logits round-trip. Final rows also
         scatter their verified prompt length into ``cache.lengths``;
         non-final rows are steered to the sentinel slot (``mode="drop"``
-        voids both). ``meta_i`` [6, g] packs slot-or-sentinel / start /
-        take_idx / top_k / seed / new_len; ``meta_f`` [2, g] packs
-        temperature / top_p — the admission-group packed-transfer
-        convention."""
-        tokens, attn_mask = tokmask[0], tokmask[1]
+        voids both)."""
+        f = self._cut_chunk_group(packed)
         slots, starts, take_idx, topk, seeds, new_len = (
-            meta_i[0], meta_i[1], meta_i[2], meta_i[3], meta_i[4],
-            meta_i[5],
-        )
-        temps, topp = meta_f[0], meta_f[1]
+            f.meta_i[:, j] for j in range(6))
+        temps, topp = f.meta_f[:, 0], f.meta_f[:, 1]
         params = self._mp(params)
         rings, ring_pools = {}, {}
-        if tables.ndim == 3:
-            # state by layer kind: [2, g, NP], the rows' page-table rows
-            # and their slots' ring tables
-            tables, rings["ring_tables"] = tables[0], tables[1]
+        if f.ring is not None:
+            # state by layer kind: the rows' slots' ring tables
+            rings["ring_tables"] = f.ring
         # An expert model's routing counters ride the ids fetch: [g + 4].
         taken, pools, *moe = self.model.prefill_chunk_paged(
-            params, tokens, attn_mask, cache, tables, starts, take_idx,
+            params, f.tokens, f.mask, cache, f.table, starts, take_idx,
             **self._moe_kw, **rings,
         )
         lengths = cache.lengths.at[slots].set(new_len, mode="drop")
@@ -1536,8 +1602,8 @@ class DecodeEngine:
             index_k=pools.index_k, **ring_pools,
         )
         first = self._sample_tokens(
-            taken, temps, topk, seeds, jnp.zeros_like(slots), bias_ids,
-            bias_vals, topp,
+            taken, temps, topk, seeds, jnp.zeros_like(slots), f.bias_ids,
+            f.bias_vals, topp,
         )
         if moe:
             first = jnp.concatenate([first, moe[0]])
@@ -1791,37 +1857,18 @@ class DecodeEngine:
     def _warmup_impl(self) -> None:
         # The pages-direct chunk program at every (bucket, group) shape
         # the pump can produce, plus the (1, C_max) long-train shape
-        # (covered by group size 1 at the largest bucket). All-sentinel
-        # tables: every page write drops, the lengths scatter steers to
-        # the sentinel slot — the full program compiles without touching
-        # a real page.
+        # (covered by group size 1 at the largest bucket). The packer's
+        # empty group, the one a served group fills (so the warmed shapes
+        # ARE the served ones): every page write drops, the lengths
+        # scatter steers to the sentinel slot — the full program compiles
+        # without touching a real page.
         for b in self.prompt_buckets:
             for g in self._admit_group_sizes():
                 with self._warming("chunk_prefill", f"b={b},g={g}"):
                     first, self._cache = self._chunk_paged_fn(
                         self.params,
-                        jnp.stack([
-                            jnp.zeros((g, b), jnp.int32),
-                            jnp.ones((g, b), jnp.int32),
-                        ]),
+                        jnp.asarray(self._new_chunk_group(g, b)[0]),
                         self._cache,
-                        jnp.full((g, self._n_table_entries),
-                                 self.num_pages, jnp.int32),
-                        jnp.stack([
-                            jnp.full((g,), self.num_slots, jnp.int32),
-                            jnp.zeros((g,), jnp.int32),
-                            jnp.zeros((g,), jnp.int32),
-                            jnp.zeros((g,), jnp.int32),
-                            jnp.zeros((g,), jnp.int32),
-                            jnp.zeros((g,), jnp.int32),
-                        ]),
-                        jnp.stack([
-                            jnp.zeros((g,), jnp.float32),
-                            jnp.ones((g,), jnp.float32),
-                        ]),
-                        jnp.zeros((g, self.max_bias_entries), jnp.int32),
-                        jnp.zeros((g, self.max_bias_entries),
-                                  jnp.float32),
                     )
                     first.block_until_ready()
         self._warmup_decode()
@@ -2350,79 +2397,54 @@ class DecodeEngine:
         same-width trains: chunk k/v scatter through per-row page-table
         rows, first token sampled in-program for final rows. Pad rows
         duplicate row 0 (identical data to identical pages — idempotent,
-        the group-admission convention). Prepared and dispatched, nothing
-        fetched: the trains stand at their next position, and a train at
-        its prompt's end stays in ``_trains`` until
-        :meth:`_complete_chunk_group`."""
+        the group-admission convention). The group's state is filled
+        into ONE host buffer (:meth:`_new_chunk_group`, the packer the
+        warm-up shares) and reaches the program as ONE upload. Prepared
+        and dispatched, nothing fetched: the trains stand at their next
+        position, and a train at its prompt's end stays in ``_trains``
+        until :meth:`_complete_chunk_group`."""
         W = trains[0].C
         n = len(trains)
         active = int(self._active_mask.sum())
         pending = len(self._trains)
         with self._phase("rdb.engine.prefill.prepare"):
             group = next(s for s in self._admit_group_sizes() if s >= n)
-            tokens = np.zeros((group, W), np.int32)
-            mask = np.zeros((group, W), np.int32)
-            tables = np.full((group, self._n_table_entries),
-                             self.num_pages, np.int32)
-            meta_i = np.zeros((6, group), np.int32)
-            meta_f = np.zeros((2, group), np.float32)
-            bias_ids = np.zeros((group, self.max_bias_entries), np.int32)
-            bias_vals = np.zeros((group, self.max_bias_entries),
-                                 np.float32)
+            packed, f = self._new_chunk_group(group, W)
             finals: List[Tuple[int, _ChunkTrain]] = []
             for i, t in enumerate(trains):
                 piece = t.prompt[t.pos : t.pos + W]
                 take = int(piece.size)
                 final = t.pos + take >= t.total
-                tokens[i, :take] = piece
-                mask[i, :take] = 1
-                tables[i] = table_array(
+                f.tokens[i, :take] = piece
+                f.mask[i, :take] = 1
+                f.table[i] = table_array(
                     t.opts["_pages"], self._n_table_entries, self.num_pages
                 )
                 # Non-final rows steer the lengths scatter to the
                 # sentinel slot: only the FINAL chunk publishes the
                 # verified length.
-                meta_i[0, i] = t.slot_idx if final else self.num_slots
-                meta_i[1, i] = t.pos
-                meta_i[2, i] = take - 1
-                meta_i[3, i] = t.opts["top_k"]
-                meta_i[4, i] = t.opts["seed"]
-                meta_i[5, i] = t.total
-                meta_f[0, i] = t.opts["temperature"]
-                meta_f[1, i] = t.opts.get("top_p", 1.0)
-                bias_ids[i], bias_vals[i] = self._bias_arrays(t.opts)
+                f.meta_i[i] = (
+                    t.slot_idx if final else self.num_slots, t.pos,
+                    take - 1, t.opts["top_k"], t.opts["seed"], t.total)
+                f.meta_f[i] = (t.opts["temperature"],
+                               t.opts.get("top_p", 1.0))
+                f.bias_ids[i], f.bias_vals[i] = self._bias_arrays(t.opts)
                 if final:
                     finals.append((i, t))
-            for i in range(n, group):
-                # A filler row repeats row 0's writes; its mask stays 0
-                # (the program reads the mask only to count an expert
-                # model's REAL routed tokens).
-                tokens[i] = tokens[0]
-                tables[i] = tables[0]
-                meta_i[:, i] = meta_i[:, 0]
-                meta_f[:, i] = meta_f[:, 0]
-                bias_ids[i] = bias_ids[0]
-                bias_vals[i] = bias_vals[0]
             if self._ring_pages:
-                # ... and each row's slot's ring (a filler row row 0's)
-                slots = np.full(group, trains[0].slot_idx, np.int32)
-                slots[:n] = [t.slot_idx for t in trains]
-                tables = np.stack([tables, ring_table(
-                    slots, self._ring_pages, self._n_table_entries)])
-            tokmask = np.stack([tokens, mask])
+                f.ring[:n] = ring_table(
+                    np.asarray([t.slot_idx for t in trains], np.int32),
+                    self._ring_pages, self._n_table_entries)
+            # A filler row repeats row 0's writes; its mask stays 0 (the
+            # program reads the mask only to count an expert model's REAL
+            # routed tokens).
+            packed[n:] = packed[0]
+            f.mask[n:] = 0
         seq, behind = self._note_issue()
         t_dispatch = now_ms()
         with self._phase("rdb.engine.prefill.dispatch"):
             first, self._cache = self._chunk_paged_fn(
-                self.params,
-                jnp.asarray(tokmask),
-                self._cache,
-                jnp.asarray(tables),
-                jnp.asarray(meta_i),
-                jnp.asarray(meta_f),
-                jnp.asarray(bias_ids),
-                jnp.asarray(bias_vals),
-            )
+                self.params, jnp.asarray(packed), self._cache)
         t_issued = now_ms()
         for t in trains:
             t.pos = min(t.pos + W, t.total)

@@ -184,34 +184,20 @@ class DecodeProfiler:
         num_slots = max(2, group)
         engine = self._engine(num_slots, max_len, prompt_bucket, group)
         try:
-            tokmask = jnp.stack([
-                jnp.ones((group, prompt_bucket), jnp.int32),
-                jnp.ones((group, prompt_bucket), jnp.int32),
-            ])
+            packed, f = engine._new_chunk_group(group, prompt_bucket)
+            f.tokens[:] = 1
+            f.mask[:] = 1
             NP = engine._n_table_entries
-            tables = jnp.arange(group * NP, dtype=jnp.int32).reshape(
+            f.table[:] = np.arange(group * NP, dtype=np.int32).reshape(
                 group, NP)
             # slot / start / take_idx / top_k / seed / new_len
-            meta_i = jnp.stack([
-                jnp.arange(group, dtype=jnp.int32) % num_slots,
-                jnp.zeros((group,), jnp.int32),
-                jnp.full((group,), prompt_bucket - 1, jnp.int32),
-                jnp.zeros((group,), jnp.int32),
-                jnp.zeros((group,), jnp.int32),
-                jnp.full((group,), prompt_bucket, jnp.int32),
-            ])
-            meta_f = jnp.stack([
-                jnp.zeros((group,), jnp.float32),
-                jnp.ones((group,), jnp.float32),
-            ])
-            bias_ids = jnp.zeros((group, engine.max_bias_entries), jnp.int32)
-            bias_vals = jnp.zeros(
-                (group, engine.max_bias_entries), jnp.float32
-            )
+            f.meta_i[:, 0] = np.arange(group) % num_slots
+            f.meta_i[:, 2] = prompt_bucket - 1
+            f.meta_i[:, 5] = prompt_bucket
+            packed = jnp.asarray(packed)
             fn = jax.jit(engine._chunk_group_paged_impl,
                          donate_argnums=(2,))
-            args = (engine.params, tokmask, engine._cache, tables, meta_i,
-                    meta_f, bias_ids, bias_vals)
+            args = (engine.params, packed, engine._cache)
             t0 = time.perf_counter()
             compiled = fn.lower(*args).compile()
             compile_ms = (time.perf_counter() - t0) * 1000.0
@@ -219,17 +205,13 @@ class DecodeProfiler:
 
             cache = engine._cache
             for _ in range(self.warmup_iters):
-                first, cache = compiled(engine.params, tokmask, cache,
-                                        tables, meta_i, meta_f, bias_ids,
-                                        bias_vals)
+                first, cache = compiled(engine.params, packed, cache)
             float(np.asarray(first)[0])
             samples = []
             for _ in range(3):
                 t0 = time.perf_counter()
                 for _ in range(self.timing_iters):
-                    first, cache = compiled(engine.params, tokmask, cache,
-                                            tables, meta_i, meta_f,
-                                            bias_ids, bias_vals)
+                    first, cache = compiled(engine.params, packed, cache)
                 float(np.asarray(first)[0])
                 samples.append(
                     (time.perf_counter() - t0) * 1000.0 / self.timing_iters

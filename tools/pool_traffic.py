@@ -170,9 +170,7 @@ def main() -> int:
         for h in sorted({1, engine.ttft_horizon, engine.decode_horizon})
     ] + [
         (f"chunk_prefill W={b} g={g}", lambda b=b, g=g: chunk.lower(
-            p_d, sds((2, g, b), i32), c_d,
-            sds((g, engine._n_table_entries), i32), sds((6, g), i32),
-            sds((2, g), f32), sds((g, K), i32), sds((g, K), f32)))
+            p_d, sds(engine._new_chunk_group(g, b)[0].shape, i32), c_d))
         for b in engine.prompt_buckets for g in engine._admit_group_sizes()
     ]
     bad = 0
