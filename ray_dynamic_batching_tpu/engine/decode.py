@@ -106,8 +106,9 @@ from ray_dynamic_batching_tpu.engine.pagefabric import (
 from ray_dynamic_batching_tpu.engine.queue import RequestQueue
 from ray_dynamic_batching_tpu.models.causal_lm import merge_routing_counters
 from ray_dynamic_batching_tpu.models.decoder import (
-    fit_head_dim,
+    from_pool_rows,
     ring_table,
+    to_pool_rows,
 )
 from ray_dynamic_batching_tpu.ops import jit_model, tile_math
 from ray_dynamic_batching_tpu.ops.tile_math import (
@@ -956,11 +957,16 @@ class DecodeEngine:
         self._select_layers = (
             sum(1 for i in range(cfg.num_layers) if cfg.layer_kind(i).select)
             if self._index_topk else 0)
-        # The head's true width, as the model's row caches have it
-        # (the pool's rows are lane-padded: pool_head_dim).
-        self._kv_head_dim = model.cfg.head_dim if by_kind or latent else (
-            jax.eval_shape(lambda: model.make_cache(
-                1, self.page_size)).k.shape[-1])
+        # A position's true [K, head_dim] block, as the model's row caches
+        # have it and a parcel or the spill carries it (the pool's rows
+        # are lane-padded or hold several heads: pool_heads_per_row).
+        if latent:
+            self._kv_block: Tuple[int, int] = (0, model.cfg.head_dim)
+        elif by_kind:
+            self._kv_block = (self._cache.k.shape[3], model.cfg.head_dim)
+        else:
+            self._kv_block = tuple(jax.eval_shape(
+                lambda: model.make_cache(1, self.page_size)).k.shape[-2:])
         # How the pool lies on the device, once, for snapshot():
         # the order of its axes (row-major is what the paged kernel
         # and the page write read; the pool's lane-padded rows make
@@ -977,6 +983,11 @@ class DecodeEngine:
             "resident_bytes": sum(
                 x.on_device_size_in_bytes() for x in planes),
         }
+        if not latent:
+            # KV heads side by side in a pool row, off the pool's shape.
+            self._pool_stats.update(
+                pool_shape=list(self._cache.k.shape),
+                heads_per_row=self._kv_block[0] // self._cache.k.shape[3])
         if by_kind:
             kinds = {"full": (self._cache.k, self._cache.v),
                      "ring": (self._cache.ring_k, self._cache.ring_v)}
@@ -2538,12 +2549,13 @@ class DecodeEngine:
         are pinned (prefix-cache refs) and never rewritten after
         publication (CoW invariant), so this read races nothing."""
         idx = np.asarray(page_ids, np.int32)
-        # A parcel carries the head, not the pool's lane padding (cut
-        # AFTER the gather: a gather of part of a row makes XLA re-lay
-        # the whole pool out for it).
-        H = self._kv_head_dim
-        out = {"k": np.asarray(self._cache.k[:, idx])[..., :H],
-               "v": np.asarray(self._cache.v[:, idx])[..., :H]}
+        # A parcel carries [.., K, head_dim] whatever the pool's rows look
+        # like (cut or reshaped AFTER the gather: a gather of part of a
+        # row makes XLA re-lay the whole pool out for it).
+        out = {"k": from_pool_rows(np.asarray(self._cache.k[:, idx]),
+                                   *self._kv_block),
+               "v": from_pool_rows(np.asarray(self._cache.v[:, idx]),
+                                   *self._kv_block)}
         if self._cache.quantized:
             out["k_scale"] = np.asarray(self._cache.k_scale[:, idx])
             out["v_scale"] = np.asarray(self._cache.v_scale[:, idx])
@@ -2560,12 +2572,13 @@ class DecodeEngine:
         writer (this engine thread), like the page-table upload."""
         with self._device_ctx():
             idx = jnp.asarray(np.asarray(page_ids, np.int32))
-            Hp = self._cache.k.shape[-1]
             repl = {
-                "k": self._cache.k.at[:, idx].set(fit_head_dim(
-                    jnp.asarray(payload["k"], self._cache.k.dtype), Hp)),
-                "v": self._cache.v.at[:, idx].set(fit_head_dim(
-                    jnp.asarray(payload["v"], self._cache.v.dtype), Hp)),
+                "k": self._cache.k.at[:, idx].set(to_pool_rows(
+                    jnp.asarray(payload["k"], self._cache.k.dtype),
+                    self._cache.k)),
+                "v": self._cache.v.at[:, idx].set(to_pool_rows(
+                    jnp.asarray(payload["v"], self._cache.v.dtype),
+                    self._cache.v)),
             }
             if self._cache.quantized:
                 repl["k_scale"] = self._cache.k_scale.at[:, idx].set(

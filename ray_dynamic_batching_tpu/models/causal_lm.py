@@ -375,17 +375,19 @@ class CausalLM(ServableModel):
 
     def make_paged_cache(
         self, batch_size: int, num_pages: int, page_size: int,
-        max_len: int, widest_chunk: Optional[int] = None,
+        max_len: int, widest_chunk: Optional[int] = None, tp: int = 1,
     ) -> PagedKVCache:
         """A paged KV pool: ``num_pages`` fixed HBM pages + a
         ``[batch_size, max_len // page_size]`` page table (engine-owned
         allocation — ``engine/paging.py``). ``widest_chunk``: the most
         rows one program writes to a slot at once, which sizes the
-        sliding layers' ring where state is by layer kind."""
+        sliding layers' ring where state is by layer kind. ``tp``: the
+        width of the mesh the head axis will be split over (the rows'
+        layout asks: ``models/decoder.py::pool_heads_per_row``)."""
         return PagedKVCache.zeros(
             self.cfg, batch_size, num_pages, page_size, max_len,
             dtype=self.kv_dtype or self.dtype, index_dtype=self.dtype,
-            widest_chunk=widest_chunk,
+            widest_chunk=widest_chunk, tp=tp,
         )
 
     def decode_step_paged(
@@ -507,8 +509,10 @@ class CausalLM(ServableModel):
     def paged_cache_pspec(self) -> PagedKVCache:
         """PartitionSpecs for the PAGED KV pool (ROADMAP item 2): pages
         shard on the kv-head dim exactly like the slab cache — the pool
-        is ``[L, P, ps, K, H]``, so K sits at the same index 3 and a
-        shard owns the full page set for its head slice. The page table
+        is ``[L, P, ps, K // f, Hp]``, so the heads sit at the same index
+        3 (``f`` side by side in a row only where the rows still divide
+        over the mesh: ``pool_heads_per_row``) and a shard owns the full
+        page set for its head slice. The page table
         and lengths REPLICATE: page indices are shard-invariant (every
         shard's slice of page ``p`` backs the same logical positions),
         which is what lets the host-side ``PageAllocator`` stay

@@ -327,8 +327,14 @@ class KVCache:
 
 @pytree_dataclass
 class PagedKVCache:
-    """Paged KV pool: k/v ``[L, P, page_size, K, Hp]`` fixed HBM pages
-    (``Hp`` = :func:`pool_head_dim`: the head, lane-padded),
+    """Paged KV pool: k/v ``[L, P, page_size, K // f, Hp]`` fixed HBM
+    pages: ``f`` = :func:`pool_heads_per_row` heads side by side in a
+    row where a head is narrower than the 128 lanes and the heads pair off
+    (16 x 64: ``[.., 8, 128]``, row ``r`` of a position holds heads
+    ``r * f .. r * f + f - 1``; :func:`to_pool_rows` /
+    :func:`from_pool_rows` are the two ways across), else ``f`` = 1 and
+    ``Hp`` = :func:`pool_head_dim`: the head, lane-padded. Every reader
+    takes ``f`` off the shape (``num_kv_heads // k.shape[3]``);
     gathered per slot through ``page_table`` ``[B, NP]`` int32 (entry j
     names the physical page backing logical positions
     ``[j*page_size, (j+1)*page_size)`` of that slot; unallocated entries
@@ -389,10 +395,13 @@ class PagedKVCache:
         dtype: jnp.dtype = jnp.bfloat16,
         index_dtype: jnp.dtype = jnp.bfloat16,
         widest_chunk: Optional[int] = None,
+        tp: int = 1,
     ) -> "PagedKVCache":
         """``widest_chunk`` (state by layer kind only): the most rows one
         program writes to a slot at once, which with the window sets the
-        pages of a slot's ring (:attr:`ring_pages`)."""
+        pages of a slot's ring (:attr:`ring_pages`). ``tp``: the width of
+        the mesh the pool's head axis is split over
+        (:func:`pool_heads_per_row` asks)."""
         if max_len % page_size != 0:
             raise ValueError(
                 f"max_len {max_len} must be a multiple of page_size "
@@ -449,8 +458,10 @@ class PagedKVCache:
                 ring_k=rows(slide, ring, k_w, cfg.head_dim),
                 ring_v=rows(slide, ring, k_w, cfg.v_head_dim),
             )
+        f = pool_heads_per_row(cfg.head_dim, cfg.num_kv_heads, dtype, tp,
+                               indexed=bool(cfg.index_topk))
         shape = (cfg.num_layers, num_pages, page_size,
-                 cfg.num_kv_heads, pool_head_dim(cfg.head_dim))
+                 cfg.num_kv_heads // f, pool_head_dim(cfg.head_dim * f))
         return PagedKVCache(
             k=jnp.zeros(shape, dtype=dtype),
             v=jnp.zeros(shape, dtype=dtype),
@@ -520,6 +531,50 @@ def pool_head_dim(head_dim: int) -> int:
     cache forgets it (PERF.md, PR 25). A head that fills the lanes (128,
     256) is not padded."""
     return -(-head_dim // 128) * 128
+
+
+def pool_heads_per_row(head_dim: int, kv_heads: int, dtype: Any,
+                       tp: int = 1, indexed: bool = False) -> int:
+    """``f``, the KV heads that lie side by side in ONE 128-lane row of
+    the paged pool: the rule, owned here; every reader takes ``f`` off
+    the pool's shape (``kv_heads // pool.shape[3]``). Where a head is
+    narrower than the lanes and divides them, ``f = 128 // head_dim``
+    whole heads fill a row instead of one head and zeros: a position's
+    ``[K, head_dim]`` block read as ``[K // f, 128]``, the same bytes in
+    the same order, so a gpt2-medium pool (16 x 64) is ``[.., 8, 128]``,
+    half the padded bytes, and the paged kernel walks a page once, in the
+    8 x 128 geometry of a 128-wide-head model. 1 (a head a row, lane-padded:
+    :func:`pool_head_dim`) where the heads do not pair off (``kv_heads %
+    f``), for an int8 pool (a scale plane holds one value a (position,
+    head): two heads in a row want two), under a TP mesh that ``kv_heads
+    // f`` rows do not divide over, and for a selecting model (its sparse
+    kernel reads a head a row)."""
+    if head_dim <= 0 or 128 % head_dim:
+        return 1
+    f = 128 // head_dim
+    if (kv_heads % f or indexed or (kv_heads // f) % max(1, tp)
+            or jnp.dtype(dtype) == jnp.dtype(jnp.int8)):
+        return 1
+    return f
+
+
+def to_pool_rows(x: jax.Array, pool: jax.Array) -> jax.Array:
+    """x [..., K, H] -> [..., K_pool, Hp], the rows of ``pool``
+    ``[L, P, ps, K_pool, Hp]``: ``f`` heads a row (a reshape: the same
+    bytes) where the pool packs them (:func:`pool_heads_per_row`), else
+    a head a row, lane-padded."""
+    if pool.shape[-2] != x.shape[-2]:
+        return x.reshape(x.shape[:-2] + pool.shape[-2:])
+    return fit_head_dim(x, pool.shape[-1])
+
+
+def from_pool_rows(rows, kv_heads: int, head_dim: int):
+    """:func:`to_pool_rows` back: rows [..., K_pool, Hp] (a jax or a numpy
+    array) -> [..., kv_heads, head_dim], the form a slab view, a parcel
+    and the spill hold whatever the pool's rows look like."""
+    if rows.shape[-2] != kv_heads:
+        return rows.reshape(rows.shape[:-2] + (kv_heads, head_dim))
+    return rows[..., :head_dim]
 
 
 def fit_head_dim(x: jax.Array, width: int) -> jax.Array:
@@ -825,9 +880,10 @@ class DecoderLayer(nn.Module):
                     )
                 P = k_full.shape[1]
                 ps = k_full.shape[2]
-                # Pool rows are lane-padded (pool_head_dim).
-                k_w = fit_head_dim(k_w, k_full.shape[-1])
-                v_w = fit_head_dim(v_w, v_full.shape[-1])
+                # Pool rows are lane-padded (pool_head_dim), or hold
+                # several heads side by side (pool_heads_per_row).
+                k_w = to_pool_rows(k_w, k_full)
+                v_w = to_pool_rows(v_w, v_full)
                 n_entries = page_table.shape[1]
                 idx = positions  # [B, T]
                 rows = jnp.arange(B)[:, None]
@@ -941,6 +997,9 @@ class DecoderLayer(nn.Module):
                     scale_kwargs["select"] = select
                 if odd:
                     scale_kwargs.update(sink=sink, v_dim=cfg.v_head_dim)
+                if k_full.shape[3] != kv_heads:
+                    scale_kwargs["heads_per_row"] = (
+                        kv_heads // k_full.shape[3])
             else:
                 kv = (k_full[li], v_full[li])
             attn_out = attn_ops.dot_product_attention(

@@ -64,6 +64,7 @@ class AttentionPath:
     sliding: int = 0          # a sliding layer's window on a paged read
     v_dim: int = 0            # a value row's width where not the key's
     sink: bool = False        # a learned sink in the softmax
+    heads_per_row: int = 1    # KV heads side by side in a pool row
 
     def describe(self) -> str:
         name = {
@@ -77,7 +78,9 @@ class AttentionPath:
             [f"shard_map tp={self.tp}"] if self.tp > 1 else []) + (
             [f"window {self.sliding}"] if self.sliding else []) + (
             [f"v rows {self.v_dim}"] if self.v_dim else []) + (
-            ["sink"] if self.sink else [])
+            ["sink"] if self.sink else []) + (
+            [f"{self.heads_per_row} heads a row"]
+            if self.heads_per_row > 1 else [])
         if how:
             name += f" ({', '.join(how)})"
         return ("gather-then-" if self.gathered else "") + name
@@ -100,7 +103,8 @@ def clear_attention_paths() -> None:
 
 def _record(path: str, q, k, declines: List[str], *, gathered: bool = False,
             stacked: bool = False, tp: int = 1, sliding: int = 0,
-            v_dim: int = 0, sink: bool = False) -> None:
+            v_dim: int = 0, sink: bool = False,
+            heads_per_row: int = 1) -> None:
     kernel = path in (PATH_PAGED_KERNEL, PATH_SLAB_KERNEL, PATH_FLASH)
     _PATHS.append(AttentionPath(
         program=current_program(), path=path, gathered=gathered,
@@ -108,7 +112,7 @@ def _record(path: str, q, k, declines: List[str], *, gathered: bool = False,
         interpret=kernel and resolve_interpret(None),
         q_shape=tuple(q.shape), kv_shape=tuple(k.shape),
         kv_dtype=str(k.dtype), declines=tuple(declines), sliding=sliding,
-        v_dim=v_dim, sink=sink,
+        v_dim=v_dim, sink=sink, heads_per_row=heads_per_row,
     ))
 
 
@@ -242,6 +246,7 @@ def dot_product_attention(
     select: Optional[tuple] = None,
     sink: Optional[jax.Array] = None,
     v_dim: int = 0,
+    heads_per_row: int = 1,
 ) -> jax.Array:
     """Multi-head attention.
 
@@ -275,14 +280,21 @@ def dot_product_attention(
     of a model with state by layer kind: its v rows may be narrower than
     its k rows and its softmax may carry a learned ``sink`` ([N] float32,
     or None): the paged kernel takes both, and ``ops/kind_attention.py``
-    every other read.
+    every other read. ``heads_per_row`` > 1 (paged reads only): the pools
+    are ``[L, P, ps, K // f, f * H]``, ``f`` KV heads side by side in a
+    row (``models/decoder.py::pool_heads_per_row``): the paged kernel
+    reads them as they lie, the gather reshapes its pages' rows back to
+    ``[.., K, H]``.
     """
     if page_table is not None:
         return _paged_attention(
             q, k, v, page_table, kv_lengths, layer, mask=mask,
             scale=scale, k_scale=k_scale, v_scale=v_scale, sliding=sliding,
             select=select, sink=sink, v_dim=v_dim,
+            heads_per_row=heads_per_row,
         )
+    if heads_per_row != 1:
+        raise ValueError("heads_per_row is the paged read's")
     if v_dim or sink is not None:
         raise ValueError("sink and v_dim are the paged read's")
     if select is not None:
@@ -451,6 +463,7 @@ def _paged_attention(
     select: Optional[tuple] = None,
     sink: Optional[jax.Array] = None,
     v_dim: int = 0,
+    heads_per_row: int = 1,
 ) -> jax.Array:
     """Paged decode read: fused page-table KV scan on the Pallas path,
     explicit gather back to the slab view otherwise (the token-exact
@@ -475,6 +488,13 @@ def _paged_attention(
     kind = {"sink": sink, "v_dim": v_dim} if v_dim else {}
     kind_record = ({"v_dim": int(v.shape[-1]), "sink": sink is not None}
                    if v_dim else {})
+    if heads_per_row > 1:
+        if kind or select is not None or k_scale is not None:
+            raise ValueError(
+                "a pool with several heads a row has no sink, no narrower "
+                "v row, no indexer and no scale planes "
+                "(models/decoder.py::pool_heads_per_row)")
+        kind = kind_record = {"heads_per_row": heads_per_row}
     if select is not None:
         from ray_dynamic_batching_tpu.ops import sparse_attention
 
@@ -503,7 +523,7 @@ def _paged_attention(
             _record(PATH_PAGED_KERNEL, q, k, declines, stacked=stacked,
                     tp=tp, sliding=sliding, **kind_record)
             return out
-    if kind:
+    if v_dim:
         # A sink, a narrower value row: no gather-then-kernel form takes
         # them. The table is walked in blocks of pages in plain XLA (a
         # chunk's rows on the chip; every read where Pallas is off).
@@ -562,10 +582,14 @@ def _paged_attention(
     # head's lanes only (pool[layer, safe, :, :, :H]) reads half the
     # bytes on paper, but XLA then re-lays the whole pool out for that
     # gather: four pool-sized copies in the chunk program
-    # (tools/pool_traffic.py).
+    # (tools/pool_traffic.py). Rows that hold several heads side by side
+    # hold no padding: the gathered pages are the slab view's own bytes.
+    from ray_dynamic_batching_tpu.models.decoder import from_pool_rows
+
     H = q.shape[-1]
-    k_g = logical(k[layer, safe])[..., :H]
-    v_g = logical(v[layer, safe])[..., :H]
+    K = k.shape[3] * heads_per_row
+    k_g = from_pool_rows(logical(k[layer, safe]), K, H)
+    v_g = from_pool_rows(logical(v[layer, safe]), K, H)
     ks_g = vs_g = None
     if k_scale is not None:
         ks_g, vs_g = logical(k_scale[safe]), logical(v_scale[safe])

@@ -148,9 +148,12 @@ class DecodePath:
     kv_heads: int = 0     # the pool's KV heads (a layer kind's own)
     v_dim: int = 0        # a v row's width where not the k row's
     sink: bool = False    # a learned sink joins the sum at the scan's end
+    heads_per_row: int = 1  # KV heads side by side in a pool row
 
     def describe(self) -> str:
-        return (f"{self.kb} heads x {self.rows} rows over "
+        return ((f"{self.heads_per_row} heads a pool row: "
+                 if self.heads_per_row > 1 else "")
+                + f"{self.kb} heads x {self.rows} rows over "
                 f"[{self.page_size}, {self.kb}, {self.head_dim}] "
                 f"{self.kv_dtype} pages -> {self.form} ({self.why}); "
                 f"{self.walk}, a ring of {self.depth}, of "
@@ -858,6 +861,7 @@ def paged_decode_attention(
     sliding: int = 0,
     sink: Optional[jax.Array] = None,
     v_dim: int = 0,
+    heads_per_row: int = 1,
 ) -> Optional[jax.Array]:
     """Fused page-table decode attention; returns None when the shapes
     aren't the paged decode pattern (caller falls back to the explicit
@@ -916,6 +920,16 @@ def paged_decode_attention(
     is ``v_dim`` wide. ``sink`` [N] float32 is a learned sink a query
     head: ``exp(sink - m)`` joins the softmax's sum when a slot's scan
     ends and adds nothing to the accumulator.
+
+    ``heads_per_row`` = ``f`` > 1: the pools are ``[L, P, ps, K // f,
+    f * H]``, row ``r`` of a position holding KV heads ``r * f .. r * f +
+    f - 1`` side by side (``models/decoder.py::pool_heads_per_row``). To
+    the kernel that is a GQA pool of ``K // f`` heads x ``f * H`` with
+    ``f * G`` query rows a head: query head ``n``'s values go into the
+    lane segment of ITS KV head (``(n // G) % f``) of a row otherwise
+    zeros, which add nothing to a score; ``p . v`` over the whole row
+    gives every segment's values and the row's own segment is taken.
+    The same body, no other arm: 16 x 64 is walked as 8 x 128.
     """
     if k.ndim == 4 and v.ndim == 4:
         k, v = k[None], v[None]  # one layer's pool: a one-layer stack
@@ -928,6 +942,22 @@ def paged_decode_attention(
         return declined(
             why, f"paged kernel: window Tq={Tq} > "
             f"{MAX_WINDOW_FOR_KERNEL} is prefill-shaped")
+    f, seg = heads_per_row, None
+    if f > 1:
+        if (k.shape[-1] != f * H or sink is not None or v_dim
+                or k_scale is not None):
+            return declined(
+                why, f"paged kernel: {f} heads a pool row, and the row is "
+                "not f heads wide, or a sink, a narrower v row or scale "
+                "planes came with it")
+        # [.., n, H] -> [.., n, f * H]: a head's values in the segment of
+        # ITS KV head, zeros in the others (the mask is made of shapes: a
+        # constant the program folds).
+        scale = scale if scale is not None else H ** -0.5
+        seg = jnp.arange(N) // max(1, N // (k.shape[-2] * f)) % f
+        q = jnp.where(seg[:, None, None] == jnp.arange(f)[:, None],
+                      q[:, :, :, None, :], 0).reshape(B, Tq, N, f * H)
+        H = f * H
     L, P, ps, K, Hk = k.shape
     if not 0 <= layer < L:
         raise ValueError(f"layer {layer} is not in a stack of {L}")
@@ -985,6 +1015,8 @@ def paged_decode_attention(
                                  "under a mesh or over an int8 pool")
         kind = dict(kv_heads=K, v_dim=int(v.shape[-1]),
                     sink=sink is not None)
+    if f > 1:
+        kind = dict(heads_per_row=f)
     _record_path(kb, Tq * G, ps, Hk, k.dtype, int(sliding),
                  tile_math.window_table_width(
                      int(sliding), Tq, ps, page_table.shape[1]),
@@ -1020,8 +1052,17 @@ def paged_decode_attention(
         H = v_dim or H
     else:
         out = _paged_decode_attention(*operands, **static)
-    return out[..., :H].reshape(B, K, Tq, G, H).transpose(
+    out = out[..., :H].reshape(B, K, Tq, G, H).transpose(
         0, 2, 1, 3, 4).reshape(B, Tq, N, H)
+    if f == 1:
+        return out
+    # p . v over a whole row gave every segment's values: a head keeps
+    # its own KV head's.
+    rows = out.reshape(B, Tq, N, f, H // f)
+    out = rows[:, :, :, 0]
+    for s in range(1, f):
+        out = jnp.where(seg[:, None] == s, rows[:, :, :, s], out)
+    return out
 
 
 def _paged_decode_attention_tp(

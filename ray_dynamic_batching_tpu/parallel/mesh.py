@@ -181,7 +181,8 @@ def make_sharded_paged_cache(
     return _sharded_alloc(
         mesh,
         lambda: model.make_paged_cache(
-            num_slots, num_pages, page_size, max_len
+            num_slots, num_pages, page_size, max_len,
+            tp=int(mesh.shape.get("tp", 1)),
         ),
         model.paged_cache_pspec(),
     )

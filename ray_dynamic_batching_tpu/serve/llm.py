@@ -578,13 +578,17 @@ class LLMDeployment:
         fitting = [b for b in self.prompt_buckets if b <= max_len]
         return fitting or [max_len]
 
-    def pool_bytes_per_slot(self, model: Any, max_len: int) -> int:
-        """What one slot's full page run occupies in the engine's pool:
+    def pool_bytes_per_slot(self, model: Any, max_len: int,
+                            tp: int = 1) -> int:
+        """What one slot's full page run occupies in the engine's pool,
+        over all ``tp`` chips of its mesh:
         the bytes ``model.make_paged_cache`` allocates for
         ``pages_for(max_len)`` pages — read from the shapes it would make
-        (the lane-padded head ``pool_head_dim``, whole pages, the scale
-        planes of an int8 pool), not from a second formula. A 64-wide
-        head holds twice what ``kv_bytes_per_slot`` counts."""
+        (rows of whole heads side by side or of one lane-padded head,
+        ``pool_heads_per_row``; whole pages; the scale planes of an int8
+        pool), not from a second formula. A 64-wide head that cannot pair
+        off (an int8 pool, an odd head count) holds twice what
+        ``kv_bytes_per_slot`` counts."""
         import math
 
         import jax
@@ -597,7 +601,7 @@ class LLMDeployment:
             b for b in DEFAULT_PROMPT_BUCKETS if b <= max_len]
         pool = jax.eval_shape(lambda: model.make_paged_cache(
             1, n, self.page_size, n * self.page_size,
-            widest_chunk=max(buckets, default=None)))
+            widest_chunk=max(buckets, default=None), tp=tp))
         return sum(
             math.prod(x.shape) * x.dtype.itemsize
             for x in (pool.k, pool.v, pool.k_scale, pool.v_scale,
@@ -644,7 +648,8 @@ class LLMDeployment:
         weights_bytes = tree_bytes(params) / max(1, n_chips)
         budget = float(cfg.hbm_budget_bytes)
         per_slot = float(
-            self.pool_bytes_per_slot(model, max_len or self.max_len)
+            self.pool_bytes_per_slot(model, max_len or self.max_len,
+                                     tp=max(1, n_chips))
         ) / max(1, n_chips)
         if draft_model is not None:
             # Speculative decoding doubles the residency story: the draft's

@@ -145,16 +145,20 @@ def test_the_benchmarks_configuration_keys_are_accepted(path):
     require_paged(llm["paged"])
 
 
-@pytest.mark.parametrize("head_dim", [64, 128])
-def test_auto_slot_sizing_prices_a_slot_by_the_pool(head_dim, monkeypatch):
+@pytest.mark.parametrize("head_dim, kv_heads, padding", [
+    (64, 2, 1), (64, 1, 2), (128, 2, 1)])
+def test_auto_slot_sizing_prices_a_slot_by_the_pool(head_dim, kv_heads,
+                                                    padding, monkeypatch):
     """The planner's figure for one slot equals what a built engine's
-    pool holds on the device, per slot — a 64-wide head is lane-padded to
-    128 and costs twice what ``kv_bytes_per_slot`` counts; max_len is
-    rounded up to whole pages."""
+    pool holds on the device, per slot — two 64-wide heads lie in one
+    128-lane row and cost what ``kv_bytes_per_slot`` counts; ONE 64-wide
+    head has nothing to pair with, is lane-padded to 128 and costs twice
+    that; max_len is rounded up to whole pages."""
     cfg = DecoderConfig(vocab_size=64, d_model=2 * head_dim, num_layers=2,
-                        num_heads=2, num_kv_heads=2, mlp_dim=64,
+                        num_heads=2, num_kv_heads=kv_heads, mlp_dim=64,
                         max_seq_len=256)
-    model = CausalLM(cfg, name=f"sizing{head_dim}", dtype=jnp.bfloat16)
+    model = CausalLM(cfg, name=f"sizing{head_dim}x{kv_heads}",
+                     dtype=jnp.bfloat16)
     params = model.init(jax.random.PRNGKey(0))
     max_len, slots = 200, 4               # 2 pages a slot, not 200 rows
     dep = LLMDeployment(model.name, model=model, params=params,
@@ -164,7 +168,9 @@ def test_auto_slot_sizing_prices_a_slot_by_the_pool(head_dim, monkeypatch):
                           num_slots=slots, max_len=max_len)
     assert engine.snapshot()["kv_pool"]["resident_bytes"] == slots * priced
     unpadded = model.kv_bytes_per_slot(256)
-    assert priced == unpadded * (128 // head_dim)
+    assert priced == unpadded * padding
+    assert engine.snapshot()["kv_pool"]["heads_per_row"] == (
+        2 if (head_dim, kv_heads) == (64, 2) else 1)
     # And auto sizing divides the budget by that figure.
     from ray_dynamic_batching_tpu.utils.config import RDBConfig, set_config
 

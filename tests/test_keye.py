@@ -605,9 +605,11 @@ def test_summarize_turns_of_another_models_ring_has_no_selection_keys():
 # --- a configuration WITHOUT an indexer is untouched ---------------------------------
 def test_a_model_without_an_indexer_has_no_index_pool_and_its_old_program():
     """gpt2-medium's pool has four leaves as before, and its decode and
-    chunk programs trace to as many equations as the parent's (abd412c:
-    4,188 / 5,016 with inner ones, 4,216 / 5,051; counted there with this
-    function)."""
+    chunk programs trace to as many TOP-LEVEL equations as the parent's
+    (abd412c: 4,188 / 5,016 with inner ones, 4,216 / 5,051; counted there
+    with this function). Since PR 48 its pool holds two heads a row and
+    each layer's paged write is a reshape where it was two pads: 96 inner
+    equations fewer, on purpose (``tests/test_xing.py::PINNED``)."""
     m = CausalLM(GPT2_MEDIUM, name="g", dtype=jnp.bfloat16)
     cache = jax.eval_shape(lambda: m.make_paged_cache(16, 128, 128, 1024))
     assert cache.index_k is None
@@ -622,8 +624,8 @@ def test_a_model_without_an_indexer_has_no_index_pool_and_its_old_program():
     chunk = jax.make_jaxpr(m.prefill_chunk_paged)(
         p, sds((2, 256), jnp.int32), sds((2, 256), jnp.int32), cache,
         sds((2, 8), jnp.int32), sds((2,), jnp.int32), sds((2,), jnp.int32))
-    assert (len(decode.jaxpr.eqns), _equations(decode.jaxpr)) == (4188, 5016)
-    assert (len(chunk.jaxpr.eqns), _equations(chunk.jaxpr)) == (4216, 5051)
+    assert (len(decode.jaxpr.eqns), _equations(decode.jaxpr)) == (4188, 4920)
+    assert (len(chunk.jaxpr.eqns), _equations(chunk.jaxpr)) == (4216, 4955)
 
 
 def _equations(j):

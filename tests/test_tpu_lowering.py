@@ -197,9 +197,11 @@ class TestPagedKernelLowersForTPU:
         from ray_dynamic_batching_tpu.models.decoder import pool_head_dim
 
         ps = 128
+        per_row = g.get("f", 1)     # KV heads side by side in a pool row
         q = struct((g["B"], window, g["N"], g["H"]), jnp.bfloat16)
         pool = struct(
-            (g["L"], g["P"], ps, g["K"], pool_head_dim(g["H"])), dtype)
+            (g["L"], g["P"], ps, g["K"] // per_row,
+             pool_head_dim(g["H"] * per_row)), dtype)
         table = struct((g["B"], g["NP"]), jnp.int32)
         lengths = struct((g["B"],), jnp.int32)
         scale = (struct((g["P"], ps, g["K"]), jnp.float32)
@@ -209,7 +211,7 @@ class TestPagedKernelLowersForTPU:
             out = da.paged_decode_attention(
                 q, pool, pool, table, lengths, layer=g["L"] - 1,
                 k_scale=scale, v_scale=scale, interpret=False,
-                sliding=sliding)
+                sliding=sliding, heads_per_row=per_row)
             assert out is not None, "paged kernel declined"
             return out
 
@@ -284,6 +286,12 @@ class TestPagedKernelCompilesForV5e:
             L=5, P=2048, B=64, NP=32, N=64, K=8, H=128),
         ("gpt2-medium", 5, 200): TestPagedKernelLowersForTPU.GEOMETRIES[
             "gpt2-medium"],
+        # gpt2-medium's pool as the engine lays it out since PR 48: two
+        # 64-wide heads a row, [.., 8, 128], ONE head block, two query
+        # rows a pool head (ten under the spec window)
+        **{("gpt2-medium-packed", *rest): dict(
+            TestPagedKernelLowersForTPU.GEOMETRIES["gpt2-medium"], f=2)
+           for rest in ((1,), (5,), (5, 200))},
     }
 
     @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8])
@@ -297,6 +305,9 @@ class TestPagedKernelCompilesForV5e:
             pytest.skip("an int8 pool of fewer than 4 heads keeps the "
                         "per-head form, whose (4, 128) int8 tile Mosaic "
                         "refuses to slice: no configuration has one")
+        if dtype == jnp.int8 and self.CASES[case].get("f", 1) > 1:
+            pytest.skip("an int8 pool keeps a head a row "
+                        "(models/decoder.py::pool_heads_per_row)")
         struct = lambda shape, dt: jax.ShapeDtypeStruct(
             shape, dt, sharding=one_chip)
         f, args = TestPagedKernelLowersForTPU._call(
@@ -316,6 +327,94 @@ class TestPagedKernelCompilesForV5e:
             case[0].endswith(("-kv-heads", "-kv-head")) and dtype == jnp.int8
         ) else da.FORM_FLAT
         assert da.decode_paths()[-1].form == want
+
+
+class TestPackedPoolProgramsCompileForV5e:
+    """gpt2-medium's decode step and widest chunk program, at its benchmark
+    deployment cut to 3 layers, compiled for the described chip with the
+    pool two heads a row (``[3, 128, 128, 8, 128]``): the pool is read and
+    written in place (no pool-sized ``copy``, every operation that yields a
+    pool a scatter's fusion), the paged write takes the k projection's
+    output as it lies (no ``pad`` in front of the scatter), the kernel is
+    handed 8 x 128 rows, and the ``bf16[16,64]`` copies of ROADMAP S1 (f)
+    are NAMED: each is the asynchronous prefetch of a q, k or v BIAS (a
+    ``[16, 64]`` parameter), there at the parent too: not the pool's."""
+
+    def test_the_pool_is_read_and_written_where_it_lies(
+            self, one_chip, monkeypatch):
+        import dataclasses
+        import json
+        import pathlib
+        import re
+
+        from jax.experimental.compilation_cache import compilation_cache
+
+        from ray_dynamic_batching_tpu.models.causal_lm import CausalLM
+        from ray_dynamic_batching_tpu.models.decoder import DecoderConfig
+
+        root = pathlib.Path(__file__).resolve().parent.parent / "benchmark"
+        cfg = json.loads((root / "configs" / "gpt2-medium.json").read_text())
+        llm = cfg["deployment"]["llm"]
+        dc = dataclasses.replace(
+            DecoderConfig(**cfg["program"]["decoder_config"]), num_layers=3)
+        m = CausalLM(dc, name="m", dtype=jnp.bfloat16)
+        B, ps = llm["num_slots"], llm["page_size"]
+        W, NP = max(llm["prompt_buckets"]), llm["max_len"] // ps
+        struct = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dt, sharding=one_chip)
+        placed = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+            lambda x: struct(x.shape, x.dtype), tree)
+        cache = placed(jax.eval_shape(lambda: m.make_paged_cache(
+            B, llm["kv_pool_pages"], ps, llm["max_len"])))
+        assert cache.k.shape == (3, llm["kv_pool_pages"], ps, 8, 128)
+        p = jax.tree_util.tree_map(
+            lambda x: struct(x.shape, jnp.bfloat16),
+            jax.eval_shape(m.init, jax.random.PRNGKey(0)))
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        da.clear_decode_paths()
+        try:
+            decode = jax.jit(
+                lambda p, t, c, a: m.decode_step_paged(p, t, c, a),
+                donate_argnums=(2,)).lower(
+                p, struct((B, 1), jnp.int32), cache,
+                struct((B,), jnp.bool_)).compile().as_text()
+            chunk = jax.jit(
+                lambda p, t, k, c, tb, s, i: m.prefill_chunk_paged(
+                    p, t, k, c, tb, s, i), donate_argnums=(3,)).lower(
+                p, struct((2, W), jnp.int32), struct((2, W), jnp.int32),
+                cache, struct((2, NP), jnp.int32), struct((2,), jnp.int32),
+                struct((2,), jnp.int32)).compile().as_text()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+            compilation_cache.reset_cache()
+        paths = da.decode_paths()
+        assert paths and all(
+            (q.heads_per_row, q.kb, q.rows, q.head_dim) == (2, 8, 2, 128)
+            for q in paths)
+        pool = rf"bf16\[3,{llm['kv_pool_pages']},{ps},8,128\]"
+        for text in (decode, chunk):
+            lines = text.splitlines()
+            assert not [ln for ln in lines
+                        if re.search(rf"= {pool}\S* copy", ln)]
+            # what yields a pool: a scatter (its fusion), in place
+            made = [ln for ln in lines if re.search(
+                rf"^\s*(ROOT )?%\S+ = {pool}\S* (?!parameter|get-tuple)", ln)]
+            assert made and all(
+                re.search(r" (scatter|fusion)\(", ln) and "scatter" in ln
+                for ln in made), made[:3]
+            # the rows reach the scatter as the projection left them
+            # (the parent's: ``pad_bitcast_fusion``, ``jit(_pad)/pad``)
+            assert not [ln for ln in lines if "_kv_attention" in ln
+                        and re.search(r"= bf16\S* pad\(|jit\(_pad\)", ln)]
+        # S1 (f), named: the [16, 64] copies are the biases' prefetch
+        starts = [ln for ln in decode.splitlines()
+                  if "copy-start(" in ln and "bf16[16,64]" in ln]
+        assert starts and all(
+            re.search(r"copy-start\(%p__params____layer\d+____[qkv]____"
+                      r"bias__", ln) for ln in starts)
+        assert "_paged_decode_attention" in decode
 
 
 class TestSparseKernelCompilesForV5e:

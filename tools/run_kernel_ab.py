@@ -24,8 +24,9 @@ walks adds, a two-parameter fit over the three live shares (an entry
 past the length is never visited: it costs nothing). One jitted program
 chains a call a layer, as a decode substep does, so the launch is paid
 once a program and not once a kernel. Read every kernel PR's costs with
-it (``PERF.md``); the file keeps the parent commit's rows (the grid-walk
-kernel's live and dead grid steps) under ``parent``.
+it (``PERF.md``); the file keeps the parent commit's rows under ``parent``
+(since PR 48: gpt2-medium's lane-padded pool, a head a row, beside the two
+heads a row the tool now builds by ``pool_heads_per_row``).
 
 ``--sparse`` measures a selecting layer's decode read (index scores, top-k
 and attention over the selection) at the configuration that has an indexer,
@@ -160,6 +161,7 @@ def _time_paged(tag, L, P, B, NP, N, K, H, int8, iters, sliding=0):
 
     from ray_dynamic_batching_tpu.models.decoder import (
         pool_head_dim,
+        pool_heads_per_row,
         quantize_kv_rows,
     )
     from ray_dynamic_batching_tpu.ops import attention as attn
@@ -169,7 +171,10 @@ def _time_paged(tag, L, P, B, NP, N, K, H, int8, iters, sliding=0):
     )
 
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
-    shape = (L, P, PAGE, K, pool_head_dim(H))
+    # the pool as the engine would lay it out: gpt2-medium's two 64-wide
+    # heads a row, [.., 8, 128]
+    f = pool_heads_per_row(H, K, jnp.int8 if int8 else jnp.bfloat16)
+    shape = (L, P, PAGE, K // f, pool_head_dim(H * f))
     k = jax.random.normal(keys[0], shape, jnp.bfloat16)
     v = jax.random.normal(keys[1], shape, jnp.bfloat16)
     q = jax.random.normal(keys[2], (B, 1, N, H), jnp.bfloat16)
@@ -191,11 +196,11 @@ def _time_paged(tag, L, P, B, NP, N, K, H, int8, iters, sliding=0):
                     layer=layer,
                     k_scale=None if ks is None else ks[layer],
                     v_scale=None if vs is None else vs[layer],
-                    sliding=sliding)
+                    sliding=sliding, heads_per_row=f)
             return q
         return jax.jit(run)
 
-    blocks = K // _pick_heads_block(K)
+    blocks = (K // f) // _pick_heads_block(K // f)
     cases = []
     for share in LIVE_SHARES:
         table, lengths, live = paged_case(0, B, NP, P, share)
@@ -228,6 +233,7 @@ def _time_paged(tag, L, P, B, NP, N, K, H, int8, iters, sliding=0):
                     lengths, sliding, PAGE)).sum()) / B
             rows.append({
                 "geometry": tag, "live_share": share,
+                "heads_per_row": f,
                 "call_us": statistics.median(samples),
                 "call_us_min_max": [min(samples), max(samples)],
                 "steps": B * blocks,
