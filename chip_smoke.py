@@ -131,9 +131,9 @@ def kernel_cases(N: int, K: int, H: int, *, slots: int, capacity: int,
     import jax.numpy as jnp
     import numpy as np
 
-    from ray_dynamic_batching_tpu.models.decoder import (
+    from ray_dynamic_batching_tpu.models.decoder import paged_window_mask
+    from ray_dynamic_batching_tpu.models.kv_state import (
         dequantize_kv,
-        paged_window_mask,
         quantize_kv_rows,
     )
     from ray_dynamic_batching_tpu.ops import decode_attention as da

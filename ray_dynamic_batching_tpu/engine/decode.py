@@ -105,10 +105,10 @@ from ray_dynamic_batching_tpu.engine.pagefabric import (
 )
 from ray_dynamic_batching_tpu.engine.queue import RequestQueue
 from ray_dynamic_batching_tpu.models.causal_lm import merge_routing_counters
-from ray_dynamic_batching_tpu.models.decoder import (
-    from_pool_rows,
+from ray_dynamic_batching_tpu.models.kv_state import (
+    commit_row,
+    refuse_unsupported,
     ring_table,
-    to_pool_rows,
 )
 from ray_dynamic_batching_tpu.ops import jit_model, tile_math
 from ray_dynamic_batching_tpu.ops.tile_math import (
@@ -613,32 +613,6 @@ PREFILL_PENDING = m.Gauge(
 )
 
 
-def commit_row(cache, row, slot):
-    """Copy a single finished row cache into the shared cache at ``slot``,
-    slicing the (whole-chunk-rounded, possibly longer) row down to shared
-    capacity: the commit of the draft model's prompt replay."""
-    S = cache.capacity
-    k = jax.lax.dynamic_update_slice(
-        cache.k, row.k[:, :, :S], (0, slot, 0, 0, 0)
-    )
-    v = jax.lax.dynamic_update_slice(
-        cache.v, row.v[:, :, :S], (0, slot, 0, 0, 0)
-    )
-    ks, vs = cache.k_scale, cache.v_scale
-    if ks is not None:
-        ks = jax.lax.dynamic_update_slice(
-            ks, row.k_scale[:, :, :S], (0, slot, 0, 0)
-        )
-        vs = jax.lax.dynamic_update_slice(
-            vs, row.v_scale[:, :, :S], (0, slot, 0, 0)
-        )
-    lengths = jax.lax.dynamic_update_slice(
-        cache.lengths, row.lengths, (slot,)
-    )
-    return cache.replace(k=k, v=v, lengths=lengths,
-                         k_scale=ks, v_scale=vs)
-
-
 def run_chunked(chunk_fn, params, prompt, C, row, between=None):
     """Host loop driving a compiled chunk program over a prompt on a row
     cache: full-width chunks, right-padded tail, optional ``between``
@@ -735,63 +709,17 @@ class DecodeEngine:
         self.model = model
         self.device = device
         self.mesh = mesh
-        # State by layer kind: the sliding layers keep a ring of their
-        # window a slot (models/decoder.py::PagedKVCache). What reaches a
-        # slot's KV by PAGE REFERENCE cannot work with it and is refused
-        # here, when the engine is built (ROADMAP D10).
-        by_kind = bool(getattr(getattr(model, "cfg", None), "kv_by_kind",
-                               False))
-        if by_kind:
-            refused = {
-                "prefix_cache_size": prefix_cache_size and (
-                    "a borrowed page holds the full layers' KV of a shared "
-                    "prefix and nothing of the sliding layers', whose ring "
-                    "is the slot's own"),
-                "session_cache_size": session_cache_size and (
-                    "a stored session pins pages; the slot's ring is "
-                    "overwritten by its next tenant"),
-                "host_spill_pages": host_spill_pages and (
-                    "it spills the prefix cache, which is refused"),
-                "draft_model": draft_model is not None and (
-                    "spec verify writes a window into scratch pages and "
-                    "rolls a rejected tail back; a ring's write is over "
-                    "the position 6 pages back and cannot be undone"),
-                "mesh": mesh is not None and (
-                    "the ring's pool has no sharding layout"),
-                "kv_dtype int8": jnp.dtype(
-                    getattr(model, "kv_dtype", None) or jnp.bfloat16
-                ) == jnp.dtype(jnp.int8) and (
-                    "the ring has no scale planes"),
-            }
-            for option, why in refused.items():
-                if why:
-                    raise ValueError(
-                        f"{getattr(model, 'name', 'model')}: {option} "
-                        f"cannot be used with state by layer kind: {why}")
-        # A LATENT pool (``PagedKVCache.latent``): one row a position, no
-        # head axis, no k/v pair. What moves or scales a slot's KV as k/v
-        # pages cannot work with it and is refused here, by name (D10).
-        latent = bool(getattr(getattr(model, "cfg", None), "latent", False))
-        if latent:
-            refused = {
-                "host_spill_pages": host_spill_pages and (
-                    "a spilled page is stored and restored as a k/v pair "
-                    "of heads; a latent page has neither"),
-                "draft_model": draft_model is not None and (
-                    "spec verify scores a window of rows a slot; the "
-                    "absorbed decode kernel folds one row a slot"),
-                "mesh": mesh is not None and (
-                    "a latent row has no head axis to shard"),
-                "kv_dtype int8": jnp.dtype(
-                    getattr(model, "kv_dtype", None) or jnp.bfloat16
-                ) == jnp.dtype(jnp.int8) and (
-                    "a latent row has no scale plane"),
-            }
-            for option, why in refused.items():
-                if why:
-                    raise ValueError(
-                        f"{getattr(model, 'name', 'model')}: {option} "
-                        f"cannot be used with a latent pool: {why}")
+        # What this model's kind of KV state cannot serve is refused here,
+        # when the engine is built, from the state's own table (ROADMAP
+        # D10; models/kv_state.py::CANNOT).
+        cfg = model.cfg
+        refuse_unsupported(
+            cfg, model.name,
+            prefix_cache_size=prefix_cache_size,
+            session_cache_size=session_cache_size,
+            host_spill_pages=host_spill_pages,
+            draft_model=draft_model is not None, mesh=mesh is not None,
+            kv_dtype=model.kv_dtype)
         if draft_model is not None and mesh is not None:
             # Loud, like the draft-model conflict ISSUE 13 lifted (and
             # the PR 10 TP-paged pattern): the spec verify window would
@@ -898,18 +826,6 @@ class DecodeEngine:
             dtype=np.int32,
         )
         self._table_dirty = True
-        if mesh is not None and not hasattr(model, "paged_cache_pspec"):
-            # Loud, like the draft-model conflict: silently
-            # allocating the pool on ONE chip under a TP mesh would
-            # reshard it through ICI every step and mislabel every
-            # measurement stamped from the config (the PR-7 silent-
-            # fallback class).
-            raise ValueError(
-                f"{getattr(model, 'name', type(model).__name__)}: "
-                "a TP mesh needs the model to define "
-                "paged_cache_pspec (the pool's sharding layout) — "
-                "see CausalLM.paged_cache_pspec"
-            )
         if mesh is not None:
             # TP serving slice over the paged pool (ROADMAP item 2):
             # pages shard on the kv-head dim (codes + scales planes
@@ -941,9 +857,7 @@ class DecodeEngine:
         self._ring_pages = self._cache.ring_pages
         # Each layer's sliding window (0: a full layer), and the table
         # columns a slot's decode scan walks, averaged over layers.
-        self._layer_windows: Tuple[int, ...] = tuple(
-            getattr(model, "layer_windows", None)
-            or (0,) * self._cache.pages.shape[0])  # (a model without a cfg)
+        self._layer_windows: Tuple[int, ...] = model.layer_windows
         self._layer_table_widths = [
             tile_math.window_table_width(
                 w, 1, self.page_size, self._n_table_entries)
@@ -952,64 +866,16 @@ class DecodeEngine:
                               / len(self._layer_table_widths))
         # Positions a selecting layer's indexer keeps a query (0: no layer
         # selects), and how many layers select.
-        cfg = getattr(model, "cfg", None)
         self._index_topk = int(getattr(cfg, "index_topk", 0) or 0)
         self._select_layers = (
             sum(1 for i in range(cfg.num_layers) if cfg.layer_kind(i).select)
             if self._index_topk else 0)
-        # A position's true [K, head_dim] block, as the model's row caches
-        # have it and a parcel or the spill carries it (the pool's rows
-        # are lane-padded or hold several heads: pool_heads_per_row).
-        if latent:
-            self._kv_block: Tuple[int, int] = (0, model.cfg.head_dim)
-        elif by_kind:
-            self._kv_block = (self._cache.k.shape[3], model.cfg.head_dim)
-        else:
-            self._kv_block = tuple(jax.eval_shape(
-                lambda: model.make_cache(1, self.page_size)).k.shape[-2:])
-        # How the pool lies on the device, once, for snapshot():
-        # the order of its axes (row-major is what the paged kernel
-        # and the page write read; the pool's lane-padded rows make
-        # it the device's default) and its bytes there.
-        planes = [x for x in (self._cache.k, self._cache.v,
-                              self._cache.k_scale, self._cache.v_scale,
-                              self._cache.index_k, self._cache.ring_k,
-                              self._cache.ring_v, self._cache.latent)
-                  if x is not None]
-        layout = self._cache.pages.format.layout
-        self._pool_stats = {
-            "layout": (None if layout is None
-                       else list(layout.major_to_minor)),
-            "resident_bytes": sum(
-                x.on_device_size_in_bytes() for x in planes),
-        }
-        if not latent:
-            # KV heads side by side in a pool row, off the pool's shape.
-            self._pool_stats.update(
-                pool_shape=list(self._cache.k.shape),
-                heads_per_row=self._kv_block[0] // self._cache.k.shape[3])
-        if by_kind:
-            kinds = {"full": (self._cache.k, self._cache.v),
-                     "ring": (self._cache.ring_k, self._cache.ring_v)}
-            self._pool_stats.update(
-                ring_pages_per_slot=self._ring_pages,
-                bytes_by_kind={
-                    kind: sum(x.on_device_size_in_bytes() for x in pools)
-                    for kind, pools in kinds.items()})
-            for kind, n in self._pool_stats["bytes_by_kind"].items():
-                KV_POOL_BYTES.set(n, tags={"model": model.name,
-                                           "kind": kind})
-        # Latent layers (0: k/v pairs), and the pool's own lines.
-        self._latent_layers = self._cache.latent.shape[0] if latent else 0
-        if latent:
-            rows = self._cache.latent
-            self._pool_stats.update(
-                kind="latent", shape=list(rows.shape),
-                row_width=rows.shape[-1],
-                row_bytes=rows.shape[-1] * rows.dtype.itemsize,
-                bytes_by_kind={"latent": rows.on_device_size_in_bytes()})
-            KV_POOL_BYTES.set(rows.on_device_size_in_bytes(),
-                              tags={"model": model.name, "kind": "latent"})
+        # Latent layers (0: k/v pairs), and how the state lies on the
+        # device, once, for snapshot().
+        self._latent_layers = self._cache.latent_layers
+        self._pool_stats = self._cache.describe(cfg)
+        for kind, n in self._pool_stats.get("bytes_by_kind", {}).items():
+            KV_POOL_BYTES.set(n, tags={"model": model.name, "kind": kind})
         self._tokens = np.zeros((num_slots, 1), dtype=np.int32)
         self._active_mask = np.zeros((num_slots,), dtype=bool)
         # Per-slot sampling params (temperature 0 == greedy).
@@ -1593,7 +1459,7 @@ class DecodeEngine:
             f.meta_i[:, j] for j in range(6))
         temps, topp = f.meta_f[:, 0], f.meta_f[:, 1]
         params = self._mp(params)
-        rings, ring_pools = {}, {}
+        rings = {}
         if f.ring is not None:
             # state by layer kind: the rows' slots' ring tables
             rings["ring_tables"] = f.ring
@@ -1602,16 +1468,10 @@ class DecodeEngine:
             params, f.tokens, f.mask, cache, f.table, starts, take_idx,
             **self._moe_kw, **rings,
         )
-        lengths = cache.lengths.at[slots].set(new_len, mode="drop")
-        if rings:
-            ring_pools = {"ring_k": pools.ring_k, "ring_v": pools.ring_v}
-        if pools.latent is not None:
-            ring_pools = {"latent": pools.latent}
-        cache = cache.replace(
-            k=pools.k, v=pools.v, lengths=lengths,
-            k_scale=pools.k_scale, v_scale=pools.v_scale,
-            index_k=pools.index_k, **ring_pools,
-        )
+        # (the pools come back under the cache's own table: the rows'
+        # tables were the layers' to write through, not the result's)
+        cache = pools.replace(
+            lengths=cache.lengths.at[slots].set(new_len, mode="drop"))
         first = self._sample_tokens(
             taken, temps, topk, seeds, jnp.zeros_like(slots), f.bias_ids,
             f.bias_vals, topp,
@@ -2548,22 +2408,8 @@ class DecodeEngine:
         """Gather the listed pages' contents to host (spill). The pages
         are pinned (prefix-cache refs) and never rewritten after
         publication (CoW invariant), so this read races nothing."""
-        idx = np.asarray(page_ids, np.int32)
-        # A parcel carries [.., K, head_dim] whatever the pool's rows look
-        # like (cut or reshaped AFTER the gather: a gather of part of a
-        # row makes XLA re-lay the whole pool out for it).
-        out = {"k": from_pool_rows(np.asarray(self._cache.k[:, idx]),
-                                   *self._kv_block),
-               "v": from_pool_rows(np.asarray(self._cache.v[:, idx]),
-                                   *self._kv_block)}
-        if self._cache.quantized:
-            out["k_scale"] = np.asarray(self._cache.k_scale[:, idx])
-            out["v_scale"] = np.asarray(self._cache.v_scale[:, idx])
-        if self._cache.index_k is not None:
-            # A page travels with its index keys: without them a selecting
-            # layer would score zeros there after a migration.
-            out["index_k"] = np.asarray(self._cache.index_k[:, idx])
-        return out
+        return self._cache.read_pages(
+            np.asarray(page_ids, np.int32), self.model.cfg)
 
     def _write_pages(self, page_ids: List[int],
                      payload: Dict[str, np.ndarray]) -> None:
@@ -2571,25 +2417,9 @@ class DecodeEngine:
         (reload). Functional update — the pool array has one logical
         writer (this engine thread), like the page-table upload."""
         with self._device_ctx():
-            idx = jnp.asarray(np.asarray(page_ids, np.int32))
-            repl = {
-                "k": self._cache.k.at[:, idx].set(to_pool_rows(
-                    jnp.asarray(payload["k"], self._cache.k.dtype),
-                    self._cache.k)),
-                "v": self._cache.v.at[:, idx].set(to_pool_rows(
-                    jnp.asarray(payload["v"], self._cache.v.dtype),
-                    self._cache.v)),
-            }
-            if self._cache.quantized:
-                repl["k_scale"] = self._cache.k_scale.at[:, idx].set(
-                    jnp.asarray(payload["k_scale"], jnp.float32))
-                repl["v_scale"] = self._cache.v_scale.at[:, idx].set(
-                    jnp.asarray(payload["v_scale"], jnp.float32))
-            if self._cache.index_k is not None:
-                repl["index_k"] = self._cache.index_k.at[:, idx].set(
-                    jnp.asarray(payload["index_k"],
-                                self._cache.index_k.dtype))
-            self._cache = self._cache.replace(**repl)
+            self._cache = self._cache.write_pages(
+                jnp.asarray(np.asarray(page_ids, np.int32)), payload,
+                self.model.cfg)
 
     def _reload_spilled_prefix(
         self, prompt: np.ndarray
@@ -3536,20 +3366,6 @@ class DecodeEngine:
                 out.append(s.request.request_id)
         return out
 
-    def _refuse_parcels(self) -> None:
-        """A parcel carries pages; a slot's ring is no page of the pool,
-        and a latent page is no k/v pair of heads."""
-        if self._latent_layers:
-            raise ValueError(
-                f"{self.model.name}: the page fabric moves a stream as k/v "
-                "pages of heads; a latent pool has one row a position and "
-                "no such pair")
-        if self._ring_pages:
-            raise ValueError(
-                f"{self.model.name}: the page fabric moves a stream as the "
-                "pages of its table; with state by layer kind the sliding "
-                "layers' ring is not among them")
-
     def request_migration(
         self, request_id: str,
         deliver: Callable[[PageParcel], bool],
@@ -3562,7 +3378,7 @@ class DecodeEngine:
         left decoding untouched on False/raise. Returns False if the
         stream is not live here (advisory — a stream that finishes
         before service is simply skipped, duplicates are harmless)."""
-        self._refuse_parcels()
+        refuse_unsupported(self.model.cfg, self.model.name, parcel=True)
         live = any(
             (not s.free) and s.request is not None
             and s.request.request_id == request_id
@@ -3594,7 +3410,7 @@ class DecodeEngine:
         chain (reclaim cache pins -> capacity-truncate), so a stale
         accept is honest, never corrupting. A False return leaves the
         source slot untouched — it simply resumes decoding."""
-        self._refuse_parcels()
+        refuse_unsupported(self.model.cfg, self.model.name, parcel=True)
         if parcel.page_size != self.page_size:
             return False
         if parcel.kind == STREAM:
@@ -4092,28 +3908,6 @@ class DecodeEngine:
         return sorted({f"{p.program}: {p.describe()}"
                        for p in decode_paths() if p.program})
 
-    def _index_pool_stats(self, turns: Dict[str, Any]) -> Dict[str, Any]:
-        """A selecting model's lines of ``snapshot()["kv_pool"]``: the index
-        keys' pool, what the indexer keeps, and how each program's selecting
-        layers were read (``ops/sparse_attention.py::sparse_forms``).
-        Nothing for a model without an indexer."""
-        pool = self._cache.index_k
-        if pool is None:
-            return {}
-        from ray_dynamic_batching_tpu.ops.sparse_attention import (
-            sparse_forms,
-        )
-
-        return {
-            "index_pool": {
-                "shape": list(pool.shape), "dtype": str(pool.dtype),
-                "resident_bytes": pool.on_device_size_in_bytes()},
-            "index_topk": self._index_topk,
-            "select_layers": self._select_layers,
-            "selected_row_share": turns.get("kv_selected_row_share"),
-            "sparse_forms": sparse_forms(),
-        }
-
     def turn_summary(self, records: Optional[Sequence[Turn]] = None,
                      span_ms: Optional[float] = None,
                      longest: int = 8) -> Dict[str, Any]:
@@ -4163,6 +3957,19 @@ class DecodeEngine:
         ``prefill.mode`` have one value each: kept for the dashboards
         that read them."""
         turns = self.turn_summary()
+        select = {}
+        if self._index_topk:
+            # a selecting model: what the indexer keeps, and how each
+            # program's selecting layers were read
+            from ray_dynamic_batching_tpu.ops.sparse_attention import (
+                sparse_forms,
+            )
+
+            select = {
+                "index_topk": self._index_topk,
+                "select_layers": self._select_layers,
+                "selected_row_share": turns.get("kv_selected_row_share"),
+                "sparse_forms": sparse_forms()}
         out: Dict[str, Any] = {
             "model": self.model.name,
             "paged": True,
@@ -4193,7 +4000,7 @@ class DecodeEngine:
                    if self._ring_pages else {}),
                 **({"latent_rows_read": turns.get("kv_latent_rows", 0)}
                    if self._latent_layers else {}),
-                **self._index_pool_stats(turns),
+                **select,
             ),
             "page_journal": {
                 "events": self._page_journal.snapshot(),

@@ -2,7 +2,7 @@
 
 Host-side half of the paged KV cache (ISSUE 7 / ROADMAP open item 1).
 The DEVICE half is a fixed pool of lane-aligned HBM pages
-(``models/decoder.py::PagedKVCache``: k/v ``[L, P, page_size, K, H]``)
+(``models/kv_state.py::PagedKVCache``: k/v ``[L, P, page_size, K, H]``)
 gathered through per-slot page tables; THIS module owns which pages
 belong to whom:
 

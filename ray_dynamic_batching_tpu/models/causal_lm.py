@@ -20,14 +20,14 @@ from ray_dynamic_batching_tpu.models.base import (
     ServableModel,
     register_model,
 )
+from ray_dynamic_batching_tpu.models import kv_state
 from ray_dynamic_batching_tpu.models.decoder import (
     DecoderConfig,
     DecoderModule,
-    KVCache,
-    PagedKVCache,
     decode_mask,
     prefill_mask,
 )
+from ray_dynamic_batching_tpu.models.kv_state import KVCache, PagedKVCache
 
 
 def routing_counters(routing: Any, valid: jax.Array, first_expert: int,
@@ -77,7 +77,7 @@ class CausalLM(ServableModel):
         self.cfg = cfg
         # KV-cache storage dtype (None = activations dtype). int8 halves
         # the decode scan's HBM traffic: codes + per-(token, head) f32
-        # scales, quantized at write (models/decoder.py::quantize_kv_rows).
+        # scales, quantized at write (models/kv_state.py::quantize_kv_rows).
         self.kv_dtype = kv_dtype
         self.module = DecoderModule(cfg, dtype=dtype)
 
@@ -287,7 +287,7 @@ class CausalLM(ServableModel):
         with ``moe_counters`` (an expert model), the chunk's
         :func:`routing_counters` over ``attn_mask``'s real tokens.
         ``ring_tables`` (a model with state by layer kind): each row's
-        slot's ring table (``models/decoder.py::ring_table``), through
+        slot's ring table (``models/kv_state.py::ring_table``), through
         which its sliding layers write and read."""
         B, W = tokens.shape
         S = tables.shape[1] * cache.page_size
@@ -383,7 +383,7 @@ class CausalLM(ServableModel):
         rows one program writes to a slot at once, which sizes the
         sliding layers' ring where state is by layer kind. ``tp``: the
         width of the mesh the head axis will be split over (the rows'
-        layout asks: ``models/decoder.py::pool_heads_per_row``)."""
+        layout asks: ``models/kv_state.py::pool_heads_per_row``)."""
         return PagedKVCache.zeros(
             self.cfg, batch_size, num_pages, page_size, max_len,
             dtype=self.kv_dtype or self.dtype, index_dtype=self.dtype,
@@ -451,27 +451,9 @@ class CausalLM(ServableModel):
         return total + 2 * c.d_model * c.vocab_size * T
 
     def kv_bytes_per_slot(self, max_len: Optional[int] = None) -> int:
-        c = self.cfg
-        S = max_len or c.max_seq_len
-        itemsize = jnp.dtype(self.kv_dtype or self.dtype).itemsize
-        per_row = c.head_dim * itemsize
-        if self.kv_dtype is not None and jnp.dtype(
-                self.kv_dtype) == jnp.dtype(jnp.int8):
-            per_row += 4  # one f32 scale per cached (token, head) row
-        # an indexer's ONE key a position a layer, in the model's own dtype
-        index_row = (c.index_head_dim * jnp.dtype(self.dtype).itemsize
-                     if c.index_topk else 0)
-        if c.latent:
-            # ONE row a position a layer: the latent and the shared key
-            return c.num_layers * S * (
-                c.kv_lora_rank + c.rope_dim) * itemsize
-        if c.kv_by_kind:
-            # the full layers a position, the sliding layers their window
-            row = (c.head_dim + c.v_head_dim) * itemsize
-            return (c.layers_of(False) * S * c.num_kv_heads * row
-                    + c.layers_of(True) * min(S, c.sliding_window)
-                    * (c.sliding_kv_heads or c.num_kv_heads) * row)
-        return c.num_layers * S * (2 * c.num_kv_heads * per_row + index_row)
+        """One slot's KV bytes at its true widths (the planner's figure)."""
+        return kv_state.kv_bytes_per_slot(
+            self.cfg, self.dtype, self.kv_dtype, max_len)
 
     def sharding_rules(self):
         return [
@@ -492,50 +474,9 @@ class CausalLM(ServableModel):
             (r"lm_head/kernel", P(None, "tp")),
         ]
 
-    def cache_pspec(self) -> KVCache:
-        """PartitionSpecs for the KV cache (kv heads sharded over tp)."""
-        scale_spec = None
-        if self.kv_dtype is not None and jnp.dtype(
-                self.kv_dtype) == jnp.dtype(jnp.int8):
-            scale_spec = P(None, None, None, "tp")
-        return KVCache(
-            k=P(None, None, None, "tp", None),   # type: ignore[arg-type]
-            v=P(None, None, None, "tp", None),   # type: ignore[arg-type]
-            lengths=P(None),                      # type: ignore[arg-type]
-            k_scale=scale_spec,                   # type: ignore[arg-type]
-            v_scale=scale_spec,                   # type: ignore[arg-type]
-        )
-
     def paged_cache_pspec(self) -> PagedKVCache:
-        """PartitionSpecs for the PAGED KV pool (ROADMAP item 2): pages
-        shard on the kv-head dim exactly like the slab cache — the pool
-        is ``[L, P, ps, K // f, Hp]``, so the heads sit at the same index
-        3 (``f`` side by side in a row only where the rows still divide
-        over the mesh: ``pool_heads_per_row``) and a shard owns the full
-        page set for its head slice. The page table
-        and lengths REPLICATE: page indices are shard-invariant (every
-        shard's slice of page ``p`` backs the same logical positions),
-        which is what lets the host-side ``PageAllocator`` stay
-        replica-global. Scale planes (``[L, P, ps, K]``) shard with
-        their heads; a selecting model's index keys replicate."""
-        if self.cfg.latent:
-            raise NotImplementedError(
-                "a latent pool's rows have no head axis to shard over tp")
-        scale_spec = None
-        if self.kv_dtype is not None and jnp.dtype(
-                self.kv_dtype) == jnp.dtype(jnp.int8):
-            scale_spec = P(None, None, None, "tp")
-        return PagedKVCache(
-            k=P(None, None, None, "tp", None),   # type: ignore[arg-type]
-            v=P(None, None, None, "tp", None),   # type: ignore[arg-type]
-            page_table=P(None, None),             # type: ignore[arg-type]
-            lengths=P(None),                      # type: ignore[arg-type]
-            k_scale=scale_spec,                   # type: ignore[arg-type]
-            v_scale=scale_spec,                   # type: ignore[arg-type]
-            # ONE index key a position, whatever the head shard: replicated
-            index_k=(P(None, None, None, None)    # type: ignore[arg-type]
-                     if self.cfg.index_topk else None),
-        )
+        """PartitionSpecs for the paged pool (``PagedKVCache.pspec``)."""
+        return PagedKVCache.pspec(self.cfg, self.kv_dtype, self.name)
 
 
 GPT2_MEDIUM = DecoderConfig(

@@ -26,9 +26,16 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
-from flax.struct import dataclass as pytree_dataclass
 
+from ray_dynamic_batching_tpu.models.kv_state import (
+    KVCache,
+    LayerState,
+    fit_head_dim,
+    quantize_kv_rows,
+    refuse_unsupported,
+    ring_table,
+    to_pool_rows,
+)
 from ray_dynamic_batching_tpu.ops import attention as attn_ops
 
 
@@ -277,339 +284,6 @@ class LayerKind:
     latent: bool = False
 
 
-@pytree_dataclass
-class KVCache:
-    """Per-model cache: k/v [L, B, S, K, H]; lengths [B] = valid prefix.
-
-    With ``dtype=int8`` the cache is weight-free quantized storage:
-    k/v hold int8 codes and ``k_scale``/``v_scale`` [L, B, S, K] f32
-    hold one scale per cached (token, head) row (absmax/127, computed
-    at write). The guaranteed win is CAPACITY: half the HBM per slot,
-    so auto-sizing fits ~2x the slots per chip. The bandwidth win on
-    the decode scan (its dominant HBM traffic) is realized where the
-    dequant fuses into the attention read; the XLA fallback path
-    materializes a dequantized operand, trading scan bandwidth for
-    capacity. Scales are pytree fields: donation and sharding treat
-    them as part of the cache automatically; the row seed/extract paths
-    (admission copies, prefix/session segments) thread them explicitly
-    as part of every stored segment tuple."""
-
-    k: jax.Array
-    v: jax.Array
-    lengths: jax.Array
-    k_scale: Optional[jax.Array] = None
-    v_scale: Optional[jax.Array] = None
-
-    @staticmethod
-    def zeros(
-        cfg: DecoderConfig, batch_size: int, max_len: Optional[int] = None,
-        dtype: jnp.dtype = jnp.bfloat16,
-    ) -> "KVCache":
-        S = max_len or cfg.max_seq_len
-        shape = (cfg.num_layers, batch_size, S, cfg.num_kv_heads, cfg.head_dim)
-        quantized = jnp.dtype(dtype) == jnp.dtype(jnp.int8)
-        return KVCache(
-            k=jnp.zeros(shape, dtype=dtype),
-            v=jnp.zeros(shape, dtype=dtype),
-            lengths=jnp.zeros((batch_size,), dtype=jnp.int32),
-            k_scale=jnp.zeros(shape[:-1], jnp.float32) if quantized else None,
-            v_scale=jnp.zeros(shape[:-1], jnp.float32) if quantized else None,
-        )
-
-    @property
-    def capacity(self) -> int:
-        return self.k.shape[2]
-
-    @property
-    def quantized(self) -> bool:
-        return self.k_scale is not None
-
-
-@pytree_dataclass
-class PagedKVCache:
-    """Paged KV pool: k/v ``[L, P, page_size, K // f, Hp]`` fixed HBM
-    pages: ``f`` = :func:`pool_heads_per_row` heads side by side in a
-    row where a head is narrower than the 128 lanes and the heads pair off
-    (16 x 64: ``[.., 8, 128]``, row ``r`` of a position holds heads
-    ``r * f .. r * f + f - 1``; :func:`to_pool_rows` /
-    :func:`from_pool_rows` are the two ways across), else ``f`` = 1 and
-    ``Hp`` = :func:`pool_head_dim`: the head, lane-padded. Every reader
-    takes ``f`` off the shape (``num_kv_heads // k.shape[3]``);
-    gathered per slot through ``page_table`` ``[B, NP]`` int32 (entry j
-    names the physical page backing logical positions
-    ``[j*page_size, (j+1)*page_size)`` of that slot; unallocated entries
-    carry the sentinel ``P`` — one past the last page — so writes
-    through them drop and gathers clamp into masked territory).
-
-    The slab cache gives every slot a private ``max_len`` KV run whether
-    it uses 3 tokens or 300; here HBM occupancy follows *actual* cached
-    tokens at page granularity, prefix/session reuse shares pages by
-    refcount instead of copying rows (``engine/paging.py``), and EOS
-    returns pages to the free list mid-cycle. Shapes stay fully static —
-    continuous batching still varies contents, never shapes — so the
-    one-compiled-program-per-stream property of the slab path survives.
-
-    Quantized pools mirror the slab layout: k/v hold int8 codes,
-    ``k_scale``/``v_scale`` ``[L, P, page_size, K]`` hold the per-row
-    f32 scales, paged with the SAME page table. So is ``index_k``
-    ``[L, P, page_size, Hip]``, a selecting model's index keys (one a
-    position a layer, ``Hip`` the indexer's head lane-padded; the model's
-    own dtype in an int8 pool too): a second kind of per-position state
-    in the one pool, None for a model without an indexer.
-
-    State BY LAYER KIND (``DecoderConfig.kv_by_kind``): ``k``/``v`` hold
-    the FULL layers only (``L`` their count, ``K`` their head count; a v
-    row as wide as a value head, lane-padded, where that is narrower than
-    a key's), and the sliding layers keep ``ring_k``/``ring_v``
-    ``[L_w, B * R, page_size, K_w, Hp]``: a ring of ``R`` pages a slot
-    (:attr:`ring_pages`), read and written through :func:`ring_table`, a
-    page table that is arithmetic (logical column ``c`` of slot ``b`` is
-    page ``b * R + c % R``), so a window layer uses the paged write, the
-    gather and the kernel's window walk as they are and the allocator
-    hands out full-layer pages only. A position older than the ring is
-    overwritten by a newer one; nothing attends it (the window's lower
-    edge is the kernel's and the fallback's mask, by position), so a
-    reused slot's ring is never cleared. None for every other model.
-
-    A LATENT model (``DecoderConfig.latent``) has no k/v pair at all:
-    ``k`` and ``v`` are None and ``latent`` ``[L, P, page_size, Wp]`` holds
-    one row a position a layer, ``[c_kv | k_r | 0]`` (``Wp``:
-    ``ops/latent_attention.py::row_width``), with NO head axis, paged with
-    the same table. None for every other model."""
-
-    k: Optional[jax.Array]
-    v: Optional[jax.Array]
-    page_table: jax.Array  # [B, NP] int32, sentinel P = unallocated
-    lengths: jax.Array     # [B] valid logical prefix per slot
-    k_scale: Optional[jax.Array] = None
-    v_scale: Optional[jax.Array] = None
-    index_k: Optional[jax.Array] = None
-    ring_k: Optional[jax.Array] = None
-    ring_v: Optional[jax.Array] = None
-    latent: Optional[jax.Array] = None
-
-    @staticmethod
-    def zeros(
-        cfg: DecoderConfig, batch_size: int, num_pages: int,
-        page_size: int, max_len: int,
-        dtype: jnp.dtype = jnp.bfloat16,
-        index_dtype: jnp.dtype = jnp.bfloat16,
-        widest_chunk: Optional[int] = None,
-        tp: int = 1,
-    ) -> "PagedKVCache":
-        """``widest_chunk`` (state by layer kind only): the most rows one
-        program writes to a slot at once, which with the window sets the
-        pages of a slot's ring (:attr:`ring_pages`). ``tp``: the width of
-        the mesh the pool's head axis is split over
-        (:func:`pool_heads_per_row` asks)."""
-        if max_len % page_size != 0:
-            raise ValueError(
-                f"max_len {max_len} must be a multiple of page_size "
-                f"{page_size} (logical capacity is whole pages)"
-            )
-        n_entries = max_len // page_size
-        quantized = jnp.dtype(dtype) == jnp.dtype(jnp.int8)
-        if cfg.latent:
-            if quantized:
-                raise NotImplementedError(
-                    "kv_lora_rank: a latent row has no scale plane (an "
-                    "int8 pool)")
-            from ray_dynamic_batching_tpu.ops.latent_attention import (
-                row_width,
-            )
-
-            return PagedKVCache(
-                k=None, v=None,
-                page_table=jnp.full((batch_size, n_entries), num_pages,
-                                    dtype=jnp.int32),
-                lengths=jnp.zeros((batch_size,), dtype=jnp.int32),
-                latent=jnp.zeros(
-                    (cfg.num_layers, num_pages, page_size,
-                     row_width(cfg.kv_lora_rank, cfg.rope_dim)), dtype))
-        if cfg.kv_by_kind:
-            if quantized or cfg.index_topk:
-                raise NotImplementedError(
-                    "kv_by_kind: the ring has no scale planes and no index "
-                    "keys (an int8 pool, an indexer)")
-            rows = lambda layers, pages, heads, width: jnp.zeros(  # noqa: E731
-                (layers, pages, page_size, heads, pool_head_dim(width)),
-                dtype)
-            if widest_chunk is None:
-                raise ValueError(
-                    "state by layer kind: a slot's ring is sized for the "
-                    "widest chunk written to it at once; pass widest_chunk")
-            from ray_dynamic_batching_tpu.ops.tile_math import (
-                window_table_width,
-            )
-
-            full, slide = cfg.layers_of(False), cfg.layers_of(True)
-            # The table columns that a chunk's rows can attend between
-            # them (window 128, 512 rows, pages of 128: 6), so that no row
-            # of a chunk is written over a position another row attends.
-            ring = batch_size * window_table_width(
-                cfg.sliding_window, widest_chunk, page_size, n_entries)
-            k_w = cfg.sliding_kv_heads or cfg.num_kv_heads
-            return PagedKVCache(
-                k=rows(full, num_pages, cfg.num_kv_heads, cfg.head_dim),
-                v=rows(full, num_pages, cfg.num_kv_heads, cfg.v_head_dim),
-                page_table=jnp.full((batch_size, n_entries), num_pages,
-                                    dtype=jnp.int32),
-                lengths=jnp.zeros((batch_size,), dtype=jnp.int32),
-                ring_k=rows(slide, ring, k_w, cfg.head_dim),
-                ring_v=rows(slide, ring, k_w, cfg.v_head_dim),
-            )
-        f = pool_heads_per_row(cfg.head_dim, cfg.num_kv_heads, dtype, tp,
-                               indexed=bool(cfg.index_topk))
-        shape = (cfg.num_layers, num_pages, page_size,
-                 cfg.num_kv_heads // f, pool_head_dim(cfg.head_dim * f))
-        return PagedKVCache(
-            k=jnp.zeros(shape, dtype=dtype),
-            v=jnp.zeros(shape, dtype=dtype),
-            page_table=jnp.full((batch_size, n_entries), num_pages,
-                                dtype=jnp.int32),
-            lengths=jnp.zeros((batch_size,), dtype=jnp.int32),
-            k_scale=jnp.zeros(shape[:-1], jnp.float32) if quantized else None,
-            v_scale=jnp.zeros(shape[:-1], jnp.float32) if quantized else None,
-            index_k=jnp.zeros(
-                shape[:3] + (pool_head_dim(cfg.index_head_dim),),
-                index_dtype) if cfg.index_topk else None,
-        )
-
-    @property
-    def pages(self) -> jax.Array:
-        """The paged pool whose axes 1 and 2 are (page, position): ``k``,
-        or a latent model's rows."""
-        return self.latent if self.k is None else self.k
-
-    @property
-    def page_size(self) -> int:
-        return self.pages.shape[2]
-
-    @property
-    def num_pages(self) -> int:
-        return self.pages.shape[1]
-
-    @property
-    def capacity(self) -> int:
-        """Per-slot LOGICAL capacity (page_table width x page size) —
-        the same contract as ``KVCache.capacity``."""
-        return self.page_table.shape[1] * self.page_size
-
-    @property
-    def quantized(self) -> bool:
-        return self.k_scale is not None
-
-    @property
-    def ring_pages(self) -> int:
-        """Pages in a slot's ring; 0: one pool for every layer."""
-        if self.ring_k is None:
-            return 0
-        return self.ring_k.shape[1] // self.page_table.shape[0]
-
-
-def ring_table(slots, ring: int, n_entries: int):
-    """The sliding layers' page table, ``[len(slots), n_entries]``: logical
-    column ``c`` of slot ``b`` is ring page ``b * ring + c % ring``.
-    Arithmetic on ``slots`` (a numpy or a traced array alike): nothing is
-    allocated, freed or stored."""
-    cols = np.arange(n_entries, dtype=np.int32) % ring
-    return slots[:, None] * ring + cols[None, :]
-
-
-def pool_head_dim(head_dim: int) -> int:
-    """Width of a (token, head) row in the PAGED pool: the head size
-    rounded up to the 128 lanes. The paged kernel and XLA's in-place page
-    write both read rows lane-major, so a row narrower than the lanes is
-    lane-padded on the device whatever the array says; saying it in the
-    SHAPE makes that row-major layout the device's default for the pool.
-    With the true width in the shape (64), the default layout puts the
-    page's position axis minor-most instead, and every program that
-    touches the pool converts k and v on the way in and back on the way
-    out: four pool-sized copies a dispatch. A layout kept by
-    ``jax.experimental.layout`` would say the same thing without the
-    padding showing, but an executable loaded from the persistent compile
-    cache forgets it (PERF.md, PR 25). A head that fills the lanes (128,
-    256) is not padded."""
-    return -(-head_dim // 128) * 128
-
-
-def pool_heads_per_row(head_dim: int, kv_heads: int, dtype: Any,
-                       tp: int = 1, indexed: bool = False) -> int:
-    """``f``, the KV heads that lie side by side in ONE 128-lane row of
-    the paged pool: the rule, owned here; every reader takes ``f`` off
-    the pool's shape (``kv_heads // pool.shape[3]``). Where a head is
-    narrower than the lanes and divides them, ``f = 128 // head_dim``
-    whole heads fill a row instead of one head and zeros: a position's
-    ``[K, head_dim]`` block read as ``[K // f, 128]``, the same bytes in
-    the same order, so a gpt2-medium pool (16 x 64) is ``[.., 8, 128]``,
-    half the padded bytes, and the paged kernel walks a page once, in the
-    8 x 128 geometry of a 128-wide-head model. 1 (a head a row, lane-padded:
-    :func:`pool_head_dim`) where the heads do not pair off (``kv_heads %
-    f``), for an int8 pool (a scale plane holds one value a (position,
-    head): two heads in a row want two), under a TP mesh that ``kv_heads
-    // f`` rows do not divide over, and for a selecting model (its sparse
-    kernel reads a head a row)."""
-    if head_dim <= 0 or 128 % head_dim:
-        return 1
-    f = 128 // head_dim
-    if (kv_heads % f or indexed or (kv_heads // f) % max(1, tp)
-            or jnp.dtype(dtype) == jnp.dtype(jnp.int8)):
-        return 1
-    return f
-
-
-def to_pool_rows(x: jax.Array, pool: jax.Array) -> jax.Array:
-    """x [..., K, H] -> [..., K_pool, Hp], the rows of ``pool``
-    ``[L, P, ps, K_pool, Hp]``: ``f`` heads a row (a reshape: the same
-    bytes) where the pool packs them (:func:`pool_heads_per_row`), else
-    a head a row, lane-padded."""
-    if pool.shape[-2] != x.shape[-2]:
-        return x.reshape(x.shape[:-2] + pool.shape[-2:])
-    return fit_head_dim(x, pool.shape[-1])
-
-
-def from_pool_rows(rows, kv_heads: int, head_dim: int):
-    """:func:`to_pool_rows` back: rows [..., K_pool, Hp] (a jax or a numpy
-    array) -> [..., kv_heads, head_dim], the form a slab view, a parcel
-    and the spill hold whatever the pool's rows look like."""
-    if rows.shape[-2] != kv_heads:
-        return rows.reshape(rows.shape[:-2] + (kv_heads, head_dim))
-    return rows[..., :head_dim]
-
-
-def fit_head_dim(x: jax.Array, width: int) -> jax.Array:
-    """x [..., H] -> [..., width]: zero-pad the head axis up to the
-    pool's row width, or cut a pool row back to the head. Zeros are
-    inert on both sides of attention (q . 0 adds nothing to a score, p .
-    0 nothing to an output lane that is then cut)."""
-    H = x.shape[-1]
-    if width == H:
-        return x
-    if width < H:
-        return x[..., :width]
-    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - H)])
-
-
-def quantize_kv_rows(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Per-(token, head) absmax int8 quantization: x [..., H] ->
-    (codes int8 [..., H], scale f32 [...])."""
-    absmax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1)
-    scale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
-    codes = jnp.clip(
-        jnp.round(x.astype(jnp.float32) / scale[..., None]), -127, 127
-    ).astype(jnp.int8)
-    return codes, scale
-
-
-def dequantize_kv(codes: jax.Array, scale: jax.Array,
-                  dtype: jnp.dtype) -> jax.Array:
-    """codes int8 [..., H] * scale [...] -> [..., H] in ``dtype``.
-    Single source of the dequant rule — the attention dispatcher's
-    fallback path uses this exact function, so kernel-vs-fallback
-    parity cannot drift."""
-    return (codes.astype(jnp.float32) * scale[..., None]).astype(dtype)
-
-
 def apply_rope(
     x: jax.Array, positions: jax.Array, theta: float = 10000.0,
     rope_dim: int = 0,
@@ -671,19 +345,20 @@ class DecoderLayer(nn.Module):
         x: jax.Array,               # [B, T, D]
         positions: jax.Array,       # [B, T]
         mask: Optional[jax.Array],  # [B, 1, T, S_attended] True = attend
-        cache_kv: Optional[Tuple[jax.Array, jax.Array]] = None,  # k/v [L,B,S,K,H]
+        cache_kv: Optional[LayerState] = None,  # stacked pools, [L, ...]
         token_mask: Optional[jax.Array] = None,  # [B, T] (no-cache path)
         layer_idx: int = 0,
         write_start: Optional[jax.Array] = None,  # scalar: chunk write offset
         scatter_writes: bool = False,  # per-row writes at ``positions``
         page_table: Optional[jax.Array] = None,  # [B, NP]: paged decode
         kv_lengths: Optional[jax.Array] = None,  # [B] paged validity bound
-        index_pool: Optional[jax.Array] = None,  # [L, P, ps, Hip] index keys
-    ) -> Tuple[jax.Array, ...]:
-        """(x, updated cache or None); a selecting layer handed the paged
-        ``index_pool`` returns it, updated, as a third result."""
+    ) -> Tuple[jax.Array, Optional[LayerState]]:
+        """(x, the layer's state updated, or None without a cache)."""
         cfg = self.cfg
         kind = cfg.layer_kind(layer_idx)
+        if cache_kv is not None:
+            refuse_unsupported(cfg, error=NotImplementedError,
+                               slab=page_table is None)
         dense = lambda feats, name, axis=-1: nn.DenseGeneral(  # noqa: E731
             feats,
             axis=axis,
@@ -710,10 +385,10 @@ class DecoderLayer(nn.Module):
                 y, positions, mask, cache_kv, token_mask, layer_idx,
                 page_table, kv_lengths)
         else:
-            attn_out, new_cache, index_pool = self._kv_attention(
+            attn_out, new_cache = self._kv_attention(
                 dense, kind, y, positions, mask, cache_kv, token_mask,
                 layer_idx, write_start, scatter_writes, page_table,
-                kv_lengths, index_pool)
+                kv_lengths)
         attn_out = dense(cfg.d_model, "o", axis=(-2, -1))(attn_out)
         x = hyper_connections.mix(x, attn_out, maps) if hc else x + attn_out
 
@@ -744,20 +419,14 @@ class DecoderLayer(nn.Module):
             y = nn.gelu(dense(kind.mlp_dim, "mlp_up")(y))
             y = dense(cfg.d_model, "mlp_down")(y)
         x = hyper_connections.mix(x, y, maps) if hc else x + y
-        if index_pool is not None:
-            return x, new_cache, index_pool
         return x, new_cache
 
     def _latent_attention(self, y, positions, mask, cache_kv, token_mask,
                           layer_idx, page_table, kv_lengths):
-        """A latent layer's heads' outputs and its pool, updated, as a
-        1-tuple (``models/latent.py``; its model alone loads it)."""
+        """A latent layer's heads' outputs and its state, the pool updated
+        (``models/latent.py``; its model alone loads it)."""
         from ray_dynamic_batching_tpu.models import latent
 
-        if cache_kv is not None and page_table is None:
-            raise NotImplementedError(
-                "a latent layer's rows live in the paged pool "
-                "(PagedKVCache.latent): the slab cache has none")
         allowed = None
         if cache_kv is None:
             B, T = positions.shape
@@ -767,16 +436,16 @@ class DecoderLayer(nn.Module):
         out, pool = latent.attention(
             self.cfg, self.dtype,
             lambda name: RMSNorm(name=name, eps=self.cfg.rms_eps),
-            y, positions, pool=None if cache_kv is None else cache_kv[0],
+            y, positions, pool=None if cache_kv is None else cache_kv.latent,
             layer=layer_idx, page_table=page_table, kv_lengths=kv_lengths,
             allowed=allowed)
-        return out, None if pool is None else (pool,)
+        return out, None if pool is None else cache_kv._replace(latent=pool)
 
     def _kv_attention(self, dense, kind, y, positions, mask, cache_kv,
                       token_mask, layer_idx, write_start, scatter_writes,
-                      page_table, kv_lengths, index_pool):
-        """A layer of k/v pairs: the heads' outputs, the cache updated (or
-        None) and a selecting layer's index pool."""
+                      page_table, kv_lengths):
+        """A layer of k/v pairs: the heads' outputs and its state updated
+        (or None), a selecting layer's index keys among it."""
         cfg = self.cfg
         q = dense((cfg.num_heads, cfg.head_dim), "q")(y)
         kv_heads = kind.kv_heads or cfg.num_kv_heads
@@ -821,7 +490,7 @@ class DecoderLayer(nn.Module):
                     q_i = apply_rope(q_i, positions, cfg.rope_theta)
                     k_i = apply_rope(k_i, positions, cfg.rope_theta)
                 k_i = k_i[:, :, 0]                       # ONE key a position
-            if cache_kv is not None and index_pool is None:
+            if cache_kv is not None and cache_kv.index_k is None:
                 raise NotImplementedError(
                     "a selecting layer's index keys live in the paged pool "
                     "(PagedKVCache.index_k): the slab cache has none")
@@ -842,22 +511,16 @@ class DecoderLayer(nn.Module):
             # The paged READ keeps the same contract (below): the pools
             # are passed whole and the layer is an index — no read makes
             # an array the size of a layer of the pool.
-            # A 4-tuple carries the int8 cache's per-row scales; every
-            # write path scatters codes and scales with the SAME indices.
-            quantized = len(cache_kv) == 4
+            k_full, v_full = cache_kv.k, cache_kv.v
+            ks_full, vs_full = cache_kv.k_scale, cache_kv.v_scale
+            index_pool = cache_kv.index_k
+            quantized = ks_full is not None
             if quantized:
-                k_full, v_full, ks_full, vs_full = cache_kv
                 k_w, k_s = quantize_kv_rows(k)
                 v_w, v_s = quantize_kv_rows(v)
             else:
-                k_full, v_full = cache_kv
-                ks_full = vs_full = None
                 k_w, v_w = k, v
             B, T = positions.shape
-            if page_table is None and kind.pool_layer >= 0:
-                raise NotImplementedError(
-                    "state by layer kind is the paged cache's: the slab "
-                    "cache has one shape for every layer")
             if page_table is not None:
                 # Paged writes: the cache arrays are page POOLS
                 # [L, P, ps, K, H]; each token's logical position maps
@@ -892,84 +555,48 @@ class DecoderLayer(nn.Module):
                     idx < n_entries * ps, page_table[rows, pidx], P
                 )
                 off = idx % ps
-                k_full = k_full.at[li, pid, off].set(
-                    k_w, mode="drop"
-                )
-                v_full = v_full.at[li, pid, off].set(
-                    v_w, mode="drop"
-                )
-                if quantized:
-                    ks_full = ks_full.at[li, pid, off].set(
-                        k_s, mode="drop"
-                    )
-                    vs_full = vs_full.at[li, pid, off].set(
-                        v_s, mode="drop"
-                    )
-                if kind.select:
-                    # The index key rides the SAME (page, offset): written
-                    # before it is scored, as k and v are.
-                    index_pool = index_pool.at[li, pid, off].set(
-                        fit_head_dim(k_i, index_pool.shape[-1]).astype(
-                            index_pool.dtype), mode="drop")
-                    select = sparse_attention.Selection(
-                        q_i, w_i, index_pool, kind.select)
+
+                def write(pool, x):
+                    return pool.at[li, pid, off].set(x, mode="drop")
             elif scatter_writes:
                 # Batched multi-token writes at PER-ROW positions (the
                 # speculative-verify path: each slot's window starts at its
                 # own length). mode="drop" voids rows steered out of
                 # bounds, exactly like the single-token decode scatter.
                 rows = jnp.arange(B)[:, None]
-                k_full = k_full.at[li, rows, positions].set(
-                    k_w, mode="drop"
-                )
-                v_full = v_full.at[li, rows, positions].set(
-                    v_w, mode="drop"
-                )
-                if quantized:
-                    ks_full = ks_full.at[li, rows, positions].set(
-                        k_s, mode="drop"
-                    )
-                    vs_full = vs_full.at[li, rows, positions].set(
-                        v_s, mode="drop"
-                    )
+
+                def write(pool, x):
+                    return pool.at[li, rows, positions].set(x, mode="drop")
             elif T == 1:
                 # Decode: scatter this token's k/v at its row position.
                 # mode="drop" makes a full row's out-of-bounds write a no-op
                 # instead of clamping onto (and corrupting) the last slot.
                 idx = positions[:, 0]
                 rows = jnp.arange(B)
-                k_full = k_full.at[li, rows, idx].set(
-                    k_w[:, 0], mode="drop"
-                )
-                v_full = v_full.at[li, rows, idx].set(
-                    v_w[:, 0], mode="drop"
-                )
-                if quantized:
-                    ks_full = ks_full.at[li, rows, idx].set(
-                        k_s[:, 0], mode="drop"
-                    )
-                    vs_full = vs_full.at[li, rows, idx].set(
-                        v_s[:, 0], mode="drop"
-                    )
+
+                def write(pool, x):
+                    return pool.at[li, rows, idx].set(x[:, 0], mode="drop")
             else:
                 # Prefill: contiguous write at offset 0, or — for chunked
                 # prefill of long prompts — at a TRACED start position, so
                 # one compiled program serves every chunk of the prompt
                 # (dynamic start, static chunk shape).
                 start = write_start if write_start is not None else 0
-                k_full = jax.lax.dynamic_update_slice(
-                    k_full, k_w[None], (li, 0, start, 0, 0)
-                )
-                v_full = jax.lax.dynamic_update_slice(
-                    v_full, v_w[None], (li, 0, start, 0, 0)
-                )
-                if quantized:
-                    ks_full = jax.lax.dynamic_update_slice(
-                        ks_full, k_s[None], (li, 0, start, 0)
-                    )
-                    vs_full = jax.lax.dynamic_update_slice(
-                        vs_full, v_s[None], (li, 0, start, 0)
-                    )
+
+                def write(pool, x):
+                    return jax.lax.dynamic_update_slice(
+                        pool, x[None], (li, 0, start) + (0,) * (pool.ndim - 3))
+            # ONE rule a pattern for every plane of the layer's state: the
+            # codes, their scales and a selecting layer's index key (written
+            # before it is scored, as k and v are) land at the SAME indices.
+            k_full, v_full = write(k_full, k_w), write(v_full, v_w)
+            if quantized:
+                ks_full, vs_full = write(ks_full, k_s), write(vs_full, v_s)
+            if kind.select:
+                index_pool = write(index_pool, fit_head_dim(
+                    k_i, index_pool.shape[-1]).astype(index_pool.dtype))
+                select = sparse_attention.Selection(
+                    q_i, w_i, index_pool, kind.select)
             # Quantized caches hand CODES + scales to the dispatcher:
             # the decode kernel scans the 1-byte codes directly (the
             # bandwidth win); non-kernel paths dequantize there.
@@ -977,9 +604,9 @@ class DecoderLayer(nn.Module):
             if quantized:
                 scale_kwargs = {"k_scale": ks_full[li],
                                 "v_scale": vs_full[li]}
-                new_cache = (k_full, v_full, ks_full, vs_full)
-            else:
-                new_cache = (k_full, v_full)
+            new_cache = cache_kv._replace(
+                k=k_full, v=v_full, k_scale=ks_full, v_scale=vs_full,
+                index_k=index_pool)
             if page_table is not None:
                 # Paged read: the STACKED pools go to the dispatcher
                 # whole and this layer is an index into them — in the
@@ -1050,7 +677,7 @@ class DecoderLayer(nn.Module):
             attn_out = attn_ops.dot_product_attention(q, k, v, mask=mask)
             new_cache = None
 
-        return attn_out, new_cache, index_pool
+        return attn_out, new_cache
 
 
 class DecoderModule(nn.Module):
@@ -1095,45 +722,30 @@ class DecoderModule(nn.Module):
             x = jnp.broadcast_to(
                 x[:, :, None, :], x.shape[:2] + (cfg.hc_mult, cfg.d_model))
 
-        cache_kv = None
-        if getattr(cache, "latent", None) is not None:
-            cache_kv = (cache.latent,)
-        elif cache is not None:
-            cache_kv = (
-                (cache.k, cache.v, cache.k_scale, cache.v_scale)
-                if cache.quantized else (cache.k, cache.v)
-            )
-        # A selecting model's index keys, paged beside k and v.
-        index_kw = {}
-        if getattr(cache, "index_k", None) is not None:
-            index_kw["index_pool"] = cache.index_k
-        # State by layer kind: the sliding layers' ring rides beside the
-        # full layers' pool, each layer handed its own kind's and its table
-        # (a row's ring table is its slot's: the caller's for a chunk's
-        # rows, slot b's for row b of a decode step).
-        ring_kv = None
-        if getattr(cache, "ring_k", None) is not None:
-            ring_kv = (cache.ring_k, cache.ring_v)
-            if ring_tables is None:
-                ring_tables = ring_table(
-                    jnp.arange(tokens.shape[0], dtype=jnp.int32),
-                    cache.ring_pages, page_table.shape[1])
+        # Each layer is handed its kind's share of the state and hands it
+        # back updated (``models/kv_state.py``): where state is by layer
+        # kind, a sliding layer its ring and its table (a row's ring table
+        # is its slot's: the caller's for a chunk's rows, slot b's for row
+        # b of a decode step).
+        ring_pages = getattr(cache, "ring_pages", 0)
+        if ring_pages and ring_tables is None:
+            ring_tables = ring_table(
+                jnp.arange(tokens.shape[0], dtype=jnp.int32),
+                ring_pages, page_table.shape[1])
         for i in range(cfg.num_layers):
-            ring = ring_kv is not None and cfg.layer_kind(i).ring
-            x, updated, *index = DecoderLayer(
+            kind = cfg.layer_kind(i)
+            x, updated = DecoderLayer(
                 cfg, dtype=self.dtype, name=f"layer{i}")(
-                x, positions, mask, ring_kv if ring else cache_kv,
+                x, positions, mask,
+                None if cache is None else cache.layer_state(kind),
                 token_mask, layer_idx=i,
                 write_start=write_start, scatter_writes=scatter_writes,
-                page_table=ring_tables if ring else page_table,
-                kv_lengths=kv_lengths, **index_kw,
+                page_table=(ring_tables if ring_pages and kind.ring
+                            else page_table),
+                kv_lengths=kv_lengths,
             )
-            if updated is not None and ring:
-                ring_kv = updated
-            elif updated is not None:
-                cache_kv = updated
-            if index:
-                index_kw["index_pool"] = index[0]
+            if updated is not None:
+                cache = cache.with_layer_state(kind, updated)
 
         if cfg.hc_mult > 1:
             # ... and the streams' sum is what the head reads
@@ -1154,30 +766,7 @@ class DecoderModule(nn.Module):
                 name="lm_head",
             )(x)
 
-        out_cache = None
-        if cache is not None and len(cache_kv) == 1:
-            out_cache = PagedKVCache(
-                k=None, v=None, page_table=page_table,
-                lengths=cache.lengths, latent=cache_kv[0])
-        elif cache is not None:
-            scales = dict(
-                k_scale=cache_kv[2] if len(cache_kv) == 4 else None,
-                v_scale=cache_kv[3] if len(cache_kv) == 4 else None,
-            )
-            if page_table is not None:
-                out_cache = PagedKVCache(
-                    k=cache_kv[0], v=cache_kv[1], page_table=page_table,
-                    lengths=cache.lengths, **scales,
-                    index_k=index_kw.get("index_pool"),
-                    **({} if ring_kv is None else
-                       {"ring_k": ring_kv[0], "ring_v": ring_kv[1]}),
-                )
-            else:
-                out_cache = KVCache(
-                    k=cache_kv[0], v=cache_kv[1], lengths=cache.lengths,
-                    **scales,
-                )
-        return logits, out_cache
+        return logits, cache
 
 
 def prefill_mask(attn_mask: jax.Array) -> jax.Array:

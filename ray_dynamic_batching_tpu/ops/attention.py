@@ -26,6 +26,10 @@ from typing import Callable, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_dynamic_batching_tpu.models.kv_state import (
+    dequantize_kv,
+    from_pool_rows,
+)
 from ray_dynamic_batching_tpu.ops.pallas_common import resolve_interpret
 from ray_dynamic_batching_tpu.utils.compile_ledger import current_program
 
@@ -255,7 +259,7 @@ def dot_product_attention(
     mask: broadcastable to [B, 1, Tq, Tk], True = attend.
 
     ``k_scale``/``v_scale`` [B, Tk, K]: k/v are int8 KV-cache codes
-    (models/decoder.py::KVCache). The decode kernel consumes the codes
+    (models/kv_state.py::KVCache). The decode kernel consumes the codes
     directly (1-byte scan, scales applied inside the dots); every other
     path dequantizes first and proceeds as usual.
 
@@ -282,7 +286,7 @@ def dot_product_attention(
     or None): the paged kernel takes both, and ``ops/kind_attention.py``
     every other read. ``heads_per_row`` > 1 (paged reads only): the pools
     are ``[L, P, ps, K // f, f * H]``, ``f`` KV heads side by side in a
-    row (``models/decoder.py::pool_heads_per_row``): the paged kernel
+    row (``models/kv_state.py::pool_heads_per_row``): the paged kernel
     reads them as they lie, the gather reshapes its pages' rows back to
     ``[.., K, H]``.
     """
@@ -414,7 +418,7 @@ def _dense_attention(
             declines.append("decode kernel: causal=True call (its "
                             "windows ride an explicit mask)")
         if k_scale is not None:
-            k, v = _dequantize(k, k_scale, q.dtype), _dequantize(
+            k, v = dequantize_kv(k, k_scale, q.dtype), dequantize_kv(
                 v, v_scale, q.dtype)
             k_scale = v_scale = None
         from ray_dynamic_batching_tpu.ops import flash_attention
@@ -441,7 +445,7 @@ def _dense_attention(
             f"pallas off: backend {_BACKEND!r} on "
             f"{jax.default_backend()}")
     if k_scale is not None:
-        k, v = _dequantize(k, k_scale, q.dtype), _dequantize(
+        k, v = dequantize_kv(k, k_scale, q.dtype), dequantize_kv(
             v, v_scale, q.dtype)
     _record(PATH_XLA, q, k, declines, gathered=gathered, sliding=sliding)
     return _xla_attention(q, k, v, causal=causal, mask=mask, scale=scale)
@@ -493,7 +497,7 @@ def _paged_attention(
             raise ValueError(
                 "a pool with several heads a row has no sink, no narrower "
                 "v row, no indexer and no scale planes "
-                "(models/decoder.py::pool_heads_per_row)")
+                "(models/kv_state.py::pool_heads_per_row)")
         kind = kind_record = {"heads_per_row": heads_per_row}
     if select is not None:
         from ray_dynamic_batching_tpu.ops import sparse_attention
@@ -577,15 +581,13 @@ def _paged_attention(
     def logical(g):  # [B, NP, ps, ...] -> [B, NP * ps, ...]
         return g.reshape((B, NP * ps) + g.shape[3:])
 
-    # Pool rows are lane-padded (models/decoder.py::pool_head_dim): the
+    # Pool rows are lane-padded (models/kv_state.py::pool_head_dim): the
     # slab view is cut back to the head AFTER the gather. Gathering the
     # head's lanes only (pool[layer, safe, :, :, :H]) reads half the
     # bytes on paper, but XLA then re-lays the whole pool out for that
     # gather: four pool-sized copies in the chunk program
     # (tools/pool_traffic.py). Rows that hold several heads side by side
     # hold no padding: the gathered pages are the slab view's own bytes.
-    from ray_dynamic_batching_tpu.models.decoder import from_pool_rows
-
     H = q.shape[-1]
     K = k.shape[3] * heads_per_row
     k_g = from_pool_rows(logical(k[layer, safe]), K, H)
@@ -603,15 +605,6 @@ def _paged_attention(
         k_scale=ks_g, v_scale=vs_g, declines=declines, gathered=True,
         sliding=sliding,
     )
-
-
-def _dequantize(codes: jax.Array, scales: jax.Array,
-                dtype) -> jax.Array:
-    # Deferred import (decoder imports this module): the dequant rule
-    # has exactly one definition, next to the quantizer it inverts.
-    from ray_dynamic_batching_tpu.models.decoder import dequantize_kv
-
-    return dequantize_kv(codes, scales, dtype)
 
 
 def _xla_attention(

@@ -589,8 +589,6 @@ class LLMDeployment:
         pool), not from a second formula. A 64-wide head that cannot pair
         off (an int8 pool, an odd head count) holds twice what
         ``kv_bytes_per_slot`` counts."""
-        import math
-
         import jax
 
         from ray_dynamic_batching_tpu.ops.tile_math import pages_for
@@ -599,15 +597,9 @@ class LLMDeployment:
         # a slot's ring (state by layer kind) is sized for the widest chunk
         buckets = self._prompt_buckets_for(max_len) or [
             b for b in DEFAULT_PROMPT_BUCKETS if b <= max_len]
-        pool = jax.eval_shape(lambda: model.make_paged_cache(
+        return jax.eval_shape(lambda: model.make_paged_cache(
             1, n, self.page_size, n * self.page_size,
-            widest_chunk=max(buckets, default=None), tp=tp))
-        return sum(
-            math.prod(x.shape) * x.dtype.itemsize
-            for x in (pool.k, pool.v, pool.k_scale, pool.v_scale,
-                      pool.index_k, pool.ring_k, pool.ring_v, pool.latent)
-            if x is not None
-        )
+            widest_chunk=max(buckets, default=None), tp=tp)).logical_bytes()
 
     def auto_num_slots(self, n_chips: int = 1,
                        max_len: Optional[int] = None,

@@ -106,7 +106,7 @@ def test_multi_tile_no_mask():
 
 
 def _quantize(x):
-    from ray_dynamic_batching_tpu.models.decoder import quantize_kv_rows
+    from ray_dynamic_batching_tpu.models.kv_state import quantize_kv_rows
 
     return quantize_kv_rows(x)
 
@@ -127,7 +127,7 @@ def test_int8_codes_match_dequantized_oracle():
         interpret=True,
     )
     assert out is not None, "int8 path declined"
-    from ray_dynamic_batching_tpu.models.decoder import dequantize_kv
+    from ray_dynamic_batching_tpu.models.kv_state import dequantize_kv
 
     ref = _xla_attention(
         q, dequantize_kv(k8, kscale, q.dtype),
@@ -159,7 +159,7 @@ def test_int8_multi_tile_spec_window():
         block_k=128, interpret=True,
     )
     assert out is not None
-    from ray_dynamic_batching_tpu.models.decoder import dequantize_kv
+    from ray_dynamic_batching_tpu.models.kv_state import dequantize_kv
 
     ref = _xla_attention(
         q, dequantize_kv(k8, kscale, q.dtype),
@@ -201,7 +201,7 @@ def test_int8_dispatch_reaches_kernel_and_matches(monkeypatch):
     finally:
         set_attention_backend("auto")
     assert calls == [True], "int8 decode did not engage the kernel"
-    from ray_dynamic_batching_tpu.models.decoder import dequantize_kv
+    from ray_dynamic_batching_tpu.models.kv_state import dequantize_kv
 
     ref = _xla_attention(
         q, dequantize_kv(k8, kscale, q.dtype),

@@ -16,9 +16,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_dynamic_batching_tpu.models.decoder import (
+from ray_dynamic_batching_tpu.models.decoder import paged_window_mask
+from ray_dynamic_batching_tpu.models.kv_state import (
     dequantize_kv,
-    paged_window_mask,
     pool_head_dim,
 )
 from ray_dynamic_batching_tpu.ops import decode_attention as da

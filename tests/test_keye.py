@@ -29,10 +29,8 @@ from ray_dynamic_batching_tpu.engine.queue import RequestQueue
 from ray_dynamic_batching_tpu.engine.request import Request
 from ray_dynamic_batching_tpu.models import decoder
 from ray_dynamic_batching_tpu.models.causal_lm import GPT2_MEDIUM, CausalLM
-from ray_dynamic_batching_tpu.models.decoder import (
-    DecoderConfig,
-    PagedKVCache,
-)
+from ray_dynamic_batching_tpu.models.decoder import DecoderConfig
+from ray_dynamic_batching_tpu.models.kv_state import PagedKVCache
 from ray_dynamic_batching_tpu.models.moe import MoEBlock, RoutingRule
 from ray_dynamic_batching_tpu.ops import attention as attn_ops
 from ray_dynamic_batching_tpu.ops import sparse_attention as sparse

@@ -18,9 +18,9 @@ import pytest
 pytestmark = pytest.mark.slow
 
 from ray_dynamic_batching_tpu.models.causal_lm import CausalLM, TINY_LM
-from ray_dynamic_batching_tpu.models.decoder import (
+from ray_dynamic_batching_tpu.models.decoder import prefill_mask
+from ray_dynamic_batching_tpu.models.kv_state import (
     dequantize_kv,
-    prefill_mask,
     quantize_kv_rows,
 )
 

@@ -33,11 +33,8 @@ from ray_dynamic_batching_tpu.engine.paging import PageAllocator
 from ray_dynamic_batching_tpu.engine.queue import RequestQueue
 from ray_dynamic_batching_tpu.engine.request import Request
 from ray_dynamic_batching_tpu.models.causal_lm import CausalLM
-from ray_dynamic_batching_tpu.models.decoder import (
-    DecoderConfig,
-    PagedKVCache,
-    ring_table,
-)
+from ray_dynamic_batching_tpu.models.decoder import DecoderConfig
+from ray_dynamic_batching_tpu.models.kv_state import PagedKVCache, ring_table
 from ray_dynamic_batching_tpu.models.moe import MoEBlock, RoutingRule
 from ray_dynamic_batching_tpu.ops import attention as attn_ops
 from ray_dynamic_batching_tpu.ops import decode_attention, kind_attention

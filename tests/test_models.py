@@ -9,7 +9,7 @@ pytestmark = pytest.mark.slow  # XLA-compile-heavy (fast lane excludes)
 
 from ray_dynamic_batching_tpu.models import registry
 from ray_dynamic_batching_tpu.models.base import get_model, param_path_specs
-from ray_dynamic_batching_tpu.models.decoder import KVCache
+from ray_dynamic_batching_tpu.models.kv_state import KVCache
 
 TINY_VISION = ["resnet18_tiny", "shufflenet_tiny", "vit_tiny", "efficientnet_tiny"]
 

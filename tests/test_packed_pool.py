@@ -1,6 +1,6 @@
 """Heads narrower than the 128 lanes lie side by side in a row of the paged
 pool (ISSUE 48, tier-1): ``[L, P, ps, K // f, 128]`` where ``f = 128 //
-head_dim`` whole heads pair off (``models/decoder.py::pool_heads_per_row``,
+head_dim`` whole heads pair off (``models/kv_state.py::pool_heads_per_row``,
 the ONE rule), the parent's lane-padded ``[L, P, ps, K, 128]`` everywhere
 else.
 
@@ -37,9 +37,11 @@ from ray_dynamic_batching_tpu.models.causal_lm import (
 )
 from ray_dynamic_batching_tpu.models.decoder import (
     DecoderConfig,
+    paged_window_mask,
+)
+from ray_dynamic_batching_tpu.models.kv_state import (
     fit_head_dim,
     from_pool_rows,
-    paged_window_mask,
     pool_heads_per_row,
     to_pool_rows,
 )

@@ -27,9 +27,9 @@ from ray_dynamic_batching_tpu.models import registry  # noqa: F401
 from ray_dynamic_batching_tpu.models.base import get_model
 from ray_dynamic_batching_tpu.models.decoder import (
     decode_mask,
-    dequantize_kv,
     paged_window_mask,
 )
+from ray_dynamic_batching_tpu.models.kv_state import dequantize_kv
 from ray_dynamic_batching_tpu.ops import decode_attention as da
 from ray_dynamic_batching_tpu.ops.attention import (
     _xla_attention,

@@ -36,10 +36,8 @@ from ray_dynamic_batching_tpu.engine.queue import RequestQueue
 from ray_dynamic_batching_tpu.engine.request import Request
 from ray_dynamic_batching_tpu.models import registry  # noqa: F401
 from ray_dynamic_batching_tpu.models.base import get_model
-from ray_dynamic_batching_tpu.models.decoder import (
-    dequantize_kv,
-    paged_window_mask,
-)
+from ray_dynamic_batching_tpu.models.decoder import paged_window_mask
+from ray_dynamic_batching_tpu.models.kv_state import dequantize_kv
 from ray_dynamic_batching_tpu.ops import decode_attention as da
 from ray_dynamic_batching_tpu.ops.attention import (
     _xla_attention,

@@ -20,10 +20,10 @@ from ray_dynamic_batching_tpu.engine.decode import DecodeEngine
 from ray_dynamic_batching_tpu.engine.queue import RequestQueue
 from ray_dynamic_batching_tpu.models import registry  # noqa: F401
 from ray_dynamic_batching_tpu.models.base import get_model
-from ray_dynamic_batching_tpu.models.decoder import (
+from ray_dynamic_batching_tpu.models.decoder import paged_window_mask
+from ray_dynamic_batching_tpu.models.kv_state import (
     dequantize_kv,
     fit_head_dim,
-    paged_window_mask,
     pool_head_dim,
 )
 from ray_dynamic_batching_tpu.ops import attention

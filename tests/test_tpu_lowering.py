@@ -194,7 +194,7 @@ class TestPagedKernelLowersForTPU:
     def _call(g, window, dtype, struct=jnp.zeros, sliding=0):
         """The jitted call and its arguments (``struct(shape, dtype)``
         makes each: arrays for an export, shapes for a compile)."""
-        from ray_dynamic_batching_tpu.models.decoder import pool_head_dim
+        from ray_dynamic_batching_tpu.models.kv_state import pool_head_dim
 
         ps = 128
         per_row = g.get("f", 1)     # KV heads side by side in a pool row
@@ -307,7 +307,7 @@ class TestPagedKernelCompilesForV5e:
                         "refuses to slice: no configuration has one")
         if dtype == jnp.int8 and self.CASES[case].get("f", 1) > 1:
             pytest.skip("an int8 pool keeps a head a row "
-                        "(models/decoder.py::pool_heads_per_row)")
+                        "(models/kv_state.py::pool_heads_per_row)")
         struct = lambda shape, dt: jax.ShapeDtypeStruct(
             shape, dt, sharding=one_chip)
         f, args = TestPagedKernelLowersForTPU._call(

@@ -33,10 +33,8 @@ from ray_dynamic_batching_tpu.engine.decode import (
 from ray_dynamic_batching_tpu.engine.queue import RequestQueue
 from ray_dynamic_batching_tpu.engine.request import Request
 from ray_dynamic_batching_tpu.models.causal_lm import CausalLM
-from ray_dynamic_batching_tpu.models.decoder import (
-    DecoderConfig,
-    PagedKVCache,
-)
+from ray_dynamic_batching_tpu.models.decoder import DecoderConfig
+from ray_dynamic_batching_tpu.models.kv_state import PagedKVCache
 from ray_dynamic_batching_tpu.models.moe import MoEBlock, RoutingRule
 from ray_dynamic_batching_tpu.ops import attention as attn_ops
 from ray_dynamic_batching_tpu.serve.llm import LLMDeployment

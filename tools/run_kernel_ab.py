@@ -159,7 +159,7 @@ def _time_paged(tag, L, P, B, NP, N, K, H, int8, iters, sliding=0):
     import jax
     import jax.numpy as jnp
 
-    from ray_dynamic_batching_tpu.models.decoder import (
+    from ray_dynamic_batching_tpu.models.kv_state import (
         pool_head_dim,
         pool_heads_per_row,
         quantize_kv_rows,
@@ -345,7 +345,7 @@ def _time_sparse(iters: int):
     import jax
     import jax.numpy as jnp
 
-    from ray_dynamic_batching_tpu.models.decoder import pool_head_dim
+    from ray_dynamic_batching_tpu.models.kv_state import pool_head_dim
     from ray_dynamic_batching_tpu.ops import attention as attn
     from ray_dynamic_batching_tpu.ops import sparse_attention as sparse
 
@@ -532,7 +532,7 @@ def main() -> int:
         v = jax.random.normal(ks[2], (B, S, K, H), jnp.bfloat16)
         kscale = vscale = None
         if int8_kv:
-            from ray_dynamic_batching_tpu.models.decoder import (
+            from ray_dynamic_batching_tpu.models.kv_state import (
                 quantize_kv_rows,
             )
 
