@@ -244,6 +244,8 @@ class _IssuedGroup(NamedTuple):
     queued_behind: int
     active: int
     pending: int
+    state_resets: int = 0           # rows that began their prompt (conv)
+    state_carries: int = 0          # rows begun from a carried state
 
 
 class _ChunkFields(NamedTuple):
@@ -257,7 +259,8 @@ class _ChunkFields(NamedTuple):
     table: Any       # [g, NP] the row's page-table row
     ring: Any        # [g, NP] its slot's ring table; None without rings
     meta_i: Any      # [g, 6] slot-or-sentinel, start, take_idx, top_k,
-    #                  seed, new_len
+    #                  seed, new_len (+ 1, a model with a conv state a
+    #                  slot: the row's slot, final chunk or not)
     meta_f: Any      # [g, 2] float32: temperature, top_p
     bias_ids: Any    # [g, E]
     bias_vals: Any   # [g, E] float32
@@ -324,7 +327,13 @@ class Turn(NamedTuple):
     carries ``kv_latent_rows``: the pool rows, summed over all slots and
     layers, that its first substep's scans read (whole live pages, by
     ``tile_math.live_pages``' rule, from the host's lengths). 0 for any
-    other model."""
+    other model.
+
+    A chunk group of a model with CONV layers (``PagedKVCache.conv_state``)
+    also carries ``state_resets``, its rows that began their prompt (the
+    program zeroed their slots' states), and ``state_carries``, its rows
+    that began from the state an earlier chunk of their train left. Their
+    sum is the chunks run. Both 0 for a scan and for any other model."""
 
     kind: str
     t_dispatch: float
@@ -349,6 +358,8 @@ class Turn(NamedTuple):
     queued_behind: int = 0
     kv_full_pages_live: int = 0
     kv_latent_rows: int = 0
+    state_resets: int = 0
+    state_carries: int = 0
 
 
 # An engine's prompt buckets where its builder names none.
@@ -462,7 +473,9 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
     ``kv_full_pages_live`` and ``kv_full_live_page_share``, the same two of
     the FULL layers' pages alone. A latent model's scans add
     ``kv_latent_rows`` (each weighed by its substeps): the pool rows its
-    decode scans read."""
+    decode scans read. A model with conv layers adds ``state_resets``,
+    ``state_carries`` and ``state_carried_chunk_share``: of the chunks run,
+    those that began from a carried state."""
     scans = [t for t in turns if t.kind == "turn"]
     out: Dict[str, Any] = {"dispatches": len(turns), "scans": len(scans),
                            "dropped": dropped}
@@ -481,6 +494,11 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
     latent_rows = sum(t.kv_latent_rows * t.substeps for t in scans)
     if latent_rows:
         out["kv_latent_rows"] = latent_rows
+    resets = sum(t.state_resets for t in turns)
+    carries = sum(t.state_carries for t in turns)
+    if resets or carries:
+        out.update(state_resets=resets, state_carries=carries,
+                   state_carried_chunk_share=carries / (resets + carries))
     rows_live = sum(t.kv_rows_live * t.substeps for t in scans)
     if rows_live:
         out["kv_rows_live"] = rows_live
@@ -584,7 +602,8 @@ KV_POOL_BYTES = m.Gauge(
     "rdb_decode_kv_pool_bytes",
     "Resident bytes of the KV state of one kind of layer (a model with "
     "state by layer kind): 'full' the paged pool, 'ring' the sliding "
-    "layers' rings; set once, at build", tag_keys=("model", "kind"),
+    "layers' rings, 'state' the conv layers' states; set once, at build",
+    tag_keys=("model", "kind"),
 )
 KV_PAGE_OCCUPANCY = m.Gauge(
     "rdb_decode_kv_page_occupancy",
@@ -863,7 +882,7 @@ class DecodeEngine:
                 w, 1, self.page_size, self._n_table_entries)
             for w in self._layer_windows]
         self._table_walked = (sum(self._layer_table_widths)
-                              / len(self._layer_table_widths))
+                              / max(1, len(self._layer_table_widths)))
         # Positions a selecting layer's indexer keeps a query (0: no layer
         # selects), and how many layers select.
         self._index_topk = int(getattr(cfg, "index_topk", 0) or 0)
@@ -873,6 +892,10 @@ class DecodeEngine:
         # Latent layers (0: k/v pairs), and how the state lies on the
         # device, once, for snapshot().
         self._latent_layers = self._cache.latent_layers
+        # A state a slot beside the pages (a model with conv layers): the
+        # chunk program is told each row's slot, and the ring counts the
+        # chunks that reset a state and those that carried one.
+        self._slot_state = self._cache.conv_state is not None
         self._pool_stats = self._cache.describe(cfg)
         for kind, n in self._pool_stats.get("bytes_by_kind", {}).items():
             KV_POOL_BYTES.set(n, tags={"model": model.name, "kind": kind})
@@ -1156,7 +1179,8 @@ class DecodeEngine:
                       kv_rows: Tuple[int, int] = (0, 0),
                       queued_behind: int = 0,
                       kv_full_pages_live: int = 0,
-                      kv_latent_rows: int = 0) -> Turn:
+                      kv_latent_rows: int = 0,
+                      state_turns: Tuple[int, int] = (0, 0)) -> Turn:
         """Append this dispatch's record to the turn ring (its work on the
         host is done: ``t_done`` is now). ``moe``: the dispatch's routing
         counters as fetched (``Turn``'s ``moe_*`` fields);
@@ -1170,6 +1194,7 @@ class DecodeEngine:
             int(self._len_host.sum()), self._idled,
             *(int(c) for c in moe[:3]), kv_pages_live, int(moe[3]),
             *kv_rows, queued_behind, kv_full_pages_live, kv_latent_rows,
+            *state_turns,
         )
         if len(self.turns) == self.turns.maxlen:
             self.turns_dropped += 1
@@ -1204,8 +1229,8 @@ class DecodeEngine:
             for w in set(self._layer_windows)}
         if len(live) == 1:
             return live[self._layer_windows[0]]
-        return sum(live[w] for w in self._layer_windows) / len(
-            self._layer_windows)
+        return sum(live[w] for w in self._layer_windows) / max(
+            1, len(self._layer_windows))
 
     def _kv_full_pages_live(self) -> int:
         """``Turn.kv_full_pages_live`` of the scan about to be dispatched;
@@ -1384,11 +1409,12 @@ class DecodeEngine:
     def _chunk_group_widths(self, W: int) -> Tuple[int, ...]:
         """The layout of a chunk group's one int32 buffer ``[g, cols]``, a
         row = ``tokens[W] | mask[W] | table[NP] | ring table[NP] (0 wide
-        unless the model keeps rings) | meta_i[6] | meta_f[2] |
-        bias_ids[E] | bias_vals[E]``: every width a shape the engine
-        holds."""
+        unless the model keeps rings) | meta_i[6, or 7 where the model
+        keeps a conv state a slot] | meta_f[2] | bias_ids[E] |
+        bias_vals[E]``: every width a shape the engine holds."""
         NP, E = self._n_table_entries, self.max_bias_entries
-        return (W, W, NP, NP if self._ring_pages else 0, 6, 2, E, E)
+        return (W, W, NP, NP if self._ring_pages else 0,
+                6 + self._slot_state, 2, E, E)
 
     def _cut_chunk_group(self, packed) -> _ChunkFields:
         """The fields of a chunk group's buffer
@@ -1427,6 +1453,8 @@ class DecodeEngine:
             f.ring[:] = ring_table(np.full(group, self.num_slots, np.int32),
                                    self._ring_pages, self._n_table_entries)
         f.meta_i[:, 0] = self.num_slots
+        if self._slot_state:
+            f.meta_i[:, 6] = self.num_slots
         f.meta_f[:, 1] = 1.0
         return packed, f
 
@@ -1463,6 +1491,10 @@ class DecodeEngine:
         if f.ring is not None:
             # state by layer kind: the rows' slots' ring tables
             rings["ring_tables"] = f.ring
+        if self._slot_state:
+            # conv layers: the rows' slots, whose states the program zeroes
+            # (a row at its prompt's start) or carries on, on the device
+            rings["state_slots"] = f.meta_i[:, 6]
         # An expert model's routing counters ride the ids fetch: [g + 4].
         taken, pools, *moe = self.model.prefill_chunk_paged(
             params, f.tokens, f.mask, cache, f.table, starts, take_idx,
@@ -2294,9 +2326,11 @@ class DecodeEngine:
                 # Non-final rows steer the lengths scatter to the
                 # sentinel slot: only the FINAL chunk publishes the
                 # verified length.
-                f.meta_i[i] = (
+                f.meta_i[i, :6] = (
                     t.slot_idx if final else self.num_slots, t.pos,
                     take - 1, t.opts["top_k"], t.opts["seed"], t.total)
+                if self._slot_state:
+                    f.meta_i[i, 6] = t.slot_idx
                 f.meta_f[i] = (t.opts["temperature"],
                                t.opts.get("top_p", 1.0))
                 f.bias_ids[i], f.bias_vals[i] = self._bias_arrays(t.opts)
@@ -2311,9 +2345,17 @@ class DecodeEngine:
             # routed tokens).
             packed[n:] = packed[0]
             f.mask[n:] = 0
+        # conv layers: rows that begin their prompt (the program zeroes
+        # their slots' states) and rows begun from a carried state
+        state_turns, state_attrs = (0, 0), {}
+        if self._slot_state:
+            carries = sum(1 for t in trains if t.pos > 0)
+            state_turns = (n - carries, carries)
+            state_attrs = dict(zip(("state_resets", "state_carries"),
+                                   state_turns))
         seq, behind = self._note_issue()
         t_dispatch = now_ms()
-        with self._phase("rdb.engine.prefill.dispatch"):
+        with self._phase("rdb.engine.prefill.dispatch", **state_attrs):
             first, self._cache = self._chunk_paged_fn(
                 self.params, jnp.asarray(packed), self._cache)
         t_issued = now_ms()
@@ -2321,7 +2363,7 @@ class DecodeEngine:
             t.pos = min(t.pos + W, t.total)
         PREFILL_CHUNKS.inc(n, tags={"model": self.model.name})
         return _IssuedGroup(first, seq, trains, finals, group, t_dispatch,
-                            t_issued, behind, active, pending)
+                            t_issued, behind, active, pending, *state_turns)
 
     def _complete_chunk_group(self, issued: _IssuedGroup) -> None:
         """What a dispatched group leaves to do: where rows ended their
@@ -2357,7 +2399,9 @@ class DecodeEngine:
                            t_fetched, 0,
                            issued.trains[0].C * len(issued.trains),
                            issued.active, issued.pending, moe,
-                           queued_behind=issued.queued_behind)
+                           queued_behind=issued.queued_behind,
+                           state_turns=(issued.state_resets,
+                                        issued.state_carries))
 
     def _retire_train(self, train: _ChunkTrain) -> None:
         if train in self._trains:
@@ -4000,6 +4044,12 @@ class DecodeEngine:
                    if self._ring_pages else {}),
                 **({"latent_rows_read": turns.get("kv_latent_rows", 0)}
                    if self._latent_layers else {}),
+                # conv layers: chunks that zeroed a slot's state and chunks
+                # begun from a carried one (the state's shape and bytes are
+                # ``conv_state`` / ``bytes_by_kind``, from ``describe``)
+                **({"state_resets": turns.get("state_resets", 0),
+                    "state_carries": turns.get("state_carries", 0)}
+                   if self._slot_state else {}),
                 **select,
             ),
             "page_journal": {

@@ -87,9 +87,10 @@ class CausalLM(ServableModel):
 
     @property
     def layer_windows(self) -> Tuple[int, ...]:
-        """Each layer's sliding window (0: it attends its whole prefix)."""
-        return tuple(self.cfg.layer_kind(i).window
-                     for i in range(self.cfg.num_layers))
+        """Each attention layer's sliding window (0: it attends its whole
+        prefix); a conv layer walks no table and is not listed."""
+        kinds = (self.cfg.layer_kind(i) for i in range(self.cfg.num_layers))
+        return tuple(k.window for k in kinds if not k.conv)
 
     def _forward(self, params, *args, moe_valid=None, **kwargs):
         """``module.apply``; with ``moe_valid`` [B, T] (an expert model's
@@ -266,6 +267,7 @@ class CausalLM(ServableModel):
         take_idx: jax.Array,   # [B] per-row logits row to return
         moe_counters: bool = False,
         ring_tables: Optional[jax.Array] = None,  # [B, NP] the rows' rings
+        state_slots: Optional[jax.Array] = None,  # [B] the rows' slots
     ) -> Tuple[jax.Array, ...]:
         """Pages-DIRECT chunked prefill: one chunk of B independent (and
         independently-positioned) prompt fills, written straight through
@@ -288,9 +290,25 @@ class CausalLM(ServableModel):
         :func:`routing_counters` over ``attn_mask``'s real tokens.
         ``ring_tables`` (a model with state by layer kind): each row's
         slot's ring table (``models/kv_state.py::ring_table``), through
-        which its sliding layers write and read."""
+        which its sliding layers write and read.
+        ``state_slots`` (a model with conv layers): each row's slot, whose
+        conv state the row starts from — ZEROS where the row starts its
+        prompt (``starts`` 0), whatever the slot's last tenant left — and
+        leaves at its true end (``attn_mask``'s real tokens); a row without
+        a real token (a filler, warm-up's) writes none back."""
         B, W = tokens.shape
         S = tables.shape[1] * cache.page_size
+        conv = {}
+        if cache.conv_state is not None:
+            states = cache.conv_state          # [L_conv, slots, K - 1, D]
+            n_slots = states.shape[1]
+            rows = jnp.take(states, jnp.minimum(state_slots, n_slots - 1),
+                            axis=1)
+            real = attn_mask.sum(axis=1).astype(jnp.int32)
+            conv["state_lens"] = real
+            # the layers see the ROWS' states; the slots' come back below
+            cache = cache.replace(conv_state=jnp.where(
+                (starts == 0)[None, :, None, None], 0, rows))
         positions = starts[:, None] + jnp.broadcast_to(
             jnp.arange(W)[None, :], (B, W)
         )
@@ -302,8 +320,12 @@ class CausalLM(ServableModel):
             params, tokens, positions, None, cache, scatter_writes=True,
             page_table=tables, kv_lengths=starts,
             moe_valid=attn_mask if moe_counters else None,
-            ring_tables=ring_tables,
+            ring_tables=ring_tables, **conv,
         )
+        if conv:
+            new_cache = new_cache.replace(conv_state=states.at[
+                :, jnp.where(real > 0, state_slots, n_slots)].set(
+                    new_cache.conv_state, mode="drop"))
         taken = jnp.take_along_axis(
             logits, take_idx[:, None, None], axis=1
         )[:, 0]
@@ -410,10 +432,13 @@ class CausalLM(ServableModel):
         in_bounds = cache.lengths < cache.capacity
         active = jnp.logical_and(active, in_bounds)
         positions = cache.lengths[:, None]
+        # a slot's conv state moves on only where the slot advances
+        conv = ({} if cache.conv_state is None
+                else {"state_lens": active.astype(jnp.int32)})
         logits, new_cache, *counters = self._forward(
             params, tokens, positions, None, cache,
             page_table=cache.page_table, kv_lengths=cache.lengths,
-            moe_valid=active[:, None] if moe_counters else None,
+            moe_valid=active[:, None] if moe_counters else None, **conv,
         )
         new_lengths = cache.lengths + active.astype(jnp.int32)
         return (logits[:, 0], new_cache.replace(lengths=new_lengths),
@@ -428,7 +453,10 @@ class CausalLM(ServableModel):
             kind = c.layer_kind(i)
             mlp = kind.mlp_dim * (
                 c.moe_top_k + c.moe_shared_experts if kind.sparse else 1)
-            if kind.latent:
+            if kind.conv:
+                # in (D -> 3D) and out (D -> D); the taps are no matmul
+                proj = 4 * c.d_model * c.d_model
+            elif kind.latent:
                 # the low-rank q and kv paths, keys and values expanded
                 nope = c.head_dim - c.rope_dim
                 proj = (c.d_model * (c.q_lora_rank + c.kv_lora_rank
@@ -445,7 +473,8 @@ class CausalLM(ServableModel):
                 proj + (3 if c.gated_mlp else 2) * c.d_model * mlp
             )
             # score+value flops per token, avg T/2 ctx * 2
-            attn = 4 * (min(T, 2 * kind.window) if kind.window else T) * (
+            attn = 0 if kind.conv else 4 * (
+                min(T, 2 * kind.window) if kind.window else T) * (
                 c.num_heads * c.head_dim)
             total += (per_tok + attn) * T
         return total + 2 * c.d_model * c.vocab_size * T

@@ -153,6 +153,13 @@ class DecoderConfig:
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
+    # A layer whose letter in ``layer_pattern`` is "C" mixes its sequence
+    # with a gated SHORT CONVOLUTION (models/short_conv.py), not attention:
+    # depthwise, causal, ``conv_kernel`` taps. It keeps no KV: its state is
+    # the last ``conv_kernel - 1`` inputs of the taps, a fixed size a slot
+    # (``PagedKVCache.conv_state``), and the paged pool holds the OTHER
+    # layers alone. 0: no layer is one.
+    conv_kernel: int = 0
 
     def __post_init__(self):
         object.__setattr__(self, "hc_res_clamp", tuple(self.hc_res_clamp))
@@ -193,6 +200,20 @@ class DecoderConfig:
             raise ValueError(
                 f"layer_pattern {self.layer_pattern!r}: letters are L "
                 "(sliding) and G (full)")
+        if ("C" in self.layer_pattern) != (self.conv_kernel >= 2):
+            raise ValueError(
+                f"layer_pattern {self.layer_pattern!r} with conv_kernel "
+                f"{self.conv_kernel}: a conv layer (C) needs its taps "
+                "(conv_kernel >= 2), and taps need a layer")
+        if self.conv_kernel and (
+                self.sliding_window or self.index_topk or self.latent
+                or self.hc_mult > 1 or set(self.layer_pattern) - set("CG")
+                or self.norm != "rms"):
+            raise ValueError(
+                "conv_kernel: conv layers (C) stand beside full attention "
+                "layers (G) of k/v pairs under RMSNorm; a window, an "
+                "indexer, a latent cache and residual streams are not "
+                "built beside them")
         if self.moe_first_expert + self.held_experts > self.num_experts:
             raise ValueError(
                 f"held experts [{self.moe_first_expert}, "
@@ -230,6 +251,16 @@ class DecoderConfig:
         return bool(self.sliding_window) and (
             self.layer_pattern[i % len(self.layer_pattern)] == "L")
 
+    def _convs(self, i: int) -> bool:
+        return bool(self.conv_kernel) and (
+            self.layer_pattern[i % len(self.layer_pattern)] == "C")
+
+    @property
+    def conv_layers(self) -> int:
+        """Layers whose mixer is a short convolution: they hold a state a
+        slot (``PagedKVCache.conv_state``) and no pages."""
+        return sum(1 for i in range(self.num_layers) if self._convs(i))
+
     def layer_kind(self, i: int) -> "LayerKind":
         """What layer ``i`` is: THE place a layer asks."""
         slides = self._slides(i)
@@ -239,6 +270,12 @@ class DecoderConfig:
             # its place among the layers of its own kind: its pool's layer
             by_kind = dict(ring=slides, pool_layer=sum(
                 1 for j in range(i) if self._slides(j) == slides))
+        elif self.conv_kernel:
+            # likewise: a conv layer's place in the state plane, an
+            # attention layer's among the layers that hold pages
+            conv = self._convs(i)
+            by_kind = dict(conv=conv, pool_layer=sum(
+                1 for j in range(i) if self._convs(j) == conv))
         return LayerKind(
             window=self.sliding_window if slides else 0,
             rope=self.pos == "rope" and (
@@ -258,6 +295,12 @@ class DecoderConfig:
         """How many layers keep a ring (``ring``) or pages (not)."""
         return sum(1 for i in range(self.num_layers)
                    if self._slides(i) == ring)
+
+    @property
+    def pool_layers(self) -> int:
+        """Layers of the paged pool where it is ONE pool: every layer but
+        the conv layers."""
+        return self.num_layers - self.conv_layers
 
 
 @dataclasses.dataclass(frozen=True)
@@ -282,6 +325,10 @@ class LayerKind:
     # Its attention is latent: its state is ONE row a position
     # (``PagedKVCache.latent``), no k/v pair.
     latent: bool = False
+    # Its mixer is a gated short convolution, not attention: its state is
+    # the taps' last inputs a slot (``PagedKVCache.conv_state``, layer
+    # ``pool_layer`` of it), no pages.
+    conv: bool = False
 
 
 def apply_rope(
@@ -352,6 +399,7 @@ class DecoderLayer(nn.Module):
         scatter_writes: bool = False,  # per-row writes at ``positions``
         page_table: Optional[jax.Array] = None,  # [B, NP]: paged decode
         kv_lengths: Optional[jax.Array] = None,  # [B] paged validity bound
+        state_lens: Optional[jax.Array] = None,  # [B] a conv layer's real rows
     ) -> Tuple[jax.Array, Optional[LayerState]]:
         """(x, the layer's state updated, or None without a cache)."""
         cfg = self.cfg
@@ -380,7 +428,13 @@ class DecoderLayer(nn.Module):
                 dtype=self.dtype, name=name)
         h, maps = hc("attn_hc")(x) if hc else (x, None)
         y = self._norm("attn_norm")(h).astype(self.dtype)
-        if kind.latent:
+        if kind.conv:
+            # a gated short convolution (its model alone loads the module)
+            from ray_dynamic_batching_tpu.models import short_conv
+
+            attn_out, new_cache = short_conv.mixer(
+                self, dense, kind, y, cache_kv, state_lens)
+        elif kind.latent:
             attn_out, new_cache = self._latent_attention(
                 y, positions, mask, cache_kv, token_mask, layer_idx,
                 page_table, kv_lengths)
@@ -389,7 +443,8 @@ class DecoderLayer(nn.Module):
                 dense, kind, y, positions, mask, cache_kv, token_mask,
                 layer_idx, write_start, scatter_writes, page_table,
                 kv_lengths)
-        attn_out = dense(cfg.d_model, "o", axis=(-2, -1))(attn_out)
+        if not kind.conv:
+            attn_out = dense(cfg.d_model, "o", axis=(-2, -1))(attn_out)
         x = hyper_connections.mix(x, attn_out, maps) if hc else x + attn_out
 
         h, maps = hc("mlp_hc")(x) if hc else (x, None)
@@ -697,6 +752,7 @@ class DecoderModule(nn.Module):
         page_table: Optional[jax.Array] = None,  # paged decode (T == 1)
         kv_lengths: Optional[jax.Array] = None,
         ring_tables: Optional[jax.Array] = None,  # [B, NP]: rows' rings
+        state_lens: Optional[jax.Array] = None,  # [B]: rows' real tokens
     ) -> Tuple[jax.Array, Optional[KVCache]]:
         cfg = self.cfg
         embed = nn.Embed(
@@ -732,6 +788,9 @@ class DecoderModule(nn.Module):
             ring_tables = ring_table(
                 jnp.arange(tokens.shape[0], dtype=jnp.int32),
                 ring_pages, page_table.shape[1])
+        # A conv layer's state moves on by its row's REAL tokens (a chunk's
+        # unpadded rows; 1 or 0 for a decode row that advances or not).
+        conv = {"state_lens": state_lens} if cfg.conv_kernel else {}
         for i in range(cfg.num_layers):
             kind = cfg.layer_kind(i)
             x, updated = DecoderLayer(
@@ -742,7 +801,7 @@ class DecoderModule(nn.Module):
                 write_start=write_start, scatter_writes=scatter_writes,
                 page_table=(ring_tables if ring_pages and kind.ring
                             else page_table),
-                kv_lengths=kv_lengths,
+                kv_lengths=kv_lengths, **conv,
             )
             if updated is not None:
                 cache = cache.with_layer_state(kind, updated)

@@ -35,10 +35,14 @@ def _is_int8(dtype: Any) -> bool:
 # --- what a kind of state cannot serve (ROADMAP D10) ---------------------------
 def state_kind(cfg: Any) -> str:
     """``"pair"`` (k/v pages for every layer; scale planes and index keys
-    ride them), ``"by_kind"`` (full layers' pages + sliding layers' rings)
-    or ``"latent"`` (one row a position, no k/v pair)."""
+    ride them), ``"by_kind"`` (full layers' pages + sliding layers' rings),
+    ``"latent"`` (one row a position, no k/v pair) or ``"conv"`` (k/v pages
+    for the attention layers + a fixed-size state a slot for the conv
+    layers)."""
     if getattr(cfg, "latent", False):
         return "latent"
+    if getattr(cfg, "conv_kernel", 0):
+        return "conv"
     return "by_kind" if getattr(cfg, "kv_by_kind", False) else "pair"
 
 
@@ -47,8 +51,11 @@ def state_kind(cfg: Any) -> str:
 # fabric) and ``slab`` (a slab cache under the layer), whose reason is the
 # whole message. What reaches a slot's KV by PAGE REFERENCE cannot work with
 # a ring, the slot's own; what moves or scales it as k/v pages of heads
-# cannot work with a latent row, which has neither.
-_STATE = {"by_kind": "state by layer kind", "latent": "a latent pool"}
+# cannot work with a latent row, which has neither. A conv layer's state is
+# the slot's own too, and is of its sequence's END: a page of a prefix holds
+# nothing of it, and no write to it can be undone without a snapshot.
+_STATE = {"by_kind": "state by layer kind", "latent": "a latent pool",
+          "conv": "a conv state a slot"}
 CANNOT: Dict[str, Dict[str, str]] = {
     "pair": {},
     "by_kind": {
@@ -89,6 +96,28 @@ CANNOT: Dict[str, Dict[str, str]] = {
         "slab": (
             "a latent layer's rows live in the paged pool "
             "(PagedKVCache.latent): the slab cache has none"),
+    },
+    "conv": {
+        "prefix_cache_size": (
+            "a borrowed page holds the attention layers' KV of a shared "
+            "prefix and nothing of the conv layers' state at its end: that "
+            "takes a snapshot of the state a prefix"),
+        "session_cache_size": (
+            "a stored session pins pages; the slot's conv state is "
+            "overwritten by its next tenant"),
+        "host_spill_pages": "it spills the prefix cache, which is refused",
+        "draft_model": (
+            "spec verify rolls a rejected tail back by its lengths; a conv "
+            "state moved on by the window cannot be moved back without a "
+            "snapshot"),
+        "mesh": "the conv state has no sharding layout",
+        "kv_dtype int8": "the conv state has no scale plane",
+        "parcel": (
+            "{name}: the page fabric moves a stream as the pages of its "
+            "table; the conv layers' state a slot is not among them"),
+        "slab": (
+            "a conv layer's state is the paged cache's "
+            "(PagedKVCache.conv_state): the slab cache has none"),
     },
 }
 
@@ -132,6 +161,12 @@ def kv_bytes_per_slot(cfg: "DecoderConfig", dtype: Any, kv_dtype: Any,
         return (c.layers_of(False) * S * c.num_kv_heads * row
                 + c.layers_of(True) * min(S, c.sliding_window)
                 * (c.sliding_kv_heads or c.num_kv_heads) * row)
+    if c.conv_kernel:
+        # the attention layers a position; the conv layers their taps'
+        # last inputs, whatever the length
+        return (c.pool_layers * S * 2 * c.num_kv_heads * per_row
+                + c.conv_layers * (c.conv_kernel - 1) * c.d_model
+                * jnp.dtype(dtype).itemsize)
     return c.num_layers * S * (2 * c.num_kv_heads * per_row + index_row)
 
 
@@ -147,15 +182,18 @@ class LayerState(NamedTuple):
     v_scale: Optional[jax.Array] = None
     index_k: Optional[jax.Array] = None
     latent: Optional[jax.Array] = None
+    conv_state: Optional[jax.Array] = None  # a conv layer's: the rows' states
 
 
 class Plane(NamedTuple):
-    """One per-position array of a paged cache (``PagedKVCache.planes``)."""
+    """One array of a paged cache's state (``PagedKVCache.planes``)."""
 
     name: str     # the cache's field
     array: Any
-    table: str    # what pages it: "pages" (the slot's table) | "ring"
-    kind: str     # what its bytes count under: "full" | "ring" | "latent"
+    # what finds a position in it: "pages" (the slot's table) | "ring" |
+    # "slot" (no position: ONE state a slot, whatever its length)
+    table: str
+    kind: str     # its bytes count under: "full" | "ring" | "latent" | "state"
     heads: bool   # rows are heads (to_pool_rows / from_pool_rows) or flat
 
 
@@ -167,6 +205,7 @@ _PLANES = (
     ("index_k", "pages", False),
     ("ring_k", "ring", True), ("ring_v", "ring", True),
     ("latent", "pages", False),
+    ("conv_state", "slot", False),
 )
 
 
@@ -276,7 +315,16 @@ class PagedKVCache:
     ``k`` and ``v`` are None and ``latent`` ``[L, P, page_size, Wp]`` holds
     one row a position a layer, ``[c_kv | k_r | 0]`` (``Wp``:
     ``ops/latent_attention.py::row_width``), with NO head axis, paged with
-    the same table. None for every other model."""
+    the same table. None for every other model.
+
+    A model with CONV layers (``DecoderConfig.conv_kernel``): ``k``/``v``
+    hold its attention layers only (``L`` their count) and ``conv_state``
+    ``[L_conv, B, conv_kernel - 1, D]`` the conv layers' taps' last inputs:
+    ONE state a slot a layer, no pages, no table, as large whatever the
+    slot's length. It is of the slot's sequence at its END, so the engine's
+    chunk program zeroes it where a prompt starts (a conv layer reads it
+    unconditionally at position 0; a ring's stale rows are never attended)
+    and hands it from chunk to chunk. None for every other model."""
 
     k: Optional[jax.Array]
     v: Optional[jax.Array]
@@ -288,6 +336,7 @@ class PagedKVCache:
     ring_k: Optional[jax.Array] = None
     ring_v: Optional[jax.Array] = None
     latent: Optional[jax.Array] = None
+    conv_state: Optional[jax.Array] = None
 
     @staticmethod
     def zeros(
@@ -353,7 +402,13 @@ class PagedKVCache:
             )
         f = pool_heads_per_row(cfg.head_dim, cfg.num_kv_heads, dtype, tp,
                                indexed=bool(cfg.index_topk))
-        shape = (cfg.num_layers, num_pages, page_size,
+        conv = {}
+        if cfg.conv_kernel:
+            conv["conv_state"] = jnp.zeros(
+                (cfg.conv_layers, batch_size, cfg.conv_kernel - 1,
+                 cfg.d_model), dtype)
+        # every layer, but for a model's conv layers: they hold no pages
+        shape = (cfg.pool_layers, num_pages, page_size,
                  cfg.num_kv_heads // f, pool_head_dim(cfg.head_dim * f))
         return PagedKVCache(
             k=jnp.zeros(shape, dtype=dtype),
@@ -364,6 +419,7 @@ class PagedKVCache:
             index_k=jnp.zeros(
                 shape[:3] + (pool_head_dim(cfg.index_head_dim),),
                 index_dtype) if cfg.index_topk else None,
+            **conv,
         )
 
     @property
@@ -403,22 +459,25 @@ class PagedKVCache:
         return 0 if self.latent is None else self.latent.shape[0]
 
     def planes(self) -> Tuple[Plane, ...]:
-        """The per-position arrays this cache holds, in the order of its
+        """The arrays of state this cache holds, in the order of its
         fields: every array leaf but ``page_table`` and ``lengths``. The
         scale planes and index keys count under their pool's kind."""
         pool = "full" if self.latent is None else "latent"
+        kinds = {"pages": pool, "ring": "ring", "slot": "state"}
         return tuple(
-            Plane(name, getattr(self, name), table,
-                  "ring" if table == "ring" else pool, heads)
+            Plane(name, getattr(self, name), table, kinds[table], heads)
             for name, table, heads in _PLANES
             if getattr(self, name) is not None)
 
     # --- a layer's share ---------------------------------------------------
     def layer_state(self, kind: "LayerKind") -> "LayerState":
         """What a layer of ``kind`` reads and writes: the sliding layers'
-        ring where state is by layer kind, else the paged pools."""
+        ring where state is by layer kind, a conv layer's states, else the
+        paged pools."""
         if kind.ring:
             return LayerState(self.ring_k, self.ring_v)
+        if kind.conv:
+            return LayerState(conv_state=self.conv_state)
         return LayerState(self.k, self.v, self.k_scale, self.v_scale,
                           self.index_k, self.latent)
 
@@ -426,7 +485,11 @@ class PagedKVCache:
                          updated: "LayerState") -> "PagedKVCache":
         if kind.ring:
             return self.replace(ring_k=updated.k, ring_v=updated.v)
-        return self.replace(**updated._asdict())
+        if kind.conv:
+            return self.replace(conv_state=updated.conv_state)
+        pools = updated._asdict()
+        del pools["conv_state"]     # an attention layer is handed none
+        return self.replace(**pools)
 
     # --- bytes ---------------------------------------------------------------
     def resident_bytes(self) -> int:
@@ -435,7 +498,8 @@ class PagedKVCache:
 
     def bytes_by_kind(self) -> Dict[str, int]:
         """:meth:`resident_bytes` by the planes' kind: ``full`` the paged
-        pool, ``ring`` the sliding layers' rings, ``latent`` the rows."""
+        pool, ``ring`` the sliding layers' rings, ``latent`` the rows,
+        ``state`` the conv layers' states."""
         out: Dict[str, int] = {}
         for p in self.planes():
             out[p.kind] = out.get(p.kind, 0) + (
@@ -471,6 +535,15 @@ class PagedKVCache:
                 kind="latent", shape=list(rows.shape),
                 row_width=rows.shape[-1],
                 row_bytes=rows.shape[-1] * rows.dtype.itemsize,
+                bytes_by_kind=self.bytes_by_kind())
+        if self.conv_state is not None:
+            state = self.conv_state
+            out.update(
+                kind="conv", pool_layers=self.k.shape[0],
+                conv_state={
+                    "shape": list(state.shape), "dtype": str(state.dtype),
+                    "bytes_per_slot": math.prod(state.shape[2:])
+                    * state.shape[0] * state.dtype.itemsize},
                 bytes_by_kind=self.bytes_by_kind())
         if self.index_k is not None:
             out["index_pool"] = {

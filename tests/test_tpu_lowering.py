@@ -763,6 +763,113 @@ class TestLatentProgramsCompileForV5e:
         assert "_hc_sinkhorn" in chunk.as_text()
 
 
+class TestHybridProgramsCompileForV5e:
+    """``lfm2-24b-a2b-ep8-1chip``'s programs at the published widths, 8 of
+    its 40 layers (CCGC CCGC: six conv layers, two pool layers, the first
+    two dense and the rest of experts), for a described v5e: the decode
+    step takes the dense paged kernel over two heads a pool row at GQA 32/8;
+    the widest chunk program gathers its pages where they lie (a gather of
+    4-row positions out of a pool of more than one layer made XLA lay the
+    WHOLE pool out anew, twice an attention layer:
+    ``ops/attention.py::_pages``), so nothing but the in-place page write
+    makes an array of the pool's size; the conv state is written where it
+    lies; and the operations ``short_conv_in_proj_dev_share_pct.batch``'s
+    pattern takes (one width: 3 x hidden) are the conv mixers' first product
+    and its streamed weights (the TPU's trace carries no scope). Nothing
+    runs: ``tools/short_conv_ab.py`` on the chip says what the mixer costs."""
+
+    def test_the_programs_compile_and_the_pattern_is_the_mixers_operations(
+            self, one_chip, monkeypatch):
+        import json
+        import re
+        from pathlib import Path
+        from types import SimpleNamespace
+
+        from benchmark import trace_reduce
+        from ray_dynamic_batching_tpu.models.causal_lm import CausalLM
+        from ray_dynamic_batching_tpu.models.decoder import DecoderConfig
+        from ray_dynamic_batching_tpu.ops import attention as attn_ops
+
+        root = Path(__file__).resolve().parents[1] / "benchmark"
+        cfg = json.loads((root / "configs"
+                          / "lfm2-24b-a2b-ep8-1chip.json").read_text())
+        rx = re.compile(json.loads((
+            root / "layer_metrics"
+            / "short_conv_in_proj_dev_share_pct.batch.json"
+        ).read_text())["args"]["op"])
+        llm = cfg["deployment"]["llm"]
+        dc = dict(cfg["program"]["decoder_config"], num_layers=8)
+        m = CausalLM(DecoderConfig(**dc), name="m", dtype=jnp.bfloat16)
+        B, ps, P = llm["num_slots"], llm["page_size"], llm["kv_pool_pages"]
+        W, NP = max(llm["prompt_buckets"]), llm["max_len"] // ps
+        struct = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dt, sharding=one_chip)
+        cache = jax.tree_util.tree_map(
+            lambda x: struct(x.shape, x.dtype),
+            jax.eval_shape(lambda: m.make_paged_cache(
+                B, P, ps, llm["max_len"])))
+        assert cache.k.shape == (2, P, ps, 4, 128)
+        assert cache.conv_state.shape == (6, B, 2, 2048)
+        p = jax.tree_util.tree_map(
+            lambda x: struct(x.shape, jnp.bfloat16),
+            jax.eval_shape(m.init, jax.random.PRNGKey(0)))
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        attn_ops.clear_attention_paths()
+        compile_ = TestLatentProgramsCompileForV5e._compile
+        g = 2
+        chunk = compile_(
+            lambda *a: m.prefill_chunk_paged(
+                *a[:-1], moe_counters=True, state_slots=a[-1]),
+            p, struct((g, W), jnp.int32), struct((g, W), jnp.int32), cache,
+            struct((g, NP), jnp.int32), struct((g,), jnp.int32),
+            struct((g,), jnp.int32), struct((g,), jnp.int32), donate=(3,))
+        decode = compile_(
+            lambda *a: m.decode_step_paged(*a, moe_counters=True),
+            p, struct((B, 1), jnp.int32), cache, struct((B,), jnp.bool_),
+            donate=(2,))
+        said = {(r.q_shape[1], r.describe()) for r in attn_ops.attention_paths()
+                if r.path != "short_conv"}
+        assert said == {
+            (W, "gather-then-flash kernel"),
+            (1, "paged kernel (stacked pool, 2 heads a row)")}
+        assert {r.describe() for r in attn_ops.attention_paths()
+                if r.path == "short_conv"} == {
+            "short convolution, 3 taps in XLA, a state a slot (no pages)"}
+        pool = rf"bf16\[2,{P},{ps},4,128\]"
+        for compiled in (chunk, decode):
+            text = compiled.as_text()
+            made = re.findall(rf"= {pool}\{{[^}}]*\}} ([\w\-]+)\(", text)
+            assert set(made) <= {"parameter", "scatter", "fusion",
+                                 "bitcast", "get-tuple-element"}, made
+            # the chunk's rows and scores, never a copy of the pool (0.5 GB
+            # here, 2.7 GB at the cell's 10 pool layers)
+            assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
+            comp = None
+            taken = set()
+            for line in text.splitlines():
+                opened = re.match(r"^(ENTRY )?%?([\w\.\-]+) \(.*\{\s*$",
+                                  line)
+                if opened:
+                    comp = opened.group(2)
+                    continue
+                if (comp is None or not line.startswith("  ")
+                        or comp.startswith(("fused_computation", "region"))):
+                    continue
+                line = line.strip().removeprefix("ROOT ")
+                if " parameter(" in line:
+                    continue
+                name = trace_reduce.stable_name(SimpleNamespace(name=line))
+                op_name = re.search(r'op_name="([^"]*)"', line)
+                if rx.search(name) and op_name:
+                    # the mixer's own first product, under its scope
+                    assert re.search(r"short_conv|conv_in",
+                                     op_name.group(1)), (
+                        name, op_name.group(1))
+                    taken.add(name)
+            assert taken, "the pattern takes nothing of this program"
+        assert "_paged_decode_attention" in decode.as_text()
+
+
 class TestSelectionsOperationsInTheCompiledPrograms:
     """``sparse_select_dev_share_pct.batch`` finds the index scan and the
     top-k in a device trace by the HLO lines of the cell's programs (the

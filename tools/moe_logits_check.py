@@ -33,7 +33,11 @@ of thresholds the share of positions the reference would excuse as undecided
 reference, to show what the gap reads when something is wrong:
 ``drop_bias`` (the router's selection bias left out of the choice),
 ``drop_gate_scale`` (the gates not multiplied by the routed scaling factor),
-``int8_pool`` (the KV pool in int8 codes) or, for a model with an indexer,
+``int8_pool`` (the KV pool in int8 codes), ``float8_reference`` (with
+``--harness`` alone: the program served as it is and the REFERENCE given the
+weights rounded to float8_e4m3, the nearest precision below the one the
+configuration states: a comparison that passes it holds nothing) or, for a
+model with an indexer,
 ``dense_attention`` (``index_topk`` as large as the cache: every query
 attends every cached position, the selection switched off and nothing else).
 For such a model the gap is also printed for the positions PAST
@@ -69,7 +73,7 @@ import sys
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 CONTROLS = ("none", "drop_bias", "drop_gate_scale", "int8_pool",
-            "dense_attention")
+            "dense_attention", "float8_reference")
 GAPS = (0.002, 0.004, 0.006, 2.0 ** -7, 0.012, 0.016)
 # A selecting model's served logits past ``index_topk`` positions: the root
 # mean square gap to the reference the served path must stay within. At the
@@ -105,6 +109,7 @@ def harness_checks(cfg, control: str, seeds, few_programs: bool) -> int:
     import types
 
     import jax
+    import numpy as np
 
     from benchmark import run, weights
 
@@ -141,12 +146,21 @@ def harness_checks(cfg, control: str, seeds, few_programs: bool) -> int:
         finally:
             weights.make_params = make_params
         try:
+            view = dep.view.view
             if biases:
-                view = dep.view.view
                 dep.view = types.SimpleNamespace(view=lambda params, config: (
                     view(jax.tree_util.tree_map_with_path(
                         lambda path, x: biases.get(
                             jax.tree_util.keystr(path), x), params), config)))
+            if control == "float8_reference":
+                # kept on the host, a leaf at a time: the chip does not hold
+                # the weights twice beside a deployment that fills it
+                dep.view = types.SimpleNamespace(view=lambda params, config: (
+                    jax.tree_util.tree_map(
+                        lambda x: np.asarray(x.astype(
+                            jax.numpy.float8_e4m3fn).astype(x.dtype))
+                        if jax.numpy.issubdtype(x.dtype, jax.numpy.floating)
+                        else np.asarray(x), view(params, config))))
             got = run.reference_check(dep, seed)
         finally:
             dep.close()
