@@ -37,7 +37,10 @@ Forms of the paged read (``sparse_forms()`` says which each program took):
   nothing. A head block narrower than 8 (4 key heads) is read through a
   free view of the pool in which two positions' heads are one (8, 128)
   tile (:func:`_page_fold`), so its page arrives as whole tiles and folds
-  in one contraction with no relayout, as a block of 8 heads does.
+  in one contraction with no relayout, as a block of 8 heads does; such a
+  block folds SEVERAL live pages an online-softmax update
+  (:func:`_fold_pages`: the update's serial chain, not the page's copy,
+  paced a page a fold).
   A kernel of its own, here, and not an option of the paged
   kernel: a Mosaic module carries its source lines, and a line moved in
   that file compiles every program of every other model again. The fold,
@@ -251,11 +254,14 @@ def paged_decode(q, k, v, page_table, kv_lengths, layer: int,
         chosen = exact_topk_mask(scores, win, select.topk)[:, 0]
     with jax.named_scope("sparse_attend"):
         K = k.shape[3]
-        fold = _page_fold(_pick_heads_block(K), q.shape[2] // K, ps)
+        kb, G = _pick_heads_block(K), q.shape[2] // K
+        fold = _page_fold(kb, G, ps)
+        pages, depth = _walk(kb, G, ps, k.shape[4], k.dtype.itemsize, NP)
         page = (f"{K}-head page as {K * fold}-row tiles, "
                 if fold > 1 else "")
-        _record(f"{FORM_MASK} (live pages, {page}the selection a page's "
-                "row)")
+        _record(f"{FORM_MASK} (live pages, {page}{pages} "
+                f"page{'s' if pages > 1 else ''} a fold, a ring of {depth}, "
+                "the selection a fold's row)")
         return sparse_paged_decode_attention(
             q, k, v, page_table, kv_lengths, chosen, layer=layer, scale=scale)
 
@@ -385,6 +391,26 @@ def _page_fold(kb: int, G: int, ps: int) -> int:
     return tile_math.page_view_fold(kb, ps) if _own_fold(kb, G, ps) else 1
 
 
+def _fold_pages(kb: int, G: int, ps: int, H: int, itemsize: int,
+                NP: int) -> int:
+    """Live pages the kernel folds in one online-softmax update
+    (``tile_math.sparse_fold_pages``: 4, 2 or 1 by the table's width and
+    the VMEM budget where the fold is :func:`_own_fold`'s; 1 elsewhere)."""
+    return tile_math.sparse_fold_pages(
+        ps, kb, H, itemsize, G, NP, _page_fold(kb, G, ps),
+        _own_fold(kb, G, ps))
+
+
+def _walk(kb: int, G: int, ps: int, H: int, itemsize: int, NP: int):
+    """``(pages a fold, ring slots of that many pages)`` of the kernel's
+    walk at these shapes: what it traces and what ``sparse_forms()``
+    says."""
+    pages = _fold_pages(kb, G, ps, H, itemsize, NP)
+    return pages, tile_math.sparse_walk_depth(
+        ps, kb, H, itemsize, G, NP, _page_fold(kb, G, ps), _flat(kb, G, ps),
+        pages)
+
+
 def sparse_paged_decode_attention(
     q: jax.Array,            # [B, 1, N, H]
     k: jax.Array,            # [L, P, ps, K, Hk] the stacked pool, whole
@@ -412,15 +438,21 @@ def sparse_paged_decode_attention(
     sel = chosen.reshape(B, NP, ps).astype(jnp.int32)
     if _flat(kb, G, ps):
         sel = jnp.repeat(sel, kb, axis=2)
+    # a fold's pages lie one after another: their rows of the selection
+    # are ONE row of the operand (free: the same bytes)
+    pages, depth = _walk(kb, G, ps, Hk, k.dtype.itemsize, NP)
+    sel = sel.reshape(B, NP // pages, -1)
     out = _sparse_paged_decode_attention(
         q_r, k, v, page_table.astype(jnp.int32),
         kv_lengths.astype(jnp.int32), jnp.full((1,), layer, jnp.int32), sel,
-        fold=_page_fold(kb, G, ps), scale=float(scale),
+        fold=_page_fold(kb, G, ps), pages=pages, depth=depth,
+        scale=float(scale),
         interpret=bool(resolve_interpret(interpret)))
     return out[..., :H].reshape(B, 1, N, H)
 
 
-@functools.partial(jax.jit, static_argnames=("fold", "scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=(
+    "fold", "pages", "depth", "scale", "interpret"))
 def _sparse_paged_decode_attention(
     q: jax.Array,           # [B, K, G, H]
     k: jax.Array,           # [L, P, ps, K, H]
@@ -428,20 +460,30 @@ def _sparse_paged_decode_attention(
     page_table: jax.Array,  # [B, NP] int32, sentinel P
     lengths: jax.Array,     # [B] int32: attends pos <= lengths[b]
     layer: jax.Array,       # [1] int32
-    sel: jax.Array,         # [B, NP, cols] int32, != 0: selected
+    sel: jax.Array,         # [B, NP // pages, pages * cols] int32, != 0
     *,
     fold: int,              # _page_fold: > 1 reads the pool's tile view
+    pages: int,             # _walk: live pages an online-softmax update,
+    depth: int,             # ... and the ring's slots of that many pages
     scale: float,
     interpret: bool,
 ) -> jax.Array:
     """``decode_attention._paged_decode_attention`` at one query row a
     slot and a bf16 pool, with ``sel``: the same (slot, head block) grid,
-    the same ring of ``depth`` pages copied by the kernel itself and
-    running on across steps, the same cursor in SMEM; page ``p``'s fold is
-    handed ``pos <= length`` AND row ``p`` of the slot's selection. With
-    ``fold`` > 1 the two pools are read through their tile view (a reshape
-    the compiler takes as a bitcast) and the ring holds a page as
-    [ps // fold, kb * fold, H]: whole (8, 128) tiles."""
+    the same ring copied by the kernel itself and running on across steps,
+    the same cursor in SMEM. With ``fold`` > 1 the two pools are read
+    through their tile view (a reshape the compiler takes as a bitcast)
+    and the ring holds a page as [ps // fold, kb * fold, H]: whole (8, 128)
+    tiles.
+
+    The walk's item is a GROUP of ``pages`` live pages, one after another
+    in a ring slot, each copied once by its own DMA, ``depth - 1`` groups
+    in flight behind the one being folded; a group is ONE fold (one score
+    product, one running-max update, one rescale, one value product)
+    handed ``pos <= length`` AND the group's row of the slot's selection. A
+    slot of ``count`` live pages runs ``ceil(count / pages)`` folds; the
+    last group's tail repeats the slot's last live page (finite rows, never
+    an unwritten ring slot), at positions past the length."""
     B, K, R, H = q.shape
     L, P, ps = k.shape[:3]
     NP = page_table.shape[1]
@@ -450,41 +492,47 @@ def _sparse_paged_decode_attention(
     steps = B * nj
     flat = _flat(kb, R, ps)
     own_fold = _own_fold(kb, R, ps)
-    depth = tile_math.sparse_walk_depth(ps, kb, H, k.dtype.itemsize, R, NP,
-                                        fold, flat)
     ahead = depth - 1
     page = (ps // fold, kb * fold, H)       # as the ring holds it
+    span = pages * ps                       # positions a group covers
     if fold > 1:                            # nj == 1: the block is all K
         k, v = k.reshape(L, P, *page), v.reshape(L, P, *page)
 
     def bounds(b, len_ref):
-        return tile_math.live_pages(len_ref[b], 1, 0, ps, NP)
+        """A slot's live pages and the groups they make."""
+        _, count = tile_math.live_pages(len_ref[b], 1, 0, ps, NP)
+        return count, (count + (pages - 1)) // pages
 
     def kernel(pt_ref, len_ref, ly_ref, q_ref, sel_ref, k_hbm, v_hbm,
                o_ref, k_buf, v_buf, sem, cur, m_ref, l_ref, acc_ref):
         s = pl.program_id(0) * nj + pl.program_id(1)
 
-        def copies(t, page, slot):
+        def copies(t, count, group, slot):
             b, j = (t, 0) if nj == 1 else (t // nj, t % nj)
-            phys = jnp.minimum(pt_ref[b, page], P - 1)
             heads = (slice(None) if nj == 1
                      else pl.ds(pl.multiple_of(j * kb, kb), kb))
-            return [pltpu.make_async_copy(
-                hbm.at[ly_ref[0], pl.ds(phys, 1), :, heads, :],
-                buf.at[pl.ds(slot, 1)], sem.at[n, slot])
-                for n, (hbm, buf) in enumerate(
-                    ((k_hbm, k_buf), (v_hbm, v_buf)))]
+            out = []
+            for r in range(pages):
+                col = jnp.minimum(group * pages + r, count - 1)
+                phys = jnp.minimum(pt_ref[b, col], P - 1)
+                out += [pltpu.make_async_copy(
+                    hbm.at[ly_ref[0], pl.ds(phys, 1), :, heads, :],
+                    buf.at[pl.ds(slot, 1), pl.ds(r * page[0], page[0])],
+                    sem.at[n, slot, r])
+                    for n, (hbm, buf) in enumerate(
+                        ((k_hbm, k_buf), (v_hbm, v_buf)))]
+            return out
 
         def start_next(t, i, slot):
             t_in = jnp.minimum(t, steps - 1)
-            first, count = bounds(t_in if nj == 1 else t_in // nj, len_ref)
+            count, groups = bounds(t_in if nj == 1 else t_in // nj, len_ref)
 
             @pl.when(t < steps)
             def _start():
-                for c in copies(t_in, first + i, slot):
+                for c in copies(t_in, count, i, slot):
                     c.start()
 
-            roll = i + 1 >= count
+            roll = i + 1 >= groups
             return (jnp.where(roll, t + 1, t),
                     jnp.where(roll, 0, i + 1))
 
@@ -497,24 +545,24 @@ def _sparse_paged_decode_attention(
             cur[1], cur[2] = cursor
 
         b = pl.program_id(0)
-        first, count = bounds(b, len_ref)
+        count, groups = bounds(b, len_ref)
         base = cur[0]
         _scan_begin(m_ref, l_ref, acc_ref)
 
-        def fold(i, cursor):
+        def fold_group(i, cursor):
             cursor = start_next(*cursor, (base + i + ahead) % depth)
-            page, slot = first + i, (base + i) % depth
-            for c in copies(s, page, slot):
+            slot = (base + i) % depth
+            for c in copies(s, count, i, slot):
                 c.wait()
-            shape = (kb * R, ps * kb) if flat else (R, ps)
+            shape = (kb * R, span * kb) if flat else (R, span)
             col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-            pos = page * ps + (col // kb if flat else col)
-            # the page's row of the selection, broadcast over the rows
-            picked = sel_ref[0, pl.ds(page, 1), :] != 0
+            pos = i * span + (col // kb if flat else col)
+            # the group's row of the selection, broadcast over the rows
+            picked = sel_ref[0, pl.ds(i, 1), :] != 0
             valid = (pos <= len_ref[b]) & picked
             tiles = k_buf.at[pl.ds(slot, 1)], v_buf.at[pl.ds(slot, 1)]
             if own_fold:
-                _fold_flat(q_ref, *tiles, m_ref, l_ref, acc_ref, ps=ps,
+                _fold_flat(q_ref, *tiles, m_ref, l_ref, acc_ref, ps=span,
                            kb=kb, valid=valid, scale=scale)
             else:
                 _accumulate_tile(q_ref, *tiles, None, None, m_ref, l_ref,
@@ -522,8 +570,8 @@ def _sparse_paged_decode_attention(
             return cursor
 
         cur[1], cur[2] = jax.lax.fori_loop(
-            0, count, fold, (cur[1], cur[2]))
-        cur[0] = (base + count) % depth
+            0, groups, fold_group, (cur[1], cur[2]))
+        cur[0] = (base + groups) % depth
         _scan_end(o_ref, m_ref, l_ref, acc_ref)
 
     rows_spec = pl.BlockSpec(
@@ -538,8 +586,9 @@ def _sparse_paged_decode_attention(
                          lambda b, j, pt, ln, ly: (b, 0, 0)),
             in_hbm, in_hbm],
         out_specs=rows_spec,
-        scratch_shapes=[pltpu.VMEM((depth,) + page, k.dtype)] * 2 + [
-            pltpu.SemaphoreType.DMA((2, depth)),
+        scratch_shapes=[pltpu.VMEM(
+            (depth, pages * page[0]) + page[1:], k.dtype)] * 2 + [
+            pltpu.SemaphoreType.DMA((2, depth, pages)),
             pltpu.SMEM((3,), jnp.int32),
         ] + _scratch(kb, R, H, flat),
     )

@@ -391,32 +391,65 @@ def sparse_tile_bytes(
     fold: int = 1,
     flat: bool = True,
     depth: int = DOUBLE_BUFFER,
+    pages: int = 1,
 ) -> int:
     """VMEM footprint of one grid step of the sparse mask-form kernel
     (``ops/sparse_attention.py::_sparse_paged_decode_attention``), the
-    tiles AS THEY LIE: a ring of ``depth`` K and V pages in scratch, each
-    [page_size // fold, kb * fold, H] (``fold`` > 1: the view above, whole
-    tiles where [page_size, kb, H] pads ``kb`` up to the dtype's tile
-    height); the slot's selection [n_entries, cols] int32, a pipelined
-    block; and, where the fold is ``flat`` (every head in one
-    contraction), its two f32 score tiles [kb * G, cols]. The padding is
+    tiles AS THEY LIE: a ring of ``depth`` slots of ``pages`` K and V
+    pages in scratch, each page [page_size // fold, kb * fold, H]
+    (``fold`` > 1: the view above, whole tiles where [page_size, kb, H]
+    pads ``kb`` up to the dtype's tile height); the slot's selection
+    [n_entries // pages, pages * cols] int32, a pipelined block; and,
+    where the fold is ``flat`` (every head in one contraction), its two
+    f32 score tiles [kb * G, pages * cols]. The padding is
     :func:`padded_block_bytes`'s, the one the ``vmem-budget`` rule prices
     a scratch shape by (bf16's 8-row tiles count as 16: never under)."""
-    cols = page_size * kb if flat else page_size
+    cols = pages * (page_size * kb if flat else page_size)
     ring = 2 * padded_block_bytes(
-        (1, page_size // fold, kb * fold, H), kv_itemsize)
-    sel = DOUBLE_BUFFER * padded_block_bytes((1, n_entries, cols), 4)
+        (1, pages * page_size // fold, kb * fold, H), kv_itemsize)
+    sel = DOUBLE_BUFFER * padded_block_bytes(
+        (1, n_entries // pages, cols), 4)
     scores = 2 * padded_block_bytes((kb * G, cols), 4) if flat else 0
     return depth * ring + sel + scores
 
 
 def sparse_walk_depth(page_size: int, kb: int, H: int, kv_itemsize: int,
                       G: int, n_entries: int, fold: int = 1,
-                      flat: bool = True) -> int:
+                      flat: bool = True, pages: int = 1) -> int:
     """:func:`paged_walk_depth` for the sparse kernel's ring, priced by
     :func:`sparse_tile_bytes`."""
     for depth in range(PAGED_WALK_MAX_DEPTH, DOUBLE_BUFFER, -1):
         if sparse_tile_bytes(page_size, kb, H, kv_itemsize, G, n_entries,
-                             fold, flat, depth) <= VMEM_BLOCK_BUDGET_BYTES:
+                             fold, flat, depth, pages
+                             ) <= VMEM_BLOCK_BUDGET_BYTES:
             return depth
     return DOUBLE_BUFFER
+
+
+# The most live pages the sparse kernel folds in ONE online-softmax
+# update. A page's fold is a serial chain (score product, running max,
+# exp, value product, rescale of the accumulator) that the next page's
+# waits on; with a narrow head block the chain, not the page's copy, sets
+# the pace, and folding several pages an update pays it once for all of
+# them (the latent kernel's ``FOLD_PAGES``; the chip's readings at 1, 2
+# and 4 pages of 4 heads: PERF.md, PR 51).
+SPARSE_FOLD_MAX_PAGES = 4
+
+
+def sparse_fold_pages(page_size: int, kb: int, H: int, kv_itemsize: int,
+                      G: int, n_entries: int, fold: int = 1,
+                      own: bool = True) -> int:
+    """Live pages the sparse kernel folds an update, from the shapes
+    alone: the largest of 4, 2, 1 that divides the table's ``n_entries``
+    (the selection is handed in a row a fold) and whose ring still takes
+    a depth past ``DOUBLE_BUFFER`` within ``VMEM_BLOCK_BUDGET_BYTES``
+    (:func:`sparse_tile_bytes`). Only where the fold is ``own``, the flat
+    form for a narrow head block (``decode_attention._fold_flat``: a
+    column is position ``c // kb`` of head ``c % kb`` however many pages
+    lie one after another); the shared forms keep a page a fold."""
+    pages = SPARSE_FOLD_MAX_PAGES if own else 1
+    while pages > 1 and (n_entries % pages or sparse_tile_bytes(
+            page_size, kb, H, kv_itemsize, G, n_entries, fold, True,
+            DOUBLE_BUFFER + 1, pages) > VMEM_BLOCK_BUDGET_BYTES):
+        pages //= 2
+    return pages

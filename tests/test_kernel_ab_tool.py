@@ -103,6 +103,11 @@ def test_the_sparse_geometry_is_the_benchmarks_configuration():
     ("rows", "mask_untiled", 0.62, 0.66),     # ... measured again, PR 39
     ("rows", "mask", 0.44, 0.49),             # as whole (8, 128) tiles
     ("rows", "gather", -0.01, 0.01),          # flat in the length
+    # PR 51: live pages an online-softmax update (the chain a page is what
+    # held 0.47 where the copy is 0.32)
+    ("pages_a_fold", "mask_fold1", 0.44, 0.49),
+    ("pages_a_fold", "mask_fold2", 0.33, 0.37),
+    ("pages_a_fold", "mask_fold4", 0.33, 0.37),     # shipped
 ])
 def test_the_recorded_rows_slope_is_the_page_cost(rows, form, low, high):
     """``page_slopes_us`` over the committed capture: microseconds a live
@@ -116,8 +121,74 @@ def test_the_recorded_rows_slope_is_the_page_cost(rows, form, low, high):
     _, L, P, B, NP, *_ = ab.SPARSE_GEOMETRY
     pages = {n: int((ab.sparse_case(0, B, NP, P, n)[1] // ab.PAGE + 1).sum())
              for n in ab.SPARSE_LENGTHS}
+    kept = record[rows]["rows"] if rows == "pages_a_fold" else record[rows]
     slopes = ab.page_slopes_us(
-        [dict(r, pages_live=pages[r["length"]]) for r in record[rows]])
+        [dict(r, pages_live=pages[r["length"]]) for r in kept])
     assert low < slopes[form] < high
     if rows == "rows":
         assert slopes[form] == pytest.approx(record["page_us"][form])
+    if rows == "pages_a_fold":
+        assert slopes[form] == pytest.approx(record[rows]["page_us"][form])
+
+
+# --- ``--sparse --pages-a-fold``: the mask form at each width of its fold ------
+def test_pages_a_fold_names_a_form_a_width():
+    assert ab.fold_forms("1,2,4") == (
+        "floor", "mask_fold1", "mask_fold2", "mask_fold4")
+    assert ab.fold_forms("4") == ("floor", "mask_fold4")
+    for bad in ("", "0,2", "two"):
+        with pytest.raises((SystemExit, ValueError)):
+            ab.fold_forms(bad)
+
+
+def test_the_sweep_patches_the_picker_and_puts_it_back(monkeypatch):
+    """The sweep's rows on the CPU at a tiny geometry (the kernel
+    interpreted: no timing is read): every width's answer is the floor's,
+    each form took the width its name says, and the picker is the
+    module's own again afterwards."""
+    from ray_dynamic_batching_tpu.ops import attention as attn
+    from ray_dynamic_batching_tpu.ops import sparse_attention as sparse
+
+    monkeypatch.setattr(ab, "SPARSE_GEOMETRY", (
+        "tiny", 2, 16, 2, 4, 8, 4, 128, 2, 8, 40))
+    monkeypatch.setattr(ab, "SPARSE_LENGTHS", (140, 500))
+    picker, taken = sparse._fold_pages, []
+    inner = sparse._sparse_paged_decode_attention
+    monkeypatch.setattr(
+        sparse, "_sparse_paged_decode_attention",
+        lambda *a, **kw: taken.append(kw["pages"]) or inner(*a, **kw))
+    attn.set_attention_backend("pallas")
+    try:
+        rows = ab._time_sparse(1, ab.fold_forms("1,2,4"), samples=1)
+    finally:
+        attn.set_attention_backend("auto")
+    assert sparse._fold_pages is picker
+    assert [(r["form"], r["length"]) for r in rows] == [
+        (f, n) for f in ab.fold_forms("1,2,4") for n in (140, 500)]
+    assert all(r["max_abs_diff"] < 2e-2 for r in rows)
+    # traced once a form: the one-layer chain's read, the program's two
+    assert taken == [1] * 3 + [2] * 3 + [4] * 3
+    assert set(ab.page_slopes_us(rows)) == set(ab.fold_forms("1,2,4"))
+
+
+def test_the_sweep_is_one_key_of_the_file(monkeypatch, tmp_path, capsys):
+    import json
+
+    rows = [{"geometry": "g", "form": f, "length": n, "layer_us": us,
+             "pages_live": p, "rows_selected": 1, "rows_live": 2,
+             "max_abs_diff": 0.0}
+            for f, slope in (("floor", 0.25), ("mask_fold4", 0.4))
+            for n, p, us in ((1, 100, 50 + 100 * slope),
+                             (2, 300, 50 + 300 * slope))]
+    monkeypatch.setattr(ab, "_time_sparse", lambda iters, forms: [
+        r for r in rows if r["form"] in forms])
+    (tmp_path / "s.json").write_text(json.dumps(
+        {"note": "kept", "rows": ["as they were"]}))
+    ab.sparse_main(str(tmp_path), "s.json", 3, "4")
+    record = json.loads((tmp_path / "s.json").read_text())
+    assert record["note"] == "kept" and record["rows"] == ["as they were"]
+    sweep = record["pages_a_fold"]
+    assert sweep["iters"] == 3 and len(sweep["rows"]) == 4
+    assert sweep["page_us"] == pytest.approx(
+        {"floor": 0.25, "mask_fold4": 0.4})
+    assert "mask_fold4: 0.400 us a live page" in capsys.readouterr().out

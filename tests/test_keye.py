@@ -372,6 +372,124 @@ def test_a_narrow_page_is_read_as_whole_tiles(
     assert (f"{kv}{VIEW}" in took[0]) == (fold > 1), took
 
 
+# --- several live pages an online-softmax update ---------------------------------
+FOLD_PAGE, FOLD_TABLE = 128, 8
+
+
+def _fold_case(case: str, pages: int):
+    """Lengths of a batch (one stream of groups across its slots) and the
+    live pages a selection leaves wholly unchosen, by slot."""
+    ps = FOLD_PAGE
+    return {
+        "one_page": ([5, ps - 2, 3 * ps + 1], {}),
+        "a_fold_exactly": ([pages * ps - 3, 2 * pages * ps - 1, 7], {}),
+        "a_fold_and_a_page": ([pages * ps + 4, pages * ps, 6 * ps + 9], {}),
+        "a_pages_last_position": ([ps - 1, 3 * ps - 1,
+                                   FOLD_TABLE * ps - 1], {}),
+        "an_idle_slot": ([0, 5 * ps + 17, 0, 2 * ps], {}),
+        "a_page_unchosen": ([4 * ps + 30, 7 * ps + 2, 2 * ps + 1],
+                            {0: [0, 3], 1: [5, 6], 2: [1]}),
+    }[case]
+
+
+@pytest.mark.parametrize("pages", [1, 2, 4])
+@pytest.mark.parametrize("case", [
+    "one_page", "a_fold_exactly", "a_fold_and_a_page",
+    "a_pages_last_position", "an_idle_slot", "a_page_unchosen"])
+def test_a_fold_of_several_pages_is_the_same_sum(case, pages, monkeypatch):
+    """ISSUE 51: the mask form's kernel (interpreted) folding ``pages``
+    live pages an online-softmax update against float32 arithmetic a slot
+    and a head at a time, and against itself at a page a fold: a slot
+    with one live page, with a fold's pages exactly, with one more (a
+    short last group, whose tail repeats the last live page behind the
+    length bound), a length at a page's last position, an idle slot, and
+    a selection that leaves whole live pages unchosen. Entries past the
+    last live page are the sentinel."""
+    lengths, unchosen = _fold_case(case, pages)
+    rng = np.random.default_rng(zlib.crc32(f"{case}/{pages}".encode()))
+    ps, NP, H, heads, kv, n_pages, layer = (
+        FOLD_PAGE, FOLD_TABLE, 128, 8, 4, 40, 1)
+    lengths = np.asarray(lengths)
+    B = len(lengths)
+    q = jnp.asarray(rng.normal(size=(B, 1, heads, H)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(2, n_pages, ps, kv, H)),
+                        jnp.float32) for _ in range(2))
+    table = rng.permutation(n_pages)[:B * NP].reshape(B, NP)
+    for b in range(B):
+        table[b, lengths[b] // ps + 1:] = n_pages
+    chosen = rng.random((B, NP * ps)) < 0.3
+    chosen[np.arange(B), lengths] = True            # never an empty row
+    for b, gone in unchosen.items():
+        for page in gone:
+            assert page < lengths[b] // ps          # live, not the last
+            chosen[b, page * ps:(page + 1) * ps] = False
+
+    def kernel(n):
+        with monkeypatch.context() as picked:
+            picked.setattr(sparse, "_fold_pages", lambda *a: n)
+            return np.asarray(sparse.sparse_paged_decode_attention(
+                q, k, v, jnp.asarray(table, jnp.int32),
+                jnp.asarray(lengths, jnp.int32), jnp.asarray(chosen),
+                layer=layer, interpret=True), np.float32)
+
+    got = kernel(pages)
+    np.testing.assert_allclose(
+        got, _selected_attention(q, k, v, table, lengths, chosen, layer),
+        rtol=2e-5, atol=2e-5)
+    # another order of rescaling, the same sum
+    np.testing.assert_allclose(got, kernel(1), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("NP, kv, pages", [
+    (8, 4, 4),      # a table four divides
+    (6, 4, 2),      # ... two only
+    (7, 2, 1),      # ... neither
+    (8, 8, 1),      # 8 heads: the shared flat fold keeps a page a fold
+])
+def test_the_shapes_pick_the_pages_a_fold(NP, kv, pages):
+    """The dispatcher's own call: the mask form at the width its shapes
+    pick (``tile_math.sparse_fold_pages``) against the floor on ragged
+    lengths, and ``sparse_forms()`` saying what the program folds."""
+    from ray_dynamic_batching_tpu.utils import compile_ledger
+
+    rng = np.random.default_rng(NP * 16 + kv)
+    ps, H, heads, n_pages, layer = FOLD_PAGE, 128, 16, 48, 1
+    lengths = np.asarray([0, ps - 1, 3 * ps + 7, NP * ps - 1, 5 * ps])
+    B = len(lengths)
+    assert sparse._fold_pages(kv, heads // kv, ps, H, 4, NP) == pages
+    q = jnp.asarray(rng.normal(size=(B, 1, heads, H)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(2, n_pages, ps, kv, H)),
+                        jnp.float32) for _ in range(2))
+    table = rng.permutation(n_pages)[:B * NP].reshape(B, NP)
+    for b in range(B):
+        table[b, lengths[b] // ps + 1:] = n_pages
+    select = sparse.Selection(
+        q=jnp.asarray(rng.normal(size=(B, 1, 2, 8)), jnp.float32),
+        w=jnp.asarray(rng.normal(size=(B, 1, 2)), jnp.float32),
+        pool=jnp.asarray(rng.normal(size=(2, n_pages, ps, 128)),
+                         jnp.float32), topk=200)
+    name = f"pages_a_fold_{NP}_{kv}"
+    out = {}
+    for form, backend in ((sparse.FORM_FLOOR, "xla"),
+                          (sparse.FORM_MASK, "pallas")):
+        attn_ops.set_attention_backend(backend)
+        try:
+            out[form] = np.asarray(compile_ledger.instrument(
+                f"{name}_{form}", attn_ops.dot_product_attention)(
+                q, k, v, page_table=jnp.asarray(table, jnp.int32),
+                kv_lengths=jnp.asarray(lengths, jnp.int32), layer=layer,
+                select=select))
+        finally:
+            attn_ops.set_attention_backend("auto")
+    np.testing.assert_allclose(out[sparse.FORM_MASK], out[sparse.FORM_FLOOR],
+                               rtol=2e-5, atol=2e-5)
+    took = [f for f in sparse.sparse_forms()
+            if f.startswith(f"{name}_{sparse.FORM_MASK}:")]
+    assert len(took) == 1 and (
+        f"{pages} page{'s' if pages > 1 else ''} a fold, a ring of 3"
+        in took[0]), took
+
+
 def test_mask_form_declines_by_name(monkeypatch):
     q = jnp.zeros((2, 1, 8, 128))
     k = jnp.zeros((1, 4, 128, 4, 128))

@@ -1232,6 +1232,59 @@ class TestSharedTileMath:
             rules=["vmem-budget"])
         assert rules_found(report) == []
 
+    @pytest.mark.parametrize("kb", [4, 2])
+    @pytest.mark.parametrize("pages", [1, 2, 4])
+    def test_a_fold_of_several_pages_is_priced_as_it_lies(self, kb, pages):
+        """ISSUE 51: the sparse kernel's ring holds ``pages`` pages a
+        slot, its selection block a row a fold, its score tiles a fold's
+        columns: the runtime's price and the linter's standalone copy are
+        one number over the same grid, and the ring's part is ``pages``
+        times a page's."""
+        lm = tile_math_module()
+        ps, H, G, NP = 128, 128, 32 // kb, 144
+        f = tm.page_view_fold(kb, ps)
+        for depth in (2, 3):
+            for fold in (1, f):
+                assert lm.sparse_tile_bytes(
+                    ps, kb, H, 2, G, NP, fold, True, depth, pages
+                ) == tm.sparse_tile_bytes(
+                    ps, kb, H, 2, G, NP, fold, True, depth, pages)
+        ring = lambda n: (  # noqa: E731 (ring alone: less depth 0)
+            tm.sparse_tile_bytes(ps, kb, H, 2, G, NP, f, True, 3, n)
+            - tm.sparse_tile_bytes(ps, kb, H, 2, G, NP, f, True, 0, n))
+        assert ring(pages) == pages * ring(1)
+        assert lm.sparse_fold_pages(ps, kb, H, 2, G, NP, f) \
+            == tm.sparse_fold_pages(ps, kb, H, 2, G, NP, f) == 4
+        assert lm.sparse_walk_depth(ps, kb, H, 2, G, NP, f, True, pages) \
+            == tm.sparse_walk_depth(ps, kb, H, 2, G, NP, f, True, pages) == 3
+
+    def test_the_cells_shapes_fold_four_pages_in_a_ring_of_three(self):
+        """Keye's shapes (a page of 128, 4 key heads of 128, 8 query rows
+        a head, a table of 144): (4, 3), ~7 MB of the 15 MB budget. The
+        picker falls by halves: a table 4 does not divide, a ring that no
+        longer fits a depth past the double buffer, a fold that is not
+        the narrow block's own."""
+        ps, kb, H, G, NP, f = 128, 4, 128, 8, 144, 2
+        pages = tm.sparse_fold_pages(ps, kb, H, 2, G, NP, f)
+        depth = tm.sparse_walk_depth(ps, kb, H, 2, G, NP, f, True, pages)
+        assert (pages, depth) == (tm.SPARSE_FOLD_MAX_PAGES, 3) == (4, 3)
+        took = tm.sparse_tile_bytes(ps, kb, H, 2, G, NP, f, True, depth,
+                                    pages)
+        # 3 x 2 x 4 pages of 256 KB + the selection 2 x 40 x 2048 x 4
+        # + two [32, 2048] f32 score tiles
+        assert took == (3 * 2 * 4 * 256 + 640 + 512) * 1024 \
+            < tm.VMEM_BLOCK_BUDGET_BYTES // 2
+        assert [tm.sparse_fold_pages(ps, kb, H, 2, G, n, f)
+                for n in (146, 145, 8, 4, 2, 1)] == [2, 1, 4, 4, 2, 1]
+        assert tm.sparse_fold_pages(ps, kb, H, 2, G, NP, f, own=False) == 1
+        # a head so wide that four pages' ring of three busts the budget
+        wide = 512
+        assert tm.sparse_tile_bytes(ps, kb, wide, 2, G, NP, f, True, 3, 4) \
+            > tm.VMEM_BLOCK_BUDGET_BYTES
+        assert tm.sparse_fold_pages(ps, kb, wide, 2, G, NP, f) == 2
+        assert tm.sparse_tile_bytes(ps, kb, wide, 2, G, NP, f, True, 3, 2) \
+            <= tm.VMEM_BLOCK_BUDGET_BYTES
+
     def test_shard_heads_agreement_pin(self):
         # ROADMAP item 2: the per-shard footprint rule (a head-sharded
         # paged kernel budgets K/tp heads; an indivisible head axis

@@ -429,8 +429,11 @@ class TestSparseKernelCompilesForV5e:
     (``_mask_form_declines``). Nothing runs:
     ``tools/run_kernel_ab.py --sparse`` on the chip says what it costs."""
 
-    @pytest.mark.parametrize("kv", [4, 8])
-    def test_the_mask_form_compiles(self, kv, one_chip):
+    @pytest.mark.parametrize("kv, pages", [
+        (4, 4),     # what the cell's shapes pick: four pages a fold
+        (4, 2), (4, 1),     # the A/B tool's other widths (picker patched)
+        (8, 1)])
+    def test_the_mask_form_compiles(self, kv, pages, one_chip, monkeypatch):
         import re
 
         from jax.experimental.compilation_cache import compilation_cache
@@ -438,6 +441,10 @@ class TestSparseKernelCompilesForV5e:
         from ray_dynamic_batching_tpu.ops import sparse_attention as sparse
 
         B, NP, ps, H, N, L, P = 24, 144, 128, 128, 32, 8, 3456
+        if (kv, pages) in ((4, 4), (8, 1)):     # from the shapes alone
+            assert sparse._walk(kv, N // kv, ps, H, 2, NP) == (pages, 3)
+        else:
+            monkeypatch.setattr(sparse, "_fold_pages", lambda *a: pages)
         struct = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
             shape, dt, sharding=one_chip)
 

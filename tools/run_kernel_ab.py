@@ -38,9 +38,15 @@ the page relaid in every fold) and a GATHER form that lives here
 (:func:`gather_form_decode`: the selected rows only; slower below ~30k
 positions a slot, so the program does not carry it). ``page_us`` is the
 slope of a form's three rows: microseconds a live page of a slot adds.
+``--sparse --pages-a-fold 1,2,4`` times the mask form alone at each of those
+widths of its fold (``mask_fold<n>``: the kernel's picker,
+``sparse._fold_pages``, patched to ``n`` live pages an online-softmax
+update) beside the floor they are compared with, and writes the rows and
+their slopes under ``pages_a_fold`` of the file, leaving its other keys.
 
 Usage: python tools/run_kernel_ab.py [out_dir] [--iters N] [--paged|--sparse]
                                      [--only tag1,tag2] [--out-name F]
+                                     [--pages-a-fold n1,n2]
 Writes <out_dir>/<F> (default kernel_ab.json, paged_steps.json with
 ``--paged``, in profiles/tpu_v5e) and prints one JSON summary line.
 ``--only`` restricts to named geometries (a couple of geometries are ~2
@@ -100,6 +106,16 @@ SPARSE_GEOMETRY = ("keye-vl2-30b-ep8-1chip", 8, 3456, 24, 144, 32, 4, 128,
                    16, 64, 2048)
 SPARSE_LENGTHS = (4608, 9216, 18300)
 SPARSE_FORMS = ("floor", "mask", "mask_untiled", "gather")
+FOLD_FORM = "mask_fold"     # + n: the mask form at n pages a fold
+
+
+def fold_forms(widths: str):
+    """``--pages-a-fold``'s forms: the floor (every row's reference) and
+    the mask form at each width of ``"1,2,4"``, in the order given."""
+    pages = [int(w) for w in widths.split(",")]
+    if any(n < 1 for n in pages):
+        raise SystemExit(f"--pages-a-fold: not widths: {widths!r}")
+    return ("floor",) + tuple(f"{FOLD_FORM}{n}" for n in pages)
 
 
 def sparse_case(seed: int, B: int, NP: int, P: int, length: int):
@@ -337,7 +353,7 @@ def gather_form_decode(q, k, v, page_table, kv_lengths, layer: int, select):
         declines=["A/B tool: the gather form"], gathered=True)
 
 
-def _time_sparse(iters: int):
+def _time_sparse(iters: int, forms=SPARSE_FORMS, samples: int = 5):
     """Rows (one a form a length) of a selecting layer's decode read: us a
     layer of a program that chains one read a layer (scores, top-k and
     attention together: what a decode substep pays a layer), and the worst
@@ -378,29 +394,32 @@ def _time_sparse(iters: int):
              for n in SPARSE_LENGTHS]
     rows, refs = [], {}
     declines, page_fold = sparse._mask_form_declines, sparse._page_fold
-    for form in SPARSE_FORMS:
+    fold_pages = sparse._fold_pages
+    for form in forms:
         if form == "floor":     # what a read the kernel declines takes
             sparse._mask_form_declines = lambda *a: "A/B tool: the floor"
         if form == "mask_untiled":  # the page as it lies: [ps, 4, H]
             sparse._page_fold = lambda *a: 1
+        if form.startswith(FOLD_FORM):
+            sparse._fold_pages = lambda *a, n=int(form[len(FOLD_FORM):]): n
         one, program = chain(form, [L - 1]), chain(form, range(L))
         try:
             for n, table, lengths in cases:
                 out = one(q, k, v, pool, table, lengths)
                 refs.setdefault(n, out)            # the floor comes first
                 program(q, k, v, pool, table, lengths).block_until_ready()
-                samples = []
-                for _ in range(5):
+                took = []
+                for _ in range(samples):
                     t0 = time.perf_counter()
                     for _ in range(iters):
                         res = program(q, k, v, pool, table, lengths)
                     res.block_until_ready()
-                    samples.append(
+                    took.append(
                         (time.perf_counter() - t0) * 1e6 / (iters * L))
                 rows.append({
                     "geometry": tag, "form": form, "length": n,
-                    "layer_us": statistics.median(samples),
-                    "layer_us_min_max": [min(samples), max(samples)],
+                    "layer_us": statistics.median(took),
+                    "layer_us_min_max": [min(took), max(took)],
                     "rows_live": int(lengths.sum()) + B,
                     "pages_live": int((lengths // PAGE + 1).sum()),
                     "rows_selected": int(jnp.minimum(
@@ -412,6 +431,7 @@ def _time_sparse(iters: int):
         finally:
             sparse._mask_form_declines = declines
             sparse._page_fold = page_fold
+            sparse._fold_pages = fold_pages
     return rows
 
 
@@ -427,33 +447,42 @@ def page_slopes_us(rows) -> dict:
     return out
 
 
-def sparse_main(out_dir: str, out_name: str, iters: int) -> int:
+def sparse_main(out_dir: str, out_name: str, iters: int,
+                widths: str = "") -> int:
     import jax
 
     backend = jax.default_backend()
-    rows = _time_sparse(iters)
+    rows = _time_sparse(iters, fold_forms(widths) if widths else SPARSE_FORMS)
+    slopes = page_slopes_us(rows)
     record = {"backend": backend,
               "device_kind": jax.devices()[0].device_kind,
               "captured": time.strftime("%Y%m%dT%H%M%S"), "iters": iters,
               "geometry": SPARSE_GEOMETRY[0], "rows": rows,
-              "page_us": page_slopes_us(rows)}
+              "page_us": slopes}
+    path = os.path.join(out_dir, out_name)
+    if widths:      # the sweep is one key of the file; the rest stays
+        kept = {}
+        if os.path.exists(path):
+            with open(path) as f:
+                kept = json.load(f)
+        record = dict(kept, pages_a_fold=record)
     for r in rows:
         print(f"{r['geometry']}: {r['form']} at {r['length']} positions: "
               f"{r['layer_us']:.1f} us a layer ({r['rows_selected']} of "
               f"{r['rows_live']} rows selected), max |form - floor| "
               f"{r['max_abs_diff']:.2e}", flush=True)
-    for form, us in record["page_us"].items():
+    for form, us in slopes.items():
         print(f"{SPARSE_GEOMETRY[0]}: {form}: {us:.3f} us a live page",
               flush=True)
     os.makedirs(out_dir, exist_ok=True)
-    with open(os.path.join(out_dir, out_name), "w") as f:
+    with open(path, "w") as f:
         json.dump(record, f, indent=1)
         f.write("\n")
     print(json.dumps({
         "metric": "sparse_decode_layer_us", "backend": backend,
         "layer_us": {f"{r['form']}@{r['length']}": r["layer_us"]
                      for r in rows},
-        "page_us": record["page_us"]}), flush=True)
+        "page_us": slopes}), flush=True)
     ok = all(r["max_abs_diff"] < 0.1 for r in rows)
     return 0 if ok and backend != "cpu" else 1
 
@@ -495,7 +524,9 @@ def main() -> int:
     if "--sparse" in sys.argv:
         out_name = (sys.argv[sys.argv.index("--out-name") + 1]
                     if "--out-name" in sys.argv else "sparse_decode.json")
-        return sparse_main(out_dir, out_name, iters)
+        widths = (sys.argv[sys.argv.index("--pages-a-fold") + 1]
+                  if "--pages-a-fold" in sys.argv else "")
+        return sparse_main(out_dir, out_name, iters, widths)
     if "--paged" in sys.argv:
         only = (set(sys.argv[sys.argv.index("--only") + 1].split(","))
                 if "--only" in sys.argv else None)
