@@ -149,6 +149,7 @@ class DecodePath:
     v_dim: int = 0        # a v row's width where not the k row's
     sink: bool = False    # a learned sink joins the sum at the scan's end
     heads_per_row: int = 1  # KV heads side by side in a pool row
+    pages: int = 1        # live pages an online-softmax update (_walk)
 
     def describe(self) -> str:
         return ((f"{self.heads_per_row} heads a pool row: "
@@ -156,7 +157,9 @@ class DecodePath:
                 + f"{self.kb} heads x {self.rows} rows over "
                 f"[{self.page_size}, {self.kb}, {self.head_dim}] "
                 f"{self.kv_dtype} pages -> {self.form} ({self.why}); "
-                f"{self.walk}, a ring of {self.depth}, of "
+                f"{self.walk}, {self.pages} "
+                f"page{'s' if self.pages > 1 else ''} a fold, a ring of "
+                f"{self.depth}, of "
                 + (f"window {self.sliding}: the " if self.sliding else "")
                 + f"{self.table_width} table columns a slot"
                 + (f"; v rows {self.v_dim} wide" if self.v_dim else "")
@@ -178,7 +181,7 @@ def clear_decode_paths() -> None:
 
 def _record_path(kb: int, rows: int, ps: int, H: int, dtype,
                  sliding: int, table_width: int, depth: int,
-                 fold: int = 1, **kind) -> None:
+                 fold: int = 1, pages: int = 1, **kind) -> None:
     if tile_math.flat_heads(kb, rows, ps):
         form, why = FORM_FLAT, (
             f"{kb * rows} rows x {ps * kb} columns in one contraction")
@@ -197,7 +200,7 @@ def _record_path(kb: int, rows: int, ps: int, H: int, dtype,
         program=current_program(), kb=kb, rows=rows, page_size=ps,
         head_dim=H, kv_dtype=str(jnp.dtype(dtype)), form=form, why=why,
         sliding=sliding, table_width=table_width, walk=WALK_LOOP,
-        depth=depth, **kind))
+        depth=depth, pages=pages, **kind))
 
 
 def _window_rows(mask_ref, rows: int, R: int, window: int):
@@ -618,8 +621,8 @@ def window_table(page_table: jax.Array, lengths: jax.Array, sliding: int,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "window", "sliding", "interpret")
-)
+    jax.jit, static_argnames=(
+        "scale", "window", "sliding", "interpret", "pages", "depth"))
 def _paged_decode_attention(
     q: jax.Array,          # [B, K, Tq*G, H]  rows ordered (t, g)
     k: jax.Array,          # [L, P, ps, K, H] the STACKED page pool, whole
@@ -635,6 +638,8 @@ def _paged_decode_attention(
     window: int,
     interpret: bool,
     sliding: int = 0,
+    pages: int,             # _walk: live pages an online-softmax update,
+    depth: int,             # ... and the ring's slots of that many pages
 ) -> jax.Array:
     B, K, R, H = q.shape
     G = R // window
@@ -659,9 +664,9 @@ def _paged_decode_attention(
     if view > 1:
         k = k.reshape(k.shape[:2] + (ps // view_k, K * view_k, H))
         v = v.reshape(v.shape[:2] + (ps // view_v, K * view_v, Hv))
-    depth = tile_math.paged_walk_depth(
-        ps, kb, H, k.dtype.itemsize, has_scales, window, G)
     ahead = depth - 1
+    span = pages * ps           # positions a fold covers
+    assert pages == 1 or view > 1, "only _fold_flat folds several pages"
 
     # The grid is (slot, head block) and nothing else: the PAGES of a slot
     # are a loop inside the step, over the table columns
@@ -686,8 +691,23 @@ def _paged_decode_attention(
     # a cold copy's latency. Both grid axes are sequential for that. The
     # cursor (the step and page of the next item to start) and the
     # stream index of this step's first item ride in SMEM across steps.
+    #
+    # Where the block is narrow and :func:`_walk` says so (``pages`` > 1),
+    # the stream's item is a GROUP of ``pages`` consecutive live pages of
+    # the slot, one after another in one ring slot, each copied by its own
+    # DMA, and a group is ONE :func:`_fold_flat` over ``span`` positions:
+    # the update's serial chain (score product, running max, exp, value
+    # product, rescale) is paid once a group, ``ceil(count / pages)`` times
+    # a slot. A short last group starts NO copy for a page past ``count``:
+    # its part of the ring slot keeps an earlier page's finite bytes (the
+    # v ring is zeroed once, before the stream's first copy), behind the
+    # position bound.
     def bounds(b, len_ref):
-        return tile_math.live_pages(len_ref[b], window, sliding, ps, NP)
+        """A slot's first live column, its live pages and its folds."""
+        first, count = tile_math.live_pages(
+            len_ref[b], window, sliding, ps, NP)
+        return first, count, (
+            count if pages == 1 else (count + (pages - 1)) // pages)
 
     def kernel(pt_ref, len_ref, ly_ref, q_ref, k_hbm, v_hbm, *rest):
         ks_hbm = vs_hbm = ks_buf = vs_buf = None
@@ -702,11 +722,30 @@ def _paged_decode_attention(
         sem, cur, m_ref, l_ref, acc_ref = rest
         s = pl.program_id(0) * nj + pl.program_id(1)
 
-        def copies(t, page, slot):
+        def copies(t, page, slot, left=None):
             """The copies of step ``t``'s table column ``page`` into ring
             slot ``slot`` (a sentinel or garbage entry clamps to a real
             page; only an idle slot's column 0 can hold one, and the
-            length bound masks everything it could contribute)."""
+            length bound masks everything it could contribute), each as
+            ``(live, copy)``: ``live`` None where the copy always runs."""
+            if pages > 1:
+                # The group of columns from ``page`` on (nj == 1, no
+                # scales: the narrow arm), page r into its part of the
+                # slot; one past ``left``, the slot's live pages from
+                # ``page`` on, is not copied.
+                out = []
+                for r in range(pages):
+                    phys = jnp.minimum(
+                        pt_ref[t, jnp.minimum(page + r, NP - 1)], P - 1)
+                    for n, (hbm, buf) in enumerate(
+                            ((k_hbm, k_buf), (v_hbm, v_buf))):
+                        rows = buf.shape[1] // pages
+                        out.append((r < left if r else None,
+                                    pltpu.make_async_copy(
+                            hbm.at[ly_ref[0], pl.ds(phys, 1)],
+                            buf.at[pl.ds(slot, 1), pl.ds(r * rows, rows)],
+                            sem.at[n, slot, r])))
+                return out
             b, j = (t, 0) if nj == 1 else (t // nj, t % nj)
             phys = jnp.minimum(pt_ref[b, page], P - 1)
             heads = (slice(None) if nj == 1
@@ -721,27 +760,37 @@ def _paged_decode_attention(
                 pairs += [(hbm.at[pl.ds(phys, 1), heads, :], buf)
                           for hbm, buf in ((ks_hbm, ks_buf),
                                            (vs_hbm, vs_buf))]
-            return [pltpu.make_async_copy(
-                src, buf.at[pl.ds(slot, 1)], sem.at[n, slot])
+            return [(None, pltpu.make_async_copy(
+                src, buf.at[pl.ds(slot, 1)], sem.at[n, slot]))
                 for n, (src, buf) in enumerate(pairs)]
 
+        def each(pairs, act: str):
+            """``copy.start()`` / ``copy.wait()`` of every live copy."""
+            for live, c in pairs:
+                run = getattr(c, act)
+                run() if live is None else pl.when(live)(run)
+
         def start_next(t, i, slot):
-            """Start the copy of the cursor's item (step ``t``, its
-            ``i``-th live page) if there is one, and move the cursor on."""
+            """Start the copies of the cursor's item (step ``t``, the
+            ``pages`` live pages from its ``i``-th on) if there is one, and
+            move the cursor on."""
             t_in = jnp.minimum(t, steps - 1)
-            first, count = bounds(t_in if nj == 1 else t_in // nj, len_ref)
+            first, count, _ = bounds(
+                t_in if nj == 1 else t_in // nj, len_ref)
 
             @pl.when(t < steps)
             def _start():
-                for c in copies(t_in, first + i, slot):
-                    c.start()
+                each(copies(t_in, first + i, slot,
+                            count - i if pages > 1 else None), "start")
 
-            roll = i + 1 >= count
+            roll = i + pages >= count
             return (jnp.where(roll, t + 1, t),
-                    jnp.where(roll, 0, i + 1))
+                    jnp.where(roll, 0, i + pages))
 
         @pl.when(s == 0)
         def _stream_begins():
+            if pages > 1:   # a part of a slot no copy has reached is read
+                v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
             cursor = (jnp.int32(0), jnp.int32(0))
             for n in range(ahead):
                 cursor = start_next(*cursor, n)
@@ -749,15 +798,16 @@ def _paged_decode_attention(
             cur[1], cur[2] = cursor
 
         b = pl.program_id(0)
-        first, count = bounds(b, len_ref)
+        first, count, folds = bounds(b, len_ref)
         base = cur[0]
         _scan_begin(m_ref, l_ref, acc_ref)
 
         def fold(i, cursor):
             cursor = start_next(*cursor, (base + i + ahead) % depth)
-            page, slot = first + i, (base + i) % depth
-            for c in copies(s, page, slot):
-                c.wait()
+            at = i if pages == 1 else i * pages     # the item's first page
+            page, slot = first + at, (base + i) % depth
+            each(copies(s, page, slot, count - at if pages > 1 else None),
+                 "wait")
             # In-kernel STAIRCASE validity from the prefetched lengths:
             # page p covers logical positions [p*ps, (p+1)*ps); window
             # row t (row r = t*G + g of its head) attends pos <=
@@ -765,13 +815,17 @@ def _paged_decode_attention(
             # Tq == 1 degenerate case is exactly the slab decode_mask
             # bound. No mask array is streamed at all. In the flat form
             # a column is (position, head) and a row (head, t, g).
-            shape = (kb * R, ps * kb) if flat else (R, ps)
+            shape = (kb * R, span * kb) if flat else (R, ps)
             col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
             pos = page * ps + (col // kb if flat else col)
             t_of_row = (jax.lax.broadcasted_iota(
                 jnp.int32, shape, 0) % R) // G
             bound = len_ref[b] + t_of_row
-            valid = pos <= bound
+            # (a short last group's tail lies past every row's bound, or,
+            # where a window row's bound passes the table's end, past the
+            # table: the walk of single pages stops there too)
+            valid = pos <= (bound if pages == 1 else jnp.minimum(
+                bound, (first + count) * ps - 1))
             if sliding:
                 # the lower edge (``models/decoder.py::sliding_edge``)
                 valid = valid & (pos > bound - sliding)
@@ -779,7 +833,8 @@ def _paged_decode_attention(
                 None if buf is None else buf.at[pl.ds(slot, 1)])
             if view > 1:
                 _fold_flat(q_ref, tile(k_buf), tile(v_buf), m_ref, l_ref,
-                           acc_ref, ps=ps, kb=kb, valid=valid, scale=scale)
+                           acc_ref, ps=span, kb=kb, valid=valid,
+                           scale=scale)
                 return cursor
             _accumulate_tile(
                 q_ref, tile(k_buf), tile(v_buf), tile(ks_buf), tile(vs_buf),
@@ -788,8 +843,8 @@ def _paged_decode_attention(
             return cursor
 
         cur[1], cur[2] = jax.lax.fori_loop(
-            0, count, fold, (cur[1], cur[2]))
-        cur[0] = (base + count) % depth
+            0, folds, fold, (cur[1], cur[2]))
+        cur[0] = (base + folds) % depth
         _scan_end(o_ref, m_ref, l_ref, acc_ref, sink_ref)
 
     a_block = lambda b, j, pt, ln, ly: (b, j, 0)  # noqa: E731
@@ -799,8 +854,8 @@ def _paged_decode_attention(
     # A head block's rows are one contiguous [kb * R, H] tile (free: the
     # same bytes).
     args = [q.reshape(B, K * R, H), k, v]
-    ring = [pltpu.VMEM((depth, ps // view_k, kb * view_k, H), k.dtype),
-            pltpu.VMEM((depth, ps // view_v, kb * view_v, Hv), v.dtype)]
+    ring = [pltpu.VMEM((depth, span // view_k, kb * view_k, H), k.dtype),
+            pltpu.VMEM((depth, span // view_v, kb * view_v, Hv), v.dtype)]
     if has_scales and flat:
         # A page's scales as ONE lane row in the flat column order.
         args += [_flat_columns(k_scale, kb, ps),
@@ -827,7 +882,8 @@ def _paged_decode_attention(
         in_specs=in_specs,
         out_specs=out_spec,
         scratch_shapes=ring + [
-            pltpu.SemaphoreType.DMA((len(ring), depth)),
+            pltpu.SemaphoreType.DMA(
+                (len(ring), depth) + ((pages,) if pages > 1 else ())),
             pltpu.SMEM((3,), jnp.int32),
         ] + _scratch(kb, R, Hv, flat),
     )
@@ -897,7 +953,9 @@ def paged_decode_attention(
     ``ops/tile_math.py``: the page IS the KV tile, so the least ring of
     them the walk needs (``paged_tile_bytes``: a page folding, a page
     arriving) must fit the shared budget (the ring it takes,
-    ``paged_walk_depth``, is the deepest up to three that does), and
+    ``paged_walk_depth``, is the deepest up to three that does; where the
+    head block is narrow an item of the ring is several pages, folded in
+    one update: :func:`_walk`), and
     the page size must be a 128-lane multiple (the int8 scale tile's
     lane dim is the page). The static ``vmem-budget`` lint rule holds
     the call's scratch to the same budget.
@@ -1017,14 +1075,13 @@ def paged_decode_attention(
                     sink=sink is not None)
     if f > 1:
         kind = dict(heads_per_row=f)
+    fold = _narrow_fold(k_local, kb, Tq * G, ps, k_scale is not None)
+    pages, depth = _walk(fold, ps, kb, Hk, k.dtype.itemsize,
+                         k_scale is not None, Tq, G)
     _record_path(kb, Tq * G, ps, Hk, k.dtype, int(sliding),
                  tile_math.window_table_width(
                      int(sliding), Tq, ps, page_table.shape[1]),
-                 tile_math.paged_walk_depth(
-                     ps, kb, Hk, k.dtype.itemsize, k_scale is not None,
-                     Tq, G),
-                 fold=_narrow_fold(
-                     k_local, kb, Tq * G, ps, k_scale is not None), **kind)
+                 depth, fold=fold, pages=pages, **kind)
     scale = scale if scale is not None else H ** -0.5
     # Rows ordered (t, g) per kv head: [B, Tq, K, G, H] ->
     # [B, K, Tq*G, H] (Tq == 1 collapses to the historical layout),
@@ -1038,7 +1095,8 @@ def paged_decode_attention(
                 kv_lengths.astype(jnp.int32),
                 jnp.full((1,), layer, jnp.int32), k_scale, v_scale)
     static = dict(scale=float(scale), window=int(Tq),
-                  interpret=bool(interpret), sliding=int(sliding))
+                  interpret=bool(interpret), sliding=int(sliding),
+                  pages=pages, depth=depth)
     if tp > 1:
         out = _paged_decode_attention_tp(mesh, mesh_axis, *operands,
                                          **static)
@@ -1068,6 +1126,7 @@ def paged_decode_attention(
 def _paged_decode_attention_tp(
     mesh, axis: str, q_r, k, v, page_table, kv_lengths, layer, ks, vs,
     *, scale: float, window: int, interpret: bool, sliding: int = 0,
+    pages: int, depth: int,
 ):
     """The TP wrapper: ``shard_map`` the paged kernel over the mesh's
     ``axis`` with q/pools split on the kv-head dim and the page
@@ -1097,7 +1156,7 @@ def _paged_decode_attention_tp(
         return _paged_decode_attention(
             q_l, k_l, v_l, pt, ln, ly, ks_l, vs_l,
             scale=scale, window=window, interpret=interpret,
-            sliding=sliding,
+            sliding=sliding, pages=pages, depth=depth,
         )
 
     # check_vma=False: pallas_call declares no varying-axes rule, and
@@ -1195,3 +1254,16 @@ def decode_attention(
     return out.reshape(B, K, Tq, G, H).transpose(0, 2, 1, 3, 4).reshape(
         B, Tq, N, H
     )
+
+
+def _walk(fold: int, ps: int, kb: int, H: int, itemsize: int,
+          has_scales: bool, window: int, G: int):
+    """``(pages a fold, ring slots of that many pages)`` of the paged
+    kernel's walk at these shapes: what it traces (its static arguments)
+    and what ``decode_paths()`` says. ``fold`` is :func:`_narrow_fold`'s:
+    only the narrow arm's fold takes several pages
+    (``tile_math.paged_fold_pages``)."""
+    pages = tile_math.paged_fold_pages(
+        ps, kb, H, itemsize, window, G, narrow=fold > 1)
+    return pages, tile_math.paged_walk_depth(
+        ps, kb, H, itemsize, has_scales, window, G, pages)

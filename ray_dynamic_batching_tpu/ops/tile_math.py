@@ -189,16 +189,19 @@ def paged_walk_depth(
     with_scales: bool = False,
     window: int = 1,
     G: int = 1,
+    pages: int = 1,
 ) -> int:
-    """Pages of K and V (and their scale rows) the paged kernel's page
-    walk keeps in its own VMEM scratch: the most, up to
-    ``PAGED_WALK_MAX_DEPTH``, that :func:`paged_tile_bytes` prices within
-    ``VMEM_BLOCK_BUDGET_BYTES``, and never fewer than ``DOUBLE_BUFFER``
-    (a page folding, a page arriving: where even that does not fit, the
-    kernel's guard declines)."""
+    """Items of K and V pages (and their scale rows) the paged kernel's
+    page walk keeps in its own VMEM scratch, an item ``pages`` pages (one
+    but for a narrow head block: :func:`paged_fold_pages`): the most, up
+    to ``PAGED_WALK_MAX_DEPTH``, that :func:`paged_tile_bytes` prices
+    within ``VMEM_BLOCK_BUDGET_BYTES``, and never fewer than
+    ``DOUBLE_BUFFER`` (a page folding, a page arriving: where even that
+    does not fit, the kernel's guard declines)."""
     for depth in range(PAGED_WALK_MAX_DEPTH, DOUBLE_BUFFER, -1):
         if paged_tile_bytes(page_size, kb, H, kv_itemsize, with_scales,
-                            window, G, depth) <= VMEM_BLOCK_BUDGET_BYTES:
+                            window, G, depth, pages
+                            ) <= VMEM_BLOCK_BUDGET_BYTES:
             return depth
     return DOUBLE_BUFFER
 
@@ -212,9 +215,11 @@ def paged_tile_bytes(
     window: int = 1,
     G: int = 1,
     depth: int = DOUBLE_BUFFER,
+    pages: int = 1,
 ) -> int:
     """VMEM footprint of one PAGED decode-attention grid step whose page
-    walk keeps a ring of ``depth`` pages in scratch it fills itself. The
+    walk keeps a ring of ``depth`` items of ``pages`` pages in scratch it
+    fills itself. The
     default is the least ring (a page folding, a page arriving): what
     must fit for the kernel to engage at all, so what its runtime guard
     declines by; :func:`paged_walk_depth` is the ring it then takes. The
@@ -239,13 +244,21 @@ def paged_tile_bytes(
     grow with the window's row count, and for decode's Tq == 1 they are
     the small riders the base model documents away — a wide window makes
     them first-class.
+
+    ``pages`` > 1 (a narrow head block's fold of several live pages,
+    :func:`paged_fold_pages`) multiplies the ring and adds that fold's
+    two f32 score tiles [kb * rows, pages * page_size * kb]; at a page a
+    fold they are riders too (128 KB at 32 rows), as they always were.
     """
     rows = int(window) * max(1, int(G))
     flat = flat_heads(kb, rows, page_size)
     kv = 2 * padded_block_bytes((1, page_size, kb, H), kv_itemsize)
     scale_shape = (1, 1, page_size * kb) if flat else (1, kb, page_size)
     scale_b = 2 * padded_block_bytes(scale_shape, 4) if with_scales else 0
-    total = depth * (kv + scale_b)
+    total = depth * pages * (kv + scale_b)
+    if pages > 1:
+        total += 2 * padded_block_bytes(
+            (kb * rows, pages * page_size * kb), 4)
     if window > 1:
         qo = 2 * padded_block_bytes((1, kb, rows, H), kv_itemsize)
         acc = padded_block_bytes((kb, rows, H), 4)  # f32 scratch, single
@@ -447,9 +460,49 @@ def sparse_fold_pages(page_size: int, kb: int, H: int, kv_itemsize: int,
     form for a narrow head block (``decode_attention._fold_flat``: a
     column is position ``c // kb`` of head ``c % kb`` however many pages
     lie one after another); the shared forms keep a page a fold."""
-    pages = SPARSE_FOLD_MAX_PAGES if own else 1
-    while pages > 1 and (n_entries % pages or sparse_tile_bytes(
+    return _fold_pages(
+        SPARSE_FOLD_MAX_PAGES if own else 1,
+        lambda pages: not n_entries % pages and sparse_tile_bytes(
             page_size, kb, H, kv_itemsize, G, n_entries, fold, True,
-            DOUBLE_BUFFER + 1, pages) > VMEM_BLOCK_BUDGET_BYTES):
+            DOUBLE_BUFFER + 1, pages) <= VMEM_BLOCK_BUDGET_BYTES)
+
+
+def _fold_pages(most: int, fits) -> int:
+    """The ONE rule of the page walks' fold width: the largest of ``most``
+    and its halves down to 1 live page an update that ``fits`` (a ring of
+    them past the double buffer within the VMEM budget, by the kernel's
+    own model)."""
+    pages = most
+    while pages > 1 and not fits(pages):
         pages //= 2
     return pages
+
+
+# The most live pages the DENSE paged kernel's narrow arm folds in one
+# update. Measured on a v5e at LFM2's 4 rows x 128 lanes a position, 64
+# slots (PERF.md, PR 52; profiles/tpu_v5e/paged_steps.json): a live page
+# 0.509 | 0.345 | 0.340 us at 1 | 2 | 4 pages a fold against a 0.32 us
+# copy, so two pages already pay the chain; a call over slots of TWO live
+# pages 96.8 | 68.2 | 82.4 us (a group of four folds two dead pages'
+# columns and its first fold waits for more copies), over 8 and 32 live
+# pages 196.5 | 197.5 and 729.4 | 729.9 at 2 | 4: four is never faster
+# and a fifth slower at the lengths a slot starts at.
+PAGED_FOLD_MAX_PAGES = 2
+
+
+def paged_fold_pages(page_size: int, kb: int, H: int, kv_itemsize: int,
+                     window: int = 1, G: int = 1,
+                     narrow: bool = False) -> int:
+    """Live pages the DENSE paged kernel folds an update, from the shapes
+    alone (:func:`_fold_pages`, priced by :func:`paged_tile_bytes`): 2
+    (``PAGED_FOLD_MAX_PAGES``) where its head block is ``narrow``
+    (``decode_attention._narrow_fold``: fewer than 8 rows a position, all
+    of K, a bf16 pool), whose fold's serial chain and not the page's copy
+    set the pace, unless even two pages' ring of three busts the budget;
+    1 for a block of 8 heads (a page 0.69 us against its 0.64 us copy:
+    finished) and for an int8 pool."""
+    return _fold_pages(
+        PAGED_FOLD_MAX_PAGES if narrow else 1,
+        lambda pages: paged_tile_bytes(
+            page_size, kb, H, kv_itemsize, False, window, G,
+            DOUBLE_BUFFER + 1, pages) <= VMEM_BLOCK_BUDGET_BYTES)

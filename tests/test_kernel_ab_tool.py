@@ -38,15 +38,24 @@ def test_the_geometries_are_the_benchmarks_configurations():
     root = Path(__file__).resolve().parents[1]
     named = [g for g in ab.PAGED_GEOMETRIES
              if (root / "benchmark" / "configs" / f"{g[0]}.json").exists()]
-    assert len(named) == 4
+    assert len(named) == 6
     for tag, L, P, B, NP, N, K, H, _ in named:
         cfg = json.loads((root / "benchmark" / "configs"
                           / f"{tag}.json").read_text())
         dec, llm = cfg["program"]["decoder_config"], cfg["deployment"]["llm"]
-        assert (L, N, K, H) == (dec["num_layers"], dec["num_heads"],
+        # the layers that hold pages of this kind: LFM2's G of CCGC, MiMo's
+        # full layers (its window layers keep a ring, 8 heads: not timed)
+        pattern = dec.get("layer_pattern", "")
+        paging = (sum(pattern[i % len(pattern)] == "G"
+                      for i in range(dec["num_layers"]))
+                  if "C" in pattern or "sliding_kv_heads" in dec
+                  else dec["num_layers"])
+        assert (L, N, K, H) == (paging, dec["num_heads"],
                                 dec["num_kv_heads"],
                                 dec.get("head_dim")
                                 or dec["d_model"] // dec["num_heads"])
+        assert ab.V_DIM.get(tag, 0) == dec.get("v_head_dim", 0)
+        assert max(ab.LIVE_PAGES.get(tag, (NP,))) == NP
         assert (B, P) == (llm["num_slots"], llm["kv_pool_pages"])
         assert NP == -(-llm["max_len"] // llm["page_size"])
         assert llm["page_size"] == ab.PAGE
@@ -58,6 +67,110 @@ def test_walk_costs_fit_the_three_shares(fixed, page):
              "live_pages": live} for live in (32, 128, 256)]
     got = ab.walk_costs_us(rows)
     assert got == pytest.approx((fixed, page))
+
+
+# --- ``--paged --pages-a-fold``: the narrow arm at each width of its fold ------
+def test_the_paged_sweep_patches_the_picker_and_puts_it_back(monkeypatch):
+    """The sweep's rows on the CPU at tiny geometries (the kernel
+    interpreted: no timing is read): a narrow block runs at each width
+    given and says so, a block of 8 heads at a page a fold whatever is
+    asked, a pool whose value rows are narrower answers as the blocked
+    walk does, and the picker is ``tile_math``'s own again afterwards."""
+    from ray_dynamic_batching_tpu.ops import tile_math
+
+    picker = tile_math.paged_fold_pages
+    monkeypatch.setitem(ab.LIVE_PAGES, "narrow", (1, 3, 6))
+    monkeypatch.setitem(ab.LIVE_PAGES, "kinds", (2, 5))
+    monkeypatch.setitem(ab.V_DIM, "kinds", 128)
+    narrow = ("narrow", 2, 16, 2, 6, 8, 8, 64, False)   # 4 packed rows
+    for pages in (1, 2, 4):
+        rows = ab._time_paged(*narrow, 1, pages=pages, samples=1)
+        assert [r["pages_a_fold"] for r in rows] == [pages] * 3
+        assert [r["live_pages"] for r in rows] == [2, 6, 12]
+        assert all(r["max_abs_diff"] < 2e-2 for r in rows)
+        assert tile_math.paged_fold_pages is picker
+    # what the shapes pick, and the cell's own lengths
+    assert {r["pages_a_fold"] for r in ab._time_paged(
+        *narrow, 1, samples=1)} == {2}
+    rows = ab._time_paged("kinds", 2, 16, 2, 6, 16, 4, 192, False, 1,
+                          v_dim=128, samples=1)
+    assert [(r["pages_a_fold"], r["live_pages"]) for r in rows] == [
+        (2, 4), (2, 10)]
+    assert all(r["max_abs_diff"] < 2e-2 for r in rows)
+    eight = ab._time_paged("eight", 2, 16, 2, 4, 8, 8, 128, False, 1,
+                           pages=4, samples=1)
+    assert {r["pages_a_fold"] for r in eight} == {1}
+
+
+def test_the_paged_sweep_writes_a_row_of_costs_a_width(
+        monkeypatch, tmp_path, capsys):
+    import json
+
+    def rows(tag, *_, pages=0, **kw):
+        took = pages if tag == "narrow" and pages else 1
+        return [{"geometry": tag, "live_share": s, "pages_a_fold": took,
+                 "call_us": 4 * 0.1 + live * 0.5 / took, "steps": 4,
+                 "live_pages": live, "table_entries": 64,
+                 "max_abs_diff": 0.0}
+                for s, live in ((0.125, 8), (0.5, 32), (1.0, 64))]
+
+    monkeypatch.setattr(ab, "_time_paged", lambda tag, *a: rows(
+        tag, pages=a[-1]))
+    monkeypatch.setattr(ab, "PAGED_GEOMETRIES", [
+        ("narrow", 2, 16, 4, 16, 8, 4, 128, False),
+        ("eight", 2, 16, 4, 16, 8, 8, 128, False)])
+    (tmp_path / "p.json").write_text(json.dumps({"parent": {"kept": 1}}))
+    ab.paged_main(str(tmp_path), "p.json", 1, None, "1,2,4")
+    record = json.loads((tmp_path / "p.json").read_text())
+    assert record["parent"] == {"kept": 1}
+    got = [(g["geometry"], g["pages_a_fold"], round(g["live_page_us"], 6))
+           for g in record["geometries"]]
+    # a block of 8 heads is timed once: it keeps a page a fold
+    assert got == [("narrow", 1, 0.5), ("narrow", 2, 0.25),
+                   ("narrow", 4, 0.125), ("eight", 1, 0.5)]
+    line = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert line["live_page"]["narrow@4"] == pytest.approx(0.125)
+
+
+@pytest.mark.parametrize("tag, pages, low, high", [
+    # PR 52: live pages an online-softmax update in the dense kernel's
+    # narrow arm (the chain a page is what held 0.51 where the copy is 0.32)
+    ("lfm2-24b-a2b-ep8-1chip", 1, 0.49, 0.53),
+    ("lfm2-24b-a2b-ep8-1chip", 2, 0.33, 0.36),      # shipped
+    ("lfm2-24b-a2b-ep8-1chip", 4, 0.33, 0.36),
+    # ... MiMo's k page is relaid in every fold besides
+    ("mimo-v2-flash-ep16-1chip", 1, 0.65, 0.70),
+    ("mimo-v2-flash-ep16-1chip", 2, 0.50, 0.54),    # shipped
+    ("mimo-v2-flash-ep16-1chip", 4, 0.50, 0.54),
+])
+def test_the_recorded_narrow_rows_fit_is_the_page_cost(tag, pages, low, high):
+    """``walk_costs_us`` over the committed capture's rows of the two narrow
+    geometries, a width (records, not timings taken here): the recorded
+    fit, inside the band the chip read."""
+    import json
+
+    root = Path(__file__).resolve().parents[1]
+    record = json.loads((root / "profiles" / "tpu_v5e"
+                         / "paged_steps.json").read_text())
+    (kept,) = [g for g in record["geometries"]
+               if (g["geometry"], g.get("pages_a_fold")) == (tag, pages)]
+    fixed, page = ab.walk_costs_us(kept["rows"])
+    assert low < page < high
+    assert (fixed, page) == pytest.approx(
+        (kept["fixed_step_us"], kept["live_page_us"]))
+    geometry = next(g for g in ab.PAGED_GEOMETRIES if g[0] == tag)
+    B = geometry[3]
+    assert [r["live_pages"] for r in kept["rows"]] == [
+        B * n for n in ab.LIVE_PAGES[tag]]
+    assert all(r["pages_a_fold"] == pages and r["max_abs_diff"] < 2e-2
+               for r in kept["rows"])
+    # two slots of two live pages: four pages a fold is the slower there
+    if tag.startswith("lfm2") and pages == 4:
+        (two,) = [g for g in record["geometries"]
+                  if (g["geometry"], g.get("pages_a_fold")) == (tag, 2)]
+        assert two["rows"][0]["call_us"] < 0.9 * kept["rows"][0]["call_us"]
+        assert two["rows"][2]["call_us"] == pytest.approx(
+            kept["rows"][2]["call_us"], rel=0.01)
 
 
 # --- ``--sparse``: a selecting layer's decode read ----------------------------

@@ -439,6 +439,30 @@ def test_prefill_then_decode_through_the_engine_matches_the_reference(
         assert {p.kv_shape for p in paged} == {(2, 8, PAGE, 1, 128)}
         assert {d.heads_per_row for d in decode_attention.decode_paths()
                 } == {2}
+        # ... whose ONE row a position is a narrow block: the kernel folds
+        # two live pages an online-softmax update (ISSUE 52)
+        assert {d.pages for d in decode_attention.decode_paths()} == {2}
+
+
+def test_the_counter_names_the_pages_a_fold(model, params, tokens):
+    """``snapshot()["kv_pool"]["decode_paths"]`` says, for each program
+    that read pages through the kernel, the live pages a fold and the
+    ring's depth: two and three for this model's attention layers."""
+    attn_ops.set_attention_backend("pallas")
+    decode_attention.clear_decode_paths()
+    try:
+        engine, queue, _ = _engine(model, params)
+        req = _submit(queue, model, tokens[:20], 3)
+        engine.run_until_idle(timeout_s=600)
+        lines = engine.snapshot()["kv_pool"]["decode_paths"]
+    finally:
+        attn_ops.set_attention_backend("auto")
+        decode_attention.clear_decode_paths()
+    assert len(req.future.result(timeout=5).tokens) == 3
+    assert lines and all(line.startswith("decode_step: 2 heads a pool row")
+                         for line in lines)
+    assert all("a loop over the live pages, 2 pages a fold, a ring of 3, "
+               in line for line in lines)
 
 
 def test_a_slot_reused_after_a_longer_request_is_reset(

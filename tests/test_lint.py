@@ -1285,6 +1285,132 @@ class TestSharedTileMath:
         assert tm.sparse_tile_bytes(ps, kb, wide, 2, G, NP, f, True, 3, 2) \
             <= tm.VMEM_BLOCK_BUDGET_BYTES
 
+    @pytest.mark.parametrize("kb, H, rows", [
+        (4, 128, 8),      # LFM2: 4 packed rows a position, 8 query rows each
+        (4, 256, 16),     # MiMo's full layers: k rows of two lane tiles
+        (8, 128, 2),      # gpt2-medium packed: a block of 8
+    ])
+    @pytest.mark.parametrize("pages", [1, 2, 4])
+    def test_a_dense_fold_of_several_pages_is_priced_as_it_lies(
+            self, kb, H, rows, pages):
+        """ISSUE 52: the dense paged kernel's ring holds ``pages`` pages a
+        slot and its fold's score tiles are ``pages`` pages of columns
+        wide: the runtime's price (``paged_tile_bytes`` with ``pages``)
+        and the linter's standalone copy are one number, the ring's part
+        ``pages`` times a page's, and a page a fold what it always was."""
+        lm = tile_math_module()
+        for depth in (2, 3):
+            for itemsize in (1, 2, 4):
+                assert lm.paged_tile_bytes(
+                    128, kb, H, itemsize, False, 1, rows, depth, pages
+                ) == tm.paged_tile_bytes(
+                    128, kb, H, itemsize, False, 1, rows, depth, pages)
+        price = lambda depth, n: tm.paged_tile_bytes(  # noqa: E731
+            128, kb, H, 2, False, 1, rows, depth, n)
+        ring = lambda n: price(3, n) - price(0, n)  # noqa: E731
+        assert ring(pages) == pages * ring(1) == (
+            pages * 3 * 2 * tm.padded_block_bytes((1, 128, kb, H), 2))
+        # ... and what rides beside the ring: the fold's two score tiles
+        assert price(0, pages) - price(0, 1) == (
+            2 * (kb * rows) * pages * 128 * kb * 4 if pages > 1 else 0)
+        assert price(3, 1) == tm.paged_tile_bytes(
+            128, kb, H, 2, window=1, G=rows, depth=3)
+        assert lm.paged_walk_depth(128, kb, H, 2, False, 1, rows, pages) \
+            == tm.paged_walk_depth(128, kb, H, 2, False, 1, rows, pages)
+        for narrow in (False, True):
+            assert lm.paged_fold_pages(128, kb, H, 2, 1, rows, narrow) \
+                == tm.paged_fold_pages(128, kb, H, 2, 1, rows, narrow)
+
+    # name -> the (pages a fold, ring depth) its paged layers' decode
+    # walk takes and the bytes ``paged_tile_bytes`` prices that at; the
+    # six 8-row geometries a page a fold, PINNED.
+    PAGED_WALKS = {
+        "gpt2-medium": ((1, 3), 3 * 2 ** 20 + 2 * 16 * 1024 * 4),
+        "gpt2-medium-x4": ((1, 3), 3 * 2 ** 20 + 2 * 16 * 1024 * 4),
+        "mistral-7b-v0.3-1chip": ((1, 3), 3 * 2 ** 20 + 2 * 32 * 1024 * 4),
+        "olmoe-1b-7b-1chip": ((1, 3), 3 * 2 ** 20 + 2 * 8 * 1024 * 4),
+        "k-exaone-236b-ep8-1chip": ((1, 3), 3 * 2 ** 20 + 2 * 64 * 1024 * 4),
+        # a ring of 3 groups of 2 pages, k + v a page 2 x 512 KB as priced
+        # (4 rows pad to a 16-row tile), two [32, 1024] f32 score tiles
+        "lfm2-24b-a2b-ep8-1chip": ((2, 3), 6 * 2 ** 20 + 2 * 32 * 1024 * 4),
+        # 3 groups of 2 pages of 2 x 1 MB (both priced at k's 256 lanes),
+        # two [64, 1024] f32 score tiles
+        "mimo-v2-flash-ep16-1chip": ((2, 3), 12 * 2 ** 20 + 2 * 64 * 1024 * 4),
+        "mimo-v2-flash-ep16-1chip/window": (
+            (1, 3), 6 * 2 ** 20 + 2 * 64 * 1024 * 4),
+    }
+
+    @staticmethod
+    def _paged_walk(dec, kv_heads, itemsize=2):
+        """The walk of one paged layer kind of a configuration's
+        ``decoder_config``, as the wrapper works it out: the pool's rows a
+        position, their width, the query rows a row."""
+        import jax.numpy as jnp
+
+        from ray_dynamic_batching_tpu.models.kv_state import (
+            pool_head_dim,
+            pool_heads_per_row,
+        )
+
+        N = dec["num_heads"]
+        H = dec.get("head_dim") or dec["d_model"] // N
+        by_kind = "v_head_dim" in dec
+        f = 1 if by_kind else pool_heads_per_row(
+            H, kv_heads, jnp.int8 if itemsize == 1 else jnp.bfloat16)
+        rows_a_position, G = kv_heads // f, f * N // kv_heads
+        kb = da._pick_heads_block(rows_a_position)
+        fold = da._narrow_fold(rows_a_position, kb, G, 128, itemsize == 1)
+        walk = da._walk(fold, 128, kb, pool_head_dim(H * f), itemsize,
+                        itemsize == 1, 1, G)
+        return walk, tm.paged_tile_bytes(
+            128, kb, pool_head_dim(H * f), itemsize, itemsize == 1, 1, G,
+            walk[1], walk[0])
+
+    def test_every_configurations_walk_is_pinned(self):
+        """The picker's choice and its bytes for every geometry of
+        ``benchmark/configs/*.json`` whose decode read is the paged
+        kernel's (Keye's selecting layers are the sparse kernel's, Xing's
+        latent rows the latent kernel's): 2 for LFM2's packed rows and
+        for MiMo's full layers, 1 for the six 8-row geometries."""
+        import pathlib
+
+        root = pathlib.Path(__file__).resolve().parent.parent / "benchmark"
+        seen = {}
+        for path in sorted((root / "configs").glob("*.json")):
+            dec = json.loads(path.read_text())["program"]["decoder_config"]
+            if "index_topk" in dec or "kv_lora_rank" in dec:
+                continue
+            seen[path.stem] = self._paged_walk(dec, dec["num_kv_heads"])
+            if "sliding_kv_heads" in dec:
+                seen[path.stem + "/window"] = self._paged_walk(
+                    dec, dec["sliding_kv_heads"])
+        assert seen == self.PAGED_WALKS
+        assert all(took <= tm.VMEM_BLOCK_BUDGET_BYTES
+                   for _, took in seen.values())
+
+    def test_an_int8_pool_keeps_a_page_a_fold(self):
+        """gpt2-medium's int8 pool (a head a row, 16 of them) and a narrow
+        int8 block (4 heads: the per-head fold, which reads scales)."""
+        gpt2 = dict(num_heads=16, d_model=1024)
+        assert self._paged_walk(gpt2, 16, itemsize=1)[0] == (1, 3)
+        assert self._paged_walk(dict(num_heads=32, head_dim=128, d_model=0),
+                                4, itemsize=1)[0] == (1, 3)
+        assert tm.paged_fold_pages(128, 4, 128, 1, narrow=False) == 1
+
+    def test_the_dense_picker_falls_by_halves(self):
+        """2 (``PAGED_FOLD_MAX_PAGES``: what the chip's A/B says, four is
+        never faster) where a ring of three groups fits the budget, 1
+        where the head is so wide that two pages' ring busts it; never
+        more than 1 for a fold that is not the narrow arm's."""
+        assert tm.PAGED_FOLD_MAX_PAGES == 2
+        assert [tm.paged_fold_pages(128, 4, H, 2, 1, 8, narrow=True)
+                for H in (128, 256, 512, 1024)] == [2, 2, 1, 1]
+        assert tm.paged_tile_bytes(128, 4, 512, 2, False, 1, 8, 3, 2) \
+            > tm.VMEM_BLOCK_BUDGET_BYTES >= tm.paged_tile_bytes(
+                128, 4, 256, 2, False, 1, 8, 3, 2)
+        assert tm.paged_fold_pages(128, 4, 128, 2, 1, 8, narrow=False) == 1
+        assert tm.paged_fold_pages(128, 8, 128, 2, 1, 4) == 1
+
     def test_shard_heads_agreement_pin(self):
         # ROADMAP item 2: the per-shard footprint rule (a head-sharded
         # paged kernel budgets K/tp heads; an indivisible head axis

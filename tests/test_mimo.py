@@ -266,6 +266,14 @@ def test_chunks_then_batched_decode_through_both_pools_match_the_reference(
                 for p in decode_attention.decode_paths()}
         assert took == {(WINDOW, 2, 4, True),
                         (0, TINY.max_seq_len // page, 2, False)}
+        # both kinds' blocks are narrow at these tiny head counts (2 and
+        # 4 rows a position, a lane tile wide): each walk, the window's
+        # two columns behind its sink too, folds a group of pages an
+        # update (ISSUE 52; the published widths' 2 and 1:
+        # ``tests/test_lint.py -k every_configurations_walk``)
+        assert {(p.kv_heads, p.pages, p.depth)
+                for p in decode_attention.decode_paths()} == {
+            (4, 2, 3), (2, 2, 3)}
 
 
 # --- knock-outs: each wrong arithmetic must FAIL the tolerance -----------------

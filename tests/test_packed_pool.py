@@ -466,6 +466,37 @@ def test_the_kernels_tokens_are_the_fallbacks(lm, spec):
     assert kernel == xla
 
 
+def test_the_counter_says_a_page_a_fold_for_a_block_of_8_rows():
+    """gpt2-medium's geometry (MHA 16 x 64: 8 pool rows a position, ONE head
+    block of 8) at one tiny layer: the engine's counter
+    (``snapshot()["kv_pool"]["decode_paths"]``) says its decode walk keeps
+    a page a fold, where a narrow block's says two (ISSUE 52;
+    ``tests/test_lfm2.py``)."""
+    model = CausalLM(_cfg(16, 16, 64, num_layers=1, max_seq_len=512,
+                          vocab_size=512),
+                     name="gpt2m_tiny", dtype=jnp.float32)
+    lm = (model, model.init(jax.random.PRNGKey(0)))
+    attention.set_attention_backend("pallas")
+    da.clear_decode_paths()
+    try:
+        engine, queue = _engine(lm, num_slots=2)
+        assert engine._cache.k.shape == (1, 8, PS, 8, 128)
+        _workload(queue, model.name, sampled=False, n=2)
+        engine.run_until_idle(timeout_s=300)
+        paths = da.decode_paths()
+        lines = engine.snapshot()["kv_pool"]["decode_paths"]
+    finally:
+        attention.set_attention_backend("auto")
+        da.clear_decode_paths()
+    assert {(p.kb, p.pages, p.depth) for p in paths} == {(8, 1, 3)}
+    # (the 8-row bucket's chunk program reads its pages through the kernel
+    # too: 16 rows a pool head)
+    assert any(line.startswith("decode_step: 2 heads a pool row: 8 heads "
+                               "x 2 rows") for line in lines)
+    assert all("a loop over the live pages, 1 page a fold, a ring of 3, "
+               in line for line in lines)
+
+
 def test_after_the_warm_up_a_packed_pool_compiles_nothing(lm):
     """The warm-up's programs are the served ones: no shape of the packed
     rows is met first by a request (``tools/check_compiles.py`` holds

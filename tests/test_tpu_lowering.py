@@ -551,6 +551,89 @@ class TestKindsKernelCompilesForV5e:
         assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
+class TestNarrowFoldCompilesForV5e:
+    """ISSUE 52: the paged kernel's narrow arm at 4, 2 (what the shapes
+    pick) and 1 live pages an online-softmax update, at the two cells' own
+    shapes: LFM2's pool of two
+    heads a row (64 slots, a table of 32, ``[10, 2048, 128, 4, 128]``, both
+    pools through their tile view) and MiMo's full layers (40 slots, a table
+    of 144, k rows of two lane tiles as they lie, v rows through the view).
+    The Mosaic compiler takes the group's ring (``[depth, pages * 64, 8,
+    128]``: a page's copy lands ``r * 64`` rows into a slot), the
+    conditional copies of a short last group and the ring's zeroing; the
+    pool's view stays a BITCAST behind the page write and nothing else
+    makes an array of the pool's size. Nothing runs:
+    ``tools/run_kernel_ab.py --paged --pages-a-fold 1,2,4`` on the chip
+    says what a page costs at each width."""
+
+    CELLS = {
+        # q heads, q width, pool rows a position, k width, v width, heads a
+        # row, slots, table, layers, pages
+        "lfm2": (32, 64, 4, 128, 128, 2, 64, 32, 10, 2048),
+        "mimo_full": (64, 192, 4, 256, 128, 1, 40, 144, 2, 5760),
+    }
+
+    @pytest.mark.parametrize("pages", [4, 2, 1])
+    @pytest.mark.parametrize("cell", sorted(CELLS))
+    def test_the_group_walk_compiles(self, cell, pages, one_chip,
+                                     monkeypatch):
+        import re
+
+        from jax.experimental.compilation_cache import compilation_cache
+
+        from ray_dynamic_batching_tpu.ops import tile_math
+
+        N, H, rows, Hk, Hv, f, B, NP, L, P = self.CELLS[cell]
+        ps = 128
+        if pages != 2:      # the A/B tool's other widths
+            monkeypatch.setattr(
+                tile_math, "paged_fold_pages",
+                lambda *a, narrow=False, **kw: pages if narrow else 1)
+        struct = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dt, sharding=one_chip)
+
+        def step(q, k, v, table, lengths, page, krow, vrow):
+            k, v = k.at[1, page, 0].set(krow), v.at[1, page, 0].set(vrow)
+            out = da.paged_decode_attention(
+                q, k, v, table, lengths, layer=1, interpret=False,
+                heads_per_row=f, v_dim=Hv if f == 1 else 0)
+            assert out is not None
+            return out, k, v
+
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        da.clear_decode_paths()
+        try:
+            compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+                struct((B, 1, N, H), jnp.bfloat16),
+                struct((L, P, ps, rows, Hk), jnp.bfloat16),
+                struct((L, P, ps, rows, Hv), jnp.bfloat16),
+                struct((B, NP), jnp.int32), struct((B,), jnp.int32),
+                struct((B,), jnp.int32),
+                struct((B, rows, Hk), jnp.bfloat16),
+                struct((B, rows, Hv), jnp.bfloat16)).compile()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+            compilation_cache.reset_cache()
+        (path,) = da.decode_paths()
+        da.clear_decode_paths()
+        assert (path.form, path.pages, path.kb) == (da.FORM_FLAT, pages, 4)
+        # MiMo's rows of two lane tiles: four pages leave the ring a
+        # double buffer (an A/B reading only: the picker takes 2 for both)
+        assert path.depth == (2 if (cell, pages) == ("mimo_full", 4) else 3)
+        text = compiled.as_text()
+        casts = [ln for ln in text.splitlines() if re.search(
+            rf"= bf16\[{L},{P},{ps // 2},8,128\]\S* bitcast\(", ln)]
+        # a pool a lane tile wide is read through its view: LFM2's two,
+        # MiMo's v
+        assert len(casts) == (2 if cell == "lfm2" else 1), casts
+        made = re.findall(
+            rf"= bf16\[{L},{P},[\d,]*\]\{{[^}}]*\}} ([\w\-]+)\(", text)
+        assert sorted({op for op in made if op != "parameter"}) == [
+            "bitcast", "fusion", "scatter"], made
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 class TestFullLayersChunkAttentionInTheCompiledProgram:
     """``chunk_attention_full_dev_share_pct.batch`` finds the full layers'
     chunk attention in a device trace by XLA's fusion names, because the

@@ -27,6 +27,13 @@ once a program and not once a kernel. Read every kernel PR's costs with
 it (``PERF.md``); the file keeps the parent commit's rows under ``parent``
 (since PR 48: gpt2-medium's lane-padded pool, a head a row, beside the two
 heads a row the tool now builds by ``pool_heads_per_row``).
+``--paged --pages-a-fold 1,2,4`` times each geometry whose head block is
+NARROW (LFM2's 4 packed rows, MiMo's full layers' 4 heads) at each of those
+widths of its fold (the kernel's picker, ``tile_math.paged_fold_pages``,
+patched to ``n`` live pages an online-softmax update where the block is
+narrow; a block of 8 heads keeps a page a fold and is timed once), a row of
+costs a geometry a width; without it every geometry runs at the width its
+shapes pick, which its rows name (``pages_a_fold``).
 
 ``--sparse`` measures a selecting layer's decode read (index scores, top-k
 and attention over the selection) at the configuration that has an indexer,
@@ -92,10 +99,21 @@ PAGED_GEOMETRIES = [
     ("k-exaone-236b-ep8-1chip", 5, 2048, 64, 32, 64, 8, 128, False),
     ("k-exaone-236b-ep8-1chip-window128", 5, 2048, 64, 32, 64, 8, 128,
      False),
+    # the two NARROW head blocks: LFM2's ten attention layers (GQA 32/8 x
+    # 64, two heads a row: 4 rows x 128 lanes a position) and MiMo's two
+    # full layers (4 KV heads, k 192 held as 256 lanes, v 128)
+    ("lfm2-24b-a2b-ep8-1chip", 10, 2048, 64, 32, 32, 8, 64, False),
+    ("mimo-v2-flash-ep16-1chip", 2, 5760, 40, 144, 64, 4, 192, False),
 ]
 # Geometries whose every call is a sliding layer's, and their window.
 SLIDING = {"k-exaone-236b-ep8-1chip-window128": 128}
+# ... whose value rows are narrower than their key rows, and that width.
+V_DIM = {"mimo-v2-flash-ep16-1chip": 128}
 LIVE_SHARES = (0.125, 0.5, 1.0)
+# ... whose live pages a slot are the cell's own (its shortest slots, its
+# mean, a full table) and not LIVE_SHARES of the table.
+LIVE_PAGES = {"lfm2-24b-a2b-ep8-1chip": (2, 8, 32),
+              "mimo-v2-flash-ep16-1chip": (36, 72, 144)}
 PAGE = 128
 
 # ``--sparse``: a selecting layer's decode read (ops/sparse_attention.py) in
@@ -109,13 +127,18 @@ SPARSE_FORMS = ("floor", "mask", "mask_untiled", "gather")
 FOLD_FORM = "mask_fold"     # + n: the mask form at n pages a fold
 
 
-def fold_forms(widths: str):
-    """``--pages-a-fold``'s forms: the floor (every row's reference) and
-    the mask form at each width of ``"1,2,4"``, in the order given."""
+def fold_widths(widths: str):
+    """``--pages-a-fold``'s widths, ``"1,2,4"``, in the order given."""
     pages = [int(w) for w in widths.split(",")]
     if any(n < 1 for n in pages):
         raise SystemExit(f"--pages-a-fold: not widths: {widths!r}")
-    return ("floor",) + tuple(f"{FOLD_FORM}{n}" for n in pages)
+    return pages
+
+
+def fold_forms(widths: str):
+    """``--sparse --pages-a-fold``'s forms: the floor (every row's
+    reference) and the mask form at each width."""
+    return ("floor",) + tuple(f"{FOLD_FORM}{n}" for n in fold_widths(widths))
 
 
 def sparse_case(seed: int, B: int, NP: int, P: int, length: int):
@@ -168,10 +191,13 @@ def walk_costs_us(rows):
     return float(fixed), float(page)
 
 
-def _time_paged(tag, L, P, B, NP, N, K, H, int8, iters, sliding=0):
+def _time_paged(tag, L, P, B, NP, N, K, H, int8, iters, sliding=0,
+                v_dim=0, pages=0, samples=5):
     """Rows (one a live share) of the paged kernel's time a call, with
     its worst gap to the gather path on the same inputs. ``sliding``: every
-    call is a sliding layer's, over that window."""
+    call is a sliding layer's, over that window. ``v_dim``: the value
+    rows' width where not ``H``. ``pages`` > 0: the kernel's picker patched
+    to that many pages a fold where its head block is narrow."""
     import jax
     import jax.numpy as jnp
 
@@ -181,18 +207,19 @@ def _time_paged(tag, L, P, B, NP, N, K, H, int8, iters, sliding=0):
         quantize_kv_rows,
     )
     from ray_dynamic_batching_tpu.ops import attention as attn
+    from ray_dynamic_batching_tpu.ops import decode_attention as da
     from ray_dynamic_batching_tpu.ops import tile_math
-    from ray_dynamic_batching_tpu.ops.decode_attention import (
-        _pick_heads_block,
-    )
 
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
     # the pool as the engine would lay it out: gpt2-medium's two 64-wide
     # heads a row, [.., 8, 128]
-    f = pool_heads_per_row(H, K, jnp.int8 if int8 else jnp.bfloat16)
-    shape = (L, P, PAGE, K // f, pool_head_dim(H * f))
-    k = jax.random.normal(keys[0], shape, jnp.bfloat16)
-    v = jax.random.normal(keys[1], shape, jnp.bfloat16)
+    f = 1 if v_dim else pool_heads_per_row(
+        H, K, jnp.int8 if int8 else jnp.bfloat16)
+    shape = (L, P, PAGE, K // f)
+    k = jax.random.normal(
+        keys[0], shape + (pool_head_dim(H * f),), jnp.bfloat16)
+    v = jax.random.normal(
+        keys[1], shape + (pool_head_dim(v_dim or H * f),), jnp.bfloat16)
     q = jax.random.normal(keys[2], (B, 1, N, H), jnp.bfloat16)
     ks = vs = None
     if int8:
@@ -212,13 +239,16 @@ def _time_paged(tag, L, P, B, NP, N, K, H, int8, iters, sliding=0):
                     layer=layer,
                     k_scale=None if ks is None else ks[layer],
                     v_scale=None if vs is None else vs[layer],
-                    sliding=sliding, heads_per_row=f)
+                    sliding=sliding, heads_per_row=f, v_dim=v_dim)
+                if v_dim:   # a value row back to a query's width
+                    q = jnp.concatenate([q, q[..., :H - v_dim]], axis=-1)
             return q
         return jax.jit(run)
 
-    blocks = (K // f) // _pick_heads_block(K // f)
+    blocks = (K // f) // da._pick_heads_block(K // f)
     cases = []
-    for share in LIVE_SHARES:
+    for share in ([n / NP for n in LIVE_PAGES[tag]] if tag in LIVE_PAGES
+                  else LIVE_SHARES):
         table, lengths, live = paged_case(0, B, NP, P, share)
         cases.append((share, live, jnp.asarray(table), jnp.asarray(lengths)))
     # Each traced once: the table and the lengths are arguments.
@@ -231,17 +261,22 @@ def _time_paged(tag, L, P, B, NP, N, K, H, int8, iters, sliding=0):
         attn.set_attention_backend("auto")
     rows = []
     attn.set_attention_backend("pallas")
+    picker = getattr(tile_math, "paged_fold_pages", None)  # none: a parent
+    if pages:
+        tile_math.paged_fold_pages = (
+            lambda *a, narrow=False, **kw: pages if narrow else 1)
+    da.clear_decode_paths()
     try:
         for (share, live, table, lengths), ref in zip(cases, refs):
             out = kernel(q, k, v, ks, vs, table, lengths)
             program(q, k, v, ks, vs, table, lengths).block_until_ready()
-            samples = []
-            for _ in range(5):
+            took = []
+            for _ in range(samples):
                 t0 = time.perf_counter()
                 for _ in range(iters):
                     res = program(q, k, v, ks, vs, table, lengths)
                 res.block_until_ready()
-                samples.append(
+                took.append(
                     (time.perf_counter() - t0) * 1e6 / (iters * L))
             walked = tile_math.window_table_width(sliding, 1, PAGE, NP)
             if sliding:    # the window's columns that hold a position
@@ -250,8 +285,10 @@ def _time_paged(tag, L, P, B, NP, N, K, H, int8, iters, sliding=0):
             rows.append({
                 "geometry": tag, "live_share": share,
                 "heads_per_row": f,
-                "call_us": statistics.median(samples),
-                "call_us_min_max": [min(samples), max(samples)],
+                # what the kernel's call traced (a parent has no field)
+                "pages_a_fold": getattr(da.decode_paths()[-1], "pages", 1),
+                "call_us": statistics.median(took),
+                "call_us_min_max": [min(took), max(took)],
                 "steps": B * blocks,
                 "live_pages": round(B * blocks * live),
                 "table_entries": B * blocks * walked,
@@ -260,24 +297,35 @@ def _time_paged(tag, L, P, B, NP, N, K, H, int8, iters, sliding=0):
             })
     finally:
         attn.set_attention_backend("auto")
+        if picker is not None:
+            tile_math.paged_fold_pages = picker
     return rows
 
 
-def paged_main(out_dir: str, out_name: str, iters: int, only) -> int:
+def paged_main(out_dir: str, out_name: str, iters: int, only,
+               widths: str = "") -> int:
     import jax
 
     backend = jax.default_backend()
     geometries = [g for g in PAGED_GEOMETRIES if not only or g[0] in only]
     if not geometries:
         raise SystemExit(f"--only matched nothing: {only}")
+    # a width a run of a geometry; 0: what its shapes pick
+    runs = [(g, n) for g in geometries
+            for n in (fold_widths(widths) if widths else [0])]
     record = {"backend": backend,
               "device_kind": jax.devices()[0].device_kind,
               "captured": time.strftime("%Y%m%dT%H%M%S"), "iters": iters,
               "geometries": []}
     ok = True
-    for g in geometries:
+    seen = set()
+    for g, n in runs:
         try:
-            rows = _time_paged(*g, iters, SLIDING.get(g[0], 0))
+            rows = _time_paged(*g, iters, SLIDING.get(g[0], 0),
+                               V_DIM.get(g[0], 0), n)
+            if (g[0], rows[0]["pages_a_fold"]) in seen:
+                continue    # a block of 8 heads: a page a fold again
+            seen.add((g[0], rows[0]["pages_a_fold"]))
         except Exception as exc:  # noqa: BLE001
             ok = False
             record["geometries"].append(
@@ -289,18 +337,21 @@ def paged_main(out_dir: str, out_name: str, iters: int, only) -> int:
         fixed_us, page_us = ((None, None) if g[0] in SLIDING
                              else walk_costs_us(rows))
         record["geometries"].append({
-            "geometry": g[0], "rows": rows,
-            "fixed_step_us": fixed_us, "live_page_us": page_us})
+            "geometry": g[0], "pages_a_fold": rows[0]["pages_a_fold"],
+            "rows": rows, "fixed_step_us": fixed_us,
+            "live_page_us": page_us})
         for r in rows:
-            print(f"{g[0]}: live share {r['live_share']:.3f}: "
+            print(f"{g[0]}: {r['pages_a_fold']} pages a fold: "
+                  f"live share {r['live_share']:.3f}: "
                   f"{r['call_us']:.1f} us a call "
                   f"({r['steps']} steps walk {r['live_pages']} of "
                   f"{r['table_entries']} table entries), "
                   f"max |kernel - gather| {r['max_abs_diff']:.2e}",
                   flush=True)
         if fixed_us is not None:
-            print(f"{g[0]}: a (slot, head block) step {fixed_us:.3f} us, "
-                  f"a live page {page_us:.3f} us", flush=True)
+            print(f"{g[0]}: {rows[0]['pages_a_fold']} pages a fold: a "
+                  f"(slot, head block) step {fixed_us:.3f} us, a live "
+                  f"page {page_us:.3f} us", flush=True)
         ok = ok and all(r["max_abs_diff"] < 0.1 for r in rows)
     os.makedirs(out_dir, exist_ok=True)
     path = os.path.join(out_dir, out_name)
@@ -314,10 +365,9 @@ def paged_main(out_dir: str, out_name: str, iters: int, only) -> int:
         f.write("\n")
     print(json.dumps({
         "metric": "paged_decode_walk_us", "backend": backend,
-        "fixed_step": {g["geometry"]: g.get("fixed_step_us")
-                       for g in record["geometries"]},
-        "live_page": {g["geometry"]: g.get("live_page_us")
-                      for g in record["geometries"]},
+        **{key: {f"{g['geometry']}@{g.get('pages_a_fold', 1)}":
+                 g.get(f"{key}_us") for g in record["geometries"]}
+           for key in ("fixed_step", "live_page")},
     }), flush=True)
     return 0 if ok and backend != "cpu" else 1
 
@@ -521,18 +571,18 @@ def main() -> int:
     iters = 20
     if "--iters" in sys.argv:
         iters = int(sys.argv[sys.argv.index("--iters") + 1])
+    widths = (sys.argv[sys.argv.index("--pages-a-fold") + 1]
+              if "--pages-a-fold" in sys.argv else "")
     if "--sparse" in sys.argv:
         out_name = (sys.argv[sys.argv.index("--out-name") + 1]
                     if "--out-name" in sys.argv else "sparse_decode.json")
-        widths = (sys.argv[sys.argv.index("--pages-a-fold") + 1]
-                  if "--pages-a-fold" in sys.argv else "")
         return sparse_main(out_dir, out_name, iters, widths)
     if "--paged" in sys.argv:
         only = (set(sys.argv[sys.argv.index("--only") + 1].split(","))
                 if "--only" in sys.argv else None)
         out_name = (sys.argv[sys.argv.index("--out-name") + 1]
                     if "--out-name" in sys.argv else "paged_steps.json")
-        return paged_main(out_dir, out_name, iters, only)
+        return paged_main(out_dir, out_name, iters, only, widths)
     geometries = GEOMETRIES
     if "--only" in sys.argv:
         # A couple of geometries (~2 compiles each) fit a short chip
