@@ -333,7 +333,13 @@ class Turn(NamedTuple):
     also carries ``state_resets``, its rows that began their prompt (the
     program zeroed their slots' states), and ``state_carries``, its rows
     that began from the state an earlier chunk of their train left. Their
-    sum is the chunks run. Both 0 for a scan and for any other model."""
+    sum is the chunks run. Both 0 for a scan and for any other model.
+
+    A scan of a HYBRID model (``PagedKVCache.ssm_state``) carries
+    ``ssm_state_bytes``: the bytes of state-space state its substeps had to
+    move, active slots x layers x a slot's matrices (in the plane's own
+    dtype) x 2 (read and written) x substeps. 0 for a chunk group and for
+    any other model."""
 
     kind: str
     t_dispatch: float
@@ -360,6 +366,7 @@ class Turn(NamedTuple):
     kv_latent_rows: int = 0
     state_resets: int = 0
     state_carries: int = 0
+    ssm_state_bytes: int = 0
 
 
 # An engine's prompt buckets where its builder names none.
@@ -475,7 +482,8 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
     ``kv_latent_rows`` (each weighed by its substeps): the pool rows its
     decode scans read. A model with conv layers adds ``state_resets``,
     ``state_carries`` and ``state_carried_chunk_share``: of the chunks run,
-    those that began from a carried state."""
+    those that began from a carried state. A hybrid model's scans add
+    ``ssm_state_bytes``, the state-space state they had to read and write."""
     scans = [t for t in turns if t.kind == "turn"]
     out: Dict[str, Any] = {"dispatches": len(turns), "scans": len(scans),
                            "dropped": dropped}
@@ -499,6 +507,9 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
     if resets or carries:
         out.update(state_resets=resets, state_carries=carries,
                    state_carried_chunk_share=carries / (resets + carries))
+    ssm_bytes = sum(t.ssm_state_bytes for t in scans)
+    if ssm_bytes:
+        out["ssm_state_bytes"] = ssm_bytes
     rows_live = sum(t.kv_rows_live * t.substeps for t in scans)
     if rows_live:
         out["kv_rows_live"] = rows_live
@@ -602,7 +613,8 @@ KV_POOL_BYTES = m.Gauge(
     "rdb_decode_kv_pool_bytes",
     "Resident bytes of the KV state of one kind of layer (a model with "
     "state by layer kind): 'full' the paged pool, 'ring' the sliding "
-    "layers' rings, 'state' the conv layers' states; set once, at build",
+    "layers' rings, 'state' the states a slot (conv, state-space); set "
+    "once, at build",
     tag_keys=("model", "kind"),
 )
 KV_PAGE_OCCUPANCY = m.Gauge(
@@ -896,6 +908,12 @@ class DecodeEngine:
         # chunk program is told each row's slot, and the ring counts the
         # chunks that reset a state and those that carried one.
         self._slot_state = self._cache.conv_state is not None
+        # ... of which a hybrid model's state-space matrices are read and
+        # written by every decode substep of every active slot: the bytes
+        # that is a slot (0: no such plane).
+        ssm = self._cache.ssm_state
+        self._ssm_step_bytes = 0 if ssm is None else (
+            2 * ssm.dtype.itemsize * math.prod(ssm.shape) // ssm.shape[1])
         self._pool_stats = self._cache.describe(cfg)
         for kind, n in self._pool_stats.get("bytes_by_kind", {}).items():
             KV_POOL_BYTES.set(n, tags={"model": model.name, "kind": kind})
@@ -1195,6 +1213,7 @@ class DecodeEngine:
             *(int(c) for c in moe[:3]), kv_pages_live, int(moe[3]),
             *kv_rows, queued_behind, kv_full_pages_live, kv_latent_rows,
             *state_turns,
+            active * substeps * self._ssm_step_bytes if kind == "turn" else 0,
         )
         if len(self.turns) == self.turns.maxlen:
             self.turns_dropped += 1
@@ -4050,6 +4069,9 @@ class DecodeEngine:
                 **({"state_resets": turns.get("state_resets", 0),
                     "state_carries": turns.get("state_carries", 0)}
                    if self._slot_state else {}),
+                # a hybrid model: the state-space state its scans moved
+                **({"ssm_state_bytes_moved": turns.get("ssm_state_bytes", 0)}
+                   if self._ssm_step_bytes else {}),
                 **select,
             ),
             "page_journal": {

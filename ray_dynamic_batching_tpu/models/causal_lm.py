@@ -62,6 +62,17 @@ def merge_routing_counters(counters: jax.Array) -> jax.Array:
                       counters[:, 2].max(), counters[:, 3].sum()])
 
 
+# A chunk group's logits are made over EVERY row, ``[B, W, V]`` float32, and
+# one row a sequence is taken — where a row of them is at most this many
+# bytes (131,072 columns). A wider vocabulary (Falcon-H1's 261,120: 1.07 GB
+# for two 512-row chunks, twice that with the head's multiplier, beside a
+# model that fills the chip, and 7 ms of head product a 512-row chunk of
+# which one row is read) has the head read the taken rows alone
+# (``DecoderModule``'s ``head_rows``). From shapes; every configuration
+# under it (the widest: 65,536) traces the program it did (ROADMAP S13).
+CHUNK_LOGITS_ROW_BYTES = 2 ** 19
+
+
 class CausalLM(ServableModel):
     family = "causal_lm"
 
@@ -291,24 +302,32 @@ class CausalLM(ServableModel):
         ``ring_tables`` (a model with state by layer kind): each row's
         slot's ring table (``models/kv_state.py::ring_table``), through
         which its sliding layers write and read.
-        ``state_slots`` (a model with conv layers): each row's slot, whose
-        conv state the row starts from — ZEROS where the row starts its
-        prompt (``starts`` 0), whatever the slot's last tenant left — and
-        leaves at its true end (``attn_mask``'s real tokens); a row without
-        a real token (a filler, warm-up's) writes none back."""
+        ``state_slots`` (a model with a state a slot: conv layers, hybrid
+        layers): each row's slot, whose state (every plane of it:
+        ``conv_state``, ``ssm_state``) the row starts from — ZEROS where
+        the row starts its prompt (``starts`` 0), whatever the slot's last
+        tenant left — and leaves at its true end (``attn_mask``'s real
+        tokens); a row without a real token (a filler, warm-up's) writes
+        none back."""
         B, W = tokens.shape
         S = tables.shape[1] * cache.page_size
         conv = {}
-        if cache.conv_state is not None:
-            states = cache.conv_state          # [L_conv, slots, K - 1, D]
-            n_slots = states.shape[1]
-            rows = jnp.take(states, jnp.minimum(state_slots, n_slots - 1),
-                            axis=1)
+        # the planes that are ONE state a slot: [L, slots, ...]
+        slot_planes = [p for p in cache.planes() if p.table == "slot"]
+        if slot_planes:
+            n_slots = slot_planes[0].array.shape[1]
             real = attn_mask.sum(axis=1).astype(jnp.int32)
             conv["state_lens"] = real
-            # the layers see the ROWS' states; the slots' come back below
-            cache = cache.replace(conv_state=jnp.where(
-                (starts == 0)[None, :, None, None], 0, rows))
+            at = jnp.minimum(state_slots, n_slots - 1)
+            # The layers see the ROWS' states; the slots' come back below.
+            # A row's state is cut out of the plane where it lies (a slice
+            # a row: a gather over a 1.6 GB plane of matrices made XLA lay
+            # half of it out anew, twice).
+            cache = cache.replace(**{p.name: jnp.where(
+                (starts == 0).reshape((1, B) + (1,) * (p.array.ndim - 2)),
+                0, jnp.concatenate([
+                    jax.lax.dynamic_slice_in_dim(p.array, at[r], 1, axis=1)
+                    for r in range(B)], axis=1)) for p in slot_planes})
         positions = starts[:, None] + jnp.broadcast_to(
             jnp.arange(W)[None, :], (B, W)
         )
@@ -316,16 +335,26 @@ class CausalLM(ServableModel):
         # can run past logical capacity) steer to S: their scatter drops
         # at the sentinel and their outputs are never taken.
         positions = jnp.where(positions < S, positions, S)
+        head = {}
+        if 4 * self.cfg.vocab_size > CHUNK_LOGITS_ROW_BYTES:
+            head["head_rows"] = take_idx
+            take_idx = jnp.zeros_like(take_idx)     # of the ONE row made
         logits, new_cache, *counters = self._forward(
             params, tokens, positions, None, cache, scatter_writes=True,
             page_table=tables, kv_lengths=starts,
             moe_valid=attn_mask if moe_counters else None,
-            ring_tables=ring_tables, **conv,
+            ring_tables=ring_tables, **conv, **head,
         )
-        if conv:
-            new_cache = new_cache.replace(conv_state=states.at[
-                :, jnp.where(real > 0, state_slots, n_slots)].set(
-                    new_cache.conv_state, mode="drop"))
+        for p in slot_planes:
+            # ... and written back where it lay, a row at a time, in place;
+            # a row without a real token writes back what is there
+            plane, rows = p.array, getattr(new_cache, p.name)
+            for r in range(B):
+                kept = jax.lax.dynamic_slice_in_dim(plane, at[r], 1, axis=1)
+                plane = jax.lax.dynamic_update_slice_in_dim(
+                    plane, jnp.where(real[r] > 0, rows[:, r:r + 1], kept),
+                    at[r], axis=1)
+            new_cache = new_cache.replace(**{p.name: plane})
         taken = jnp.take_along_axis(
             logits, take_idx[:, None, None], axis=1
         )[:, 0]
@@ -432,7 +461,8 @@ class CausalLM(ServableModel):
         in_bounds = cache.lengths < cache.capacity
         active = jnp.logical_and(active, in_bounds)
         positions = cache.lengths[:, None]
-        # a slot's conv state moves on only where the slot advances
+        # a slot's state (conv, state-space) moves on only where the slot
+        # advances
         conv = ({} if cache.conv_state is None
                 else {"state_lens": active.astype(jnp.int32)})
         logits, new_cache, *counters = self._forward(
@@ -456,6 +486,16 @@ class CausalLM(ServableModel):
             if kind.conv:
                 # in (D -> 3D) and out (D -> D); the taps are no matmul
                 proj = 4 * c.d_model * c.d_model
+            elif kind.ssm:
+                # attention's four products, and beside them the mixer's in
+                # ([z | x B C | dt]) and out, and a token's pass over the
+                # state (decay, outer product, read-out: 3 multiply-adds an
+                # element)
+                proj = (c.d_model * c.head_dim * (
+                    c.num_heads + 2 * c.num_kv_heads)
+                    + c.num_heads * c.head_dim * c.d_model
+                    + c.d_model * (c.d_ssm + c.conv_width + c.ssm_heads)
+                    + c.d_ssm * c.d_model + 3 * c.d_ssm * c.ssm_state)
             elif kind.latent:
                 # the low-rank q and kv paths, keys and values expanded
                 nope = c.head_dim - c.rope_dim

@@ -160,9 +160,41 @@ class DecoderConfig:
     # (``PagedKVCache.conv_state``), and the paged pool holds the OTHER
     # layers alone. 0: no layer is one.
     conv_kernel: int = 0
+    # A layer whose letter is "H" is a HYBRID: a state-space mixer
+    # (models/ssm.py: Mamba-2, ``ssm_heads`` heads of ``ssm_head_dim``
+    # channels, each a MATRIX state ``[ssm_head_dim, ssm_state]``, ``B`` and
+    # ``C`` shared by the heads of one of ``ssm_groups`` groups, blocks of
+    # ``ssm_chunk`` positions in a chunk's scan) AND attention, in parallel
+    # on the same normed input, their outputs summed. Its conv (depthwise,
+    # ``conv_kernel`` taps, a bias where ``conv_bias``, SiLU) is
+    # ``conv_width`` channels wide. Every such layer holds pages and a state
+    # a slot (``PagedKVCache.ssm_state`` / ``conv_state``). 0: no layer is.
+    ssm_state: int = 0
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_chunk: int = 128
+    conv_bias: bool = False
+    # The fixed scalars a model multiplies its activations by (Falcon-H1's
+    # twelve), each applied where it is published; 1: none.
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    # ... on the five zones [z | x | B | C | dt] of the mixer's first product
+    ssm_multipliers: Tuple[float, ...] = (1.0,) * 5
+    # ... on the MLP's gate (before its SiLU) and on its output
+    mlp_multipliers: Tuple[float, float] = (1.0, 1.0)
 
     def __post_init__(self):
         object.__setattr__(self, "hc_res_clamp", tuple(self.hc_res_clamp))
+        object.__setattr__(self, "ssm_multipliers",
+                           tuple(self.ssm_multipliers))
+        object.__setattr__(self, "mlp_multipliers",
+                           tuple(self.mlp_multipliers))
         if not self.head_dim:
             object.__setattr__(
                 self, "head_dim", self.d_model // self.num_heads)
@@ -200,12 +232,38 @@ class DecoderConfig:
             raise ValueError(
                 f"layer_pattern {self.layer_pattern!r}: letters are L "
                 "(sliding) and G (full)")
-        if ("C" in self.layer_pattern) != (self.conv_kernel >= 2):
+        if self.ssm_state:
+            if (set(self.layer_pattern) != {"H"} or self.conv_kernel < 2
+                    or not (self.ssm_heads and self.ssm_head_dim)
+                    or self.ssm_heads % self.ssm_groups
+                    or len(self.ssm_multipliers) != 5
+                    or len(self.mlp_multipliers) != 2):
+                raise ValueError(
+                    "ssm_state: a hybrid layer (H, every layer of its "
+                    "model) needs ssm_heads of ssm_head_dim in whole "
+                    "ssm_groups, its conv's taps (conv_kernel >= 2), five "
+                    "ssm_multipliers and two mlp_multipliers")
+            if (self.sliding_window or self.index_topk or self.latent
+                    or self.hc_mult > 1 or self.num_experts or self.qk_norm
+                    or self.norm != "rms" or self.pos != "rope"
+                    or not self.gated_mlp or self.use_bias
+                    or self.v_head_dim != self.head_dim):
+                raise ValueError(
+                    "ssm_state: a hybrid layer is a state-space mixer "
+                    "beside full GQA attention of k/v pairs over a dense "
+                    "SwiGLU, rotary, RMSNorm, no biases; a window, an "
+                    "indexer, a latent cache, residual streams, experts "
+                    "and a q/k norm are not built beside it")
+        elif "H" in self.layer_pattern:
+            raise ValueError(
+                f"layer_pattern {self.layer_pattern!r}: a hybrid layer (H) "
+                "needs its state's sizes (ssm_state)")
+        elif ("C" in self.layer_pattern) != (self.conv_kernel >= 2):
             raise ValueError(
                 f"layer_pattern {self.layer_pattern!r} with conv_kernel "
                 f"{self.conv_kernel}: a conv layer (C) needs its taps "
                 "(conv_kernel >= 2), and taps need a layer")
-        if self.conv_kernel and (
+        if self.conv_kernel and not self.ssm_state and (
                 self.sliding_window or self.index_topk or self.latent
                 or self.hc_mult > 1 or set(self.layer_pattern) - set("CG")
                 or self.norm != "rms"):
@@ -257,9 +315,28 @@ class DecoderConfig:
 
     @property
     def conv_layers(self) -> int:
-        """Layers whose mixer is a short convolution: they hold a state a
-        slot (``PagedKVCache.conv_state``) and no pages."""
+        """Layers that hold a conv state a slot
+        (``PagedKVCache.conv_state``): those whose mixer is a short
+        convolution (and no pages), or every layer of a hybrid model."""
+        if self.ssm_state:
+            return self.num_layers
         return sum(1 for i in range(self.num_layers) if self._convs(i))
+
+    @property
+    def conv_width(self) -> int:
+        """Channels of the depthwise conv, the last axis of
+        ``PagedKVCache.conv_state``: a gated short convolution's is the
+        residual's; a state-space mixer's is its ``[x | B | C]``, ``d_ssm +
+        2 * groups * state`` (Falcon-H1's 5,120 is its ``d_model`` by
+        coincidence)."""
+        if self.ssm_state:
+            return self.d_ssm + 2 * self.ssm_groups * self.ssm_state
+        return self.d_model
+
+    @property
+    def d_ssm(self) -> int:
+        """A state-space mixer's inner width, heads x head."""
+        return self.ssm_heads * self.ssm_head_dim
 
     def layer_kind(self, i: int) -> "LayerKind":
         """What layer ``i`` is: THE place a layer asks."""
@@ -270,6 +347,9 @@ class DecoderConfig:
             # its place among the layers of its own kind: its pool's layer
             by_kind = dict(ring=slides, pool_layer=sum(
                 1 for j in range(i) if self._slides(j) == slides))
+        elif self.ssm_state:
+            # every layer holds pages AND a state: its own index in both
+            by_kind = dict(ssm=True, pool_layer=i)
         elif self.conv_kernel:
             # likewise: a conv layer's place in the state plane, an
             # attention layer's among the layers that hold pages
@@ -299,8 +379,9 @@ class DecoderConfig:
     @property
     def pool_layers(self) -> int:
         """Layers of the paged pool where it is ONE pool: every layer but
-        the conv layers."""
-        return self.num_layers - self.conv_layers
+        the conv layers (a hybrid layer holds pages beside its state)."""
+        return self.num_layers - sum(
+            1 for i in range(self.num_layers) if self._convs(i))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -329,6 +410,11 @@ class LayerKind:
     # the taps' last inputs a slot (``PagedKVCache.conv_state``, layer
     # ``pool_layer`` of it), no pages.
     conv: bool = False
+    # A hybrid: a state-space mixer beside its attention, on the same
+    # normed input. It holds pages AND a state a slot
+    # (``PagedKVCache.ssm_state`` / ``conv_state``), layer ``pool_layer`` of
+    # each.
+    ssm: bool = False
 
 
 def apply_rope(
@@ -368,13 +454,18 @@ class RMSNorm(nn.Module):
 
 def swiglu(dense, y: jax.Array, width: int, d_model: int,
            names: Tuple[str, str, str] = ("mlp_gate", "mlp_up", "mlp_down"),
+           multipliers: Tuple[float, float] = (1.0, 1.0),
            ) -> jax.Array:
     """The dense gated MLP, ``(silu(y Wg) * (y Wu)) Wd``, from a layer's
     ``dense(features, name)`` factory: a dense layer's MLP and an expert
-    layer's shared expert are this one code."""
+    layer's shared expert are this one code. ``multipliers``: a model's
+    fixed scalars on the gate (before its SiLU) and on the output."""
     gate = dense(width, names[0])(y)
+    if multipliers[0] != 1.0:
+        gate = gate * multipliers[0]
     up = dense(width, names[1])(y)
-    return dense(d_model, names[2])(nn.silu(gate) * up)
+    out = dense(d_model, names[2])(nn.silu(gate) * up)
+    return out if multipliers[1] == 1.0 else out * multipliers[1]
 
 
 class DecoderLayer(nn.Module):
@@ -428,6 +519,16 @@ class DecoderLayer(nn.Module):
                 dtype=self.dtype, name=name)
         h, maps = hc("attn_hc")(x) if hc else (x, None)
         y = self._norm("attn_norm")(h).astype(self.dtype)
+        ssm_out = None
+        if kind.ssm:
+            # a state-space mixer beside the attention, on the same normed
+            # rows (its model alone loads the module)
+            from ray_dynamic_batching_tpu.models import ssm
+
+            ssm_out, cache_kv = ssm.mixer(
+                self, dense, kind, y, cache_kv, state_lens)
+            if cfg.attention_in_multiplier != 1.0:
+                y = y * cfg.attention_in_multiplier
         if kind.conv:
             # a gated short convolution (its model alone loads the module)
             from ray_dynamic_batching_tpu.models import short_conv
@@ -445,6 +546,10 @@ class DecoderLayer(nn.Module):
                 kv_lengths)
         if not kind.conv:
             attn_out = dense(cfg.d_model, "o", axis=(-2, -1))(attn_out)
+        if ssm_out is not None:
+            if cfg.attention_out_multiplier != 1.0:
+                attn_out = attn_out * cfg.attention_out_multiplier
+            attn_out = attn_out + ssm_out
         x = hyper_connections.mix(x, attn_out, maps) if hc else x + attn_out
 
         h, maps = hc("mlp_hc")(x) if hc else (x, None)
@@ -469,7 +574,8 @@ class DecoderLayer(nn.Module):
                 name="moe",
             )(y)
         elif cfg.gated_mlp:
-            y = swiglu(dense, y, kind.mlp_dim, cfg.d_model)
+            y = swiglu(dense, y, kind.mlp_dim, cfg.d_model,
+                       multipliers=cfg.mlp_multipliers)
         else:
             y = nn.gelu(dense(kind.mlp_dim, "mlp_up")(y))
             y = dense(cfg.d_model, "mlp_down")(y)
@@ -505,6 +611,8 @@ class DecoderLayer(nn.Module):
         q = dense((cfg.num_heads, cfg.head_dim), "q")(y)
         kv_heads = kind.kv_heads or cfg.num_kv_heads
         k = dense((kv_heads, cfg.head_dim), "k")(y)
+        if cfg.key_multiplier != 1.0:
+            k = k * cfg.key_multiplier
         v = dense((kv_heads, cfg.v_head_dim), "v")(y)
         if cfg.value_scale != 1.0:
             v = v * cfg.value_scale
@@ -753,7 +861,12 @@ class DecoderModule(nn.Module):
         kv_lengths: Optional[jax.Array] = None,
         ring_tables: Optional[jax.Array] = None,  # [B, NP]: rows' rings
         state_lens: Optional[jax.Array] = None,  # [B]: rows' real tokens
+        head_rows: Optional[jax.Array] = None,  # [B]: the head reads these
     ) -> Tuple[jax.Array, Optional[KVCache]]:
+        """(logits ``[B, T, V]``, the cache updated). ``head_rows``: the
+        ONE row a sequence whose logits the caller wants; the final norm and
+        the head then read those rows alone and the logits are ``[B, 1,
+        V]``."""
         cfg = self.cfg
         embed = nn.Embed(
             cfg.vocab_size,
@@ -763,6 +876,8 @@ class DecoderModule(nn.Module):
             name="tok_embed",
         )
         x = embed(tokens)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
         if cfg.pos == "learned":
             pos_embed = nn.Embed(
                 cfg.max_seq_len,
@@ -788,8 +903,9 @@ class DecoderModule(nn.Module):
             ring_tables = ring_table(
                 jnp.arange(tokens.shape[0], dtype=jnp.int32),
                 ring_pages, page_table.shape[1])
-        # A conv layer's state moves on by its row's REAL tokens (a chunk's
-        # unpadded rows; 1 or 0 for a decode row that advances or not).
+        # A state a slot (a conv layer's, a hybrid layer's) moves on by its
+        # row's REAL tokens (a chunk's unpadded rows; 1 or 0 for a decode
+        # row that advances or not).
         conv = {"state_lens": state_lens} if cfg.conv_kernel else {}
         for i in range(cfg.num_layers):
             kind = cfg.layer_kind(i)
@@ -806,6 +922,9 @@ class DecoderModule(nn.Module):
             if updated is not None:
                 cache = cache.with_layer_state(kind, updated)
 
+        if head_rows is not None:
+            x = jnp.take_along_axis(x, head_rows.reshape(
+                (-1,) + (1,) * (x.ndim - 1)), axis=1)
         if cfg.hc_mult > 1:
             # ... and the streams' sum is what the head reads
             x = x.astype(jnp.float32).sum(axis=2)
@@ -824,6 +943,8 @@ class DecoderModule(nn.Module):
                 param_dtype=jnp.float32,
                 name="lm_head",
             )(x)
+        if cfg.lm_head_multiplier != 1.0:
+            logits = logits * cfg.lm_head_multiplier
 
         return logits, cache
 

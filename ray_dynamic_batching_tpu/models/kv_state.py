@@ -36,11 +36,14 @@ def _is_int8(dtype: Any) -> bool:
 def state_kind(cfg: Any) -> str:
     """``"pair"`` (k/v pages for every layer; scale planes and index keys
     ride them), ``"by_kind"`` (full layers' pages + sliding layers' rings),
-    ``"latent"`` (one row a position, no k/v pair) or ``"conv"`` (k/v pages
+    ``"latent"`` (one row a position, no k/v pair), ``"conv"`` (k/v pages
     for the attention layers + a fixed-size state a slot for the conv
-    layers)."""
+    layers) or ``"ssm"`` (k/v pages for EVERY layer + a matrix state a head
+    and a conv state, a slot, for every layer's state-space mixer)."""
     if getattr(cfg, "latent", False):
         return "latent"
+    if getattr(cfg, "ssm_state", 0):
+        return "ssm"
     if getattr(cfg, "conv_kernel", 0):
         return "conv"
     return "by_kind" if getattr(cfg, "kv_by_kind", False) else "pair"
@@ -53,9 +56,11 @@ def state_kind(cfg: Any) -> str:
 # a ring, the slot's own; what moves or scales it as k/v pages of heads
 # cannot work with a latent row, which has neither. A conv layer's state is
 # the slot's own too, and is of its sequence's END: a page of a prefix holds
-# nothing of it, and no write to it can be undone without a snapshot.
+# nothing of it, and no write to it can be undone without a snapshot. A
+# state-space mixer's state (a matrix a head, 25 MB a slot for Falcon-H1's six
+# layers) is refused the same things for the same reasons.
 _STATE = {"by_kind": "state by layer kind", "latent": "a latent pool",
-          "conv": "a conv state a slot"}
+          "conv": "a conv state a slot", "ssm": "a state-space state a slot"}
 CANNOT: Dict[str, Dict[str, str]] = {
     "pair": {},
     "by_kind": {
@@ -119,6 +124,28 @@ CANNOT: Dict[str, Dict[str, str]] = {
             "a conv layer's state is the paged cache's "
             "(PagedKVCache.conv_state): the slab cache has none"),
     },
+    "ssm": {
+        "prefix_cache_size": (
+            "a borrowed page holds the layers' KV of a shared prefix and "
+            "nothing of their state-space state at its end: that takes a "
+            "snapshot of the state a prefix"),
+        "session_cache_size": (
+            "a stored session pins pages; the slot's state-space state is "
+            "overwritten by its next tenant"),
+        "host_spill_pages": "it spills the prefix cache, which is refused",
+        "draft_model": (
+            "spec verify rolls a rejected tail back by its lengths; a "
+            "state-space state moved on by the window cannot be moved back "
+            "without a snapshot"),
+        "mesh": "the state-space state has no sharding layout",
+        "kv_dtype int8": "the state-space state has no scale plane",
+        "parcel": (
+            "{name}: the page fabric moves a stream as the pages of its "
+            "table; the layers' state-space state a slot is not among them"),
+        "slab": (
+            "a state-space mixer's state is the paged cache's "
+            "(PagedKVCache.ssm_state): the slab cache has none"),
+    },
 }
 
 
@@ -162,11 +189,16 @@ def kv_bytes_per_slot(cfg: "DecoderConfig", dtype: Any, kv_dtype: Any,
                 + c.layers_of(True) * min(S, c.sliding_window)
                 * (c.sliding_kv_heads or c.num_kv_heads) * row)
     if c.conv_kernel:
-        # the attention layers a position; the conv layers their taps'
-        # last inputs, whatever the length
+        # the layers that hold pages a position; the layers that hold a
+        # state their taps' last inputs (as wide as the CONV, not the
+        # residual: ``conv_width``) and a state-space mixer's matrices in
+        # float32, whatever the length
         return (c.pool_layers * S * 2 * c.num_kv_heads * per_row
-                + c.conv_layers * (c.conv_kernel - 1) * c.d_model
-                * jnp.dtype(dtype).itemsize)
+                + c.conv_layers * (c.conv_kernel - 1) * c.conv_width
+                * jnp.dtype(dtype).itemsize
+                + (c.num_layers * c.d_ssm * c.ssm_state
+                   * jnp.dtype(SSM_STATE_DTYPE).itemsize
+                   if c.ssm_state else 0))
     return c.num_layers * S * (2 * c.num_kv_heads * per_row + index_row)
 
 
@@ -183,6 +215,7 @@ class LayerState(NamedTuple):
     index_k: Optional[jax.Array] = None
     latent: Optional[jax.Array] = None
     conv_state: Optional[jax.Array] = None  # a conv layer's: the rows' states
+    ssm_state: Optional[jax.Array] = None   # a state-space mixer's, likewise
 
 
 class Plane(NamedTuple):
@@ -206,7 +239,12 @@ _PLANES = (
     ("ring_k", "ring", True), ("ring_v", "ring", True),
     ("latent", "pages", False),
     ("conv_state", "slot", False),
+    ("ssm_state", "slot", False),
 )
+
+# A state-space mixer's matrices are kept in float32 whatever the model's
+# dtype: what is rounded into the state stays for the rest of the sequence.
+SSM_STATE_DTYPE = jnp.float32
 
 
 @pytree_dataclass
@@ -319,12 +357,22 @@ class PagedKVCache:
 
     A model with CONV layers (``DecoderConfig.conv_kernel``): ``k``/``v``
     hold its attention layers only (``L`` their count) and ``conv_state``
-    ``[L_conv, B, conv_kernel - 1, D]`` the conv layers' taps' last inputs:
+    ``[L_conv, B, conv_kernel - 1, W]`` the conv layers' taps' last inputs
+    (``W`` the conv's channels, ``DecoderConfig.conv_width``: the residual's
+    width for a gated short convolution):
     ONE state a slot a layer, no pages, no table, as large whatever the
     slot's length. It is of the slot's sequence at its END, so the engine's
     chunk program zeroes it where a prompt starts (a conv layer reads it
     unconditionally at position 0; a ring's stale rows are never attended)
-    and hands it from chunk to chunk. None for every other model."""
+    and hands it from chunk to chunk. None for every other model.
+
+    A HYBRID model (``DecoderConfig.ssm_state``): every layer holds pages
+    in ``k``/``v`` AND, for its state-space mixer, a ``conv_state`` as above
+    (``W`` = ``d_ssm + 2 * groups * state``) and ``ssm_state`` ``[L, B,
+    heads, head_dim, state]``: a MATRIX a head, in float32
+    (``SSM_STATE_DTYPE``) whatever the model's dtype: the one plane with a
+    dtype of its own. Zeroed and handed over as the conv state is; a decode
+    step updates it in place. None for every other model."""
 
     k: Optional[jax.Array]
     v: Optional[jax.Array]
@@ -337,6 +385,7 @@ class PagedKVCache:
     ring_v: Optional[jax.Array] = None
     latent: Optional[jax.Array] = None
     conv_state: Optional[jax.Array] = None
+    ssm_state: Optional[jax.Array] = None
 
     @staticmethod
     def zeros(
@@ -406,7 +455,11 @@ class PagedKVCache:
         if cfg.conv_kernel:
             conv["conv_state"] = jnp.zeros(
                 (cfg.conv_layers, batch_size, cfg.conv_kernel - 1,
-                 cfg.d_model), dtype)
+                 cfg.conv_width), dtype)
+        if cfg.ssm_state:
+            conv["ssm_state"] = jnp.zeros(
+                (cfg.num_layers, batch_size, cfg.ssm_heads,
+                 cfg.ssm_head_dim, cfg.ssm_state), SSM_STATE_DTYPE)
         # every layer, but for a model's conv layers: they hold no pages
         shape = (cfg.pool_layers, num_pages, page_size,
                  cfg.num_kv_heads // f, pool_head_dim(cfg.head_dim * f))
@@ -478,6 +531,9 @@ class PagedKVCache:
             return LayerState(self.ring_k, self.ring_v)
         if kind.conv:
             return LayerState(conv_state=self.conv_state)
+        if kind.ssm:
+            return LayerState(self.k, self.v, conv_state=self.conv_state,
+                              ssm_state=self.ssm_state)
         return LayerState(self.k, self.v, self.k_scale, self.v_scale,
                           self.index_k, self.latent)
 
@@ -488,7 +544,8 @@ class PagedKVCache:
         if kind.conv:
             return self.replace(conv_state=updated.conv_state)
         pools = updated._asdict()
-        del pools["conv_state"]     # an attention layer is handed none
+        if not kind.ssm:            # an attention layer is handed no state
+            del pools["conv_state"], pools["ssm_state"]
         return self.replace(**pools)
 
     # --- bytes ---------------------------------------------------------------
@@ -499,7 +556,7 @@ class PagedKVCache:
     def bytes_by_kind(self) -> Dict[str, int]:
         """:meth:`resident_bytes` by the planes' kind: ``full`` the paged
         pool, ``ring`` the sliding layers' rings, ``latent`` the rows,
-        ``state`` the conv layers' states."""
+        ``state`` the states a slot (conv and state-space)."""
         out: Dict[str, int] = {}
         for p in self.planes():
             out[p.kind] = out.get(p.kind, 0) + (
@@ -537,14 +594,16 @@ class PagedKVCache:
                 row_bytes=rows.shape[-1] * rows.dtype.itemsize,
                 bytes_by_kind=self.bytes_by_kind())
         if self.conv_state is not None:
-            state = self.conv_state
+            # a state a slot: each plane's shape, its OWN dtype and bytes
             out.update(
-                kind="conv", pool_layers=self.k.shape[0],
-                conv_state={
-                    "shape": list(state.shape), "dtype": str(state.dtype),
-                    "bytes_per_slot": math.prod(state.shape[2:])
-                    * state.shape[0] * state.dtype.itemsize},
-                bytes_by_kind=self.bytes_by_kind())
+                kind="conv" if self.ssm_state is None else "ssm",
+                pool_layers=self.k.shape[0],
+                bytes_by_kind=self.bytes_by_kind(),
+                **{p.name: {
+                    "shape": list(p.array.shape), "dtype": str(p.array.dtype),
+                    "bytes_per_slot": math.prod(p.array.shape[2:])
+                    * p.array.shape[0] * p.array.dtype.itemsize}
+                   for p in self.planes() if p.table == "slot"})
         if self.index_k is not None:
             out["index_pool"] = {
                 "shape": list(self.index_k.shape),

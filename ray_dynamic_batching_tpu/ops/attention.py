@@ -590,8 +590,8 @@ def _paged_attention(
     # hold no padding: the gathered pages are the slab view's own bytes.
     H = q.shape[-1]
     K = k.shape[3] * heads_per_row
-    k_g = from_pool_rows(logical(_pages(k, layer, safe, K)), K, H)
-    v_g = from_pool_rows(logical(_pages(v, layer, safe, K)), K, H)
+    k_g = from_pool_rows(logical(_pages(k, layer, safe, K, select)), K, H)
+    v_g = from_pool_rows(logical(_pages(v, layer, safe, K, select)), K, H)
     ks_g = vs_g = None
     if k_scale is not None:
         ks_g, vs_g = logical(k_scale[safe]), logical(v_scale[safe])
@@ -640,22 +640,26 @@ def _xla_attention(
 
 
 def _pages(pool: jax.Array, layer: int, safe: jax.Array,
-           kv_heads: int) -> jax.Array:
+           kv_heads: int, select: Optional[object] = None) -> jax.Array:
     """``pool[layer, safe]``: the pages ``safe`` ``[B, NP]`` of one layer of
-    a stacked pool ``[L, P, ps, K_pool, Hp]``. Where its rows hold heads
-    side by side (``kv_heads`` more than the rows) and a position is FEWER
-    rows than a sublane tile (8 KV heads of 64: 4 rows of 128), the pages
+    a stacked pool ``[L, P, ps, K_pool, Hp]``. Where a position is FEWER
+    rows than a sublane tile (8 KV heads of 64 side by side: 4 rows of 128;
+    4 KV heads of 128: 4 rows), the pages
     are gathered through the pool's view ``[ps * rows //
     8, 8, Hp]`` (the same bytes: a bitcast where a row is one lane tile)
     and viewed back: for a gather of 4-row positions XLA lays the WHOLE
     pool out anew, positions under rows — two pool-sized copies an
     attention layer a chunk program — and for one of 8-row tiles,
-    gpt2-medium's, it does not (``tools/pool_traffic.py``). Here at the
+    gpt2-medium's, it does not (``tools/pool_traffic.py``). A selecting
+    layer's gather (``select``: Keye's 4 heads of 128) keeps
+    ``pool[layer, safe]`` until its own cell has measured the view
+    (ROADMAP S8). Here at the
     file's END, and called from lines that were there: a Mosaic module
     carries its callers' source lines (PERF.md, section 7), and nothing
     above a kernel's call may move."""
     L, P, ps, rows, width = pool.shape
-    if (L > 1 and rows < min(8, kv_heads) and 8 % rows == 0 and width == 128
+    narrow = rows < kv_heads or (rows < 8 and select is None)
+    if (L > 1 and rows < 8 and narrow and 8 % rows == 0 and width == 128
             and pool.dtype.itemsize == 2 and (ps * rows) % 8 == 0):
         view = pool.reshape(L, P, ps * rows // 8, 8, width)
         return view[layer, safe].reshape(safe.shape + pool.shape[2:])

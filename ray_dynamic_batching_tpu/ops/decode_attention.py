@@ -1053,7 +1053,7 @@ def paged_decode_attention(
     # budgets the block the kernel will ACTUALLY stream on one core.
     k_local = tile_math.shard_heads(K, tp)
     kb = _pick_heads_block(k_local)
-    G = N // K
+    G, G0 = _group(N // K, kb, k_local, Tq, k_scale, sink, v_dim, tp), N // K
     if tile_math.paged_tile_bytes(
             ps, kb, Hk, k.dtype.itemsize,
             with_scales=k_scale is not None,
@@ -1086,8 +1086,8 @@ def paged_decode_attention(
     # Rows ordered (t, g) per kv head: [B, Tq, K, G, H] ->
     # [B, K, Tq*G, H] (Tq == 1 collapses to the historical layout),
     # zero-padded to the pool's row width.
-    q_r = q.reshape(B, Tq, K, G, H).transpose(0, 2, 1, 3, 4).reshape(
-        B, K, Tq * G, H
+    q_r = _rows(q.reshape(B, Tq, K, G0, H), G).transpose(
+        0, 2, 1, 3, 4).reshape(B, K, Tq * G, H
     )
     if Hk > H:
         q_r = jnp.pad(q_r, ((0, 0), (0, 0), (0, 0), (0, Hk - H)))
@@ -1110,7 +1110,7 @@ def paged_decode_attention(
         H = v_dim or H
     else:
         out = _paged_decode_attention(*operands, **static)
-    out = out[..., :H].reshape(B, K, Tq, G, H).transpose(
+    out = _rows(out[..., :H].reshape(B, K, Tq, G, H), G0).transpose(
         0, 2, 1, 3, 4).reshape(B, Tq, N, H)
     if f == 1:
         return out
@@ -1267,3 +1267,42 @@ def _walk(fold: int, ps: int, kb: int, H: int, itemsize: int,
         ps, kb, H, itemsize, window, G, narrow=fold > 1)
     return pages, tile_math.paged_walk_depth(
         ps, kb, H, itemsize, has_scales, window, G, pages)
+
+
+def _group(G: int, kb: int, k_local: int, Tq: int, k_scale, sink, v_dim,
+           tp: int) -> int:
+    """The query rows a KV head that the kernel is GIVEN: ``G``, or ``G``
+    padded with rows of zeros where that alone keeps a narrow head block
+    (fewer than 8 pool rows a position that are all of K, a float pool) off
+    the flat fold (:func:`_narrow_fold` wants ``kb * Tq * G`` rows in whole
+    sublane tiles): FIVE query heads a KV head over 4 KV heads (Falcon-H1's
+    GQA 20/4) are 20 rows, and the per-head form they would fall to costs
+    6.6 us a live page against 0.35 (PERF.md, PRs 41 and 52); as 4 x 6 they
+    fold flat. A zero row scores 0 everywhere, costs nothing the copy does
+    not hide, and its output is cut. A group that is a power of two (every
+    other configuration's, and the tests' small ones, whose few rows of one
+    or two heads the per-head form reads as it always did) is returned as
+    it is: their programs do not change. Here at the file's END, and called from
+    lines that were there: a Mosaic module carries its callers' source
+    lines (PERF.md, section 7)."""
+    from math import gcd
+
+    if (G & (G - 1) == 0 or kb != k_local or kb >= 8 or tp > 1
+            or (kb * Tq * G) % 8 == 0
+            or k_scale is not None or sink is not None or v_dim):
+        return G
+    step = 8 // gcd(kb * Tq, 8)
+    return -(-G // step) * step
+
+
+def _rows(x: jax.Array, G: int, axis: int = 3) -> jax.Array:
+    """``x`` ``[B, .., G0, H]`` with ``axis`` padded with zeros up to ``G``
+    rows, or cut back to ``G``; ``x`` itself where it already has ``G``."""
+    have = x.shape[axis]
+    if have == G:
+        return x
+    if have > G:
+        return jax.lax.slice_in_dim(x, 0, G, axis=axis)
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, G - have)
+    return jnp.pad(x, pad)

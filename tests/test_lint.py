@@ -1338,6 +1338,10 @@ class TestSharedTileMath:
         "mimo-v2-flash-ep16-1chip": ((2, 3), 12 * 2 ** 20 + 2 * 64 * 1024 * 4),
         "mimo-v2-flash-ep16-1chip/window": (
             (1, 3), 6 * 2 ** 20 + 2 * 64 * 1024 * 4),
+        # 4 KV heads of 128 under FIVE query heads each, given as 4 x 6 rows
+        # (da._group: 24 are whole sublane tiles, 20 are not): LFM2's ring
+        # of 3 groups of 2 pages, two [24, 1024] f32 score tiles
+        "falcon-h1-34b-1chip": ((2, 3), 6 * 2 ** 20 + 2 * 24 * 1024 * 4),
     }
 
     @staticmethod
@@ -1359,6 +1363,8 @@ class TestSharedTileMath:
             H, kv_heads, jnp.int8 if itemsize == 1 else jnp.bfloat16)
         rows_a_position, G = kv_heads // f, f * N // kv_heads
         kb = da._pick_heads_block(rows_a_position)
+        # (a group that is no whole sublane tiles is given rows of zeros)
+        G = da._group(G, kb, rows_a_position, 1, None, None, 0, 1)
         fold = da._narrow_fold(rows_a_position, kb, G, 128, itemsize == 1)
         walk = da._walk(fold, 128, kb, pool_head_dim(H * f), itemsize,
                         itemsize == 1, 1, G)

@@ -960,6 +960,125 @@ class TestHybridProgramsCompileForV5e:
         assert "_paged_decode_attention" in decode.as_text()
 
 
+class TestStateSpaceProgramsCompileForV5e:
+    """``falcon-h1-34b-1chip``'s programs at the published widths, 2 of its 6
+    layers, for a described v5e: the decode step takes the dense paged kernel
+    at GQA 20/4 (FIVE query heads a KV head, 4 pool rows a position: the
+    narrow arm) and updates the float32 state plane IN PLACE (no copy of
+    it; a second 1.6 GB would not fit the cell); the widest chunk program
+    gathers its pages where they lie (``ops/attention.py::_pages``: 4 KV
+    heads of 128 are 4 rows a position), cuts its rows' states out of the
+    plane a row at a time (a gather made XLA lay half the plane out anew,
+    twice) and makes one row of logits a sequence, not 512 (1.07 GB at
+    261,120 columns). The patterns the cell's metrics read are held to the
+    scopes' operations (the TPU's trace carries no scope). Nothing runs."""
+
+    def test_the_programs_compile_in_place_and_the_patterns_find_the_mixer(
+            self, one_chip, monkeypatch):
+        import json
+        import re
+        from pathlib import Path
+        from types import SimpleNamespace
+
+        from benchmark import trace_reduce
+        from benchmark.readers import ssm_state_update
+        from ray_dynamic_batching_tpu.models.causal_lm import CausalLM
+        from ray_dynamic_batching_tpu.models.decoder import DecoderConfig
+        from ray_dynamic_batching_tpu.ops import attention as attn_ops
+
+        root = Path(__file__).resolve().parents[1] / "benchmark"
+        cfg = json.loads((root / "configs"
+                          / "falcon-h1-34b-1chip.json").read_text())
+        layers = 2
+        cfg["num_hidden_layers"] = layers
+        metric = lambda name: json.loads((  # noqa: E731
+            root / "layer_metrics" / f"{name}.json").read_text())["args"]
+        in_proj = re.compile(metric("ssm_in_proj_dev_share_pct.batch")["op"])
+        scan = re.compile(metric("ssm_chunk_scan_dev_share_pct.batch")["op"])
+        update = re.compile(ssm_state_update.plane_pattern(cfg))
+        llm = cfg["deployment"]["llm"]
+        dc = dict(cfg["program"]["decoder_config"], num_layers=layers)
+        m = CausalLM(DecoderConfig(**dc), name="m", dtype=jnp.bfloat16)
+        B, ps, P = llm["num_slots"], llm["page_size"], llm["kv_pool_pages"]
+        W, NP = max(llm["prompt_buckets"]), llm["max_len"] // ps
+        struct = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dt, sharding=one_chip)
+        cache = jax.tree_util.tree_map(
+            lambda x: struct(x.shape, x.dtype),
+            jax.eval_shape(lambda: m.make_paged_cache(
+                B, P, ps, llm["max_len"])))
+        assert cache.k.shape == (layers, P, ps, 4, 128)
+        assert cache.ssm_state.shape == (layers, B, 32, 128, 256)
+        assert cache.ssm_state.dtype == jnp.float32
+        p = jax.tree_util.tree_map(
+            lambda x: struct(x.shape, jnp.bfloat16),
+            jax.eval_shape(m.init, jax.random.PRNGKey(0)))
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        attn_ops.clear_attention_paths()
+        compile_ = TestLatentProgramsCompileForV5e._compile
+        g = 2
+        chunk = compile_(
+            lambda *a: m.prefill_chunk_paged(*a[:-1], state_slots=a[-1]),
+            p, struct((g, W), jnp.int32), struct((g, W), jnp.int32), cache,
+            struct((g, NP), jnp.int32), struct((g,), jnp.int32),
+            struct((g,), jnp.int32), struct((g,), jnp.int32), donate=(3,))
+        decode = compile_(
+            m.decode_step_paged, p, struct((B, 1), jnp.int32), cache,
+            struct((B,), jnp.bool_), donate=(2,))
+        said = {(r.q_shape[1], r.describe())
+                for r in attn_ops.attention_paths() if r.path != "ssm"}
+        assert said == {(W, "gather-then-flash kernel"),
+                        (1, "paged kernel (stacked pool)")}
+        pool = rf"bf16\[{layers},{P},{ps},4,128\]"
+        plane = rf"f32\[{layers},{B},32,128,256\]"
+        found = {"decode": set(), "chunk": set()}
+        for which, compiled in (("chunk", chunk), ("decode", decode)):
+            text = compiled.as_text()
+            for shape in (pool, plane):
+                made = re.findall(rf"= {shape}\{{[^}}]*\}} ([\w\-]+)\(", text)
+                # (an update slice is the write in place, inside a fusion)
+                assert set(made) <= {"parameter", "scatter", "fusion",
+                                     "bitcast", "get-tuple-element",
+                                     "dynamic-update-slice"}, made
+            # the chunk's rows, scores and MLP, never a copy of the pool
+            # (0.27 GB here), of the plane (0.54 GB) or 512 rows of logits
+        # (``causal_lm.CHUNK_LOGITS_ROW_BYTES``)
+            assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9
+            comp = None
+            for line in text.splitlines():
+                opened = re.match(r"^(ENTRY )?%?([\w\.\-]+) \(.*\{\s*$",
+                                  line)
+                if opened:
+                    comp = opened.group(2)
+                    continue
+                if (comp is None or not line.startswith("  ")
+                        or comp.startswith(("fused_computation", "region"))):
+                    continue
+                line = line.strip().removeprefix("ROOT ")
+                if " parameter(" in line or " get-tuple-element(" in line:
+                    continue
+                name = trace_reduce.stable_name(SimpleNamespace(name=line))
+                op_name = re.search(r'op_name="([^"]*)"', line)
+                if not op_name:
+                    continue
+                for label, rx, scope in (
+                        ("in_proj", in_proj, "ssm_in_proj|ssm_in"),
+                        ("scan", scan, "ssm_chunk_scan|ssm_conv"),
+                        ("update", update,
+                         "ssm_state_update|dynamic_update_slice|scatter")):
+                    if label == "scan" and which == "decode":
+                        continue
+                    if label == "update" and which == "chunk":
+                        continue
+                    if rx.search(name):
+                        assert re.search(scope, op_name.group(1)), (
+                            label, name, op_name.group(1))
+                        found[which].add(label)
+        assert found == {"decode": {"in_proj", "update"},
+                         "chunk": {"in_proj", "scan"}}
+        assert "_paged_decode_attention" in decode.as_text()
+
+
 class TestSelectionsOperationsInTheCompiledPrograms:
     """``sparse_select_dev_share_pct.batch`` finds the index scan and the
     top-k in a device trace by the HLO lines of the cell's programs (the

@@ -74,6 +74,15 @@ import sys
 REPO = pathlib.Path(__file__).resolve().parent.parent
 CONTROLS = ("none", "drop_bias", "drop_gate_scale", "int8_pool",
             "dense_attention", "float8_reference")
+# With --harness, a hybrid (attention + state-space) configuration's: the
+# program served as it is and the REFERENCE wrong on purpose, without one of
+# a layer's two mixers, or with the state-space state not carried across
+# the first chunk's edge (benchmark/reference/falcon_h1.py's keywords).
+REFERENCE_CONTROLS = {
+    "no_ssm_reference": {"without": ("ssm",)},
+    "no_attention_reference": {"without": ("attention",)},
+    "cut_state_reference": {"cut_state_at": 512},
+}
 GAPS = (0.002, 0.004, 0.006, 2.0 ** -7, 0.012, 0.016)
 # A selecting model's served logits past ``index_topk`` positions: the root
 # mean square gap to the reference the served path must stay within. At the
@@ -101,17 +110,22 @@ def seeding_with(seeding, overrides):
     return ask
 
 
-def harness_checks(cfg, control: str, seeds, few_programs: bool) -> int:
+def harness_checks(cfg, control: str, seeds, few_programs: bool,
+                   also=()) -> int:
     """``run.py``'s deployment and its reference check, a seed at a time; a
-    control changes what is SERVED and nothing the reference reads."""
+    control changes what is SERVED and nothing the reference reads, but for
+    ``float8_reference`` and ``also`` (``float8_reference`` or names of
+    ``REFERENCE_CONTROLS``): those change the REFERENCE, and ``also``'s run
+    one after another on the same deployment, after the control's own."""
     import copy
+    import functools
     import gc
     import types
 
     import jax
     import numpy as np
 
-    from benchmark import run, weights
+    from benchmark import reference, run, weights
 
     make_params = weights.make_params
     failed = 0
@@ -152,22 +166,50 @@ def harness_checks(cfg, control: str, seeds, few_programs: bool) -> int:
                     view(jax.tree_util.tree_map_with_path(
                         lambda path, x: biases.get(
                             jax.tree_util.keystr(path), x), params), config)))
+            # kept on the host, a leaf at a time: the chip does not hold
+            # the weights twice beside a deployment that fills it
+            served_view = dep.view
+            def rounded(x):
+                if not jax.numpy.issubdtype(x.dtype, jax.numpy.floating):
+                    return np.asarray(x)
+                f8 = lambda a: np.asarray(a.astype(  # noqa: E731
+                    jax.numpy.float8_e4m3fn).astype(x.dtype))
+                if not x.ndim:
+                    return f8(x)
+                # (a leaf of gigabytes, an untied head's, 128 MB at a time)
+                step = -(-x.shape[0] // max(1, x.nbytes // 2 ** 27))
+                return np.concatenate([
+                    f8(x[a:a + step]) for a in range(0, x.shape[0], step)])
+
+            float8_view = types.SimpleNamespace(view=lambda params, config: (
+                jax.tree_util.tree_map(rounded, view(params, config))))
             if control == "float8_reference":
-                # kept on the host, a leaf at a time: the chip does not hold
-                # the weights twice beside a deployment that fills it
-                dep.view = types.SimpleNamespace(view=lambda params, config: (
-                    jax.tree_util.tree_map(
-                        lambda x: np.asarray(x.astype(
-                            jax.numpy.float8_e4m3fn).astype(x.dtype))
-                        if jax.numpy.issubdtype(x.dtype, jax.numpy.floating)
-                        else np.asarray(x), view(params, config))))
+                dep.view = float8_view
             got = run.reference_check(dep, seed)
+            print(f"harness: control {control} seed {seed}: ok={got['ok']} "
+                  f"worst margin {got['worst_gap']:.4f} (tolerance "
+                  f"{got['tol']})", flush=True)
+            # ... and, on the SAME deployment, each wrong reference asked
+            # for beside it (what is served does not change)
+            module = reference.get(c["reference"])
+            plain = module.logits
+            for name in also:
+                if name == "float8_reference":
+                    dep.view = float8_view
+                else:
+                    module.logits = functools.partial(
+                        plain, **REFERENCE_CONTROLS[name])
+                try:
+                    wrong = run.reference_check(dep, seed)
+                finally:
+                    module.logits, dep.view = plain, served_view
+                print(f"harness: control {name} seed {seed}: "
+                      f"ok={wrong['ok']} worst margin "
+                      f"{wrong['worst_gap']:.4f} (tolerance {wrong['tol']})",
+                      flush=True)
         finally:
             dep.close()
         failed += not got["ok"]
-        print(f"harness: control {control} seed {seed}: ok={got['ok']} worst "
-              f"margin {got['worst_gap']:.4f} (tolerance {got['tol']})",
-              flush=True)
         del dep
         gc.collect()
         live = sum(x.nbytes for x in jax.live_arrays())
@@ -294,6 +336,9 @@ def main() -> int:
     ap.add_argument("--harness", default="", metavar="SEEDS",
                     help="run.py's own reference_check, once a seed")
     ap.add_argument("--few-programs", action="store_true")
+    ap.add_argument("--also", default="", metavar="CONTROLS",
+                    help="with --harness: wrong REFERENCES checked on the "
+                         "same deployment (names of REFERENCE_CONTROLS)")
     ap.add_argument("--gap-sweep", default="", metavar="GAPS",
                     help="with --harness: what each undecided_score_gap "
                          "excuses and leaves, once a seed")
@@ -344,7 +389,8 @@ def main() -> int:
     if a.harness:
         return harness_checks(cfg, a.control,
                               [int(x) for x in a.harness.split(",")],
-                              a.few_programs)
+                              a.few_programs,
+                              [x for x in a.also.split(",") if x])
     prog, llm = cfg["program"], cfg["deployment"]["llm"]
     dtype = jnp.dtype(a.dtype or prog["dtype"])
     model = model_factory(prog, "logits_check")(dtype=dtype)
