@@ -23,8 +23,12 @@ within-block product, one state a block, the short recurrence over blocks),
 starts from the state its slot holds (zeros where the prompt starts) and
 leaves the state at its TRUE end: a padded position has ``d = 0``, which
 leaves ``S`` as it is and adds nothing. A decode row is one step of the
-recurrence on the plane in place (:func:`decode_update`); a slot that does
-not advance keeps its state bit for bit. Plain XLA.
+recurrence on the plane in place; a slot that does not advance keeps its
+state bit for bit. Where Pallas is on, that row is ONE kernel
+(``ops/ssm_update.py``: a tile of the plane read once, moved on, read out and
+written back where it lay); where it is not, or the kernel declines
+(``SsmPath.declines`` names why), :func:`decode_update` in plain XLA, as the
+chunk's scan is.
 """
 
 from __future__ import annotations
@@ -39,6 +43,8 @@ import numpy as np
 
 from ray_dynamic_batching_tpu.models import short_conv
 from ray_dynamic_batching_tpu.ops import attention as attn_ops
+from ray_dynamic_batching_tpu.ops import ssm_update
+from ray_dynamic_batching_tpu.ops.pallas_common import resolve_interpret
 
 PATH_SSM = "ssm"
 F32 = jnp.float32
@@ -49,26 +55,33 @@ class SsmPath(attn_ops.AttentionPath):
     """A state-space mixer's dispatch among
     ``ops.attention.attention_paths()``: ``q_shape`` the rows mixed ``[B, T,
     H, P]``, ``kv_shape`` the states they start from and leave (``[L, B, H,
-    P, N]``; ``()`` without a cache)."""
+    P, N]``; ``()`` without a cache); ``kernel``: a decode row went to
+    ``ops/ssm_update.py`` (else ``declines`` says why not)."""
+
+    kernel: bool = False
 
     def describe(self) -> str:
         if not self.kv_shape:
             return "state-space scan in XLA from a zero state"
         if self.q_shape[1] == 1:
             return ("state-space mixer, one step of the recurrence on the "
-                    f"{self.kv_dtype} state a slot in place (no pages)")
+                    f"{self.kv_dtype} state a slot in place (no pages), "
+                    + ("one kernel, the plane in place" if self.kernel
+                       else "in XLA"))
         return ("state-space mixer, a blocked scan in XLA from the "
                 f"{self.kv_dtype} state a slot (no pages)")
 
 
-def _record(x: jax.Array, states: Optional[jax.Array]) -> None:
+def _record(x: jax.Array, states: Optional[jax.Array], kernel: bool = False,
+            declines: Tuple[str, ...] = ()) -> None:
     attn_ops._PATHS.append(SsmPath(
         program=attn_ops.current_program(), path=PATH_SSM,
-        gathered=False, stacked=states is not None, tp=1, interpret=False,
+        gathered=False, stacked=states is not None, tp=1,
+        interpret=kernel and resolve_interpret(None),
         q_shape=tuple(x.shape),
         kv_shape=() if states is None else tuple(states.shape),
         kv_dtype=str(x.dtype if states is None else states.dtype),
-        declines=()))
+        declines=tuple(declines), kernel=kernel))
 
 
 # --- the family's initial values ------------------------------------------------
@@ -207,13 +220,22 @@ def mixer(layer: nn.Module, dense: Any, kind: Any, u: jax.Array,
         dt = jax.nn.softplus(dt.astype(F32) + dt_bias)    # [B, T, H]
 
     states = None if cache_kv is None else cache_kv.ssm_state
-    _record(x, states)
     if states is not None and T == 1:
         with jax.named_scope("ssm_state_update"):
-            y, S = decode_update(x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0],
-                                 states[li], state_lens)
+            row = (x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0])
+            declines = []
+            # the kernel takes the plane WHOLE and hands it back with this
+            # layer moved on where it lay: the old one is not read again
+            out = ssm_update.state_update(*row, states, li, state_lens,
+                                          declines)
+            _record(x, states, out is not None, declines)
+            if out is None:
+                y, S = decode_update(*row, states[li], state_lens)
+                out = y, states.at[li].set(S.astype(states.dtype))
+            y, states = out
             y = y[:, None]
     else:
+        _record(x, states)
         with jax.named_scope("ssm_chunk_scan"):
             if states is None:
                 S0 = jnp.zeros((B, H, P, N), F32)
@@ -223,11 +245,13 @@ def mixer(layer: nn.Module, dense: Any, kind: Any, u: jax.Array,
                 real = jnp.arange(T)[None, :] < state_lens[:, None]
                 dt = jnp.where(real[..., None], dt, 0.0)
             y, S = chunk_scan(x, dt, A, Bm, Cm, S0, cfg.ssm_chunk)
+            if states is not None:
+                states = states.at[li].set(S.astype(states.dtype))
     new_cache = None
     if cache_kv is not None:
         new_cache = cache_kv._replace(
             conv_state=cache_kv.conv_state.at[li].set(conv_new),
-            ssm_state=states.at[li].set(S.astype(states.dtype)))
+            ssm_state=states)
 
     with jax.named_scope("ssm_gate_norm"):
         y = (y + D[:, None] * x).reshape(B, T, d_ssm)

@@ -506,3 +506,30 @@ def paged_fold_pages(page_size: int, kb: int, H: int, kv_itemsize: int,
         lambda pages: paged_tile_bytes(
             page_size, kb, H, kv_itemsize, False, window, G,
             DOUBLE_BUFFER + 1, pages) <= VMEM_BLOCK_BUDGET_BYTES)
+
+
+def ssm_update_tile_bytes(hb: int, P: int, N: int) -> int:
+    """VMEM footprint of one grid step of the state-space update kernel
+    (``ops/ssm_update.py``): ``hb`` heads' float32 states ``[hb, P, N]``
+    in and out, each double-buffered, with the ``[hb, P]`` rows of ``dt x``
+    and ``y`` beside them."""
+    state = padded_block_bytes((hb, P, N), 4)
+    rows = padded_block_bytes((hb, P), 4)
+    return 2 * DOUBLE_BUFFER * (state + rows)
+
+
+def ssm_update_heads(group_heads: int, P: int, N: int) -> int:
+    """Heads a tile of the state-space update kernel, from the shapes
+    alone: a whole group (its heads share ONE row of ``B`` and of ``C``),
+    halved while it is even and its tiles pass ``VMEM_BLOCK_BUDGET_BYTES``.
+    Measured on a v5e at Falcon-H1's 64 slots x 32 heads x [128, 256]
+    float32 (PERF.md, PR 54; profiles/tpu_v5e/ssm_update.json): a layer
+    830.3 | 829.5 | 850.4 us at 16 | 8 | 4 heads a tile (2 | 1 | 0.5 MB)
+    against 1,186.1 in XLA, and 828.5 with the tile's two copies alone and
+    nothing computed: the copies set the pace, a step's ~0.35 us shows only
+    below 1 MB, and the whole group is never slower."""
+    hb = group_heads
+    while hb % 2 == 0 and ssm_update_tile_bytes(
+            hb, P, N) > VMEM_BLOCK_BUDGET_BYTES:
+        hb //= 2
+    return hb

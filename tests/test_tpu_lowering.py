@@ -964,8 +964,11 @@ class TestStateSpaceProgramsCompileForV5e:
     """``falcon-h1-34b-1chip``'s programs at the published widths, 2 of its 6
     layers, for a described v5e: the decode step takes the dense paged kernel
     at GQA 20/4 (FIVE query heads a KV head, 4 pool rows a position: the
-    narrow arm) and updates the float32 state plane IN PLACE (no copy of
-    it; a second 1.6 GB would not fit the cell); the widest chunk program
+    narrow arm) and updates the float32 state plane IN PLACE, one Pallas
+    kernel a layer with the plane aliased onto its first result
+    (``ops/ssm_update.py``; no copy of it: a second 1.6 GB would not fit the
+    cell, and the stable name of the kernel's HLO line is one that
+    ``ssm_state_update_roofline_pct``'s reader takes); the widest chunk program
     gathers its pages where they lie (``ops/attention.py::_pages``: 4 KV
     heads of 128 are 4 rows a position), cuts its rows' states out of the
     plane a row at a time (a gather made XLA lay half the plane out anew,
@@ -1029,6 +1032,10 @@ class TestStateSpaceProgramsCompileForV5e:
                 for r in attn_ops.attention_paths() if r.path != "ssm"}
         assert said == {(W, "gather-then-flash kernel"),
                         (1, "paged kernel (stacked pool)")}
+        rows = [r for r in attn_ops.attention_paths()
+                if r.path == "ssm" and r.q_shape[1] == 1]
+        assert len(rows) == layers and all(
+            r.kernel and not r.interpret and not r.declines for r in rows)
         pool = rf"bf16\[{layers},{P},{ps},4,128\]"
         plane = rf"f32\[{layers},{B},32,128,256\]"
         found = {"decode": set(), "chunk": set()}
@@ -1036,10 +1043,13 @@ class TestStateSpaceProgramsCompileForV5e:
             text = compiled.as_text()
             for shape in (pool, plane):
                 made = re.findall(rf"= {shape}\{{[^}}]*\}} ([\w\-]+)\(", text)
-                # (an update slice is the write in place, inside a fusion)
+                # (an update slice is the write in place, inside a fusion;
+                # the state's kernel is a custom call whose FIRST result,
+                # the plane, comes out of its tuple by get-tuple-element)
                 assert set(made) <= {"parameter", "scatter", "fusion",
                                      "bitcast", "get-tuple-element",
                                      "dynamic-update-slice"}, made
+                assert not re.search(rf"= {shape}\{{[^}}]*\}} copy\(", text)
             # the chunk's rows, scores and MLP, never a copy of the pool
             # (0.27 GB here), of the plane (0.54 GB) or 512 rows of logits
         # (``causal_lm.CHUNK_LOGITS_ROW_BYTES``)
@@ -1077,6 +1087,24 @@ class TestStateSpaceProgramsCompileForV5e:
         assert found == {"decode": {"in_proj", "update"},
                          "chunk": {"in_proj", "scan"}}
         assert "_paged_decode_attention" in decode.as_text()
+        # The state's kernel: one custom call a layer whose first result is
+        # the plane, under the mixer's scope, by a stable name the metric's
+        # reader takes (``args`` pass no ``kernel``: by SHAPE alone); the
+        # decode program's temporaries stay far below a plane's 0.54 GB here
+        # (17 MB at six layers)
+        calls = [ln.strip().removeprefix("ROOT ")
+                 for ln in decode.as_text().splitlines()
+                 if re.search(rf"= \({plane}\{{[^}}]*\}}, f32\[{B},[\d,]*128\]"
+                              r"\{[^}]*\}\) custom-call\(", ln)]
+        assert len(calls) == layers, calls
+        for line in calls:
+            name = trace_reduce.stable_name(SimpleNamespace(name=line))
+            assert name == f"_ssm_state_update_f32_{layers}_{B}_32_128_256_"
+            assert update.search(name)
+            assert "ssm_state_update" in re.search(
+                r'op_name="([^"]*)"', line).group(1)
+        assert "_ssm_state_update" not in chunk.as_text()
+        assert decode.memory_analysis().temp_size_in_bytes < 0.1e9
 
 
 class TestSelectionsOperationsInTheCompiledPrograms:

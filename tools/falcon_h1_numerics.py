@@ -12,6 +12,9 @@ weights (``benchmark/weights.py`` by the configuration's view), a few slots;
 the rows compared are each chunk's taken row and every decode step's, against
 ``benchmark/reference/<name>.py``'s one full forward: root mean square and
 largest absolute gap of the logits, and the reference's own spread beside them.
+Each backend's line ends with the paths its programs traced
+(``attention_paths()``): the state-space mixer's decode row says ``one kernel,
+the plane in place`` (``ops/ssm_update.py``) or ``in XLA``.
 """
 
 from __future__ import annotations
@@ -73,11 +76,17 @@ def main() -> int:
             for b in range(B)]
     print(f"reference: {B} sequences of {P} + {a.decode} tokens, chunks of "
           f"{W}; logits' spread {np.std(want[0][P - 1:]):.3f}", flush=True)
-    chunk = jax.jit(model.prefill_chunk_paged, donate_argnums=(3,))
-    step = jax.jit(model.decode_step_paged, donate_argnums=(2,))
     for backend in a.backends.split(","):
         attn_ops.set_attention_backend(backend)
         attn_ops.clear_attention_paths()
+        # Fresh callables a backend: jit's trace cache is keyed by the
+        # function, and the backend it is traced under is not in the key
+        # (one pair for both would run the FIRST backend's programs twice)
+        chunk = jax.jit(
+            lambda *args, **kw: model.prefill_chunk_paged(*args, **kw),
+            donate_argnums=(3,))
+        step = jax.jit(lambda *args: model.decode_step_paged(*args),
+                       donate_argnums=(2,))
         cache = model.make_paged_cache(B, B * per_slot, ps, per_slot * ps)
         tables = np.arange(B * per_slot, dtype=np.int32).reshape(B, per_slot)
         cache = cache.replace(page_table=jnp.asarray(tables))

@@ -51,9 +51,24 @@ widths of its fold (``mask_fold<n>``: the kernel's picker,
 update) beside the floor they are compared with, and writes the rows and
 their slopes under ``pages_a_fold`` of the file, leaving its other keys.
 
-Usage: python tools/run_kernel_ab.py [out_dir] [--iters N] [--paged|--sparse]
+``--ssm`` measures a state-space mixer's decode row on the state plane
+(``models/ssm.py``) at the configuration that has one, the plane at the
+cell's own size (``SSM_GEOMETRY``: 1.6 GB), into ``ssm_update.json``: the XLA
+form (``ssm.decode_update`` written into its layer of the plane: the update
+one fusion, the read-out another that reads the state again) against the
+kernel (``ops/ssm_update.py``: a tile read once and written once, the plane
+in place) at each head block of ``--head-blocks 16,8`` (the kernel's picker,
+``ssm_update._heads_block``, patched; without the option, the block its
+shapes pick). One jitted program chains a call a layer on the donated plane,
+as a decode substep does. A row says microseconds a layer and a (slot,
+layer), and GB/s of the bytes the MODEL needs (a read and a write of a
+layer's states: ``benchmark/ssm_counts.py``'s count).
+
+Usage: python tools/run_kernel_ab.py [out_dir] [--iters N]
+                                     [--paged|--sparse|--ssm]
                                      [--only tag1,tag2] [--out-name F]
                                      [--pages-a-fold n1,n2]
+                                     [--head-blocks n1,n2]
 Writes <out_dir>/<F> (default kernel_ab.json, paged_steps.json with
 ``--paged``, in profiles/tpu_v5e) and prints one JSON summary line.
 ``--only`` restricts to named geometries (a couple of geometries are ~2
@@ -125,6 +140,11 @@ SPARSE_GEOMETRY = ("keye-vl2-30b-ep8-1chip", 8, 3456, 24, 144, 32, 4, 128,
 SPARSE_LENGTHS = (4608, 9216, 18300)
 SPARSE_FORMS = ("floor", "mask", "mask_untiled", "gather")
 FOLD_FORM = "mask_fold"     # + n: the mask form at n pages a fold
+
+# ``--ssm``: the state plane of the configuration that has a state-space
+# mixer, as its cell holds it: (tag, L layers, B slots, H heads, P a head,
+# N state, G groups).
+SSM_GEOMETRY = ("falcon-h1-34b-1chip", 6, 64, 32, 128, 256, 2)
 
 
 def fold_widths(widths: str):
@@ -537,6 +557,153 @@ def sparse_main(out_dir: str, out_name: str, iters: int,
     return 0 if ok and backend != "cpu" else 1
 
 
+def _time_ssm(iters: int, blocks=(0,), geometry=SSM_GEOMETRY,
+              samples: int = 5):
+    """Rows of a decode row's time on the state plane: the XLA form, then
+    the kernel at each head block of ``blocks`` (0: what the shapes pick),
+    each with its worst gaps to the XLA form on the same inputs (``y``, the
+    advanced states; a slot that does not advance and every other layer
+    must come back bit for bit)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_dynamic_batching_tpu.models import ssm
+    from ray_dynamic_batching_tpu.ops import attention as attn
+    from ray_dynamic_batching_tpu.ops import ssm_update
+
+    tag, L, B, H, P, N, G = geometry
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    x = jax.random.normal(keys[1], (B, H, P), jnp.float32)
+    dt = jax.nn.softplus(jax.random.normal(keys[2], (B, H)) - 3.0)
+    A = -jnp.exp(ssm.a_log_init(keys[3], (H,)))
+    Bm, Cm = (jax.random.normal(k, (B, G, N), jnp.float32)
+              for k in keys[4:6])
+    everyone = jnp.ones((B,), jnp.int32)
+    some = everyone.at[1::3].set(0)
+    picker = ssm_update._heads_block
+
+    def new_plane():
+        return jax.random.normal(keys[0], (L, B, H, P, N), jnp.float32)
+
+    def chain(layers, kernel: bool):
+        """A program of one row a layer of ``layers`` on the donated plane,
+        each layer's ``y`` the next one's ``x`` (one dependent chain, as
+        the decode program's layers are)."""
+        def run(plane, x, advance):
+            for li in layers:
+                if kernel:
+                    y, plane = ssm_update.state_update(
+                        x, dt, A, Bm, Cm, plane, li, advance)
+                else:
+                    y, S = ssm.decode_update(x, dt, A, Bm, Cm, plane[li],
+                                             advance)
+                    plane = plane.at[li].set(S)
+                x = x + 1e-3 * y
+            return plane, x, y
+        return jax.jit(run, donate_argnums=(0,))
+
+    def timed(program):
+        plane = program(new_plane(), x, everyone)[0]
+        plane.block_until_ready()
+        took = []
+        for _ in range(samples):
+            t0 = time.perf_counter()
+            for _ in range(iters):
+                plane, out, _ = program(plane, x, everyone)
+            out.block_until_ready()
+            took.append((time.perf_counter() - t0) * 1e6 / (iters * L))
+        return statistics.median(took), [min(took), max(took)]
+
+    rows = []
+    li = L // 2
+    attn.set_attention_backend("xla")
+    try:
+        want_plane, _, want_y = chain([li], False)(new_plane(), x, some)
+        want = np.asarray(want_plane[li]), np.asarray(want_y)
+        del want_plane
+        layer_us, spread = timed(chain(range(L), False))
+    finally:
+        attn.set_attention_backend("auto")
+    rows.append({"form": "xla", "heads_a_tile": None,
+                 "layer_us": layer_us, "layer_us_min_max": spread})
+    attn.set_attention_backend("pallas")
+    try:
+        for hb in blocks:
+            if hb:
+                ssm_update._heads_block = lambda *a, hb=hb: hb
+            try:
+                got_plane, _, got_y = chain([li], True)(
+                    new_plane(), x, some)
+                got = np.asarray(got_plane[li])
+                fresh = new_plane()     # a layer at a time: no gather
+                kept = all(bool(jnp.array_equal(got_plane[i], fresh[i]))
+                           for i in range(L) if i != li)
+                del fresh
+                still = np.asarray(some) == 0
+                rows.append({
+                    "form": "kernel",
+                    "heads_a_tile": ssm_update._heads_block(H, G, P, N),
+                    "max_abs_diff_y": float(np.abs(
+                        np.asarray(got_y) - want[1]).max()),
+                    "max_abs_diff_state": float(
+                        np.abs(got - want[0]).max()),
+                    "idle_slots_and_other_layers_bit_for_bit": kept and bool(
+                        np.array_equal(got[still], want[0][still])),
+                })
+                del got_plane
+                layer_us, spread = timed(chain(range(L), True))
+                rows[-1].update(layer_us=layer_us, layer_us_min_max=spread)
+            finally:
+                ssm_update._heads_block = picker
+    finally:
+        attn.set_attention_backend("auto")
+    need = 2 * B * H * P * N * 4      # a layer's states read and written
+    for r in rows:
+        r.update(geometry=tag, slot_layer_us=r["layer_us"] / B,
+                 model_gb_per_s=need / r["layer_us"] / 1e3)
+    return rows
+
+
+def ssm_main(out_dir: str, out_name: str, iters: int, blocks) -> int:
+    import jax
+
+    backend = jax.default_backend()
+    rows = _time_ssm(iters, blocks)
+    record = {"backend": backend,
+              "device_kind": jax.devices()[0].device_kind,
+              "captured": time.strftime("%Y%m%dT%H%M%S"), "iters": iters,
+              "geometry": SSM_GEOMETRY[0], "rows": rows}
+    for r in rows:
+        print(f"{r['geometry']}: {r['form']}"
+              + (f" at {r['heads_a_tile']} heads a tile"
+                 if r["heads_a_tile"] else "")
+              + f": {r['layer_us']:.1f} us a layer, "
+              f"{r['slot_layer_us']:.3f} us a (slot, layer), "
+              f"{r['model_gb_per_s']:.1f} GB/s of the model's bytes"
+              + (f", max |kernel - xla| y {r['max_abs_diff_y']:.2e} "
+                 f"state {r['max_abs_diff_state']:.2e}"
+                 if r["form"] == "kernel" else ""), flush=True)
+    os.makedirs(out_dir, exist_ok=True)
+    path = os.path.join(out_dir, out_name)
+    if os.path.exists(path):    # what a builder wrote beside the rows stays
+        with open(path) as f:
+            kept = json.load(f)
+        record.update({k: kept[k] for k in ("note", "variants")
+                       if k in kept})
+    with open(path, "w") as f:
+        json.dump(record, f, indent=1)
+        f.write("\n")
+    print(json.dumps({
+        "metric": "ssm_update_layer_us", "backend": backend,
+        "layer_us": {f"{r['form']}@{r['heads_a_tile'] or 0}": r["layer_us"]
+                     for r in rows}}), flush=True)
+    ok = all(r["max_abs_diff_y"] < 1e-3 and r["max_abs_diff_state"] < 1e-3
+             and r["idle_slots_and_other_layers_bit_for_bit"]
+             for r in rows if r["form"] == "kernel")
+    return 0 if ok and backend != "cpu" else 1
+
+
 def _time_attention(backend: str, q, k, v, mask, iters: int,
                     k_scale=None, v_scale=None):
     """Median ms/step for the dispatched attention substep."""
@@ -577,6 +744,13 @@ def main() -> int:
         out_name = (sys.argv[sys.argv.index("--out-name") + 1]
                     if "--out-name" in sys.argv else "sparse_decode.json")
         return sparse_main(out_dir, out_name, iters, widths)
+    if "--ssm" in sys.argv:
+        out_name = (sys.argv[sys.argv.index("--out-name") + 1]
+                    if "--out-name" in sys.argv else "ssm_update.json")
+        blocks = ([int(n) for n in sys.argv[
+            sys.argv.index("--head-blocks") + 1].split(",")]
+            if "--head-blocks" in sys.argv else [0])
+        return ssm_main(out_dir, out_name, iters, blocks)
     if "--paged" in sys.argv:
         only = (set(sys.argv[sys.argv.index("--only") + 1].split(","))
                 if "--only" in sys.argv else None)
