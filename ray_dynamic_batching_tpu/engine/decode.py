@@ -339,7 +339,23 @@ class Turn(NamedTuple):
     ``ssm_state_bytes``: the bytes of state-space state its substeps had to
     move, active slots x layers x a slot's matrices (in the plane's own
     dtype) x 2 (read and written) x substeps. 0 for a chunk group and for
-    any other model."""
+    any other model.
+
+    Where the engine thread's time went (:func:`thread_parts`: the records
+    are appended in ``t_done`` order, so consecutive ``t_done``s tile the
+    thread). ``t_fetch`` is ``now_ms()`` immediately before the one fetch
+    (0.0 exactly where ``t_fetched`` is), and ``ready_at_fetch`` the
+    result's ``is_ready()`` just before it: True, the device had finished
+    and waited for the host (the fetch is a copy); False, the host waited.
+    ``idle_ms``: the wall time inside ``rdb.engine.idle_wait`` since the
+    previous record (else 0.0). ``cpu_ms``: what the thread's CPU clock
+    (``time.thread_time()``) moved since the previous record was written;
+    0.0 where another thread wrote that one (the baseline is a thread's).
+    A READING, in no share: where that clock moves in 10 ms ticks it
+    charges a burst of a millisecond 0.6 to 8.6 times its length, and only
+    a stall of seconds is told by it (on the CPU, or off it). ``seq``: the
+    program's number (:meth:`DecodeEngine._note_issue`), which its
+    ``.dispatch`` and ``.fetch`` phases carry under a profiler session."""
 
     kind: str
     t_dispatch: float
@@ -367,14 +383,26 @@ class Turn(NamedTuple):
     state_resets: int = 0
     state_carries: int = 0
     ssm_state_bytes: int = 0
+    t_fetch: float = 0.0
+    ready_at_fetch: bool = False
+    idle_ms: float = 0.0
+    cpu_ms: float = 0.0
+    seq: int = 0
 
 
 # An engine's prompt buckets where its builder names none.
 DEFAULT_PROMPT_BUCKETS = (16, 32, 64, 128)
 
-# Sized for the benchmark's 51 s window at several times the cells'
-# highest dispatch rate (~30/s at a 33 ms one-substep scan): 160/s.
+# Holds the benchmark's 51 s window at 160 dispatches a second. The cells'
+# highest rates an engine are 100/s (chat), 129/s (long-context) and 134/s
+# (x4: 2,756 records in 20.6 s; builder's traced runs, PR 48): 6,830
+# records a window, a headroom of 1.2. A cell that dispatches faster wraps
+# the ring, and every reader of it then gives nothing (``turns_dropped``).
 _TURN_RING = 8192
+# A record whose thread was blocked in its fetch, or busy on the host's side,
+# for longer than this is logged as it is written
+# (``DecodeEngine._log_dispatch``).
+_STALL_WARN_MS = 1000.0
 _ENGINE_ORDINAL = itertools.count()   # numbers the engines of a process
 
 STARTUP_PROGRAM = "rdb.startup.warmup.program"
@@ -483,7 +511,10 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
     decode scans read. A model with conv layers adds ``state_resets``,
     ``state_carries`` and ``state_carried_chunk_share``: of the chunks run,
     those that began from a carried state. A hybrid model's scans add
-    ``ssm_state_bytes``, the state-space state they had to read and write."""
+    ``ssm_state_bytes``, the state-space state they had to read and write.
+    Two records or more add where the engine thread's time went
+    (:func:`thread_tiling`: ``thread_ms``, the three ``thread_*_share``,
+    ``fetch_found_ready_share``, ``longest_records``)."""
     scans = [t for t in turns if t.kind == "turn"]
     out: Dict[str, Any] = {"dispatches": len(turns), "scans": len(scans),
                            "dropped": dropped}
@@ -567,6 +598,7 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
         for g, prev, cur in heapq.nlargest(
             longest, gaps, key=lambda g: g[0])
     ]
+    out.update(thread_tiling(turns, longest))
     return out
 
 
@@ -1004,6 +1036,11 @@ class DecodeEngine:
         self.turns_dropped = 0
         self._last_scan: Optional[Turn] = None  # newest "turn" record
         self._idled = False
+        # Since the previous record: the wall time inside idle waits; and
+        # the CPU clock of the thread that wrote it (``Turn.cpu_ms``).
+        self._idle_ms = 0.0
+        self._cpu_mark = 0.0
+        self._cpu_thread: Optional[int] = None
         # Programs issued, and the newest of them a fetch has proven done:
         # their difference at a dispatch is its ``Turn.queued_behind``.
         self._programs_issued = 0
@@ -1198,13 +1235,21 @@ class DecodeEngine:
                       queued_behind: int = 0,
                       kv_full_pages_live: int = 0,
                       kv_latent_rows: int = 0,
-                      state_turns: Tuple[int, int] = (0, 0)) -> Turn:
+                      state_turns: Tuple[int, int] = (0, 0),
+                      fetch: Tuple[float, bool] = (0.0, False),
+                      seq: int = 0) -> Turn:
         """Append this dispatch's record to the turn ring (its work on the
         host is done: ``t_done`` is now). ``moe``: the dispatch's routing
         counters as fetched (``Turn``'s ``moe_*`` fields);
         ``kv_pages_live``: :meth:`_kv_pages_live` as the scan was
         dispatched; ``kv_rows``: :meth:`_kv_rows` then; ``queued_behind``:
-        :meth:`_note_issue` then."""
+        and ``seq``: :meth:`_note_issue` then; ``fetch``: ``Turn``'s
+        ``t_fetch`` and ``ready_at_fetch``. The thread's CPU clock is read
+        here (once a record), and a record that stalled is logged."""
+        cpu, me = time.thread_time(), threading.get_ident()
+        same_thread = me == self._cpu_thread
+        cpu_ms = (cpu - self._cpu_mark) * 1000.0 if same_thread else 0.0
+        self._cpu_mark, self._cpu_thread = cpu, me
         rec = Turn(
             kind, t_dispatch, t_issued, t_fetched, now_ms(),
             substeps, tokens, active, trains, len(self.queue),
@@ -1214,13 +1259,26 @@ class DecodeEngine:
             *kv_rows, queued_behind, kv_full_pages_live, kv_latent_rows,
             *state_turns,
             active * substeps * self._ssm_step_bytes if kind == "turn" else 0,
+            *fetch, self._idle_ms, cpu_ms, seq,
         )
+        if same_thread and self.turns:
+            parts = thread_parts(self.turns[-1], rec)
+            if max(parts.blocked, parts.host) > _STALL_WARN_MS:
+                logger.warning(
+                    "%s: a %s record took %.0f ms of the engine thread: "
+                    "blocked in its fetch %.0f, idle %.0f, the host's side "
+                    "%.0f (its CPU clock moved %.0f) "
+                    "(substeps=%d queued_behind=%d)",
+                    self.model.name, kind, parts.wall, parts.blocked,
+                    parts.idle, parts.host, parts.cpu, substeps,
+                    queued_behind)
         if len(self.turns) == self.turns.maxlen:
             self.turns_dropped += 1
         self.turns.append(rec)
         if kind == "turn":
             self._last_scan = rec
         self._idled = False
+        self._idle_ms = 0.0
         return rec
 
     def _note_issue(self) -> Tuple[int, int]:
@@ -2374,7 +2432,8 @@ class DecodeEngine:
                                    state_turns))
         seq, behind = self._note_issue()
         t_dispatch = now_ms()
-        with self._phase("rdb.engine.prefill.dispatch", **state_attrs):
+        with self._phase("rdb.engine.prefill.dispatch", seq=seq,
+                         **state_attrs):
             first, self._cache = self._chunk_paged_fn(
                 self.params, jnp.asarray(packed), self._cache)
         t_issued = now_ms()
@@ -2388,10 +2447,13 @@ class DecodeEngine:
         """What a dispatched group leaves to do: where rows ended their
         prompt, fetch their first tokens, retire the trains, publish their
         prefix pages and register the slots; then the ring's record."""
-        t_fetched = 0.0
+        t_fetched, fetch = 0.0, (0.0, False)
         moe = (0, 0, 0, 0)
         if issued.finals:
-            with self._phase("rdb.engine.prefill.fetch"):
+            ready = issued.first.is_ready()
+            with self._phase("rdb.engine.prefill.fetch", seq=issued.seq,
+                             ready=int(ready)):
+                fetch = (now_ms(), ready)
                 first_host = np.asarray(issued.first)  # rdb-lint: disable=host-sync-in-hot-path (THE one fetch per chunk dispatch: the fused first-token ids — TTFT ends here, never at a logits round-trip)
             t_fetched = now_ms()
             self._note_fetched(issued.seq)
@@ -2420,7 +2482,8 @@ class DecodeEngine:
                            issued.active, issued.pending, moe,
                            queued_behind=issued.queued_behind,
                            state_turns=(issued.state_resets,
-                                        issued.state_carries))
+                                        issued.state_carries),
+                           fetch=fetch, seq=issued.seq)
 
     def _retire_train(self, train: _ChunkTrain) -> None:
         if train in self._trains:
@@ -3135,7 +3198,7 @@ class DecodeEngine:
                     ])
                 seq, behind = self._note_issue()
                 t_dispatch = now_ms()
-                with self._phase("rdb.engine.turn.dispatch"):
+                with self._phase("rdb.engine.turn.dispatch", seq=seq):
                     packed, self._cache, self._dcache = self._spec_fn(
                         self.params,
                         self._cache,
@@ -3145,7 +3208,10 @@ class DecodeEngine:
                         bias_vals_d,
                     )
                 t_issued = now_ms()
-                with self._phase("rdb.engine.turn.fetch"):
+                ready = packed.is_ready()
+                with self._phase("rdb.engine.turn.fetch", seq=seq,
+                                 ready=int(ready)):
+                    fetch = (now_ms(), ready)
                     ph_host = np.asarray(packed)  # ONE fetch per round  # rdb-lint: disable=host-sync-in-hot-path (THE one fetch per spec round: ph_host carries tokens+counts+lengths packed)
             except BaseException:
                 self._rollback_spec_scratch()
@@ -3195,7 +3261,8 @@ class DecodeEngine:
             rec = self._log_dispatch("turn", t_dispatch, t_issued, t_fetched,
                                      1, 0, active, len(self._trains),
                                      kv_pages_live=kv_pages_live,
-                                     kv_rows=kv_rows, queued_behind=behind)
+                                     kv_rows=kv_rows, queued_behind=behind,
+                                     fetch=fetch, seq=seq)
             if links is not None:
                 self._record_turn_span(rec, links, k, spec=True)
 
@@ -3251,7 +3318,7 @@ class DecodeEngine:
         ph.set_metadata(horizon=h, active=active, spec=0)
         seq, behind = self._note_issue()
         t_dispatch = now_ms()
-        with self._phase("rdb.engine.turn.dispatch"):
+        with self._phase("rdb.engine.turn.dispatch", seq=seq):
             packed, self._cache, self._counts = self._decode_fn(
                 self.params,
                 self._cache,
@@ -3276,7 +3343,10 @@ class DecodeEngine:
         h, active_at_dispatch = issued.h, issued.active_at_dispatch
         active = int(active_at_dispatch.sum())
         ph.set_metadata(horizon=h, active=active, spec=0)
-        with self._phase("rdb.engine.turn.fetch"):
+        ready = issued.packed.is_ready()
+        with self._phase("rdb.engine.turn.fetch", seq=issued.seq,
+                         ready=int(ready)):
+            fetch = (now_ms(), ready)
             packed_host = np.asarray(issued.packed)   # ONE fetch per dispatch  # rdb-lint: disable=host-sync-in-hot-path (THE one fetch per dispatch: packed carries tokens+advanced+lengths)
         t_fetched = now_ms()
         self._note_fetched(issued.seq)
@@ -3314,7 +3384,8 @@ class DecodeEngine:
             kv_pages_live=issued.kv_pages_live, kv_rows=issued.kv_rows,
             queued_behind=issued.queued_behind,
             kv_full_pages_live=issued.kv_full_pages_live,
-            kv_latent_rows=issued.kv_latent_rows)
+            kv_latent_rows=issued.kv_latent_rows,
+            fetch=fetch, seq=issued.seq)
         if links is not None:
             self._record_turn_span(rec, links, h)
 
@@ -3840,8 +3911,10 @@ class DecodeEngine:
                 try:
                     _admitted, turned = self._iterate()
                     if not turned and not self._trains:
+                        t_idle = now_ms()
                         with self._phase("rdb.engine.idle_wait"):
                             self.queue.wait_for_requests(self.idle_wait_s)
+                        self._idle_ms += now_ms() - t_idle
                         self._idled = True
                     self.last_heartbeat = time.monotonic()
                 except Exception:  # noqa: BLE001 — engine must not die silently
@@ -4125,3 +4198,71 @@ class DecodeEngine:
         return (self._admitting > 0 or bool(self._trains)
                 or bool(self._active_mask.any())
                 or self._fabric_pending())
+
+
+# --- the engine thread's time, tiled from the ring ---------------------------
+# Below the engine and its programs on purpose: an edit here moves no line
+# of a jitted function (the chip's compile cache keys on them).
+class ThreadParts(NamedTuple):
+    """Where the engine thread's time went over one tile (ms, wall clock):
+    ``blocked``, ``idle`` and ``host`` sum to ``wall`` where nothing was
+    ``clipped``; ``cpu`` is what the thread's CPU clock charged the tile
+    (``Turn.cpu_ms``: a reading beside the three, part of none)."""
+
+    wall: float
+    blocked: float
+    idle: float
+    host: float
+    cpu: float
+    clipped: float
+
+
+def thread_parts(prev: Turn, rec: Turn) -> ThreadParts:
+    """The tile that ends at ``rec``: ``wall`` from the previous record's
+    ``t_done`` to its own; ``blocked`` in its fetch, where the result was
+    not ready when the host came for it (a fetch that found it ready is a
+    copy, and counts as the host's); ``idle`` inside idle waits; and
+    ``host``, the rest: the thread had work on the host's side (prepare,
+    the jitted call with its uploads, harvest, admission, the record's own
+    writing), whether it ran or wanted to run and did not (the interpreter
+    lock, the scheduler, the runtime's threads inside the call). A rest
+    below 0 (hand-built records; never one thread's own) is ``clipped`` to
+    0 and kept."""
+    wall = rec.t_done - prev.t_done
+    blocked = (rec.t_fetched - rec.t_fetch
+               if rec.t_fetch and not rec.ready_at_fetch else 0.0)
+    rest = wall - blocked - rec.idle_ms
+    return ThreadParts(wall, blocked, rec.idle_ms, max(rest, 0.0),
+                       rec.cpu_ms, max(-rest, 0.0))
+
+
+def thread_tiling(turns: Sequence[Turn], longest: int = 8) -> Dict[str, Any]:
+    """:func:`summarize_turns`' keys for the engine thread's time, over
+    consecutive records (the first has no predecessor and ends no tile; a
+    list with a record left out of its middle gives that record's time to
+    the next one's ``host``): ``thread_ms``, the tiles' parts summed; the
+    three ``thread_{blocked,idle,host}_share``, each part over the three's
+    sum (``wall`` + ``clipped``: they sum to 1);
+    ``fetch_found_ready_share`` of the fetched records; and
+    ``longest_records``, the ``longest`` tiles of largest ``wall`` with
+    their own parts and what the record was."""
+    tiles = [(thread_parts(a, b), b) for a, b in zip(turns, turns[1:])]
+    if not tiles:
+        return {}
+    sums = ThreadParts(*(sum(col) for col in zip(*(p for p, _ in tiles))))
+    out: Dict[str, Any] = {"thread_ms": sums._asdict()}
+    whole = sums.wall + sums.clipped
+    if whole > 0:
+        out.update((f"thread_{name}_share", getattr(sums, name) / whole)
+                   for name in ("blocked", "idle", "host"))
+    fetched = [t for t in turns if t.t_fetched]
+    if fetched:
+        out["fetch_found_ready_share"] = sum(
+            1 for t in fetched if t.ready_at_fetch) / len(fetched)
+    out["longest_records"] = [
+        dict({k: round(v, 3) for k, v in p._asdict().items()},
+             kind=t.kind, substeps=t.substeps,
+             queued_behind=t.queued_behind,
+             t_dispatch=round(t.t_dispatch, 3))
+        for p, t in heapq.nlargest(longest, tiles, key=lambda x: x[0].wall)]
+    return out

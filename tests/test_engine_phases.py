@@ -37,6 +37,10 @@ CHILDREN = {
                            "rdb.engine.prefill.fetch",
                            "rdb.engine.prefill.finish"},
 }
+# What ``summarize_turns`` says of the engine thread's time (ISSUE 55).
+SHARES = ("thread_blocked_share", "thread_idle_share", "thread_host_share")
+TILING_KEYS = ("thread_ms", *SHARES, "fetch_found_ready_share",
+               "longest_records")
 # The share of the engine thread's time, between its first and its last
 # phase, that no top-level phase may leave uncovered: what lies between two
 # phases is the loop's own `while`, one `any()` and the heartbeat stamp.
@@ -504,6 +508,12 @@ def test_a_ring_with_no_overlap_sums_as_it_did():
         _at("turn", 345.0, 380.0, 382.0, substeps=4),        # gap 5 = 1 + 4
     ]
     s = summarize_turns(ring, 4, span_ms=1000.0)
+    # ... beside the thread's tiling (ISSUE 55), which records without its
+    # fields give whole to the host's side
+    tiling = {k: s.pop(k) for k in TILING_KEYS}
+    assert tiling["thread_ms"] == {"wall": 230.0, "blocked": 0.0, "idle": 0.0,
+                                   "host": 230.0, "cpu": 0.0, "clipped": 0.0}
+    assert tiling["thread_host_share"] == 1.0
     assert s.pop("overlapped_dispatch_share") == pytest.approx(1 / 5)
     gaps = s.pop("longest_gaps")
     assert [g["gap_ms"] for g in gaps] == [5.0, 5.0]
@@ -533,3 +543,297 @@ def test_the_engines_ring_is_in_dispatch_order_with_overlap(lm):
     assert all(g["gap_ms"] >= 0 and g["harvest_ms"] >= 0 and g["feed_ms"] >= 0
                for g in s["longest_gaps"])
     assert 0.0 < s["overlapped_dispatch_share"] < 1.0
+
+
+# --- the engine thread's time, tiled from the ring (ISSUE 55) ----------------------
+def test_fetch_stamps_lie_between_issue_and_done_and_are_zero_together(lm):
+    engine, queue = _engine(lm)
+    reqs = _submit(queue, engine.model.name)
+    engine.run_until_idle(timeout_s=300)
+    for r in reqs:
+        r.future.result(timeout=5)
+    ring = list(engine.turns)
+    assert any(t.t_fetched == 0.0 for t in ring)
+    for t in ring:
+        assert (t.t_fetch == 0.0) == (t.t_fetched == 0.0)
+        if t.t_fetched:
+            assert t.t_issued <= t.t_fetch <= t.t_fetched <= t.t_done
+        else:
+            assert t.ready_at_fetch is False
+        assert isinstance(t.ready_at_fetch, bool)
+        assert t.cpu_ms >= 0.0
+        assert t.idle_ms == 0.0            # run_until_idle never waits
+    # one thread wrote them all: only the first found no baseline, and a
+    # thread's CPU clock never runs ahead of the wall (1 ms for the grain)
+    assert ring[0].cpu_ms == 0.0
+    for a, b in zip(ring, ring[1:]):
+        assert a.t_done <= b.t_done        # appended in ``t_done`` order
+        assert b.cpu_ms <= b.t_done - a.t_done + 1.0
+    # every record carries its program's number, each once
+    assert sorted(t.seq for t in ring) == list(
+        range(ring[0].seq, ring[0].seq + len(ring)))
+
+
+def test_a_scan_waited_out_before_its_fetch_is_found_ready(lm):
+    from ray_dynamic_batching_tpu.engine.decode import thread_parts
+
+    engine, queue = _engine(lm)
+    _submit(queue, engine.model.name, lens=(5,))
+    engine._admit()
+    engine._drain_prefill()
+    with engine._phase("rdb.engine.turn") as ph:
+        issued = engine._issue_turn(ph, 2)
+    issued.packed.block_until_ready()
+    time.sleep(0.01)
+    prev = engine.turns[-1]
+    with engine._phase("rdb.engine.turn") as ph:
+        engine._complete_turn(ph, issued)
+    rec = engine.turns[-1]
+    assert rec.kind == "turn" and rec.t_fetched and rec.ready_at_fetch is True
+    # the device waited for the host: none of the tile counts as blocked
+    assert thread_parts(prev, rec).blocked == 0.0
+    engine.run_until_idle(timeout_s=300)
+
+
+def test_idle_ms_holds_the_idle_waits_and_nothing_else(lm, monkeypatch):
+    from ray_dynamic_batching_tpu.utils.metrics import now_ms
+
+    engine, queue = _engine(lm)
+    engine.warmup()
+    engine.reset_ttft_window()
+    waits = []
+    wait = queue.wait_for_requests
+
+    def stamped(timeout_s):
+        t = now_ms()
+        try:
+            return wait(timeout_s)
+        finally:
+            waits.append((t, now_ms()))
+
+    monkeypatch.setattr(queue, "wait_for_requests", stamped)
+    t_start = now_ms()
+    engine.start()
+    try:
+        time.sleep(0.03)                  # the loop idles
+        for r in _submit(queue, engine.model.name, lens=(5,)):
+            r.future.result(timeout=120)
+    finally:
+        engine.stop()
+    first, *rest = list(engine.turns)
+    assert first.after_idle and rest
+    for t in rest:
+        assert not t.after_idle and t.idle_ms == 0.0
+    # the engine's pair of stamps stands round each wait: no less than the
+    # waits' own wall, no more than the time since the loop began
+    inside = sum(b - a for a, b in waits if b <= first.t_dispatch)
+    assert inside > 0.0
+    assert inside <= first.idle_ms <= first.t_dispatch - t_start
+
+
+def test_a_thread_switch_restarts_the_cpu_baseline(lm):
+    import threading
+
+    engine, queue = _engine(lm)
+    me = threading.get_ident()
+    reqs = _submit(queue, engine.model.name, lens=(5, 9))
+    engine.run_until_idle(timeout_s=300)       # this thread writes
+    n = len(engine.turns)
+    assert n >= 2 and engine._cpu_thread == me
+    engine.start()                              # then the loop's own
+    try:
+        loop = engine._thread.ident
+        reqs += _submit(queue, engine.model.name, lens=(7, 11))
+        for r in reqs:
+            r.future.result(timeout=120)
+    finally:
+        engine.stop()
+    m = len(engine.turns)
+    assert m > n and loop != me and engine._cpu_thread == loop
+    reqs = _submit(queue, engine.model.name, lens=(6,))
+    engine.run_until_idle(timeout_s=300)       # and this one again
+    for r in reqs:
+        r.future.result(timeout=5)
+    ring = list(engine.turns)
+    assert len(ring) > m and engine._cpu_thread == me
+    # the first record a thread writes after another's has no baseline of
+    # its own clock: 0.0, never one thread's clock less another's
+    assert ring[0].cpu_ms == 0.0 and ring[n].cpu_ms == 0.0
+    assert ring[m].cpu_ms == 0.0
+    assert all(t.cpu_ms >= 0.0 for t in ring)
+
+
+def _tile(kind, dispatch, done, fetch=(), ready=False, cpu=0.0, idle=0.0,
+          substeps=0, behind=0):
+    """A record whose call took 1 ms; ``fetch``: (t_fetch, t_fetched)."""
+    t_fetch, t_fetched = fetch or (0.0, 0.0)
+    return _at(kind, dispatch, t_fetched, done, behind, substeps,
+               after_idle=idle > 0)._replace(
+        t_fetch=t_fetch, ready_at_fetch=ready, cpu_ms=cpu, idle_ms=idle)
+
+
+FIRST = _tile("turn", 90.0, 100.0, fetch=(92.0, 98.0), cpu=50.0, substeps=1)
+
+
+@pytest.mark.parametrize("records, ms", [
+    # the host waited 7 of 12 ms; the rest is its own side
+    ([_tile("turn", 101.0, 112.0, fetch=(103.0, 110.0), cpu=3.0)],
+     dict(wall=12.0, blocked=7.0, idle=0.0, host=5.0, cpu=3.0)),
+    # the same fetch found its result ready: a copy, counted as the host's
+    ([_tile("turn", 101.0, 112.0, fetch=(103.0, 110.0), ready=True,
+            cpu=10.0)],
+     dict(wall=12.0, blocked=0.0, idle=0.0, host=12.0, cpu=10.0)),
+    # a chunk that ended no prompt fetched nothing
+    ([_tile("chunk", 101.0, 104.0, cpu=2.5)],
+     dict(wall=4.0, blocked=0.0, idle=0.0, host=4.0, cpu=2.5)),
+    # an idle wait of 40 ms inside a tile of 50
+    ([_tile("chunk", 141.0, 150.0, fetch=(143.0, 148.0), cpu=4.0,
+            idle=40.0)],
+     dict(wall=50.0, blocked=5.0, idle=40.0, host=5.0, cpu=4.0)),
+    # two tiles sum; the first record's own parts never count
+    ([_tile("turn", 101.0, 110.0, fetch=(102.0, 108.0), cpu=2.0),
+      _tile("chunk", 103.0, 114.0, fetch=(111.0, 113.0), cpu=1.0, behind=1)],
+     dict(wall=14.0, blocked=8.0, idle=0.0, host=6.0, cpu=3.0)),
+    # a CPU clock of 10 ms ticks charges one tile of four a whole tick: a
+    # reading beside the parts, which it does not move
+    ([_tile("turn", 100.0 + 3 * i, 103.0 + 3 * i, cpu=10.0 * (i == 1))
+      for i in range(4)],
+     dict(wall=12.0, blocked=0.0, idle=0.0, host=12.0, cpu=10.0)),
+    # a hand-built tile whose fetch and idle wait outlast it (8 + 6 of 12
+    # ms): its rest is clipped at 0 and kept; the next tile's is its own
+    ([_tile("turn", 101.0, 112.0, fetch=(102.0, 110.0), idle=6.0),
+      _tile("turn", 113.0, 122.0, fetch=(114.0, 120.0), cpu=3.0)],
+     dict(wall=22.0, blocked=14.0, idle=6.0, host=4.0, cpu=3.0,
+          clipped=2.0)),
+], ids=["blocked", "found-ready", "unfetched", "idle", "two-tiles",
+        "coarse-clock", "clipped"])
+def test_summarize_turns_tiles_the_thread_by_hand(records, ms):
+    from ray_dynamic_batching_tpu.engine.decode import summarize_turns
+
+    s = summarize_turns([FIRST] + records, 4)
+    ms = dict(ms, clipped=ms.get("clipped", 0.0))
+    assert s["thread_ms"] == ms
+    whole = ms["wall"] + ms["clipped"]
+    assert [s[k] for k in SHARES] == [
+        ms[k] / whole for k in ("blocked", "idle", "host")]
+    assert sum(s[k] for k in SHARES) == pytest.approx(1.0, abs=1e-12)
+    worst = s["longest_records"][0]
+    assert worst["wall"] == max(
+        b.t_done - a.t_done for a, b in zip([FIRST] + records, records))
+    assert worst["wall"] + worst["clipped"] == pytest.approx(
+        worst["blocked"] + worst["idle"] + worst["host"])
+
+
+def test_summarize_turns_gives_the_fetches_and_the_longest_tiles_by_hand():
+    from ray_dynamic_batching_tpu.engine.decode import summarize_turns
+
+    ring = [
+        FIRST,
+        _tile("turn", 101.0, 112.0, fetch=(103.0, 110.0), ready=True,
+              substeps=2),
+        _tile("chunk", 113.0, 116.0),
+        _tile("turn", 117.0, 190.0, fetch=(119.0, 188.0), cpu=20.0,
+              substeps=4, behind=1),
+        _tile("chunk", 191.0, 196.0, fetch=(192.0, 195.0)),
+    ]
+    s = summarize_turns(ring, 4, longest=2)
+    assert s["fetch_found_ready_share"] == 1 / 4     # of FETCHED records
+    assert [(r["wall"], r["kind"], r["substeps"], r["queued_behind"],
+             r["t_dispatch"], r["blocked"], r["host"], r["cpu"])
+            for r in s["longest_records"]] == [
+        (74.0, "turn", 4, 1, 117.0, 69.0, 5.0, 20.0),
+        (12.0, "turn", 2, 0, 101.0, 0.0, 12.0, 0.0)]
+    # one record, or none, tiles nothing
+    assert not set(TILING_KEYS) & set(summarize_turns(ring[:1], 4))
+    # the tiling does not care for a span, nor for the slots
+    assert summarize_turns(ring, 16, span_ms=1e6)["thread_ms"] == s["thread_ms"]
+
+
+def test_snapshot_carries_the_threads_tiling(lm):
+    engine, queue = _engine(lm)
+    reqs = _submit(queue, engine.model.name)
+    engine.run_until_idle(timeout_s=300)
+    for r in reqs:
+        r.future.result(timeout=5)
+    ring = list(engine.turns)
+    s = engine.snapshot()["turns"]
+    assert set(TILING_KEYS) <= set(s)
+    ms = s["thread_ms"]
+    assert ms["wall"] == pytest.approx(ring[-1].t_done - ring[0].t_done)
+    assert ms["idle"] == 0.0 and ms["cpu"] == pytest.approx(
+        sum(t.cpu_ms for t in ring[1:]))
+    assert ms["clipped"] == 0.0 and ms["wall"] == pytest.approx(
+        ms["blocked"] + ms["idle"] + ms["host"])
+    assert sum(s[k] for k in SHARES) == pytest.approx(1.0)
+    assert all(0.0 <= s[k] <= 1.0 for k in SHARES)
+    assert 0.0 <= s["fetch_found_ready_share"] <= 1.0
+    longest = s["longest_records"]
+    assert 1 <= len(longest) <= 8
+    assert [r["wall"] for r in longest] == sorted(
+        (r["wall"] for r in longest), reverse=True)
+    assert {"blocked", "idle", "host", "cpu", "clipped", "kind",
+            "substeps", "queued_behind", "t_dispatch"} <= set(longest[0])
+
+
+def test_a_record_that_stalled_is_logged_with_its_parts(lm, monkeypatch):
+    from ray_dynamic_batching_tpu.engine import decode
+    from ray_dynamic_batching_tpu.utils.metrics import now_ms
+
+    engine, _queue = _engine(lm)
+    said = []
+    monkeypatch.setattr(decode.logger, "warning",
+                        lambda fmt, *a: said.append(fmt % a))
+    t = now_ms()
+    engine._log_dispatch("turn", t - 3.0, t - 2.0, t, 1, 0, 1, 0,
+                         fetch=(t - 1.0, False))
+    assert said == []          # no record before it: nothing to tile
+    t = now_ms()
+    engine._log_dispatch("turn", t - 3.0, t - 2.0, t, 1, 0, 1, 0,
+                         fetch=(t - 1.0, False))
+    assert said == []          # a millisecond in its fetch
+    t = now_ms()
+    engine._log_dispatch("chunk", t - 3.0, t - 2.0, t, 0, 16, 1, 1,
+                         fetch=(t - 2 * decode._STALL_WARN_MS, True))
+    assert said == []          # long, but the result was ready: a copy
+    t = now_ms()
+    engine._log_dispatch("turn", t - 3.0, t - 2.0, t, 2, 0, 1, 0,
+                         fetch=(t - 2 * decode._STALL_WARN_MS, False),
+                         queued_behind=1)
+    (line,) = said
+    assert "a turn record" in line and "blocked in its fetch 2000" in line
+    assert "substeps=2 queued_behind=1" in line
+    # and one whose HOST side outlasts the limit (no fetch waited)
+    monkeypatch.setattr(decode, "_STALL_WARN_MS", 5.0)
+    time.sleep(0.01)
+    t = now_ms()
+    engine._log_dispatch("chunk", t - 3.0, t - 2.0, 0.0, 0, 16, 1, 1)
+    assert len(said) == 2 and "blocked in its fetch 0, idle 0" in said[1]
+    assert float(said[1].split("the host's side ")[1].split()[0]) >= 10.0
+
+
+def test_dispatch_and_fetch_phases_carry_the_programs_number(traced):
+    """``seq`` (``_note_issue``'s number, the record's ``Turn.seq``) on the
+    dispatch and fetch phases, ``ready`` on the fetches: a device program of
+    the trace joins its ring record, whatever order the ring stands in and
+    however often it wrapped, and an idle gap under a fetch says who
+    waited."""
+    engine, (spans,) = traced
+    ring = list(engine.turns)
+    kinds = {"rdb.engine.turn": "turn", "rdb.engine.prefill": "chunk"}
+    by_seq = {t.seq: t for t in ring}
+    # the warm-up numbers no program: the records are programs 1 .. n
+    assert sorted(by_seq) == list(range(1, len(ring) + 1))
+    issued = {s[3]["seq"]: kinds[s[0].rsplit(".", 1)[0]]
+              for s in spans if s[0].endswith(".dispatch")}
+    assert issued == {n: t.kind for n, t in by_seq.items()}
+    fetches = [s for s in spans if s[0].endswith(".fetch")]
+    assert all({"seq", "ready"} <= set(s[3]) for s in fetches)
+    assert sorted(s[3]["seq"] for s in fetches) == sorted(
+        t.seq for t in ring if t.t_fetched)
+    for s in fetches:
+        rec = by_seq[s[3]["seq"]]
+        assert kinds[s[0].rsplit(".", 1)[0]] == rec.kind
+        assert s[3]["ready"] == int(rec.ready_at_fetch)
+    # every other phase's attributes stand as they were
+    assert not any("seq" in s[3] for s in spans
+                   if not s[0].endswith((".dispatch", ".fetch")))
