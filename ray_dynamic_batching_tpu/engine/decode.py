@@ -228,6 +228,8 @@ class _IssuedTurn(NamedTuple):
     kv_rows: Tuple[int, int]
     kv_full_pages_live: int = 0
     kv_latent_rows: int = 0
+    requests: Tuple = ()            # each slot's tenant at the dispatch
+    ahead: bool = False             # issued before the scan ahead was fetched
 
 
 class _IssuedGroup(NamedTuple):
@@ -355,7 +357,14 @@ class Turn(NamedTuple):
     charges a burst of a millisecond 0.6 to 8.6 times its length, and only
     a stall of seconds is told by it (on the CPU, or off it). ``seq``: the
     program's number (:meth:`DecodeEngine._note_issue`), which its
-    ``.dispatch`` and ``.fetch`` phases carry under a profiler session."""
+    ``.dispatch`` and ``.fetch`` phases carry under a profiler session.
+
+    ``ahead``: a scan dispatched BEFORE the scan in front of it was fetched,
+    its pending tokens read on the device from that scan's last substep
+    (:meth:`DecodeEngine._horizon_ahead`). ``wasted_substeps``: the substeps
+    such a scan ran for slots whose tenant had ended in the scan in front
+    (an EOS or a stop id nobody knew of at the dispatch): rows x substeps,
+    their tokens discarded."""
 
     kind: str
     t_dispatch: float
@@ -388,17 +397,27 @@ class Turn(NamedTuple):
     idle_ms: float = 0.0
     cpu_ms: float = 0.0
     seq: int = 0
+    ahead: bool = False
+    wasted_substeps: int = 0
 
 
 # An engine's prompt buckets where its builder names none.
 DEFAULT_PROMPT_BUCKETS = (16, 32, 64, 128)
 
-# Holds the benchmark's 51 s window at 160 dispatches a second. The cells'
-# highest rates an engine are 100/s (chat), 129/s (long-context) and 134/s
-# (x4: 2,756 records in 20.6 s; builder's traced runs, PR 48): 6,830
-# records a window, a headroom of 1.2. A cell that dispatches faster wraps
-# the ring, and every reader of it then gives nothing (``turns_dropped``).
-_TURN_RING = 8192
+# Holds the benchmark's 51 s window at 320 dispatches a second. The cells'
+# highest rates an engine were 100/s (chat), 129/s (long-context) and 134/s
+# (x4: 2,756 records in 20.6 s; PR 48); chat's rose to 114/s with scans
+# issued ahead (PR 56: the tile is shorter) and x4's engines are never idle.
+# A cell that dispatches faster wraps it: readers give nothing (``dropped``).
+_TURN_RING = 16384
+# Scans are issued ahead while fewer than this share of the engine's recent
+# scan fetches (a moving average over about ``_READY_TURNS`` of them:
+# ``DecodeEngine._note_ready``) found their result ready. The chip read
+# (PR 56) 0.000-0.004 of the fetches ready in every one-chip cell, in either
+# order (the device is the pace), and on four engine threads under one
+# interpreter lock 0.16-0.18 in today's order, 0.93-0.95 with scans ahead.
+AHEAD_READY_MAX = 1 / 32
+_READY_TURNS = 128
 # A record whose thread was blocked in its fetch, or busy on the host's side,
 # for longer than this is logged as it is written
 # (``DecodeEngine._log_dispatch``).
@@ -514,10 +533,12 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
     ``ssm_state_bytes``, the state-space state they had to read and write.
     Two records or more add where the engine thread's time went
     (:func:`thread_tiling`: ``thread_ms``, the three ``thread_*_share``,
-    ``fetch_found_ready_share``, ``longest_records``)."""
+    ``fetch_found_ready_share``, ``longest_records``). Scans add
+    :func:`ahead_counters`: ``scans_issued_ahead_share`` and what such scans
+    wasted. ``overlapped_dispatch_share`` counts a scan issued ahead too."""
     scans = [t for t in turns if t.kind == "turn"]
     out: Dict[str, Any] = {"dispatches": len(turns), "scans": len(scans),
-                           "dropped": dropped}
+                           "dropped": dropped, **ahead_counters(scans)}
     live = sum(t.kv_pages_live * t.substeps for t in scans)
     if live and table_entries:
         out["kv_pages_live"] = live
@@ -600,6 +621,25 @@ def summarize_turns(turns: Sequence[Turn], num_slots: int, dropped: int = 0,
     ]
     out.update(thread_tiling(turns, longest))
     return out
+
+
+def ahead_counters(scans: Sequence[Turn]) -> Dict[str, Any]:
+    """:func:`summarize_turns`' keys for scans issued AHEAD of an unfetched
+    one (``Turn.ahead``): ``scans_issued_ahead_share`` of the scans;
+    ``ahead_wasted_substeps``, the slot-substeps such scans ran for tenants
+    that had already ended (``Turn.wasted_substeps``); and
+    ``ahead_wasted_substep_share``, those over all slot-substeps run
+    (``active`` x ``substeps``). Nothing without a scan."""
+    if not scans:
+        return {}
+    wasted = sum(t.wasted_substeps for t in scans)
+    ran = sum(t.active * t.substeps for t in scans)
+    return {
+        "scans_issued_ahead_share": sum(
+            1 for t in scans if t.ahead) / len(scans),
+        "ahead_wasted_substeps": wasted,
+        "ahead_wasted_substep_share": wasted / ran if ran else 0.0,
+    }
 
 
 # Speculation observability (ISSUE 13 satellite): accepted + rejected ==
@@ -1045,10 +1085,18 @@ class DecodeEngine:
         # their difference at a dispatch is its ``Turn.queued_behind``.
         self._programs_issued = 0
         self._programs_seen = 0
-        # What ``_iterate`` has issued and not completed: the scan, and the
-        # chunk groups dispatched behind it (completed after it, in order).
+        # What ``_iterate`` has issued and not completed: the scan (ONE may
+        # stay in flight between iterations), the chunk groups behind it.
         self._issued_turn: Optional[_IssuedTurn] = None
         self._issued_groups: List[_IssuedGroup] = []
+        # The newest scan's last tokens, on the device (``_decode_impl``).
+        self._carry_sharding = None if mesh is None else self._put(
+            np.zeros((), np.int32)).sharding
+        with self._device_ctx():
+            self._carry = self._put(np.zeros((num_slots,), np.int32))
+        # The share of recent scan fetches that found their result ready
+        # (``_note_ready``): above ``AHEAD_READY_MAX`` the host is the pace.
+        self._ready_share = 0.0
         # Prefill tokens spent behind the scan in flight: the one budget
         # of a turn, so the pump before the NEXT scan spends only the rest.
         self._prefill_spent = 0
@@ -1237,15 +1285,17 @@ class DecodeEngine:
                       kv_latent_rows: int = 0,
                       state_turns: Tuple[int, int] = (0, 0),
                       fetch: Tuple[float, bool] = (0.0, False),
-                      seq: int = 0) -> Turn:
+                      seq: int = 0, ahead: Tuple[bool, int] = (False, 0)
+                      ) -> Turn:
         """Append this dispatch's record to the turn ring (its work on the
         host is done: ``t_done`` is now). ``moe``: the dispatch's routing
         counters as fetched (``Turn``'s ``moe_*`` fields);
         ``kv_pages_live``: :meth:`_kv_pages_live` as the scan was
         dispatched; ``kv_rows``: :meth:`_kv_rows` then; ``queued_behind``:
         and ``seq``: :meth:`_note_issue` then; ``fetch``: ``Turn``'s
-        ``t_fetch`` and ``ready_at_fetch``. The thread's CPU clock is read
-        here (once a record), and a record that stalled is logged."""
+        ``t_fetch`` and ``ready_at_fetch``; ``ahead``: ``Turn``'s ``ahead``
+        and ``wasted_substeps``. The thread's CPU clock is read here (once a
+        record), and a record that stalled is logged."""
         cpu, me = time.thread_time(), threading.get_ident()
         same_thread = me == self._cpu_thread
         cpu_ms = (cpu - self._cpu_mark) * 1000.0 if same_thread else 0.0
@@ -1259,7 +1309,7 @@ class DecodeEngine:
             *kv_rows, queued_behind, kv_full_pages_live, kv_latent_rows,
             *state_turns,
             active * substeps * self._ssm_step_bytes if kind == "turn" else 0,
-            *fetch, self._idle_ms, cpu_ms, seq,
+            *fetch, self._idle_ms, cpu_ms, seq, *ahead,
         )
         if same_thread and self.turns:
             parts = thread_parts(self.turns[-1], rec)
@@ -1590,12 +1640,18 @@ class DecodeEngine:
         return first, cache
 
     def _decode_impl(self, params, cache, step_state, horizon: int,
-                     samp_f, samp_i, bias_ids, bias_vals, counts):
+                     samp_f, samp_i, bias_ids, bias_vals, counts, carried):
         """``horizon`` chained decode steps in one program (one host sync).
 
-        The per-DISPATCH state arrives as ONE packed [3, B] int32 upload —
-        rows = pending tokens / active mask / next sample index — instead
-        of three separate transfers; per-slot sampling state arrives
+        The per-DISPATCH state arrives as ONE packed [4, B] int32 upload —
+        rows = pending tokens / active mask / next sample index / "use the
+        carry" — instead of separate transfers. ``carried`` [B] is the
+        previous scan's last tokens, still on the device: a row whose
+        fourth entry is set reads its pending token there, so the host can
+        dispatch this scan BEFORE it has fetched that one (all zero: the
+        uploaded tokens, the order in which every scan is fetched first).
+        The scan's own last tokens come back beside ``packed`` as the next
+        scan's ``carried``. Per-slot sampling state arrives
         packed by dtype — ``samp_f`` [4, B] stacks
         temperature/top_p/presence/frequency, ``samp_i`` [2, B] stacks
         top_k/seeds — so a sampling-state refresh costs two transfers
@@ -1613,7 +1669,8 @@ class DecodeEngine:
         An expert model adds four rows, each one routing counter of the
         whole scan broadcast over B (``Turn``'s ``moe_*``): [2h+5, B].
         """
-        tokens = step_state[0][:, None]
+        tokens = jnp.where(step_state[3].astype(bool), carried,
+                           step_state[0])[:, None]
         active = step_state[1].astype(bool)
         tok_idx0 = step_state[2]
         # Mask sampling state to the ACTIVE rows in-program: freed slots
@@ -1664,8 +1721,12 @@ class DecodeEngine:
             packed.append(jnp.broadcast_to(
                 merge_routing_counters(moe[0])[:, None],
                 (4, tokens.shape[0])))
+        last = toks[-1]
+        if self._carry_sharding is not None:
+            last = jax.lax.with_sharding_constraint(
+                last, self._carry_sharding)
         return (jnp.concatenate(packed, axis=0), cache,
-                self._pin_counts(counts))
+                self._pin_counts(counts), last)
 
     def _spec_impl(self, params, cache, dcache, step_state,
                    bias_ids, bias_vals):
@@ -1865,16 +1926,18 @@ class DecodeEngine:
         horizons = sorted({1, self.ttft_horizon, self.decode_horizon})
         for h in horizons:
             with self._warming("decode_step", f"h={h}"):
-                packed, self._cache, self._counts = self._decode_fn(
+                (packed, self._cache, self._counts,
+                 self._carry) = self._decode_fn(
                     self.params,
                     self._cache,
-                    jnp.zeros((3, B), dtype=jnp.int32),
+                    jnp.zeros((4, B), dtype=jnp.int32),
                     h,
                     warm_samp_f,
                     warm_samp_i,
                     jnp.zeros((B, self.max_bias_entries), jnp.int32),
                     jnp.zeros((B, self.max_bias_entries), jnp.float32),
                     self._counts,
+                    self._carry,
                 )
                 packed.block_until_ready()
         if self._dcache is not None:
@@ -2185,7 +2248,10 @@ class DecodeEngine:
         Groups are then dispatched and NOT completed (``_issued_groups``),
         up to the first that ends a prompt; pages come from the free list
         alone, and a train that would need a cache pin shed or a spilled
-        prefix read back parks, uncounted, for the pump after the harvest."""
+        prefix read back parks, uncounted, for the pump after the harvest.
+        Any other pump completes what is in flight first."""
+        if not behind_turn:
+            self._drain_issued()     # it completes its groups; may reclaim
         if not self._trains:
             return 0
         with self._phase("rdb.engine.prefill",
@@ -2531,9 +2597,12 @@ class DecodeEngine:
 
     # --- paged admission bookkeeping ---------------------------------------
     def _read_pages(self, page_ids: List[int]) -> Dict[str, np.ndarray]:
-        """Gather the listed pages' contents to host (spill). The pages
-        are pinned (prefix-cache refs) and never rewritten after
-        publication (CoW invariant), so this read races nothing."""
+        """Gather the listed pages' contents to host (spill, the fabric's
+        parcels: every host-side read of the pool comes through here). The
+        pages are pinned (prefix-cache refs) and never rewritten after
+        publication (CoW invariant), so this read races nothing; a scan
+        issued ahead is completed first (its callers have, by then)."""
+        self._drain_issued()
         return self._cache.read_pages(
             np.asarray(page_ids, np.int32), self.model.cfg)
 
@@ -2542,6 +2611,7 @@ class DecodeEngine:
         """Scatter spilled contents into freshly allocated pages
         (reload). Functional update — the pool array has one logical
         writer (this engine thread), like the page-table upload."""
+        self._drain_issued()
         with self._device_ctx():
             self._cache = self._cache.write_pages(
                 jnp.asarray(np.asarray(page_ids, np.int32)), payload,
@@ -2886,11 +2956,7 @@ class DecodeEngine:
             slot = self._slots[i]
             if slot.free:
                 continue
-            need = pages_for(
-                min(int(self._len_host[i]) + horizon, self.max_len),
-                self.page_size,
-            )
-            delta = need - len(slot.pages)
+            delta = self._pages_short(i, horizon)
             if delta <= 0:
                 continue
             while not self._allocator.can_alloc(delta):
@@ -2924,6 +2990,14 @@ class DecodeEngine:
                 slot.pages, self._n_table_entries, self.num_pages
             )
             self._table_dirty = True
+
+    def _pages_short(self, i: int, positions: int) -> int:
+        """Pages slot ``i`` lacks to hold ``positions`` more than the host's
+        mirror of its length says it holds (0 or less: none); the engine's
+        ``max_len`` bounds what a slot can ever hold."""
+        return pages_for(
+            min(int(self._len_host[i]) + positions, self.max_len),
+            self.page_size) - len(self._slots[i].pages)
 
     def _eviction_victim(self, exclude: int) -> Optional[int]:
         """Most recently admitted active slot other than ``exclude``
@@ -3278,15 +3352,31 @@ class DecodeEngine:
         ring's record."""
         self._complete_turn(ph, self._issue_turn(ph, horizon))
 
-    def _issue_turn(self, ph: Any, horizon: Optional[int]) -> _IssuedTurn:
+    def _issue_turn(self, ph: Any, horizon: Optional[int],
+                    ahead_of: Optional[_IssuedTurn] = None) -> _IssuedTurn:
         """Prepare and dispatch one plain decode scan inside the open
         ``rdb.engine.turn`` phase ``ph``; nothing is fetched. Until
         :meth:`_complete_turn` the slots' host state (mask, tokens, lengths,
         tables, sampling arrays) must stand as dispatched: admission and a
         chunk group's DISPATCH touch none of it, a group's completion
-        (``_register``) does."""
+        (``_register``) does.
+
+        ``ahead_of``: the scan in flight, dispatched and NOT fetched, where
+        :meth:`_horizon_ahead` allowed this one (``horizon``) to go out
+        behind it. Every active row then reads its pending token from that
+        scan's last substep on the device (``_decode_impl``'s ``carried``),
+        and what the host would have learned from the fetch goes forward by
+        arithmetic: the sample index and the mirror of the lengths by that
+        scan's substeps (all of which advance, or this was not allowed; its
+        harvest writes the same lengths). Any other scan is dispatched with
+        nothing in flight."""
+        if ahead_of is None:
+            self._drain_issued()
+        late = 0 if ahead_of is None else ahead_of.h
         with self._phase("rdb.engine.turn.prepare"):
             h = horizon if horizon is not None else self._pick_horizon()
+            if late:
+                self._len_host[self._active_mask] += late
             # Pages for every position this scan can write, allocated
             # host-side before the dispatch (static shapes can't grow
             # mid-scan), then one tiny [B, NP] table upload when dirty.
@@ -3295,7 +3385,8 @@ class DecodeEngine:
             # Per-slot index of the NEXT token to sample (prefill was
             # index 0).
             tok_idx = np.asarray(
-                [len(s.generated) if not s.free else 0 for s in self._slots],
+                [len(s.generated) + late if not s.free else 0
+                 for s in self._slots],
                 dtype=np.int32,
             )
             prev_tokens = self._tokens.copy()  # draft catch-up window head
@@ -3309,17 +3400,22 @@ class DecodeEngine:
             kv_latent = (int(kv_pages_live) * self.page_size
                          * self._latent_layers)
             samp_f, samp_i, bias_ids_d, bias_vals_d = self._sampling_arrays()
-            # ONE per-dispatch upload: tokens / active / sample index.
+            # ONE per-dispatch upload: tokens / active / sample index /
+            # which rows read the carry (all the active ones, or none).
+            carry = active_at_dispatch if late else np.zeros_like(
+                active_at_dispatch)
             state = np.stack([
                 self._tokens[:, 0],
                 active_at_dispatch.astype(np.int32),
                 tok_idx,
+                carry.astype(np.int32),
             ])
-        ph.set_metadata(horizon=h, active=active, spec=0)
+        ph.set_metadata(horizon=h, active=active, spec=0, ahead=int(late > 0))
         seq, behind = self._note_issue()
         t_dispatch = now_ms()
         with self._phase("rdb.engine.turn.dispatch", seq=seq):
-            packed, self._cache, self._counts = self._decode_fn(
+            (packed, self._cache, self._counts,
+             self._carry) = self._decode_fn(
                 self.params,
                 self._cache,
                 jnp.asarray(state),
@@ -3329,21 +3425,29 @@ class DecodeEngine:
                 bias_ids_d,
                 bias_vals_d,
                 self._counts,
+                self._carry,
             )
         return _IssuedTurn(packed, seq, h, t_dispatch, now_ms(), behind,
                            active_at_dispatch, prev_tokens,
                            len(self._trains), kv_pages_live, kv_rows,
-                           kv_full, kv_latent)
+                           kv_full, kv_latent,
+                           tuple(s.request for s in self._slots), late > 0)
 
     def _complete_turn(self, ph: Any, issued: _IssuedTurn) -> None:
         """Fetch and harvest an issued scan inside the open
         ``rdb.engine.turn`` phase ``ph``, and write the ring's record. The
-        harvest goes by the mask the scan was DISPATCHED with: a slot
-        registered since took no part in it."""
+        harvest goes by the IDENTITY the scan was dispatched with, the mask
+        and each slot's tenant then: a slot registered since took no part in
+        it, and where the scan was issued ahead (``issued.ahead``) a tenant
+        that ended in the scan in front of it (an EOS nobody knew of) had
+        its rows decoded for nothing, past its length in pages that were
+        its own: discarded and counted (``Turn.wasted_substeps``), whether
+        the slot is free, held by a train or another request's by now."""
         h, active_at_dispatch = issued.h, issued.active_at_dispatch
         active = int(active_at_dispatch.sum())
         ph.set_metadata(horizon=h, active=active, spec=0)
         ready = issued.packed.is_ready()
+        self._note_ready(ready)
         with self._phase("rdb.engine.turn.fetch", seq=issued.seq,
                          ready=int(ready)):
             fetch = (now_ms(), ready)
@@ -3351,6 +3455,12 @@ class DecodeEngine:
         t_fetched = now_ms()
         self._note_fetched(issued.seq)
         with self._phase("rdb.engine.turn.harvest"):
+            if issued.requests:
+                active_at_dispatch = active_at_dispatch & np.fromiter(
+                    (s.request is r
+                     for s, r in zip(self._slots, issued.requests)),
+                    bool, len(self._slots))
+            wasted = (active - int(active_at_dispatch.sum())) * h
             links = self._turn_links(active_at_dispatch)
             toks_host = packed_host[:h]               # [h, B]
             advanced_host = packed_host[h : 2 * h].astype(bool)   # [h, B]
@@ -3385,7 +3495,7 @@ class DecodeEngine:
             queued_behind=issued.queued_behind,
             kv_full_pages_live=issued.kv_full_pages_live,
             kv_latent_rows=issued.kv_latent_rows,
-            fetch=fetch, seq=issued.seq)
+            fetch=fetch, seq=issued.seq, ahead=(issued.ahead, wasted))
         if links is not None:
             self._record_turn_span(rec, links, h)
 
@@ -3600,6 +3710,8 @@ class DecodeEngine:
                 inbound, self._parcel_in_q = self._parcel_in_q, []
                 moves, self._migrate_out_q = self._migrate_out_q, []
                 pushes, self._push_out_q = self._push_out_q, []
+            # a parcel reads or writes pages: nothing may be in flight
+            self._drain_issued()
             for parcel in inbound:
                 self._import_parcel(parcel)
             for rid, deliver in moves:
@@ -3832,6 +3944,7 @@ class DecodeEngine:
                         and len(self.queue) == 0
                         and not self._fabric_pending()):
                     return
+            self._drain_issued()
         raise TimeoutError(f"{self.model.name}: decode did not drain")
 
     def _iterate(self) -> Tuple[int, bool]:
@@ -3844,42 +3957,145 @@ class DecodeEngine:
         program. A turn has ONE prefill budget, spent at the earliest point
         a train is pending: what the section behind the scan spent, the pump
         before the next scan does not spend again (never two budgets between
-        two scans). Everything issued is completed before the iteration
-        ends, so the fabric, the headroom's evictions, ``stop``,
-        ``abort_active`` and ``release_buffers`` find nothing in flight.
-        Returns (requests admitted before the scan, whether a scan ran)."""
-        self._service_fabric()
-        admitted = self._admit()
-        left = self.prefill_token_budget - self._prefill_spent
-        self._prefill_spent = 0
-        if left > 0:
-            self._pump_prefill(left)
-        if not self._active_mask.any():
-            return admitted, False
-        if self._dcache is not None:
-            # A draft model's rounds and catch-up keep their order: each
-            # scan fetched at once.
-            self._step()
-        else:
+        two scans).
+
+        Where nothing waits to join the batch (:meth:`_horizon_ahead`) the
+        NEXT scan is what runs behind this one: scan N+1 is dispatched
+        before N is fetched, its pending tokens read from N's last substep
+        on the device; N is fetched and harvested while N+1 runs, and N+1
+        STAYS IN FLIGHT when the iteration ends: the next iteration starts
+        from it (admission and the prefill pump behind it, N+2 issued or
+        not, N+1 fetched). So the device goes from scan to
+        scan without waiting for the host's harvest, preparation and launch.
+        At most ONE scan is ever dispatched ahead of an unfetched one, and a
+        chunk group never stands behind two. A request that arrives while a
+        scan is ahead has its chunk behind that scan (up to ``ttft_horizon``
+        substeps later than on an empty device) and registers before the
+        next scan is issued.
+
+        At most one scan is in flight between iterations, and nothing else:
+        whatever reads or frees the pool from the host, or ends the loop,
+        completes it first (:meth:`_drain_issued`: the fabric, a dispatch
+        that may reclaim or evict, ``_pump_prefill`` by hand,
+        ``_drain_prefill``, ``run_until_idle``'s timeout, the loop's end,
+        ``stop``, ``abort_active``, ``release_buffers``).
+        Returns (requests admitted before the scan, whether a scan ran or
+        is in flight)."""
+        admitted = 0
+        if self._issued_turn is None:
+            self._service_fabric()
+            admitted = self._admit()
+            left = self.prefill_token_budget - self._prefill_spent
+            self._prefill_spent = 0
+            if left > 0:
+                self._pump_prefill(left)
+            if not self._active_mask.any():
+                return admitted, False
+            if self._dcache is not None:
+                # A draft model's rounds and catch-up keep their order: each
+                # scan fetched at once.
+                self._step()
+                self._publish_gauges()
+                return admitted, True
             with self._phase("rdb.engine.turn") as ph:
                 self._issued_turn = self._issue_turn(ph, None)
-            try:
-                self._admit()
-                self._prefill_spent = self._pump_prefill(behind_turn=True)
-            finally:
-                self._complete_issued()
+        # Behind the scan in flight (this iteration's, or the one the last
+        # iteration issued ahead): whoever arrived since joins HERE.
+        ahead = None
+        try:
+            self._admit()
+            left = self.prefill_token_budget - self._prefill_spent
+            if left > 0:
+                self._prefill_spent += self._pump_prefill(
+                    left, behind_turn=True)
+            h = self._horizon_ahead()
+            if h:
+                with self._phase("rdb.engine.turn") as ph:
+                    ahead = self._issue_turn(
+                        ph, h, ahead_of=self._issued_turn)
+        finally:
+            self._complete_issued(ahead)
         self._publish_gauges()
         return admitted, True
 
-    def _complete_issued(self) -> None:
+    def _horizon_ahead(self) -> int:
+        """The horizon of a scan that may be dispatched now, AHEAD of the
+        unfetched scan in flight, or 0: today's order. From what the engine
+        can observe at this moment, all of:
+
+        - nothing waits to join the batch — no chunk train, no queued
+          request, nothing in the fabric's mailboxes — so no request's first
+          token stands behind a scan that need not have been committed;
+        - no draft model (its rounds and catch-up keep their order);
+        - no active slot is CERTAIN to end inside the scan in flight, by its
+          length bound or the cache's end, both known before the fetch: its
+          slot should be refilled at once, and its rows would be wasted
+          (an EOS or a stop id cannot be known: ``Turn.wasted_substeps``);
+        - the pages for both scans' positions (the host's lengths are a scan
+          late: the upper bound) are on the free list: nothing is reclaimed
+          or evicted with a scan in flight;
+        - the DEVICE is the pace (:meth:`_note_ready`): under
+          ``AHEAD_READY_MAX`` of the recent scan fetches found their result
+          ready. Above it the host comes late to its fetches: there is no
+          wait for a committed scan to hide, and it costs a newcomer its
+          place."""
+        cur = self._issued_turn
+        if (cur is None or self._ready_share > AHEAD_READY_MAX
+                or self._dcache is not None or self._trains
+                or self._issued_groups or len(self.queue)
+                or self._fabric_pending()):
+            return 0
+        idx = np.flatnonzero(self._active_mask)
+        if idx.size == 0:
+            return 0
+        h = self._pick_horizon()
+        grow = 0
+        for i in idx:
+            slot = self._slots[i]
+            if (slot.max_new_tokens - len(slot.generated) <= cur.h
+                    or int(self._len_host[i]) + cur.h >= self.max_len):
+                return 0
+            grow += max(0, self._pages_short(i, cur.h + h))
+        if grow and not self._allocator.can_alloc(grow):
+            return 0
+        return h
+
+    def _note_ready(self, ready: bool) -> None:
+        """A decode scan is about to be fetched: ``ready``, its result had
+        reached the host's side before the host came for it, so the device
+        had finished and was waiting. Kept as a moving average over about
+        ``_READY_TURNS`` fetches (``_ready_share``), in either order of the
+        loop. Where it stands above ``AHEAD_READY_MAX`` the HOST is the pace
+        (several engine threads under one interpreter lock): a scan issued
+        ahead hides no wait, since there is none, and it costs a newcomer
+        its place (its chunk stands behind a committed scan, and the loop
+        admits once a tile where today's order admits twice), so
+        :meth:`_horizon_ahead` keeps today's order until the share falls
+        again. A fetch that follows its scan's dispatch at once (today's
+        order) finds the result ready only where the host took longer than
+        the scan over the launch and what it dispatched behind it; a fetch
+        a scan late finds it ready unless the device is the pace."""
+        self._ready_share += (float(ready) - self._ready_share) / _READY_TURNS
+
+    def _drain_issued(self) -> None:
+        """Complete what is in flight (the scan issued ahead, the groups
+        behind it), where the next step reads or frees the pool from the
+        host or must find every token harvested."""
+        if self._issued_turn is not None or self._issued_groups:
+            self._complete_issued()
+
+    def _complete_issued(self, ahead: Optional[_IssuedTurn] = None) -> None:
         """Fetch and harvest the issued scan, then complete the chunk
         groups dispatched behind it, in their order (first tokens,
-        ``_register``, the ring's records after the scan's)."""
-        issued, self._issued_turn = self._issued_turn, None
+        ``_register``, the ring's records after the scan's). ``ahead``: the
+        scan dispatched behind it (:meth:`_horizon_ahead`; there are no
+        groups then), which is what is in flight from here on."""
+        issued, self._issued_turn = self._issued_turn, ahead
         groups, self._issued_groups = self._issued_groups, []
         try:
-            with self._phase("rdb.engine.turn") as ph:
-                self._complete_turn(ph, issued)
+            if issued is not None:
+                with self._phase("rdb.engine.turn") as ph:
+                    self._complete_turn(ph, issued)
         finally:
             if groups:
                 with self._phase("rdb.engine.prefill",
@@ -3922,11 +4138,18 @@ class DecodeEngine:
                         "%s: decode loop iteration failed", self.model.name
                     )
                     time.sleep(0.05)  # rdb-lint: disable=event-loop-blocking (decode-loop error backoff on the engine's own thread)
+            try:
+                self._drain_issued()     # the scan issued ahead: its tokens
+            except Exception:  # noqa: BLE001 — the thread ends either way
+                logger.exception(
+                    "%s: the scan in flight at the loop's end was lost",
+                    self.model.name)
 
     def release_buffers(self) -> None:
         """Drop the engine's HBM footprint (cache + params + compiled fns)
         so a replacement replica can reuse the chip. Call only after the
         loop has stopped; the engine is unusable afterwards."""
+        self._drain_stopped()
         self._cache = None
         self.params = None
         self._draft_fill_fns.clear()
@@ -3954,6 +4177,7 @@ class DecodeEngine:
         """Reject every request still occupying a slot (replica shutdown:
         in-flight sequences must not leave futures/streams hanging). Call
         only after the loop has stopped."""
+        self._drain_stopped()
         for i, slot in enumerate(self._slots):
             if not slot.free and slot.request is not None:
                 slot.request.reject(exc)
@@ -4010,6 +4234,16 @@ class DecodeEngine:
                 )
             else:
                 self._thread = None
+        self._drain_stopped()
+
+    def _drain_stopped(self) -> None:
+        """:meth:`_drain_issued` for the entry points that follow the loop's
+        end (which drains itself; an engine driven by hand has no loop).
+        Not under a loop thread that never came back: a wedged device would
+        take this thread too."""
+        if self._thread is None and self._cache is not None:
+            with self._device_ctx():
+                self._drain_issued()
 
     def kv_occupancy(self) -> float:
         """Useful fraction of RESERVED KV positions — the decode
@@ -4266,3 +4500,4 @@ def thread_tiling(turns: Sequence[Turn], longest: int = 8) -> Dict[str, Any]:
              t_dispatch=round(t.t_dispatch, 3))
         for p, t in heapq.nlargest(longest, tiles, key=lambda x: x[0].wall)]
     return out
+

@@ -112,11 +112,13 @@ class DecodeProfiler:
             B = num_slots
             (samp_f, samp_i, bias_ids, bias_vals) = \
                 engine._sampling_arrays()
-            # Rows: pending tokens / active mask / sample index — the
-            # engine's single per-dispatch upload, all slots active.
+            # Rows: pending tokens / active mask / sample index / use the
+            # carry — the engine's single per-dispatch upload, all slots
+            # active, each step fetched before the next (no carry).
             step_state = jnp.stack([
                 jnp.ones((B,), jnp.int32),
                 jnp.ones((B,), jnp.int32),
+                jnp.zeros((B,), jnp.int32),
                 jnp.zeros((B,), jnp.int32),
             ])
             fn = jax.jit(
@@ -133,24 +135,25 @@ class DecodeProfiler:
                     np.full((B,), max_len - 1, np.int32)),
             )
             args = (engine.params, cache, step_state, 1,
-                    samp_f, samp_i, bias_ids, bias_vals, engine._counts)
+                    samp_f, samp_i, bias_ids, bias_vals, engine._counts,
+                    engine._carry)
             t0 = time.perf_counter()
             compiled = fn.lower(*args).compile()
             compile_ms = (time.perf_counter() - t0) * 1000.0
             hbm_bytes = _program_hbm(compiled)
 
-            counts = engine._counts
+            counts, carry = engine._counts, engine._carry
             run_args = lambda: (engine.params, cache, step_state,  # noqa: E731
                                 samp_f, samp_i, bias_ids,
-                                bias_vals, counts)
+                                bias_vals, counts, carry)
             for _ in range(self.warmup_iters):
-                packed, cache, counts = compiled(*run_args())
+                packed, cache, counts, carry = compiled(*run_args())
             float(np.asarray(packed)[0, 0])
             samples = []
             for _ in range(3):
                 t0 = time.perf_counter()
                 for _ in range(self.timing_iters):
-                    packed, cache, counts = compiled(*run_args())
+                    packed, cache, counts, carry = compiled(*run_args())
                 float(np.asarray(packed)[0, 0])  # host fetch = completion
                 samples.append(
                     (time.perf_counter() - t0) * 1000.0 / self.timing_iters

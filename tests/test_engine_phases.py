@@ -521,6 +521,10 @@ def test_a_ring_with_no_overlap_sums_as_it_did():
         "n": 2, "p50": 5.0, "p99": 5.0, "max": 5.0, "sum": 10.0,
         "harvest_sum": 3.0, "feed_sum": 7.0, "harvest_p50": 1.5,
         "feed_p50": 3.5}
+    # ... and the counters of scans issued ahead (ISSUE 56): none here
+    assert [s.pop(k) for k in (
+        "scans_issued_ahead_share", "ahead_wasted_substeps",
+        "ahead_wasted_substep_share")] == [0.0, 0, 0.0]
     assert s == {"dispatches": 5, "scans": 4, "dropped": 0,
                  "substeps_per_dispatch": 4.0,
                  "mean_occupancy": 2 * 16 / (4 * 16),

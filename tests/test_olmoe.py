@@ -426,8 +426,12 @@ def test_summarize_turns_of_a_dense_ring_has_no_expert_keys():
 # norms, must leave a dense model's programs as they were. ``chunk_prefill``
 # was retaken in PR 47, whose commit changes that program ON PURPOSE (its
 # per-dispatch state arrives as one packed int32 upload, cut by static
-# offsets, where it took six arrays); ``decode_step`` is still 66de37d's
-# byte for byte, which pins that PR 47 did not touch the decode turn.
+# offsets, where it took six arrays). ``decode_step`` was retaken in PR 56,
+# which changes that program ON PURPOSE (a fourth row of the per-dispatch
+# state, "use the carry", a ``[B]`` operand of carried tokens selected by it
+# before the scan, and the scan's last tokens as a fourth result: the text's
+# whole difference from d432e58's, which was 66de37d's byte for byte);
+# ``chunk_prefill`` did not move with it.
 PARENT = json.loads(
     (Path(__file__).resolve().parent / "data"
      / "dense_program_digests.json").read_text())
@@ -441,8 +445,9 @@ def _lowered(engine):
         lambda x: sds(x.shape, x.dtype), tree)
     p, c = shapes(engine.params), shapes(engine._cache)
     decode = engine._decode_fn.__wrapped__.lower(
-        p, c, sds((3, B), i32), 2, sds((4, B), f32), sds((2, B), i32),
-        sds((B, K), i32), sds((B, K), f32), shapes(engine._counts))
+        p, c, sds((4, B), i32), 2, sds((4, B), f32), sds((2, B), i32),
+        sds((B, K), i32), sds((B, K), f32), shapes(engine._counts),
+        sds((B,), i32))
     chunk = engine._chunk_paged_fn.__wrapped__.lower(
         p, sds(engine._new_chunk_group(1, 16)[0].shape, i32), c)
     return {"decode_step": decode, "chunk_prefill": chunk}

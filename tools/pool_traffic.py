@@ -165,8 +165,9 @@ def main() -> int:
     chunk = engine._chunk_paged_fn.__wrapped__
     todo = [
         (f"decode_step h={h}", lambda h=h: decode.lower(
-            p_d, c_d, sds((3, B), i32), h, sds((4, B), f32),
-            sds((2, B), i32), sds((B, K), i32), sds((B, K), f32), counts_d))
+            p_d, c_d, sds((4, B), i32), h, sds((4, B), f32),
+            sds((2, B), i32), sds((B, K), i32), sds((B, K), f32), counts_d,
+            sds((B,), i32)))
         for h in sorted({1, engine.ttft_horizon, engine.decode_horizon})
     ] + [
         (f"chunk_prefill W={b} g={g}", lambda b=b, g=g: chunk.lower(
