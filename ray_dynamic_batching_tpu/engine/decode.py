@@ -4340,6 +4340,21 @@ class DecodeEngine:
                 "select_layers": self._select_layers,
                 "selected_row_share": turns.get("kv_selected_row_share"),
                 "sparse_forms": sparse_forms()}
+            if self._latent_layers:
+                # a selection over a LATENT pool, a decode substep (means
+                # over the ring's scans): the rows a query could attend,
+                # those its indexer keeps, the pool rows the mask form
+                # walks (every live page's), and the index keys scored
+                # (every column of every slot's table: the view is
+                # gathered whole)
+                substeps = max(1, sum(
+                    t.substeps for t in self.turns.copy() if t.kind == "turn"))
+                select["rows_a_substep"] = {
+                    "live": turns.get("kv_rows_live", 0) / substeps,
+                    "selected": turns.get("kv_rows_selected", 0) / substeps,
+                    "walked": turns.get("kv_latent_rows", 0) / substeps,
+                    "index_scored": (self.num_slots * self._paged_capacity
+                                     * self._select_layers)}
         out: Dict[str, Any] = {
             "model": self.model.name,
             "paged": True,

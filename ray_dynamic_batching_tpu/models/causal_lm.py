@@ -505,6 +505,12 @@ class CausalLM(ServableModel):
                             c.q_lora_rank * c.head_dim
                             + c.kv_lora_rank * (nope + c.v_head_dim)
                             + c.v_head_dim * c.d_model))
+                if kind.select:
+                    # the indexer's three projections (its queries from
+                    # the q latent)
+                    proj += (c.q_lora_rank * c.index_heads * c.index_head_dim
+                             + c.d_model * (c.index_head_dim
+                                            + c.index_heads))
             else:
                 proj = (c.d_model * c.head_dim * (
                     c.num_heads + 2 * c.num_kv_heads)
@@ -516,6 +522,12 @@ class CausalLM(ServableModel):
             attn = 0 if kind.conv else 4 * (
                 min(T, 2 * kind.window) if kind.window else T) * (
                 c.num_heads * c.head_dim)
+            if kind.latent and kind.select:
+                # a query attends at most ``select`` positions, and its
+                # index heads score every one up to its own (avg T/2 * 2)
+                attn = (4 * min(T, 2 * kind.select) * (
+                    c.num_heads * c.head_dim)
+                    + 2 * T * c.index_heads * c.index_head_dim)
             total += (per_tok + attn) * T
         return total + 2 * c.d_model * c.vocab_size * T
 

@@ -105,7 +105,10 @@ class DecoderConfig:
     # ``index_head_dim`` score every cached position against ONE index key a
     # position, and a query attends only its ``index_topk`` best positions
     # (all of them while there are no more). 0: no indexer, every layer
-    # attends its whole prefix or window.
+    # attends its whole prefix or window. Beside k/v pairs its queries come
+    # from the layer's normed input and its whole head is rotated; a LATENT
+    # layer's is DeepSeek-V3.2's (``models/latent.py::Indexer``: queries
+    # from the q latent, a LayerNorm on the key, rotary over ``rope_dim``).
     index_topk: int = 0
     index_heads: int = 0
     index_head_dim: int = 0
@@ -216,11 +219,18 @@ class DecoderConfig:
                 "kv_lora_rank: a latent layer needs q_lora_rank, a head "
                 "split by rope_dim, rotary positions, RMSNorm and as many "
                 "KV heads as heads")
-        if self.latent and (self.sliding_window or self.index_topk
-                            or self.qk_norm or self.use_bias):
+        if self.latent and (self.sliding_window or self.qk_norm
+                            or self.use_bias):
             raise ValueError(
-                "kv_lora_rank: a latent layer attends its whole prefix, "
-                "without an indexer, a q/k norm per head or biases")
+                "kv_lora_rank: a latent layer attends its whole prefix or "
+                "an indexer's selection of it, without a window, a q/k "
+                "norm per head or biases")
+        if self.latent and self.index_head_dim and (
+                self.rope_dim > self.index_head_dim):
+            raise ValueError(
+                f"index_head_dim {self.index_head_dim}: a latent layer's "
+                f"indexer rotates the first rope_dim {self.rope_dim} values "
+                "of an index head")
         if (self.rope_yarn_factor > 1.0) and not (
                 self.latent and self.rope_yarn_original):
             raise ValueError("rope_yarn_factor is a latent layer's and "
@@ -537,7 +547,7 @@ class DecoderLayer(nn.Module):
                 self, dense, kind, y, cache_kv, state_lens)
         elif kind.latent:
             attn_out, new_cache = self._latent_attention(
-                y, positions, mask, cache_kv, token_mask, layer_idx,
+                kind, y, positions, mask, cache_kv, token_mask, layer_idx,
                 page_table, kv_lengths)
         else:
             attn_out, new_cache = self._kv_attention(
@@ -582,25 +592,39 @@ class DecoderLayer(nn.Module):
         x = hyper_connections.mix(x, y, maps) if hc else x + y
         return x, new_cache
 
-    def _latent_attention(self, y, positions, mask, cache_kv, token_mask,
-                          layer_idx, page_table, kv_lengths):
+    def _latent_attention(self, kind, y, positions, mask, cache_kv,
+                          token_mask, layer_idx, page_table, kv_lengths):
         """A latent layer's heads' outputs and its state, the pool updated
-        (``models/latent.py``; its model alone loads it)."""
+        (``models/latent.py``; its model alone loads it). A selecting
+        layer hands on its indexer, which ``latent.attention`` gives the q
+        latent, and its index keys' plane."""
         from ray_dynamic_batching_tpu.models import latent
 
+        cfg = self.cfg
         allowed = None
         if cache_kv is None:
             B, T = positions.shape
             allowed = (prefill_mask(token_mask) if token_mask is not None
                        else mask if mask is not None
                        else jnp.ones((B, 1, T, T), bool))
-        out, pool = latent.attention(
-            self.cfg, self.dtype,
-            lambda name: RMSNorm(name=name, eps=self.cfg.rms_eps),
+        select = {}
+        if kind.select:
+            if cache_kv is not None and cache_kv.index_k is None:
+                raise NotImplementedError(
+                    "a selecting layer's index keys live in the paged pool "
+                    "(PagedKVCache.index_k): this cache has none")
+            select = dict(
+                indexer=latent.Indexer(cfg, self.dtype, kind.select),
+                index_pool=None if cache_kv is None else cache_kv.index_k)
+        out, pool, index_pool = latent.attention(
+            cfg, self.dtype,
+            lambda name: RMSNorm(name=name, eps=cfg.rms_eps),
             y, positions, pool=None if cache_kv is None else cache_kv.latent,
             layer=layer_idx, page_table=page_table, kv_lengths=kv_lengths,
-            allowed=allowed)
-        return out, None if pool is None else cache_kv._replace(latent=pool)
+            allowed=allowed, **select)
+        if pool is None:
+            return out, None
+        return out, cache_kv._replace(latent=pool, index_k=index_pool)
 
     def _kv_attention(self, dense, kind, y, positions, mask, cache_kv,
                       token_mask, layer_idx, write_start, scatter_writes,

@@ -36,8 +36,9 @@ def _is_int8(dtype: Any) -> bool:
 def state_kind(cfg: Any) -> str:
     """``"pair"`` (k/v pages for every layer; scale planes and index keys
     ride them), ``"by_kind"`` (full layers' pages + sliding layers' rings),
-    ``"latent"`` (one row a position, no k/v pair), ``"conv"`` (k/v pages
-    for the attention layers + a fixed-size state a slot for the conv
+    ``"latent"`` (one row a position, no k/v pair; a selecting layer's index
+    keys lie in a plane beside the rows), ``"conv"`` (k/v pages for the
+    attention layers + a fixed-size state a slot for the conv
     layers) or ``"ssm"`` (k/v pages for EVERY layer + a matrix state a head
     and a conv state, a slot, for every layer's state-space mixer)."""
     if getattr(cfg, "latent", False):
@@ -94,10 +95,13 @@ CANNOT: Dict[str, Dict[str, str]] = {
             "spec verify scores a window of rows a slot; the absorbed "
             "decode kernel folds one row a slot"),
         "mesh": "a latent row has no head axis to shard",
-        "kv_dtype int8": "a latent row has no scale plane",
+        "kv_dtype int8": (
+            "a latent row has no scale plane, nor has a selecting layer's "
+            "index key beside it"),
         "parcel": (
             "{name}: the page fabric moves a stream as k/v pages of heads; "
-            "a latent pool has one row a position and no such pair"),
+            "a latent pool has one row a position (and one index key where "
+            "its layers select) and no such pair"),
         "slab": (
             "a latent layer's rows live in the paged pool "
             "(PagedKVCache.latent): the slab cache has none"),
@@ -179,9 +183,10 @@ def kv_bytes_per_slot(cfg: "DecoderConfig", dtype: Any, kv_dtype: Any,
     index_row = (c.index_head_dim * jnp.dtype(dtype).itemsize
                  if c.index_topk else 0)
     if c.latent:
-        # ONE row a position a layer: the latent and the shared key
+        # ONE row a position a layer: the latent and the shared key (and
+        # a selecting layer's index key)
         return c.num_layers * S * (
-            c.kv_lora_rank + c.rope_dim) * itemsize
+            (c.kv_lora_rank + c.rope_dim) * itemsize + index_row)
     if c.kv_by_kind:
         # the full layers a position, the sliding layers their window
         row = (c.head_dim + c.v_head_dim) * itemsize
@@ -353,7 +358,9 @@ class PagedKVCache:
     ``k`` and ``v`` are None and ``latent`` ``[L, P, page_size, Wp]`` holds
     one row a position a layer, ``[c_kv | k_r | 0]`` (``Wp``:
     ``ops/latent_attention.py::row_width``), with NO head axis, paged with
-    the same table. None for every other model.
+    the same table. None for every other model. Where its layers SELECT
+    (``index_topk``), ``index_k`` ``[L, P, page_size, Hip]`` lies beside the
+    rows on that table, as it does beside a k/v pair.
 
     A model with CONV layers (``DecoderConfig.conv_kernel``): ``k``/``v``
     hold its attention layers only (``L`` their count) and ``conv_state``
@@ -419,10 +426,16 @@ class PagedKVCache:
                 row_width,
             )
 
+            rows = (cfg.num_layers, num_pages, page_size)
             return PagedKVCache(
                 k=None, v=None, **table, latent=jnp.zeros(
-                    (cfg.num_layers, num_pages, page_size,
-                     row_width(cfg.kv_lora_rank, cfg.rope_dim)), dtype))
+                    rows + (row_width(cfg.kv_lora_rank, cfg.rope_dim),),
+                    dtype),
+                # a selecting layer's index keys: a plane beside the rows,
+                # on the same table
+                index_k=jnp.zeros(
+                    rows + (pool_head_dim(cfg.index_head_dim),),
+                    index_dtype) if cfg.index_topk else None)
         if cfg.kv_by_kind:
             rows = lambda layers, pages, heads, width: jnp.zeros(  # noqa: E731
                 (layers, pages, page_size, heads, pool_head_dim(width)),

@@ -166,10 +166,12 @@ ROWS = [
     ("latent", "draft_model", "the absorbed decode kernel folds one row a "
                               "slot"),
     ("latent", "mesh", "a latent row has no head axis to shard"),
-    ("latent", "kv_dtype int8", "a latent row has no scale plane"),
+    ("latent", "kv_dtype int8", "a latent row has no scale plane, nor has a "
+                                "selecting layer's index key beside it"),
     ("latent", "parcel", "the page fabric moves a stream as k/v pages of "
-                         "heads; a latent pool has one row a position and "
-                         "no such pair"),
+                         "heads; a latent pool has one row a position (and "
+                         "one index key where its layers select) and no "
+                         "such pair"),
     ("latent", "slab", "a latent layer's rows live in the paged pool "
                        "(PagedKVCache.latent): the slab cache has none"),
 ]

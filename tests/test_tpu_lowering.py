@@ -853,6 +853,160 @@ class TestLatentProgramsCompileForV5e:
         assert "_hc_sinkhorn" in chunk.as_text()
 
 
+class TestSparseLatentProgramsCompileForV5e:
+    """``glm-5-ep16-1chip``'s own kernel and programs at the published
+    widths, for a described v5e: the latent decode kernel under a selection
+    (``ops/latent_attention.py``, the mask form: 40 slots, 64 heads against
+    a pool of ``[5, 5760, 128, 640]`` rows, a slot's selection ``[36, 512]``
+    a fold's row beside it), and the decode and the widest chunk program of
+    the first two layers (the dense one and one of experts): nothing but
+    the in-place writes of a row and an index key makes an array the size
+    of either plane, the kernel is in the decode program, and the
+    operations the two pattern metrics take
+    (``sparse_latent_select_dev_share_pct.batch``,
+    ``chunk_attention_sparse_latent_dev_share_pct.batch``: the TPU's trace
+    carries no scope) say they came from the selection and from the chunk
+    walk. Nothing runs: a traced run of the cell says what they cost."""
+
+    _compile = staticmethod(TestLatentProgramsCompileForV5e._compile)
+
+    def test_the_mask_form_kernel_compiles_at_full_size(self, one_chip):
+        from ray_dynamic_batching_tpu.ops import latent_attention as la
+
+        struct = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dt, sharding=one_chip)
+        B, N, NP, L, P, ps = 40, 64, 144, 5, 5760, 128
+        W, bp = la.row_width(512, 64), la.FOLD_PAGES
+        self._compile(
+            lambda q, pool, t, n, ly, sel: (
+                la._latent_paged_decode_attention(
+                    q, pool, t, n, ly, sel, rank=512, scale=0.0625,
+                    interpret=False)),
+            struct((B, N, W), jnp.bfloat16),
+            struct((L, P, ps, W), jnp.bfloat16), struct((B, NP), jnp.int32),
+            struct((B,), jnp.int32), struct((1,), jnp.int32),
+            struct((B, NP // bp, bp * ps), jnp.int32))
+
+    @staticmethod
+    def _lowered(one_chip, layers):
+        """(the decode program's and the widest chunk program's
+        ``jax.stages.Lowered`` thunks, the deployment, the select metric's
+        and the chunk walk's arguments) of the first ``layers`` layers."""
+        import json
+        import re
+        from pathlib import Path
+
+        from ray_dynamic_batching_tpu.models.causal_lm import CausalLM
+        from ray_dynamic_batching_tpu.models.decoder import DecoderConfig
+
+        root = Path(__file__).resolve().parents[1] / "benchmark"
+        cfg = json.loads((root / "configs"
+                          / "glm-5-ep16-1chip.json").read_text())
+        metric = lambda name: json.loads(  # noqa: E731
+            (root / "layer_metrics" / f"{name}.json").read_text())["args"]
+        select = metric("sparse_latent_select_dev_share_pct.batch")
+        walk = re.compile(metric(
+            "chunk_attention_sparse_latent_dev_share_pct.batch")["op"])
+        llm = cfg["deployment"]["llm"]
+        dc = dict(cfg["program"]["decoder_config"], num_layers=layers)
+        m = CausalLM(DecoderConfig(**dc), name="m", dtype=jnp.bfloat16)
+        B, ps = llm["num_slots"], llm["page_size"]
+        W, NP = max(llm["prompt_buckets"]), llm["max_len"] // ps
+        struct = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dt, sharding=one_chip)
+        cache = jax.tree_util.tree_map(
+            lambda x: struct(x.shape, x.dtype),
+            jax.eval_shape(lambda: m.make_paged_cache(
+                B, llm["kv_pool_pages"], ps, llm["max_len"])))
+        p = jax.tree_util.tree_map(
+            lambda x: struct(x.shape, jnp.bfloat16),
+            jax.eval_shape(m.init, jax.random.PRNGKey(0)))
+        compile_ = TestLatentProgramsCompileForV5e._compile
+        chunk = lambda: compile_(  # noqa: E731
+            lambda *a: m.prefill_chunk_paged(*a, moe_counters=True),
+            p, struct((2, W), jnp.int32), struct((2, W), jnp.int32), cache,
+            struct((2, NP), jnp.int32), struct((2,), jnp.int32),
+            struct((2,), jnp.int32), donate=(3,))
+        decode = lambda: compile_(  # noqa: E731
+            lambda *a: m.decode_step_paged(*a, moe_counters=True),
+            p, struct((B, 1), jnp.int32), cache, struct((B,), jnp.bool_),
+            donate=(2,))
+        return decode, chunk, llm, select, walk
+
+    def test_the_views_the_decode_program_computes_again_are_the_selections(
+            self, one_chip, monkeypatch):
+        """ALL five layers' decode step: XLA's rematerialisation pass
+        computes some layers' gathered index keys a second time and renames
+        them ``fusion.<n>.remat`` (none at two layers), which the select
+        metric's ``remat`` takes by that name inside the decode program:
+        every such operation there says it is the selection's gather."""
+        import re
+        from types import SimpleNamespace
+
+        from benchmark import trace_reduce
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        decode, _, _, select, _ = self._lowered(one_chip, 5)
+        remat = re.compile(select["remat"]["op"])
+        again = [
+            line for line in decode().as_text().splitlines()
+            if line.startswith("  %") and remat.search(
+                trace_reduce.stable_name(SimpleNamespace(name=line.strip())))]
+        assert again
+        for line in again:
+            assert re.search(
+                r"bf16\[5760,128,128\].*sparse_latent_select/.*gather", line
+            ), line
+
+    def test_the_programs_compile_and_the_patterns_are_the_selections(
+            self, one_chip, monkeypatch):
+        import re
+        from types import SimpleNamespace
+
+        from benchmark import trace_reduce
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        decode, chunk, llm, select, walk = self._lowered(one_chip, 2)
+        decode, chunk, ps = decode(), chunk(), llm["page_size"]
+        planes = rf"bf16\[2,{llm['kv_pool_pages']},{ps},(?:640|128)\]"
+        taken = TestSelectionsOperationsInTheCompiledPrograms._taken
+        for compiled in (chunk, decode):
+            text = compiled.as_text()
+            made = re.findall(rf"= {planes}\{{[^}}]*\}} ([\w\-]+)\(", text)
+            assert set(made) <= {"parameter", "scatter", "fusion",
+                                 "bitcast", "get-tuple-element"}, made
+            assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+            # one k-th-key search a layer, taken whole; the scores among
+            # the operations beside it
+            loops, names = taken(text, select)
+            assert len(loops) == 2, loops
+            assert names
+        # what the chunk walk's pattern takes at the top level of the chunk
+        # program says it came from the walk (or says nothing: a bare copy)
+        comp = None
+        found = set()
+        for line in chunk.as_text().splitlines():
+            opened = re.match(r"^(ENTRY )?%?([\w\.\-]+) \(.*\{\s*$", line)
+            if opened:
+                comp = opened.group(2)
+                continue
+            if (comp is None or not line.startswith("  ")
+                    or comp.startswith(("fused_computation", "region"))):
+                continue
+            line = line.strip().removeprefix("ROOT ")
+            if " parameter(" in line:
+                continue
+            name = trace_reduce.stable_name(SimpleNamespace(name=line))
+            said = re.search(r'op_name="([^"]*)"', line)
+            if walk.search(name) and said:
+                found.add(name)
+                assert re.search(r"sparse_latent_chunk|\._latent_attention/",
+                                 said.group(1)), (name, said.group(1))
+        assert found
+        assert "_latent_paged_decode_attention" in decode.as_text()
+        assert "_latent_paged_decode_attention" not in chunk.as_text()
+
+
 class TestHybridProgramsCompileForV5e:
     """``lfm2-24b-a2b-ep8-1chip``'s programs at the published widths, 8 of
     its 40 layers (CCGC CCGC: six conv layers, two pool layers, the first

@@ -641,8 +641,12 @@ PINNED = {
     "keye-vl2-30b-ep8-1chip": ((3150, 3836), (2874, 4527)),
     "mimo-v2-flash-ep16-1chip": ((1726, 2976), (1733, 2990)),
     # (the CPU's form: the Sinkhorn rounds unrolled, 800 equations a
-    # sublayer; where Pallas is on they are one kernel's call)
-    "xing4-29b-ep8-1chip": ((39405, 40985), (39339, 40966)),
+    # sublayer; where Pallas is on they are one kernel's call). The chunk
+    # program was counted again in PR 57, which moves it ON PURPOSE (one
+    # walk for Xing and GLM-5: a block's keys made whole heads and scored
+    # in ONE product, 3 equations a layer more; it was (39339, 40966)); the
+    # decode program is still PR 46's.
+    "xing4-29b-ep8-1chip": ((39405, 40985), (39369, 40996)),
 }
 
 

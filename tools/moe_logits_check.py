@@ -36,8 +36,10 @@ reference, to show what the gap reads when something is wrong:
 ``int8_pool`` (the KV pool in int8 codes), ``float8_reference`` (with
 ``--harness`` alone: the program served as it is and the REFERENCE given the
 weights rounded to float8_e4m3, the nearest precision below the one the
-configuration states: a comparison that passes it holds nothing) or, for a
-model with an indexer,
+configuration states: a comparison that passes it holds nothing; beside it
+under ``--also``, the names of ``REFERENCE_CONTROLS``: a reference wrong on
+purpose, e.g. ``unrotated_index_keys_reference`` for a selecting latent
+configuration) or, for a model with an indexer,
 ``dense_attention`` (``index_topk`` as large as the cache: every query
 attends every cached position, the selection switched off and nothing else).
 For such a model the gap is also printed for the positions PAST
@@ -82,6 +84,9 @@ REFERENCE_CONTROLS = {
     "no_ssm_reference": {"without": ("ssm",)},
     "no_attention_reference": {"without": ("attention",)},
     "cut_state_reference": {"cut_state_at": 512},
+    # a selecting latent configuration's (benchmark/reference/glm5.py): the
+    # index keys left unrotated, a wrong indexer
+    "unrotated_index_keys_reference": {"rotate_index_keys": False},
 }
 GAPS = (0.002, 0.004, 0.006, 2.0 ** -7, 0.012, 0.016)
 # A selecting model's served logits past ``index_topk`` positions: the root
